@@ -10,14 +10,14 @@ WORKDIR /app
 COPY gubernator_tpu/ gubernator_tpu/
 COPY example.conf /etc/gubernator/gubernator.conf
 
-# C++ fast lane (batch hashing + protobuf wire codec); the service
-# falls back to the pure-Python paths if the build is unavailable
+# C++ fast lane (batch hashing + protobuf wire codec).  The image does
+# not build without it: a container serving through the slow
+# pure-Python lane would look like a slow device.
 RUN apt-get update \
     && apt-get install -y --no-install-recommends g++ \
     && python gubernator_tpu/ops/setup_native.py build_ext --inplace \
     && apt-get purge -y g++ && apt-get autoremove -y \
-    && rm -rf /var/lib/apt/lists/* \
-    || echo "native build unavailable; using pure-Python fallback"
+    && rm -rf /var/lib/apt/lists/*
 
 ENV GUBER_GRPC_ADDRESS=0.0.0.0:1051 \
     GUBER_HTTP_ADDRESS=0.0.0.0:1050

@@ -3,7 +3,7 @@
 PY ?= python
 
 .PHONY: test proto bench bench-pallas bench-tiered bench-diff chaos \
-        scenarios fleet-audit tpu-session b-sweep daemon cluster lint \
+        scenarios fleet-audit chip-smoke daemon cluster lint \
         native tsan asan racer check clean
 
 test:
@@ -93,14 +93,12 @@ bench-tiered:
 bench-diff:
 	$(PY) tools/bench_compare.py
 
-# one-shot on-chip validation battery (run when a TPU is reachable)
-tpu-session:
-	$(PY) tools/tpu_session.py
-
-# headline-only device-batch sweep, e.g. make b-sweep B="131072 262144"
-B ?= 131072
-b-sweep:
-	$(PY) tools/b_sweep.py $(B)
+# the quickest proof that the service path starts on the chip: one
+# process, both engines compiled, answers checked against the oracle
+# (run it on a machine with a TPU; add --cpu-rehearsal to rehearse the
+# same phases at tiny sizes on the CPU backend)
+chip-smoke:
+	$(PY) chip_smoke.py
 
 daemon:
 	$(PY) -m gubernator_tpu.cmd.daemon --config example.conf

@@ -5,7 +5,7 @@ lowering-independent step: the hand-scheduled kernel owns its table
 scatters, so its cost does not depend on how the XLA backend of the
 day lowers a 2^24-row scatter.  Use it when the XLA mode hits a
 large-CAP lowering pathology (see `tools/cap_ab.py`), and size the
-table up front — full 8-slot buckets turn NEW keys into table_full
+table up front — full 128-slot buckets turn NEW keys into table_full
 errors, watched by the `gubernator_pallas_bucket_saturation` gauge.
 Run: python examples/pallas_serving.py   (CPU runs the kernel in
 interpret mode — correct but slow; the mode targets real TPUs.)

@@ -15,30 +15,6 @@ gRPC peer fan-out.
 __version__ = "0.1.0"
 
 
-def _tune_xla_cpu_runtime() -> None:
-    """Serving-path CPU tuning, applied before the XLA backend
-    initializes: the thunk runtime that newer XLA:CPU builds default to
-    executes the sort-heavy decision step ~3× slower than the legacy
-    emitter (measured on this repo's serving program: 12.1 → 3.7 ms per
-    dense 8192-row wave, PERF.md §8), which directly caps the wire
-    front door.  ``xla_cpu_*`` flags are ignored by non-CPU backends,
-    and an operator's own XLA_FLAGS choice for this flag is respected.
-    """
-    import os
-
-    if os.environ.get("GUBER_XLA_CPU_TUNE", "1") != "1":
-        # escape hatch: an XLA build that drops this flag fails backend
-        # init on ANY unknown XLA_FLAGS entry (--undefok is itself
-        # rejected by XLA's parser) — GUBER_XLA_CPU_TUNE=0 recovers
-        return
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_cpu_use_thunk_runtime" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_cpu_use_thunk_runtime=false").strip()
-
-
-_tune_xla_cpu_runtime()
-
 from .types import (  # noqa: F401
     Algorithm,
     Behavior,

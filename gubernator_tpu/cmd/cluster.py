@@ -35,9 +35,9 @@ def main(argv=None) -> int:
                  "workers use OS-assigned peer ports; use --client-port "
                  "for the shared front door)")
 
-    from . import maybe_pin_platform
+    from .. import compilecache
 
-    maybe_pin_platform()
+    compilecache.setup()  # before jax is imported; workers inherit it
 
     def _serve(handle):
         """Install signal handlers only AFTER startup, so Ctrl-C during

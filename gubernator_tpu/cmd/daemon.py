@@ -23,9 +23,9 @@ def main(argv=None) -> int:
                          "SO_REUSEPORT front door)")
     args = ap.parse_args(argv)
 
-    from . import maybe_pin_platform
+    from .. import compilecache
 
-    maybe_pin_platform()
+    compilecache.setup()  # before jax is imported
 
     from ..config import setup_daemon_config
     from ..daemon import spawn_daemon

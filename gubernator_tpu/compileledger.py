@@ -49,9 +49,9 @@ from typing import Dict, List, Optional
 #: loudly instead of silently recording nothing
 _JAX_COMPILE_LOGGER = "jax._src.interpreters.pxla"
 
-#: "Compiling <fn> with global shapes and types ..." — fn is the
-#: jitted callable's __name__ (wrappers like jit(<lambda>) included)
-_COMPILE_RX = re.compile(r"^Compiling ([^\s]+)")
+#: "Compiling jit(<fn>) with global shapes and types ..." — fn is the
+#: jitted callable's __name__ (``<lambda>`` for lambdas)
+_COMPILE_RX = re.compile(r"^Compiling jit\(([^\s]+)\)")
 
 
 def enabled() -> bool:

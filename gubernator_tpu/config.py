@@ -36,23 +36,17 @@ ENV_REGISTRY: Dict[str, str] = {
     "GUBER_BATCH_WAIT": "peer-forward batch coalescing wait (duration)",
     "GUBER_BENCH_B": "bench: device-batch size override",
     "GUBER_BENCH_CAP": "bench: table capacity override",
-    "GUBER_BENCH_EXPECT_BACKEND": "bench: fail unless jax backend matches",
     "GUBER_BENCH_FAST": "bench: fast mode (fewer reps, smaller shapes)",
-    "GUBER_BENCH_INNER": "bench: marks the re-exec'd child process",
     "GUBER_BENCH_KEYS": "bench: key-cardinality override",
     "GUBER_BENCH_NO_PALLAS": "bench: skip Pallas sections",
     "GUBER_BENCH_PARTIAL": "bench: emit partial BENCH row on timeout salvage",
     "GUBER_BENCH_SCAN": "bench: occupancy-scan section toggle",
     "GUBER_BENCH_SECTION": "bench: run only this section",
     "GUBER_BENCH_SECTION_OUT": "bench: per-section checkpoint JSON path",
-    "GUBER_BENCH_SECTION_TIMEOUT": "bench: per-section timeout seconds",
-    "GUBER_BENCH_SKIP_FILE": "bench: file listing sections to skip",
     "GUBER_BENCH_SKIP_GROUP": "bench: skip the group-spread check",
     "GUBER_BENCH_STEP_MODE": "bench: step-impl mode for the step sections",
-    "GUBER_BENCH_TIMEOUT": "bench: whole-run watchdog seconds",
     "GUBER_CACHE_AUTOGROW_MAX": "auto-grow ceiling in TOTAL table rows; 0 disables",
     "GUBER_CACHE_SIZE": "table capacity per shard",
-    "GUBER_CAP_AB_ANY_BACKEND": "tools/cap_ab: allow non-TPU backends",
     "GUBER_CLIENT_ADDRESS": "HTTP client-facing listen address",
     "GUBER_COALESCE_US": "dispatcher coalescing window in µs (0 disables the wait)",
     "GUBER_COMPILE_LEDGER": "0 disables the runtime jit-compile ledger (compileledger.py): per-fn XLA compile counts, gubernator_jit_compiles, the steady-state recompile verdict",
@@ -65,7 +59,6 @@ ENV_REGISTRY: Dict[str, str] = {
     "GUBER_ENGINE": "serving engine: auto (default; fused pallas on TPU, classic xla elsewhere), pallas (fused everywhere — compiled XLA flavor off-TPU), xla/sharded (classic)",
     "GUBER_ETCD_ENDPOINTS": "etcd discovery: comma-separated endpoints",
     "GUBER_ETCD_PREFIX": "etcd discovery: key prefix for peer registration",
-    "GUBER_EXTRAS_SMOKE": "tools/tpu_session: run the extras smoke block",
     "GUBER_FAULT": "fault-injection spec point[@tag]:mode[:arg[:prob]],... (faults.py)",
     "GUBER_FAULT_SEED": "fault-injection RNG seed for bit-for-bit chaos replay",
     "GUBER_FLEET_AUDIT": "conservation auditor on the GLOBAL lanes: 0 disables the audit taps + /debug/audit drift (default on)",
@@ -79,7 +72,6 @@ ENV_REGISTRY: Dict[str, str] = {
     "GUBER_HANDOVER_ON_RESHARD": "stream moved rows to new owners on SetPeers",
     "GUBER_HTTP_ADDRESS": "HTTP (metrics/debug) listen address",
     "GUBER_INSTANCE_ID": "stable instance id (defaults to advertise address)",
-    "GUBER_JAX_PLATFORM": "force the jax platform (cpu/tpu) before first import",
     "GUBER_K8S_INSECURE": "k8s discovery: skip API-server cert verification",
     "GUBER_K8S_NAMESPACE": "k8s discovery: namespace to watch",
     "GUBER_K8S_POD_SELECTOR": "k8s discovery: pod label selector",
@@ -96,8 +88,7 @@ ENV_REGISTRY: Dict[str, str] = {
     "GUBER_MULTI_REGION_SYNC_WAIT": "cross-region flush coalescing wait (duration)",
     "GUBER_MULTI_REGION_TIMEOUT": "cross-region flush RPC timeout (duration)",
     "GUBER_NATIVE_SAN": "setup_native.py: build _native under tsan/asan (make tsan / make asan)",
-    "GUBER_PALLAS_PROBE_OUT": "tools/pallas_probe: checkpoint JSON path",
-    "GUBER_PALLAS_TILE": "Mosaic kernel block shape: requests per grid step (8-4096, default 128)",
+    "GUBER_PALLAS_TILE": "Mosaic kernel block shape: requests per grid step (a multiple of 8 in 8-512, default 128)",
     "GUBER_PALLAS_SWEEP": "1/0 force the fused Pallas sweep on/off (default: TPU only)",
     "GUBER_PEERS": "static peer list (host:port,... ) for static discovery",
     "GUBER_PEERS_FILE": "file-based discovery: path to the peer list",
@@ -114,8 +105,6 @@ ENV_REGISTRY: Dict[str, str] = {
     "GUBER_SCENARIO_DIR": "scenario-lab spec library directory (default scenarios/)",
     "GUBER_SCENARIO_FAST": "1 forces fast mode in every scenario-lab entry point",
     "GUBER_SCENARIO_SEED": "overrides every scenario spec's seed (sweep knob)",
-    "GUBER_SESSION_BENCH_TIMEOUT": "tools/tpu_session: bench stage timeout seconds",
-    "GUBER_SESSION_EXTRAS_OUT": "tools/tpu_session: extras checkpoint JSON path",
     "GUBER_SKETCH_WIDTH": "heavy-hitter sketch counter width (default 4×TOPK)",
     "GUBER_SLO": "0 disables the in-process SLO burn-rate engine",
     "GUBER_SLO_BURN": "burn-rate breach threshold (multiple of the error-budget spend rate, default 2.0)",
@@ -143,7 +132,6 @@ ENV_REGISTRY: Dict[str, str] = {
     "GUBER_TRACE_SAMPLE": "head-sampling rate for the trace plane (0 disables)",
     "GUBER_TRACE_SPANS": "span-recorder ring capacity (completed spans kept)",
     "GUBER_WAVE_BUCKETS": "comma-separated wave-size buckets for check_packed",
-    "GUBER_XLA_CPU_TUNE": "0 skips the XLA:CPU thunk-runtime opt-out at import",
 }
 
 _DUR_RE = re.compile(r"(\d+(?:\.\d+)?)(ns|us|µs|ms|s|m|h)")
@@ -292,8 +280,8 @@ class Config:
     #: everywhere (off-TPU: the compiled XLA fused flavor — one fused
     #: program per wave with on-device tap + mesh scatter, small-shape
     #: wave buckets); "xla"/"sharded" = the classic engine explicitly.
-    #: Construction failures fall back LOUDLY to the classic engine
-    #: (engine_fallback event) — availability beats mode fidelity.
+    #: A selected engine that cannot be built stops the daemon (no
+    #: stand-in engine: that would hide the device).
     engine: str = ""
     #: GLOBAL reconcile backend (ISSUE 7): "" / "grpc" keeps the
     #: reference's hit-queue + broadcast machinery; "mesh" serves

@@ -86,9 +86,11 @@ class WaveLease:
     a32 [3,m] i32) from a :class:`WaveBufferPool`.
 
     The holder must call :meth:`release` on EVERY path (success, engine
-    raise, close) once the device launch has consumed the buffers —
-    jax copies host operands during dispatch, so release-after-launch
-    is safe.  A lease dropped without release is detected by the GC
+    raise, close) once the wave's RESULTS are on the host — a launch is
+    asynchronous and the runtime may read the host operands until then
+    (the CPU backend aliases them outright), so a buffer returned right
+    after the launch is a data race with the next wave's fill.  A lease
+    dropped without release is detected by the GC
     hook: the pool counts it as a leak (``gubernator_wave_buffer_leaks``)
     and reclaims the buffers, so a bug degrades to a counter, not an
     unbounded allocation regression."""

@@ -51,21 +51,56 @@ PROBES = int(__import__("os").environ.get("GUBER_PROBES", "8"))
 INSERT_ROUNDS = 4  # slot-claim rounds per batch
 
 #: K-split scatter fallback (GUBER_KSPLIT=<log2 window>, default off):
-#: the 2026-08-01 backend compiler serialized the donated step's table
-#: scatters at CAP >= 2^22 (217-258 ms/step) while CAP 2^21 lowered
-#: well (0.118 ms).  With GUBER_KSPLIT=21, every table-row scatter is
-#: performed as CAP/2^21 slice-local scatters whose operands are the
-#: 2^21-row size that lowers well — subtracting each window's base
+#: a TPU compiler that serializes the donated step's table scatters at
+#: large CAP can be worked around by performing every table-row scatter
+#: as CAP/2^K slice-local scatters — subtracting each window's base
 #: preserves BOTH scatter promises (an ascending+unique index vector
 #: stays ascending+unique; rows outside the window fall out of bounds
 #: and drop), so no masking is needed.  Opt-in: on backends WITHOUT
 #: the pathology it is pure overhead (measured 2x on XLA:CPU at CAP
-#: 2^22 — the per-window concatenate streams the table), so it is an
-#: escalation tier between "promises fixed it" and "serve large CAP
-#: from the Pallas kernel", A/B-able on-chip in one compile
-#: (tools/cap_ab.py records the active value; tpu_session stage 2b
-#: fires it automatically when the plain probe stays pathological).
+#: 2^22 — the per-window concatenate streams the table).  Not measured
+#: on the current stack.
 KSPLIT_LOG2 = int(__import__("os").environ.get("GUBER_KSPLIT", "0"))
+
+
+def _long_divmod(n, d) -> tuple[jax.Array, jax.Array]:
+    """Shift-subtract long division of the operands' 64 bits as
+    unsigned words, as a loop unrolled 8-fold (8 trips) — divmod_nn's
+    TPU lowering."""
+    i64, u64 = jnp.int64, jnp.uint64
+    n, d, one = n.astype(u64), d.astype(u64), u64(1)
+
+    def step(_, c):
+        q, r, n = c
+        r = (r << one) | (n >> u64(63))
+        ge = r >= d
+        return ((q << one) | ge.astype(u64), jnp.where(ge, r - d, r),
+                n << one)
+
+    # zeros that inherit BOTH operands' mesh variance: under shard_map
+    # a loop carry must enter as varying as it leaves
+    zero = (n | d) & u64(0)
+    q, r, _ = lax.fori_loop(0, 64, step, (zero, zero, n | zero), unroll=8)
+    return q.astype(i64), r.astype(i64)
+
+
+def divmod_nn(n, d) -> tuple[jax.Array, jax.Array]:
+    """``(n // d, n % d)`` for int64 ``n >= 0`` and ``d >= 1``,
+    elementwise (operands broadcast).  Lanes outside that domain return
+    garbage; every caller selects them away.
+
+    TPUs have no 64-bit integer divider and XLA:TPU expands EACH int64
+    divide into a fully unrolled 64-step long division: ~6 s of compile
+    per divide, ~35 divides in the decision step — minutes per wave
+    program, longer than the dispatcher's result timeout.  On TPU this
+    lowers to the same long division as a short loop (_long_divmod),
+    which compiles in a fraction of a second; other platforms divide
+    natively."""
+    n, d = jnp.broadcast_arrays(jnp.asarray(n, jnp.int64),
+                                jnp.asarray(d, jnp.int64))
+    return lax.platform_dependent(
+        n, d, tpu=_long_divmod,
+        default=lambda n, d: (lax.div(n, d), lax.rem(n, d)))
 
 
 def _scatter_rows(col, idx, vals, *, sorted_idx: bool):
@@ -302,11 +337,13 @@ def _apply_position(item: _Item, req: _Req):
     # frac × eff fits int64 (both denominators ≤ FRAC_SAFE), else the
     # rescale floors to whole tokens — identical in oracle.apply_leaky.
     leaky_eff_change = is_leaky & (~fresh) & (req.eff != eff0)
-    whole = rem0 // jnp.maximum(eff0, 1)
-    frac = rem0 % jnp.maximum(eff0, 1)
-    whole = jnp.minimum(whole, TD_BOUND // jnp.maximum(req.eff, 1))
+    old_eff = jnp.maximum(eff0, 1)
+    whole, frac = divmod_nn(rem0, old_eff)
+    whole = jnp.minimum(
+        whole, divmod_nn(TD_BOUND, jnp.maximum(req.eff, 1))[0])
     frac_ok = (eff0 <= FRAC_SAFE) & (req.eff <= FRAC_SAFE)
-    frac_term = (jnp.where(frac_ok, frac, 0) * req.eff) // jnp.maximum(eff0, 1)
+    frac_term = divmod_nn(jnp.where(frac_ok, frac, 0) * req.eff,
+                          old_eff)[0]
     rem_rescaled = whole * req.eff + frac_term
     rem0 = jnp.where(leaky_eff_change, rem_rescaled, rem0)
     eff0 = jnp.where(is_leaky, req.eff, jnp.where(tok_dur_change, req.eff, eff0))
@@ -332,14 +369,15 @@ def _apply_position(item: _Item, req: _Req):
     burst1 = jnp.where(is_leaky, req.burst, limit1)
     elapsed = now - t0
     cap_td = burst1 * jnp.where(is_leaky, eff0, 0)
-    safe_el = TD_BOUND // jnp.maximum(limit1, 1)
+    safe_el = divmod_nn(TD_BOUND, jnp.maximum(limit1, 1))[0]
     rem_rep = jnp.where(
         elapsed > safe_el, cap_td,
         jnp.minimum(rem0 + jnp.minimum(elapsed, safe_el) * limit1, cap_td))
     rem0 = jnp.where(is_leaky, rem_rep, rem0)
     t1 = jnp.where(is_leaky, now, t0)
 
-    rate = jnp.where(limit1 > 0, eff0 // jnp.maximum(limit1, 1), eff0)
+    rate = jnp.where(limit1 > 0,
+                     divmod_nn(eff0, jnp.maximum(limit1, 1))[0], eff0)
     exp_out = jnp.where(is_leaky, now + eff0, exp0)
     reset_time = jnp.where(is_leaky, now + rate, exp_out)
 
@@ -352,7 +390,8 @@ def _apply_position(item: _Item, req: _Req):
     status1 = jnp.where(is_query, status0,
                         jnp.where(ok, 0, 1)).astype(jnp.int32)
 
-    out_rem = jnp.where(is_leaky, rem2 // jnp.maximum(eff0, 1), rem2)
+    out_rem = jnp.where(
+        is_leaky, divmod_nn(rem2, jnp.maximum(eff0, 1))[0], rem2)
     dur1 = req.duration
     new_item = _Item(alg=req.alg, status=status1, limit=limit1, duration=dur1,
                      eff=eff0, burst=burst1, rem=rem2, t=t1, exp=exp_out)
@@ -496,7 +535,9 @@ def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array
     simple = exists & uniform & (~any_flag)
     complex_seg = exists & (seg_len > 1) & (~simple)
     cost0 = req0.hits * jnp.where(is_leaky0, item1.eff, 1)
-    k_raw = jnp.where(cost0 > 0, item1.rem // jnp.maximum(cost0, 1), _I64_MAX)
+    k_raw = jnp.where(
+        cost0 > 0, divmod_nn(item1.rem, jnp.maximum(cost0, 1))[0],
+        _I64_MAX)
     tail_n = jnp.maximum(seg_len - 1, 0).astype(i64)
     k = jnp.minimum(k_raw, tail_n)  # accepted tail requests
     # final per-segment state after the whole tail
@@ -522,8 +563,9 @@ def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array
     t_status = jnp.where(cost0[sid] > 0,
                          jnp.where(tail_ok, 0, 1), item1.status[sid]).astype(i32)
     t_rem = item1.rem[sid] - jnp.minimum(jj, k[sid]) * jnp.maximum(cost0[sid], 0)
-    t_rem_out = jnp.where(is_leaky0[sid],
-                          t_rem // jnp.maximum(item1.eff[sid], 1), t_rem)
+    t_rem_out = jnp.where(
+        is_leaky0[sid],
+        divmod_nn(t_rem, jnp.maximum(item1.eff[sid], 1))[0], t_rem)
     tail_mask = simple[sid] & (pos > 0)
 
     # assemble sorted-order outputs: heads then simple tails.  out0 is
@@ -583,7 +625,7 @@ def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array
         c = sf.hits * jnp.where(lseg[sid], effp, 1)  # mask: token
         # hits*eff of a non-participating segment may wrap int64
         cap_td = sf.burst * jnp.where(lseg[sid], effp, 1)
-        safe_el = TD_BOUND // jnp.maximum(L, 1)
+        safe_el = divmod_nn(TD_BOUND, jnp.maximum(L, 1))[0]
         tail_sel = lseg[sid] & (pos > 0)
         m_el = jnp.where(tail_sel, cap_td - c, INF)
         # d >= eff crosses the expiry: the bucket goes FRESH (rem =
@@ -622,10 +664,11 @@ def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array
         st_pos = jnp.where(is_query,
                            jnp.where(crossed, 0, item1.status[sid]),
                            0).astype(i32)
-        rate = jnp.where(L > 0, effp // jnp.maximum(L, 1), effp)
+        rate = jnp.where(L > 0,
+                         divmod_nn(effp, jnp.maximum(L, 1))[0], effp)
         ap = ok_seg[sid] & tail_sel
         os_ = jnp.where(ap, st_pos, os_)
-        or_ = jnp.where(ap, r // effp, or_)
+        or_ = jnp.where(ap, divmod_nn(r, effp)[0], or_)
         ot_ = jnp.where(ap, e + rate, ot_)
         ol_ = jnp.where(ap, L, ol_)
 

@@ -215,20 +215,34 @@ class Daemon:
                           if self.tls is not None else None)
             self.instance = V1Instance(icfg, mesh=mesh, engine=engine,
                                        peer_tls_creds=peer_creds)
-            # Warm-up: compile the device step before serving (first
-            # compile is tens of seconds; an RPC must not eat that).
-            self.instance.get_rate_limits(
-                [RateLimitRequest(name="_warmup", unique_key="w", hits=0,
-                                  limit=1, duration=1000)])
+            log.info("serving from engine=%(engine)s on "
+                     "platform=%(platform)s device_kind=%(device_kind)r "
+                     "devices=%(device_count)d "
+                     "native_wire_lane=%(native_wire_lane)s",
+                     self.instance.serving_info)
+            if not self.instance.serving_info["native_wire_lane"]:
+                log.warning(
+                    "ops/_native is not built: hashing and the wire "
+                    "lane run in pure Python/pb2 (build it with "
+                    "`python gubernator_tpu/ops/setup_native.py "
+                    "build_ext --inplace`)")
+            # Warm-up: compile the device step before serving (an RPC
+            # must not eat a cold compile).
             import jax
 
             if (hasattr(self.instance.engine, "warmup")
                     and jax.default_backend() == "tpu"):
-                # every wave bucket, so a first coalesced burst never
-                # eats a minutes-scale cold compile inside an RPC.  Off
-                # TPU a bucket compiles in milliseconds on first use,
-                # not worth taxing every (test) daemon startup.
-                self.instance.engine.warmup()
+                # every wave bucket, called on the engine directly and
+                # FIRST: cold TPU compiles can outlast the dispatcher's
+                # result timeout the warm-up request below waits under,
+                # and a first coalesced burst must never eat one inside
+                # an RPC.  Off TPU a bucket compiles quickly on first
+                # use, not worth taxing every (test) daemon startup.
+                with self.instance._engine_mu:
+                    self.instance.engine.warmup()
+            self.instance.get_rate_limits(
+                [RateLimitRequest(name="_warmup", unique_key="w", hits=0,
+                                  limit=1, duration=1000)])
             add_v1_servicer_raw(self.grpc_server,
                                 _V1Servicer(self.instance))
             add_peers_servicer_raw(self.grpc_server,
@@ -330,7 +344,8 @@ class Daemon:
                     h = daemon.instance.health_check()
                     code = 200 if h.status == "healthy" else 503
                     body = {"status": h.status, "message": h.message,
-                            "peer_count": h.peer_count}
+                            "peer_count": h.peer_count,
+                            "serving": daemon.instance.serving_info}
                     if q.get("deep", ["0"])[-1] not in ("", "0", "false"):
                         # deep mode: dispatcher queue depth, last-wave
                         # age, stalled state — the stall watchdog's

@@ -145,6 +145,7 @@ class V1Instance:
         if install_if_enabled():
             _compile_ledger.attach_metrics(self.metrics)
         self.compile_ledger = _compile_ledger
+        kind = type(engine).__name__  # an injected engine names itself
         if engine is None:
             # lazy: an injected engine (tests, alternative backends)
             # must not drag the sharded/jax stack in
@@ -176,6 +177,19 @@ class V1Instance:
                 step_impl, _jax.default_backend())
             engine = self._build_engine(kind, m, n, cap_local, config)
         self.engine = engine
+        #: what is serving — the daemon's start-up log line and /healthz
+        #: name it, so a daemon on the wrong backend or engine is seen
+        #: at a glance rather than inferred from its speed
+        devs = (list(engine.mesh.devices.flat)
+                if getattr(engine, "mesh", None) is not None else [])
+        self.serving_info = {
+            "engine": kind,
+            "platform": devs[0].platform if devs else "",
+            "device_kind": devs[0].device_kind if devs else "",
+            "device_count": len(devs),
+            # ops/_native*.so is a build output: a checkout that never
+            # built it serves through the slow numpy/pb2 lane
+            "native_wire_lane": _wire_native is not None}
         self._engine_mu = threading.Lock()
         from .dispatcher import Dispatcher
 
@@ -350,53 +364,39 @@ class V1Instance:
 
     def _build_engine(self, kind: str, m, n: int, cap_local: int,
                       config: Config):
-        """Construct the resolved engine kind (ISSUE 8).  Fused kinds
-        selected through GUBER_ENGINE fall back LOUDLY to the classic
-        sharded engine on construction failure (engine_fallback event +
-        warning, decisions stay correct — availability beats mode
-        fidelity); the legacy explicit GUBER_STEP_IMPL=pallas raises as
-        it always has (the operator asked for that kernel engine
-        specifically, e.g. for a parity battery)."""
+        """Construct the resolved engine kind (ISSUE 8).  An engine
+        that was selected and cannot be built stops the daemon: serving
+        from a different engine than the one the operator (or the
+        platform default) chose would hide the device."""
         from .parallel.sharded import (ShardedEngine,
                                        autogrow_limit_per_shard)
 
-        if kind in ("pallas-kernel", "pallas-fused", "xla-fused"):
-            try:
-                if kind == "xla-fused":
-                    from .parallel.pallas_engine import XlaFusedEngine
+        if kind == "xla-fused":
+            from .parallel.pallas_engine import XlaFusedEngine
 
-                    return XlaFusedEngine(
-                        m, capacity_per_shard=cap_local,
-                        batch_per_shard=config.batch_rows,
-                        auto_grow_limit=autogrow_limit_per_shard(
-                            config.cache_autogrow_max, n, cap_local))
-                from .parallel.pallas_engine import PallasServingEngine
+            return XlaFusedEngine(
+                m, capacity_per_shard=cap_local,
+                batch_per_shard=config.batch_rows,
+                auto_grow_limit=autogrow_limit_per_shard(
+                    config.cache_autogrow_max, n, cap_local))
+        if kind in ("pallas-kernel", "pallas-fused"):
+            from .parallel.pallas_engine import PallasServingEngine
 
-                if config.cache_autogrow_max:
-                    # silently different capacity semantics would be a
-                    # trap: the xla engine grows to this bound, pallas
-                    # mode never grows (VERDICT r4 weak #4)
-                    log.warning(
-                        "pallas serving engine ignores "
-                        "cache_autogrow_max=%d: this mode has no "
-                        "on-device grow — size cache_size for peak "
-                        "keys up front (full 8-slot buckets err as "
-                        "table_full; watch "
-                        "gubernator_pallas_bucket_saturation)",
-                        config.cache_autogrow_max)
-                return PallasServingEngine(
-                    m, capacity_per_shard=cap_local,
-                    batch_per_shard=config.batch_rows)
-            except Exception as e:  # noqa: BLE001 - loud fallback below
-                if kind == "pallas-kernel":
-                    raise
+            if config.cache_autogrow_max:
+                # silently different capacity semantics would be a
+                # trap: the xla engine grows to this bound, pallas
+                # mode never grows (VERDICT r4 weak #4)
                 log.warning(
-                    "fused engine %r unavailable (%s) — serving falls "
-                    "back to the classic sharded engine; decisions are "
-                    "identical, the fused-wave perf tier is OFF",
-                    kind, exc_text(e))
-                self.recorder.record("engine_fallback", wanted=kind,
-                                     error=exc_text(e))
+                    "pallas serving engine ignores "
+                    "cache_autogrow_max=%d: this mode has no "
+                    "on-device grow — size cache_size for peak "
+                    "keys up front (full 128-slot buckets err as "
+                    "table_full; watch "
+                    "gubernator_pallas_bucket_saturation)",
+                    config.cache_autogrow_max)
+            return PallasServingEngine(
+                m, capacity_per_shard=cap_local,
+                batch_per_shard=config.batch_rows)
         return ShardedEngine(
             m, capacity_per_shard=cap_local,
             batch_per_shard=config.batch_rows,

@@ -1,23 +1,28 @@
-"""Pallas TPU kernel: the TOKEN_BUCKET decision step (probe → gather →
-update → scatter) as ONE hand-scheduled Mosaic program.
+"""Pallas TPU kernel: the decision step (probe → gather → update →
+scatter) as ONE hand-scheduled Mosaic program — the TPU's default
+serving kernel (parallel/pallas_engine.py).
 
-Why this exists (VERDICT r2 item 4, SURVEY §2.2 north star): the XLA
-decision step's throughput is lowering-sensitive — the same program has
-measured 500 M dec/s (donated) and 209 ms/step (copy-mode scatters
-serialized) on the same chip on the same day.  This kernel owns its
-memory traffic explicitly, so its rate is a measured FLOOR independent
-of XLA's scatter/gather lowering choices.  bench.py enters it in the
-per-run mode duel alongside copy/donate (`extra.step_mode` can report
-"pallas").
+Why this exists (SURVEY §2.2 north star): the XLA decision step's
+throughput depends on how the compiler of the day lowers its scatters
+and gathers.  This kernel owns its memory traffic explicitly.
+bench.py also enters it in the per-run mode duel alongside copy/donate
+(`extra.step_mode` can report "pallas").
 
 Design (TPU-first, not a translation):
 
-- **Bucketized AoS table.**  Instead of the XLA path's SoA columns +
-  double-hash probing (9 scattered per-row touches), the Pallas table
-  is `[CAP, 32] int32`: 8-slot buckets of 128-byte rows, so ONE 1 KiB
-  DMA moves a key's entire probe window *with* its data.  Layout is a
-  mode-level choice — decisions are layout-independent, and the parity
-  tests assert exactly that.
+- **Bucket = one DMA window, one lane per slot.**  Instead of the XLA
+  path's SoA columns + double-hash probing (9 scattered per-row
+  touches), the Pallas table is ``[n_buckets, 16, 128] int32``: a
+  bucket holds 128 slots, word ``w`` of slot ``s`` at ``[w, s]``.  That
+  is two native (8, 128) int32 tiles — HBM holds exactly 64 B per slot
+  with no lane padding (a ``[CAP, 32]`` row table is padded 4× by the
+  TPU's (8, 128) tiling and cannot be sliced by a DMA) — and ONE
+  aligned 8 KiB DMA moves a key's entire probe window *with* its data.
+  In VMEM each state word of the bucket is a (1, 128) lane vector, so
+  the decision math runs once for all 128 candidate slots and the
+  matched slot is lane-selected at writeback: no vector→scalar
+  extraction anywhere.  Layout is a mode-level choice — decisions are
+  layout-independent, and the parity tests assert exactly that.
 - **Sequential grid + in-tile serial loop.**  TPU Pallas grids run
   sequentially, which gives cross-tile duplicate ordering for free;
   within a tile a `fori_loop` applies requests strictly in order
@@ -71,27 +76,28 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.batch import RequestBatch
-from ..core.step import StepOutput
+from ..core.step import StepOutput, divmod_nn
 from ..types import TD_BOUND, Behavior
 
-SLOTS = 8  # probe window = one bucket
-WORDS = 32  # i32 words per row (128 B — DMA-friendly, room to grow)
+SLOTS = 128  # slots per bucket = lanes: the probe window is one bucket
+WORDS = 16  # i32 words per slot = sublanes of a bucket's two tiles
 TILE = 128  # requests per grid step (default; see pallas_tile())
 
 
 def pallas_tile() -> int:
     """Requests per Mosaic grid step — the kernel's block-shape knob
-    (GUBER_PALLAS_TILE).  Bounded to [8, 4096]: the in-tile dedup map
-    is O(tile²) host work and the VMEM scratch is tile×1 KiB, so an
-    unbounded value would trade one launch for an unschedulable tile.
-    Malformed/out-of-range values keep the default (a perf knob must
-    never turn into a crash knob).  Resolved at engine/program BUILD
-    time — a live env flip does not retrace compiled programs."""
+    (GUBER_PALLAS_TILE).  A multiple of 8 (the response block's sublane
+    rule) in [8, 512]: the in-tile dedup map is O(tile²) host work and
+    the VMEM scratch is tile×8 KiB, so an unbounded value would trade
+    one launch for an unschedulable tile.  Malformed/out-of-range
+    values keep the default (a perf knob must never turn into a crash
+    knob).  Resolved at engine/program BUILD time — a live env flip
+    does not retrace compiled programs."""
     raw = os.environ.get("GUBER_PALLAS_TILE", "")
     if raw:
         try:
             t = int(raw)
-            if 8 <= t <= 4096:
+            if 8 <= t <= 512 and t % 8 == 0:
                 return t
         except ValueError:
             pass
@@ -114,7 +120,7 @@ _RESET = int(Behavior.RESET_REMAINING)
 _DRAIN = int(Behavior.DRAIN_OVER_LIMIT)
 _GREG = int(Behavior.DURATION_IS_GREGORIAN)
 
-# ---- row word layout (i32 words within a 32-word slot) -----------------
+# ---- slot word layout (word w of a bucket = sublane w) ------------------
 W_KLO, W_KHI = 0, 1
 W_REM, W_STATUS, W_LIMIT = 2, 3, 4
 W_TLO, W_THI = 5, 6
@@ -123,7 +129,6 @@ W_ELO, W_EHI = 9, 10  # eff_ms
 W_DLO, W_DHI = 11, 12  # duration
 W_ALG = 13  # 0 token / 1 leaky (empty slot = 0: insert is fresh anyway)
 W_TDLO, W_TDHI = 14, 15  # leaky remaining, td units (= remaining × eff)
-# words 16..31: reserved
 # (item.burst is NOT stored: oracle.apply_leaky overwrites it from the
 # request before every read, so the replenish cap is the request-only
 # burst×eff column)
@@ -159,26 +164,6 @@ def _neq64(ah, al, bh, bl):
 
 def _sel(c, a, b):
     return jnp.where(c, a, b)
-
-
-def _tsum8(v):
-    """(8,) i32 → scalar sum via an explicit halving tree.  jnp.sum on
-    a rank-1 vector goes through Mosaic's proxy lowering, which
-    re-traces under the ambient x64 config and emits 64-bit converts
-    that have no TPU lowering (observed on-chip 2026-08-01); elementwise
-    adds + a final scalar extract lower natively."""
-    assert SLOTS == 8, "halving trees are hardcoded to 8-slot buckets"
-    m = v[:4] + v[4:]
-    m = m[:2] + m[2:]
-    return m[0] + m[1]
-
-
-def _tmin8(v):
-    """(8,) i32 → scalar min via a halving tree (see _tsum8)."""
-    assert SLOTS == 8, "halving trees are hardcoded to 8-slot buckets"
-    m = jnp.minimum(v[:4], v[4:])
-    m = jnp.minimum(m[:2], m[2:])
-    return jnp.minimum(m[0], m[1])
 
 
 def _sel64(c, ah, al, bh, bl):
@@ -244,7 +229,7 @@ def _udiv64_32(nh, nl, d):
         Q = (Q << 1) | geq.astype(i32)
         return R, Q, L
 
-    R, Q, _ = lax.fori_loop(0, 32, step, (nh, i32(0), nl))
+    R, Q, _ = lax.fori_loop(0, 32, step, (nh, jnp.zeros_like(nh), nl))
     return Q, R
 
 
@@ -262,16 +247,26 @@ def _join64(hi, lo, dtype):
 
 
 class PallasTable(NamedTuple):
-    """Bucketized AoS table: ``rows[CAP, WORDS]`` int32, CAP a power of
-    two ≥ 8; bucket b = rows[8b : 8b+8].  Empty slot: key words 0."""
+    """Bucket table: ``buckets[n_buckets, WORDS, SLOTS]`` int32,
+    n_buckets a power of two; word w of bucket b's slot s is
+    ``buckets[b, w, s]``.  Empty slot: all words 0."""
 
-    rows: jax.Array
+    buckets: jax.Array
 
 
 def init_pallas_table(capacity: int) -> PallasTable:
+    """``capacity`` counts slots (the unit every engine sizes in)."""
     if capacity < SLOTS or capacity & (capacity - 1):
         raise ValueError(f"capacity must be a power of two >= {SLOTS}")
-    return PallasTable(rows=jnp.zeros((capacity, WORDS), jnp.int32))
+    return PallasTable(buckets=jnp.zeros(
+        (capacity // SLOTS, WORDS, SLOTS), jnp.int32))
+
+
+def buckets_to_rows(buckets):
+    """[nb, WORDS, SLOTS] device layout → [nb, SLOTS, WORDS] slot rows
+    (the host-side view: row ``[b, s]`` is one slot's words).  Its own
+    inverse; numpy or jax arrays."""
+    return buckets.transpose(0, 2, 1)
 
 
 def pallas_value_domain_mask(batch: RequestBatch):
@@ -335,128 +330,145 @@ def pallas_qualifies(batch: RequestBatch) -> bool:
     return True
 
 
-def _kernel(tile, bb_ref, brep_ref, klo_ref, khi_ref, hits_ref, lim_ref,
-            dlo_ref, dhi_ref, elo_ref, ehi_ref, glo_ref, ghi_ref,
-            beh_ref, nlo_ref, nhi_ref, valid_ref,
-            alg_ref, htl_ref, hth_ref, cpl_ref, cph_ref,
-            rsl_ref, rsh_ref, rate_ref, gdl_ref, gdh_ref,
-            _table_in, table_ref, st_o, rem_o, rlo_o, rhi_o, lim_o,
-            flg_o, scratch, sem_in, sem_out):
+#: request columns, one SMEM row each (row index within a tile's block)
+(C_BUCKET, C_BREP, C_KLO, C_KHI, C_HITS, C_LIM, C_DLO, C_DHI, C_ELO,
+ C_EHI, C_GLO, C_GHI, C_BEH, C_NLO, C_NHI, C_VALID, C_ALG, C_HTL, C_HTH,
+ C_CPL, C_CPH, C_RSL, C_RSH, C_RATE, C_GDL, C_GDH) = range(26)
+N_COLS = 32  # SMEM block rows: the 26 columns above, padded to 8k
+
+#: output lanes of a request's (1, SLOTS) result row
+O_STATUS, O_REM, O_RLO, O_RHI, O_LIMIT, O_FLAGS = range(6)
+
+
+def _kernel(tile, c_ref, _table_in, table_ref, out_ref, scratch, sems):
     """One grid step = one ``tile`` of requests, strictly in order.
 
-    scratch[j*8:(j+1)*8] holds request j's bucket copy iff j is its
-    tile-first occurrence (brep[j] == j); later same-bucket requests
-    read/write the first copy, so in-tile duplicates see each other's
-    updates exactly as a sequential loop would."""
+    scratch[j] holds request j's bucket copy iff j is its tile-first
+    occurrence (brep[j] == j); later same-bucket requests read/write
+    the first copy, so in-tile duplicates see each other's updates
+    exactly as a sequential loop would.
+
+    Every per-request value is a (1, SLOTS) lane vector — lane s is
+    "this request applied to slot s" — and the matched (or claimed)
+    slot's lane is selected at writeback.  Request scalars are splat
+    from SMEM; the only cross-lane work is the found/first-empty
+    reductions and the response-row reductions."""
     i32 = jnp.int32
+    row = (1, SLOTS)
+    lane = lax.broadcasted_iota(i32, row, 1)
 
     def first_live(j):
-        return (brep_ref[0, 0, j] == j) & (valid_ref[0, 0, j] != 0)
+        return (c_ref[C_BREP, j] == j) & (c_ref[C_VALID, j] != 0)
+
+    def bucket_dma(j, gather):
+        hbm = table_ref.at[c_ref[C_BUCKET, j]]
+        if gather:
+            return pltpu.make_async_copy(hbm, scratch.at[j], sems.at[0])
+        return pltpu.make_async_copy(scratch.at[j], hbm, sems.at[1])
+
+    def for_each_first_live(fn):
+        def step(j, c):
+            @pl.when(first_live(j))
+            def _():
+                fn(j)
+            return c
+
+        lax.fori_loop(0, tile, step, 0)
 
     # 1) gather: one DMA per distinct live bucket in the tile
-    def issue_in(j, c):
-        @pl.when(first_live(j))
-        def _():
-            pltpu.make_async_copy(
-                table_ref.at[pl.ds(bb_ref[0, 0, j], SLOTS)],
-                scratch.at[pl.ds(j * SLOTS, SLOTS)],
-                sem_in.at[j]).start()
-        return c
+    for_each_first_live(lambda j: bucket_dma(j, True).start())
+    for_each_first_live(lambda j: bucket_dma(j, True).wait())
 
-    lax.fori_loop(0, tile, issue_in, 0)
-
-    def wait_in(j, c):
-        @pl.when(first_live(j))
-        def _():
-            pltpu.make_async_copy(
-                table_ref.at[pl.ds(bb_ref[0, 0, j], SLOTS)],
-                scratch.at[pl.ds(j * SLOTS, SLOTS)],
-                sem_in.at[j]).wait()
-        return c
-
-    lax.fori_loop(0, tile, wait_in, 0)
-
-    lane = lax.broadcasted_iota(i32, (SLOTS, WORDS), 1)
+    def lanes_of(x):
+        """(1, 1) reduction result → all lanes."""
+        return jnp.broadcast_to(x, row)
 
     # 2) apply requests in order against the live bucket copies
     def body(j, c):
-        valid = valid_ref[0, 0, j] != 0
+        def splat(k):
+            return jnp.full(row, c_ref[k, j], i32)
 
-        @pl.when(valid)
+        def emit(vals):
+            """Store request j's response row: ``vals`` maps output
+            lane → (1, SLOTS) value (already lane-uniform)."""
+            out = jnp.zeros(row, i32)
+            for o, v in vals.items():
+                out = jnp.where(lane == o, v, out)
+            out_ref[pl.ds(j, 1), :] = out
+
+        @pl.when(c_ref[C_VALID, j] != 0)
         def _process():
-            base = brep_ref[0, 0, j] * SLOTS
-            tile = scratch[pl.ds(base, SLOTS), :]  # [SLOTS, WORDS]
-            klo, khi = klo_ref[0, 0, j], khi_ref[0, 0, j]
+            b = c_ref[C_BREP, j]
 
-            def col(w):
-                return tile[:, w]
+            def ld(w):
+                return scratch[b, pl.ds(w, 1), :]
 
-            match = (col(W_KLO) == klo) & (col(W_KHI) == khi)
-            # all reductions in i32: Mosaic's bool reduce_or/any proxy
-            # lowers through float64, which has no scalar conversion
-            # on TPU (observed on-chip 2026-08-01)
-            found = _tsum8(match.astype(i32)) > 0
-            empty = (col(W_KLO) == 0) & (col(W_KHI) == 0)
-            # first empty slot: lowest slot index among empties (iota +
-            # min — stable, deterministic, no float cumsum)
-            slot_iota = lax.broadcasted_iota(i32, (SLOTS,), 0)
-            first_idx = _tmin8(jnp.where(empty, slot_iota, i32(SLOTS)))
-            first_empty = empty & (slot_iota == first_idx)
-            has_empty = first_idx < i32(SLOTS)
+            def pick(mask, v):
+                """The masked slot's value on every lane (0 if none)."""
+                return lanes_of(jnp.sum(jnp.where(mask, v, 0), axis=1,
+                                        keepdims=True))
+
+            klo, khi = splat(C_KLO), splat(C_KHI)
+            s_klo, s_khi = ld(W_KLO), ld(W_KHI)
+            match = (s_klo == klo) & (s_khi == khi)
+            empty = (s_klo == 0) & (s_khi == 0)
+            found = pick(match, 1) > 0
+            # first empty slot: lowest lane among empties
+            first_idx = lanes_of(jnp.min(
+                jnp.where(empty, lane, SLOTS), axis=1, keepdims=True))
+            has_empty = first_idx < SLOTS
             insert = (~found) & has_empty
             err = (~found) & (~has_empty)  # bucket full
-            slot1h = jnp.where(found, match, first_empty)  # [SLOTS]
+            slot1h = (found & match) | (insert & (lane == first_idx))
 
-            def pick(w):
-                """matched/claimed slot's word w as a scalar (0 for a
-                fresh insert: empty slots hold zero words)."""
-                return _tsum8(jnp.where(slot1h, col(w), i32(0)))
-
-            # item state (insert reads the zeroed empty slot → fresh
-            # fires below, matching the XLA path's post-insert read)
-            it_rem, it_status = pick(W_REM), pick(W_STATUS)
-            it_limit, it_alg = pick(W_LIMIT), pick(W_ALG)
-            it_tlo, it_thi = pick(W_TLO), pick(W_THI)
-            it_xlo, it_xhi = pick(W_XLO), pick(W_XHI)
-            it_elo, it_ehi = pick(W_ELO), pick(W_EHI)
-            it_dlo, it_dhi = pick(W_DLO), pick(W_DHI)
-            it_tdlo, it_tdhi = pick(W_TDLO), pick(W_TDHI)
+            # item state per slot lane (an insert claims a zeroed empty
+            # slot → fresh fires below, matching the XLA path's
+            # post-insert read)
+            it_rem, it_status = ld(W_REM), ld(W_STATUS)
+            it_limit, it_alg = ld(W_LIMIT), ld(W_ALG)
+            it_tlo, it_thi = ld(W_TLO), ld(W_THI)
+            it_xlo, it_xhi = ld(W_XLO), ld(W_XHI)
+            it_elo, it_ehi = ld(W_ELO), ld(W_EHI)
+            it_dlo, it_dhi = ld(W_DLO), ld(W_DHI)
+            it_tdlo, it_tdhi = ld(W_TDLO), ld(W_TDHI)
 
             # request fields
-            r_hits, r_lim = hits_ref[0, 0, j], lim_ref[0, 0, j]
-            r_dlo, r_dhi = dlo_ref[0, 0, j], dhi_ref[0, 0, j]
-            r_elo, r_ehi = elo_ref[0, 0, j], ehi_ref[0, 0, j]
-            r_glo, r_ghi = glo_ref[0, 0, j], ghi_ref[0, 0, j]
-            r_alg = alg_ref[0, 0, j]
-            beh = beh_ref[0, 0, j]
+            r_hits, r_lim = splat(C_HITS), splat(C_LIM)
+            r_dlo, r_dhi = splat(C_DLO), splat(C_DHI)
+            r_elo, r_ehi = splat(C_ELO), splat(C_EHI)
+            r_glo, r_ghi = splat(C_GLO), splat(C_GHI)
+            r_alg_s = c_ref[C_ALG, j]
+            r_alg = splat(C_ALG)
+            beh = splat(C_BEH)
             is_greg = (beh & _GREG) != 0
             reset = (beh & _RESET) != 0
             drain = (beh & _DRAIN) != 0
 
             # now = max(req.now, item.t)  (per-key monotonic clock)
-            nhi0, nlo0 = nhi_ref[0, 0, j], nlo_ref[0, 0, j]
+            nhi0, nlo0 = splat(C_NHI), splat(C_NLO)
             use_req = _ge64(nhi0, nlo0, it_thi, it_tlo)
             nhi1, nlo1 = _sel64(use_req, nhi0, nlo0, it_thi, it_tlo)
 
             # fresh: empty / expired / algorithm switch
             fresh0 = ((~found) | _ge64(nhi1, nlo1, it_xhi, it_xlo)
                       | (it_alg != r_alg))
-            is_query = r_hits == i32(0)
-            dead = err
-            flg_o[0, 0, j] = err.astype(i32) | (
-                (insert & ~err).astype(i32) << 1)
-            lim_o[0, 0, j] = _sel(dead, i32(0), r_lim)
-            # default-zero the branch-written outputs: a valid row with
-            # an out-of-domain algorithm (neither pl.when fires —
-            # callers must gate on pallas_qualifies, but defense here
-            # is one store) must return zeros, never uninitialized
-            # output memory
-            st_o[0, 0, j] = i32(0)
-            rem_o[0, 0, j] = i32(0)
-            rlo_o[0, 0, j] = i32(0)
-            rhi_o[0, 0, j] = i32(0)
+            is_query = r_hits == 0
+            flags = err.astype(i32) | (insert.astype(i32) << 1)
+            lim_out = _sel(err, 0, r_lim)
+            # a valid row with an out-of-domain algorithm (neither
+            # pl.when below fires — callers must gate on
+            # pallas_qualifies, but defense here is one store) returns
+            # zeros, never uninitialized output memory
+            emit({O_LIMIT: lim_out, O_FLAGS: flags})
 
-            @pl.when(r_alg == i32(0))
+            def writeback(words):
+                """Store the selected slot's new words (a full bucket —
+                err — selects no lane, so nothing changes)."""
+                for w, v in words.items():
+                    scratch[b, pl.ds(w, 1), :] = jnp.where(slot1h, v,
+                                                           ld(w))
+
+            @pl.when(r_alg_s == 0)
             def _token():
                 fresh = fresh0
                 # token duration change → recompute expiry from item.t
@@ -467,13 +479,9 @@ def _kernel(tile, bb_ref, brep_ref, klo_ref, khi_ref, hits_ref, lim_ref,
                                       ne_lo)
                 x1hi, x1lo = _sel64(dur_change, ne_hi, ne_lo,
                                     it_xhi, it_xlo)
+                # oracle's `exp1 <= now`
                 fresh = fresh | (dur_change
-                                 & ~_ge64(x1hi, x1lo, nhi1, nlo1)
-                                 ) | (dur_change & _ge64(nhi1, nlo1,
-                                                         x1hi, x1lo))
-                # (exp1 <= now  ≡  now >= exp1; the first disjunct is
-                # exp1 < now via !(exp1 >= now) — keep both for
-                # exactness with oracle's `exp1 <= now`)
+                                 & _ge64(nhi1, nlo1, x1hi, x1lo))
 
                 # adopt fresh or existing
                 xf_hi, xf_lo = _add64(nhi1, nlo1, r_ehi, r_elo)
@@ -483,72 +491,54 @@ def _kernel(tile, bb_ref, brep_ref, klo_ref, khi_ref, hits_ref, lim_ref,
                 rem0 = _sel(fresh, r_lim, it_rem)
                 t_hi, t_lo = _sel64(fresh, nhi1, nlo1, it_thi, it_tlo)
                 x_hi, x_lo = _sel64(fresh, xf_hi, xf_lo, x1hi, x1lo)
-                status0 = _sel(fresh, i32(0), it_status)
+                status0 = _sel(fresh, 0, it_status)
                 e_hi, e_lo = _sel64(fresh | dur_change, r_ehi, r_elo,
                                     it_ehi, it_elo)
 
                 # RESET_REMAINING on existing items
                 reset_live = reset & (~fresh)
                 rem0 = _sel(reset_live, r_lim, rem0)
-                status0 = _sel(reset_live, i32(0), status0)
+                status0 = _sel(reset_live, 0, status0)
                 limit_ar = _sel(reset_live, r_lim, limit0)
 
                 # token limit change in place
                 lim_change = r_lim != limit_ar
-                rem_adj = jnp.clip(rem0 + r_lim - limit_ar, i32(0),
-                                   r_lim)
+                rem_adj = jnp.clip(rem0 + r_lim - limit_ar, 0, r_lim)
                 rem0 = _sel(lim_change, rem_adj, rem0)
 
                 # hits
                 ok = r_hits <= rem0
                 rem2 = _sel((~is_query) & ok, rem0 - r_hits, rem0)
-                rem2 = _sel((~is_query) & (~ok) & drain, i32(0), rem2)
-                status1 = _sel(is_query, status0,
-                               _sel(ok, i32(0), i32(1)))
+                rem2 = _sel((~is_query) & (~ok) & drain, 0, rem2)
+                status1 = _sel(is_query, status0, _sel(ok, 0, 1))
 
-                # write the slot back (unless the bucket was full)
-                @pl.when(~err)
-                def _writeback():
-                    sel = slot1h[:, None]
+                zero = jnp.zeros(row, i32)
+                writeback({
+                    W_KLO: klo, W_KHI: khi, W_REM: rem2,
+                    W_STATUS: status1, W_LIMIT: r_lim,
+                    W_TLO: t_lo, W_THI: t_hi, W_XLO: x_lo, W_XHI: x_hi,
+                    W_ELO: e_lo, W_EHI: e_hi, W_DLO: r_dlo,
+                    W_DHI: r_dhi, W_ALG: zero, W_TDLO: zero,
+                    W_TDHI: zero})
+                # err rows select no lane → zeros, as the XLA step
+                # masks them
+                emit({O_STATUS: pick(slot1h, status1),
+                      O_REM: pick(slot1h, rem2),
+                      O_RLO: pick(slot1h, x_lo),
+                      O_RHI: pick(slot1h, x_hi),
+                      O_LIMIT: lim_out, O_FLAGS: flags})
 
-                    def put(t, w, v):
-                        return jnp.where(sel & (lane == w), v, t)
-
-                    nt = tile
-                    nt = put(nt, W_KLO, klo)
-                    nt = put(nt, W_KHI, khi)
-                    nt = put(nt, W_REM, rem2)
-                    nt = put(nt, W_STATUS, status1)
-                    nt = put(nt, W_LIMIT, r_lim)
-                    nt = put(nt, W_TLO, t_lo)
-                    nt = put(nt, W_THI, t_hi)
-                    nt = put(nt, W_XLO, x_lo)
-                    nt = put(nt, W_XHI, x_hi)
-                    nt = put(nt, W_ELO, e_lo)
-                    nt = put(nt, W_EHI, e_hi)
-                    nt = put(nt, W_DLO, r_dlo)
-                    nt = put(nt, W_DHI, r_dhi)
-                    nt = put(nt, W_ALG, i32(0))
-                    nt = put(nt, W_TDLO, i32(0))
-                    nt = put(nt, W_TDHI, i32(0))
-                    scratch[pl.ds(base, SLOTS), :] = nt
-
-                # outputs (err rows zeroed, as the XLA step masks them)
-                st_o[0, 0, j] = _sel(dead, i32(0), status1)
-                rem_o[0, 0, j] = _sel(dead, i32(0), rem2)
-                rlo_o[0, 0, j] = _sel(dead, i32(0), x_lo)
-                rhi_o[0, 0, j] = _sel(dead, i32(0), x_hi)
-
-            @pl.when(r_alg == i32(1))
+            @pl.when(r_alg_s == 1)
             def _leaky():
                 # request-only td columns (precomputed by the wrapper):
                 # hits×eff, burst×eff (cap), limit×eff (reset value),
                 # eff//limit (rate), TD_BOUND//limit (replenish guard)
-                r_htl, r_hth = htl_ref[0, 0, j], hth_ref[0, 0, j]
-                r_cpl, r_cph = cpl_ref[0, 0, j], cph_ref[0, 0, j]
-                r_rsl, r_rsh = rsl_ref[0, 0, j], rsh_ref[0, 0, j]
-                r_rate = rate_ref[0, 0, j]
-                r_gdl, r_gdh = gdl_ref[0, 0, j], gdh_ref[0, 0, j]
+                r_htl, r_hth = splat(C_HTL), splat(C_HTH)
+                r_cpl, r_cph = splat(C_CPL), splat(C_CPH)
+                r_rsl, r_rsh = splat(C_RSL), splat(C_RSH)
+                r_rate = splat(C_RATE)
+                r_gdl, r_gdh = splat(C_GDL), splat(C_GDH)
+                zero = jnp.zeros(row, i32)
 
                 # denominator change → rescale the td fixed point to
                 # the new eff.  In the kernel domain both denominators
@@ -556,8 +546,8 @@ def _kernel(tile, bb_ref, brep_ref, klo_ref, khi_ref, hits_ref, lim_ref,
                 # fraction is ALWAYS kept, and whole < 2^30 ≤
                 # TD_BOUND//eff makes the oracle's whole-token clamp a
                 # no-op (see EFF_BOUND).  Divides run unconditionally
-                # (lane-selected away on ~eff_change); a token-item
-                # divisor (alg switch) feeds garbage that fresh0
+                # (lane-selected away on ~eff_change); a token-item or
+                # empty-slot divisor feeds garbage that fresh0
                 # discards — _udiv64_32 is total, never faulting.
                 eff_change = ((~fresh0)
                               & _neq64(r_ehi, r_elo, it_ehi, it_elo))
@@ -565,20 +555,20 @@ def _kernel(tile, bb_ref, brep_ref, klo_ref, khi_ref, hits_ref, lim_ref,
                 fth, ftl = _umul32x32(fracr, r_elo)
                 frac_term, _ = _udiv64_32(fth, ftl, it_elo)
                 wh, wl = _umul32x32(whole, r_elo)
-                resc_h, resc_l = _add64(wh, wl, i32(0), frac_term)
+                resc_h, resc_l = _add64(wh, wl, zero, frac_term)
                 td0h, td0l = _sel64(eff_change, resc_h, resc_l,
                                     it_tdhi, it_tdlo)
 
                 # fresh adoption: bucket starts full (burst × eff)
                 td0h, td0l = _sel64(fresh0, r_cph, r_cpl, td0h, td0l)
-                status0 = _sel(fresh0, i32(0), it_status)
+                status0 = _sel(fresh0, 0, it_status)
                 t0h, t0l = _sel64(fresh0, nhi1, nlo1, it_thi, it_tlo)
 
                 # RESET_REMAINING on existing items: limit × eff
                 reset_live = reset & (~fresh0)
                 td0h, td0l = _sel64(reset_live, r_rsh, r_rsl,
                                     td0h, td0l)
-                status0 = _sel(reset_live, i32(0), status0)
+                status0 = _sel(reset_live, 0, status0)
 
                 # replenish: elapsed × limit td, clamped to cap.
                 # elapsed > TD_BOUND//limit ⇒ the true product already
@@ -599,56 +589,32 @@ def _kernel(tile, bb_ref, brep_ref, klo_ref, khi_ref, hits_ref, lim_ref,
                 apply_ok = (~is_query) & ok
                 td2h, td2l = _sel64(apply_ok, d2h, d2l, rph, rpl)
                 drain_hit = (~is_query) & (~ok) & drain
-                td2h, td2l = _sel64(drain_hit, i32(0), i32(0),
-                                    td2h, td2l)
-                status1 = _sel(is_query, status0,
-                               _sel(ok, i32(0), i32(1)))
+                td2h, td2l = _sel64(drain_hit, zero, zero, td2h, td2l)
+                status1 = _sel(is_query, status0, _sel(ok, 0, 1))
 
                 # response: remaining in whole tokens, reset_time =
                 # now + eff//limit (NOT the stored expire = now + eff)
                 rem_out, _ = _udiv64_32(td2h, td2l, r_elo)
                 x_hi, x_lo = _add64(nhi1, nlo1, r_ehi, r_elo)
-                rsh_, rsl_ = _add64(nhi1, nlo1, i32(0), r_rate)
+                rsh_, rsl_ = _add64(nhi1, nlo1, zero, r_rate)
 
-                @pl.when(~err)
-                def _writeback():
-                    sel = slot1h[:, None]
+                writeback({
+                    W_KLO: klo, W_KHI: khi, W_REM: zero,
+                    W_STATUS: status1, W_LIMIT: r_lim,
+                    W_TLO: nlo1, W_THI: nhi1, W_XLO: x_lo,
+                    W_XHI: x_hi, W_ELO: r_elo, W_EHI: r_ehi,
+                    W_DLO: r_dlo, W_DHI: r_dhi,
+                    W_ALG: jnp.ones(row, i32), W_TDLO: td2l,
+                    W_TDHI: td2h})
+                emit({O_STATUS: pick(slot1h, status1),
+                      O_REM: pick(slot1h, rem_out),
+                      O_RLO: pick(slot1h, rsl_),
+                      O_RHI: pick(slot1h, rsh_),
+                      O_LIMIT: lim_out, O_FLAGS: flags})
 
-                    def put(t, w, v):
-                        return jnp.where(sel & (lane == w), v, t)
-
-                    nt = tile
-                    nt = put(nt, W_KLO, klo)
-                    nt = put(nt, W_KHI, khi)
-                    nt = put(nt, W_REM, i32(0))
-                    nt = put(nt, W_STATUS, status1)
-                    nt = put(nt, W_LIMIT, r_lim)
-                    nt = put(nt, W_TLO, nlo1)
-                    nt = put(nt, W_THI, nhi1)
-                    nt = put(nt, W_XLO, x_lo)
-                    nt = put(nt, W_XHI, x_hi)
-                    nt = put(nt, W_ELO, r_elo)
-                    nt = put(nt, W_EHI, r_ehi)
-                    nt = put(nt, W_DLO, r_dlo)
-                    nt = put(nt, W_DHI, r_dhi)
-                    nt = put(nt, W_ALG, i32(1))
-                    nt = put(nt, W_TDLO, td2l)
-                    nt = put(nt, W_TDHI, td2h)
-                    scratch[pl.ds(base, SLOTS), :] = nt
-
-                st_o[0, 0, j] = _sel(dead, i32(0), status1)
-                rem_o[0, 0, j] = _sel(dead, i32(0), rem_out)
-                rlo_o[0, 0, j] = _sel(dead, i32(0), rsl_)
-                rhi_o[0, 0, j] = _sel(dead, i32(0), rsh_)
-
-        @pl.when(~valid)
+        @pl.when(c_ref[C_VALID, j] == 0)
         def _invalid():
-            st_o[0, 0, j] = i32(0)
-            rem_o[0, 0, j] = i32(0)
-            rlo_o[0, 0, j] = i32(0)
-            rhi_o[0, 0, j] = i32(0)
-            lim_o[0, 0, j] = i32(0)
-            flg_o[0, 0, j] = i32(0)
+            emit({})
 
         return c
 
@@ -656,75 +622,39 @@ def _kernel(tile, bb_ref, brep_ref, klo_ref, khi_ref, hits_ref, lim_ref,
 
     # 3) scatter: write distinct live buckets back, then fence the tile
     # (the wait orders these stores before the NEXT tile's gathers)
-    def issue_out(j, c):
-        @pl.when(first_live(j))
-        def _():
-            pltpu.make_async_copy(
-                scratch.at[pl.ds(j * SLOTS, SLOTS)],
-                table_ref.at[pl.ds(bb_ref[0, 0, j], SLOTS)],
-                sem_out.at[j]).start()
-        return c
-
-    lax.fori_loop(0, tile, issue_out, 0)
-
-    def wait_out(j, c):
-        @pl.when(first_live(j))
-        def _():
-            pltpu.make_async_copy(
-                scratch.at[pl.ds(j * SLOTS, SLOTS)],
-                table_ref.at[pl.ds(bb_ref[0, 0, j], SLOTS)],
-                sem_out.at[j]).wait()
-        return c
-
-    lax.fori_loop(0, tile, wait_out, 0)
+    for_each_first_live(lambda j: bucket_dma(j, False).start())
+    for_each_first_live(lambda j: bucket_dma(j, False).wait())
 
 
-N_COLS = 26  # SMEM request columns (see _kernel signature order)
-
-
-def _call_kernel(rows, cols, interpret: bool, tile: int = TILE):
-    """cols: N_COLS int32 arrays shaped [G, 1, tile] (_kernel order).
-
-    The singleton middle axis is load-bearing on real Mosaic: a block's
-    last two dims must be divisible by (8, 128) or equal the array's —
-    a [G, TILE] array with (1, TILE) blocks violates that (observed
-    on-chip 2026-08-01), while [G, 1, TILE] with (1, 1, TILE) blocks
-    has last-two dims (1, TILE) == the array's, which is allowed."""
-    G = cols[0].shape[0]
-    smem_tile = pl.BlockSpec((1, 1, tile), lambda i: (i, 0, 0),
-                             memory_space=pltpu.SMEM)
-    out_tile = pl.BlockSpec((1, 1, tile), lambda i: (i, 0, 0),
-                            memory_space=pltpu.SMEM)
+def _call_kernel(buckets, cols, interpret: bool, tile: int = TILE):
+    """cols: [G·N_COLS, tile] int32 — grid step g reads rows
+    [g·N_COLS, (g+1)·N_COLS) as its SMEM block (C_* row order).
+    Returns (buckets, [G·tile, SLOTS] int32 response rows, O_* lanes)."""
+    G = cols.shape[0] // N_COLS
     table_spec = pl.BlockSpec(memory_space=pl.ANY)
-    o32 = jax.ShapeDtypeStruct((G, 1, tile), jnp.int32)
-    # jax.enable_x64 left the top-level namespace in jax 0.4.3x (this
-    # image raises AttributeError on it); the experimental alias is the
-    # stable spelling of the same x64-off trace scope.  The scope wraps
-    # only the REAL Mosaic build: on jax 0.4.37 the interpreter's grid
-    # loop captures x64 carries from the enclosing trace, and flipping
-    # x64 off mid-trace emits mixed i32/i64 while-carries that fail MLIR
-    # verification (this image's "jax 0.4.37 kills pallas" breakage);
-    # the kernel body itself is explicitly typed, so the interpret path
-    # needs no ambient-dtype pinning.
-    import contextlib
-    scope = (contextlib.nullcontext() if interpret
-             else jax.experimental.enable_x64(False))
-    with scope:
+    # x64 off while tracing the kernel: every operand is explicitly
+    # int32, but under x64 the index_map literals and loop counters
+    # trace as i64 scalars, which Mosaic cannot legalize
+    with jax.enable_x64(False):
         return pl.pallas_call(
             partial(_kernel, tile),
             grid=(G,),
-            in_specs=[smem_tile] * N_COLS + [table_spec],
-            out_specs=[table_spec] + [out_tile] * 6,
-            out_shape=[jax.ShapeDtypeStruct(rows.shape, jnp.int32)]
-            + [o32] * 6,
-            input_output_aliases={N_COLS: 0},
+            in_specs=[pl.BlockSpec((N_COLS, tile), lambda i: (i, 0),
+                                   memory_space=pltpu.SMEM),
+                      table_spec],
+            out_specs=[table_spec,
+                       pl.BlockSpec((tile, SLOTS), lambda i: (i, 0),
+                                    memory_space=pltpu.VMEM)],
+            out_shape=[
+                jax.ShapeDtypeStruct(buckets.shape, jnp.int32),
+                jax.ShapeDtypeStruct((G * tile, SLOTS), jnp.int32)],
+            input_output_aliases={1: 0},
             scratch_shapes=[
-                pltpu.VMEM((tile * SLOTS, WORDS), jnp.int32),
-                pltpu.SemaphoreType.DMA((tile,)),
-                pltpu.SemaphoreType.DMA((tile,)),
+                pltpu.VMEM((tile, WORDS, SLOTS), jnp.int32),
+                pltpu.SemaphoreType.DMA((2,)),  # gather, scatter
             ],
             interpret=interpret,
-        )(*cols, rows)
+        )(cols, buckets)
 
 
 def decide_batch_pallas_impl(table: PallasTable, batch: RequestBatch,
@@ -744,8 +674,7 @@ def decide_batch_pallas_impl(table: PallasTable, batch: RequestBatch,
     """
     i32, i64 = jnp.int32, jnp.int64
     TILE = tile if tile else pallas_tile()
-    cap = table.rows.shape[0]
-    n_buckets = cap // SLOTS
+    n_buckets = table.buckets.shape[0]
     B = batch.key.shape[0]
     G = -(-B // TILE)
     pad = G * TILE - B
@@ -759,7 +688,7 @@ def decide_batch_pallas_impl(table: PallasTable, batch: RequestBatch,
 
     key = batch.key.astype(jnp.uint64)
     valid = (batch.valid & (key != 0)).astype(i32)
-    bucket = (key & jnp.uint64(n_buckets - 1)).astype(i32) * SLOTS
+    bucket = (key & jnp.uint64(n_buckets - 1)).astype(i32)
 
     def pad_to(x, fill=0):
         return jnp.pad(x, (0, pad), constant_values=fill) if pad else x
@@ -782,20 +711,10 @@ def decide_batch_pallas_impl(table: PallasTable, batch: RequestBatch,
     hth, htl = _split64(batch.hits.astype(i64) * eff_l)
     cph, cpl = _split64(batch.burst.astype(i64) * eff_l)
     rsh, rsl = _split64(lim64 * eff_l)
-    rate = jnp.where(lim64 > 0, eff_l // jnp.maximum(lim64, 1),
+    lim_d = jnp.maximum(lim64, 1)
+    rate = jnp.where(lim64 > 0, divmod_nn(eff_l, lim_d)[0],
                      eff_l).astype(i32)
-    gdh, gdl = _split64(TD_BOUND // jnp.maximum(lim64, 1))
-
-    bb = pad_to(bucket)
-    cols1d = [
-        bb,
-        klo, khi,
-        batch.hits.astype(i32), batch.limit.astype(i32),
-        dlo, dhi, elo, ehi, glo, ghi,
-        batch.behavior.astype(i32), nlo, nhi, valid,
-        alg, htl, hth, cpl, cph, rsl, rsh, rate, gdl, gdh,
-    ]
-    cols1d = [bb] + [pad_to(c) for c in cols1d[1:]]
+    gdh, gdl = _split64(divmod_nn(TD_BOUND, lim_d)[0])
 
     # tile-relative first occurrence of each bucket (dedup map): the
     # kernel's serial loop routes same-bucket requests to one VMEM
@@ -803,35 +722,39 @@ def decide_batch_pallas_impl(table: PallasTable, batch: RequestBatch,
     # become a bucket's representative: first_live gates the DMA on
     # valid, so an invalid representative would starve a later valid
     # same-bucket request of its gather/writeback entirely.
-    bt = bb.reshape(G, TILE)
+    bt = pad_to(bucket).reshape(G, TILE)
     iota = jnp.arange(G * TILE, dtype=jnp.int64).reshape(G, TILE)
     vpad = pad_to(valid).reshape(G, TILE).astype(bool)
     rep_key = jnp.where(vpad, bt.astype(jnp.int64), -1 - iota)
     eq = rep_key[:, :, None] == rep_key[:, None, :]
     brep = jnp.argmax(eq, axis=-1).astype(i32)  # first True per row
 
-    # [G, 1, TILE]: the singleton axis satisfies Mosaic's block-shape
-    # rule (see _call_kernel)
-    cols = [c.reshape(G, 1, TILE) for c in [bt, brep] + cols1d[1:]]
-    rows2, st, rem, rlo, rhi, lim, flg = _call_kernel(
-        table.rows, cols, interpret, TILE)
+    cols1d = [  # C_* order, after bucket/brep
+        klo, khi,
+        batch.hits.astype(i32), batch.limit.astype(i32),
+        dlo, dhi, elo, ehi, glo, ghi,
+        batch.behavior.astype(i32), nlo, nhi, valid,
+        alg, htl, hth, cpl, cph, rsl, rsh, rate, gdl, gdh,
+    ]
+    cols = [bt, brep] + [pad_to(c).reshape(G, TILE) for c in cols1d]
+    cols += [jnp.zeros((G, TILE), i32)] * (N_COLS - len(cols))
+    # [G, N_COLS, TILE] → one SMEM block of N_COLS rows per grid step
+    cols = jnp.stack(cols, axis=1).reshape(G * N_COLS, TILE)
+    buckets2, res = _call_kernel(table.buckets, cols, interpret, TILE)
 
-    def unpad(x):
-        return x.reshape(-1)[:B]
-
-    st = unpad(st)
-    flg = unpad(flg)
+    res = res[:B]
+    flg = res[:, O_FLAGS]
     err = (flg & 1) != 0
-    vb = valid.astype(bool)[:B] if pad else valid.astype(bool)
+    vb = valid.astype(bool)
     live = vb & (~err)
-    status = jnp.where(live, st, 0)
-    remaining = jnp.where(live, unpad(rem).astype(i64), 0)
+    status = jnp.where(live, res[:, O_STATUS], 0)
+    remaining = jnp.where(live, res[:, O_REM].astype(i64), 0)
     reset_time = jnp.where(
-        live, _join64(unpad(rhi), unpad(rlo), i64), 0)
-    limit_out = jnp.where(live, unpad(lim).astype(i64), 0)
+        live, _join64(res[:, O_RHI], res[:, O_RLO], i64), 0)
+    limit_out = jnp.where(live, res[:, O_LIMIT].astype(i64), 0)
     over = (live & (status == 1)).sum(dtype=i64)
     inserts = ((flg >> 1) & 1).sum(dtype=i64)
-    return PallasTable(rows=rows2), StepOutput(
+    return PallasTable(buckets=buckets2), StepOutput(
         status=status.astype(i32), remaining=remaining,
         reset_time=reset_time, limit=limit_out,
         err=vb & err, over_count=over, insert_count=inserts)

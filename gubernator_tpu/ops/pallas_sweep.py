@@ -91,7 +91,7 @@ def _sweep_2d(khi, klo, ehi, elo, now_hi_lo, *, interpret: bool):
     # x64 off while tracing the kernel: every operand is already int32,
     # but under x64 the BlockSpec index_map's literals trace as i64
     # scalars and Mosaic fails to legalize the index function's return
-    with jax.experimental.enable_x64(False):
+    with jax.enable_x64(False):
         return pl.pallas_call(
             _sweep_kernel,
             grid=grid,

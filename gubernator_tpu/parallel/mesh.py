@@ -31,31 +31,6 @@ import threading as _threading
 
 XLA_EXEC_MU = _threading.Lock()
 
-try:  # jax >= 0.5 exports shard_map at top level (check_vma kwarg)
-    from jax import shard_map as _shard_map_impl
-
-    _VMA_KW = "check_vma"
-except ImportError:  # jax 0.4.x: experimental home, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _VMA_KW = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """Version-portable ``shard_map``: the repo targets the public
-    ``jax.shard_map`` API (``check_vma``); on jax 0.4.x images the same
-    call routes to ``jax.experimental.shard_map`` (whose equivalent
-    kwarg is ``check_rep``)."""
-    if check_vma is None and _VMA_KW == "check_rep":
-        # 0.4.x replication checking has no rule for lax.while_loop
-        # (the decision step's per-position fallback); the upstream
-        # workaround is check_rep=False — purely a static checker, so
-        # disabling it changes no computed values
-        check_vma = False
-    kw = {} if check_vma is None else {_VMA_KW: check_vma}
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
-
 
 def make_mesh(devices: Sequence[jax.Device] | None = None,
               n: int | None = None) -> Mesh:
@@ -74,8 +49,9 @@ def table_sharding(mesh: Mesh) -> NamedSharding:
 
 def shard_table(mesh: Mesh, capacity_per_shard: int) -> TableState:
     """Build a global table of n_shards × capacity_per_shard rows,
-    sharded one block per device."""
+    sharded one block per device.  Built under jit with the output
+    sharding, so each device materializes only its own block — a
+    2^26-row table is 4.6 GB and must never exist whole on device 0."""
     n = mesh.shape[SHARD_AXIS]
-    global_tab = init_table(n * capacity_per_shard)
-    sh = table_sharding(mesh)
-    return jax.tree.map(lambda x: jax.device_put(x, sh), global_tab)
+    return jax.jit(lambda: init_table(n * capacity_per_shard),
+                   out_shardings=table_sharding(mesh))()

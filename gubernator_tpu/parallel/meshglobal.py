@@ -51,7 +51,7 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.batch import RequestBatch, clamp_config, empty_batch, pack_requests
@@ -59,7 +59,7 @@ from ..core.step import _lookup, _probe_slots, decide_batch_impl
 from ..core.table import TableState, init_table
 from ..hashing import shard_of
 from ..types import EFF_MAX, RateLimitRequest, RateLimitResponse, Status
-from .mesh import SHARD_AXIS, XLA_EXEC_MU, shard_map
+from .mesh import SHARD_AXIS, XLA_EXEC_MU
 from .sharded import pack_wave_host
 
 #: TableState value columns (all but `key`) — the fold adopts the home
@@ -123,14 +123,11 @@ def make_mesh_global_fold(mesh):
     hit totals (the conservation ledger), and comes back zeroed."""
     S = SHARD_AXIS
     n = mesh.shape[S]
-    # singleton meshes elide the collectives (identity fold) — same
-    # AOT-compile guard as the hot set's sync program
-    psum = (lambda x: lax.psum(x, S)) if n > 1 else (lambda x: x)
 
     def _fold(state, acc):
         st = jax.tree.map(lambda x: x[0], state)
         a = acc[0]
-        my = lax.axis_index(S) if n > 1 else jnp.int32(0)
+        my = lax.axis_index(S)
         # home shard from the key column itself (hashing.shard_of):
         # ((h >> 32) * n) >> 32 — the exact host formula, on device
         home = (((st.key >> jnp.uint64(32)) * jnp.uint64(n))
@@ -139,8 +136,8 @@ def make_mesh_global_fold(mesh):
         new = {"key": st.key}  # identical on every replica by pinning
         for f in _VALUE_COLS:
             col = getattr(st, f)
-            new[f] = psum(jnp.where(mine, col, jnp.zeros_like(col)))
-        slot_tot = psum(a)
+            new[f] = lax.psum(jnp.where(mine, col, jnp.zeros_like(col)), S)
+        slot_tot = lax.psum(a, S)
         folded = TableState(**new)
         return (jax.tree.map(lambda x: x[None], folded),
                 jnp.zeros_like(a)[None], slot_tot)

@@ -4,9 +4,9 @@ deployable step mode (SURVEY §2.2; VERDICT r3 item 1's escalation —
 scatters").
 
 ``PallasServingEngine`` is a drop-in ``ShardedEngine`` whose per-shard
-table is the kernel's bucketized AoS layout (``[rows, 32] int32``,
-8-slot buckets — ops/pallas_step.py) instead of SoA columns, and whose
-step is the Mosaic kernel under ``shard_map``.  Everything above the
+table is the kernel's bucket layout (``[n_buckets, 16, 128] int32``,
+128-slot buckets — ops/pallas_step.py) instead of SoA columns, and
+whose step is the Mosaic kernel under ``shard_map``.  Everything above the
 step — wave routing, dispatcher coalescing, the wire lanes, metrics —
 is inherited unchanged; the engine protocol (gather/upsert/remove
 rows, snapshot/restore, sweep) is re-implemented on the bucket layout
@@ -37,13 +37,13 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.batch import RequestBatch
 from ..core.step import decide_batch_impl
 from ..ops import pallas_step as ps
-from .mesh import SHARD_AXIS, XLA_EXEC_MU, shard_map
+from .mesh import SHARD_AXIS, XLA_EXEC_MU
 from .sharded import PACK32, PACK64, ShardedEngine
 
 log = logging.getLogger("gubernator_tpu.pallas_engine")
@@ -71,7 +71,7 @@ def _split_np(x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
 
 
 def _rows_to_columns(rows: np.ndarray) -> dict:
-    """[N, WORDS] int32 bucket rows → SoA column dict (live rows only),
+    """[N, WORDS] int32 slot rows → SoA column dict (live rows only),
     in the store/Loader format (store.py › table_to_arrays).
 
     ``burst`` is emitted as ``limit``: the kernel does not store burst
@@ -174,14 +174,25 @@ def _place_into_buckets(buckets: np.ndarray, group_id: np.ndarray,
     Keys must be distinct (callers dedupe last-write-wins).  Existing
     keys update their slot; new keys take empty slots in caller order,
     rows sharing a bucket getting distinct empties via rank-in-group.
-    Returns the [n] bool mask of rows that found a slot.  Only the two
-    key columns are materialized per row (not whole buckets), so peak
-    extra memory is O(n * SLOTS) words even at 10M rows."""
-    sklo = buckets[:, :, ps.W_KLO][group_id]  # [n, SLOTS] pre-write
-    skhi = buckets[:, :, ps.W_KHI][group_id]
-    hit = (sklo == klo[:, None]) & (skhi == khi[:, None])
-    placed = hit.any(axis=1)
-    slot = hit.argmax(axis=1)
+    Returns the [n] bool mask of rows that found a slot.  Per-row work
+    is O(1) lookups into per-BUCKET tables (a key lives in exactly one
+    bucket, so residents are matched by key alone), so restore stays
+    linear in rows + slots even with 128-slot buckets at 10M rows."""
+    n = len(group_id)
+    keys = _join_u64(khi, klo)
+    res = _join_u64(buckets[:, :, ps.W_KHI], buckets[:, :, ps.W_KLO])
+    empty = res == 0
+    placed = np.zeros(n, bool)
+    slot = np.zeros(n, np.int64)
+    occ = np.flatnonzero(~empty.reshape(-1))  # flat (bucket, slot)
+    if occ.size:
+        rk = res.reshape(-1)[occ]
+        order = np.argsort(rk, kind="stable")
+        rk, occ = rk[order], occ[order]
+        pos = np.minimum(np.searchsorted(rk, keys), len(rk) - 1)
+        hit = (rk[pos] == keys) & (occ[pos] // ps.SLOTS == group_id)
+        placed[hit] = True
+        slot[hit] = occ[pos[hit]] % ps.SLOTS
     new = np.nonzero(~placed)[0]
     if new.size:
         order = new[np.argsort(group_id[new], kind="stable")]
@@ -189,17 +200,21 @@ def _place_into_buckets(buckets: np.ndarray, group_id: np.ndarray,
         start = np.r_[True, sg[1:] != sg[:-1]]
         rank = np.arange(sg.size) - np.nonzero(start)[0][
             np.cumsum(start) - 1]
-        empty = (sklo[order] == 0) & (skhi[order] == 0)
-        # row with rank r in its bucket takes the (r+1)-th empty slot
-        sel = empty & (np.cumsum(empty, axis=1) == (rank + 1)[:, None])
-        got = sel.any(axis=1)
+        # each bucket's empty slots, ascending, listed first: the row
+        # with rank r in its bucket takes the r-th of them
+        free = np.argsort(~empty, axis=1, kind="stable")
+        got = rank < empty.sum(axis=1)[sg]
         placed[order[got]] = True
-        slot[order[got]] = sel.argmax(axis=1)[got]
+        slot[order[got]] = free[sg[got], rank[got]]
     # all (group_id, slot) pairs are distinct — hits sit at distinct
     # occupied slots (distinct keys), news at distinct empties — so
     # this fancy assignment has no write collisions
     buckets[group_id[placed], slot[placed]] = words[placed]
     return placed
+
+
+#: the bucket table's partition: bucket axis over the mesh
+_BUCKET_SPEC = P(SHARD_AXIS, None, None)
 
 
 def _batch_from_packed(a64, a32) -> RequestBatch:
@@ -223,19 +238,20 @@ def make_pallas_step_packed(mesh, interpret: bool = False):
     table is always donated — the kernel owns its scatters in-place."""
     S = SHARD_AXIS
 
-    def _step(rows, a64, a32, now):
+    def _step(buckets, a64, a32, now):
         batch = _batch_from_packed(a64, a32)
         tbl, out = ps.decide_batch_pallas_impl(
-            ps.PallasTable(rows=rows), batch, now, interpret=interpret)
+            ps.PallasTable(buckets=buckets), batch, now,
+            interpret=interpret)
         packed = _pack_outputs(out)
         over = lax.psum(out.over_count, S)
         ins = lax.psum(out.insert_count, S)
-        return tbl.rows, packed, (over, ins)
+        return tbl.buckets, packed, (over, ins)
 
     sharded = shard_map(
         _step, mesh=mesh,
-        in_specs=(P(S, None), P(None, S), P(None, S), P()),
-        out_specs=(P(S, None), P(None, S), P()),
+        in_specs=(_BUCKET_SPEC, P(None, S), P(None, S), P()),
+        out_specs=(_BUCKET_SPEC, P(None, S), P()),
         check_vma=False)  # pallas_call out_shape carries no vma
     return jax.jit(sharded, donate_argnums=(0,))
 
@@ -263,9 +279,9 @@ def _make_serve(flavor: str, interpret: bool, tile: int):
     if flavor == "pallas":
         def _serve(state, batch, now):
             tbl, out = ps.decide_batch_pallas_impl(
-                ps.PallasTable(rows=state), batch, now,
+                ps.PallasTable(buckets=state), batch, now,
                 interpret=interpret, tile=tile)
-            return tbl.rows, out
+            return tbl.buckets, out
         return _serve
     if flavor != "xla":
         raise ValueError(f"unknown fused-step flavor {flavor!r}")
@@ -294,7 +310,7 @@ def make_fused_step_packed(mesh, *, flavor: str, interpret: bool = False,
         ins = lax.psum(out.insert_count, S)
         return state, packed, tap, (over, ins)
 
-    state_spec = P(S, None) if flavor == "pallas" else P(S)
+    state_spec = _BUCKET_SPEC if flavor == "pallas" else P(S)
     sharded = shard_map(
         _step, mesh=mesh,
         in_specs=(state_spec, P(None, S), P(None, S), P()),
@@ -363,7 +379,7 @@ def make_fused_mesh_step_packed(mesh, *, flavor: str, mesh_cap: int,
         return (state, jax.tree.map(lambda x: x[None], mst), a[None],
                 packed, tap, (over, ins, mesh_hits))
 
-    state_spec = P(S, None) if flavor == "pallas" else P(S)
+    state_spec = _BUCKET_SPEC if flavor == "pallas" else P(S)
     sharded = shard_map(
         _step, mesh=mesh,
         in_specs=(state_spec, P(S), P(S), P(None, S), P(None, S),
@@ -548,10 +564,15 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
                                          & (self.cap_local - 1)):
             raise ValueError("rows per shard must be a power of two "
                              f">= {ps.SLOTS}")
-        sh = NamedSharding(self.mesh, P(SHARD_AXIS, None))
-        self.state = jax.device_put(
-            jnp.zeros((self.n * self.cap_local, ps.WORDS), jnp.int32),
-            sh)
+        #: buckets per shard; global bucket id = shard·nb_local + local
+        self.nb_local = self.cap_local // ps.SLOTS
+        sh = NamedSharding(self.mesh, _BUCKET_SPEC)
+        # built under jit with the output sharding: each device
+        # materializes only its own shard (see mesh.shard_table)
+        self.state = jax.jit(
+            lambda: jnp.zeros((self.n * self.nb_local, ps.WORDS,
+                               ps.SLOTS), jnp.int32),
+            out_shardings=sh)()
         # interpret everywhere the Mosaic kernel can't compile natively
         # (same gate as sharded.py's fused sweep)
         self._interpret = jax.default_backend() != "tpu"
@@ -566,11 +587,10 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
         # watermark, compiled (and warmed) here so the first
         # health_check doesn't pay a jit under the engine lock while
         # serving waves wait on it
-        def _occ_sat(r):
-            live = (r[:, ps.W_KLO] != 0) | (r[:, ps.W_KHI] != 0)
-            per_bucket = live.reshape(-1, ps.SLOTS).sum(
-                axis=1, dtype=jnp.int32)
-            return (live.sum(dtype=jnp.int64),
+        def _occ_sat(b):
+            live = (b[:, ps.W_KLO] != 0) | (b[:, ps.W_KHI] != 0)
+            per_bucket = live.sum(axis=1, dtype=jnp.int32)
+            return (per_bucket.sum(dtype=jnp.int64),
                     (per_bucket == ps.SLOTS).sum(dtype=jnp.int64))
 
         self._occ_sat_fn = jax.jit(_occ_sat)
@@ -641,6 +661,9 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
         return self._merge_ood(
             super().sync_packed(inner, engine_lock=engine_lock), ood)
 
+    def drop_packed(self, token) -> None:
+        super().drop_packed(token[0])
+
     def _try_auto_grow(self, grew: list) -> bool:
         return False  # no on-device grow for the bucket layout (doc)
 
@@ -667,7 +690,7 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
         bucketized layout's probe window IS the key's bucket, so the
         occupants are the bucket's resident keys (0 = free slot)."""
         b = self._fetch_buckets(
-            self._bucket_indices(np.array([kh], np.uint64)))[0]
+            self._bucket_ids(np.array([kh], np.uint64)))[0]
         lo = b[:, ps.W_KLO].astype(np.uint64) & np.uint64(0xFFFFFFFF)
         hi = b[:, ps.W_KHI].astype(np.uint64) & np.uint64(0xFFFFFFFF)
         return (hi << np.uint64(32)) | lo
@@ -675,27 +698,27 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
     # ---- sweep ---------------------------------------------------------
 
     def sweep(self, now_ms: int) -> None:
-        """Expire-clear over bucket rows: zero every slot whose
-        expire_at <= now (whole row, so leaky td state can't leak into
-        a future occupant).  Elementwise per shard — no collective."""
+        """Expire-clear over the buckets: zero every slot whose
+        expire_at <= now (all its words, so leaky td state can't leak
+        into a future occupant — the kernel relies on empty slots being
+        all-zero).  Elementwise per shard — no collective."""
         if not hasattr(self, "_sweep_fn"):
             S = SHARD_AXIS
 
-            def _one(rows, now):
-                exp = (rows[:, ps.W_XHI].astype(jnp.int64) << 32) | (
-                    rows[:, ps.W_XLO].astype(jnp.int64)
+            def _one(b, now):
+                exp = (b[:, ps.W_XHI].astype(jnp.int64) << 32) | (
+                    b[:, ps.W_XLO].astype(jnp.int64)
                     & jnp.int64(0xFFFFFFFF))
-                live = ((rows[:, ps.W_KLO] != 0)
-                        | (rows[:, ps.W_KHI] != 0))
+                live = (b[:, ps.W_KLO] != 0) | (b[:, ps.W_KHI] != 0)
                 expired = live & (now >= exp)
-                rows = jnp.where(expired[:, None], jnp.int32(0), rows)
+                b = jnp.where(expired[:, None, :], jnp.int32(0), b)
                 n_live = lax.psum((live & ~expired).sum(dtype=jnp.int64),
                                   S)
-                return rows, n_live
+                return b, n_live
 
             self._sweep_fn = jax.jit(shard_map(
-                _one, mesh=self.mesh, in_specs=(P(S, None), P()),
-                out_specs=(P(S, None), P()), check_vma=False),
+                _one, mesh=self.mesh, in_specs=(_BUCKET_SPEC, P()),
+                out_specs=(_BUCKET_SPEC, P())),
                 donate_argnums=(0,))
         self.state, live = self._sweep_fn(
             self.state, jnp.asarray(now_ms, jnp.int64))
@@ -704,31 +727,22 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
 
     # ---- row ops (bucket-level, cold path) -----------------------------
 
-    def _bucket_base(self, khash: np.ndarray) -> np.ndarray:
-        """[m] global row index of each key's bucket start."""
+    def _bucket_ids(self, khash: np.ndarray) -> np.ndarray:
+        """[m] global bucket id of each key (owner shard's block, then
+        the kernel's own ``key & (n_buckets - 1)``)."""
         from ..hashing import shard_of
 
-        nb = self.cap_local // ps.SLOTS
         shard = shard_of(khash, self.n).astype(np.int64)
-        bucket = (khash & np.uint64(nb - 1)).astype(np.int64)
-        return shard * self.cap_local + bucket * ps.SLOTS
+        local = (khash & np.uint64(self.nb_local - 1)).astype(np.int64)
+        return shard * self.nb_local + local
 
-    def _bucket_indices(self, khash: np.ndarray) -> np.ndarray:
-        """[m, SLOTS] global row indices of each key's bucket."""
-        return (self._bucket_base(khash)[:, None]
-                + np.arange(ps.SLOTS)[None, :])
+    def _fetch_buckets(self, bids: np.ndarray) -> np.ndarray:
+        """Gather [m, SLOTS, WORDS] slot-row copies of buckets ``bids``
+        to host (writable: callers mutate them in place)."""
+        got = np.asarray(jnp.take(self.state, jnp.asarray(bids), axis=0))
+        return np.ascontiguousarray(ps.buckets_to_rows(got))
 
-    def _fetch_buckets(self, idx: np.ndarray) -> np.ndarray:
-        """Gather [m, SLOTS, WORDS] bucket copies to host."""
-        take = jnp.asarray(idx.reshape(-1))
-        # .copy(): np.asarray of a jax array is a read-only view and
-        # the callers mutate these buckets in place
-        return np.asarray(jnp.take(self.state, take, axis=0)).reshape(
-            idx.shape[0], ps.SLOTS, ps.WORDS).copy()
-
-    def _write_buckets(self, idx: np.ndarray, rows: np.ndarray) -> None:
-        flat_idx = jnp.asarray(idx.reshape(-1))
-        flat_rows = jnp.asarray(rows.reshape(-1, ps.WORDS))
+    def _write_buckets(self, bids: np.ndarray, rows: np.ndarray) -> None:
         # duplicate buckets in one call carry identical content (the
         # caller mutates a shared host copy per bucket), so last-write
         # equivalence holds even without a uniqueness promise
@@ -737,7 +751,9 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
             # the scatter on every store write-through
             self._write_fn = jax.jit(lambda s, i, r: s.at[i].set(r),
                                      donate_argnums=(0,))
-        self.state = self._write_fn(self.state, flat_idx, flat_rows)
+        self.state = self._write_fn(
+            self.state, jnp.asarray(bids),
+            jnp.asarray(np.ascontiguousarray(ps.buckets_to_rows(rows))))
 
     def gather_rows(self, khash: np.ndarray) -> tuple[np.ndarray, dict]:
         m = len(khash)
@@ -748,8 +764,7 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
         cols["meta"] = cols["meta"].astype(np.int32)
         if m == 0:
             return found, cols
-        idx = self._bucket_indices(khash)
-        buckets = self._fetch_buckets(idx)
+        buckets = self._fetch_buckets(self._bucket_ids(khash))
         khi, klo = _split_np(khash)
         for i in range(m):
             b = buckets[i]
@@ -784,11 +799,9 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
 
     def _grouped_bucket_view(self, keys: np.ndarray
                              ) -> tuple[np.ndarray, np.ndarray]:
-        """(uidx [g, SLOTS] distinct-bucket row indices, group_id [n])
-        — so keys sharing a bucket resolve against ONE image."""
-        ubase, group_id = np.unique(self._bucket_base(keys),
-                                    return_inverse=True)
-        return ubase[:, None] + np.arange(ps.SLOTS)[None, :], group_id
+        """(ubids [g] distinct bucket ids, group_id [n]) — so keys
+        sharing a bucket resolve against ONE image."""
+        return np.unique(self._bucket_ids(keys), return_inverse=True)
 
     def upsert_rows(self, khash: np.ndarray, cols: dict) -> int:
         if len(khash) == 0:
@@ -798,21 +811,21 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
             return 0
         # ONE batched device fetch of the distinct buckets (a per-key
         # fetch would cost a blocking device round trip per bucket)
-        uidx, group_id = self._grouped_bucket_view(keys)
-        buckets = self._fetch_buckets(uidx)
+        ubids, group_id = self._grouped_bucket_view(keys)
+        buckets = self._fetch_buckets(ubids)
         khi, klo = _split_np(keys)
         placed = _place_into_buckets(buckets, group_id, klo, khi, words)
         self.dropped_rows += int(counts[~placed].sum())  # bucket full
         if not placed.any():
             return 0  # saturated buckets: skip the no-op device write
-        self._write_buckets(uidx, buckets)
+        self._write_buckets(ubids, buckets)
         return int(counts[placed].sum())
 
     def remove_rows(self, khash: np.ndarray) -> int:
         if len(khash) == 0:
             return 0
-        idx = self._bucket_indices(khash)
-        buckets = self._fetch_buckets(idx)
+        bids = self._bucket_ids(khash)
+        buckets = self._fetch_buckets(bids)
         khi, klo = _split_np(khash)
         removed = 0
         dirty = []
@@ -826,7 +839,7 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
                 dirty.append(i)
         if dirty:
             d = np.asarray(dirty)
-            self._write_buckets(idx[d], buckets[d])
+            self._write_buckets(bids[d], buckets[d])
         return removed
 
     def occupancy(self) -> int:
@@ -844,7 +857,7 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
 
     def bucket_saturation(self) -> tuple[int, int]:
         """(full_buckets, total_buckets) — the capacity-safety
-        watermark for this mode.  A FULL 8-slot bucket is the unit of
+        watermark for this mode.  A FULL 128-slot bucket is the unit of
         unservability here: with no on-device grow, any NEW key hashing
         into one errs as table_full, so 'how many buckets are full' is
         the operative early warning, not total occupancy (a table can
@@ -866,7 +879,8 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
     # ---- checkpoint/resume ---------------------------------------------
 
     def snapshot(self) -> dict:
-        return _rows_to_columns(np.asarray(self.state))
+        return _rows_to_columns(ps.buckets_to_rows(
+            np.asarray(self.state)).reshape(-1, ps.WORDS))
 
     def restore(self, arrays: dict) -> int:
         """Vectorized (no per-row Python): a 1M-row snapshot restores
@@ -877,17 +891,19 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
         keys, words, counts = self._prepared_rows(arrays["key"], arrays)
         if keys.size == 0:
             return 0  # all dropped: no host copy / re-upload for a no-op
-        host = np.asarray(self.state).copy()
-        uidx, group_id = self._grouped_bucket_view(keys)
-        buckets = host[uidx]
+        host = np.ascontiguousarray(
+            ps.buckets_to_rows(np.asarray(self.state)))
+        ubids, group_id = self._grouped_bucket_view(keys)
+        buckets = host[ubids]
         khi, klo = _split_np(keys)
         placed = _place_into_buckets(buckets, group_id, klo, khi, words)
         self.dropped_rows += int(counts[~placed].sum())  # bucket full
         if not placed.any():
             return 0  # saturated buckets: skip the no-op re-upload
-        host[uidx] = buckets
-        self.state = jax.device_put(jnp.asarray(host),
-                                    self._rows_sharding)
+        host[ubids] = buckets
+        self.state = jax.device_put(
+            np.ascontiguousarray(ps.buckets_to_rows(host)),
+            self._rows_sharding)
         return int(counts[placed].sum())
 
 

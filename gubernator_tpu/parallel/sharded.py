@@ -18,7 +18,7 @@ from typing import List, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..hashing import shard_of
@@ -27,8 +27,8 @@ from ..core.batch import (RequestBatch, WaveBufferPool, empty_batch,
                           pack_requests)
 from ..core.step import decide_batch_impl, _insert, _lookup, _probe_slots
 from ..core.table import TableState, init_table
-from .mesh import (SHARD_AXIS, XLA_EXEC_MU, make_mesh, shard_map,
-                   shard_table, table_sharding)
+from .mesh import (SHARD_AXIS, XLA_EXEC_MU, make_mesh, shard_table,
+                   table_sharding)
 
 log = logging.getLogger("gubernator_tpu.sharded")
 
@@ -248,8 +248,8 @@ def make_sharded_step(mesh, donate: bool = False):
 #: the per-request arrival time), the int32/bool columns one [3, B]
 #: int32 upload, and all five outputs one [5, B] int64 download.  A
 #: device call then costs 2 uploads + 1 download instead of 10 + 5 —
-#: per-transfer latency (PCIe doorbells, or milliseconds over a
-#: tunneled link) dominates these tiny arrays, not bandwidth.
+#: per-transfer latency (PCIe doorbells) dominates these tiny arrays,
+#: not bandwidth.
 PACK64 = ("key", "hits", "limit", "duration", "eff_ms", "greg_end",
           "burst", "now")
 PACK32 = ("behavior", "algorithm", "valid")
@@ -547,20 +547,28 @@ class ShardedEngine:
                 batch = batch._replace(
                     valid=np.asarray(batch.valid) & ~cm)
         pending = self._arrival_order(batch)
-        launched = []
-        for idx, slots, bw_w in self._build_waves(khash, pending):
-            a64, a32, lease, mblk = self._fill_packed(batch, idx, slots,
-                                                      bw_w, mslot)
-            try:
+        launched, leases = [], []
+        try:
+            for idx, slots, bw_w in self._build_waves(khash, pending):
+                a64, a32, lease, mblk = self._fill_packed(
+                    batch, idx, slots, bw_w, mslot)
+                # the lease rides the token until sync_packed has the
+                # wave's results: the launch is asynchronous, and the
+                # runtime may still be reading the host operands (the
+                # CPU backend aliases them outright) — a pooled buffer
+                # handed to the next wave before that is a data race
+                leases.append(lease)
                 # positional mblk only when a mesh lane exists: tests
                 # and profilers wrap _launch_arrays with the classic
                 # 3-arg signature
                 packed, counters = (
                     self._launch_arrays(a64, a32, now_ms) if mblk is None
                     else self._launch_arrays(a64, a32, now_ms, mblk))
-            finally:
-                lease.release()  # the launch copied the host operands
-            launched.append((idx, slots, packed, counters))
+                launched.append((idx, slots, packed, counters, lease))
+        except BaseException:
+            for lease in leases:
+                lease.release()
+            raise
         return (batch, khash, now_ms, launched, mslot, cold_idx)
 
     def sync_packed(self, token, engine_lock=None) -> tuple:
@@ -581,9 +589,14 @@ class ShardedEngine:
         lim_o = np.zeros(n, np.int64)
         full = np.zeros(n, bool)
         err_idx: List[int] = []
-        for idx, slots, packed, counters in launched:
-            o_st, o_rem, o_rst, o_lim, o_err = self._finish_wave(
-                packed, counters)
+        for idx, slots, packed, counters, lease in launched:
+            try:
+                o_st, o_rem, o_rst, o_lim, o_err = self._finish_wave(
+                    packed, counters)
+            except BaseException:
+                ShardedEngine.drop_packed(self, token)
+                raise
+            lease.release()  # results are here: the operands were read
             status[idx] = o_st[slots]
             rem_o[idx] = o_rem[slots]
             rst_o[idx] = o_rst[slots]
@@ -627,6 +640,14 @@ class ShardedEngine:
             rst_o[ci] = c_rst
             full[ci] = c_full
         return status, lim_o, rem_o, rst_o, full
+
+    def drop_packed(self, token) -> None:
+        """Give up a launched token that will never be synced (the
+        dispatcher's failure paths): return its upload buffers to the
+        pool.  Idempotent; the device work itself already happened —
+        state threads through the launches."""
+        for wave in token[3]:
+            wave[-1].release()
 
     def warmup(self, now_ms: int = 1) -> None:
         """Pre-compile every wave-bucket step program (all-invalid rows:

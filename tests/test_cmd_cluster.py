@@ -18,7 +18,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 ENV = dict(
     os.environ,
-    GUBER_JAX_PLATFORM="cpu",
     JAX_PLATFORMS="cpu",
     XLA_FLAGS="--xla_force_host_platform_device_count=2",
     GUBER_CACHE_SIZE="4096",

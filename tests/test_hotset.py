@@ -52,6 +52,20 @@ def test_replicas_diverge_then_psum_converges(mesh4):
     assert {r.remaining for r in rs} == {960}
 
 
+def test_sync_on_a_one_device_mesh():
+    """One chip is a 1-device mesh: the sync's psum/pmax must run there
+    too, folding the lone replica's consumption into its base."""
+    eng = HotSetEngine(make_mesh(n=1), capacity=256, batch_per_chip=32)
+    eng.pin(req("one", limit=1000), kh("one"), NOW)
+    rs = eng.check_batch([req("one", limit=1000) for _ in range(40)],
+                         [kh("one")] * 40, NOW + 1)
+    assert all(r.status == Status.UNDER_LIMIT for r in rs)
+    eng.sync()
+    r = eng.check_batch([req("one", limit=1000, hits=0)], [kh("one")],
+                        NOW + 2)[0]
+    assert r.remaining == 960
+
+
 def test_conservation_across_syncs(mesh4):
     """Total admitted ≤ limit once syncs run between windows."""
     eng = HotSetEngine(mesh4, capacity=256, batch_per_chip=32)

@@ -106,6 +106,30 @@ def test_conservation_convergence_staleness(monkeypatch):
         inst.close()
 
 
+def test_fold_on_a_one_device_mesh_conserves(monkeypatch):
+    """One chip is a 1-device mesh — the shape no CI mesh ever had.
+    The fold's collectives must stay real there (an elided psum fails
+    shard_map's replication check on every tick): the reconcile tick
+    folds, conserves exactly, and the tier does not stand down."""
+    inst = mesh_instance(monkeypatch, n=1)
+    try:
+        seeded_traffic(inst)
+        inst._mesh_reconcile_tick()
+        mge = inst._meshglobal
+        mge.drain()
+        s = mge.stats()
+        assert s["n_shards"] == 1
+        assert s["folded_hits"] == s["injected_hits"] == 4 * 20 * 2, s
+        assert inst.metrics.mesh_global_folds._value.get() >= 1
+        assert inst.metrics.mesh_global_fold_errors._value.get() == 0
+        assert not inst._mesh_degraded
+        kh0 = hash_key("mg", "k0")
+        rem = np.asarray(mge.state.remaining)[0, mge.slots[kh0]]
+        assert int(rem) == 100_000 - 4 * 4 * 2
+    finally:
+        inst.close()
+
+
 def test_bit_identical_vs_grpc_path(monkeypatch):
     """Same seeded traffic through mesh mode and through the gRPC-mode
     solo path (hot set off → owner-sharded GLOBAL): response bytes

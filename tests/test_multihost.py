@@ -75,12 +75,12 @@ assert ins == 4 * B // 2 * 2, ins  # every process's 2B keys inserted
 from gubernator_tpu.ops import pallas_step as pstep_mod
 from gubernator_tpu.parallel.pallas_engine import make_pallas_step_packed
 
-CAPL = 1 << 8   # rows per shard
+NBL = 2         # buckets per shard
 PB = 32         # batch rows per shard
 pkstep = make_pallas_step_packed(mesh, interpret=True)
 rows = multihost.process_local_batch(
-    mesh, np.zeros((2 * CAPL, pstep_mod.WORDS), np.int32),
-    (4 * CAPL, pstep_mod.WORDS))
+    mesh, np.zeros((2 * NBL, pstep_mod.WORDS, pstep_mod.SLOTS), np.int32),
+    (4 * NBL, pstep_mod.WORDS, pstep_mod.SLOTS))
 NOWP = 1_760_000_000_000
 rngp = np.random.default_rng(100 + proc_id)
 nreq = 2 * PB

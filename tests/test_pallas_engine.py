@@ -444,7 +444,7 @@ class TestSnapshotRestore:
         placed = pe.restore(arrays)
         dt = time.monotonic() - t0
         # every row is accounted for: placed or dropped (bucket full
-        # at 0.5 load over 8-slot buckets loses a small tail)
+        # at 0.5 load a bucket-full tail is possible in principle)
         assert placed + pe.dropped_rows == n
         assert placed > 0.9 * n
         assert dt < 60, f"1M-row restore took {dt:.1f}s"

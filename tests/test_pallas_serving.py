@@ -108,30 +108,19 @@ class TestEngineSelection:
         finally:
             inst.close()
 
-    def test_engine_fallback_is_loud_and_serves(self, clean_env):
-        """Fused engine unavailable → classic sharded engine, one
-        engine_fallback event, NO error rows on traffic."""
+    def test_selected_engine_that_cannot_build_stops_the_daemon(
+            self, clean_env, monkeypatch):
+        """No stand-in engine: a selected engine whose construction
+        fails propagates out of V1Instance (and so out of
+        spawn_daemon) instead of serving from ShardedEngine."""
         import gubernator_tpu.parallel.pallas_engine as pe
-
-        orig = pe.XlaFusedEngine.__init__
 
         def boom(self, *a, **kw):
             raise RuntimeError("no fused engine on this stack")
 
-        pe.XlaFusedEngine.__init__ = boom
-        try:
-            inst = fused_instance()
-        finally:
-            pe.XlaFusedEngine.__init__ = orig
-        try:
-            assert type(inst.engine) is ShardedEngine
-            kinds = [e.get("kind") for e in inst.recorder.events()]
-            assert "engine_fallback" in kinds
-            resps = inst.get_rate_limits(
-                [req(f"fb{i}") for i in range(8)], now_ms=NOW)
-            assert all(r.error == "" for r in resps)
-        finally:
-            inst.close()
+        monkeypatch.setattr(pe.XlaFusedEngine, "__init__", boom)
+        with pytest.raises(RuntimeError, match="no fused engine"):
+            fused_instance()
 
 
 class TestFusedParity:

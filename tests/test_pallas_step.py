@@ -185,7 +185,7 @@ class TestPallasStepParity:
         pt, po = decide_batch_pallas(pt, b, jnp.asarray(NOW, i64),
                                      interpret=True)
         err = np.asarray(po.err)
-        assert err.sum() == 3  # 11 keys, 8 slots
+        assert err.sum() == 3  # SLOTS + 3 keys, SLOTS slots
         assert (np.asarray(po.status)[~err] == 0).all()
         assert (np.asarray(po.remaining)[~err] == 9).all()
         # the survivors keep serving (their state was not clobbered)

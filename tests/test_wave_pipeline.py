@@ -175,6 +175,8 @@ def test_midstream_engine_exception_fails_only_its_wave(pipeline,
     if pipeline == "1":
         eng.launch_packed = gated_launch
         eng.sync_packed = tagged_sync
+        orig_drop = eng.drop_packed
+        eng.drop_packed = lambda token: orig_drop(token[1])
     else:
         eng.check_packed = gated_cp
     disp = Dispatcher(eng, max_delay_ms=0.2)
@@ -383,3 +385,37 @@ def test_pipeline_depth_env_parsing(monkeypatch):
             assert d.pipeline_depth == want, raw
         finally:
             d.close()
+
+
+def test_launched_wave_keeps_its_upload_buffers_until_synced():
+    """A launch is asynchronous: the runtime may read a wave's host
+    operands after launch_packed returns (the CPU backend aliases
+    them).  Back-to-back launches of same-width waves must therefore
+    not share a pooled buffer — wave 1 must still answer for ITS rows
+    (its own limit) after wave 2 was packed and launched."""
+    eng = ShardedEngine(make_mesh(n=1), capacity_per_shard=1 << 18,
+                        batch_per_shard=4096)  # load stays < 0.16
+    n = 4000
+
+    def cols(tag, limit, now):
+        kh = hash_request_keys(["pw"] * n,
+                               [f"{tag}{i}" for i in range(n)])
+        b, _ = pack_columns(kh, np.ones(n, np.int64),
+                            np.full(n, limit, np.int64),
+                            np.full(n, 60_000, np.int64),
+                            np.zeros(n, np.int32), np.zeros(n, np.int32),
+                            np.zeros(n, np.int64), now)
+        return b, kh
+
+    eng.warmup()
+    for rep in range(5):
+        waves = [cols(f"r{rep}a", 50, NOW + rep), cols(f"r{rep}b", 70,
+                                                       NOW + rep)]
+        tokens = [eng.launch_packed(b, kh, NOW + rep) for b, kh in waves]
+        assert eng.wave_pool.stats()["outstanding"] == 2
+        for tok, limit in zip(tokens, (50, 70)):
+            st, lim, rem, rst, full = eng.sync_packed(tok)
+            assert (lim == limit).all() and (rem == limit - 1).all(), \
+                (rep, limit, np.unique(lim).tolist())
+    assert eng.wave_pool.stats()["outstanding"] == 0
+    assert eng.wave_pool.stats()["leaks"] == 0

@@ -2,7 +2,7 @@
 
 ``jax.jit`` caches compiled programs by (shapes, dtypes, weak-type
 flags, static-arg hashes).  A call site that drifts any of those
-recompiles SILENTLY — a 250-305 s cold compile in the middle of
+recompiles SILENTLY — a minutes-long cold TPU compile in the middle of
 steady-state serving, surfacing only as a caller timeout (the exact
 failure the dispatcher's stall watchdog was built for).  This pass
 pins the two statically-checkable drift classes at every call site of

@@ -1,0 +1,11 @@
+"""Mean time inside the routing layer's entry,
+instance.get_rate_limits_wire (benchmark span, open loop)."""
+import numpy as np
+
+
+def read(ctx):
+    if ctx["traffic"]["loop"] != "open":
+        return None
+    spans = ctx["spans"].within("instance.get_rate_limits_wire",
+                                ctx["start_at"], ctx["end"])
+    return float(1000.0 * np.mean(spans)) if spans else None

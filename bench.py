@@ -1789,8 +1789,8 @@ def _sec_pallas():
     COMPILED small-shape XLA kernel (XlaFusedEngine) — the old
     interpret-mode toy row measured nothing and is gone (its number is
     recorded under pre_pr).  The row carries the A/B bit-identity, the
-    fused/xla throughput ratio, and PhaseLedger evidence that the pack
-    phase collapsed into `device` (phase_deleted)."""
+    fused/xla throughput ratio, and the PhaseLedger's in-wave partition
+    of both engines (phase_partition)."""
     import jax
 
     from gubernator_tpu.config import Config
@@ -1885,13 +1885,14 @@ def _sec_pallas():
         # per-consumer memory block (comparable across rows 6/11/12/13)
         "hbm": fused["hbm"],
         "telemetry": fused["telemetry"],
-        # PhaseLedger evidence: the classic engine's waves carry a pack
-        # segment; fused waves don't — `device` absorbed it, and the
-        # per-wave partition stays exact (drift is float rounding)
-        "phase_deleted": {
-            "deleted_phase": "pack",
-            "pack_absent_in_fused": "pack" not in fused["phases"],
-            "pack_present_in_xla": "pack" in xla["phases"],
+        # PhaseLedger evidence: both engines' waves carry pack, device
+        # (in-flight time) and resolve, and the per-wave partition
+        # stays exact (drift is float rounding).  This block used to
+        # read an absent `pack` as proof that fusion deleted the host
+        # work; the chip refuted that (PERF.md §5, ISSUE 24)
+        "phase_partition": {
+            "pack_in_fused": "pack" in fused["phases"],
+            "pack_in_xla": "pack" in xla["phases"],
             "phase_p50_ms": pmeans,
             "partition_max_drift_ms": round(
                 max(fused["drift_ms"], xla["drift_ms"]), 3)},
@@ -1902,7 +1903,7 @@ def _sec_pallas():
             "flavor (GUBER_ENGINE=pallas off-TPU): decisions "
             "bit-identical to the classic engine by construction, so "
             "the A/B prices exactly what fusion deletes (host tap "
-            "copies + the pack mark). The Mosaic bucket kernel at "
+            "copies). The Mosaic bucket kernel at "
             "large CAP is the TPU row")
     return {"11_pallas_serving": row}
 

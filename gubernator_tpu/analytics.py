@@ -19,10 +19,10 @@ Two pieces, both bounded-memory and OFF the caller's critical path:
   carried one (object-lane taps; pure-columnar wire waves only know
   the 64-bit khash).
 
-- ``PhaseLedger``: per-phase duration attribution (ingest, pack,
-  queue_wait, device, resolve, build, peer_flush) feeding both the
-  ``gubernator_phase_duration{phase=...}`` histograms and the
-  ``GET /debug/phases`` percentile snapshot.  The in-wave phases
+- ``PhaseLedger``: per-phase duration attribution (every name of
+  ``tracing.PHASE_CATALOG``; ``tracing.phase`` is what feeds it)
+  behind both the ``gubernator_phase_duration{phase=...}`` histograms
+  and the ``GET /debug/phases`` percentile snapshot.  The in-wave phases
   (pack, device, resolve) partition the existing
   ``gubernator_dispatcher_wave_duration`` exactly (asserted by
   tests/test_telemetry.py).
@@ -826,9 +826,6 @@ class KeyAnalytics:
                  width: Optional[int] = None, queue_cap: int = 512,
                  clock=time.time):
         self.metrics = metrics
-        #: per-phase histogram children resolved once — .labels() per
-        #: sample is a lock + dict walk on the serving path
-        self._phase_hist: Dict[str, object] = {}
         self._clock = clock
         k = k if k is not None else _env_int("GUBER_TOPK", 256)
         width = (width if width is not None
@@ -961,22 +958,19 @@ class KeyAnalytics:
     # ---- phase attribution ---------------------------------------------
 
     def observe_phase(self, phase: str, seconds: float,
-                      exemplar=None) -> None:
-        """One phase sample → histogram + /debug/phases ledger.
-        ``exemplar`` (ISSUE 12): a recent sampled trace's label dict,
-        attached to the histogram observation so a slow-phase bucket
-        links to one concrete trace (openmetrics exposition)."""
-        from .metrics import observe_with_exemplar
-
-        seconds = max(seconds, 0.0)
+                      cpu: Optional[float] = None, exemplar=None) -> None:
+        """One phase sample → histogram + /debug/phases ledger (the
+        sink ``tracing.phase`` hands its samples to).  ``cpu``: the
+        thread's CPU seconds over the same section, for the phases
+        that record them.  ``exemplar`` (ISSUE 12): a recent sampled
+        trace's label dict, attached to the histogram observation so a
+        slow-phase bucket links to one concrete trace (openmetrics
+        exposition)."""
+        if seconds < 0.0:
+            seconds = 0.0
         self.phases.observe(phase, seconds)
-        m = self.metrics
-        if m is not None:
-            child = self._phase_hist.get(phase)
-            if child is None:  # benign race: labels() is idempotent
-                child = self._phase_hist[phase] = \
-                    m.phase_duration.labels(phase=phase)
-            observe_with_exemplar(child, seconds, exemplar)
+        if self.metrics is not None:
+            self.metrics.observe_phase(phase, seconds, cpu, exemplar)
 
     # ---- worker ---------------------------------------------------------
 

@@ -45,6 +45,10 @@ log = logging.getLogger("gubernator_tpu.daemon")
 class _V1Servicer:
     def __init__(self, instance: V1Instance):
         self.instance = instance
+        #: one entry per handler in flight; append / pop / len are
+        #: each atomic under the GIL, so the count needs no lock
+        self._door: list = []
+        self._door_calls = 0  # lock-free: sampling counter, 1 call in 8 is observed
 
     def GetRateLimits(self, request: pb.GetRateLimitsReq, context):
         with grpc_request_context(
@@ -67,18 +71,33 @@ class _V1Servicer:
         """Raw-bytes twin of GetRateLimits (grpc_api.add_v1_servicer_raw):
         lets the instance's C++ wire lane run decode→decide→encode
         without pb2 when the batch qualifies.  The caller's remaining
-        deadline scopes deadline-aware admission shedding (ISSUE 5)."""
-        with grpc_request_context(
-                context, recorder=self.instance.span_recorder), \
-                span("grpc.GetRateLimits", metrics=self.instance.metrics), \
-                request_deadline(context.time_remaining()):
-            try:
-                return self.instance.get_rate_limits_wire(request)
-            except ValueError as e:
-                context.abort(grpc.StatusCode.INVALID_ARGUMENT, exc_text(e))
-            except ResourceExhausted as e:
-                context.abort(grpc.StatusCode.RESOURCE_EXHAUSTED,
-                              exc_text(e))
+        deadline scopes deadline-aware admission shedding (ISSUE 5).
+
+        gubernator_door_inflight: handlers in flight at this one's
+        entry, itself included.  A mean at the pool's 32 says callers
+        queue for a worker thread; a mean well under it says their
+        time passes before Python code runs (gRPC core, GIL hand-off)."""
+        door = self._door
+        door.append(None)
+        n = self._door_calls = self._door_calls + 1
+        if not n & 7:  # the mean needs no more than 1 call in 8
+            self.instance.metrics.door_inflight.observe(len(door))
+        try:
+            with grpc_request_context(
+                    context, recorder=self.instance.span_recorder), \
+                    span("grpc.GetRateLimits",
+                         metrics=self.instance.metrics), \
+                    request_deadline(context.time_remaining()):
+                try:
+                    return self.instance.get_rate_limits_wire(request)
+                except ValueError as e:
+                    context.abort(grpc.StatusCode.INVALID_ARGUMENT,
+                                  exc_text(e))
+                except ResourceExhausted as e:
+                    context.abort(grpc.StatusCode.RESOURCE_EXHAUSTED,
+                                  exc_text(e))
+        finally:
+            door.pop()
 
     def HealthCheck(self, request: pb.HealthCheckReq, context):
         return health_to_pb(self.instance.health_check())

@@ -29,6 +29,7 @@ from typing import List, Optional, Sequence, Tuple
 from contextlib import contextmanager
 from contextvars import ContextVar
 
+from .tracing import WaveScope, phase, take_gap
 from .types import RateLimitRequest, RateLimitResponse
 
 log = logging.getLogger("gubernator_tpu.dispatcher")
@@ -105,15 +106,19 @@ class ResultView:
 
 
 class _Job:
-    __slots__ = ("reqs", "now_ms", "future", "t_enq", "trace", "span")
+    __slots__ = ("reqs", "now_ms", "future", "t_enq", "qwait", "trace",
+                 "span")
 
     def __init__(self, reqs, now_ms):
         self.reqs = reqs
         self.now_ms = now_ms
         self.future: Future = Future()
-        #: stamped by _submit: queue-wait start + caller's trace id
-        #: (+ the caller's open span id, the wave span's parent)
+        #: stamped by _submit: queue-wait start (and the open
+        #: `queue_wait` phase, ended by the wave that takes the job) +
+        #: caller's trace id (+ the caller's open span id, the wave
+        #: span's parent)
         self.t_enq: Optional[float] = None
+        self.qwait: Optional[phase] = None
         self.trace: Optional[str] = None
         self.span: Optional[str] = None
 
@@ -125,8 +130,8 @@ class _PackedJob:
     column (-1 = sharded row) — rides the job so a fused engine can
     serve both lanes in ONE launch."""
 
-    __slots__ = ("batch", "khash", "now_ms", "future", "t_enq", "trace",
-                 "span", "mslot")
+    __slots__ = ("batch", "khash", "now_ms", "future", "t_enq", "qwait",
+                 "trace", "span", "mslot")
 
     def __init__(self, batch, khash, now_ms, mslot=None):
         self.batch = batch
@@ -135,6 +140,7 @@ class _PackedJob:
         self.mslot = mslot
         self.future: Future = Future()
         self.t_enq: Optional[float] = None
+        self.qwait: Optional[phase] = None
         self.trace: Optional[str] = None
         self.span: Optional[str] = None
 
@@ -185,6 +191,17 @@ class Dispatcher:
     #: many OTHER batched requests' (trace, span) pairs as attributes
     WAVE_LINKS = 8
 
+    #: one wave in this many also records thread CPU time in its
+    #: phases (gubernator_phase_cpu_seconds against ..._cpu_wall_
+    #: seconds): time.thread_time() is a system call, and ~30 of them
+    #: a wave cost single-request traffic 1.6 % of its rate on the chip
+    CPU_SAMPLE = 16
+
+    #: the per-call phases `handler` and `call.wait` time one call in
+    #: this many (``call_sample`` = 1: every call, set by an instance
+    #: whose GLOBAL rows route on the handler threads)
+    CALL_SAMPLE = 8
+
     def __init__(self, engine, max_wave: int = 8192,
                  max_delay_ms: float = 0.2,
                  lock: Optional[threading.Lock] = None,
@@ -200,7 +217,8 @@ class Dispatcher:
         #: off the caller's critical path — and per-phase durations
         #: feed its ledger.  None (bare dispatchers) costs nothing.
         self.analytics = analytics
-        self._phase_hist: dict = {}  # phase → cached histogram child
+        self._scope_seq = 0  # lock-free: sampling counter (see _new_scope)
+        self.call_sample = self.CALL_SAMPLE
         self.max_wave = max_wave
         # coalescing window: how long the worker waits for more jobs
         # after the first before launching the wave.  GUBER_COALESCE_US
@@ -227,9 +245,10 @@ class Dispatcher:
         self.metrics = metrics
         self.recorder = recorder
         #: optional tracing.SpanRecorder (ISSUE 12): when attached (by
-        #: the instance), every wave emits a fan-in span + exact phase
-        #: child spans; None (bare dispatchers, bench "off" arm) costs
-        #: nothing.  Plain attr — swapped whole, racy reads are fine.
+        #: the instance), every wave emits a fan-in span whose children
+        #: are the wave.*/lock.* phases that ran for it; None (bare
+        #: dispatchers, bench "off" arm) costs nothing.  Plain attr —
+        #: swapped whole, racy reads are fine.
         self.span_recorder = None
         self._clock = clock
         #: mesh-GLOBAL reconcile generation (ISSUE 7): bumped by the
@@ -293,13 +312,9 @@ class Dispatcher:
         #: fast path to a pipeline that can't exist)
         self._pipelined = (self._want_pipeline()
                            and hasattr(engine, "launch_packed"))
-        # fused-engine capabilities (ISSUE 8): a fused engine's wave IS
-        # one device program, so the pack mark collapses into the
-        # `device` phase (the PhaseLedger partition stays exact — the
-        # tail segment is still `resolve`), and the engine emits the
+        # fused-engine capability (ISSUE 8): the engine emits the
         # heavy-hitter tap columns on device at launch, so the
         # dispatcher's host-side column copies are skipped.
-        self._fused_phases = getattr(engine, "fused_serving", False)
         self._fused_tap = getattr(engine, "fused_tap", False)
         if self.metrics is not None:
             self.metrics.pipeline_depth.set(
@@ -407,18 +422,18 @@ class Dispatcher:
         if not self._try_inline():
             return self._BUSY
         try:
-            wid = self._wave_begin(kind, nreq=nreq, tenant=tenant)
-            try:
-                self._mark_pack(wid)
-                with self._engine_lock:
-                    self._fault("device_step")
-                    out = fn()
-                self._wave_mark(wid, "device")
-            except Exception as e:  # noqa: BLE001 - recorded, re-raised
-                self._wave_end(wid, error=e)
-                raise
-            self._wave_end(wid)
-            return out
+            with self._new_scope() as scope:
+                wid = self._wave_begin(scope, kind, nreq=nreq,
+                                       tenant=tenant)
+                try:
+                    with self._engine_step(wid, scope):
+                        out = fn()
+                except Exception as e:  # noqa: BLE001 - recorded, re-raised
+                    self._wave_end(wid, error=e)
+                    raise
+                with phase("wave.end", self):
+                    self._wave_end(wid)
+                return out
         finally:
             self._inline_mu.release()
 
@@ -434,28 +449,34 @@ class Dispatcher:
         thread handoff)."""
         if self._try_inline():
             try:
-                wid = self._wave_begin("inline", nreq=len(reqs),
-                                       tenant=self._hint_reqs(reqs))
-                try:
-                    self._mark_pack(wid)
-                    with self._engine_lock:
-                        self._fault("device_step")
-                        out = self.engine.check_batch(list(reqs), now_ms)
-                    self._wave_mark(wid, "device")
-                except Exception as e:  # noqa: BLE001 - recorded, re-raised
-                    self._wave_end(wid, error=e)
-                    raise
-                self._wave_end(wid)
-                self._tap_reqs(reqs, out)
-                return out
+                with self._new_scope() as scope:
+                    wid = self._wave_begin(scope, "inline",
+                                           nreq=len(reqs),
+                                           tenant=self._hint_reqs(reqs))
+                    try:
+                        with self._engine_step(wid, scope):
+                            out = self.engine.check_batch(list(reqs),
+                                                          now_ms)
+                    except Exception as e:  # noqa: BLE001 - recorded, re-raised
+                        self._wave_end(wid, error=e)
+                        raise
+                    with phase("wave.end", self):
+                        self._wave_end(wid)
+                        self._tap_reqs(reqs, out)
+                    return out
             finally:
                 self._inline_mu.release()
-        job = _Job(list(reqs), now_ms)
-        self._submit(job)
-        try:
-            return job.future.result(timeout=self.RESULT_TIMEOUT_S)
-        except FuturesTimeout as e:
-            raise self._result_timeout(e) from e
+        return self._submit_and_wait(_Job(list(reqs), now_ms))
+
+    def _submit_and_wait(self, job):
+        """Queue ``job`` and block on its wave's future: `call.wait` is
+        queue wait + wave, from the caller's side."""
+        with phase("call.wait", self, every=self.call_sample):
+            self._submit(job)
+            try:
+                return job.future.result(timeout=self.RESULT_TIMEOUT_S)
+            except FuturesTimeout as e:
+                raise self._result_timeout(e) from e
 
     def check_packed(self, batch, khash, now_ms: int,
                      mslot=None) -> tuple:
@@ -478,30 +499,25 @@ class Dispatcher:
         column tuples."""
         if self._try_inline():
             try:
-                wid = self._wave_begin("inline_packed",
-                                       nreq=len(khash),
-                                       tenant=self._hint_khash(khash))
-                try:
-                    self._mark_pack(wid)
-                    with self._engine_lock:
-                        self._fault("device_step")
-                        out = self._engine_check_packed(batch, khash,
-                                                        now_ms, mslot)
-                    self._wave_mark(wid, "device")
-                except Exception as e:  # noqa: BLE001 - recorded, re-raised
-                    self._wave_end(wid, error=e)
-                    raise
-                self._wave_end(wid)
-                self._tap_packed(khash, batch.hits, out[0])
-                return ResultView(out, 0, len(khash))
+                with self._new_scope() as scope:
+                    wid = self._wave_begin(
+                        scope, "inline_packed", nreq=len(khash),
+                        tenant=self._hint_khash(khash))
+                    try:
+                        with self._engine_step(wid, scope):
+                            out = self._engine_check_packed(
+                                batch, khash, now_ms, mslot)
+                    except Exception as e:  # noqa: BLE001 - recorded, re-raised
+                        self._wave_end(wid, error=e)
+                        raise
+                    with phase("wave.end", self):
+                        self._wave_end(wid)
+                        self._tap_packed(khash, batch.hits, out[0])
+                    return ResultView(out, 0, len(khash))
             finally:
                 self._inline_mu.release()
-        job = _PackedJob(batch, khash, now_ms, mslot=mslot)
-        self._submit(job)
-        try:
-            return job.future.result(timeout=self.RESULT_TIMEOUT_S)
-        except FuturesTimeout as e:
-            raise self._result_timeout(e) from e
+        return self._submit_and_wait(
+            _PackedJob(batch, khash, now_ms, mslot=mslot))
 
     def _fault(self, point: str) -> None:
         f = self._faults
@@ -621,6 +637,8 @@ class Dispatcher:
         n = _job_len(job)
         self.admit(n)
         job.t_enq = self._clock()
+        job.qwait = phase("queue_wait", self, span=False).begin(
+            at=job.t_enq)
         job.trace = current_trace_id()
         job.span = current_span_id()
         with self._submit_mu:
@@ -640,19 +658,25 @@ class Dispatcher:
     # All metric/recorder emission is None-guarded: a bare Dispatcher
     # costs two dict ops and a few deque appends per wave.
 
-    def _wave_begin(self, kind: str, jobs=None, nreq: int = 0,
-                    trace: Optional[str] = None,
+    def _wave_begin(self, scope: WaveScope, kind: str, jobs=None,
+                    nreq: int = 0, trace: Optional[str] = None,
                     slot: Optional[int] = None,
                     tenant: Optional[str] = None) -> int:
+        """Register a wave (see above) inside its already entered
+        ``scope``: binds the wave's ids into it, ends the jobs'
+        `queue_wait` phases, and opens the coarse `pack` phase at the
+        wave's own t0.  The bookkeeping itself is the `wave.begin`
+        phase."""
         t0 = self._clock()
+        ph = phase("wave.begin", self).begin()
         waits = []
         parent = None
         links = []
         if jobs:
             nreq = sum(_job_len(j) for j in jobs)
             for j in jobs:
-                if j.t_enq is not None:
-                    waits.append(max(t0 - j.t_enq, 0.0))
+                if j.qwait is not None:
+                    waits.append(j.qwait.end(at=t0))
                 if trace is None:
                     trace = j.trace
                     parent = getattr(j, "span", None)
@@ -669,7 +693,8 @@ class Dispatcher:
             trace = current_trace_id()
             parent = current_span_id()
         wspan = None
-        if self.span_recorder is not None and trace is not None:
+        sr = self.span_recorder
+        if sr is not None and trace is not None:
             from .tracing import new_span_id
 
             wspan = new_span_id()
@@ -686,17 +711,20 @@ class Dispatcher:
                                    "trace": trace, "stalled": False,
                                    "slot": slot, "gen": gen,
                                    "tenant": tenant, "span": wspan,
-                                   "parent": parent, "links": links,
-                                   "marks": []}
+                                   "links": links, "scope": scope,
+                                   "coarse": phase(
+                                       "pack", self, cpu=scope.cpu,
+                                       span=False).begin(at=t0),
+                                   "phases": {}}
             self._recent_sizes.append(nreq)
             self._recent_waits.extend(waits)
+        if wspan is not None:
+            scope.bind(sr, trace, wspan, parent, wid)
         if self.metrics is not None:
             self.metrics.wave_size.observe(nreq)
             for w in waits:
                 self.metrics.wave_queue_wait.observe(w)
             self.metrics.waves_in_flight.inc()
-        for w in waits:
-            self._obs_phase("queue_wait", w)
         if self.recorder is not None:
             ev = {"trace": trace, "wave": wid, "wave_kind": kind,
                   "size": nreq, "jobs": len(jobs) if jobs else 1}
@@ -712,6 +740,7 @@ class Dispatcher:
             if tenant is not None:
                 ev["tenant"] = tenant
             self.recorder.record("wave_launched", **ev)
+        ph.end()
         return wid
 
     # ---- tenant event hints (ISSUE 11) ----------------------------------
@@ -747,33 +776,59 @@ class Dispatcher:
             return self._hint_khash(kh)
         return None
 
-    # ---- per-phase attribution (ISSUE 4) --------------------------------
+    # ---- per-phase attribution (ISSUE 4, ISSUE 24) ---------------------
     #
-    # Each wave's duration partitions into named segments: a mark with
-    # name N stamps the END of segment N; the tail segment (last mark →
-    # wave end) is "resolve" (future resolution / view construction).
-    # Every execution path marks "pack" (host-side packing/concat up to
-    # the engine call, incl. pipelined launch work) and "device" (the
-    # engine/sync call), so pack + device + resolve == wave_duration up
-    # to float rounding — asserted in tests/test_telemetry.py.
+    # Each wave's duration partitions into three coarse phases, opened
+    # and closed on the wave's own clock with SHARED boundary readings:
+    # `pack` (wave begin → the launch returned; for unpipelined waves →
+    # the engine call entered, engine lock held), `device` (→ results
+    # on the host: IN-FLIGHT time, of which the device is busy a small
+    # part) and `resolve` (→ wave end).  `pack` and `resolve` are each
+    # one stretch of one thread, so they also record its CPU time: on
+    # the chip the worker RUNS for a fifth of `pack`, the rest it
+    # waits for the GIL.  So pack + device + resolve ==
+    # wave_duration up to float rounding, on every engine — asserted
+    # in tests/test_telemetry.py.  The fine phases (wave.*, lock.*,
+    # tracing.PHASE_CATALOG) time what happens inside them.
 
-    def _wave_mark(self, wid: int, name: str) -> None:
+    def _wave_mark(self, wid: int, nxt: phase) -> None:
+        """A coarse boundary: close the wave's open coarse phase and
+        open ``nxt`` on the same clock reading."""
         t = self._clock()
         with self._tel_mu:
             info = self._inflight.get(wid)
-            if info is not None:
-                info["marks"].append((name, t))
+        if info is None:
+            return
+        cur = info["coarse"]
+        info["phases"][cur.name] = cur.end(at=t,
+                                           exemplar=self._exemplar())
+        info["coarse"] = nxt.begin(at=t)
 
-    def _mark_pack(self, wid: int) -> None:
-        """Stamp the end of the pack segment — SUPPRESSED for fused
-        engines (ISSUE 8): their wave is one device program, so the
-        partition collapses to {device, resolve} and the `device`
-        phase absorbs what fusion deletes.  The exact wave-time
-        partition (sum of segments == wave duration) holds either way
-        — that partition IS the proof of which phase time fusion
-        removed, surfaced by the bench A/B's phase_deleted evidence."""
-        if not self._fused_phases:
-            self._wave_mark(wid, "pack")
+    def _new_scope(self) -> WaveScope:
+        """The scope of the next wave; 1 in CPU_SAMPLE has its phases
+        record thread CPU time too (a racy count: any 1 in ~16 will
+        do)."""
+        n = self._scope_seq = self._scope_seq + 1  # lock-free: sampling counter, a lost update skews nothing
+        return WaveScope(self, cpu=n % self.CPU_SAMPLE == 0)
+
+    def _exemplar(self):
+        sr = self.span_recorder
+        return sr.exemplar() if sr is not None else None
+
+    @contextmanager
+    def _engine_step(self, wid: int, scope: WaveScope):
+        """One blocking engine call of wave ``wid`` under the engine
+        lock: `lock.engine` times the wait for it, the coarse partition
+        turns to `device` once it is held and to `resolve` when the
+        call has returned."""
+        wait = phase("lock.engine", self).begin()
+        with self._engine_lock:
+            wait.end()
+            self._wave_mark(wid, phase("device", self, span=False))
+            self._fault("device_step")
+            yield
+        self._wave_mark(wid, phase("resolve", self, cpu=scope.cpu,
+                                   span=False))
 
     def _engine_check_packed(self, batch, khash, now_ms: int, mslot):
         """engine.check_packed with the mesh-slot column only when one
@@ -784,23 +839,19 @@ class Dispatcher:
         return self.engine.check_packed(batch, khash, now_ms,
                                         mslot=mslot)
 
-    def _obs_phase(self, phase: str, seconds: float,
-                   exemplar=None) -> None:
-        """One phase sample → histogram (+ the analytics ledger when
-        attached; KeyAnalytics.observe_phase already feeds the same
-        histogram, so don't double-observe).  ``exemplar`` links the
-        bucket to a recent sampled trace (ISSUE 12)."""
+    def observe_phase(self, name: str, seconds: float,
+                      cpu: Optional[float] = None, exemplar=None) -> None:
+        """The sink of ``tracing.phase`` on this dispatcher: one sample
+        → histogram (+ the analytics ledger when attached;
+        KeyAnalytics.observe_phase already feeds the same histogram, so
+        don't double-observe).  ``exemplar`` links the bucket to a
+        recent sampled trace (ISSUE 12)."""
         ana = self.analytics
         if ana is not None:
-            ana.observe_phase(phase, seconds, exemplar=exemplar)
+            ana.observe_phase(name, seconds, cpu, exemplar)
         elif self.metrics is not None:
-            from .metrics import observe_with_exemplar
-
-            child = self._phase_hist.get(phase)
-            if child is None:  # benign race: labels() is idempotent
-                child = self._phase_hist[phase] = \
-                    self.metrics.phase_duration.labels(phase=phase)
-            observe_with_exemplar(child, max(seconds, 0.0), exemplar)
+            self.metrics.observe_phase(name, max(seconds, 0.0), cpu,
+                                       exemplar)
 
     def _tap_packed(self, khash, hits, status) -> None:
         """Post-wave columnar tap (None-guarded, never raises into the
@@ -841,24 +892,16 @@ class Dispatcher:
             was_stalled = info["stalled"]
             any_stalled = any(i["stalled"]
                               for i in self._inflight.values())
-        # segment the wave into its phases (marks stamp segment ENDS;
-        # the tail is "resolve") and observe each — off the _tel_mu
-        # lock, still before any caller resumes from this wave
-        sr = self.span_recorder
-        ex = sr.exemplar() if sr is not None else None
-        phases = None
-        marks = info.get("marks")
-        if marks:
-            phases = {}
-            prev = info["t0"]
-            for name, tm in marks:
-                phases[name] = max(tm - prev, 0.0)
-                prev = tm
-            phases["resolve"] = max(t1 - prev, 0.0)
-            for name, secs in phases.items():
-                self._obs_phase(name, secs, exemplar=ex)
-        if sr is not None and info.get("span") and info["trace"]:
-            self._record_wave_span(sr, wid, info, dur, phases, error)
+        # close the open coarse phase on the wave's own end reading —
+        # off the _tel_mu lock, still before any caller resumes from
+        # this wave
+        ex = self._exemplar()
+        phases = info["phases"]
+        cur = info["coarse"]
+        phases[cur.name] = (phases.get(cur.name, 0.0)
+                            + cur.end(at=t1, exemplar=ex))
+        if info["span"]:
+            info["scope"].finish(self._wave_span_attrs(wid, info, error))
         if self.metrics is not None:
             from .metrics import observe_with_exemplar
 
@@ -887,10 +930,9 @@ class Dispatcher:
                 ev["slot"] = info["slot"]
             if info.get("tenant") is not None:
                 ev["tenant"] = info["tenant"]
-            if phases is not None:
-                # per-phase breakdown in ms; sums to duration_ms
-                ev["phases"] = {k: round(v * 1000, 3)
-                                for k, v in phases.items()}
+            # per-phase breakdown in ms; sums to duration_ms
+            ev["phases"] = {k: round(v * 1000, 3)
+                            for k, v in phases.items()}
             if error is not None:
                 self.recorder.record("wave_error", error=exc_text(error),
                                      **ev)
@@ -902,56 +944,26 @@ class Dispatcher:
                 self.recorder.record("first_wave", trace=info["trace"],
                                      duration_ms=round(dur * 1000, 3))
 
-    def _record_wave_span(self, sr, wid: int, info: dict, dur: float,
-                          phases, error) -> None:
-        """Emit the wave's fan-in span + its phase child spans
-        (ISSUE 12).  The wave clock is monotonic (`_clock`); spans
-        carry wall time, so the wave is reconstructed backwards from
-        `now`: children laid end-to-end in mark order EXACTLY
-        partition the wave span — the PhaseLedger partition, kept, as
-        tree structure.  Never raises into the serving path."""
-        try:
-            import time as _time
+    def _wave_span_attrs(self, wid: int, info: dict, error) -> dict:
+        """The wave's fan-in span attributes (ISSUE 12); the span
+        itself is recorded by its WaveScope when the wave's last
+        section on this thread ends, so its children — the wave.* /
+        lock.* phases, each with the start and end it was read at — lie
+        inside it."""
+        attrs = {"wave": wid, "kind": info["kind"], "size": info["size"]}
+        if info.get("gen"):
+            attrs["gen"] = info["gen"]
+        if info.get("slot") is not None:
+            attrs["slot"] = info["slot"]
+        if info.get("tenant") is not None:
+            attrs["tenant"] = info["tenant"]
+        if info.get("links"):
+            attrs["links"] = ",".join(info["links"])
+        if error is not None:
+            from .telemetry import exc_text
 
-            total = sum(phases.values()) if phases else dur
-            start = _time.time() - total  # clock-ok: telemetry wall clock (span layout)
-            # lay the children end-to-end FIRST and take the wave's
-            # end from the same cumulative walk — bitwise-exact
-            # partition (start + sum(...) differs in the last float
-            # bits from the accumulated chain)
-            c = start
-            kids = []
-            for name, secs in (phases or {}).items():
-                kids.append((name, c, c + secs))
-                c += secs
-            end = c if kids else start + total
-            tid = info["trace"]
-            attrs = {"wave": wid, "kind": info["kind"],
-                     "size": info["size"]}
-            if info.get("gen"):
-                attrs["gen"] = info["gen"]
-            if info.get("slot") is not None:
-                attrs["slot"] = info["slot"]
-            if info.get("tenant") is not None:
-                attrs["tenant"] = info["tenant"]
-            if info.get("links"):
-                attrs["links"] = ",".join(info["links"])
-            if error is not None:
-                from .telemetry import exc_text
-
-                attrs["error"] = exc_text(error)
-            sr.add({"trace_id": tid, "span_id": info["span"],
-                    "parent_id": info.get("parent"), "name": "wave",
-                    "start": start, "end": end, "attrs": attrs})
-            from .tracing import new_span_id
-
-            for name, k0, k1 in kids:
-                sr.add({"trace_id": tid, "span_id": new_span_id(),
-                        "parent_id": info["span"],
-                        "name": f"wave.{name}",
-                        "start": k0, "end": k1, "attrs": {}})
-        except Exception:  # pragma: no cover - tracing only
-            log.exception("wave span record")
+            attrs["error"] = exc_text(error)
+        return attrs
 
     def _watchdog_run(self) -> None:
         while not self._closing.wait(self._watch_interval_s):
@@ -1111,16 +1123,38 @@ class Dispatcher:
         next device launch.  Jobs already queued are taken greedily
         FIRST: when the backlog alone fills max_wave rows, the wave
         launches with NO coalescing wait at all — the window exists to
-        catch stragglers, not to tax a saturated queue."""
+        catch stragglers, not to tax a saturated queue.
+
+        `worker.wait` is the block for the first job, `worker.coalesce`
+        everything from there to the return: with the wave.* phases
+        and `worker.gap` (what passed between the worker's phases since
+        the last wave: glue and, mostly, waiting to get the GIL back)
+        they partition the dispatch worker's wall time."""
+        gap = take_gap()
+        if gap:
+            phase("worker.gap", self, span=False).begin(at=0.0).end(
+                at=gap)
         if self._carry is not None:
             first, self._carry = self._carry, None
+            co = phase("worker.coalesce", self, span=False).begin()
         else:
+            wt = phase("worker.wait", self, span=False).begin()
             try:
                 first = (self._queue.get(timeout=block_s) if block_s > 0
                          else self._queue.get_nowait())
             except queue.Empty:
+                wt.end()
                 return []
+            wt.end()
+            co = phase("worker.coalesce", self, span=False).begin(
+                at=wt.t1)
             self._dequeued(first)
+        try:
+            return self._coalesce(first)
+        finally:
+            co.end()
+
+    def _coalesce(self, first) -> List[_Job]:
         wave = [first]
         total = _job_len(first)
         deadline = None  # armed only after the backlog is drained
@@ -1189,6 +1223,11 @@ class Dispatcher:
         # with the inline fast path's gate).
         from collections import deque
 
+        from .tracing import partition_thread
+
+        # this thread waits for work or works on a wave, nothing else:
+        # its phases (worker.*, wave.*, lock.*) partition its wall time
+        partition_thread()
         pipelined = self._pipelined
         depth = self.pipeline_depth
         pending: deque = deque()  # [(jobs, token)] launched, unsynced
@@ -1256,64 +1295,86 @@ class Dispatcher:
 
     def _launch_packed_jobs(self, jobs, slot: Optional[int] = None):
         """Concat + LAUNCH a pure-packed wave; returns (jobs, token,
-        wave_id) for the sync phase, or None when dispatch failed
-        (futures already resolved with the error).  The wave stays "in
-        flight" (watchdog-visible) from launch until its sync resolves;
-        ``slot`` is its position in the in-flight ring at launch."""
-        wid = self._wave_begin("packed_pipelined", jobs, slot=slot)
-        try:
-            self._fault("dispatch_launch")
+        wave_id, batch, khash, scope) for the sync phase, or None when
+        dispatch failed (futures already resolved with the error).  The
+        wave stays "in flight" (watchdog-visible) from launch until its
+        sync resolves; ``slot`` is its position in the in-flight ring
+        at launch."""
+        with self._new_scope() as scope:
+            wid = self._wave_begin(scope, "packed_pipelined", jobs,
+                                   slot=slot)
+            try:
+                self._fault("dispatch_launch")
+                batch, khash, mslot, now = self._concat_jobs(jobs)
+                wait = phase("lock.engine", self).begin()
+                with self._engine_lock:
+                    wait.end()
+                    self._fault("device_step")
+                    token = (self.engine.launch_packed(batch, khash, now)
+                             if mslot is None
+                             else self.engine.launch_packed(
+                                 batch, khash, now, mslot=mslot))
+                # the launch's host-side routing/fill IS pack work; the
+                # wave is in flight from here until sync_packed returns
+                self._wave_mark(wid, phase("device", self, span=False))
+                return (jobs, token, wid, batch, khash, scope)
+            except Exception as e:  # noqa: BLE001 - surfaced per-caller
+                self._wave_end(wid, error=e)
+                for j in jobs:
+                    if not j.future.done():
+                        j.future.set_exception(e)
+                return None
+
+    def _concat_jobs(self, jobs) -> tuple:
+        """(batch, khash, mslot, now) of a pure-packed wave's jobs.
+        The scalar now only backstops sweeps/padding; requests use
+        their own now column.  max() keeps sweep time monotonic."""
+        with phase("wave.concat", self):
             if len(jobs) == 1:
                 batch, khash = jobs[0].batch, jobs[0].khash
             else:
                 batch, khash = _concat_columns(
                     [(j.batch, j.khash) for j in jobs])
-            mslot = _concat_mslot(jobs)
-            now = max(j.now_ms for j in jobs)
-            with self._engine_lock:
-                self._fault("device_step")
-                token = (self.engine.launch_packed(batch, khash, now)
-                         if mslot is None
-                         else self.engine.launch_packed(batch, khash,
-                                                        now,
-                                                        mslot=mslot))
-            # the launch's host-side routing/fill IS pack work; device
-            # time runs from here until sync_packed returns
-            self._mark_pack(wid)
-            return (jobs, token, wid, batch, khash)
-        except Exception as e:  # noqa: BLE001 - surfaced per-caller
-            self._wave_end(wid, error=e)
-            for j in jobs:
-                if not j.future.done():
-                    j.future.set_exception(e)
-            return None
+            return (batch, khash, _concat_mslot(jobs),
+                    max(j.now_ms for j in jobs))
 
-    def _sync_and_resolve(self, jobs, token, wid, batch, khash) -> None:
-        try:
-            self._fault("dispatch_sync")
-            cols = self.engine.sync_packed(
-                token, engine_lock=self._engine_lock)
-            self._wave_mark(wid, "device")
-            # racer preemption point: hold the result splice while later
-            # waves launch (callers still waiting on their views)
-            self._fault("dispatch_splice")
+    def _resolve_views(self, jobs, cols) -> None:
+        """Resolve each packed job's future with its row bounds into
+        the wave's shared result columns — a view, NOT materialized
+        slices: response build runs in each caller's own thread
+        (ResultView)."""
+        with phase("wave.resolve", self):
             a = 0
             for j in jobs:
                 b = a + len(j.khash)
-                # a row-bounds view, NOT materialized slices: response
-                # build runs in each caller's own thread (ResultView)
                 j.future.set_result(ResultView(cols, a, b))
                 a = b
-            self._wave_end(wid)
-            self._tap_packed(khash, batch.hits, cols[0])
-        except Exception as e:  # noqa: BLE001 - surfaced per-caller
-            # a token that failed before (or inside) its sync still
-            # holds the wave's pooled upload buffers
-            self.engine.drop_packed(token)
-            self._wave_end(wid, error=e)
-            for j in jobs:
-                if not j.future.done():
-                    j.future.set_exception(e)
+
+    def _sync_and_resolve(self, jobs, token, wid, batch, khash,
+                          scope) -> None:
+        with scope:
+            try:
+                self._fault("dispatch_sync")
+                cols = self.engine.sync_packed(
+                    token, engine_lock=self._engine_lock)
+                self._wave_mark(wid, phase("resolve", self,
+                                           cpu=scope.cpu, span=False))
+                # racer preemption point: hold the result splice while
+                # later waves launch (callers still waiting on their
+                # views)
+                self._fault("dispatch_splice")
+                self._resolve_views(jobs, cols)
+                with phase("wave.end", self):
+                    self._wave_end(wid)
+                    self._tap_packed(khash, batch.hits, cols[0])
+            except Exception as e:  # noqa: BLE001 - surfaced per-caller
+                # a token that failed before (or inside) its sync still
+                # holds the wave's pooled upload buffers
+                self.engine.drop_packed(token)
+                self._wave_end(wid, error=e)
+                for j in jobs:
+                    if not j.future.done():
+                        j.future.set_exception(e)
 
     def _run_merged_wave(self, wave) -> None:
         """Cross-time merge of a mixed wave: every list job is packed at
@@ -1326,20 +1387,47 @@ class Dispatcher:
         from .hashing import hash_request_keys
         from .parallel.sharded import responses_from_columns
 
-        wid = self._wave_begin("merged", wave)
-        try:
-            tap = self._run_merged_wave_inner(
-                wave, np, pack_requests, hash_request_keys,
-                responses_from_columns, wid)
-        except Exception as e:  # noqa: BLE001 - caller fails the futures
-            self._wave_end(wid, error=e)
-            raise
-        self._wave_end(wid)
-        self._tap_packed(*tap)
+        with self._new_scope() as scope:
+            wid = self._wave_begin(scope, "merged", wave)
+            try:
+                tap = self._run_merged_wave_inner(
+                    wave, np, pack_requests, hash_request_keys,
+                    responses_from_columns, wid, scope)
+            except Exception as e:  # noqa: BLE001 - caller fails the futures
+                self._wave_end(wid, error=e)
+                raise
+            with phase("wave.end", self):
+                self._wave_end(wid)
+                self._tap_packed(*tap)
 
     def _run_merged_wave_inner(self, wave, np, pack_requests,
                                hash_request_keys,
-                               responses_from_columns, wid) -> tuple:
+                               responses_from_columns, wid,
+                               scope) -> tuple:
+        with phase("wave.concat", self):
+            parts, batch, khash, mslot = self._merge_parts(
+                wave, np, pack_requests, hash_request_keys)
+        now = max(j.now_ms for j in wave)
+        with self._engine_step(wid, scope):
+            st, lim, rem, rst, full = self._engine_check_packed(
+                batch, khash, now, mslot)
+        self._fault("dispatch_splice")
+        cols = (st, lim, rem, rst, full)
+        with phase("wave.resolve", self):
+            a = 0
+            for j, _, kh, errs in parts:
+                b_ = a + len(kh)
+                if isinstance(j, _PackedJob):
+                    j.future.set_result(ResultView(cols, a, b_))
+                else:
+                    j.future.set_result(responses_from_columns(
+                        (st[a:b_], lim[a:b_], rem[a:b_], rst[a:b_],
+                         full[a:b_]), errs))
+                a = b_
+        return (khash, batch.hits, st)
+
+    @staticmethod
+    def _merge_parts(wave, np, pack_requests, hash_request_keys) -> tuple:
         parts = []  # (job, batch, khash, errs or None)
         mparts = []
         for j in wave:
@@ -1359,26 +1447,7 @@ class Dispatcher:
                  if any(isinstance(j, _PackedJob)
                         and j.mslot is not None for j in wave)
                  else None)
-        now = max(j.now_ms for j in wave)
-        self._mark_pack(wid)
-        with self._engine_lock:
-            self._fault("device_step")
-            st, lim, rem, rst, full = self._engine_check_packed(
-                batch, khash, now, mslot)
-        self._wave_mark(wid, "device")
-        self._fault("dispatch_splice")
-        a = 0
-        cols = (st, lim, rem, rst, full)
-        for j, _, kh, errs in parts:
-            b_ = a + len(kh)
-            if isinstance(j, _PackedJob):
-                j.future.set_result(ResultView(cols, a, b_))
-            else:
-                j.future.set_result(responses_from_columns(
-                    (st[a:b_], lim[a:b_], rem[a:b_], rst[a:b_],
-                     full[a:b_]), errs))
-            a = b_
-        return (khash, batch.hits, st)
+        return parts, batch, khash, mslot
 
     def _run_list_jobs(self, jobs, now) -> None:
         if not jobs:
@@ -1389,61 +1458,46 @@ class Dispatcher:
             start = len(merged)
             merged.extend(j.reqs)
             slices.append((j, start, len(merged)))
-        wid = self._wave_begin("list", jobs)
-        try:
-            self._fault("dispatch_launch")
-            self._mark_pack(wid)
-            with self._engine_lock:
-                self._fault("device_step")
-                resps = self.engine.check_batch(merged, now)
-            self._wave_mark(wid, "device")
-            self._fault("dispatch_splice")
-            for j, a, b in slices:
-                j.future.set_result(resps[a:b])
-            self._wave_end(wid)
-            self._tap_reqs(merged, resps)
-        except Exception as e:  # noqa: BLE001 - surfaced per-caller
-            self._wave_end(wid, error=e)
-            for j, _, _ in slices:
-                if not j.future.done():
-                    j.future.set_exception(e)
+        with self._new_scope() as scope:
+            wid = self._wave_begin(scope, "list", jobs)
+            try:
+                self._fault("dispatch_launch")
+                with self._engine_step(wid, scope):
+                    resps = self.engine.check_batch(merged, now)
+                self._fault("dispatch_splice")
+                with phase("wave.resolve", self):
+                    for j, a, b in slices:
+                        j.future.set_result(resps[a:b])
+                with phase("wave.end", self):
+                    self._wave_end(wid)
+                    self._tap_reqs(merged, resps)
+            except Exception as e:  # noqa: BLE001 - surfaced per-caller
+                self._wave_end(wid, error=e)
+                for j, _, _ in slices:
+                    if not j.future.done():
+                        j.future.set_exception(e)
 
     def _run_packed_jobs(self, jobs) -> None:
         if not jobs:
             return
-        import numpy as np
-
-        wid = self._wave_begin("packed", jobs)
-        try:
-            if len(jobs) == 1:
-                batch, khash = jobs[0].batch, jobs[0].khash
-            else:
-                batch, khash = _concat_columns(
-                    [(j.batch, j.khash) for j in jobs])
-            mslot = _concat_mslot(jobs)
-            # scalar now only backstops sweeps/padding; requests use
-            # their own now column.  max() keeps sweep time monotonic.
-            now = max(j.now_ms for j in jobs)
-            self._fault("dispatch_launch")
-            self._mark_pack(wid)
-            with self._engine_lock:
-                self._fault("device_step")
-                cols = self._engine_check_packed(batch, khash, now,
-                                                 mslot)
-            self._wave_mark(wid, "device")
-            self._fault("dispatch_splice")
-            a = 0
-            for j in jobs:
-                b = a + len(j.khash)
-                j.future.set_result(ResultView(cols, a, b))
-                a = b
-            self._wave_end(wid)
-            self._tap_packed(khash, batch.hits, cols[0])
-        except Exception as e:  # noqa: BLE001 - surfaced per-caller
-            self._wave_end(wid, error=e)
-            for j in jobs:
-                if not j.future.done():
-                    j.future.set_exception(e)
+        with self._new_scope() as scope:
+            wid = self._wave_begin(scope, "packed", jobs)
+            try:
+                batch, khash, mslot, now = self._concat_jobs(jobs)
+                self._fault("dispatch_launch")
+                with self._engine_step(wid, scope):
+                    cols = self._engine_check_packed(batch, khash, now,
+                                                     mslot)
+                self._fault("dispatch_splice")
+                self._resolve_views(jobs, cols)
+                with phase("wave.end", self):
+                    self._wave_end(wid)
+                    self._tap_packed(khash, batch.hits, cols[0])
+            except Exception as e:  # noqa: BLE001 - surfaced per-caller
+                self._wave_end(wid, error=e)
+                for j in jobs:
+                    if not j.future.done():
+                        j.future.set_exception(e)
 
     def close(self) -> None:
         with self._submit_mu:

@@ -24,6 +24,7 @@ from typing import Dict, List, Tuple
 from .config import BehaviorConfig
 from .interval import IntervalLoop
 from .telemetry import exc_text
+from .tracing import phase
 from .types import Behavior, RateLimitRequest
 
 log = logging.getLogger("gubernator_tpu.global")
@@ -482,10 +483,15 @@ class GlobalManager:
                 updates[req.key] = (seq, req)
         if not updates:
             return
-        t0 = time.perf_counter()
+        disp = getattr(self.instance, "dispatcher", None)
+        # per-phase attribution (closes the PR-4 ROADMAP open item):
+        # the broadcast path lands in the PhaseLedger / histogram next
+        # to ingest/device/peer_flush
+        bc = phase("broadcast", disp).begin()
         msgs = self.instance.build_global_updates(
             [r for _, r in updates.values()])
         if not msgs:
+            bc.end(keep=False)
             return
         peers = [p for p in self.instance.peers() if not self.instance.is_self(p)]
         errors = []
@@ -543,14 +549,9 @@ class GlobalManager:
                     log.warning(errors[-1])
         self._record(errors)
         self.metrics.global_broadcast_counter.inc()
-        dt = time.perf_counter() - t0
+        dt = bc.end()
         self.metrics.broadcast_duration.observe(dt)
-        # per-phase attribution (closes the PR-4 ROADMAP open item):
-        # the broadcast path lands in the PhaseLedger / histogram next
-        # to ingest/device/peer_flush
-        disp = getattr(self.instance, "dispatcher", None)
         if disp is not None:
-            disp._obs_phase("broadcast", dt)
             ana = getattr(disp, "analytics", None)
             if ana is not None and peers and not errors:
                 # cost-model sample (ISSUE 11): one broadcast fans the

@@ -31,6 +31,7 @@ from .multiregion import MultiRegionManager
 from .peer_client import ErrClosing, PeerClient
 from .peers import RegionPeerPicker, ReplicatedConsistentHash
 from .telemetry import FlightRecorder, exc_text
+from .tracing import phase
 from .proto import gubernator_pb2 as pb
 from .proto import peers_pb2 as peers_pb
 from .store import CacheItem
@@ -301,6 +302,10 @@ class V1Instance:
                 f"unknown global_mode {global_mode!r} (want 'grpc' or "
                 "'mesh')")
         self._global_mode = global_mode
+        if global_mode == "mesh":
+            # GLOBAL rows route on the handler threads: time every
+            # call's handler and call.wait (see get_rate_limits_wire)
+            self.dispatcher.call_sample = 1
         self._meshglobal = None
         #: single-writer state (the GlobalManager hits-loop thread owns
         #: the reconcile tick); request threads only read — a stale
@@ -409,16 +414,16 @@ class V1Instance:
         from .store import arrays_from_items
 
         self._fault_point("restore")
-        t0 = time.perf_counter()
-        items = list(self.loader.load())
-        if items:
-            arrays = arrays_from_items(items)
-            placed = self.engine.restore(arrays)
-            log.info("loader: restored %d/%d items", placed, len(items))
         # restore is a serving-blackout window — attribute it (ISSUE 5
         # satellite; closes the PR-4 ROADMAP item with broadcast/
         # snapshot)
-        self.dispatcher._obs_phase("restore", time.perf_counter() - t0)
+        with phase("restore", self.dispatcher):
+            items = list(self.loader.load())
+            if items:
+                arrays = arrays_from_items(items)
+                placed = self.engine.restore(arrays)
+                log.info("loader: restored %d/%d items", placed,
+                         len(items))
 
     def _save_to_loader(self) -> None:
         from .store import items_from_arrays
@@ -426,22 +431,22 @@ class V1Instance:
         if self.loader is None:
             return
         self._fault_point("snapshot")
-        t0 = time.perf_counter()
-        # hot-set / mesh-tier rows live outside the sharded table; fold
-        # them back in so the snapshot is complete
-        self._demote_all()
-        self._mesh_demote_all()
-        arrays = self.engine.snapshot()
-        if self._tier is not None:
-            # cold-tier rows are first-class state: a snapshot covers
-            # BOTH tiers (restore re-adopts whatever the device table
-            # cannot hold — engine.restore's unplaced → tier path)
-            cold = self._tier.snapshot_arrays()
-            if cold is not None:
-                arrays = {f: np.concatenate([arrays[f], cold[f]])
-                          for f in arrays}
-        self.loader.save(iter(items_from_arrays(arrays)))
-        self.dispatcher._obs_phase("snapshot", time.perf_counter() - t0)
+        with phase("snapshot", self.dispatcher):
+            # hot-set / mesh-tier rows live outside the sharded table;
+            # fold them back in so the snapshot is complete
+            self._demote_all()
+            self._mesh_demote_all()
+            arrays = self.engine.snapshot()
+            if self._tier is not None:
+                # cold-tier rows are first-class state: a snapshot
+                # covers BOTH tiers (restore re-adopts whatever the
+                # device table cannot hold — engine.restore's unplaced
+                # → tier path)
+                cold = self._tier.snapshot_arrays()
+                if cold is not None:
+                    arrays = {f: np.concatenate([arrays[f], cold[f]])
+                              for f in arrays}
+            self.loader.save(iter(items_from_arrays(arrays)))
 
     def _fault_point(self, point: str, tag: Optional[str] = None) -> None:
         """Instance-level faultpoint check (one attribute read while
@@ -633,13 +638,6 @@ class V1Instance:
         the dispatcher so bench A/B detaches ONE reference and every
         tap — dispatcher waves and fused instance lanes — goes dark."""
         return self.dispatcher.analytics
-
-    def _obs_phase(self, phase: str, seconds: float) -> None:
-        """Phase attribution outside the dispatcher's waves (wire
-        ingest, response build); no-op when analytics is off."""
-        ana = self.dispatcher.analytics
-        if ana is not None:
-            ana.observe_phase(phase, seconds)
 
     def owner_addr_by_khash(self, khash: int) -> Optional[str]:
         """Owner peer address for a MIXED table key hash (the heavy-
@@ -870,6 +868,19 @@ class V1Instance:
         pb2 object path with identical semantics.  Raises ValueError
         on oversize batches (mirroring ``get_rate_limits``).
         """
+        # the `handler` phase: the whole call.  Where GLOBAL rows route
+        # on the handler threads (mesh mode: call_sample is 1) every
+        # call, wall and thread CPU — on 32 threads and one GIL the
+        # difference is waiting; elsewhere 1 call in 8, wall only: a
+        # per-call phase costs single-request traffic its share of the
+        # rate
+        every = self.dispatcher.call_sample
+        with phase("handler", self.dispatcher, cpu=every == 1,
+                   every=every):
+            return self._get_rate_limits_wire(data, now_ms)
+
+    def _get_rate_limits_wire(self, data: bytes,
+                              now_ms: Optional[int]) -> bytes:
         self._fault_point("wire_ingest")
         parsed = None
         is_global = False
@@ -886,10 +897,9 @@ class V1Instance:
                 out = self._wire_client_fused(data, now_ms)
                 if out is not None:
                     return out
-            t_ing = time.perf_counter()
+            ing = phase("ingest", self.dispatcher).begin()
             parsed = _wire_native.parse_get_rate_limits(data)
-            if parsed is not None:
-                self._obs_phase("ingest", time.perf_counter() - t_ing)
+            ing.end(keep=parsed is not None)
             if parsed is not None:
                 is_global = bool(parsed["behavior_or"]
                                  & int(Behavior.GLOBAL))
@@ -1005,11 +1015,11 @@ class V1Instance:
         if prepack is None:
             return None
         now = clock_ms() if now_ms is None else now_ms  # clock-domain: caller
-        t_ing = time.perf_counter()
+        ing = phase("ingest", self.dispatcher).begin()
         pre = prepack(data, now)
+        ing.end(keep=pre is not None)
         if pre is None:
             return None
-        self._obs_phase("ingest", time.perf_counter() - t_ing)
         if pre.behavior_or & int(self._FUSED_EXCLUDED):
             # GLOBAL rides the hot-set flow, MULTI_REGION queues async
             # replication — both need the parsed columns; the classic
@@ -1055,11 +1065,11 @@ class V1Instance:
         if prepack is None:
             return None
         now = clock_ms() if now_ms is None else now_ms  # clock-domain: caller
-        t_ing = time.perf_counter()
+        ing = phase("ingest", self.dispatcher).begin()
         pre = prepack(data, now)
+        ing.end(keep=pre is not None)
         if pre is None:
             return None
-        self._obs_phase("ingest", time.perf_counter() - t_ing)
         if pre.behavior_or & int(self._FUSED_EXCLUDED):
             pre.lease.release()
             return None
@@ -1109,10 +1119,9 @@ class V1Instance:
                     if ana is not None:
                         ana.tap_flag("errors", 1,
                                      khash=int(pre.khash[int(i)]))
-            t_b = time.perf_counter()
-            resp = _wire_native.build_responses_from_columns(
-                (status, lim, rem, rst, full), 0, n, errors)
-            self._obs_phase("build", time.perf_counter() - t_b)
+            with phase("build", disp):
+                resp = _wire_native.build_responses_from_columns(
+                    (status, lim, rem, rst, full), 0, n, errors)
             if ana is not None:
                 disp._tap_packed(pre.khash, hits_tap, status)
             return resp
@@ -1141,10 +1150,9 @@ class V1Instance:
                 errors[int(i)] = "rate limit table full"
                 if ana is not None:
                     ana.tap_flag("errors", 1, khash=int(kh[int(i)]))
-        t_b = time.perf_counter()
-        resp = _wire_native.build_responses_from_columns(
-            view.cols, view.lo, view.hi, errors)
-        self._obs_phase("build", time.perf_counter() - t_b)
+        with phase("build", disp):
+            resp = _wire_native.build_responses_from_columns(
+                view.cols, view.lo, view.hi, errors)
         return resp
 
     # ---- tenant attribution helpers (ISSUE 11) -------------------------
@@ -1206,10 +1214,9 @@ class V1Instance:
                 out = self._wire_peer_fused(data, now_ms)
                 if out is not None:
                     return out
-            t_ing = time.perf_counter()
+            ing = phase("ingest", self.dispatcher).begin()
             parsed = _wire_native.parse_get_rate_limits(data)
-            if parsed is not None:
-                self._obs_phase("ingest", time.perf_counter() - t_ing)
+            ing.end(keep=parsed is not None)
         if parsed is None:
             from google.protobuf.message import DecodeError
 
@@ -1542,48 +1549,55 @@ class V1Instance:
         from .core.batch import pack_columns
         from .hashing import mix64_np
 
+        # route.* (ISSUE 24): per call, wall AND thread CPU — 32
+        # handler threads route on one GIL, and wall − CPU is the wait
+        disp = self.dispatcher
         n = parsed["n"]
-        kh = mix64_np(parsed["khash_raw"])
-        kh = np.where(kh == 0, np.uint64(1), kh)
-        batch, errs = pack_columns(
-            kh, parsed["hits"], parsed["limit"], parsed["duration"],
-            parsed["algorithm"], parsed["behavior"], parsed["burst"],
-            now, created_at=parsed.get("created_at"))
-        beh = np.asarray(batch.behavior)
-        glob_mask = (beh & int(Behavior.GLOBAL)) != 0
-        excluded = (beh & int(self._HOT_EXCLUDED)) != 0
-        mesh_mask = glob_mask & ~excluded & np.asarray(batch.valid)
-        mge = self._ensure_meshglobal()
+        with phase("route.pack", disp, cpu=True):
+            kh = mix64_np(parsed["khash_raw"])
+            kh = np.where(kh == 0, np.uint64(1), kh)
+            batch, errs = pack_columns(
+                kh, parsed["hits"], parsed["limit"], parsed["duration"],
+                parsed["algorithm"], parsed["behavior"], parsed["burst"],
+                now, created_at=parsed.get("created_at"))
+            beh = np.asarray(batch.behavior)
+            glob_mask = (beh & int(Behavior.GLOBAL)) != 0
+            excluded = (beh & int(self._HOT_EXCLUDED)) != 0
+            mesh_mask = glob_mask & ~excluded & np.asarray(batch.valid)
+            mge = self._ensure_meshglobal()
+        pins: List[tuple] = []
         if mesh_mask.any():
-            alg = np.asarray(batch.algorithm)
-            lim = np.asarray(batch.limit)
-            dur = np.asarray(batch.duration)
-            bur = np.asarray(batch.burst)
-            hits_col = np.asarray(batch.hits)
-            pins: List[tuple] = []
-            for k in np.unique(kh[mesh_mask]):
-                ik = int(k)
-                m = mesh_mask & (kh == k)
-                i = int(np.nonzero(m)[0][0])
-                # one config per key per batch (pinned OR to-pin): a
-                # mid-batch config change takes the object path, which
-                # demotes/serves it per request with exact semantics
-                if not ((alg[m] == alg[i]).all()
-                        and (lim[m] == lim[i]).all()
-                        and (dur[m] == dur[i]).all()
-                        and (bur[m] == bur[i]).all()):
-                    return None
-                proto = RateLimitRequest(
-                    name="", unique_key="", hits=int(hits_col[i]),
-                    limit=int(lim[i]), duration=int(dur[i]),
-                    algorithm=int(alg[i]), behavior=int(beh[i]),
-                    burst=int(bur[i]))
-                if mge.is_pinned(ik):
-                    if not mge.matches_pinned(ik, proto):
-                        return None  # config changed → demote path
-                else:
-                    pins.append((proto, ik, self._seed_row(ik)))
-            if pins:
+            with phase("route.keys", disp, cpu=True):
+                alg = np.asarray(batch.algorithm)
+                lim = np.asarray(batch.limit)
+                dur = np.asarray(batch.duration)
+                bur = np.asarray(batch.burst)
+                hits_col = np.asarray(batch.hits)
+                for k in np.unique(kh[mesh_mask]):
+                    ik = int(k)
+                    m = mesh_mask & (kh == k)
+                    i = int(np.nonzero(m)[0][0])
+                    # one config per key per batch (pinned OR to-pin):
+                    # a mid-batch config change takes the object path,
+                    # which demotes/serves it per request with exact
+                    # semantics
+                    if not ((alg[m] == alg[i]).all()
+                            and (lim[m] == lim[i]).all()
+                            and (dur[m] == dur[i]).all()
+                            and (bur[m] == bur[i]).all()):
+                        return None
+                    proto = RateLimitRequest(
+                        name="", unique_key="", hits=int(hits_col[i]),
+                        limit=int(lim[i]), duration=int(dur[i]),
+                        algorithm=int(alg[i]), behavior=int(beh[i]),
+                        burst=int(bur[i]))
+                    if mge.is_pinned(ik):
+                        if not mge.matches_pinned(ik, proto):
+                            return None  # config changed → demote path
+                    else:
+                        pins.append((proto, ik, self._seed_row(ik)))
+        if pins:
+            with phase("route.pin", disp, cpu=True):
                 ok = mge.pin_many(pins, now)
                 for (proto, ik, _s), good in zip(pins, ok):
                     if good:
@@ -1600,17 +1614,18 @@ class V1Instance:
         # each mesh row's pinned replica slot; -1 = sharded lane.
         mslot_col = None
         if getattr(self.engine, "mesh_bound", False) and mesh_mask.any():
-            mslot_col = np.full(n, -1, np.int32)
-            with mge._mu:
-                smap = dict(mge.slots)
-            for k in np.unique(kh[mesh_mask]):
-                s = smap.get(int(k))
-                if s is not None:
-                    mslot_col[mesh_mask & (kh == k)] = s
-                else:  # unpinned underneath us: sharded path is correct
-                    mesh_mask = mesh_mask & (kh != k)
-            if not (mslot_col >= 0).any():
-                mslot_col = None
+            with phase("route.slots", disp, cpu=True):
+                mslot_col = np.full(n, -1, np.int32)
+                with mge._mu:
+                    smap = dict(mge.slots)
+                for k in np.unique(kh[mesh_mask]):
+                    s = smap.get(int(k))
+                    if s is not None:
+                        mslot_col[mesh_mask & (kh == k)] = s
+                    else:  # unpinned underneath us: sharded is correct
+                        mesh_mask = mesh_mask & (kh != k)
+                if not (mslot_col >= 0).any():
+                    mslot_col = None
 
         def run_fused() -> bytes:
             st, lim_o, rem, rst, full = self.dispatcher.check_packed(
@@ -1627,8 +1642,9 @@ class V1Instance:
                 for i, emsg in errs.items():
                     errors[i] = emsg
             self.metrics.over_limit_counter.inc(int((st == 1).sum()))
-            return _wire_native.build_rate_limit_resps(
-                np.asarray(st, np.int64), lim_o, rem, rst, errors)
+            with phase("build", disp):
+                return _wire_native.build_rate_limit_resps(
+                    np.asarray(st, np.int64), lim_o, rem, rst, errors)
 
         if mslot_col is not None:
             return run_fused
@@ -1707,10 +1723,9 @@ class V1Instance:
             for i in np.nonzero(full)[0]:
                 if errors[int(i)] is None:
                     errors[int(i)] = "rate limit table full"
-        t_b = time.perf_counter()
-        resp = _wire_native.build_responses_from_columns(
-            view.cols, view.lo, view.hi, errors)
-        self._obs_phase("build", time.perf_counter() - t_b)
+        with phase("build", self.dispatcher):
+            resp = _wire_native.build_responses_from_columns(
+                view.cols, view.lo, view.hi, errors)
         return resp
 
     def _wire_check_columns(self, parsed: dict, now: int) -> bytes:
@@ -2688,7 +2703,7 @@ class V1Instance:
         mge = self._meshglobal
         if mge is None:
             return
-        t0 = time.perf_counter()
+        fold = phase("global_fold", self.dispatcher).begin()
         retired = None
         try:
             self._fault_point("global_accum_swap")
@@ -2696,6 +2711,7 @@ class V1Instance:
             self._fault_point("global_psum")
             mge.fold(retired)
         except Exception as e:  # noqa: BLE001 - incl. FaultInjected
+            fold.end(keep=False)
             if retired is not None:
                 mge.swap_back()  # unfolded hits stay accumulating
             self.metrics.mesh_global_fold_errors.inc()
@@ -2706,15 +2722,14 @@ class V1Instance:
                     and not self._mesh_degraded):
                 self._mesh_stand_down()
             return
-        dt = time.perf_counter() - t0
+        # the collective's time is its own phase (PhaseLedger)
+        dt = fold.end()
         self._mesh_fail_streak = 0
         self.metrics.mesh_global_folds.inc()
         self.metrics.mesh_global_staleness.set(mge.last_staleness_s)
         self.metrics.mesh_global_keys.set(len(mge.slots))
-        # stamp the coherence epoch onto subsequent waves and attribute
-        # the collective's time as its own phase (PhaseLedger)
+        # stamp the coherence epoch onto subsequent waves
         self.dispatcher.reconcile_gen = mge.generation
-        self.dispatcher._obs_phase("global_fold", dt)
         self._mesh_last_fold_ok = time.monotonic()
         ana = self.dispatcher.analytics
         if ana is not None:

@@ -224,10 +224,29 @@ class Metrics:
         # exactly what a million-key deployment must never export.
         self.phase_duration = Histogram(
             "gubernator_phase_duration",
-            "request time attributed per serving phase (s): ingest, "
-            "pack, queue_wait, device, resolve, build, peer_flush — "
-            "pack+device+resolve partition wave_duration",
+            "wall seconds of each program phase (tracing.PHASE_CATALOG)"
+            " — pack+device+resolve partition wave_duration",
             ["phase"], buckets=_BUCKETS, registry=r)
+        self.phase_cpu = Counter(
+            "gubernator_phase_cpu_seconds",
+            "thread CPU seconds inside the phases that record them "
+            "(route.*, handler in mesh-GLOBAL mode, a wave's pack and "
+            "resolve), read at the same boundaries as phase_duration: "
+            "wall - cpu is time the thread waited for the GIL or a lock",
+            ["phase"], registry=r)
+        self.phase_cpu_wall = Counter(
+            "gubernator_phase_cpu_wall_seconds",
+            "wall seconds of exactly those phase samples that also "
+            "recorded CPU time (phase_cpu_seconds' denominator: waves "
+            "are sampled 1 in 16 for it, calls are not)",
+            ["phase"], registry=r)
+        self._phase_children: dict = {}  # phase → its three children
+        self.door_inflight = Histogram(
+            "gubernator_door_inflight",
+            "GetRateLimits handlers in flight at a handler's entry, "
+            "itself included (the gRPC pool has 32 worker threads)",
+            buckets=(1, 2, 4, 8, 12, 16, 20, 24, 28, 32, 48, 64, 128),
+            registry=r)
         self.topkey_overlimit = Gauge(
             "gubernator_topkey_overlimit_total",
             "OVER_LIMIT decisions observed for each CURRENT top-K key "
@@ -403,6 +422,27 @@ class Metrics:
         finally:
             self.func_duration.labels(name=name).observe(
                 time.perf_counter() - t0)
+
+    def observe_phase(self, name: str, seconds: float, cpu=None,
+                      exemplar=None) -> None:
+        """One ``tracing.phase`` sample → gubernator_phase_duration
+        {phase} (+ the CPU pair when the sample recorded CPU time).
+        The label children are resolved once a phase: ``.labels()`` per
+        sample is a lock + dict walk on the serving path."""
+        kids = self._phase_children.get(name)
+        if kids is None:  # benign race: labels() is idempotent
+            kids = self._phase_children[name] = [
+                self.phase_duration.labels(phase=name), None, None]
+        if exemplar:
+            observe_with_exemplar(kids[0], seconds, exemplar)
+        else:
+            kids[0].observe(seconds)
+        if cpu is not None:
+            if kids[1] is None:  # a series only for phases that record it
+                kids[1] = self.phase_cpu.labels(phase=name)
+                kids[2] = self.phase_cpu_wall.labels(phase=name)
+            kids[1].inc(max(cpu, 0.0))
+            kids[2].inc(seconds)
 
     def render(self) -> bytes:
         """Text exposition for the /metrics endpoint."""

@@ -27,9 +27,24 @@ SHARD_AXIS = "shard"
 #: this mutex does.  Single-engine processes (the production topology)
 #: already serialize device work on their own engine lock, so the gate
 #: is uncontended there.
+import contextlib as _contextlib
 import threading as _threading
 
+from ..tracing import phase
+
 XLA_EXEC_MU = _threading.Lock()
+
+
+@_contextlib.contextmanager
+def exec_gate():
+    """XLA_EXEC_MU round one wave's dispatch (``_launch_arrays``), with
+    the wait for it and the dispatch itself timed as the `lock.xla_exec`
+    and `wave.dispatch` phases (tracing.PHASE_CATALOG)."""
+    wait = phase("lock.xla_exec").begin()
+    with XLA_EXEC_MU:
+        wait.end()
+        with phase("wave.dispatch"):
+            yield
 
 
 def make_mesh(devices: Sequence[jax.Device] | None = None,

@@ -58,6 +58,7 @@ from ..core.batch import RequestBatch, clamp_config, empty_batch, pack_requests
 from ..core.step import _lookup, _probe_slots, decide_batch_impl
 from ..core.table import TableState, init_table
 from ..hashing import shard_of
+from ..tracing import phase
 from ..types import EFF_MAX, RateLimitRequest, RateLimitResponse, Status
 from .mesh import SHARD_AXIS, XLA_EXEC_MU
 from .sharded import pack_wave_host
@@ -431,7 +432,10 @@ class MeshGlobalEngine:
         result); both store back atomically w.r.t. the fold/pins —
         the double-buffer discipline holds because the launch writes
         only the ACTIVE buffer (the fold reads retired ones)."""
+        # contended by the fold tick and by handlers pinning keys
+        wait = phase("lock.mesh_state").begin()
         with self._state_mu:
+            wait.end()
             st, acc, result = fn(self.state, self._acc[self._active])
             self.state = st
             self._acc[self._active] = acc

@@ -43,7 +43,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..core.batch import RequestBatch
 from ..core.step import decide_batch_impl
 from ..ops import pallas_step as ps
-from .mesh import SHARD_AXIS, XLA_EXEC_MU
+from ..tracing import phase
+from .mesh import SHARD_AXIS, XLA_EXEC_MU, exec_gate
 from .sharded import PACK32, PACK64, ShardedEngine
 
 log = logging.getLogger("gubernator_tpu.pallas_engine")
@@ -396,14 +397,15 @@ class FusedServingMixin:
     columns on device and, when the mesh-GLOBAL tier is bound, folds
     the replica decision + accumulator scatter into the same launch.
 
-    The dispatcher reads the two class flags: ``fused_serving`` makes
-    the PhaseLedger's pack/device/resolve partition collapse into a
-    ``device`` phase that absorbs what fusion deletes, and
-    ``fused_tap`` suppresses its host-side per-wave column copies (the
-    engine delivered the device tap at launch).
+    The dispatcher reads ``fused_tap``: it suppresses the host-side
+    per-wave column copies (the engine delivered the device tap at
+    launch).  ``fused_serving`` names the engine family (health and
+    bench output); the wave's pack/device/resolve partition is the same
+    as any engine's — fusion deleted device programs, not the host's
+    routing and fill, which the chip showed to be the larger part.
     """
 
-    #: dispatcher collapses the wave's pack mark into `device`
+    #: one device program per wave (decisions + tap + mesh tier)
     fused_serving = True
     #: dispatcher skips host-side column taps (device tap instead)
     fused_tap = True
@@ -509,19 +511,19 @@ class FusedServingMixin:
         mge = self._mge
         if (mge is None or mblk is None
                 or not bool((np.asarray(mblk) >= 0).any())):
-            with XLA_EXEC_MU:
+            with exec_gate():
                 if self.n > 1:
                     a64 = jax.device_put(a64, self._mat_sharding)
                     a32 = jax.device_put(a32, self._mat_sharding)
                 self.state, packed, tap, counters = self._step(
                     self.state, a64, a32, np.int64(now_ms))
-            self._deliver_tap(tap)
+                self._deliver_tap(tap)
             return packed, counters
         step = self._ensure_mesh_step(mge)
 
         def _go(mstate, acc):
             nonlocal a64, a32, mblk
-            with XLA_EXEC_MU:
+            with exec_gate():
                 if self.n > 1:
                     a64 = jax.device_put(a64, self._mat_sharding)
                     a32 = jax.device_put(a32, self._mat_sharding)
@@ -536,8 +538,8 @@ class FusedServingMixin:
         self._deliver_tap(tap)
         return packed, counters
 
-    def _finish_wave(self, packed, counters):
-        cols = super()._finish_wave(packed, counters[:2])
+    def _download_wave(self, packed, counters):
+        cols = super()._download_wave(packed, counters[:2])
         if len(counters) > 2:
             mh = int(counters[2])
             if mh:
@@ -628,7 +630,8 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
 
     def check_packed(self, batch, khash, now_ms: int,
                      mslot=None) -> tuple:
-        batch, ood = self._mask_out_of_domain(batch, mslot)
+        with phase("wave.route"):
+            batch, ood = self._mask_out_of_domain(batch, mslot)
         cols = self._merge_ood(
             super().check_packed(batch, khash, now_ms, mslot=mslot),
             ood)
@@ -648,21 +651,6 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
         need[elig] = True
         return tier.resolve(self, batch, khash, now_ms, cols,
                             None, need, mslot=mslot)
-
-    def launch_packed(self, batch, khash, now_ms: int, mslot=None):
-        # the pipelined dispatcher path calls launch/sync directly —
-        # the domain gate must cover it too
-        batch, ood = self._mask_out_of_domain(batch, mslot)
-        return (super().launch_packed(batch, khash, now_ms,
-                                      mslot=mslot), ood)
-
-    def sync_packed(self, token, engine_lock=None) -> tuple:
-        inner, ood = token
-        return self._merge_ood(
-            super().sync_packed(inner, engine_lock=engine_lock), ood)
-
-    def drop_packed(self, token) -> None:
-        super().drop_packed(token[0])
 
     def _try_auto_grow(self, grew: list) -> bool:
         return False  # no on-device grow for the bucket layout (doc)

@@ -27,8 +27,9 @@ from ..core.batch import (RequestBatch, WaveBufferPool, empty_batch,
                           pack_requests)
 from ..core.step import decide_batch_impl, _insert, _lookup, _probe_slots
 from ..core.table import TableState, init_table
-from .mesh import (SHARD_AXIS, XLA_EXEC_MU, make_mesh, shard_table,
-                   table_sharding)
+from ..tracing import phase
+from .mesh import (SHARD_AXIS, XLA_EXEC_MU, exec_gate, make_mesh,
+                   shard_table, table_sharding)
 
 log = logging.getLogger("gubernator_tpu.sharded")
 
@@ -533,25 +534,30 @@ class ShardedEngine:
         indices ride the token: the SYNC side re-dispatches them
         through check_packed under the engine lock — serving them here
         would let a promotion that lands between launch and sync read
-        a row this lane already consumed."""
-        tier = self.tier
-        cold_idx = None
-        if tier is not None:
-            kh = np.asarray(khash)
-            ov = np.asarray(batch.valid) & (kh != 0)
-            cm = tier.resident_mask(kh) & ov
-            if mslot is not None:
-                cm &= np.asarray(mslot) < 0
-            if cm.any():
-                cold_idx = np.nonzero(cm)[0]
-                batch = batch._replace(
-                    valid=np.asarray(batch.valid) & ~cm)
-        pending = self._arrival_order(batch)
+        a row this lane already consumed.  Rows outside the step
+        program's value domain (``_mask_out_of_domain``) ride invalid
+        too; the sync side marks them unservable."""
+        with phase("wave.route"):
+            batch, ood = self._mask_out_of_domain(batch, mslot)
+            tier = self.tier
+            cold_idx = None
+            if tier is not None:
+                kh = np.asarray(khash)
+                ov = np.asarray(batch.valid) & (kh != 0)
+                cm = tier.resident_mask(kh) & ov
+                if mslot is not None:
+                    cm &= np.asarray(mslot) < 0
+                if cm.any():
+                    cold_idx = np.nonzero(cm)[0]
+                    batch = batch._replace(
+                        valid=np.asarray(batch.valid) & ~cm)
+            waves = self._build_waves(khash, self._arrival_order(batch))
         launched, leases = [], []
         try:
-            for idx, slots, bw_w in self._build_waves(khash, pending):
-                a64, a32, lease, mblk = self._fill_packed(
-                    batch, idx, slots, bw_w, mslot)
+            for idx, slots, bw_w in waves:
+                with phase("wave.fill"):
+                    a64, a32, lease, mblk = self._fill_packed(
+                        batch, idx, slots, bw_w, mslot)
                 # the lease rides the token until sync_packed has the
                 # wave's results: the launch is asynchronous, and the
                 # runtime may still be reading the host operands (the
@@ -569,7 +575,13 @@ class ShardedEngine:
             for lease in leases:
                 lease.release()
             raise
-        return (batch, khash, now_ms, launched, mslot, cold_idx)
+        return (batch, khash, now_ms, launched, mslot, cold_idx, ood)
+
+    def _mask_out_of_domain(self, batch: RequestBatch, mslot=None):
+        """(batch with the rows this engine's step program cannot
+        represent made invalid, their indices or None).  The XLA step
+        has the full int64 domain: nothing to mask."""
+        return batch, None
 
     def sync_packed(self, token, engine_lock=None) -> tuple:
         """Pipeline phase 2: block on the launched waves and assemble
@@ -581,29 +593,37 @@ class ShardedEngine:
         acceptable: erred rows never mutated state, retries are the
         table-full corner, and the device clamps per-key time
         monotonically."""
-        batch, khash, now_ms, launched, mslot, cold_idx = token
-        n = len(khash)
-        status = np.zeros(n, np.int32)
-        rem_o = np.zeros(n, np.int64)
-        rst_o = np.zeros(n, np.int64)
-        lim_o = np.zeros(n, np.int64)
-        full = np.zeros(n, bool)
-        err_idx: List[int] = []
-        for idx, slots, packed, counters, lease in launched:
+        batch, khash, now_ms, launched, mslot, cold_idx, ood = token
+        finished = []
+        for _idx, _slots, packed, counters, lease in launched:
             try:
-                o_st, o_rem, o_rst, o_lim, o_err = self._finish_wave(
-                    packed, counters)
+                finished.append(self._finish_wave(packed, counters))
             except BaseException:
-                ShardedEngine.drop_packed(self, token)
+                self.drop_packed(token)
                 raise
             lease.release()  # results are here: the operands were read
-            status[idx] = o_st[slots]
-            rem_o[idx] = o_rem[slots]
-            rst_o[idx] = o_rst[slots]
-            lim_o[idx] = o_lim[slots]
-            werr = o_err[slots]
-            if werr.any():
-                err_idx.extend(idx[werr].tolist())
+        n = len(khash)
+        err_idx: List[int] = []
+        with phase("wave.scatter"):
+            status = np.zeros(n, np.int32)
+            rem_o = np.zeros(n, np.int64)
+            rst_o = np.zeros(n, np.int64)
+            lim_o = np.zeros(n, np.int64)
+            full = np.zeros(n, bool)
+            for (idx, slots, *_), (o_st, o_rem, o_rst, o_lim,
+                                   o_err) in zip(launched, finished):
+                status[idx] = o_st[slots]
+                rem_o[idx] = o_rem[slots]
+                rst_o[idx] = o_rst[slots]
+                lim_o[idx] = o_lim[slots]
+                werr = o_err[slots]
+                if werr.any():
+                    err_idx.extend(idx[werr].tolist())
+            if ood is not None:
+                # out-of-domain rows rode invalid (never erred, never
+                # cold): unservable, the shape a full probe window has
+                full[ood] = True
+        # the re-dispatches below run check_packed, phases and all
         if err_idx:
             import contextlib
 
@@ -671,10 +691,7 @@ class ShardedEngine:
         placement that is identical anyway.  Multi-shard meshes keep
         the explicit sharded put — there it is what makes each device
         receive 1/n of the bytes instead of a full replica."""
-        with XLA_EXEC_MU:
-            # process-wide execute gate (mesh.py): cross-ENGINE
-            # concurrent executions wedge this image's XLA:CPU; the
-            # per-instance engine lock can't see other instances
+        with exec_gate():
             if self.n > 1:
                 a64 = jax.device_put(a64, self._mat_sharding)
                 a32 = jax.device_put(a32, self._mat_sharding)
@@ -690,6 +707,10 @@ class ShardedEngine:
         """Block on a launched wave's outputs (1 download) and fold its
         counters.  Returns (status, remaining, reset, limit, table_full)
         host arrays in [n·Bw] block order."""
+        with phase("wave.sync"):
+            return self._download_wave(packed, counters)
+
+    def _download_wave(self, packed, counters):
         out = np.asarray(packed)
         self.over_count += int(counters[0])
         self.insert_count += int(counters[1])
@@ -850,28 +871,32 @@ class ShardedEngine:
         # a state fork); ride the wave invalid and serve from the cold
         # tier in the resolve below.  Mesh-pinned rows (mslot >= 0) are
         # never cold: the pin seed pops the cold copy.
-        tier = self.tier
-        cold_mask = None
-        orig_valid = None
-        if tier is not None:
-            kh = np.asarray(khash)
-            orig_valid = np.asarray(batch.valid) & (kh != 0)
-            cold_mask = tier.resident_mask(kh) & orig_valid
-            if mslot is not None:
-                cold_mask &= np.asarray(mslot) < 0
-            if cold_mask.any():
-                batch = batch._replace(
-                    valid=np.asarray(batch.valid) & ~cold_mask)
-        # earliest requests take the earliest waves: same-key requests
-        # split across waves then apply in arrival-time order (within a
-        # wave the device's (row, now) sort handles it)
-        pending = self._arrival_order(batch)
+        with phase("wave.route"):
+            tier = self.tier
+            cold_mask = None
+            orig_valid = None
+            if tier is not None:
+                kh = np.asarray(khash)
+                orig_valid = np.asarray(batch.valid) & (kh != 0)
+                cold_mask = tier.resident_mask(kh) & orig_valid
+                if mslot is not None:
+                    cold_mask &= np.asarray(mslot) < 0
+                if cold_mask.any():
+                    batch = batch._replace(
+                        valid=np.asarray(batch.valid) & ~cold_mask)
+            # earliest requests take the earliest waves: same-key
+            # requests split across waves then apply in arrival-time
+            # order (within a wave the device's (row, now) sort handles
+            # it)
+            pending = self._arrival_order(batch)
+            waves = self._build_waves(khash, pending)
         retried = False
         while len(pending):
             err_idx: List[int] = []
-            for idx, slots, bw_w in self._build_waves(khash, pending):
-                a64, a32, lease, mblk = self._fill_packed(
-                    batch, idx, slots, bw_w, mslot)
+            for idx, slots, bw_w in waves:
+                with phase("wave.fill"):
+                    a64, a32, lease, mblk = self._fill_packed(
+                        batch, idx, slots, bw_w, mslot)
                 try:
                     # see launch_packed: 3-arg call when no mesh lane
                     launched = (
@@ -882,13 +907,14 @@ class ShardedEngine:
                     lease.release()  # launch copied the host operands
                 o_st, o_rem, o_rst, o_lim, o_err = self._finish_wave(
                     *launched)
-                status[idx] = o_st[slots]
-                rem_o[idx] = o_rem[slots]
-                rst_o[idx] = o_rst[slots]
-                lim_o[idx] = o_lim[slots]
-                werr = o_err[slots]
-                if werr.any():
-                    err_idx.extend(idx[werr].tolist())
+                with phase("wave.scatter"):
+                    status[idx] = o_st[slots]
+                    rem_o[idx] = o_rem[slots]
+                    rst_o[idx] = o_rst[slots]
+                    lim_o[idx] = o_lim[slots]
+                    werr = o_err[slots]
+                    if werr.any():
+                        err_idx.extend(idx[werr].tolist())
             if err_idx and not retried:
                 # probe windows clogged with expired rows: sweep once and
                 # retry those requests (check_batch does the same)
@@ -905,6 +931,9 @@ class ShardedEngine:
                     rst_o[i] = 0
                     lim_o[i] = 0
                 pending = np.empty(0, np.int64)
+            if len(pending):
+                with phase("wave.route"):
+                    waves = self._build_waves(khash, pending)
         if tier is not None:
             # cold lane: pre-masked cold-resident rows plus residual
             # table-full rows (brand-new keys, device table saturated —

@@ -40,7 +40,7 @@ from .config import BehaviorConfig
 from .grpc_api import PeersV1Stub, dial_peer, raw_unary
 from .proto import gubernator_pb2 as pb
 from .proto import peers_pb2 as peers_pb
-from .tracing import outbound_metadata
+from .tracing import outbound_metadata, phase
 from .types import Behavior, PeerInfo, RateLimitRequest, RateLimitResponse
 from .wire import req_to_pb, resp_from_pb
 
@@ -225,7 +225,9 @@ class _SendLane:
             self._fail(entries, ErrCircuitOpen(
                 f"peer {client.info.grpc_address} circuit open"))
             return
-        t0 = time.perf_counter()
+        # the forward hop's share of a request's wall time; ended by
+        # whichever thread the RPC completes on
+        flush = phase("peer_flush", client._analytics, span=False).begin()
         try:
             # faultpoint: a chaos run failing/delaying this peer's
             # sends lands here — same handling as a real dial failure
@@ -236,7 +238,7 @@ class _SendLane:
             rpc = call.future(data, timeout=self.rpc_timeout_s,
                               metadata=md)
         except Exception as e:  # noqa: BLE001 - incl. closed channel
-            self._on_done(None, entries, data, attempt, t0, err=e)
+            self._on_done(None, entries, data, attempt, flush, err=e)
             return
         with self._cond:
             self._inflight += 1
@@ -245,9 +247,9 @@ class _SendLane:
             m.peer_inflight_rpcs.labels(
                 peer_addr=client.info.grpc_address).inc()
         rpc.add_done_callback(
-            lambda f: self._rpc_done(f, entries, data, attempt, t0))
+            lambda f: self._rpc_done(f, entries, data, attempt, flush))
 
-    def _rpc_done(self, f, entries, data, attempt, t0) -> None:
+    def _rpc_done(self, f, entries, data, attempt, flush) -> None:
         """grpc callback thread: resolve futures OFF the flusher so it
         keeps packing the next flush while responses land."""
         with self._cond:
@@ -263,21 +265,19 @@ class _SendLane:
             # succeeded (tests the retry path's idempotence)
             self.client._fault("peer_recv")
         except Exception as e:  # noqa: BLE001 - RpcError et al.
-            self._on_done(None, entries, data, attempt, t0, err=e)
+            self._on_done(None, entries, data, attempt, flush, err=e)
             return
-        self._on_done(rbytes, entries, data, attempt, t0)
+        self._on_done(rbytes, entries, data, attempt, flush)
 
-    def _on_done(self, rbytes, entries, data, attempt, t0,
+    def _on_done(self, rbytes, entries, data, attempt, flush,
                  err: Optional[BaseException] = None) -> None:
         client = self.client
         m = client._metrics
-        dt = time.perf_counter() - t0
+        dt = flush.end()
         if m is not None:
             m.batch_send_duration.labels(
                 peer_addr=client.info.grpc_address).observe(dt)
         if client._analytics is not None:
-            # the forward hop's share of a request's wall time
-            client._analytics.observe_phase("peer_flush", dt)
             if err is None:
                 # cost-model sample (ISSUE 11): one point-to-point hop
                 # of len(data) wire bytes (failed sends excluded — a
@@ -754,7 +754,7 @@ class PeerClient:
                 self._flush(batch)
 
     def _flush(self, batch: List[tuple]) -> None:
-        t0 = time.perf_counter()
+        flush = phase("peer_flush", self._analytics, span=False).begin()
         try:
             tp = next((t for _, _, t in batch if t), None)
             resps = self.get_peer_rate_limits([r for r, _, _ in batch],
@@ -776,12 +776,10 @@ class PeerClient:
                 if not fut.done():
                     fut.set_exception(e)
         finally:
-            dt = time.perf_counter() - t0
+            dt = flush.end()
             if self._metrics is not None:
                 self._metrics.batch_send_duration.labels(
                     peer_addr=self.info.grpc_address).observe(dt)
-            if self._analytics is not None:
-                self._analytics.observe_phase("peer_flush", dt)
 
     # ---- lifecycle -----------------------------------------------------
 

@@ -4,12 +4,16 @@ The reference grew OpenTelemetry spans around handlers (otelgrpc
 interceptors in daemon.go, span-per-request in gubernator.go —
 version-dependent).  Here:
 
-- ``span(name)`` wraps host-side sections; if the ``opentelemetry``
-  SDK is installed it emits real OTEL spans, otherwise it degrades to
-  a no-op that still feeds the prometheus duration histogram.
+- ``phase(name)`` is the ONE way to time a section of the program
+  (ISSUE 24): a ``jax.profiler.TraceAnnotation`` for its duration (so
+  the section lands in any profile of the process, on the device
+  trace's clock), a sample in ``gubernator_phase_duration{phase}`` and
+  the PhaseLedger (``/debug/phases``), and — inside a recorded trace —
+  a span with the real start and end.  ``span(name)`` is the same
+  primitive for the handler entries (``gubernator_func_duration``).
 - ``SpanRecorder`` (ISSUE 12) keeps the structure: when a request
-  context is armed with a recorder, every ``span()`` — and the
-  dispatcher's wave spans — lands in a bounded per-daemon ring,
+  context is armed with a recorder, every ``phase()``/``span()`` — and
+  the dispatcher's wave spans — lands in a bounded per-daemon ring,
   head-sampled at ``GUBER_TRACE_SAMPLE`` with forced sampling on
   error/degraded/shed outcomes.  ``GET /debug/traces`` exports the
   ring; ``assemble()``/``render_waterfall()`` stitch per-daemon
@@ -30,14 +34,6 @@ from typing import Dict, Iterator, List, Optional
 
 log = logging.getLogger("gubernator_tpu.tracing")
 
-try:  # pragma: no cover - OTEL not in this image; degrade gracefully
-    from opentelemetry import trace as _otel_trace
-
-    _tracer = _otel_trace.get_tracer("gubernator_tpu")
-except ImportError:
-    _tracer = None
-
-
 # --- W3C trace-context propagation (traceparent in/out) ---------------
 #
 # The reference wires otelgrpc server/client interceptors (daemon.go),
@@ -46,14 +42,27 @@ except ImportError:
 # ("00-<32hex trace-id>-<16hex parent-span-id>-<2hex flags>"), so we
 # parse/generate it natively and carry the active trace in a
 # thread-local — servicers adopt the inbound header, and every peer
-# call (pb2 or raw wire) re-emits it with a fresh span id.  When the
-# OTEL SDK is present the `span()` context manager still opens real
-# spans on top.
+# call (pb2 or raw wire) re-emits it with a fresh span id.
 
 import secrets
 import threading
 
-_tls = threading.local()
+
+
+class _ThreadState(threading.local):
+    """Per-thread tracing state.  The defaults live on the class, so a
+    read on a thread that never set one is a plain attribute hit (a
+    miss on a bare ``threading.local`` costs an exception: ~0.5 µs, and
+    ``phase`` reads five)."""
+
+    trace = None    # (trace_id, flags) of the active request
+    span = None     # _SpanState of the active request
+    wave = None     # enclosing WaveScope
+    cursor = None   # partition_thread(): end of the last phase
+    gap = 0.0       # partition_thread(): seconds between phases
+
+
+_tls = _ThreadState()
 
 #: Test/diagnostic hook: called with the RAW inbound traceparent header
 #: (or None) each time a request context is adopted.
@@ -94,14 +103,14 @@ def current_trace_id() -> Optional[str]:
     request context.  Cheap enough for hot-path capture (the flight
     recorder and dispatcher jobs stamp it at submit time — worker
     threads have no request context of their own)."""
-    tp = getattr(_tls, "trace", None)
+    tp = _tls.trace
     return tp[0] if tp is not None else None
 
 
 def current_traceparent() -> Optional[str]:
     """Outbound header for the active request's trace (fresh span id
     per hop), or None outside any request context."""
-    tp = getattr(_tls, "trace", None)
+    tp = _tls.trace
     if tp is None:
         return None
     tid, flags = tp
@@ -120,7 +129,9 @@ def current_traceparent() -> Optional[str]:
 # outcomes so the interesting requests survive even at sample=0.
 
 #: span-name catalog (linted against OBSERVABILITY.md by
-#: tools/check_metrics.py, like slo.SLO_CATALOG)
+#: tools/check_metrics.py, like slo.SLO_CATALOG).  A phase() recorded
+#: inside a trace is a span under its PHASE_CATALOG name; the names
+#: here are the spans that are not phases.
 SPAN_CATALOG: Dict[str, str] = {
     "grpc.GetRateLimits": "public V1 handler (pb2 and raw-wire twins)",
     "grpc.GetPeerRateLimits": "owner-side peer handler (pb2 and wire)",
@@ -129,10 +140,61 @@ SPAN_CATALOG: Dict[str, str] = {
     "peer.forward": "caller-side hop: batched forward lane send",
     "global.hits_flush": "async GLOBAL hit-flush tick (owner-bound)",
     "global.broadcast": "async GLOBAL broadcast tick (replica-bound)",
-    "wave": "one dispatcher wave (fan-in over the batched jobs)",
-    "wave.pack": "host pack phase (absent when the engine fuses it)",
-    "wave.device": "device step phase",
-    "wave.resolve": "host resolve/demux phase",
+    "wave": "one dispatcher wave (fan-in over the batched jobs); its "
+            "children are the wave.* / lock.* phases that ran for it",
+}
+
+#: phase-name catalog: every name the program hands to ``phase()``
+#: (linted both ways against the code's literals and OBSERVABILITY.md's
+#: phase table by tools/guberlint/docs.py).  name → site.
+PHASE_CATALOG: Dict[str, str] = {
+    # coarse in-wave partition of gubernator_dispatcher_wave_duration
+    "pack": "dispatcher: wave begin → launch returned (engine call "
+            "entered, for unpipelined waves)",
+    "device": "dispatcher: launch returned → results on the host; "
+              "IN-FLIGHT time, not device-busy time",
+    "resolve": "dispatcher: results on the host → wave end",
+    "queue_wait": "dispatcher: a job's wait from submit to its wave",
+    # the dispatch worker's wall time, partitioned (inline and
+    # unpipelined waves run the wave.*/lock.* ones in their own thread)
+    "worker.wait": "_drain_wave: blocked on an empty queue",
+    "worker.coalesce": "_drain_wave: first job → wave returned",
+    "worker.gap": "the dispatch worker BETWEEN two of its phases: "
+                  "glue, and waiting to get the GIL back",
+    "wave.begin": "_wave_begin: telemetry, per-job waits, event",
+    "wave.concat": "column concat of the merged jobs (+ mslot, now)",
+    "lock.engine": "waiting to acquire the engine lock",
+    "wave.route": "engine: domain mask, tier mask, arrival order, "
+                  "_build_waves",
+    "wave.fill": "engine: _fill_packed into the leased upload buffers",
+    "lock.xla_exec": "engine: waiting to acquire XLA_EXEC_MU",
+    "lock.mesh_state": "mesh-GLOBAL tier: a fused launch waiting for "
+                       "the tier's state lock (fold tick, pins)",
+    "wave.dispatch": "engine: device_puts + the jit call, until it "
+                     "returns",
+    "wave.sync": "engine: _finish_wave, blocked on the device and the "
+                 "download",
+    "wave.scatter": "engine: result scatter into request order "
+                    "(+ retry / cold rows / out-of-domain merge)",
+    "wave.resolve": "dispatcher: the future.set_result loop",
+    "wave.end": "_wave_end + the analytics tap",
+    # handler threads, per call
+    "handler": "instance.get_rate_limits_wire, whole, wall and CPU "
+               "(mesh-GLOBAL mode only)",
+    "ingest": "wire parse / fused prepack (bytes → columns)",
+    "build": "response wire-byte serialization",
+    "call.wait": "handler blocked on its wave's future (queue wait + "
+                 "wave, from the caller's side)",
+    "route.pack": "_wire_mesh_runner: mix64 + pack_columns + masks",
+    "route.keys": "_wire_mesh_runner: per-distinct-key config loop",
+    "route.pin": "_wire_mesh_runner: pin_many + seed commit / admit",
+    "route.slots": "_wire_mesh_runner: slot-map copy + mslot column",
+    # background
+    "peer_flush": "peer send lanes: forward-hop flush round trip",
+    "broadcast": "GLOBAL owner tick: one broadcast pass",
+    "snapshot": "Loader save blackout",
+    "restore": "Loader load blackout",
+    "global_fold": "mesh-GLOBAL reconcile tick (swap + fold launch)",
 }
 
 
@@ -257,16 +319,18 @@ class SpanRecorder:
 
 class _SpanState:
     """Per-request span bookkeeping (thread-local): the recorder, the
-    open-span stack, the inbound parent id, and the forced-sample
-    verdict."""
+    open-span stack, the inbound parent id, the head-sampling decision
+    (a pure function of the trace id) and the forced-sample verdict."""
 
-    __slots__ = ("recorder", "trace_id", "parent", "stack", "forced")
+    __slots__ = ("recorder", "trace_id", "parent", "stack", "sampled",
+                 "forced")
 
     def __init__(self, recorder, trace_id, parent):
         self.recorder = recorder
         self.trace_id = trace_id
         self.parent = parent
         self.stack: List[str] = []
+        self.sampled = recorder.head_sampled(trace_id)
         self.forced: Optional[str] = None
 
 
@@ -278,7 +342,7 @@ def current_span_id() -> Optional[str]:
     """The innermost open recorded span's id (the wave's parent when
     launched from a request thread), or None when the span plane is
     not armed here."""
-    st = getattr(_tls, "span", None)
+    st = _tls.span
     if st is None:
         return None
     return st.stack[-1] if st.stack else st.parent
@@ -287,7 +351,7 @@ def current_span_id() -> Optional[str]:
 def force_sample(reason: str) -> None:
     """Flag the active trace for forced sampling (error / degraded /
     shed outcomes must survive even at sample=0).  First reason wins."""
-    st = getattr(_tls, "span", None)
+    st = _tls.span
     if st is not None and st.forced is None:
         st.forced = reason
 
@@ -298,12 +362,12 @@ def hop_traceparent(name: str, attrs: Optional[dict] = None
     an instant span whose span id IS the minted parent id — the
     receiving daemon's request span then parents under it, stitching
     owner-side work back to this request (ISSUE 12)."""
-    tp = getattr(_tls, "trace", None)
+    tp = _tls.trace
     if tp is None:
         return None
     tid, flags = tp
     sid = secrets.token_hex(8)
-    st = getattr(_tls, "span", None)
+    st = _tls.span
     if st is not None and st.trace_id == tid:
         now = time.time()  # clock-ok: telemetry wall clock (span timestamps)
         st.recorder.add({
@@ -326,11 +390,11 @@ def request_context(traceparent: Optional[str],
     if inbound_hook is not None:
         inbound_hook(traceparent)
     parsed = parse_traceparent(traceparent)
-    prev = getattr(_tls, "trace", None)
+    prev = _tls.trace
     _tls.trace = parsed or (secrets.token_hex(16), "01")
     st = prev_st = None
     if recorder is not None:
-        prev_st = getattr(_tls, "span", None)
+        prev_st = _tls.span
         st = _SpanState(recorder, _tls.trace[0],
                         parent_span_id(traceparent))
         _tls.span = st
@@ -367,45 +431,273 @@ def outbound_metadata(extra=()):
     return md or None
 
 
-@contextlib.contextmanager
-def span(name: str, metrics=None, attrs: Optional[dict] = None
-         ) -> Iterator[None]:
-    """Host-side span: OTEL when available, always a duration metric —
-    including on the error path (try/finally).  When the request
+# --- the one timing primitive (ISSUE 24) --------------------------------
+
+_annotation = None
+
+
+def _trace_annotation():
+    """jax.profiler.TraceAnnotation, imported on first use: client.py
+    and peer_client.py import this module and must not pull JAX in."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+def partition_thread() -> None:
+    """Declare that ALL of the calling thread's time from here on
+    belongs to phases (the dispatch worker: it waits for work or works
+    on a wave, nothing else).  What passes BETWEEN two of its phases —
+    glue, the phases' own bookkeeping, and above all waiting to get
+    the GIL back from 32 handler threads — is then summed up as the
+    thread's gap (``take_gap``), so that phases + gap partition the
+    thread's wall time.  Phases given an explicit ``at=`` neither read
+    nor move the cursor."""
+    _tls.cursor = time.perf_counter()
+    _tls.gap = 0.0
+
+
+def take_gap() -> float:
+    """Seconds the calling partition thread has spent between phases
+    since the last call (0.0 on any other thread)."""
+    gap = _tls.gap
+    if gap:
+        _tls.gap = 0.0
+    return gap
+
+
+class WaveScope:
+    """One dispatcher wave as the enclosing scope of the ``phase()``s
+    that run for it, in whichever thread runs them (the dispatch
+    worker has no request context; a pipelined wave is entered twice,
+    for its launch and for its sync).  Carries the phase sink for
+    engine code, which knows neither the dispatcher nor the wave, and
+    — when the wave's trace is recorded — the ids its children parent
+    under (``children``: the trace is head-sampled, so the wave's
+    phases are recorded as its child spans).  ``finish()`` hands over
+    the wave span's attributes; the span is recorded when the scope
+    exits, so every child lies inside it on the same clock.  ``cpu``:
+    this wave is one of the few whose phases also record thread CPU
+    time (the dispatcher samples 1 in ``Dispatcher.CPU_SAMPLE``)."""
+
+    __slots__ = ("sink", "cpu", "recorder", "trace_id", "span_id",
+                 "parent_id", "wave_id", "children", "start_ns", "attrs",
+                 "_prev")
+
+    def __init__(self, sink, cpu: bool = False):
+        self.sink = sink
+        self.cpu = cpu
+        self.recorder = self.trace_id = self.span_id = None
+        self.parent_id = self.wave_id = self.attrs = None
+        self.children = False
+        self.start_ns = time.time_ns()  # clock-ok: telemetry wall clock (span start)
+
+    def bind(self, recorder, trace_id, span_id, parent_id, wave_id) -> None:
+        self.recorder, self.trace_id = recorder, trace_id
+        self.span_id, self.parent_id = span_id, parent_id
+        self.wave_id = wave_id
+        self.children = recorder.head_sampled(trace_id)
+
+    def finish(self, attrs: dict) -> None:
+        self.attrs = attrs
+
+    def __enter__(self) -> "WaveScope":
+        self._prev = _tls.wave
+        _tls.wave = self
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _tls.wave = self._prev
+        attrs, self.attrs = self.attrs, None
+        if attrs is not None and self.span_id is not None:
+            self.recorder.add({
+                "trace_id": self.trace_id, "span_id": self.span_id,
+                "parent_id": self.parent_id, "name": "wave",
+                "start": self.start_ns / 1e9,
+                "end": time.time_ns() / 1e9,  # clock-ok: telemetry wall clock (span end)
+                "attrs": attrs})
+
+
+class phase:
+    """Time one section of the program (catalog: ``PHASE_CATALOG``).
+
+    ``with phase(name, sink):`` — or ``p = phase(...).begin()`` …
+    ``p.end()`` where a ``with`` does not fit the control flow.  Every
+    use (a) holds a ``jax.profiler.TraceAnnotation(name)`` open, so the
+    section shows in any profile of the process on the device trace's
+    clock; (b) hands its wall seconds — and, with ``cpu=True``, the
+    thread's CPU seconds over the same boundaries — to
+    ``sink.observe_phase`` (``gubernator_phase_duration{phase}`` and
+    the PhaseLedger); (c) inside a wave or request whose trace the
+    SpanRecorder keeps (head-sampled, or already forced), adds a span
+    with the real start and end, parented under that wave or request
+    span.  ``sink`` defaults to the enclosing WaveScope's.
+
+    ``begin(at=)`` / ``end(at=)`` take a tick the caller already read
+    (two phases that share a boundary share the reading, so they
+    partition exactly); ``end(keep=False)`` closes the section without
+    a sample (nothing was done in it).  ``span=False`` keeps a phase
+    that overlaps its siblings out of the span tree; ``always=True``
+    records the span whatever the sampling decision, for the commit to
+    decide (``span()``: the handler entries).  ``cpu=True`` costs two
+    ``time.thread_time()`` calls — a real system call, ~6 µs each on
+    the chip's host and dearer under load — so it is for per-call
+    phases whose wall − CPU split decides something, and for the
+    phases of the waves the dispatcher samples (``WaveScope.cpu``).
+    The annotation is made only while a profile records.
+
+    ``every=n`` times 1 use in n of this name and lets the others
+    through untouched (a per-call phase on a path that serves hundreds
+    of one-request calls a second: each timed use costs ~3 µs of a
+    GIL those calls are bound by).  Means stay true; SUMS do not — so
+    only for phases nobody adds up."""
+
+    __slots__ = ("name", "sink", "_cpu", "_span", "_always", "_attrs",
+                 "_ann", "_t0", "t1", "_c0", "_ns0", "_st", "_sid",
+                 "_parent", "_skip")
+
+    #: name → uses so far, for ``every=`` (racy on purpose: any 1 in
+    #: ~n will do)
+    _uses: Dict[str, int] = {}
+
+    def __init__(self, name: str, sink=None, *, cpu: bool = False,
+                 span: bool = True, always: bool = False,
+                 attrs: Optional[dict] = None, every: int = 1):
+        self.name = name
+        self.sink = sink
+        self._cpu = cpu
+        self._span = span
+        self._always = always
+        self._attrs = attrs
+        self._skip = False
+        if every > 1:
+            n = phase._uses[name] = phase._uses.get(name, 0) + 1
+            self._skip = n % every != 0
+
+    def begin(self, at: Optional[float] = None) -> "phase":
+        if self._skip:
+            return self
+        cls = _annotation or _trace_annotation()
+        if cls.is_enabled():  # a profile is recording
+            ann = self._ann = cls(self.name)
+            ann.__enter__()
+        else:
+            self._ann = None
+        self._st = None
+        self._ns0 = 0
+        if self._span:
+            scope = _tls.wave
+            if scope is not None:
+                if scope.cpu:
+                    self._cpu = True
+                if scope.children or scope.span_id is None:
+                    self._ns0 = time.time_ns()  # clock-ok: telemetry wall clock (span start)
+            else:
+                st = _tls.span
+                if st is not None and (self._always or st.sampled
+                                       or st.forced is not None):
+                    # request thread: nest like any span
+                    self._st = st
+                    self._sid = secrets.token_hex(8)
+                    self._parent = (st.stack[-1] if st.stack
+                                    else st.parent)
+                    st.stack.append(self._sid)
+                    self._ns0 = time.time_ns()  # clock-ok: telemetry wall clock (span start)
+        if self._cpu:
+            self._c0 = time.thread_time()
+        if at is None:
+            at = time.perf_counter()
+            cur = _tls.cursor
+            if cur is not None:
+                _tls.gap += at - cur
+        self._t0 = at
+        return self
+
+    def end(self, at: Optional[float] = None, keep: bool = True,
+            exemplar=None) -> float:
+        """Close the section; returns its wall seconds (0.0 for a use
+        that ``every=`` let through untimed)."""
+        if self._skip:
+            return 0.0
+        if at is None:
+            at = time.perf_counter()
+            if _tls.cursor is not None:
+                _tls.cursor = at
+        self.t1 = at
+        cpu = (time.thread_time() - self._c0) if self._cpu else None
+        ns0 = self._ns0
+        ns1 = time.time_ns() if ns0 else 0  # clock-ok: telemetry wall clock (span end)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        dt = at - self._t0
+        if dt < 0.0:
+            dt = 0.0
+        st = self._st
+        if st is not None:
+            if st.stack and st.stack[-1] == self._sid:
+                st.stack.pop()
+        if not keep:
+            return dt
+        sink = self.sink
+        if sink is None:
+            scope = _tls.wave
+            if scope is not None:
+                sink = scope.sink
+        if sink is not None:
+            sink.observe_phase(self.name, dt, cpu, exemplar)
+        if ns0:
+            if st is not None:
+                st.recorder.add({
+                    "trace_id": st.trace_id, "span_id": self._sid,
+                    "parent_id": self._parent, "name": self.name,
+                    "start": ns0 / 1e9, "end": ns1 / 1e9,
+                    "attrs": dict(self._attrs) if self._attrs else {}})
+            else:
+                scope = _tls.wave
+                if scope is not None and scope.children:
+                    scope.recorder.add({
+                        "trace_id": scope.trace_id,
+                        "span_id": secrets.token_hex(8),
+                        "parent_id": scope.span_id, "name": self.name,
+                        "start": ns0 / 1e9, "end": ns1 / 1e9,
+                        "attrs": {"wave": scope.wave_id}})
+        return dt
+
+    __enter__ = begin
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if exc_type is not None:
+            st = _tls.span
+            if st is not None and st.forced is None:
+                # an exception in the body force-samples the whole trace
+                st.forced = "error"
+        self.end()
+
+
+class _FuncDuration:
+    """``span()``'s sink: gubernator_func_duration{name}."""
+
+    __slots__ = ("metrics",)
+
+    def __init__(self, metrics):
+        self.metrics = metrics
+
+    def observe_phase(self, name, seconds, cpu=None, exemplar=None):
+        self.metrics.func_duration.labels(name=name).observe(seconds)
+
+
+def span(name: str, metrics=None, attrs: Optional[dict] = None) -> phase:
+    """A handler entry's span (catalog: ``SPAN_CATALOG``): ``phase()``
+    with ``gubernator_func_duration{name}`` as its histogram — always a
+    duration metric, including on the error path.  When the request
     context armed a SpanRecorder, the span is RECORDED: fresh span id,
     parented under the innermost open span (or the inbound hop), and
     an exception in the body force-samples the whole trace."""
-    t0 = time.perf_counter()
-    st = getattr(_tls, "span", None)
-    sid = parent = None
-    wall0 = 0.0
-    if st is not None:
-        sid = secrets.token_hex(8)
-        parent = st.stack[-1] if st.stack else st.parent
-        wall0 = time.time()  # clock-ok: telemetry wall clock (span start)
-        st.stack.append(sid)
-    try:
-        if _tracer is not None:  # pragma: no cover
-            with _tracer.start_as_current_span(name):
-                yield
-        else:
-            yield
-    except BaseException:
-        if st is not None and st.forced is None:
-            st.forced = "error"
-        raise
-    finally:
-        dt = time.perf_counter() - t0
-        if st is not None:
-            if st.stack and st.stack[-1] == sid:
-                st.stack.pop()
-            st.recorder.add({
-                "trace_id": st.trace_id, "span_id": sid,
-                "parent_id": parent, "name": name,
-                "start": wall0, "end": wall0 + dt,
-                "attrs": dict(attrs) if attrs else {}})
-        if metrics is not None:
-            metrics.func_duration.labels(name=name).observe(dt)
+    return phase(name, _FuncDuration(metrics) if metrics is not None
+                 else None, always=True, attrs=attrs)
 
 
 # --- cross-daemon assembly (ISSUE 12) ---------------------------------
@@ -503,12 +795,3 @@ class DeviceProfiler:
             import jax
 
             jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def step_annotation(name: str) -> Iterator[None]:
-    """Named region visible in device traces (jax.profiler.TraceAnnotation)."""
-    import jax
-
-    with jax.profiler.TraceAnnotation(name):
-        yield

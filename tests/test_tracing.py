@@ -12,8 +12,8 @@ import pytest
 from gubernator_tpu import tracing
 from gubernator_tpu.metrics import Metrics
 from gubernator_tpu.tracing import (DeviceProfiler, current_traceparent,
-                                    parse_traceparent, request_context,
-                                    span, step_annotation)
+                                    parse_traceparent, phase,
+                                    request_context, span)
 
 
 def test_span_records_duration_metric():
@@ -29,11 +29,30 @@ def test_span_noop_without_metrics():
         pass  # must not raise even with no OTEL installed
 
 
-def test_step_annotation_wraps_device_work():
+def test_phase_wraps_device_work():
+    """The one timing primitive round device work: the body runs, the
+    sink gets the section's wall seconds (and its thread CPU seconds
+    when asked), and ``end()`` returns them."""
     import jax.numpy as jnp
 
-    with step_annotation("unit-test-step"):
+    class Sink:
+        got = []
+
+        def observe_phase(self, name, seconds, cpu=None, exemplar=None):
+            self.got.append((name, seconds, cpu))
+
+    with phase("wave.dispatch", Sink()):
         assert int(jnp.arange(4).sum()) == 6
+    p = phase("route.keys", Sink(), cpu=True).begin()
+    sum(range(20000))
+    dt = p.end()
+    (n0, s0, c0), (n1, s1, c1) = Sink.got
+    assert (n0, n1) == ("wave.dispatch", "route.keys")
+    assert s0 > 0 and c0 is None
+    assert s1 == dt > 0 and 0 < c1 <= s1 * 1.5 + 1e-3
+    # a section in which nothing was done leaves no sample
+    phase("ingest", Sink()).begin().end(keep=False)
+    assert len(Sink.got) == 2
 
 
 def test_device_profiler_writes_trace(tmp_path):

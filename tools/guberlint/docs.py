@@ -28,7 +28,12 @@ declarative catalogs:
 8. OBSERVABILITY.md's "Span catalog" table matches
    tracing.SPAN_CATALOG both ways — same contract for the trace
    plane: a span an operator meets in a waterfall must be in the doc,
-   and a doc row must name a span the code can actually emit.
+   and a doc row must name a span the code can actually emit;
+9. tracing.PHASE_CATALOG matches, both ways, the literal names handed
+   to ``phase(...)`` anywhere under gubernator_tpu/ AND the first
+   column of OBSERVABILITY.md's "Phase catalog" table — a phase is a
+   metric label, a /debug/phases row, a profile annotation and a span
+   name at once, so an uncatalogued one is invisible four times over.
 """
 from __future__ import annotations
 
@@ -178,6 +183,51 @@ def span_catalog_doc_problems() -> list:
     return problems
 
 
+#: literal phase names at ``tracing.phase`` call sites
+_PHASE_RX = re.compile(r"\bphase\(\s*[\"']([a-z_.]+)[\"']")
+
+
+def emitted_phase_names(pkg_dir: str) -> set:
+    names = set()
+    for root, _dirs, files in os.walk(pkg_dir):
+        for fn in files:
+            if fn.endswith(".py"):
+                with open(os.path.join(root, fn), encoding="utf-8") as f:
+                    names.update(_PHASE_RX.findall(f.read()))
+    return names
+
+
+def phase_catalog_doc_problems() -> list:
+    """tracing.PHASE_CATALOG ↔ ``phase("…")`` literals in the package
+    ↔ OBSERVABILITY.md's phase-catalog table."""
+    from gubernator_tpu.tracing import PHASE_CATALOG
+
+    with open(DOC, encoding="utf-8") as f:
+        doc = f.read()
+    documented = _table_cell_names(doc, "#### Phase catalog",
+                                   r"`([a-z][a-z_.]*)`")
+    emitted = emitted_phase_names(os.path.join(REPO, "gubernator_tpu"))
+    cat = set(PHASE_CATALOG)
+    problems = []
+    for name in sorted(emitted - cat):
+        problems.append(
+            f"phase {name!r} is handed to tracing.phase() but missing "
+            f"from tracing.PHASE_CATALOG")
+    for name in sorted(cat - emitted):
+        problems.append(
+            f"tracing.PHASE_CATALOG lists phase {name!r} but no "
+            f"phase({name!r}) call emits it")
+    for name in sorted(cat - documented):
+        problems.append(
+            f"phase {name!r} is in tracing.PHASE_CATALOG but missing "
+            f"from OBSERVABILITY.md's phase catalog table")
+    for name in sorted(documented - cat):
+        problems.append(
+            f"OBSERVABILITY.md's phase catalog table documents phase "
+            f"{name!r} but tracing.PHASE_CATALOG has no such phase")
+    return problems
+
+
 def env_registry_doc_problems() -> list:
     """CONCURRENCY.md's GUBER_* table ↔ config.ENV_REGISTRY, plus its
     lock-hierarchy table ↔ guberlint's LOCK_ORDER."""
@@ -268,6 +318,7 @@ def run(ctx) -> List[Violation]:
         ("CONCURRENCY.md", env_registry_doc_problems),
         ("OBSERVABILITY.md", slo_catalog_doc_problems),
         ("OBSERVABILITY.md", span_catalog_doc_problems),
+        ("OBSERVABILITY.md", phase_catalog_doc_problems),
     )
     out: List[Violation] = []
     for doc_rel, fn in groups:
@@ -284,7 +335,8 @@ def main() -> int:
     problems = (metric_catalog_problems() + faultpoint_doc_problems()
                 + env_registry_doc_problems()
                 + slo_catalog_doc_problems()
-                + span_catalog_doc_problems())
+                + span_catalog_doc_problems()
+                + phase_catalog_doc_problems())
     if problems:
         for p in problems:
             print(f"check_metrics: {p}", file=sys.stderr)

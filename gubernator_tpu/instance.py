@@ -72,6 +72,47 @@ def clock_ms() -> int:
     return time.time_ns() // 1_000_000  # clock-ok: the clock source itself
 
 
+def _group_key_configs(kh: np.ndarray, idx: np.ndarray, batch,
+                       pinned_cfg: dict):
+    """Rows ``idx`` of a packed batch grouped by key in ONE pass over
+    the call's rows, whatever the number of distinct keys (the routing
+    pass ``_wire_mesh_runner`` and ``_wire_global_runner`` share).
+
+    Returns ``(keys, unpinned)`` — the key of each row as a Python int,
+    in row order, and the distinct keys ``pinned_cfg`` does not hold,
+    SORTED (pin order decides slot placement when probe windows
+    collide) — or None where the object path must serve the batch per
+    request: a key whose rows carry more than one config, or a pinned
+    key whose config changed.
+
+    A dict pass over ``tolist()`` columns, not ``np.unique`` and
+    whole-column compares: 32 handlers route on one GIL, and a numpy
+    call that gives it up (``np.unique``, reductions, casts, scatters
+    — gathers and ``tolist()`` do not) has to win it back.  The array
+    version took 10.1 ms a call on the chip's host where this takes
+    2.6 (PERF.md §6, PR 25).  The batch's columns are
+    compared as they are: ``pack_columns`` clamps them exactly as
+    ``clamp_config`` clamped the tier's ``pinned_cfg`` tuples
+    (core/batch.py: "must stay in lockstep";
+    tests/test_mesh_global.py holds the two together)."""
+    keys = kh[idx].tolist()
+    cfgs = zip(*(np.asarray(col)[idx].tolist() for col in (
+        batch.algorithm, batch.limit, batch.duration, batch.burst)))
+    cfg_of: dict = {}
+    for k, cfg in zip(keys, cfgs):
+        if cfg_of.setdefault(k, cfg) != cfg:
+            return None  # mid-batch config change
+    unpinned = []
+    for k, cfg in cfg_of.items():
+        have = pinned_cfg.get(k)
+        if have is None:
+            unpinned.append(k)
+        elif have != cfg:
+            return None  # config changed → demote path
+    unpinned.sort()
+    return keys, unpinned
+
+
 def _forward_fail_reason(e: Optional[BaseException]) -> str:
     """Stable low-cardinality reason label for
     gubernator_forward_failed (ISSUE 5 satellite)."""
@@ -1453,22 +1494,14 @@ class V1Instance:
             if pinned_mask.any():
                 if (pinned_mask & excluded).any():
                     return None  # flagged request on a pinned key
-                # config match, vectorized over the few unique hot keys
-                # (duration compares unfloored, exactly as clamp_config
-                # and pack_columns store it)
-                alg = np.asarray(batch.algorithm)
-                lim = np.asarray(batch.limit)
-                dur = np.asarray(batch.duration)
-                bur = np.asarray(batch.burst)
-                for k in np.unique(kh[pinned_mask]):
-                    cfg = hs.pinned_cfg.get(int(k))
-                    m = pinned_mask & (kh == k)
-                    if cfg is None or not (
-                            (alg[m] == cfg[0]).all()
-                            and (lim[m] == cfg[1]).all()
-                            and (dur[m] == cfg[2]).all()
-                            and (bur[m] == cfg[3]).all()):
-                        return None  # config changed → demote path
+                # config match (duration compares unfloored, exactly
+                # as clamp_config and pack_columns store it); a key
+                # unpinned since the snapshot above takes the object
+                # path too
+                groups = _group_key_configs(
+                    kh, np.nonzero(pinned_mask)[0], batch, hs.pinned_cfg)
+                if groups is None or groups[1]:
+                    return None  # config changed → demote path
                 hot_mask = pinned_mask
         # promotion counting for unpinned qualifying GLOBAL keys
         promo_mask = glob_mask & ~hot_mask & ~excluded & \
@@ -1568,34 +1601,30 @@ class V1Instance:
         pins: List[tuple] = []
         if mesh_mask.any():
             with phase("route.keys", disp, cpu=True):
-                alg = np.asarray(batch.algorithm)
-                lim = np.asarray(batch.limit)
-                dur = np.asarray(batch.duration)
-                bur = np.asarray(batch.burst)
-                hits_col = np.asarray(batch.hits)
-                for k in np.unique(kh[mesh_mask]):
-                    ik = int(k)
-                    m = mesh_mask & (kh == k)
-                    i = int(np.nonzero(m)[0][0])
-                    # one config per key per batch (pinned OR to-pin):
-                    # a mid-batch config change takes the object path,
-                    # which demotes/serves it per request with exact
-                    # semantics
-                    if not ((alg[m] == alg[i]).all()
-                            and (lim[m] == lim[i]).all()
-                            and (dur[m] == dur[i]).all()
-                            and (bur[m] == bur[i]).all()):
-                        return None
-                    proto = RateLimitRequest(
-                        name="", unique_key="", hits=int(hits_col[i]),
-                        limit=int(lim[i]), duration=int(dur[i]),
-                        algorithm=int(alg[i]), behavior=int(beh[i]),
-                        burst=int(bur[i]))
-                    if mge.is_pinned(ik):
-                        if not mge.matches_pinned(ik, proto):
-                            return None  # config changed → demote path
-                    else:
-                        pins.append((proto, ik, self._seed_row(ik)))
+                # one config per key per batch (pinned OR to-pin): a
+                # mid-batch config change, or a pinned key's changed
+                # config, takes the object path, which demotes/serves
+                # it per request with exact semantics
+                idx = np.nonzero(mesh_mask)[0]
+                groups = _group_key_configs(kh, idx, batch,
+                                            mge.pinned_cfg)
+                if groups is None:
+                    return None
+                keys, unpinned = groups
+                # first touches only (never in a warm tier): the pin
+                # adopts the config of the key's first row
+                for k in unpinned:
+                    i = int(idx[keys.index(k)])
+                    pins.append((RateLimitRequest(
+                        name="", unique_key="",
+                        hits=int(batch.hits[i]),
+                        limit=int(batch.limit[i]),
+                        duration=int(batch.duration[i]),
+                        algorithm=int(batch.algorithm[i]),
+                        behavior=int(beh[i]),
+                        burst=int(batch.burst[i])),
+                        k, self._seed_row(k)))
+        refused = set()
         if pins:
             with phase("route.pin", disp, cpu=True):
                 ok = mge.pin_many(pins, now)
@@ -1604,7 +1633,9 @@ class V1Instance:
                         self._seed_commit(ik)
                     elif not self._mesh_admit(proto, ik, now):
                         # window full, nothing colder → sharded path
-                        mesh_mask = mesh_mask & (kh != np.uint64(ik))
+                        refused.add(ik)
+                if refused:
+                    mesh_mask[idx] = [k not in refused for k in keys]
 
         # Fused single-launch path (ISSUE 8): a fused engine serves the
         # WHOLE batch — mesh rows on the home replica + accumulator,
@@ -1615,17 +1646,17 @@ class V1Instance:
         mslot_col = None
         if getattr(self.engine, "mesh_bound", False) and mesh_mask.any():
             with phase("route.slots", disp, cpu=True):
-                mslot_col = np.full(n, -1, np.int32)
                 with mge._mu:
                     smap = dict(mge.slots)
-                for k in np.unique(kh[mesh_mask]):
-                    s = smap.get(int(k))
-                    if s is not None:
-                        mslot_col[mesh_mask & (kh == k)] = s
-                    else:  # unpinned underneath us: sharded is correct
-                        mesh_mask = mesh_mask & (kh != k)
-                if not (mslot_col >= 0).any():
-                    mslot_col = None
+                for k in refused:  # sharded even if pinned since
+                    smap.pop(k, None)
+                # -1: unpinned underneath us — sharded is correct
+                slot_rows = np.fromiter((smap.get(k, -1) for k in keys),
+                                        np.int32, len(keys))
+                mesh_mask[idx] = slot_rows >= 0
+                if mesh_mask.any():
+                    mslot_col = np.full(n, -1, np.int32)
+                    mslot_col[idx] = slot_rows
 
         def run_fused() -> bytes:
             st, lim_o, rem, rst, full = self.dispatcher.check_packed(

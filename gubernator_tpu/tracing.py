@@ -186,9 +186,12 @@ PHASE_CATALOG: Dict[str, str] = {
     "call.wait": "handler blocked on its wave's future (queue wait + "
                  "wave, from the caller's side)",
     "route.pack": "_wire_mesh_runner: mix64 + pack_columns + masks",
-    "route.keys": "_wire_mesh_runner: per-distinct-key config loop",
+    "route.keys": "_wire_mesh_runner: the call's mesh rows grouped by "
+                  "key in one dict pass: one config per key, pinned "
+                  "configs matched (_group_key_configs)",
     "route.pin": "_wire_mesh_runner: pin_many + seed commit / admit",
-    "route.slots": "_wire_mesh_runner: slot-map copy + mslot column",
+    "route.slots": "_wire_mesh_runner: slot-map copy + one lookup a "
+                   "row into the mslot column",
     # background
     "peer_flush": "peer send lanes: forward-hop flush round trip",
     "broadcast": "GLOBAL owner tick: one broadcast pass",

@@ -46,3 +46,32 @@ def cpu_mesh():
     from gubernator_tpu.parallel import make_mesh
 
     return make_mesh(n=4)
+
+
+@pytest.fixture
+def numpy_calls():
+    """Context manager that counts what a block asks of numpy: every
+    numpy function and ndarray method the profiler sees in the calling
+    thread (array operators make no call event and ride with these).
+    ``with numpy_calls() as c: ...; c.n``"""
+    import numpy as np
+
+    class Count:
+        n = 0
+
+        def __enter__(self):
+            def prof(frame, event, arg):
+                if event == "c_call" and (
+                        (getattr(arg, "__module__", None)
+                         or "").startswith("numpy")
+                        or isinstance(getattr(arg, "__self__", None),
+                                      np.ndarray)):
+                    self.n += 1
+
+            sys.setprofile(prof)
+            return self
+
+        def __exit__(self, *exc):
+            sys.setprofile(None)
+
+    return Count

@@ -32,6 +32,7 @@ def ser(reqs):
         q.hits, q.limit, q.duration = r.hits, r.limit, r.duration
         q.behavior = int(r.behavior)
         q.algorithm = int(r.algorithm)
+        q.burst = r.burst
     return m.SerializeToString()
 
 
@@ -307,3 +308,338 @@ def test_sketch_feeds_hotset_promotion(monkeypatch):
         assert inst._hotset is not None and inst._hotset.is_pinned(kh)
     finally:
         inst.close()
+
+
+# ---- ISSUE 25: GLOBAL routing by the call, not by the distinct key -----
+#
+# _wire_mesh_runner groups a call's mesh rows ONCE (instance.py ›
+# _group_key_configs): the answers, the fallbacks and the pins must be
+# what the per-key loops gave, and the work must not grow with the
+# number of distinct keys.
+
+from gubernator_tpu import instance as instance_mod  # noqa: E402
+from gubernator_tpu.core.batch import pack_columns  # noqa: E402
+from gubernator_tpu.instance import _wire_native  # noqa: E402
+from gubernator_tpu.types import Algorithm  # noqa: E402
+from gubernator_tpu.wire import resp_to_pb  # noqa: E402
+
+ROWS = 1000
+
+
+def _mk_big(engine):
+    return V1Instance(Config(
+        cache_size=1 << 14, sweep_interval_ms=0, engine=engine,
+        global_mode="mesh", batch_rows=256,
+        behaviors=BehaviorConfig(global_sync_wait_ms=SYNC_MS)),
+        mesh=make_mesh(n=8))
+
+
+@pytest.fixture(scope="module")
+def big_pair():
+    """(wire, object) instances on the cell's engine (fused, mesh
+    bound: run_fused + the mslot column), shared by the cases below —
+    every case works in a key namespace of its own."""
+    mp = pytest.MonkeyPatch()
+    mp.setenv("GUBER_MESH_GLOBAL_CAP", "4096")
+    wi, oi = _mk_big("pallas"), _mk_big("pallas")
+    mp.undo()  # the tier is built (and bound) at construction
+    assert wi.engine.mesh_bound
+    yield wi, oi
+    wi.close()
+    oi.close()
+
+
+@pytest.fixture(scope="module")
+def xla_inst():
+    """The classic engine: no mslot column, the two-dispatch `run`."""
+    mp = pytest.MonkeyPatch()
+    mp.setenv("GUBER_MESH_GLOBAL_CAP", "4096")
+    inst = _mk_big("xla")
+    mp.undo()
+    assert not getattr(inst.engine, "mesh_bound", False)
+    yield inst
+    inst.close()
+
+
+def empty_tier(*insts):
+    """Hand the tier's slots out afresh (host maps only: a pin writes
+    its whole row, so stale device rows are never read).  The cases
+    below share instances, and 4,096 slots do not hold all their keys."""
+    for inst in insts:
+        mge = inst._meshglobal
+        with mge._mu:
+            for d in (mge.slots, mge.pinned_cfg, mge._retired,
+                      mge._occupied):
+                d.clear()
+
+
+def lane(inst, name):
+    return inst.metrics.wire_lane_counter.labels(lane=name)._value.get()
+
+
+def key_draw(distinct):
+    """Key index per row: `distinct` keys, every one present; 490 is
+    the benchmark cell's own draw (numpy zipf(1.1) % 1024)."""
+    if distinct == 490:
+        ks = np.random.default_rng(25).zipf(1.1, ROWS) % 1024
+        assert 440 <= len(set(ks.tolist())) <= 540
+        return ks.tolist()
+    return [(i * 7919) % distinct for i in range(ROWS)]
+
+
+def call_of(shape, ns):
+    """One 1,000-row call: `g<distinct>` all GLOBAL, `mixed` every
+    other row a local key, `leaky` GLOBAL leaky buckets with a burst."""
+    if shape.startswith("g"):
+        return [greq(f"k{k}", hits=1 + i % 3, name=ns)
+                for i, k in enumerate(key_draw(int(shape[1:])))]
+    if shape == "mixed":
+        return [greq(f"k{k}", hits=1, name=ns) if i % 2 else
+                RateLimitRequest(name=ns, unique_key=f"loc{k % 40}",
+                                 hits=1, limit=7, duration=60_000)
+                for i, k in enumerate(key_draw(490))]
+    assert shape == "leaky"
+    return [greq(f"k{k}", hits=1, name=ns, limit=50 + k % 3, burst=k % 2 * 80,
+                 algorithm=Algorithm.LEAKY_BUCKET)
+            for k in key_draw(12)]
+
+
+def obj_bytes(inst, reqs, now):
+    out = pb.GetRateLimitsResp()
+    out.responses.extend(resp_to_pb(r)
+                         for r in inst.get_rate_limits(reqs, now_ms=now))
+    return out.SerializeToString()
+
+
+@pytest.mark.parametrize("state", ["cold", "warm"])
+@pytest.mark.parametrize("shape", ["g1", "g12", "g490", "g1000", "mixed",
+                                   "leaky"])
+def test_wire_lane_byte_equal_to_object_path(big_pair, shape, state):
+    """The wire lane's bytes equal the object path's for 1,000-row
+    calls, first touch (the call pins its keys) and warm (all pinned)
+    — and the wire lane really served them."""
+    wi, oi = big_pair
+    empty_tier(wi, oi)
+    reqs = call_of(shape, f"eq-{shape}-{state}")
+    data = ser(reqs)
+    now = NOW
+    if state == "warm":
+        # the same first touch on both sides, then compare a warm call
+        assert wi.get_rate_limits_wire(data, now_ms=now) == \
+            oi.get_rate_limits_wire(data, now_ms=now)
+        now += 1
+    n_wire, n_pb2 = lane(wi, "wire_hotset"), lane(wi, "pb2_fallback")
+    got = wi.get_rate_limits_wire(data, now_ms=now)
+    assert got == obj_bytes(oi, reqs, now)
+    assert lane(wi, "wire_hotset") - n_wire == ROWS
+    assert lane(wi, "pb2_fallback") == n_pb2
+    rs = pb.GetRateLimitsResp.FromString(got).responses
+    assert len(rs) == ROWS and all(r.error == "" for r in rs)
+    for inst in (wi, oi):  # every GLOBAL key of the call is pinned
+        mge = inst._meshglobal
+        assert all(mge.is_pinned(hash_key(r.name, r.unique_key))
+                   for r in reqs if r.behavior & Behavior.GLOBAL)
+
+
+def tier_state(inst):
+    mge = inst._meshglobal
+    with mge._mu:
+        return (dict(mge.slots), dict(mge.pinned_cfg),
+                dict(mge._retired), set(mge._occupied),
+                mge.stats()["injected_hits"])
+
+
+@pytest.mark.parametrize("case", ["cold-mid-batch", "warm-mid-batch",
+                                  "warm-pinned-changed"])
+def test_config_change_on_one_of_490_keys_returns_none(big_pair, case):
+    """One key of ~490 changes its limit — between two of its rows, or
+    on all of them against what the tier pinned: the runner returns
+    None where the per-key loops did, BEFORE anything moved, and the
+    object path serves the call."""
+    wi, oi = big_pair
+    empty_tier(wi, oi)
+    ns = f"cc-{case}"
+    reqs = call_of("g490", ns)
+    if case.startswith("warm"):
+        data = ser(reqs)
+        assert wi.get_rate_limits_wire(data, now_ms=NOW) == \
+            oi.get_rate_limits_wire(data, now_ms=NOW)
+    # a key with several rows; the change lands on its LAST row only,
+    # or on every row of it
+    rows_of = {}
+    for i, r in enumerate(reqs):
+        rows_of.setdefault(r.unique_key, []).append(i)
+    victim = next(k for k, rows in sorted(rows_of.items())
+                  if 2 <= len(rows) <= 6)
+    hit = rows_of[victim] if case == "warm-pinned-changed" \
+        else rows_of[victim][-1:]
+    for i in hit:
+        reqs[i] = greq(victim, hits=reqs[i].hits, name=ns, limit=99_999)
+    data = ser(reqs)
+    before = tier_state(wi)
+    runner = wi._wire_mesh_runner(
+        _wire_native.parse_get_rate_limits(data), NOW + 1)
+    assert runner is None
+    assert tier_state(wi) == before
+    n_pb2 = lane(wi, "pb2_fallback")
+    assert wi.get_rate_limits_wire(data, now_ms=NOW + 1) == \
+        obj_bytes(oi, reqs, NOW + 1)
+    assert lane(wi, "pb2_fallback") - n_pb2 == ROWS
+
+
+def mslots_of(inst, monkeypatch):
+    """Capture the mslot column the runner hands the dispatcher."""
+    seen = []
+    real = inst.dispatcher.check_packed
+
+    def spy(batch, kh, now, mslot=None):
+        seen.append((np.array(kh), None if mslot is None
+                     else np.array(mslot)))
+        return real(batch, kh, now, mslot=mslot)
+
+    monkeypatch.setattr(inst.dispatcher, "check_packed", spy)
+    return seen
+
+
+def test_key_unpinned_between_keys_and_slots_rides_sharded(big_pair,
+                                                           monkeypatch):
+    """A key unpinned after route.keys matched it and before
+    route.slots reads the slot map: its rows take the sharded lane
+    (mslot -1), the rest keep their slots, nobody errors."""
+    wi, _ = big_pair
+    empty_tier(wi)
+    reqs = call_of("g490", "unpin")
+    data = ser(reqs)
+    wi.get_rate_limits_wire(data, now_ms=NOW)  # warm: all pinned
+    gone = hash_key("unpin", reqs[0].unique_key)
+    mge = wi._meshglobal
+    real_phase = instance_mod.phase
+
+    def phase_spy(name, *a, **kw):
+        if name == "route.slots":
+            mge.unpin(gone)
+        return real_phase(name, *a, **kw)
+
+    monkeypatch.setattr(instance_mod, "phase", phase_spy)
+    seen = mslots_of(wi, monkeypatch)
+    out = wi.get_rate_limits_wire(data, now_ms=NOW + 1)
+    (kh, mslot), = seen
+    mine = kh == np.uint64(gone)
+    assert mine.sum() == sum(r.unique_key == reqs[0].unique_key
+                             for r in reqs)
+    assert (mslot[mine] == -1).all() and (mslot[~mine] >= 0).all()
+    rs = pb.GetRateLimitsResp.FromString(out).responses
+    assert all(r.error == "" for r in rs)
+
+
+@pytest.mark.parametrize("engine", ["pallas", "xla"])
+def test_full_probe_window_sends_only_that_keys_rows_sharded(
+        big_pair, xla_inst, monkeypatch, engine):
+    """First touch of ~490 keys, one of them with a full probe window
+    and nothing colder to evict: that key's rows alone ride the
+    sharded lane, every other key is pinned and served by the tier."""
+    inst = big_pair[0] if engine == "pallas" else xla_inst
+    empty_tier(inst)
+    ns = f"full-{engine}"
+    reqs = call_of("g490", ns)
+    refused = hash_key(ns, reqs[0].unique_key)
+    mge = inst._meshglobal
+    with mge._mu:
+        fake = set(mge._probe_slots_host(refused)) - mge._occupied
+        mge._occupied |= fake
+    monkeypatch.setattr(inst, "_mesh_overflow_victim", lambda kh: None)
+    seen = mslots_of(inst, monkeypatch)
+    mesh_kh = []
+    real_cc = mge.check_columns
+    monkeypatch.setattr(mge, "check_columns", lambda b, kh, now: (
+        mesh_kh.append(np.array(kh)), real_cc(b, kh, now))[1])
+    try:
+        out = inst.get_rate_limits_wire(ser(reqs), now_ms=NOW)
+    finally:
+        with mge._mu:
+            mge._occupied -= fake
+    rs = pb.GetRateLimitsResp.FromString(out).responses
+    assert len(rs) == ROWS and all(r.error == "" for r in rs)
+    assert not mge.is_pinned(refused)
+    others = {hash_key(ns, r.unique_key) for r in reqs} - {refused}
+    assert all(mge.is_pinned(k) for k in others)
+    (kh, mslot), = seen
+    if engine == "pallas":  # one fused wave, the lane is the mslot
+        mine = kh == np.uint64(refused)
+        assert mine.any()
+        assert (mslot[mine] == -1).all() and (mslot[~mine] >= 0).all()
+    else:  # the sharded dispatch carries that key alone
+        assert mslot is None and set(kh.tolist()) == {refused}
+        (mk,), = [mesh_kh]
+        assert set(mk.tolist()) == others
+
+
+def test_routing_work_does_not_grow_with_distinct_keys(big_pair,
+                                                       monkeypatch,
+                                                       numpy_calls):
+    """The complexity, not the clock: a warm 1,000-row call with ~490
+    distinct pinned keys builds no RateLimitRequest in the runner and
+    makes exactly the numpy calls a 12-key call makes."""
+    wi, _ = big_pair
+    empty_tier(wi)
+    built = []
+    real_req = instance_mod.RateLimitRequest
+    counts = {}
+    for shape in ("g12", "g490"):
+        data = ser(call_of(shape, "cx"))
+        wi.get_rate_limits_wire(data, now_ms=NOW)  # warm
+        parsed = _wire_native.parse_get_rate_limits(data)
+        monkeypatch.setattr(
+            instance_mod, "RateLimitRequest",
+            lambda *a, **kw: (built.append(1), real_req(*a, **kw))[1])
+        with numpy_calls() as calls:
+            runner = wi._wire_mesh_runner(parsed, NOW + 1)
+        monkeypatch.setattr(instance_mod, "RateLimitRequest", real_req)
+        assert runner is not None
+        counts[shape] = calls.n
+        assert runner()  # and it serves
+    assert built == []
+    assert counts["g12"] == counts["g490"] > 0, counts
+
+
+@pytest.mark.parametrize("cfg", [
+    dict(limit=100, duration=60_000),
+    dict(limit=0, duration=0),
+    dict(limit=-5, duration=-7, burst=-1),
+    dict(limit=2**62, duration=2**62, burst=2**62),
+    dict(limit=100, duration=60_000, algorithm=Algorithm.LEAKY_BUCKET),
+    dict(limit=100, duration=60_000, burst=250,
+         algorithm=Algorithm.LEAKY_BUCKET),
+    dict(limit=2**62, duration=1, burst=2**62,
+         algorithm=Algorithm.LEAKY_BUCKET),
+    dict(limit=2**40, duration=2**50, burst=3,
+         algorithm=Algorithm.LEAKY_BUCKET),
+    dict(limit=10, duration=0, algorithm=Algorithm.LEAKY_BUCKET),
+    dict(limit=10, duration=-3, burst=-2,
+         algorithm=Algorithm.LEAKY_BUCKET),
+    dict(limit=100, duration=1,
+         behavior=Behavior.GLOBAL | Behavior.DURATION_IS_GREGORIAN),
+    dict(limit=2**62, duration=4, burst=2**62,
+         algorithm=Algorithm.LEAKY_BUCKET,
+         behavior=Behavior.GLOBAL | Behavior.DURATION_IS_GREGORIAN),
+    dict(limit=77, duration=5, burst=9, algorithm=Algorithm.LEAKY_BUCKET,
+         behavior=Behavior.GLOBAL | Behavior.DURATION_IS_GREGORIAN),
+], ids=lambda c: "-".join(f"{k[:3]}{int(v)}" for k, v in c.items()))
+def test_packed_columns_equal_cfg_of(cfg):
+    """What lets the runners compare a batch's columns with the tiers'
+    pinned_cfg tuples as they are: pack_columns clamps (alg, limit,
+    duration, burst) exactly as clamp_config does — token and leaky,
+    at the bounds, and under DURATION_IS_GREGORIAN (whose rows never
+    reach the tiers; the clamps agree there all the same)."""
+    from gubernator_tpu.parallel import hotset, meshglobal
+
+    req = greq("x", **cfg)
+    col = lambda v, t=np.int64: np.array([v], t)  # noqa: E731
+    batch, errs = pack_columns(
+        col(7, np.uint64), col(req.hits), col(req.limit),
+        col(req.duration), col(int(req.algorithm), np.int32),
+        col(int(req.behavior), np.int32), col(req.burst), NOW)
+    assert not errs
+    got = tuple(int(np.asarray(c)[0]) for c in (
+        batch.algorithm, batch.limit, batch.duration, batch.burst))
+    assert got == meshglobal._cfg_of(req) == hotset._cfg_of(req)

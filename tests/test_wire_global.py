@@ -157,3 +157,176 @@ def test_wire_global_leaky_rides_hot_tier():
         assert rs[0].remaining == 1000 - 20
     finally:
         inst.close()
+
+
+# ---- ISSUE 25: the pinned-key pass shared with _wire_mesh_runner -------
+#
+# _wire_global_runner's config match over pinned keys is the mesh
+# runner's (instance.py › _group_key_configs): the parameters of
+# tests/test_mesh_global.py, over the hot set.
+
+import numpy as np  # noqa: E402
+
+from gubernator_tpu import instance as instance_mod  # noqa: E402
+from gubernator_tpu.types import Algorithm  # noqa: E402
+from gubernator_tpu.wire import resp_to_pb  # noqa: E402
+
+ROWS = 1000
+
+
+@pytest.fixture(scope="module")
+def big_pair():
+    """(wire, object) instances whose hot set holds a 1,000-key call;
+    every case empties it first (HotSetEngine.unpin_all)."""
+    mk = lambda: V1Instance(  # noqa: E731
+        Config(cache_size=1 << 14, sweep_interval_ms=0,
+               hot_set_capacity=4096, hot_promote_threshold=1,
+               behaviors=BehaviorConfig(global_sync_wait_ms=10**9)),
+        mesh=make_mesh(n=4))
+    wi, oi = mk(), mk()
+    yield wi, oi
+    wi.close()
+    oi.close()
+
+
+def empty_hot_set(*insts):
+    for inst in insts:
+        if inst._hotset is not None:
+            inst._hotset.unpin_all()
+        with inst._hot_mu:
+            inst._hot_counts.clear()
+            inst._promote_pending.clear()
+
+
+def lane(inst, name):
+    return inst.metrics.wire_lane_counter.labels(lane=name)._value.get()
+
+
+def key_draw(distinct):
+    if distinct == 490:  # the benchmark cell's draw
+        ks = np.random.default_rng(25).zipf(1.1, ROWS) % 1024
+        assert 440 <= len(set(ks.tolist())) <= 540
+        return ks.tolist()
+    return [(i * 7919) % distinct for i in range(ROWS)]
+
+
+def call_of(shape, ns):
+    if shape.startswith("g"):
+        return [greq(f"{ns}{k}", hits=1 + i % 3)
+                for i, k in enumerate(key_draw(int(shape[1:])))]
+    if shape == "mixed":
+        return [greq(f"{ns}{k}") if i % 2 else
+                RateLimitRequest(name="wgl", unique_key=f"{ns}loc{k % 40}",
+                                 hits=1, limit=7, duration=60_000)
+                for i, k in enumerate(key_draw(490))]
+    assert shape == "leaky"
+    return [greq(f"{ns}{k}", limit=50 + k % 3, burst=k % 2 * 80,
+                 algorithm=Algorithm.LEAKY_BUCKET)
+            for k in key_draw(12)]
+
+
+def obj_bytes(inst, reqs, now):
+    out = pb.GetRateLimitsResp()
+    out.responses.extend(resp_to_pb(r)
+                         for r in inst.get_rate_limits(reqs, now_ms=now))
+    return out.SerializeToString()
+
+
+@pytest.mark.parametrize("state", ["cold", "warm"])
+@pytest.mark.parametrize("shape", ["g1", "g12", "g490", "g1000", "mixed",
+                                   "leaky"])
+def test_wire_lane_byte_equal_to_object_path(big_pair, shape, state):
+    """1,000-row solo-GLOBAL calls: first touch (the call promotes its
+    keys) and warm (all pinned) give the object path's bytes, on the
+    wire lane."""
+    wi, oi = big_pair
+    empty_hot_set(wi, oi)
+    reqs = call_of(shape, f"eq-{shape}-{state}-")
+    data = wire(reqs)
+    now = NOW
+    if state == "warm":
+        assert wi.get_rate_limits_wire(data, now_ms=now) == \
+            oi.get_rate_limits_wire(data, now_ms=now)
+        now += 1
+    n_wire, n_pb2 = lane(wi, "wire_hotset"), lane(wi, "pb2_fallback")
+    got = wi.get_rate_limits_wire(data, now_ms=now)
+    assert got == obj_bytes(oi, reqs, now)
+    assert lane(wi, "wire_hotset") - n_wire == ROWS
+    assert lane(wi, "pb2_fallback") == n_pb2
+    rs = pb.GetRateLimitsResp.FromString(got).responses
+    assert len(rs) == ROWS and all(r.error == "" for r in rs)
+    for inst in (wi, oi):  # threshold 1: every GLOBAL key is pinned now
+        assert all(inst._hotset.is_pinned(hash_key(r.name, r.unique_key))
+                   for r in reqs if r.behavior & Behavior.GLOBAL)
+
+
+@pytest.mark.parametrize("case", ["mid-batch", "pinned-changed"])
+def test_config_change_on_one_of_490_pinned_keys_returns_none(big_pair,
+                                                              case):
+    """One pinned key of ~490 changes its limit — on its last row, or
+    on all of them: None, before anything moved; the object path
+    demotes the key and serves the call."""
+    wi, oi = big_pair
+    empty_hot_set(wi, oi)
+    ns = f"cc-{case}-"
+    reqs = call_of("g490", ns)
+    data = wire(reqs)
+    assert wi.get_rate_limits_wire(data, now_ms=NOW) == \
+        oi.get_rate_limits_wire(data, now_ms=NOW)
+    rows_of = {}
+    for i, r in enumerate(reqs):
+        rows_of.setdefault(r.unique_key, []).append(i)
+    victim = next(k for k, rows in sorted(rows_of.items())
+                  if 2 <= len(rows) <= 6)
+    hit = rows_of[victim] if case == "pinned-changed" \
+        else rows_of[victim][-1:]
+    for i in hit:
+        reqs[i] = greq(victim, hits=reqs[i].hits, limit=999)
+    data = wire(reqs)
+    hs = wi._hotset
+
+    def state():
+        with hs._mu, wi._hot_mu:
+            return (dict(hs.slots), dict(hs.pinned_cfg),
+                    dict(wi._hot_counts), list(wi._promote_pending))
+
+    before = state()
+    assert wi._wire_global_runner(
+        _wire_native.parse_get_rate_limits(data), NOW + 1) is None
+    assert state() == before
+    n_pb2 = lane(wi, "pb2_fallback")
+    assert wi.get_rate_limits_wire(data, now_ms=NOW + 1) == \
+        obj_bytes(oi, reqs, NOW + 1)
+    assert lane(wi, "pb2_fallback") - n_pb2 == ROWS
+    kh = hash_key("wgl", victim)  # demoted, then promoted anew
+    assert hs.pinned_cfg.get(kh) == oi._hotset.pinned_cfg.get(kh) != \
+        before[1][kh]
+
+
+def test_pinned_key_pass_does_not_grow_with_distinct_keys(big_pair,
+                                                          monkeypatch,
+                                                          numpy_calls):
+    """Warm 1,000-row calls with 12 and ~490 distinct pinned keys make
+    the same numpy calls in the runner (profiler's count of numpy
+    functions and ndarray methods), and build no RateLimitRequest."""
+    wi, _ = big_pair
+    empty_hot_set(wi)
+    built = []
+    real_req = instance_mod.RateLimitRequest
+    counts = {}
+    datas = {shape: wire(call_of(shape, "cx-")) for shape in ("g12", "g490")}
+    for data in datas.values():  # promotes: both calls are warm below,
+        wi.get_rate_limits_wire(data, now_ms=NOW)  # over ONE pinned set
+    for shape, data in datas.items():
+        parsed = _wire_native.parse_get_rate_limits(data)
+        monkeypatch.setattr(
+            instance_mod, "RateLimitRequest",
+            lambda *a, **kw: (built.append(1), real_req(*a, **kw))[1])
+        with numpy_calls() as calls:
+            runner = wi._wire_global_runner(parsed, NOW + 1)
+        monkeypatch.setattr(instance_mod, "RateLimitRequest", real_req)
+        assert runner is not None
+        counts[shape] = calls.n
+        assert runner()
+    assert built == []
+    assert counts["g12"] == counts["g490"] > 0, counts

@@ -379,7 +379,9 @@ def _apply_position(item: _Item, req: _Req):
     rate = jnp.where(limit1 > 0,
                      divmod_nn(eff0, jnp.maximum(limit1, 1))[0], eff0)
     exp_out = jnp.where(is_leaky, now + eff0, exp0)
-    reset_time = jnp.where(is_leaky, now + rate, exp_out)
+    # leaky: answered from the request's OWN stamp, not the clamped
+    # clock (the older request — oracle.py, "Leaky fixed point")
+    reset_time = jnp.where(is_leaky, req.now + rate, exp_out)
 
     # --- hits
     cost = req.hits * jnp.where(is_leaky, eff0, 1)
@@ -669,7 +671,8 @@ def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array
         ap = ok_seg[sid] & tail_sel
         os_ = jnp.where(ap, st_pos, os_)
         or_ = jnp.where(ap, divmod_nn(r, effp)[0], or_)
-        ot_ = jnp.where(ap, e + rate, ot_)
+        # own stamp, not the clamped clock e (the older request)
+        ot_ = jnp.where(ap, now_s + rate, ot_)
         ol_ = jnp.where(ap, L, ol_)
 
         # per-segment final item from the last tail position

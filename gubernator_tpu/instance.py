@@ -259,9 +259,9 @@ class V1Instance:
         # emits the heavy-hitter tap columns ON DEVICE — hand the
         # analytics sink + metrics registry to the engine BEFORE any
         # serving starts (single assignment, read-only afterwards).
-        if getattr(engine, "fused_tap", False):
-            if analytics is not None:
-                engine.tap_sink = analytics.tap_device
+        if getattr(engine, "fused_tap", False) and analytics is not None:
+            engine.tap_sink = analytics.tap_device
+        if hasattr(engine, "metrics_ref"):
             engine.metrics_ref = self.metrics
         # wave-buffer pool counters (hit/miss/leak) land on this
         # instance's registry; the pool lives engine-side (lease scope

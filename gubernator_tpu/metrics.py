@@ -129,6 +129,12 @@ class Metrics:
             "gubernator_dispatcher_wave_size",
             "requests per coalesced device wave",
             buckets=_WAVE_SIZE_BUCKETS, registry=r)
+        self.wave_leaky_rows = Counter(
+            "gubernator_wave_leaky_rows",
+            "LEAKY_BUCKET rows that entered a wave's device program "
+            "(valid, inside the step program's domain, not cold-tier "
+            "served), counted once a wave at the engine's wave.route, "
+            "by the fused wire ingest for an inline wave", registry=r)
         self.wave_queue_wait = Histogram(
             "gubernator_dispatcher_queue_wait",
             "job wait from submit to its wave launching (s)",

@@ -452,7 +452,7 @@ static inline uint64_t mix64(uint64_t x) {
 //                duration_max, value_max, eff_max, td_bound) ->
 //   None                              (needs the classic/pb2 path)
 // | (n, khash u64le, khash_raw u64le, behavior_or,
-//    tlv_off u64le, tlv_len u64le)
+//    tlv_off u64le, tlv_len u64le, leaky_rows)
 //
 // The fused wire ingest: one pass over a GetRateLimitsReq /
 // GetPeerRateLimitsReq that parses, validates, clamps (bit-identical to
@@ -470,6 +470,9 @@ static inline uint64_t mix64(uint64_t x) {
 // parse_get_rate_limits), n > m, or any DURATION_IS_GREGORIAN row
 // (calendar period ends are computed in Python).  GLOBAL/MULTI_REGION
 // gating is the caller's policy — behavior_or is returned for it.
+// leaky_rows counts the LEAKY_BUCKET rows written (the engine's
+// gubernator_wave_leaky_rows counter reads it: no host pass over the
+// algorithm row).
 static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
   Py_buffer view, b64, b32;
   long long now_ms;
@@ -509,7 +512,7 @@ static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
   uint64_t beh_or = 0;
   const uint64_t GREG = 4;  // Behavior.DURATION_IS_GREGORIAN
   bool fallback = false;
-  Py_ssize_t n = 0;
+  Py_ssize_t n = 0, n_leaky = 0;
   while (p < end) {
     const uint8_t* tlv_start = p;
     uint64_t tag, len;
@@ -624,6 +627,7 @@ static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
     r_alg[n] = leaky ? 1 : 0;
     r_valid[n] = 1;
     beh_or |= (uint64_t)(uint32_t)f_beh;
+    n_leaky += leaky;
     n++;
   }
   PyBuffer_Release(&view);
@@ -635,9 +639,9 @@ static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
   const char* kr_p = n ? (const char*)khash_raw.data() : kEmptyW;
   const char* to_p = n ? (const char*)tlv_off.data() : kEmptyW;
   const char* tl_p = n ? (const char*)tlv_len.data() : kEmptyW;
-  return Py_BuildValue("(ny#y#Ky#y#)", n, kh_p, n * 8, kr_p, n * 8,
+  return Py_BuildValue("(ny#y#Ky#y#n)", n, kh_p, n * 8, kr_p, n * 8,
                        (unsigned long long)beh_or, to_p, n * 8, tl_p,
-                       n * 8);
+                       n * 8, n_leaky);
 }
 
 // split_resp_items(bytes) ->

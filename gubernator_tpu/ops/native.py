@@ -91,9 +91,10 @@ def pack_wire_wave(data: bytes, now_ms: int, a64: np.ndarray,
     classic numpy pack) for anything the lane doesn't model: pb2
     framing, n > m, or any DURATION_IS_GREGORIAN row.  Otherwise
     (n, khash u64[n] MIXED, khash_raw u64[n], behavior_or, tlv_off,
-    tlv_len).  Clamp bounds are passed from types.py so the constants
-    have one home; clamp arithmetic is pinned bit-identical to
-    core/batch.py › pack_columns by tests/test_native.py."""
+    tlv_len, leaky_rows — the count of LEAKY_BUCKET rows).  Clamp
+    bounds are passed from types.py so the constants have one home;
+    clamp arithmetic is pinned bit-identical to core/batch.py ›
+    pack_columns by tests/test_native.py."""
     from ..types import DURATION_MAX, EFF_MAX, TD_BOUND, VALUE_MAX
 
     m = a64.shape[1]
@@ -102,13 +103,14 @@ def pack_wire_wave(data: bytes, now_ms: int, a64: np.ndarray,
                                TD_BOUND)
     if r is None:
         return None
-    n, kh, kr, beh_or, toff, tlen = r
+    n, kh, kr, beh_or, toff, tlen, leaky_rows = r
     return (n,
             np.frombuffer(kh, "<u8", count=n),
             np.frombuffer(kr, "<u8", count=n),
             int(beh_or),
             np.frombuffer(toff, "<u8", count=n),
-            np.frombuffer(tlen, "<u8", count=n))
+            np.frombuffer(tlen, "<u8", count=n),
+            leaky_rows)
 
 
 def split_resp_items(data: bytes):

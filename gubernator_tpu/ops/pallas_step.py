@@ -270,22 +270,28 @@ def buckets_to_rows(buckets):
 
 
 def pallas_value_domain_mask(batch: RequestBatch):
-    """Per-row value-domain mask (np bool[B]): True where the row's
-    algorithm/counters/eff fit the kernel's i32 arithmetic.  Row-level
-    twin of the value checks in ``pallas_qualifies`` — the serving
-    engine uses it to scope out-of-domain rows instead of failing a
-    whole coalesced wave (ordering is not row-separable and stays a
-    batch-level property)."""
+    """(per-row value-domain mask, the ``algorithm == 1`` column it is
+    built from — None where no row is leaky, and then the leaky half
+    of the mask, three passes over ``eff_ms``, is skipped).  The mask
+    (np bool[B]) is True where the row's algorithm/counters/eff fit
+    the kernel's i32 arithmetic.  Row-level twin of the value checks
+    in ``pallas_qualifies`` — the serving engine uses it to scope
+    out-of-domain rows instead of failing a whole coalesced wave
+    (ordering is not row-separable and stays a batch-level property)
+    and counts its wave's leaky rows from the second column."""
     import numpy as np
 
     alg = np.asarray(batch.algorithm)
-    ok = (alg == 0) | (alg == 1)
+    leaky = alg == 1
+    ok = (alg == 0) | leaky
     for col in (batch.hits, batch.limit, batch.burst):
         c = np.asarray(col)
         ok &= (c >= 0) & (c < VALUE_BOUND)
+    if not leaky.any():
+        return ok, None
     eff = np.asarray(batch.eff_ms)
-    ok &= (alg != 1) | ((eff >= 1) & (eff < EFF_BOUND))
-    return ok
+    ok &= ~leaky | ((eff >= 1) & (eff < EFF_BOUND))
+    return ok, leaky
 
 
 def pallas_qualifies(batch: RequestBatch) -> bool:
@@ -593,10 +599,12 @@ def _kernel(tile, c_ref, _table_in, table_ref, out_ref, scratch, sems):
                 status1 = _sel(is_query, status0, _sel(ok, 0, 1))
 
                 # response: remaining in whole tokens, reset_time =
-                # now + eff//limit (NOT the stored expire = now + eff)
+                # the request's OWN stamp + eff//limit (NOT the stored
+                # expire = now + eff, and not the clamped clock: the
+                # older request — oracle.py, "Leaky fixed point")
                 rem_out, _ = _udiv64_32(td2h, td2l, r_elo)
                 x_hi, x_lo = _add64(nhi1, nlo1, r_ehi, r_elo)
-                rsh_, rsl_ = _add64(nhi1, nlo1, zero, r_rate)
+                rsh_, rsl_ = _add64(nhi0, nlo0, zero, r_rate)
 
                 writeback({
                     W_KLO: klo, W_KHI: khi, W_REM: zero,

@@ -60,6 +60,16 @@ see the comment block above ``_clamp_token``).
   changes); burst is re-adopted from each request.
 - reset_time = now + duration_eff // limit (ms until one token leaks);
   expire_at = now + duration_eff (sliding TTL).
+- THE OLDER REQUEST (stated here once; core/step.py › _apply_position
+  and › _leaky_mixed_scan, ops/pallas_step.py › _leaky and tiering.py ›
+  _host_apply hold the same): a leaky request stamped at or before
+  its row's clock leaks nothing, takes nothing back, does not move the
+  clock or the expiry, spends its hits from what the row holds — and is
+  answered ``reset_time = its OWN stamp + eff // limit``.  So the STATE
+  runs on ``max(now, item.t_ms)`` and the ANSWER on ``now``; upstream
+  likewise leaks (and moves UpdatedAt) only when ``int64(leak) > 0`` and
+  answers ResetTime from the request's clock.  On stamps that never step
+  back the two times are one.
 
 Input clamps (applied to every request): hits < 0 → 0, limit < 0 → 0,
 non-Gregorian duration < 1 → 1, burst ≤ 0 → limit.  int64-safety bounds
@@ -242,6 +252,11 @@ def apply_leaky(item: Optional[Item], req: RateLimitRequest, now_ms: int
                 ) -> Tuple[Item, RateLimitResponse]:
     hits, r_limit, r_duration, r_burst, eff = _clamp_leaky(req)
     behavior = int(req.behavior)
+    # the older request (module docstring): the state runs on the row's
+    # clock where the stamp lies behind it, the answer on the stamp
+    stamp_ms = now_ms
+    if item is not None:
+        now_ms = max(now_ms, item.t_ms)
 
     if item is None or now_ms >= item.expire_at or item.algorithm != Algorithm.LEAKY_BUCKET:
         item = _new_leaky_item(req, now_ms)
@@ -279,7 +294,7 @@ def apply_leaky(item: Optional[Item], req: RateLimitRequest, now_ms: int
 
     rate = eff // item.limit if item.limit > 0 else eff
     item.expire_at = now_ms + eff
-    resp = RateLimitResponse(limit=item.limit, reset_time=now_ms + rate)
+    resp = RateLimitResponse(limit=item.limit, reset_time=stamp_ms + rate)
     if hits == 0:
         resp.status = Status(item.status)
         resp.remaining = item.remaining // eff

@@ -43,7 +43,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..core.batch import RequestBatch
 from ..core.step import decide_batch_impl
 from ..ops import pallas_step as ps
-from ..tracing import phase
 from .mesh import SHARD_AXIS, XLA_EXEC_MU, exec_gate
 from .sharded import PACK32, PACK64, ShardedEngine
 
@@ -417,7 +416,6 @@ class FusedServingMixin:
         #: both single-assigned at instance wiring BEFORE serving
         #: starts, then read-only on the launch path
         self.tap_sink = None  # lock-free: set once pre-serving, read-only after
-        self.metrics_ref = None  # lock-free: set once pre-serving, read-only after
         #: bound MeshGlobalEngine (GUBER_GLOBAL_MODE=mesh): a single
         #: reference swap — a wave racing an unbind serves one more
         #: mesh wave, which the tier's state lock keeps exact
@@ -602,19 +600,20 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
 
     def _mask_out_of_domain(self, batch, mslot=None):
         """Invalidate rows outside the kernel's value domain; returns
-        (masked batch, ood index array or None).  Mesh-GLOBAL rows
-        (mslot >= 0) are exempt: they decide on the replica table's XLA
-        math inside the fused program, which has the full int64
-        domain."""
-        mask = ps.pallas_value_domain_mask(batch)
+        (masked batch, ood index array or None, the mask's own
+        ``algorithm == 1`` column or None where no row is leaky).
+        Mesh-GLOBAL rows (mslot >= 0) are exempt: they decide on the
+        replica table's XLA math inside the fused program, which has
+        the full int64 domain."""
+        mask, leaky = ps.pallas_value_domain_mask(batch)
         if mslot is not None:
             mask = mask | (np.asarray(mslot) >= 0)
         v = np.asarray(batch.valid)
         ood = v & ~mask
         if not ood.any():
-            return batch, None
+            return batch, None, leaky
         return (batch._replace(valid=jnp.asarray(v & mask)),
-                np.nonzero(ood)[0])
+                np.nonzero(ood)[0], leaky)
 
     @staticmethod
     def _merge_ood(cols, ood):
@@ -628,13 +627,9 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
         full[ood] = True
         return st, lim, rem, rst, full
 
-    def check_packed(self, batch, khash, now_ms: int,
-                     mslot=None) -> tuple:
-        with phase("wave.route"):
-            batch, ood = self._mask_out_of_domain(batch, mslot)
-        cols = self._merge_ood(
-            super().check_packed(batch, khash, now_ms, mslot=mslot),
-            ood)
+    def _serve_out_of_domain(self, cols, ood, batch, khash, now_ms,
+                             mslot):
+        cols = self._merge_ood(cols, ood)
         tier = self.tier
         if tier is None or ood is None:
             return cols

@@ -50,10 +50,10 @@ class PrepackedWave:
     (or must release it explicitly on a fallback path)."""
 
     __slots__ = ("lease", "n", "khash", "khash_raw", "behavior_or",
-                 "tlv_off", "tlv_len")
+                 "tlv_off", "tlv_len", "leaky_rows")
 
     def __init__(self, lease, n, khash, khash_raw, behavior_or,
-                 tlv_off, tlv_len):
+                 tlv_off, tlv_len, leaky_rows):
         self.lease = lease
         self.n = n
         self.khash = khash
@@ -61,6 +61,7 @@ class PrepackedWave:
         self.behavior_or = behavior_or
         self.tlv_off = tlv_off
         self.tlv_len = tlv_len
+        self.leaky_rows = leaky_rows
 
 
 def autogrow_limit_per_shard(total_rows: int, n_shards: int,
@@ -341,6 +342,10 @@ class ShardedEngine:
         #: reference's LRU never fails an insert; with auto-grow on,
         #: neither do we until this bound.
         self.auto_grow_limit = auto_grow_limit
+        #: the instance's Metrics registry (gubernator_wave_leaky_rows;
+        #: the fused engines' wave counters): single-assigned at
+        #: instance wiring BEFORE serving starts, read-only after
+        self.metrics_ref = None  # lock-free: set once pre-serving, read-only after
         self._init_table_and_step()
         self._batch_sharding = NamedSharding(self.mesh, P(SHARD_AXIS))
         self._mat_sharding = NamedSharding(self.mesh, P(None, SHARD_AXIS))
@@ -538,7 +543,7 @@ class ShardedEngine:
         program's value domain (``_mask_out_of_domain``) ride invalid
         too; the sync side marks them unservable."""
         with phase("wave.route"):
-            batch, ood = self._mask_out_of_domain(batch, mslot)
+            batch, ood, leaky = self._mask_out_of_domain(batch, mslot)
             tier = self.tier
             cold_idx = None
             if tier is not None:
@@ -551,6 +556,9 @@ class ShardedEngine:
                     cold_idx = np.nonzero(cm)[0]
                     batch = batch._replace(
                         valid=np.asarray(batch.valid) & ~cm)
+            if leaky is not None:
+                self._count_leaky_rows(
+                    np.count_nonzero(leaky & np.asarray(batch.valid)))
             waves = self._build_waves(khash, self._arrival_order(batch))
         launched, leases = [], []
         try:
@@ -579,9 +587,25 @@ class ShardedEngine:
 
     def _mask_out_of_domain(self, batch: RequestBatch, mslot=None):
         """(batch with the rows this engine's step program cannot
-        represent made invalid, their indices or None).  The XLA step
-        has the full int64 domain: nothing to mask."""
-        return batch, None
+        represent made invalid, their indices or None, the wave's
+        ``algorithm == LEAKY_BUCKET`` column or None where no row is
+        leaky).  The XLA step has the full int64 domain: nothing to
+        mask."""
+        alg = np.asarray(batch.algorithm)
+        return batch, None, alg == 1 if alg.any() else None
+
+    def _serve_out_of_domain(self, cols, ood, batch, khash, now_ms,
+                             mslot):
+        """check_packed's response columns with the out-of-domain rows
+        answered: the XLA step masks none."""
+        return cols
+
+    def _count_leaky_rows(self, n: int) -> None:
+        """``gubernator_wave_leaky_rows``: the LEAKY_BUCKET rows of one
+        wave that go on to the device program."""
+        m = self.metrics_ref
+        if n and m is not None:
+            m.wave_leaky_rows.inc(n)
 
     def sync_packed(self, token, engine_lock=None) -> tuple:
         """Pipeline phase 2: block on the launched waves and assemble
@@ -753,9 +777,7 @@ class ShardedEngine:
         if res is None:
             lease.release()
             return None
-        n, khash, khash_raw, behavior_or, tlv_off, tlv_len = res
-        return PrepackedWave(lease, n, khash, khash_raw, behavior_or,
-                             tlv_off, tlv_len)
+        return PrepackedWave(lease, *res)
 
     def check_prepacked(self, pre: "PrepackedWave", now_ms: int) -> tuple:
         """Launch + resolve a prepacked wave.  Returns the check_packed
@@ -780,6 +802,10 @@ class ShardedEngine:
             if cm.any():
                 cold_i = np.nonzero(cm)[0]
                 lease.a32[2][cold_i] = 0
+        # the fused ingest counted them; cold rows are served on the host
+        self._count_leaky_rows(
+            pre.leaky_rows if cold_i is None else
+            pre.leaky_rows - np.count_nonzero(lease.a32[1][cold_i]))
         try:
             # retry needs the request columns; snapshot them from the
             # lease ONLY if the cheap error scan demands it (below)
@@ -872,6 +898,7 @@ class ShardedEngine:
         # tier in the resolve below.  Mesh-pinned rows (mslot >= 0) are
         # never cold: the pin seed pops the cold copy.
         with phase("wave.route"):
+            batch, ood, leaky = self._mask_out_of_domain(batch, mslot)
             tier = self.tier
             cold_mask = None
             orig_valid = None
@@ -884,6 +911,9 @@ class ShardedEngine:
                 if cold_mask.any():
                     batch = batch._replace(
                         valid=np.asarray(batch.valid) & ~cold_mask)
+            if leaky is not None:
+                self._count_leaky_rows(
+                    np.count_nonzero(leaky & np.asarray(batch.valid)))
             # earliest requests take the earliest waves: same-key
             # requests split across waves then apply in arrival-time
             # order (within a wave the device's (row, now) sort handles
@@ -934,14 +964,15 @@ class ShardedEngine:
             if len(pending):
                 with phase("wave.route"):
                     waves = self._build_waves(khash, pending)
+        cols = (status, lim_o, rem_o, rst_o, full)
         if tier is not None:
             # cold lane: pre-masked cold-resident rows plus residual
             # table-full rows (brand-new keys, device table saturated —
             # the tier turns table-full into find-or-create on host)
-            return tier.resolve(self, batch, khash, now_ms,
-                                (status, lim_o, rem_o, rst_o, full),
+            cols = tier.resolve(self, batch, khash, now_ms, cols,
                                 cold_mask, orig_valid, mslot=mslot)
-        return status, lim_o, rem_o, rst_o, full
+        return self._serve_out_of_domain(cols, ood, batch, khash, now_ms,
+                                         mslot)
 
     def _try_auto_grow(self, grew: list) -> bool:
         """Grow 2× (once per wave) if under auto_grow_limit.  Returns

@@ -159,7 +159,9 @@ def _host_apply(row, hits, limit, duration, eff, greg_end, behavior,
     d0 = eff0 if eff0 > 1 else 1
     rate = eff0 // (limit1 if limit1 > 1 else 1) if limit1 > 0 else eff0
     exp_out = now + eff0 if is_leaky else exp0
-    reset_time = now + rate if is_leaky else exp_out
+    # leaky: from the request's OWN stamp, not the clamped clock (the
+    # older request — oracle.py, "Leaky fixed point")
+    reset_time = req_now + rate if is_leaky else exp_out
 
     # --- hits
     cost = hits * (eff0 if is_leaky else 1)

@@ -164,8 +164,8 @@ PHASE_CATALOG: Dict[str, str] = {
     "wave.begin": "_wave_begin: telemetry, per-job waits, event",
     "wave.concat": "column concat of the merged jobs (+ mslot, now)",
     "lock.engine": "waiting to acquire the engine lock",
-    "wave.route": "engine: domain mask, tier mask, arrival order, "
-                  "_build_waves",
+    "wave.route": "engine: domain mask, tier mask, leaky rows counted, "
+                  "arrival order, _build_waves",
     "wave.fill": "engine: _fill_packed into the leased upload buffers",
     "lock.xla_exec": "engine: waiting to acquire XLA_EXEC_MU",
     "lock.mesh_state": "mesh-GLOBAL tier: a fused launch waiting for "

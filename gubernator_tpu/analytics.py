@@ -46,6 +46,9 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from .hashing import mix64_np
+from .tracing import phase
+
 #: phase label set (OBSERVABILITY.md › phase catalog).  ``IN_WAVE``
 #: phases partition wave_duration; the rest attribute time outside the
 #: wave (job queue wait, wire ingest, response build, peer flush).
@@ -561,8 +564,9 @@ def iter_wire_names(data) -> List[tuple]:
     """(name, unique_key) per request TLV of a serialized
     GetRateLimitsReq — a tolerant pure-Python walk (field 1 = repeated
     RateLimitReq; inside it field 1 = name, field 2 = unique_key).
-    Runs on the analytics worker ONLY for waves carrying khashes the
-    tenant cache hasn't seen, so steady-state traffic never parses."""
+    The analytics worker hands it ONE request TLV for each rate-limit
+    name it has never seen (``KeyAnalytics._learn``); whole messages
+    only on the shed path (``instance.py › _tenant_of_wire``)."""
     out: List[tuple] = []
     pos, end = 0, len(data)
     while pos < end:
@@ -793,6 +797,102 @@ class CostModel:
                 "buckets": buckets}
 
 
+_M64 = 0xFFFFFFFFFFFFFFFF
+
+
+class _BucketTable:
+    """Bounded uint64 → tenant-bucket map as three parallel columns
+    sorted by key: (key u64, bucket i64, age i64).
+
+    ONE writer (the analytics worker) merges keys in and publishes the
+    result by swapping the one reference ``cols`` — never in place — so
+    any thread reads a consistent triple without a lock.  ``age`` is
+    when a key was last seen by a merge; over ``cap`` the keys seen
+    longest ago go, by one mask over the columns, and learn again on
+    their next appearance."""
+
+    __slots__ = ("cap", "cols", "_tick")
+
+    def __init__(self, cap: int):
+        self.cap = int(cap)
+        self.cols = (np.empty(0, np.uint64), np.empty(0, np.int64),
+                     np.empty(0, np.int64))
+        self._tick = 0
+
+    def __len__(self) -> int:
+        return self.cols[0].size
+
+    @staticmethod
+    def _find(ks: np.ndarray, keys: np.ndarray):
+        """(held bool[n], pos i64[n]): ``ks[pos] == keys`` where held;
+        where not, ``pos`` is where the key would be inserted."""
+        if not ks.size:
+            return np.zeros(len(keys), bool), np.zeros(len(keys), np.int64)
+        pos = np.searchsorted(ks, keys)
+        return ks[np.minimum(pos, ks.size - 1)] == keys, pos
+
+    def known(self, keys: np.ndarray) -> np.ndarray:
+        """bool[n]: which of ``keys`` the table holds."""
+        return self._find(self.cols[0], keys)[0]
+
+    def buckets(self, keys: np.ndarray) -> np.ndarray:
+        """i64[n]: each key's bucket, 0 (``__other__``) where it is
+        not held."""
+        ks, ti, _ = self.cols
+        held, pos = self._find(ks, keys)
+        out = np.zeros(len(keys), np.int64)
+        out[held] = ti[pos[held]]
+        return out
+
+    def get(self, key: int) -> Optional[int]:
+        ks, ti, _ = self.cols
+        k = np.uint64(int(key) & _M64)
+        i = int(np.searchsorted(ks, k))
+        if i < ks.size and ks[i] == k:
+            return int(ti[i])
+        return None
+
+    def learn(self, rows: np.ndarray, bucket_of) -> tuple:
+        """Merge a batch of key ``rows`` (in arrival order, some not
+        held) in: the keys held are marked seen, the others inserted
+        with ``bucket_of(first)`` — ``first`` being the batch row each
+        new key first came in.  Returns the (rows of new keys, rows of
+        new keys filed under bucket 0)."""
+        order = np.argsort(rows, kind="stable")
+        srt = rows[order]
+        starts = np.flatnonzero(np.concatenate(
+            ([True], srt[1:] != srt[:-1])))
+        ends = np.append(starts[1:], srt.size)
+        keys = srt[starts]  # the batch's distinct keys, sorted
+        seen = self._tick + order[ends - 1]  # each key's LAST row
+        self._tick += rows.size
+        ks, ti, ag = self.cols
+        held, pos = self._find(ks, keys)
+        new = ~held
+        b_new = np.asarray(bucket_of(order[starts][new]), np.int64)
+        at = pos[new] + np.arange(b_new.size)
+        n = ks.size + b_new.size
+        old = np.ones(n, bool)
+        old[at] = False
+        ag = ag.copy()
+        ag[pos[held]] = seen[held]
+        cols = []
+        for have, ins, dt in ((ks, keys[new], np.uint64),
+                              (ti, b_new, np.int64),
+                              (ag, seen[new], np.int64)):
+            col = np.empty(n, dt)
+            col[at] = ins
+            col[old] = have
+            cols.append(col)
+        if n > self.cap:
+            age = cols[2]  # distinct: exactly cap keys survive
+            keep = age >= np.partition(age, n - self.cap)[n - self.cap]
+            cols = [c[keep] for c in cols]
+        self.cols = tuple(cols)
+        per_key = (ends - starts)[new]
+        return int(per_key.sum()), int(per_key[b_new == 0].sum())
+
+
 class _Flush:
     """Queue sentinel: the worker sets the event when it reaches it."""
 
@@ -822,6 +922,9 @@ class KeyAnalytics:
     #: never per fold.
     PUBLISH_INTERVAL_S = 2.0
 
+    #: bound of the name-hash → tenant table (shed like the khash one)
+    NAME_CAP = 4096
+
     def __init__(self, metrics=None, k: Optional[int] = None,
                  width: Optional[int] = None, queue_cap: int = 512,
                  clock=time.time):
@@ -840,16 +943,16 @@ class KeyAnalytics:
         #: α-β collective cost model; taps go straight in (leaf lock,
         #: samples arrive from reconcile/flush threads, never hot)
         self.costmodel = CostModel()
-        #: khash → tenant bucket index, learned from named taps and
-        #: wire-name learn items.  Worker-thread-owned writes; GIL-
-        #: atomic .get() reads serve event-field hints.  lock-free
-        self._kh_tenant: Dict[int, int] = {}
+        #: khash → tenant bucket, learnt from named taps and wire
+        #: learn items.  The worker thread alone writes it (one
+        #: reference swap a merge); event-field hints read it.  lock-free
         self._kh_cap = max(8 * width, 4096)
-        # lazily rebuilt sorted lookup for vectorized fold attribution
-        # (worker-thread only)
-        self._kh_sorted = np.empty(0, np.uint64)
-        self._kh_tidx = np.empty(0, np.int64)
-        self._kh_dirty = False
+        self._kh = _BucketTable(self._kh_cap)
+        #: FNV-1a64 of a rate-limit NAME → tenant bucket: what lets a
+        #: wire call's new khashes be learnt without reading its names
+        #: (names are a handful a deployment; bounded all the same)
+        self._names = _BucketTable(self.NAME_CAP)
+        self._learn_kids = None  # the learn counter's label children
         self._q: "queue.Queue" = queue.Queue(maxsize=queue_cap)
         self._waves = 0  # guarded-by: self._mu
         self._dropped = 0  # guarded-by: self._mu
@@ -882,18 +985,21 @@ class KeyAnalytics:
         return self._put(("reqs", list(reqs), list(resps),
                           int(self._clock() * 1000)))
 
-    def tap_wire_names(self, data, khash=None, raw: bool = False
-                       ) -> bool:
+    def tap_wire_names(self, data, khash, name_hash, tlv_off, tlv_len,
+                       raw: bool = False) -> bool:
         """Tenant learn tap for the columnar wire lanes, which carry
         only khashes: enqueue the (immutable) wire bytes plus the
-        lane's khash view so the WORKER can map new khashes to tenant
-        ids — zero copies, zero parsing on the serving path.  ``raw``
-        marks a pre-mix khash (parse output); the worker applies the
-        finalizer itself.  FIFO ordering guarantees the learn lands
-        before the wave's own "cols"/"dev" item is folded."""
+        ingest's views — khash, the FNV-1a64 of each request's name
+        (``name_hash``) and the request TLV ranges — so the WORKER can
+        map new khashes to tenant ids: zero copies, zero parsing on
+        the serving path.  ``raw`` marks a pre-mix khash (parse
+        output); the worker applies the finalizer itself.  FIFO
+        ordering guarantees the learn lands before the wave's own
+        "cols"/"dev" item is folded."""
         if self._tenants is None:
             return True
-        return self._put(("learn", data, khash, raw))
+        return self._put(("learn", data, khash, name_hash, tlv_off,
+                          tlv_len, raw))
 
     def tap_flag(self, field: str, n: int = 1,
                  tenant: Optional[str] = None,
@@ -979,13 +1085,14 @@ class KeyAnalytics:
         while True:
             item = q.get()
             cols: list = []
+            learns: list = []
             while True:
                 if item is None:
-                    self._fold_cols(cols)
+                    self._fold_window(cols, learns)
                     return
                 if isinstance(item, _Flush):
-                    self._fold_cols(cols)
-                    cols = []
+                    self._fold_window(cols, learns)
+                    cols, learns = [], []
                     item.done.set()
                 elif item[0] == "cols":
                     cols.append(item)
@@ -996,26 +1103,33 @@ class KeyAnalytics:
                     if c is not None:
                         cols.append(c)
                 elif item[0] == "learn":
-                    # tenant-cache learn MUST precede the fold of any
+                    # a tenant learn MUST land before the fold of any
                     # cols queued behind it (FIFO), and folding the
                     # ones queued AHEAD of it later is harmless — so
-                    # apply immediately, no barrier
-                    self._safe_learn(item)
+                    # the window's learns merge in ONE pass, right
+                    # before whatever next reads the khash table
+                    learns.append(item)
                 elif item[0] == "flag":
+                    self._safe_learn(learns)
+                    learns = []
                     self._safe_flag(item)
                 else:
                     # object-lane (named) tap: fold queued columns
                     # first so wave order is preserved
-                    self._fold_cols(cols)
-                    cols = []
+                    self._fold_window(cols, learns)
+                    cols, learns = [], []
                     self._safe_apply(item)
                 try:
                     item = q.get_nowait()
                 except queue.Empty:
                     break
-            self._fold_cols(cols)
+            self._fold_window(cols, learns)
             if not self._closing:
                 time.sleep(self.BATCH_INTERVAL_S)
+
+    def _fold_window(self, cols: list, learns: list) -> None:
+        self._safe_learn(learns)
+        self._fold_cols(cols)
 
     def _fold_cols(self, cols: list) -> None:
         """Everything the drain window collected folds in ONE sketch
@@ -1076,8 +1190,8 @@ class KeyAnalytics:
             tidx = np.fromiter(
                 (tl.index_of(r.name) for r in reqs), np.int64,
                 len(reqs))
-            for i in range(len(reqs)):
-                self._kh_note(int(khash[i]), int(tidx[i]))
+            if not self._kh.known(khash).all():
+                self._kh.learn(khash, lambda first: tidx[first])
             tl.fold(tidx, hits, over)
             for i, r in enumerate(resps):
                 if getattr(r, "error", ""):
@@ -1088,86 +1202,88 @@ class KeyAnalytics:
 
     # ---- tenant attribution (worker thread) -----------------------------
 
-    def _kh_note(self, kh: int, tidx: int) -> None:
-        cache = self._kh_tenant
-        if kh in cache:
-            if cache[kh] != tidx:
-                cache[kh] = tidx
-                self._kh_dirty = True
-            return
-        if len(cache) >= self._kh_cap:
-            # bounded like the sketch's name table: shed the oldest
-            # half (plain dicts pop in insertion order); affected keys
-            # re-learn on their next named/wire appearance and fold
-            # into __other__ meanwhile — conservation holds either way
-            for old in list(cache)[: self._kh_cap // 2]:
-                del cache[old]
-        cache[kh] = tidx
-        self._kh_dirty = True
-
-    def _kh_lookup_arrays(self):
-        if self._kh_dirty or self._kh_sorted.size != len(self._kh_tenant):
-            kh = np.fromiter(self._kh_tenant.keys(), np.uint64,
-                             len(self._kh_tenant))
-            ti = np.fromiter(self._kh_tenant.values(), np.int64,
-                             len(self._kh_tenant))
-            order = np.argsort(kh)
-            self._kh_sorted = kh[order]
-            self._kh_tidx = ti[order]
-            self._kh_dirty = False
-        return self._kh_sorted, self._kh_tidx
-
     def _fold_tenants(self, khash, hits, over) -> None:
         """Attribute one folded batch to tenant buckets: vectorized
-        searchsorted against the learned khash cache; khashes the
-        cache can't resolve land in ``__other__`` (bucket 0) so every
-        row is counted exactly once."""
-        tl = self._tenants
-        ks, ti = self._kh_lookup_arrays()
-        if ks.size:
-            pos = np.minimum(np.searchsorted(ks, khash), ks.size - 1)
-            known = ks[pos] == khash
-            tidx = np.where(known, ti[pos], 0)
-        else:
-            tidx = np.zeros(len(khash), np.int64)
-        tl.fold(tidx, hits, over)
+        searchsorted against the learned khash table; khashes it
+        can't resolve land in ``__other__`` (bucket 0) so every row is
+        counted exactly once."""
+        self._tenants.fold(self._kh.buckets(khash), hits, over)
 
-    def _safe_learn(self, item) -> None:
+    def _safe_learn(self, items) -> None:
+        if not items or self._tenants is None:
+            return
         try:
-            self._apply_learn(item)
+            with phase("analytics.learn", self, cpu=True):
+                self._learn(items)
         except Exception:  # pragma: no cover - must never die
             import logging
 
             logging.getLogger("gubernator_tpu.analytics").exception(
                 "tenant learn")
 
-    def _apply_learn(self, item) -> None:
-        tl = self._tenants
-        if tl is None:
-            return
-        _, data, kh, raw = item
-        if kh is not None and len(self._kh_tenant):
-            khm = np.asarray(kh)
-            if khm.dtype != np.uint64:
-                khm = khm.view(np.uint64) if khm.dtype == np.int64 \
-                    else khm.astype(np.uint64)
+    def _learn(self, items) -> None:
+        """Merge one drain window's learn items into the khash →
+        bucket table: numpy over the rows, Python only for each
+        rate-limit NAME never seen before (one request TLV decoded for
+        it).  A window whose khashes are all held costs one
+        ``searchsorted``."""
+        khs, nhs = [], []
+        for _, _data, kh, nh, _off, _len, raw in items:
+            kh = np.asarray(kh, np.uint64)
             if raw:
-                from .hashing import mix64_np
+                kh = mix64_np(kh)
+                kh[kh == 0] = 1  # as the ingest files the row
+            khs.append(kh)
+            nhs.append(nh)
+        one = len(items) == 1
+        kh = khs[0] if one else np.concatenate(khs)
+        n_new = n_other = 0
+        if not self._kh.known(kh).all():
+            nh = np.asarray(nhs[0] if one else np.concatenate(nhs),
+                            np.uint64)
+            n_new, n_other = self._kh.learn(
+                kh, lambda rows: self._name_buckets(nh[rows], rows,
+                                                    items))
+        if self.metrics is not None:
+            kids = self._learn_kids
+            if kids is None:
+                c = self.metrics.analytics_learn_rows
+                kids = self._learn_kids = tuple(
+                    c.labels(outcome=o)
+                    for o in ("known", "learned", "other"))
+            kids[0].inc(kh.size - n_new)
+            kids[1].inc(n_new - n_other)
+            kids[2].inc(n_other)
 
-                khm = mix64_np(khm)
-            ks, _ = self._kh_lookup_arrays()
-            pos = np.minimum(np.searchsorted(ks, khm), ks.size - 1)
-            if bool((ks[pos] == khm).all()):
-                return  # steady state: every khash known, no parse
-        pairs = iter_wire_names(data)
-        if not pairs:
-            return
-        from .hashing import hash_request_keys
+    def _name_buckets(self, nh: np.ndarray, rows: np.ndarray,
+                      items) -> np.ndarray:
+        """Tenant bucket of each name hash in ``nh`` (``rows``: the
+        window row each came from).  A name hash the name table does
+        not hold is read ONCE, from the request TLV of the first of
+        those rows, and its tenant gets a bucket (``__other__`` when
+        the ledger is full)."""
+        names = self._names
+        if not names.known(nh).all():
+            starts = np.cumsum([0] + [len(it[2]) for it in items])
+            by_row = np.argsort(rows)
 
-        khash = hash_request_keys([p[0] for p in pairs],
-                                  [p[1] for p in pairs])
-        for i, (name, _uniq) in enumerate(pairs):
-            self._kh_note(int(khash[i]), tl.index_of(name))
+            def read(first):
+                # in the order the window's rows came: the ledger
+                # hands out its buckets first come, first served
+                out = np.zeros(first.size, np.int64)
+                for j in np.argsort(first).tolist():
+                    row = int(rows[by_row[first[j]]])
+                    it = int(np.searchsorted(starts, row, "right")) - 1
+                    _, data, _kh, _nh, off, ln, _raw = items[it]
+                    lo = int(off[row - starts[it]])
+                    hi = lo + int(ln[row - starts[it]])
+                    pairs = iter_wire_names(memoryview(data)[lo:hi])
+                    if pairs:
+                        out[j] = self._tenants.index_of(pairs[0][0])
+                return out
+
+            names.learn(nh[by_row], read)
+        return names.buckets(nh)
 
     def _safe_flag(self, item) -> None:
         try:
@@ -1175,14 +1291,13 @@ class KeyAnalytics:
             if tl is None:
                 return
             _, field, n, tenant, khash, name = item
+            idx = None
             if tenant is not None:
                 idx = tl.index_of(tenant, pre_split=True)
-            elif khash is not None and khash in self._kh_tenant:
-                idx = self._kh_tenant[khash]
-            elif name is not None:
-                idx = tl.index_of(name)
-            else:
-                idx = 0
+            elif khash is not None:
+                idx = self._kh.get(khash)
+            if idx is None:
+                idx = tl.index_of(name) if name is not None else 0
             tl.add(idx, field, n)
         except Exception:  # pragma: no cover - must never die
             import logging
@@ -1193,14 +1308,14 @@ class KeyAnalytics:
     def tenant_hint(self, khash: Optional[int] = None,
                     name: Optional[str] = None) -> Optional[str]:
         """Best-effort tenant id for event fields: khash → learned
-        bucket name (GIL-atomic dict read of worker-owned state),
-        else the raw prefix of ``name``.  Never assigns buckets, so
-        it is safe (and cheap) from any serving thread."""
+        bucket name (a lock-free read of the columns the worker last
+        published), else the raw prefix of ``name``.  Never assigns
+        buckets, so it is safe (and cheap) from any serving thread."""
         tl = self._tenants
         if tl is None:
             return None
         if khash is not None:
-            idx = self._kh_tenant.get(int(khash))
+            idx = self._kh.get(khash)
             if idx is not None:
                 try:
                     return tl._tenant_names[idx]

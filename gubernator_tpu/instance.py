@@ -1005,10 +1005,12 @@ class V1Instance:
                     n, tenant_cb=lambda: self._tenant_of_wire(data))
                 ana = self.dispatcher.analytics
                 if ana is not None:
-                    # tenant learn tap: khash_raw rides zero-copy; the
-                    # worker skips the TLV parse once every key is known
-                    ana.tap_wire_names(data, parsed["khash_raw"],
-                                       raw=True)
+                    # tenant learn tap: the parse's views ride
+                    # zero-copy; the worker reads a request TLV only
+                    # for a rate-limit name it has never seen
+                    ana.tap_wire_names(
+                        data, parsed["khash_raw"], parsed["name_hash"],
+                        parsed["tlv_off"], parsed["tlv_len"], raw=True)
                 self.metrics.getratelimit_counter.labels(
                     calltype="api").inc(n)
                 self.metrics.wire_lane_counter.labels(lane=lane).inc(n)
@@ -1080,7 +1082,8 @@ class V1Instance:
             raise
         ana = self.dispatcher.analytics
         if ana is not None:
-            ana.tap_wire_names(data, pre.khash)
+            ana.tap_wire_names(data, pre.khash, pre.name_hash,
+                               pre.tlv_off, pre.tlv_len)
         self.metrics.getratelimit_counter.labels(calltype="api").inc(
             pre.n)
         self.metrics.wire_lane_counter.labels(lane="wire_local").inc(
@@ -1121,7 +1124,8 @@ class V1Instance:
                 f"{self.config.behaviors.batch_limit}")
         ana = self.dispatcher.analytics
         if ana is not None:
-            ana.tap_wire_names(data, pre.khash)
+            ana.tap_wire_names(data, pre.khash, pre.name_hash,
+                               pre.tlv_off, pre.tlv_len)
         self.metrics.getratelimit_counter.labels(calltype="peer").inc(
             pre.n)
         self.metrics.wire_lane_counter.labels(lane="peer_wire").inc(

@@ -267,6 +267,13 @@ class Metrics:
             "wave taps dropped because the analytics queue was full "
             "(analytics never applies backpressure to serving)",
             registry=r)
+        self.analytics_learn_rows = Counter(
+            "gubernator_analytics_learn_rows",
+            "rows of wire calls the tenant learn looked at, by what it "
+            "found: known (the khash table held the key), learned (a "
+            "new key, filed under its tenant's bucket), other (a new "
+            "key filed under __other__: the tenant ledger is full)",
+            ["outcome"], registry=r)
         # Failure-domain resilience (ISSUE 5): degraded-mode serving,
         # health-gated ring churn, admission shedding, and fault
         # injection all need first-class visibility — a cluster riding

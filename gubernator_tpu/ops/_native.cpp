@@ -192,7 +192,11 @@ static inline bool read_varint(const uint8_t** p, const uint8_t* end,
 //   None                                  (needs the pb2 fallback path)
 // | (n, khash_raw u64le, hits i64le, limit i64le, duration i64le,
 //    algorithm i32le, behavior i32le, burst i64le, behavior_or,
-//    tlv_off u64le, tlv_len u64le, created_at i64le)
+//    tlv_off u64le, tlv_len u64le, created_at i64le, name_hash u64le)
+// name_hash is the FNV-1a64 state after the request's `name` alone —
+// what khash_raw continues from before "_" and the unique key are mixed
+// in; the analytics tap learns a key's tenant from it without reading
+// the name again.
 // created_at (field 10, 0 = unset) is the caller's accepted-at clock,
 // stamped by the forward hop (stamp_req_tlvs) so the owner applies the
 // request at the caller's time base — mixing bases resets buckets and
@@ -208,7 +212,7 @@ static PyObject* parse_get_rate_limits(PyObject*, PyObject* arg) {
   const uint8_t* base = (const uint8_t*)view.buf;
   const uint8_t* p = base;
   const uint8_t* end = p + view.len;
-  std::vector<uint64_t> khash;
+  std::vector<uint64_t> khash, name_hash;
   std::vector<int64_t> hits, limit, duration, burst, created;
   std::vector<int32_t> alg, beh;
   std::vector<uint64_t> tlv_off, tlv_len;
@@ -292,6 +296,7 @@ static PyObject* parse_get_rate_limits(PyObject*, PyObject* arg) {
       break;
     }
     uint64_t h = fnv1a64(name_p, (Py_ssize_t)name_len);
+    name_hash.push_back(h);
     const unsigned char us = '_';
     h = fnv1a64(&us, 1, h);
     h = fnv1a64(key_p, (Py_ssize_t)key_len, h);
@@ -323,11 +328,12 @@ static PyObject* parse_get_rate_limits(PyObject*, PyObject* arg) {
   const char* to_p = n ? (const char*)tlv_off.data() : kEmpty;
   const char* tl_p = n ? (const char*)tlv_len.data() : kEmpty;
   const char* cr_p = n ? (const char*)created.data() : kEmpty;
+  const char* nh_p = n ? (const char*)name_hash.data() : kEmpty;
   PyObject* out = Py_BuildValue(
-      "(ny#y#y#y#y#y#y#Ky#y#y#)", n, kh_p, n * 8, hi_p, n * 8, li_p,
+      "(ny#y#y#y#y#y#y#Ky#y#y#y#)", n, kh_p, n * 8, hi_p, n * 8, li_p,
       n * 8, du_p, n * 8, al_p, n * 4, be_p, n * 4, bu_p, n * 8,
       (unsigned long long)beh_or, to_p, n * 8, tl_p, n * 8, cr_p,
-      n * 8);
+      n * 8, nh_p, n * 8);
   return out;
 }
 
@@ -452,7 +458,7 @@ static inline uint64_t mix64(uint64_t x) {
 //                duration_max, value_max, eff_max, td_bound) ->
 //   None                              (needs the classic/pb2 path)
 // | (n, khash u64le, khash_raw u64le, behavior_or,
-//    tlv_off u64le, tlv_len u64le, leaky_rows)
+//    tlv_off u64le, tlv_len u64le, leaky_rows, name_hash u64le)
 //
 // The fused wire ingest: one pass over a GetRateLimitsReq /
 // GetPeerRateLimitsReq that parses, validates, clamps (bit-identical to
@@ -472,7 +478,7 @@ static inline uint64_t mix64(uint64_t x) {
 // gating is the caller's policy — behavior_or is returned for it.
 // leaky_rows counts the LEAKY_BUCKET rows written (the engine's
 // gubernator_wave_leaky_rows counter reads it: no host pass over the
-// algorithm row).
+// algorithm row).  name_hash: as parse_get_rate_limits returns it.
 static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
   Py_buffer view, b64, b32;
   long long now_ms;
@@ -507,7 +513,7 @@ static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
   const uint8_t* base = (const uint8_t*)view.buf;
   const uint8_t* p = base;
   const uint8_t* end = p + view.len;
-  std::vector<uint64_t> khash, khash_raw, tlv_off, tlv_len;
+  std::vector<uint64_t> khash, khash_raw, name_hash, tlv_off, tlv_len;
   khash.reserve(64);
   uint64_t beh_or = 0;
   const uint64_t GREG = 4;  // Behavior.DURATION_IS_GREGORIAN
@@ -583,6 +589,7 @@ static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
       break;
     }
     uint64_t h = fnv1a64(name_p, (Py_ssize_t)name_len);
+    name_hash.push_back(h);
     const unsigned char us = '_';
     h = fnv1a64(&us, 1, h);
     h = fnv1a64(key_p, (Py_ssize_t)key_len, h);
@@ -639,9 +646,10 @@ static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
   const char* kr_p = n ? (const char*)khash_raw.data() : kEmptyW;
   const char* to_p = n ? (const char*)tlv_off.data() : kEmptyW;
   const char* tl_p = n ? (const char*)tlv_len.data() : kEmptyW;
-  return Py_BuildValue("(ny#y#Ky#y#n)", n, kh_p, n * 8, kr_p, n * 8,
+  const char* nh_p = n ? (const char*)name_hash.data() : kEmptyW;
+  return Py_BuildValue("(ny#y#Ky#y#ny#)", n, kh_p, n * 8, kr_p, n * 8,
                        (unsigned long long)beh_or, to_p, n * 8, tl_p,
-                       n * 8, n_leaky);
+                       n * 8, n_leaky, nh_p, n * 8);
 }
 
 // split_resp_items(bytes) ->

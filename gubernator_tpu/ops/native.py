@@ -33,7 +33,7 @@ def parse_get_rate_limits(data: bytes):
     if r is None:
         return None
     (n, kh, hits, limit, dur, alg, beh, burst, beh_or, toff, tlen,
-     created) = r
+     created, nh) = r
     return {
         "n": n,
         "khash_raw": np.frombuffer(kh, "<u8", count=n),
@@ -52,6 +52,9 @@ def parse_get_rate_limits(data: bytes):
         # caller's accepted-at clock (field 10, 0 = unset): forwarded
         # rows apply at THIS time base, not the owner's wall clock
         "created_at": np.frombuffer(created, "<i8", count=n),
+        # raw FNV-1a64 of each request's `name` alone (the state
+        # khash_raw continues from): the analytics tap's tenant learn
+        "name_hash": np.frombuffer(nh, "<u8", count=n),
     }
 
 
@@ -91,7 +94,8 @@ def pack_wire_wave(data: bytes, now_ms: int, a64: np.ndarray,
     classic numpy pack) for anything the lane doesn't model: pb2
     framing, n > m, or any DURATION_IS_GREGORIAN row.  Otherwise
     (n, khash u64[n] MIXED, khash_raw u64[n], behavior_or, tlv_off,
-    tlv_len, leaky_rows — the count of LEAKY_BUCKET rows).  Clamp
+    tlv_len, leaky_rows — the count of LEAKY_BUCKET rows, name_hash
+    u64[n] — raw FNV-1a64 of each request's name alone).  Clamp
     bounds are passed from types.py so the constants have one home;
     clamp arithmetic is pinned bit-identical to core/batch.py ›
     pack_columns by tests/test_native.py."""
@@ -103,14 +107,15 @@ def pack_wire_wave(data: bytes, now_ms: int, a64: np.ndarray,
                                TD_BOUND)
     if r is None:
         return None
-    n, kh, kr, beh_or, toff, tlen, leaky_rows = r
+    n, kh, kr, beh_or, toff, tlen, leaky_rows, nh = r
     return (n,
             np.frombuffer(kh, "<u8", count=n),
             np.frombuffer(kr, "<u8", count=n),
             int(beh_or),
             np.frombuffer(toff, "<u8", count=n),
             np.frombuffer(tlen, "<u8", count=n),
-            leaky_rows)
+            leaky_rows,
+            np.frombuffer(nh, "<u8", count=n))
 
 
 def split_resp_items(data: bytes):

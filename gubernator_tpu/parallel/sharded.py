@@ -50,10 +50,10 @@ class PrepackedWave:
     (or must release it explicitly on a fallback path)."""
 
     __slots__ = ("lease", "n", "khash", "khash_raw", "behavior_or",
-                 "tlv_off", "tlv_len", "leaky_rows")
+                 "tlv_off", "tlv_len", "leaky_rows", "name_hash")
 
     def __init__(self, lease, n, khash, khash_raw, behavior_or,
-                 tlv_off, tlv_len, leaky_rows):
+                 tlv_off, tlv_len, leaky_rows, name_hash):
         self.lease = lease
         self.n = n
         self.khash = khash
@@ -62,6 +62,7 @@ class PrepackedWave:
         self.tlv_off = tlv_off
         self.tlv_len = tlv_len
         self.leaky_rows = leaky_rows
+        self.name_hash = name_hash
 
 
 def autogrow_limit_per_shard(total_rows: int, n_shards: int,

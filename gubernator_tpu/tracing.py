@@ -193,6 +193,9 @@ PHASE_CATALOG: Dict[str, str] = {
     "route.slots": "_wire_mesh_runner: slot-map copy + one lookup a "
                    "row into the mslot column",
     # background
+    "analytics.learn": "key-analytics thread: one drain window's tenant "
+                       "learn items merged into the khash → bucket "
+                       "table (wall and CPU)",
     "peer_flush": "peer send lanes: forward-hop flush round trip",
     "broadcast": "GLOBAL owner tick: one broadcast pass",
     "snapshot": "Loader save blackout",

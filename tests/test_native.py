@@ -51,3 +51,94 @@ def test_errors():
         native.hash_keys([1, 2, 3])
     with pytest.raises(ValueError):
         native.hash_pairs(["a"], ["b", "c"])
+
+
+# ---- the wire parsers' columns (ISSUE 28: name_hash beside them) --------
+
+def _wire_requests():
+    from gubernator_tpu.types import RateLimitRequest
+
+    names = ["acme/x", "globex/y", "plain", "πδ/unicode", "a_b/c_d"]
+    return [RateLimitRequest(
+        name=names[i % len(names)], unique_key=f"user:{i}", hits=i % 4,
+        limit=100 + i, duration=1_000 * (1 + i % 7), algorithm=i % 2,
+        behavior=(2 if i % 5 == 0 else 0), burst=(7 if i % 3 == 0 else 0),
+        created_at=(1_700_000_000_000 + i if i % 11 == 0 else 0))
+        for i in range(300)]
+
+
+def _parsed_columns(parser):
+    """(columns by name, requests, message) from one of the two C++
+    passes over the same message."""
+    import numpy as np
+
+    from gubernator_tpu.core.batch import WaveBufferPool
+    from gubernator_tpu.wire import req_to_tlv
+
+    reqs = _wire_requests()
+    data = b"".join(req_to_tlv(r) for r in reqs)
+    if parser == "parse_get_rate_limits":
+        return native.parse_get_rate_limits(data), reqs, data
+    lease = WaveBufferPool().lease(512)
+    n, kh, kr, beh_or, toff, tlen, leaky, nh = native.pack_wire_wave(
+        data, 1_700_000_000_999, lease.a64, lease.a32)
+    cols = {"n": n, "khash": kh, "khash_raw": kr, "behavior_or": beh_or,
+            "tlv_off": toff, "tlv_len": tlen, "leaky_rows": leaky,
+            "name_hash": nh,
+            "hits": np.array(lease.a64[1][:n]),
+            "limit": np.array(lease.a64[2][:n]),
+            "duration": np.array(lease.a64[3][:n]),
+            "burst_filled": np.array(lease.a64[6][:n]),
+            "now": np.array(lease.a64[7][:n]),
+            "behavior": np.array(lease.a32[0][:n]),
+            "algorithm": np.array(lease.a32[1][:n]),
+            "valid": np.array(lease.a32[2][:n])}
+    lease.release()
+    return cols, reqs, data
+
+
+@pytest.mark.parametrize("parser",
+                         ["parse_get_rate_limits", "pack_wire_wave"])
+def test_name_hash_is_the_fnv_of_the_name_alone(parser):
+    cols, reqs, _ = _parsed_columns(parser)
+    assert cols["name_hash"].dtype.itemsize == 8
+    assert cols["name_hash"].tolist() == \
+        native.hash_keys([r.name for r in reqs]).tolist()
+    # what khash_raw continues from: name, "_", unique key
+    assert cols["khash_raw"].tolist() == native.hash_pairs(
+        [r.name for r in reqs], [r.unique_key for r in reqs]).tolist()
+
+
+@pytest.mark.parametrize("parser",
+                         ["parse_get_rate_limits", "pack_wire_wave"])
+def test_older_columns_beside_name_hash_unchanged(parser):
+    cols, reqs, data = _parsed_columns(parser)
+    n = len(reqs)
+    assert cols["n"] == n
+    assert cols["hits"].tolist() == [r.hits for r in reqs]
+    assert cols["limit"].tolist() == [r.limit for r in reqs]
+    assert cols["duration"].tolist() == [r.duration for r in reqs]
+    assert cols["algorithm"].tolist() == [int(r.algorithm) for r in reqs]
+    assert cols["behavior"].tolist() == [int(r.behavior) for r in reqs]
+    assert cols["behavior_or"] == 2
+    off, ln = cols["tlv_off"].tolist(), cols["tlv_len"].tolist()
+    assert off[0] == 0 and off[-1] + ln[-1] == len(data)
+    assert all(off[i] + ln[i] == off[i + 1] for i in range(n - 1))
+    if parser == "parse_get_rate_limits":
+        assert cols["burst"].tolist() == [r.burst for r in reqs]
+        assert cols["created_at"].tolist() == \
+            [r.created_at for r in reqs]
+        assert set(cols) == {
+            "n", "khash_raw", "hits", "limit", "duration", "algorithm",
+            "behavior", "burst", "behavior_or", "tlv_off", "tlv_len",
+            "created_at", "name_hash"}
+    else:
+        assert cols["khash"].tolist() == hash_request_keys(
+            [r.name for r in reqs],
+            [r.unique_key for r in reqs]).tolist()
+        assert cols["burst_filled"].tolist() == \
+            [r.burst or r.limit for r in reqs]
+        assert cols["now"].tolist() == \
+            [r.created_at or 1_700_000_000_999 for r in reqs]
+        assert cols["leaky_rows"] == sum(int(r.algorithm) for r in reqs)
+        assert cols["valid"].tolist() == [1] * n

@@ -649,8 +649,7 @@ def main() -> int:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={args.chips}")
-        # the branches a TPU backend takes by default, rehearsed
-        os.environ["GUBER_PIPELINE"] = "1"
+        # the branch a TPU backend takes by default, rehearsed
         os.environ["GUBER_PALLAS_SWEEP"] = "1"
     for k in ("GUBER_ENGINE", "GUBER_STEP_IMPL", "GUBER_GLOBAL_MODE",
               "GUBER_WAVE_BUCKETS"):

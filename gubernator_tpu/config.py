@@ -97,7 +97,6 @@ ENV_REGISTRY: Dict[str, str] = {
     "GUBER_PEER_EJECT_AFTER": "circuit-open streak before ring ejection (duration)",
     "GUBER_PEER_HEALTH_GATE": "0 disables the health-gated routing ring",
     "GUBER_PEER_READMIT_AFTER": "recovered time before an ejected peer readmits (duration)",
-    "GUBER_PIPELINE": "1/0 force the launch/sync wave pipeline on/off (default: TPU only)",
     "GUBER_PIPELINE_DEPTH": "in-flight launched waves in the pipeline (min 1)",
     "GUBER_PROBES": "device step: open-addressing probe count (core/step.py)",
     "GUBER_PROFILE_DIR": "on-demand device-profiler capture directory",

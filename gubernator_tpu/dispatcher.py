@@ -304,14 +304,11 @@ class Dispatcher:
         self._shed_rows = 0  # guarded-by: self._submit_mu
         #: recorder rate limit (1/s/reason)
         self._last_shed_event = 0.0  # guarded-by: self._submit_mu
-        #: one idle-path inline runner at a time (see _try_inline)
-        self._inline_mu = threading.Lock()
-        #: pipelining needs BOTH the policy and the engine capability —
-        #: folding them here keeps _try_inline's gate and _run's mode
-        #: agreeing (a capability-less engine must not lose the inline
-        #: fast path to a pipeline that can't exist)
-        self._pipelined = (self._want_pipeline()
-                           and hasattr(engine, "launch_packed"))
+        #: who runs a wave: the worker, always.  Pipelined (launch_packed
+        #: / sync_packed, depth pipeline_depth) when the engine has the
+        #: capability; serial (check_packed / check_batch) for an engine
+        #: without it (OracleEngine) and for list/merged waves.
+        self._pipelined = hasattr(engine, "launch_packed")
         # fused-engine capability (ISSUE 8): the engine emits the
         # heavy-hitter tap columns on device at launch, so the
         # dispatcher's host-side column copies are skipped.
@@ -363,109 +360,9 @@ class Dispatcher:
                                         name="device-dispatcher")
         self._thread.start()
 
-    @staticmethod
-    def _want_pipeline() -> bool:
-        """Launch/sync pipelining (depth K, see pipeline_depth) is
-        TPU-only by default: the CPU backend effectively serializes
-        dispatch, so splitting launch/sync there just adds overhead
-        (measured 644k → 227k dec/s at 16 callers).
-        GUBER_PIPELINE=1/0 overrides."""
-        import os
-
-        pipe_env = os.environ.get("GUBER_PIPELINE", "")
-        if pipe_env:
-            return pipe_env == "1"
-        try:
-            import jax
-
-            return jax.default_backend() == "tpu"
-        except Exception:  # noqa: BLE001
-            return False
-
-    def _try_inline(self) -> bool:
-        """Idle fast path: when nothing is queued and no other caller
-        is inline, the calling thread may run the engine directly —
-        skipping two scheduler wakes plus the coalescing window
-        (~0.4-0.8 ms of the service p99 on a 1-core host).  Disabled
-        under pipelining: there the worker's launch/sync overlap IS
-        the latency optimization and an inline engine call would
-        forfeit it.  Caller must release _inline_mu when True."""
-        if self._pipelined or not self._queue.empty():
-            return False
-        if self._closing.is_set():
-            return False  # _submit raises the closed error uniformly
-        if not self._inline_mu.acquire(blocking=False):
-            return False
-        if self._closing.is_set():
-            # re-checked under _inline_mu: close() drains inliners by
-            # acquiring this mutex AFTER setting _closing, so passing
-            # the first check and then acquiring late must not start
-            # an engine call after close() returned (it would race the
-            # close-time checkpoint snapshot) — ADVICE r4
-            self._inline_mu.release()
-            return False
-        if not self._queue.empty():
-            # a job slipped in: let the worker coalesce it with ours
-            self._inline_mu.release()
-            return False
-        return True
-
-    def run_inline_wave(self, kind: str, nreq: int, fn,
-                        tenant: Optional[str] = None):
-        """Run ``fn()`` (an engine call the caller composed — the fused
-        wire lane, instance.py › _wire_check_fused) as ONE inline wave
-        in the calling thread, with the same engine-lock discipline and
-        wave telemetry as check_batch's idle fast path.  Returns
-        ``fn()``'s result, or the _BUSY sentinel when the idle inline
-        path isn't available (queued jobs / pipelining / closing) — the
-        caller then falls back to the classic submit path."""
-        if not self._try_inline():
-            return self._BUSY
-        try:
-            with self._new_scope() as scope:
-                wid = self._wave_begin(scope, kind, nreq=nreq,
-                                       tenant=tenant)
-                try:
-                    with self._engine_step(wid, scope):
-                        out = fn()
-                except Exception as e:  # noqa: BLE001 - recorded, re-raised
-                    self._wave_end(wid, error=e)
-                    raise
-                with phase("wave.end", self):
-                    self._wave_end(wid)
-                return out
-        finally:
-            self._inline_mu.release()
-
-    #: run_inline_wave's "dispatcher busy" sentinel (None is a valid
-    #: engine-call result, so the miss needs its own identity)
-    _BUSY = object()
-
     def check_batch(self, reqs: Sequence[RateLimitRequest], now_ms: int
                     ) -> List[RateLimitResponse]:
-        """Submit and wait; concurrent callers share device launches.
-        An idle dispatcher runs the wave in the caller thread (a lone
-        job's wave is exactly engine.check_batch — same semantics, no
-        thread handoff)."""
-        if self._try_inline():
-            try:
-                with self._new_scope() as scope:
-                    wid = self._wave_begin(scope, "inline",
-                                           nreq=len(reqs),
-                                           tenant=self._hint_reqs(reqs))
-                    try:
-                        with self._engine_step(wid, scope):
-                            out = self.engine.check_batch(list(reqs),
-                                                          now_ms)
-                    except Exception as e:  # noqa: BLE001 - recorded, re-raised
-                        self._wave_end(wid, error=e)
-                        raise
-                    with phase("wave.end", self):
-                        self._wave_end(wid)
-                        self._tap_reqs(reqs, out)
-                    return out
-            finally:
-                self._inline_mu.release()
+        """Submit and wait; concurrent callers share device launches."""
         return self._submit_and_wait(_Job(list(reqs), now_ms))
 
     def _submit_and_wait(self, job):
@@ -481,8 +378,7 @@ class Dispatcher:
     def check_packed(self, batch, khash, now_ms: int,
                      mslot=None) -> tuple:
         """Columnar submit (see engine.check_packed); coalesces with
-        other packed callers by column concatenation.  Idle → inline
-        (a lone packed job's wave is exactly engine.check_packed).
+        other packed callers by column concatenation.
         Returns the classic 5-tuple of per-request columns; the
         slicing out of the wave's shared result columns happens HERE,
         in the caller's thread (see ResultView).  ``mslot`` (ISSUE 8):
@@ -497,25 +393,6 @@ class Dispatcher:
         wire lanes serialize straight from the view (ops/_native.cpp ›
         build_responses_from_columns) without materializing per-job
         column tuples."""
-        if self._try_inline():
-            try:
-                with self._new_scope() as scope:
-                    wid = self._wave_begin(
-                        scope, "inline_packed", nreq=len(khash),
-                        tenant=self._hint_khash(khash))
-                    try:
-                        with self._engine_step(wid, scope):
-                            out = self._engine_check_packed(
-                                batch, khash, now_ms, mslot)
-                    except Exception as e:  # noqa: BLE001 - recorded, re-raised
-                        self._wave_end(wid, error=e)
-                        raise
-                    with phase("wave.end", self):
-                        self._wave_end(wid)
-                        self._tap_packed(khash, batch.hits, out[0])
-                    return ResultView(out, 0, len(khash))
-            finally:
-                self._inline_mu.release()
         return self._submit_and_wait(
             _PackedJob(batch, khash, now_ms, mslot=mslot))
 
@@ -651,17 +528,15 @@ class Dispatcher:
 
     # ---- wave telemetry -------------------------------------------------
     #
-    # Every engine execution — inline, list, packed, merged, pipelined
+    # Every engine execution — list, packed, merged, pipelined
     # launch/sync — is ONE wave: _wave_begin observes size + per-job
     # queue waits and registers the wave in _inflight (the watchdog's
     # scan set); _wave_end observes duration and resolves stall state.
     # All metric/recorder emission is None-guarded: a bare Dispatcher
     # costs two dict ops and a few deque appends per wave.
 
-    def _wave_begin(self, scope: WaveScope, kind: str, jobs=None,
-                    nreq: int = 0, trace: Optional[str] = None,
-                    slot: Optional[int] = None,
-                    tenant: Optional[str] = None) -> int:
+    def _wave_begin(self, scope: WaveScope, kind: str, jobs,
+                    slot: Optional[int] = None) -> int:
         """Register a wave (see above) inside its already entered
         ``scope``: binds the wave's ids into it, ends the jobs'
         `queue_wait` phases, and opens the coarse `pack` phase at the
@@ -670,35 +545,27 @@ class Dispatcher:
         t0 = self._clock()
         ph = phase("wave.begin", self).begin()
         waits = []
-        parent = None
+        trace = parent = tenant = None
         links = []
-        if jobs:
-            nreq = sum(_job_len(j) for j in jobs)
-            for j in jobs:
-                if j.qwait is not None:
-                    waits.append(j.qwait.end(at=t0))
-                if trace is None:
-                    trace = j.trace
-                    parent = getattr(j, "span", None)
-                elif self.span_recorder is not None and j.trace \
-                        and len(links) < self.WAVE_LINKS:
-                    # fan-in: every OTHER request batched into this
-                    # wave, linked by (trace, span) pairs (bounded)
-                    links.append(f"{j.trace}:{getattr(j, 'span', '') or ''}")
-        elif trace is None:
-            # inline wave: the caller thread IS the request handler, so
-            # its trace context is live right here
-            from .tracing import current_span_id, current_trace_id
-
-            trace = current_trace_id()
-            parent = current_span_id()
+        nreq = sum(_job_len(j) for j in jobs)
+        for j in jobs:
+            if j.qwait is not None:
+                waits.append(j.qwait.end(at=t0))
+            if trace is None:
+                trace = j.trace
+                parent = getattr(j, "span", None)
+            elif self.span_recorder is not None and j.trace \
+                    and len(links) < self.WAVE_LINKS:
+                # fan-in: every OTHER request batched into this
+                # wave, linked by (trace, span) pairs (bounded)
+                links.append(f"{j.trace}:{getattr(j, 'span', '') or ''}")
         wspan = None
         sr = self.span_recorder
         if sr is not None and trace is not None:
             from .tracing import new_span_id
 
             wspan = new_span_id()
-        if tenant is None and jobs and self.recorder is not None:
+        if self.recorder is not None:
             # event-field hint only (one dict probe / prefix split,
             # first job names the wave) — ledger attribution happens
             # in the analytics worker, not here
@@ -727,7 +594,7 @@ class Dispatcher:
             self.metrics.waves_in_flight.inc()
         if self.recorder is not None:
             ev = {"trace": trace, "wave": wid, "wave_kind": kind,
-                  "size": nreq, "jobs": len(jobs) if jobs else 1}
+                  "size": nreq, "jobs": len(jobs)}
             if wspan is not None:
                 ev["span_id"] = wspan
             if gen:
@@ -1219,8 +1086,7 @@ class Dispatcher:
         # are read; completion resolves strictly oldest-first (the
         # in-flight ring is FIFO), preserving per-job splice order.
         # Mixed/list waves flush the pipeline first (bounded caller
-        # latency).  The TPU/CPU policy lives in _want_pipeline (shared
-        # with the inline fast path's gate).
+        # latency).
         from collections import deque
 
         from .tracing import partition_thread
@@ -1502,13 +1368,6 @@ class Dispatcher:
     def close(self) -> None:
         with self._submit_mu:
             self._closing.set()
-        # Drain inline stragglers: a caller that passed _try_inline's
-        # closing check before the set() above may still be inside the
-        # engine — re-acquiring its mutex restores the invariant that
-        # no dispatcher-initiated engine call is in flight once close()
-        # returns (instance.close snapshots engine state right after).
-        with self._inline_mu:
-            pass
         self._thread.join(timeout=10)
         if self._watchdog is not None:
             self._watchdog.join(timeout=5)

@@ -81,7 +81,7 @@ FAULT_POINTS = {
                        "call, before per-job futures resolve from the "
                        "shared result columns (delay mode holds "
                        "responses while later waves launch)",
-    "device_step": "the engine call itself (inline and queued waves)",
+    "device_step": "the engine call itself (every wave, on the worker)",
     "wire_ingest": "instance wire entry — before the C++ parse",
     "global_broadcast": "GlobalManager._run_broadcasts — before the "
                         "owner broadcast tick",

@@ -410,7 +410,12 @@ class GlobalManager:
                        hits=acc, limit=proto.limit,
                        duration=proto.duration,
                        algorithm=proto.algorithm, behavior=proto.behavior,
-                       burst=proto.burst)))
+                       burst=proto.burst,
+                       # _req_stamped's stamp (the raw lane's TLVs
+                       # carry theirs): without it the owner applies
+                       # the aggregate at its wall clock, and a row on
+                       # an older base reads as expired — bucket reset
+                       created_at=proto.created_at)))
             addr = peer.info.grpc_address
             slot = by_owner.setdefault(addr, (peer, [], []))
             slot[1].append(tlv)

@@ -1133,47 +1133,14 @@ class V1Instance:
         return self._run_fused(pre, now)
 
     def _run_fused(self, pre, now: int) -> bytes:
-        """Execute a prepacked wave and serialize its responses.  Idle:
-        one inline wave in this thread (block order == request order,
-        so results serialize straight from the engine columns).  Busy:
-        the lease's rows rebuild into a RequestBatch and ride the
-        normal coalescing submit path."""
-        disp = self.dispatcher
-        eng = self.engine
-        n = pre.n
-        ana = disp.analytics
-        # the hits column lives in the LEASED matrices, which the next
-        # wave reuses once check_prepacked releases them — snapshot it
-        # up front when the tap will need it (khash is lease-free).
-        # Fused engines (ISSUE 8) emit the tap ON DEVICE inside the
-        # wave — this host copy is exactly what the fusion deletes.
-        hits_tap = (np.array(pre.lease.a64[1][:n])
-                    if ana is not None and not disp._fused_tap
-                    else None)
-        out = disp.run_inline_wave(
-            "inline_wire", n, lambda: eng.check_prepacked(pre, now))
-        if out is not disp._BUSY:
-            status, lim, rem, rst, full = out
-            self.metrics.over_limit_counter.inc(
-                int((status == 1).sum()))
-            errors = None
-            if full.any():
-                errors = [None] * n
-                for i in np.nonzero(full)[0]:
-                    errors[int(i)] = "rate limit table full"
-                    if ana is not None:
-                        ana.tap_flag("errors", 1,
-                                     khash=int(pre.khash[int(i)]))
-            with phase("build", disp):
-                resp = _wire_native.build_responses_from_columns(
-                    (status, lim, rem, rst, full), 0, n, errors)
-            if ana is not None:
-                disp._tap_packed(pre.khash, hits_tap, status)
-            return resp
-        # contended: copy the rows out of the lease (the queued job
-        # outlives it) and coalesce with the other callers' waves
+        """Submit a prepacked call to the dispatcher and serialize its
+        responses: the rows are copied out of the lease (the queued job
+        outlives it) and coalesce with the other callers' waves."""
         from .core.batch import RequestBatch
 
+        disp = self.dispatcher
+        n = pre.n
+        ana = disp.analytics
         a64, a32 = pre.lease.a64, pre.lease.a32
         batch = RequestBatch(
             key=a64[0][:n].astype(np.int64).view(np.uint64),

@@ -30,7 +30,7 @@ _BUCKETS = (.0005, .001, .0025, .005, .01, .025, .05, .1, .25, .5, 1.0, 2.5)
 #: watchdog exists for is invisible.
 _WAVE_DURATION_BUCKETS = _BUCKETS + (10.0, 30.0, 60.0, 120.0, 300.0, 600.0)
 
-#: requests per coalesced wave: 1 (idle inline) up to max_wave (8192
+#: requests per coalesced wave: 1 (a lone call) up to max_wave (8192
 #: default) and beyond for merged packed columns
 _WAVE_SIZE_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 1024, 4096,
                       16384, 65536)
@@ -133,8 +133,8 @@ class Metrics:
             "gubernator_wave_leaky_rows",
             "LEAKY_BUCKET rows that entered a wave's device program "
             "(valid, inside the step program's domain, not cold-tier "
-            "served), counted once a wave at the engine's wave.route, "
-            "by the fused wire ingest for an inline wave", registry=r)
+            "served), counted once a wave at the engine's wave.route",
+            registry=r)
         self.wave_queue_wait = Histogram(
             "gubernator_dispatcher_queue_wait",
             "job wait from submit to its wave launching (s)",

@@ -457,8 +457,8 @@ static inline uint64_t mix64(uint64_t x) {
 // pack_wire_wave(data, now_ms, a64, a32, m,
 //                duration_max, value_max, eff_max, td_bound) ->
 //   None                              (needs the classic/pb2 path)
-// | (n, khash u64le, khash_raw u64le, behavior_or,
-//    tlv_off u64le, tlv_len u64le, leaky_rows, name_hash u64le)
+// | (n, khash u64le, behavior_or, tlv_off u64le, tlv_len u64le,
+//    name_hash u64le)
 //
 // The fused wire ingest: one pass over a GetRateLimitsReq /
 // GetPeerRateLimitsReq that parses, validates, clamps (bit-identical to
@@ -476,9 +476,7 @@ static inline uint64_t mix64(uint64_t x) {
 // parse_get_rate_limits), n > m, or any DURATION_IS_GREGORIAN row
 // (calendar period ends are computed in Python).  GLOBAL/MULTI_REGION
 // gating is the caller's policy — behavior_or is returned for it.
-// leaky_rows counts the LEAKY_BUCKET rows written (the engine's
-// gubernator_wave_leaky_rows counter reads it: no host pass over the
-// algorithm row).  name_hash: as parse_get_rate_limits returns it.
+// name_hash: as parse_get_rate_limits returns it.
 static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
   Py_buffer view, b64, b32;
   long long now_ms;
@@ -513,12 +511,12 @@ static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
   const uint8_t* base = (const uint8_t*)view.buf;
   const uint8_t* p = base;
   const uint8_t* end = p + view.len;
-  std::vector<uint64_t> khash, khash_raw, name_hash, tlv_off, tlv_len;
+  std::vector<uint64_t> khash, name_hash, tlv_off, tlv_len;
   khash.reserve(64);
   uint64_t beh_or = 0;
   const uint64_t GREG = 4;  // Behavior.DURATION_IS_GREGORIAN
   bool fallback = false;
-  Py_ssize_t n = 0, n_leaky = 0;
+  Py_ssize_t n = 0;
   while (p < end) {
     const uint8_t* tlv_start = p;
     uint64_t tag, len;
@@ -593,7 +591,6 @@ static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
     const unsigned char us = '_';
     h = fnv1a64(&us, 1, h);
     h = fnv1a64(key_p, (Py_ssize_t)key_len, h);
-    khash_raw.push_back(h);
     uint64_t hm = mix64(h);
     if (hm == 0) hm = 1;
     khash.push_back(hm);
@@ -634,7 +631,6 @@ static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
     r_alg[n] = leaky ? 1 : 0;
     r_valid[n] = 1;
     beh_or |= (uint64_t)(uint32_t)f_beh;
-    n_leaky += leaky;
     n++;
   }
   PyBuffer_Release(&view);
@@ -643,13 +639,12 @@ static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
   if (fallback) Py_RETURN_NONE;
   static const char kEmptyW[1] = {0};
   const char* kh_p = n ? (const char*)khash.data() : kEmptyW;
-  const char* kr_p = n ? (const char*)khash_raw.data() : kEmptyW;
   const char* to_p = n ? (const char*)tlv_off.data() : kEmptyW;
   const char* tl_p = n ? (const char*)tlv_len.data() : kEmptyW;
   const char* nh_p = n ? (const char*)name_hash.data() : kEmptyW;
-  return Py_BuildValue("(ny#y#Ky#y#ny#)", n, kh_p, n * 8, kr_p, n * 8,
+  return Py_BuildValue("(ny#Ky#y#y#)", n, kh_p, n * 8,
                        (unsigned long long)beh_or, to_p, n * 8, tl_p,
-                       n * 8, n_leaky, nh_p, n * 8);
+                       n * 8, nh_p, n * 8);
 }
 
 // split_resp_items(bytes) ->

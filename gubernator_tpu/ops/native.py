@@ -93,8 +93,7 @@ def pack_wire_wave(data: bytes, now_ms: int, a64: np.ndarray,
     Returns None (caller releases the lease and falls back to the
     classic numpy pack) for anything the lane doesn't model: pb2
     framing, n > m, or any DURATION_IS_GREGORIAN row.  Otherwise
-    (n, khash u64[n] MIXED, khash_raw u64[n], behavior_or, tlv_off,
-    tlv_len, leaky_rows — the count of LEAKY_BUCKET rows, name_hash
+    (n, khash u64[n] MIXED, behavior_or, tlv_off, tlv_len, name_hash
     u64[n] — raw FNV-1a64 of each request's name alone).  Clamp
     bounds are passed from types.py so the constants have one home;
     clamp arithmetic is pinned bit-identical to core/batch.py ›
@@ -107,14 +106,12 @@ def pack_wire_wave(data: bytes, now_ms: int, a64: np.ndarray,
                                TD_BOUND)
     if r is None:
         return None
-    n, kh, kr, beh_or, toff, tlen, leaky_rows, nh = r
+    n, kh, beh_or, toff, tlen, nh = r
     return (n,
             np.frombuffer(kh, "<u8", count=n),
-            np.frombuffer(kr, "<u8", count=n),
             int(beh_or),
             np.frombuffer(toff, "<u8", count=n),
             np.frombuffer(tlen, "<u8", count=n),
-            leaky_rows,
             np.frombuffer(nh, "<u8", count=n))
 
 

@@ -43,25 +43,23 @@ VALUE_COLS = tuple(f for f in TableState._fields if f != "key")
 
 
 class PrepackedWave:
-    """One fused-ingest wave: a leased packed upload pair with rows
+    """One fused-ingest call: a leased packed upload pair with rows
     [0, n) already parsed/clamped/hashed in C++ (pack_wire_wave), plus
     the per-request metadata the serving lanes gate on.  The holder
-    owns the lease until ``ShardedEngine.check_prepacked`` consumes it
-    (or must release it explicitly on a fallback path)."""
+    owns the lease and must release it on every path (instance.py ›
+    _run_fused copies the rows out and releases it)."""
 
-    __slots__ = ("lease", "n", "khash", "khash_raw", "behavior_or",
-                 "tlv_off", "tlv_len", "leaky_rows", "name_hash")
+    __slots__ = ("lease", "n", "khash", "behavior_or", "tlv_off",
+                 "tlv_len", "name_hash")
 
-    def __init__(self, lease, n, khash, khash_raw, behavior_or,
-                 tlv_off, tlv_len, leaky_rows, name_hash):
+    def __init__(self, lease, n, khash, behavior_or, tlv_off, tlv_len,
+                 name_hash):
         self.lease = lease
         self.n = n
         self.khash = khash
-        self.khash_raw = khash_raw
         self.behavior_or = behavior_or
         self.tlv_off = tlv_off
         self.tlv_len = tlv_len
-        self.leaky_rows = leaky_rows
         self.name_hash = name_hash
 
 
@@ -762,8 +760,7 @@ class ShardedEngine:
         the classic parse → pack_columns path.
 
         Returns a PrepackedWave whose lease the caller OWNS: every
-        return path must end in check_prepacked (which releases it) or
-        an explicit ``pre.lease.release()``."""
+        return path must end in ``pre.lease.release()``."""
         if self.n != 1 or _wire_native is None:
             return None
         cnt = _wire_native.count_req_items(data)
@@ -779,85 +776,6 @@ class ShardedEngine:
             lease.release()
             return None
         return PrepackedWave(lease, *res)
-
-    def check_prepacked(self, pre: "PrepackedWave", now_ms: int) -> tuple:
-        """Launch + resolve a prepacked wave.  Returns the check_packed
-        5-tuple (status i32, limit, remaining, reset, table_full) over
-        rows [0, pre.n) — block order IS request order on the 1-shard
-        mesh, so no slot gather happens.  Releases the lease on every
-        path.  Table-full rows ride the classic sweep-retry path (the
-        erred rows never mutated state, so re-running just them through
-        check_packed is the same recovery check_batch performs)."""
-        n = pre.n
-        lease = pre.lease
-        # cold-tier rows (tiering.py) must not reach the device insert:
-        # zero their valid flag in the leased matrices, then route them
-        # through the same check_packed rebuild the table-full retry
-        # uses (check_packed serves them from the cold tier)
-        tier = self.tier
-        cold_i = None
-        if tier is not None:
-            kh_n = np.asarray(pre.khash[:n], np.uint64)
-            cm = (tier.resident_mask(kh_n) & (kh_n != 0)
-                  & (lease.a32[2][:n] != 0))
-            if cm.any():
-                cold_i = np.nonzero(cm)[0]
-                lease.a32[2][cold_i] = 0
-        # the fused ingest counted them; cold rows are served on the host
-        self._count_leaky_rows(
-            pre.leaky_rows if cold_i is None else
-            pre.leaky_rows - np.count_nonzero(lease.a32[1][cold_i]))
-        try:
-            # retry needs the request columns; snapshot them from the
-            # lease ONLY if the cheap error scan demands it (below)
-            launched = self._launch_arrays(lease.a64, lease.a32, now_ms)
-            o_st, o_rem, o_rst, o_lim, o_err = self._finish_wave(
-                *launched)
-            err = o_err[:n]
-            if not err.any() and cold_i is None:
-                lease.release()
-                lease = None
-                return (o_st[:n].astype(np.int32), o_lim[:n], o_rem[:n],
-                        o_rst[:n], err)
-            # rare path: probe windows exhausted (or cold-tier rows) —
-            # rebuild those rows as a RequestBatch from the still-leased
-            # matrices and push them through check_packed (sweep-retry/
-            # auto-grow/cold serve live there; non-erred rows already
-            # applied, so only this subset re-runs)
-            ei = np.nonzero(err)[0]
-            if cold_i is not None:
-                lease.a32[2][cold_i] = 1  # restore valid for the rebuild
-                ei = np.unique(np.concatenate([ei, cold_i]))
-            a64, a32 = lease.a64, lease.a32
-            sub = RequestBatch(
-                key=a64[0][ei].view(np.uint64),
-                hits=a64[1][ei].copy(), limit=a64[2][ei].copy(),
-                duration=a64[3][ei].copy(), eff_ms=a64[4][ei].copy(),
-                greg_end=a64[5][ei].copy(),
-                behavior=a32[0][ei].copy(), algorithm=a32[1][ei].copy(),
-                burst=a64[6][ei].copy(), valid=a32[2][ei] != 0,
-                now=a64[7][ei].copy())
-            khash_sub = pre.khash[ei]
-            lease.release()
-            lease = None
-            status = o_st[:n].astype(np.int32)
-            lim_o = o_lim[:n].copy()
-            rem_o = o_rem[:n].copy()
-            rst_o = o_rst[:n].copy()
-            full = np.zeros(n, bool)
-            if err.any():  # cold-only subsets skip the expiry sweep
-                self.sweep(now_ms)
-            r_st, r_lim, r_rem, r_rst, r_full = self.check_packed(
-                sub, khash_sub, now_ms)
-            status[ei] = r_st
-            lim_o[ei] = r_lim
-            rem_o[ei] = r_rem
-            rst_o[ei] = r_rst
-            full[ei] = r_full
-            return status, lim_o, rem_o, rst_o, full
-        finally:
-            if lease is not None:
-                lease.release()
 
     def check_batch(self, reqs: Sequence[RateLimitRequest], now_ms: int
                     ) -> List[RateLimitResponse]:

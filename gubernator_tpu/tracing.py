@@ -155,8 +155,8 @@ PHASE_CATALOG: Dict[str, str] = {
               "IN-FLIGHT time, not device-busy time",
     "resolve": "dispatcher: results on the host → wave end",
     "queue_wait": "dispatcher: a job's wait from submit to its wave",
-    # the dispatch worker's wall time, partitioned (inline and
-    # unpipelined waves run the wave.*/lock.* ones in their own thread)
+    # the dispatch worker's wall time, partitioned (it runs every
+    # wave; serial waves record the same wave.*/lock.* names)
     "worker.wait": "_drain_wave: blocked on an empty queue",
     "worker.coalesce": "_drain_wave: first job → wave returned",
     "worker.gap": "the dispatch worker BETWEEN two of its phases: "

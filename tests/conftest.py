@@ -49,6 +49,23 @@ def cpu_mesh():
 
 
 @pytest.fixture
+def serial_only():
+    """``serial_only(engine)``: the same engine WITHOUT ``launch_packed``
+    — what the dispatcher's serial branch serves.  (OracleEngine, the
+    one capability-less engine in the tree, has no columnar entry at
+    all; this keeps check_packed, so both worker branches can be held
+    to the same answers.)  Entries are bound when it is built: gate the
+    engine's methods first."""
+
+    class SerialOnly:
+        def __init__(self, eng):
+            self.check_packed = eng.check_packed
+            self.check_batch = eng.check_batch
+
+    return SerialOnly
+
+
+@pytest.fixture
 def numpy_calls():
     """Context manager that counts what a block asks of numpy: every
     numpy function and ndarray method the profiler sees in the calling

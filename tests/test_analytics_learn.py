@@ -58,7 +58,7 @@ def _learn_item(reqs):
 
     data = _message(reqs)
     lease = WaveBufferPool().lease(1024)
-    _n, kh, _kr, _bo, toff, tlen, _lk, nh = native.pack_wire_wave(
+    _n, kh, _bo, toff, tlen, nh = native.pack_wire_wave(
         data, 1, lease.a64, lease.a32)
     lease.release()
     return ("learn", data, kh, nh, toff, tlen, False)

@@ -139,3 +139,71 @@ class TestPackers:
         b, _ = pack_columns(kh, z + 1, z + 10, z + DAY, z.copy(),
                             np.zeros(n, np.int32), z.copy(), T0)
         assert b.now.tolist() == [T0, T0]
+
+
+class TestHitFlushKeepsTheStamp:
+    """The deferred hit flush (GLOBAL reconcile, degraded-mode
+    reconcile) rebuilds ONE aggregate request per key.  The aggregate
+    must keep the stamp its queued requests were given: dropped, the
+    owner applies the hits at its wall clock, and a row living on an
+    older base reads as expired — the partition scenario's conservation
+    loss once the wall clock had passed the lab's clock by a window."""
+
+    def _flush(self, queue):
+        from concurrent.futures import Future
+
+        from gubernator_tpu.config import BehaviorConfig
+        from gubernator_tpu.global_manager import GlobalManager
+        from gubernator_tpu.metrics import Metrics
+
+        sent = []
+
+        class Peer:
+            class info:
+                grpc_address = "owner:1"
+
+            def forward_raw(self, data, n):
+                sent.append((data, n))
+                f = Future()
+                f.set_result(b"")
+                return f
+
+        class Inst:
+            def owner_by_raw_khash(self, kh):
+                return Peer()
+
+            def is_self(self, peer):
+                return False
+
+            def default_hash_routing(self):
+                return True
+
+        gm = GlobalManager(Inst(), BehaviorConfig(
+            global_sync_wait_ms=3_600_000), Metrics())
+        try:
+            queue(gm)
+            gm._hits_tick()
+        finally:
+            gm.close()
+        (data, n), = sent
+        assert n == 1
+        return req_from_tlv(data)
+
+    def test_object_lane_aggregate_carries_the_latest_stamp(self):
+        def queue(gm):
+            gm.queue_hits(_req("a", created=T0 + 5, hits=2), degraded=True)
+            gm.queue_hits(_req("a", created=T0 + 9, hits=3), degraded=True)
+
+        back = self._flush(queue)
+        assert (back.hits, back.created_at) == (5, T0 + 9)
+
+    def test_raw_lane_aggregate_carries_its_tlvs_stamp(self):
+        from gubernator_tpu.hashing import fnv1a64
+
+        def queue(gm):
+            kh = fnv1a64(b"ca_a")
+            gm.queue_hits_raw(kh, req_to_tlv(_req("a", created=T0 + 5)), 2)
+            gm.queue_hits_raw(kh, req_to_tlv(_req("a", created=T0 + 9)), 3)
+
+        back = self._flush(queue)
+        assert (back.hits, back.created_at) == (5, T0 + 9)

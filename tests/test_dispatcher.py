@@ -86,38 +86,91 @@ def test_close_rejects_new_and_drains(engine):
         d.check_batch([req("post")], NOW)
 
 
-def test_inline_never_starts_after_close(engine):
-    """ADVICE r4 (low): a caller that passes _try_inline's first
-    closing check and is then preempted across a full close() must NOT
-    win the inline path — close()'s drain guarantee is that no
-    dispatcher-initiated engine call STARTS after it returns (the
-    close-time checkpoint snapshot depends on it).  The preemption is
-    simulated deterministically: the inline mutex's acquire runs
-    close() to completion before actually acquiring."""
+def test_close_resolves_in_flight_and_starts_nothing_after(engine):
+    """close() while one wave is in flight on the device and more jobs
+    are queued behind it: the in-flight wave resolves with its answers,
+    every queued job either resolved before close() returned or failed
+    with "dispatcher closed" (none is left hanging), a submit after it
+    is refused, and NO engine call starts once close() has returned —
+    instance.close snapshots the engine's state right after."""
+    import numpy as np
+
+    from gubernator_tpu.core.batch import pack_columns
+    from gubernator_tpu.hashing import hash_request_keys
+
+    calls = []  # (entry, started-at) of every engine call
+    in_launch = threading.Event()
+    release = threading.Event()
+    orig_launch = engine.launch_packed
+
+    def gated_launch(batch, kh, now, **kw):
+        calls.append(("launch_packed", time.monotonic()))
+        in_launch.set()
+        release.wait(timeout=30)
+        return orig_launch(batch, kh, now, **kw)
+
+    for name in ("sync_packed", "check_packed", "check_batch"):
+        def entry(*a, _f=getattr(engine, name), _n=name, **kw):
+            calls.append((_n, time.monotonic()))
+            return _f(*a, **kw)
+        setattr(engine, name, entry)
+    engine.launch_packed = gated_launch
     d = Dispatcher(engine)
-    real_mu = d._inline_mu
+    assert d._pipelined
 
-    class RacingLock:
-        def acquire(self, blocking=True):
-            if not d._closing.is_set():
-                d.close()  # completes fully: sets closing + drains
-            return real_mu.acquire(blocking)
+    def cols(tag):
+        kh = hash_request_keys(["cl"] * 4, [f"{tag}{i}" for i in range(4)])
+        b, _ = pack_columns(kh, np.ones(4, np.int64),
+                            np.full(4, 50, np.int64),
+                            np.full(4, 60_000, np.int64),
+                            np.zeros(4, np.int32), np.zeros(4, np.int32),
+                            np.zeros(4, np.int64), NOW)
+        return b, kh
 
-        def release(self):
-            real_mu.release()
+    outcomes = {}
 
-        def __enter__(self):
-            real_mu.acquire()
-            return self
+    def call(tag):
+        b, kh = cols(tag)
+        try:
+            outcomes[tag] = d.check_packed(b, kh, NOW)
+        except BaseException as e:  # noqa: BLE001 - the verdict
+            outcomes[tag] = e
 
-        def __exit__(self, *exc):
-            real_mu.release()
-
-    d._inline_mu = RacingLock()
-    assert d._try_inline() is False
-    # the mutex was released on the refusal path
-    assert real_mu.acquire(blocking=False)
-    real_mu.release()
+    threads = [threading.Thread(target=call, args=("first",))]
+    threads[0].start()
+    assert in_launch.wait(timeout=30)  # the worker is inside the engine
+    for tag in ("q1", "q2"):
+        threads.append(threading.Thread(target=call, args=(tag,)))
+        threads[-1].start()
+    deadline = time.monotonic() + 30
+    while d._queue.qsize() < 2 and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert d._queue.qsize() == 2
+    closer = threading.Thread(target=d.close)
+    closer.start()
+    assert d._closing.wait(timeout=30)
+    release.set()
+    closer.join(timeout=60)
+    assert not closer.is_alive()
+    closed_at = time.monotonic()
+    for th in threads:
+        th.join(timeout=60)
+        assert not th.is_alive()
+    # the in-flight wave resolved with real answers
+    st, lim, rem, rst, full = outcomes["first"]
+    assert rem.tolist() == [49] * 4 and not full.any()
+    # queued jobs: served before close() returned, or failed by it
+    for tag in ("q1", "q2"):
+        got = outcomes[tag]
+        if isinstance(got, BaseException):
+            assert "dispatcher closed" in str(got)
+        else:
+            assert got[2].tolist() == [49] * 4
+    with pytest.raises(RuntimeError, match="dispatcher is closed"):
+        d.check_packed(*cols("late"), NOW)
+    time.sleep(0.05)
+    assert calls and all(t <= closed_at for _, t in calls), calls
+    assert not d._thread.is_alive()
 
 
 def test_merged_cross_now_batch_matches_sequential_oracle():
@@ -174,14 +227,14 @@ def test_merged_cross_now_batch_matches_sequential_oracle():
 import pytest
 
 
-@pytest.mark.parametrize("pipeline", ["0", "1"])
-def test_dispatcher_merges_packed_jobs_across_nows(pipeline, monkeypatch):
+@pytest.mark.parametrize("entry", ["launch_packed", "check_packed"])
+def test_dispatcher_merges_packed_jobs_across_nows(entry, serial_only):
     """Queued packed jobs with different now_ms share one launch (the
     old dispatcher quantized by timestamp and could not merge them).
     Deterministic: the engine is blocked while the jobs queue up.
-    Covers BOTH dispatcher paths: synchronous check_packed (CPU
-    default) and the launch/sync pipeline (TPU default, forced here
-    via GUBER_PIPELINE=1)."""
+    Covers BOTH worker branches, chosen from the engine's capability:
+    the launch/sync pipeline (an engine with ``launch_packed``) and the
+    serial check_packed (an engine without)."""
     import threading
 
     import numpy as np
@@ -191,14 +244,13 @@ def test_dispatcher_merges_packed_jobs_across_nows(pipeline, monkeypatch):
     from gubernator_tpu.hashing import hash_request_keys
     from gubernator_tpu.parallel import ShardedEngine, make_mesh
 
-    monkeypatch.setenv("GUBER_PIPELINE", pipeline)
     NOW = 1_777_000_000_000
     eng = ShardedEngine(make_mesh(n=2), capacity_per_shard=1 << 9,
                         batch_per_shard=64)
     launches = []
     release = threading.Event()
-    # gate whichever entry the selected path uses
-    orig = eng.launch_packed if pipeline == "1" else eng.check_packed
+    # gate whichever entry the selected branch uses
+    orig = getattr(eng, entry)
 
     entered = threading.Event()
 
@@ -208,11 +260,10 @@ def test_dispatcher_merges_packed_jobs_across_nows(pipeline, monkeypatch):
         launches.append(len(kh))
         return orig(batch, kh, now)
 
-    if pipeline == "1":
-        eng.launch_packed = gated
-    else:
-        eng.check_packed = gated
-    disp = Dispatcher(eng, max_delay_ms=0.2)
+    setattr(eng, entry, gated)
+    disp = Dispatcher(eng if entry == "launch_packed" else serial_only(eng),
+                      max_delay_ms=0.2)
+    assert disp._pipelined == (entry == "launch_packed")
 
     def cols(now):
         kh = hash_request_keys(["dm"] * 4, [f"q{i}" for i in range(4)])
@@ -223,27 +274,20 @@ def test_dispatcher_merges_packed_jobs_across_nows(pipeline, monkeypatch):
                             np.zeros(4, np.int64), now)
         return b, kh
 
-    # Force the queue path for every caller (the idle-inline fast path
-    # would otherwise run job 1 in its caller's thread and leave the
-    # worker free to drain jobs 2/3 early): with _inline_mu held, the
-    # first job blocks the WORKER inside the engine call and the other
-    # two queue up behind it, merging into ONE later launch.
-    disp._inline_mu.acquire()
-    try:
-        threads = []
-        for t in range(3):
-            b, kh = cols(NOW + t)
+    # the first job blocks the WORKER inside the engine call and the
+    # other two queue up behind it, merging into ONE later launch
+    threads = []
+    for t in range(3):
+        b, kh = cols(NOW + t)
 
-            def call(b=b, kh=kh, t=t):
-                disp.check_packed(b, kh, NOW + t)
+        def call(b=b, kh=kh, t=t):
+            disp.check_packed(b, kh, NOW + t)
 
-            th = threading.Thread(target=call)
-            th.start()
-            threads.append(th)
-            if t == 0:
-                assert entered.wait(timeout=30)
-    finally:
-        disp._inline_mu.release()
+        th = threading.Thread(target=call)
+        th.start()
+        threads.append(th)
+        if t == 0:
+            assert entered.wait(timeout=30)
     deadline = time.monotonic() + 30
     while disp._queue.qsize() < 2 and time.monotonic() < deadline:
         time.sleep(0.01)
@@ -309,12 +353,9 @@ def test_mixed_wave_cross_now_merges_list_and_packed_jobs():
         return b, kh
 
     results = {}
-    # Force the queue path for ALL callers (see the inline-fast-path
-    # note in the merge test above): job 0 blocks the WORKER inside the
-    # engine; the rest queue up behind it.  _inline_mu stays held until
-    # every job is IN the queue — the try starts immediately so any
-    # assert in the setup still releases the mutex and the blocker.
-    disp._inline_mu.acquire()
+    # job 0 blocks the WORKER inside the engine; the rest queue up
+    # behind it.  The try starts immediately so any assert in the setup
+    # still releases the blocker.
     try:
         threads = [threading.Thread(
             target=lambda: results.setdefault(
@@ -341,7 +382,6 @@ def test_mixed_wave_cross_now_merges_list_and_packed_jobs():
             _t.sleep(0.01)
         assert disp._queue.qsize() >= 3
     finally:
-        disp._inline_mu.release()
         release.set()
     for t in threads:
         t.join(timeout=60)

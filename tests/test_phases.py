@@ -2,8 +2,9 @@
 ``tracing.phase`` → TraceAnnotation + gubernator_phase_duration{phase} /
 PhaseLedger + a span with real timestamps.
 
-Run with the wave pipeline forced on (``GUBER_PIPELINE=1``), so CI runs
-the launch/sync path the chip runs:
+Every wave runs on the dispatch worker, through the launch/sync
+pipeline wherever the engine has ``launch_packed`` — the path the chip
+runs:
 
 - the dispatch worker's phases partition its wall time;
 - pack + device + resolve == gubernator_dispatcher_wave_duration, WITH
@@ -46,7 +47,6 @@ COARSE = ("pack", "device", "resolve")
 
 @pytest.fixture()
 def pipelined(monkeypatch):
-    monkeypatch.setenv("GUBER_PIPELINE", "1")
     monkeypatch.delenv("GUBER_ENGINE", raising=False)
     monkeypatch.delenv("GUBER_STEP_IMPL", raising=False)
     return monkeypatch
@@ -107,7 +107,7 @@ def test_worker_phases_partition_the_workers_wall_time(pipelined, engine):
     t0 = time.perf_counter()
     d = Dispatcher(engine, analytics=ka)
     try:
-        assert d._pipelined  # inline is off: every wave runs on the worker
+        assert d._pipelined  # the engine has launch_packed
         hammer(lambda s: d.check_packed(*packed(s), NOW))
     finally:
         d.close()  # joins the worker
@@ -250,44 +250,57 @@ def test_phase_span_timestamps_are_clock_readings():
 # ---- catalog ↔ emitted ↔ code literals ↔ OBSERVABILITY.md --------------
 
 
-@pytest.mark.parametrize("pipeline", ["1", "0"])
-def test_every_emitted_phase_is_catalogued(monkeypatch, pipeline):
+#: what a wave on the worker records whatever the engine
+WORKER_SIDE = {"wave.begin", "lock.engine", "wave.resolve", "wave.end",
+               "worker.wait", "worker.coalesce", "queue_wait", *COARSE}
+
+
+@pytest.mark.parametrize("engine_kind", ["launch_packed", "serial"])
+def test_every_emitted_phase_is_catalogued(monkeypatch, engine_kind):
     """Drive the wire lanes (local keys and GLOBAL on the mesh tier)
-    through the daemon-less instance: whatever lands in the ledger is a
-    catalogued name, and both pipeline modes use the same names for the
-    same work."""
-    monkeypatch.setenv("GUBER_PIPELINE", pipeline)
+    and the object lane through the daemon-less instance: whatever
+    lands in the ledger is a catalogued name, and both worker branches — the pipeline of an
+    engine with ``launch_packed``, the serial branch of one without
+    (OracleEngine) — use the same names for the same work."""
     monkeypatch.delenv("GUBER_ENGINE", raising=False)
     monkeypatch.delenv("GUBER_STEP_IMPL", raising=False)
     monkeypatch.setenv("GUBER_MESH_GLOBAL_CAP", "256")
-    inst = V1Instance(Config(
-        cache_size=1 << 12, sweep_interval_ms=0, engine="pallas",
-        global_mode="mesh", batch_rows=64,
-        behaviors=BehaviorConfig(global_sync_wait_ms=100)),
-        mesh=make_mesh(n=8))
+    if engine_kind == "serial":
+        from gubernator_tpu.oracle import OracleEngine
+
+        inst = V1Instance(Config(cache_size=1 << 12, sweep_interval_ms=0),
+                          engine=OracleEngine())
+    else:
+        inst = V1Instance(Config(
+            cache_size=1 << 12, sweep_interval_ms=0, engine="pallas",
+            global_mode="mesh", batch_rows=64,
+            behaviors=BehaviorConfig(global_sync_wait_ms=100)),
+            mesh=make_mesh(n=8))
     try:
+        assert inst.dispatcher._pipelined == (engine_kind != "serial")
         for i in range(3):
-            inst.get_rate_limits_wire(ser(20), now_ms=NOW + i)
-            inst.get_rate_limits_wire(
-                ser(20, name="g", behavior=Behavior.GLOBAL),
-                now_ms=NOW + i)
+            if engine_kind != "serial":  # OracleEngine: object lane only
+                inst.get_rate_limits_wire(ser(20), now_ms=NOW + i)
+                inst.get_rate_limits_wire(
+                    ser(20, name="g", behavior=Behavior.GLOBAL),
+                    now_ms=NOW + i)
             inst.get_rate_limits([RateLimitRequest(
                 name="o", unique_key=f"o{i}", hits=1, limit=10,
                 duration=60_000)], now_ms=NOW + i)
-        inst._mesh_reconcile_tick()
+        if engine_kind != "serial":
+            inst._mesh_reconcile_tick()
         snap = inst.dispatcher.analytics.phases.snapshot()
     finally:
         inst.close()
     assert set(snap) <= set(tracing.PHASE_CATALOG), \
         set(snap) - set(tracing.PHASE_CATALOG)
-    assert {"handler", "ingest", "build", "route.pack", "route.keys",
-            "route.pin", "route.slots", "global_fold", "wave.begin",
-            "lock.engine", "wave.route", "wave.fill", "lock.xla_exec",
-            "lock.mesh_state", "wave.dispatch", "wave.sync",
-            "wave.scatter", "wave.end", *COARSE} <= set(snap), sorted(snap)
-    if pipeline == "1":
-        assert {"worker.wait", "worker.coalesce", "call.wait",
-                "queue_wait", "wave.concat", "wave.resolve"} <= set(snap)
+    assert WORKER_SIDE <= set(snap), sorted(snap)
+    if engine_kind != "serial":
+        assert {"handler", "call.wait", "ingest", "build", "route.pack", "route.keys",
+                "route.pin", "route.slots", "global_fold", "wave.route",
+                "wave.fill", "lock.xla_exec", "lock.mesh_state",
+                "wave.dispatch", "wave.sync", "wave.scatter",
+                "wave.concat"} <= set(snap), sorted(snap)
 
 
 def test_phase_catalog_matches_code_and_docs():

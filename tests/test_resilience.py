@@ -520,20 +520,31 @@ class TestOverloadShedding:
 
         m = Metrics()
         eng = _GatedEngine()
-        eng.gate.set()
         d = Dispatcher(eng, metrics=m)
+        inside = []
+        th = threading.Thread(
+            target=lambda: inside.extend(d.check_batch([_req("d0")], NOW0)))
         try:
-            assert len(d.check_batch([_req("d0")], NOW0)) == 1
+            th.start()  # in flight: the worker is held in the engine
+            deadline = time.monotonic() + 30
+            while not d._inflight and time.monotonic() < deadline:
+                time.sleep(0.005)
             d.drain()
             # new ingress (the admit gate every client path runs) sheds
             with pytest.raises(ResourceExhausted):
                 d.admit(1)
             assert m.admission_shed.labels(
                 reason="draining")._value.get() == 1
-            # but in-flight / peer-side work still completes: drain
-            # finishes what's already inside the daemon
-            assert len(d.check_batch([_req("d1")], NOW0)) == 1
+            # and so does a call that reaches the queue after the flip:
+            # _submit runs the same gate, for every caller
+            with pytest.raises(ResourceExhausted):
+                d.check_batch([_req("d1")], NOW0)
+            # but what is already inside the daemon completes
+            eng.gate.set()
+            th.join(timeout=30)
+            assert len(inside) == 1 and inside[0].remaining == 999
         finally:
+            eng.gate.set()
             d.close()
 
     def test_admission_stats_in_debug(self):

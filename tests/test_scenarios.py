@@ -341,11 +341,20 @@ def test_clock_skew_pin_is_sharp(monkeypatch):
     the owner's real clock sits years past the virtual NOW0, so every
     forwarded bucket expires on arrival and the decision stream
     visibly diverges.  If this stops failing, the pin above proves
-    nothing."""
+    nothing.
+
+    WHICH rows diverge depends on the ring, and the ring on the ports
+    this run's daemons were given: a key breaks only when its first
+    touch is its owner's own client (the lab's clock) and a later one
+    is forwarded (the wall clock).  About one ring in four has no such
+    key among the twelve, so the pin is given four rings to show one
+    break; the unskewed digest is the same for every ring."""
+    unskewed_digest = ScenarioRunner(_skew_spec([])).run()["decision_digest"]
     monkeypatch.setenv("GUBER_CREATED_AT_FWD", "0")
-    skewed = ScenarioRunner(_skew_spec([-5000, 0, 5000])).run()
-    unskewed_digest = None
-    monkeypatch.delenv("GUBER_CREATED_AT_FWD")
-    unskewed = ScenarioRunner(_skew_spec([])).run()
-    unskewed_digest = unskewed["decision_digest"]
-    assert skewed["decision_digest"] != unskewed_digest
+    digests = []
+    for _ in range(4):
+        skewed = ScenarioRunner(_skew_spec([-5000, 0, 5000])).run()
+        digests.append(skewed["decision_digest"])
+        if digests[-1] != unskewed_digest:
+            break
+    assert digests[-1] != unskewed_digest, digests

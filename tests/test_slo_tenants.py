@@ -297,6 +297,10 @@ def test_mesh_staleness_slo_breach_and_recover(monkeypatch):
     monkeypatch.setenv("GUBER_MESH_GLOBAL_CAP", "256")
     monkeypatch.setenv("GUBER_SLO_FAST", "1s")
     monkeypatch.setenv("GUBER_SLO_SLOW", "2s")
+    # the test ticks the engine on a clock of its own: a tick of the
+    # instance's loop (wall clock, every second by default) in between
+    # would put a sample of another time base into the same windows
+    monkeypatch.setenv("GUBER_SLO_TICK", "1h")
     inst = V1Instance(
         Config(cache_size=1 << 12, sweep_interval_ms=0,
                global_mode="mesh", batch_rows=64,

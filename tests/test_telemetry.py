@@ -127,7 +127,7 @@ def test_wave_histograms_observed_after_dispatch(engine):
     text = m.render().decode()
     assert "gubernator_dispatcher_wave_size_count 1.0" in text
     assert "gubernator_dispatcher_wave_duration_count 1.0" in text
-    # idle dispatcher → inline wave: in-flight returned to 0, no stall
+    # the wave is over: in-flight returned to 0, no stall
     assert "gubernator_dispatcher_waves_in_flight 0.0" in text
     assert "gubernator_dispatcher_stalled 0.0" in text
     assert "gubernator_dispatcher_first_wave_seconds" in text
@@ -141,17 +141,12 @@ def test_wave_histograms_observed_after_dispatch(engine):
 def test_queue_wait_observed_for_queued_wave(engine):
     m = Metrics()
     d = Dispatcher(engine, metrics=m)
-    # force the queue path: with the inline mutex held, callers submit
-    # jobs and the worker coalesces them into one wave
-    d._inline_mu.acquire()
-    try:
-        threads = [threading.Thread(
-            target=lambda i=i: d.check_batch([req(f"q{i}")], NOW))
-            for i in range(3)]
-        for t in threads:
-            t.start()
-    finally:
-        d._inline_mu.release()
+    # callers submit jobs and the worker coalesces them into waves
+    threads = [threading.Thread(
+        target=lambda i=i: d.check_batch([req(f"q{i}")], NOW))
+        for i in range(3)]
+    for t in threads:
+        t.start()
     for t in threads:
         t.join(timeout=60)
     d.close()
@@ -200,26 +195,22 @@ def _phase_sums(text):
 
 def test_phase_histograms_partition_wave_duration(engine):
     """ISSUE 4 acceptance: pack + device + resolve sum to the existing
-    wave_duration (same clock, marks stamp segment ends), over inline
-    AND queued waves."""
+    wave_duration (same clock, marks stamp segment ends), over lone
+    AND coalesced waves."""
     from gubernator_tpu.analytics import KeyAnalytics
 
     m, rec = Metrics(), FlightRecorder()
     ka = KeyAnalytics(metrics=m)
     d = Dispatcher(engine, metrics=m, recorder=rec, analytics=ka)
     try:
-        for i in range(4):  # inline waves
+        for i in range(4):  # a call alone in its wave
             d.check_batch([req(f"p{i}")], NOW + i)
-        # queued path: coalesced wave with queue-wait samples
-        d._inline_mu.acquire()
-        try:
-            threads = [threading.Thread(
-                target=lambda i=i: d.check_batch([req(f"pq{i}")], NOW))
-                for i in range(3)]
-            for t in threads:
-                t.start()
-        finally:
-            d._inline_mu.release()
+        # concurrent callers: coalesced waves
+        threads = [threading.Thread(
+            target=lambda i=i: d.check_batch([req(f"pq{i}")], NOW))
+            for i in range(3)]
+        for t in threads:
+            t.start()
         for t in threads:
             t.join(timeout=60)
     finally:
@@ -239,7 +230,7 @@ def test_phase_histograms_partition_wave_duration(engine):
         text).group(1))
     qw_disp = float(re.search(
         r"gubernator_dispatcher_queue_wait_count (\S+)", text).group(1))
-    assert qw == qw_disp == 3.0
+    assert qw == qw_disp == 7.0  # every call waits in the queue
     # the per-wave breakdown rode the flight-recorder events and sums
     # to each wave's duration
     for ev in rec.events(kind="wave_completed"):
@@ -384,13 +375,10 @@ def test_timeout_error_is_diagnosed_and_counted(monkeypatch):
     eng = GatedEngine()
     m, rec = Metrics(), FlightRecorder()
     d = Dispatcher(eng, metrics=m, recorder=rec)
-    # force the queue path so the caller waits on the future
-    d._inline_mu.acquire()
     try:
         with pytest.raises(FuturesTimeout) as ei:
             d.check_batch([req("t")], NOW)
     finally:
-        d._inline_mu.release()
         eng.release.set()
     msg = str(ei.value)
     assert msg, "timeout error must never str() empty"

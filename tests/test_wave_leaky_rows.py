@@ -2,10 +2,11 @@
 wave's device program, counted once a wave by whatever engine served it
 (the benchmark's ``leaky_rows_per_wave`` divides it by the waves).  A
 wave of n leaky and m token rows raises it by exactly n, an all-token
-wave by 0 — on every path a wave takes into an engine: ``check_packed``
-(an inline wave, the retry lane), ``launch_packed`` (the dispatcher's
-pipeline) and ``check_prepacked`` (the fused wire ingest, which counts
-in C++ while it writes the algorithm row)."""
+wave by 0 — on both entries a wave has into an engine, each counting in
+its ``wave.route``: ``check_packed`` (serial waves, the retry lane) and
+``launch_packed`` (the dispatcher's pipeline) — and so for a call that
+came in through the fused wire ingest (``prepack_wire``), whose rows
+reach the engine through ``launch_packed`` like any other's."""
 import numpy as np
 import pytest
 
@@ -72,14 +73,7 @@ def via_launch_packed(eng, reqs):
     assert not eng.sync_packed(eng.launch_packed(batch, kh, NOW))[4].any()
 
 
-def via_check_prepacked(eng, reqs):
-    pre = eng.prepack_wire(wire(reqs), NOW)
-    assert pre is not None and pre.n == len(reqs)
-    assert not eng.check_prepacked(pre, NOW)[4].any()
-
-
-PATHS = {"check_packed": via_check_packed, "launch_packed": via_launch_packed,
-         "check_prepacked": via_check_prepacked}
+PATHS = {"check_packed": via_check_packed, "launch_packed": via_launch_packed}
 
 
 @pytest.mark.parametrize("n_leaky,n_token", [(13, 29), (40, 0), (0, 37)])
@@ -88,6 +82,28 @@ def test_a_wave_counts_its_leaky_rows(engine, path, n_leaky, n_token):
     before = counted(engine)
     PATHS[path](engine, reqs_of(n_leaky, n_token))
     assert counted(engine) - before == n_leaky
+
+
+@pytest.fixture(scope="module", params=list(ENGINES))
+def instance(request):
+    eng = ENGINES[request.param](make_mesh(n=1), capacity_per_shard=1 << 10,
+                                 batch_per_shard=64)
+    inst = V1Instance(Config(cache_size=1 << 10, sweep_interval_ms=0),
+                      engine=eng)
+    yield inst
+    inst.close()
+
+
+@pytest.mark.parametrize("n_leaky,n_token", [(13, 29), (40, 0), (0, 37)])
+def test_a_fused_wire_call_counts_its_leaky_rows(instance, n_leaky, n_token):
+    """``prepack_wire`` through ``V1Instance.get_rate_limits_wire``: the
+    call's rows are counted once, where the worker's wave routes them."""
+    m = instance.metrics
+    lane = m.wire_lane_counter.labels(lane="wire_local")._value
+    before, served = m.wave_leaky_rows._value.get(), lane.get()
+    instance.get_rate_limits_wire(wire(reqs_of(n_leaky, n_token)), NOW)
+    assert lane.get() - served == n_leaky + n_token  # the fused lane took it
+    assert m.wave_leaky_rows._value.get() - before == n_leaky
 
 
 def test_rows_the_kernel_cannot_represent_are_not_counted():

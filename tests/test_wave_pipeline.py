@@ -26,11 +26,10 @@ N_THREADS = 16
 N_CALLS = 4
 
 
-def _mk_instance(monkeypatch, pipeline: str, depth: str, engine=None):
+def _mk_instance(monkeypatch, depth: str, engine=None):
     from gubernator_tpu.config import Config
     from gubernator_tpu.instance import V1Instance
 
-    monkeypatch.setenv("GUBER_PIPELINE", pipeline)
     monkeypatch.setenv("GUBER_PIPELINE_DEPTH", depth)
     mesh = None if engine is not None else make_mesh(n=1)
     return V1Instance(Config(cache_size=1 << 12, sweep_interval_ms=0),
@@ -86,7 +85,7 @@ def test_overlapped_pipeline_byte_parity_oracle_and_depth1(monkeypatch):
     equal the oracle's and depth-1's, per request."""
     datas = _thread_datas()
 
-    inst2 = _mk_instance(monkeypatch, pipeline="1", depth="2")
+    inst2 = _mk_instance(monkeypatch, depth="2")
     try:
         got2 = _drive(inst2, datas)
         stats = inst2.dispatcher.debug_stats()
@@ -104,7 +103,7 @@ def test_overlapped_pipeline_byte_parity_oracle_and_depth1(monkeypatch):
     finally:
         inst2.close()
 
-    inst1 = _mk_instance(monkeypatch, pipeline="1", depth="1")
+    inst1 = _mk_instance(monkeypatch, depth="1")
     try:
         got1 = _drive(inst1, datas)
     finally:
@@ -117,7 +116,7 @@ def test_overlapped_pipeline_byte_parity_oracle_and_depth1(monkeypatch):
     from gubernator_tpu.proto import gubernator_pb2 as pb
     from gubernator_tpu.wire import req_from_pb, resp_to_pb
 
-    oracle_inst = _mk_instance(monkeypatch, pipeline="0", depth="1",
+    oracle_inst = _mk_instance(monkeypatch, depth="1",
                                engine=OracleEngine())
     try:
         for (t, r), raw in sorted(got2.items()):
@@ -131,14 +130,14 @@ def test_overlapped_pipeline_byte_parity_oracle_and_depth1(monkeypatch):
         oracle_inst.close()
 
 
-@pytest.mark.parametrize("pipeline", ["0", "1"])
-def test_midstream_engine_exception_fails_only_its_wave(pipeline,
-                                                        monkeypatch):
+@pytest.mark.parametrize("branch", ["pipelined", "serial"])
+def test_midstream_engine_exception_fails_only_its_wave(
+        branch, monkeypatch, serial_only):
     """An engine raise mid-stream resolves ONLY the affected wave's
-    jobs with the error; earlier and later waves are untouched.
-    Deterministic: the worker is held inside wave A while jobs B1/B2
-    queue into wave B, whose sync/check raises."""
-    monkeypatch.setenv("GUBER_PIPELINE", pipeline)
+    jobs with the error; earlier and later waves are untouched, on
+    both worker branches (an engine with ``launch_packed`` and one
+    without).  Deterministic: the worker is held inside wave A while
+    jobs B1/B2 queue into wave B, whose sync/check raises."""
     monkeypatch.setenv("GUBER_PIPELINE_DEPTH", "2")
     eng = ShardedEngine(make_mesh(n=1), capacity_per_shard=1 << 9,
                         batch_per_shard=64)
@@ -172,14 +171,16 @@ def test_midstream_engine_exception_fails_only_its_wave(pipeline,
             raise RuntimeError("device on fire (wave B)")
         return orig_cp(batch, kh, now)
 
-    if pipeline == "1":
+    if branch == "pipelined":
         eng.launch_packed = gated_launch
         eng.sync_packed = tagged_sync
         orig_drop = eng.drop_packed
         eng.drop_packed = lambda token: orig_drop(token[1])
+        disp = Dispatcher(eng, max_delay_ms=0.2)
     else:
         eng.check_packed = gated_cp
-    disp = Dispatcher(eng, max_delay_ms=0.2)
+        disp = Dispatcher(serial_only(eng), max_delay_ms=0.2)
+    assert disp._pipelined == (branch == "pipelined")
 
     def cols(tag, now):
         kh = hash_request_keys(["pw"] * 4,
@@ -201,7 +202,6 @@ def test_midstream_engine_exception_fails_only_its_wave(pipeline,
             results[tag] = e
 
     # wave A blocks the worker inside the engine; B1/B2 queue behind it
-    disp._inline_mu.acquire()
     try:
         threads = [threading.Thread(target=call, args=("a", NOW))]
         threads[0].start()
@@ -215,7 +215,6 @@ def test_midstream_engine_exception_fails_only_its_wave(pipeline,
             time.sleep(0.01)
         assert disp._queue.qsize() >= 2
     finally:
-        disp._inline_mu.release()
         release.set()
     for th in threads:
         th.join(timeout=60)
@@ -311,8 +310,7 @@ def test_drain_wave_never_overshoots_max_wave():
                         np.full(n, 60_000, np.int64),
                         np.zeros(n, np.int32), np.zeros(n, np.int32),
                         np.zeros(n, np.int64), NOW)
-    # hold the inline mutex so all three jobs take the queue path, and
-    # stall the worker's first wave until all are queued
+    # stall the worker's first wave until the other three are queued
     release = threading.Event()
     entered = threading.Event()
 
@@ -323,7 +321,6 @@ def test_drain_wave_never_overshoots_max_wave():
 
     eng.check_packed = gated
     threads = []
-    disp._inline_mu.acquire()
     try:
         for t in range(4):
             th = threading.Thread(
@@ -337,7 +334,6 @@ def test_drain_wave_never_overshoots_max_wave():
             time.sleep(0.01)
         assert disp._queue.qsize() >= 3
     finally:
-        disp._inline_mu.release()
         release.set()
     for th in threads:
         th.join(timeout=60)

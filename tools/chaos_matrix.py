@@ -173,9 +173,8 @@ def _drive_ingest(ctx: _Ctx) -> str:
 
 def _drive_dispatch(ctx: _Ctx) -> str:
     """dispatch_enqueue / dispatch_launch / dispatch_sync /
-    device_step: a local batch forced through the QUEUED wave path (the
-    inline fast path bypasses the dispatcher queue, so occupy it)."""
-    disp = ctx.i0.dispatcher
+    device_step: a local batch through the dispatcher's queue and
+    worker, on a thread of its own so that a hang is a verdict."""
     box = {}
 
     def call():
@@ -185,10 +184,8 @@ def _drive_dispatch(ctx: _Ctx) -> str:
         except BaseException as e:  # noqa: BLE001 - classified by harness
             box["err"] = e
 
-    with disp._inline_mu:  # the call below must take the queued path
-        th = threading.Thread(target=call)
-        th.start()
-        th.join(0.05)  # let it enqueue while inline is blocked
+    th = threading.Thread(target=call)
+    th.start()
     th.join(WALL_S)
     if th.is_alive():
         return "hung"

@@ -32,7 +32,6 @@ PASS_ID = "lockorder"
 #: tools/check_metrics.py).
 LOCK_ORDER: Tuple[Tuple[str, str], ...] = (
     ("submit_mu", r"^self\._submit_mu$"),
-    ("inline_mu", r"^self\._inline_mu$"),
     ("peer_mu", r"^self\._peer_mu$"),
     ("send_cond", r"^self\._cond$"),
     ("engine_lock", r"^self\._engine_lock$"),

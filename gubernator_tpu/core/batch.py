@@ -12,6 +12,7 @@ ever sees integers.
 """
 from __future__ import annotations
 
+import sys
 from typing import List, NamedTuple, Sequence
 
 import jax
@@ -81,6 +82,176 @@ class RequestBatch(NamedTuple):
     now: jax.Array | np.ndarray | None = None  # int64 epoch ms, 0 = unset
 
 
+#: The step programs' upload layout: every RequestBatch int64 column
+#: rides one [8, B] int64 matrix (key bit-viewed; row 7 is the
+#: per-request arrival time), the int32/bool columns one [3, B] int32
+#: matrix, and all five outputs one [5, B] int64 download.  A device
+#: call then costs 2 uploads + 1 download instead of 10 + 5 —
+#: per-transfer latency (PCIe doorbells) dominates these tiny arrays,
+#: not bandwidth.
+PACK64 = ("key", "hits", "limit", "duration", "eff_ms", "greg_end",
+          "burst", "now")
+PACK32 = ("behavior", "algorithm", "valid")
+_EFF = PACK64.index("eff_ms")
+_NOW = PACK64.index("now")
+_ALG = PACK32.index("algorithm")
+_VALID = PACK32.index("valid")
+
+# ``Rows.batch`` reads the int32 ``valid`` row as bool through its low
+# bytes
+assert sys.byteorder == "little"
+
+
+class Rows:
+    """Request rows laid out ONCE in the upload layout: ``m64`` [8, n]
+    i64 in PACK64 order, ``m32`` [3, n] i32 in PACK32 order.  A call's
+    handler builds one (``pack_columns``, the C++ ingest, or
+    ``stack_rows`` over loose columns) and the dispatch worker joins
+    the calls' blocks into a wave — so no column is copied per wave.
+
+    The rest is what an engine derives from the rows, once a call
+    (``ShardedEngine.lay_out``; ``monotone is None`` = not derived):
+    ``ood`` the indices of valid rows outside its step program's value
+    domain (None = none), ``leaky`` the count of LEAKY_BUCKET rows that
+    stay valid, ``now_lo`` / ``now_hi`` / ``monotone`` the range of the
+    arrival-time row and whether it never decreases.  A wave joined
+    straight into a pooled upload pair carries its ``lease`` (and the
+    mesh-slot block ``mblk``); ``m64`` / ``m32`` are then views of it
+    and die with it."""
+
+    __slots__ = ("m64", "m32", "ood", "leaky", "now_lo", "now_hi",
+                 "monotone", "lease", "mblk")
+
+    def __init__(self, m64, m32):
+        self.m64 = m64
+        self.m32 = m32
+        self.ood = None
+        self.leaky = 0
+        self.now_lo = self.now_hi = 0
+        self.monotone = None
+        self.lease = None
+        self.mblk = None
+
+    @classmethod
+    def empty(cls, n: int) -> "Rows":
+        """An uninitialised pair for n rows (the caller writes every
+        cell)."""
+        return cls(np.empty((len(PACK64), n), np.int64),
+                   np.empty((len(PACK32), n), np.int32))
+
+    def __len__(self) -> int:
+        return self.m64.shape[1]
+
+    @property
+    def valid(self) -> np.ndarray:
+        """The ``valid`` row as a bool VIEW (writes land in ``m32``)."""
+        return self.m32[_VALID].view(np.bool_)[::4]
+
+    @property
+    def now(self) -> np.ndarray:
+        return self.m64[_NOW]
+
+    @property
+    def algorithm(self) -> np.ndarray:
+        return self.m32[_ALG]
+
+    @property
+    def batch(self) -> "PackedBatch":
+        """The RequestBatch every other reader sees: row views, zero
+        copy.  Built per call — the batch points at its rows, never
+        the rows at a batch (no reference cycle to keep a lease
+        alive)."""
+        m64, m32 = self.m64, self.m32
+        b = PackedBatch(
+            key=m64[0].view(np.uint64), hits=m64[1], limit=m64[2],
+            duration=m64[3], eff_ms=m64[4], greg_end=m64[5],
+            behavior=m32[0], algorithm=self.algorithm, burst=m64[6],
+            valid=self.valid, now=self.now)
+        b.rows = self
+        return b
+
+    def take(self, idx) -> "Rows":
+        """Rows ``idx`` as a block of their own (nothing derived)."""
+        return Rows(np.take(self.m64, idx, axis=1),
+                    np.take(self.m32, idx, axis=1))
+
+
+class PackedBatch(RequestBatch):
+    """A RequestBatch whose columns are row views of ONE ``Rows`` pair
+    (``rows``).  ``_replace`` and ``type(b)(*cols)`` give a batch with
+    ``rows`` None: loose columns again, stacked when next laid out."""
+
+    rows: "Rows | None" = None
+
+
+def stack_rows(b: RequestBatch) -> Rows:
+    """A batch's rows: the pair it is a view of, else its loose numpy
+    columns stacked into one."""
+    rows = getattr(b, "rows", None)
+    if rows is not None:
+        return rows
+    rows = Rows.empty(len(b.key))
+    rows.m64[0] = np.asarray(b.key).view(np.int64)
+    for i, f in enumerate(PACK64[1:], start=1):
+        rows.m64[i] = getattr(b, f)
+    for i, f in enumerate(PACK32):
+        rows.m32[i] = getattr(b, f)
+    return rows
+
+
+def clock_order(calls: Sequence[Rows]) -> "List[int] | None":
+    """The order of the calls' whole blocks in which the joined rows'
+    clocks never run backwards and equal clocks keep the calls' own
+    order — exactly what a stable sort of the joined rows by arrival
+    time gives — or None where no order of whole blocks does (a call
+    whose own clock runs backwards, two calls whose ranges overlap, or
+    rows nobody derived the clocks of).  Calls that each carry ONE
+    stamp, as a client's do, always have one."""
+    if not all(c.monotone for c in calls):
+        return None
+    order = sorted(range(len(calls)), key=lambda i: calls[i].now_lo)
+    for i, j in zip(order, order[1:]):
+        a, b = calls[i], calls[j]
+        if a.now_hi > b.now_lo or (a.now_hi == b.now_lo and i > j):
+            return None
+    return order
+
+
+def join_calls(calls: Sequence[Rows], khashes, mslots=None,
+               into: "WaveLease | None" = None):
+    """Several calls' blocks → one wave's (rows, khash, mslot), nothing
+    derived.  ``into``: a leased upload pair wide enough — the blocks
+    are joined STRAIGHT into its first columns and the wave's rows are
+    views of it (``rows.lease``; the mesh-slot column then a view of
+    ``rows.mblk``).  Otherwise fresh matrices; one call alone is its
+    own wave.  ``mslots``: per-call mesh-GLOBAL slot columns (a call
+    without one fills -1 = sharded lane), None when no call has one."""
+    total = sum(len(c) for c in calls)
+    mparts = mslot = None
+    if mslots is not None and any(m is not None for m in mslots):
+        mparts = [m if m is not None else np.full(len(c), -1, np.int32)
+                  for c, m in zip(calls, mslots)]
+    if into is not None:
+        wave = Rows(into.a64[:, :total], into.a32[:, :total])
+        wave.lease = into
+        np.concatenate([c.m64 for c in calls], axis=1, out=wave.m64)
+        np.concatenate([c.m32 for c in calls], axis=1, out=wave.m32)
+        if mparts is not None:
+            wave.mblk = np.full(into.a64.shape[1], -1, np.int32)
+            mslot = wave.mblk[:total]
+            np.concatenate(mparts, out=mslot)
+    elif len(calls) == 1:
+        wave = calls[0]
+        mslot = mparts[0] if mparts is not None else None
+    else:
+        wave = Rows(np.concatenate([c.m64 for c in calls], axis=1),
+                    np.concatenate([c.m32 for c in calls], axis=1))
+        if mparts is not None:
+            mslot = np.concatenate(mparts)
+    khash = khashes[0] if len(khashes) == 1 else np.concatenate(khashes)
+    return wave, khash, mslot
+
+
 class WaveLease:
     """One leased pair of packed upload matrices (a64 [8,m] i64,
     a32 [3,m] i32) from a :class:`WaveBufferPool`.
@@ -95,25 +266,28 @@ class WaveLease:
     and reclaims the buffers, so a bug degrades to a counter, not an
     unbounded allocation regression."""
 
-    __slots__ = ("a64", "a32", "_pool", "_released", "__weakref__")
+    __slots__ = ("a64", "a32", "_pool", "_dirty", "_released",
+                 "__weakref__")
 
-    def __init__(self, pool: "WaveBufferPool", a64, a32):
+    def __init__(self, pool: "WaveBufferPool", a64, a32, dirty: int):
         self._pool = pool
         self.a64 = a64
         self.a32 = a32
+        #: columns [0, _dirty) may hold rows; the rest reads as padding
+        self._dirty = dirty
         self._released = False
 
     def release(self) -> None:
         if self._released:
             return
         self._released = True
-        self._pool._return(self.a64, self.a32)
+        self._pool._return(self.a64, self.a32, self._dirty)
 
     def __del__(self):  # pragma: no cover - exercised via gc in tests
         if not self._released:
             self._released = True
             self._pool._record_leak()
-            self._pool._return(self.a64, self.a32)
+            self._pool._return(self.a64, self.a32, self._dirty)
 
 
 class WaveBufferPool:
@@ -125,7 +299,7 @@ class WaveBufferPool:
     under the overlapped wave pipeline the same few shapes recur every
     couple hundred microseconds, so the allocator/page-fault churn is
     pure host-glue overhead (PERF.md §4.2).  ``lease(m)`` hands back a
-    pooled pair (zeroed to ``empty_batch`` padding semantics: all zeros,
+    pooled pair (``empty_batch`` padding semantics: all zeros,
     ``eff_ms`` row = 1) or allocates on miss; ``WaveLease.release``
     returns it.  Thread-safe; the per-width ring is bounded (pipeline
     depth + a small margin) so a burst of odd widths cannot grow the
@@ -144,7 +318,7 @@ class WaveBufferPool:
         import threading
 
         self._mu = threading.Lock()
-        #: m → [(a64, a32), ...]
+        #: m → [(a64, a32, dirty columns), ...]
         self._free: dict[int, list] = {}  # guarded-by: self._mu
         self.max_per_width = (max_per_width if max_per_width is not None
                               else self.MAX_PER_WIDTH)
@@ -154,11 +328,15 @@ class WaveBufferPool:
         self.outstanding = 0  # guarded-by: self._mu
         self.metrics = None  # bound by V1Instance after construction
 
-    def lease(self, m: int) -> WaveLease:
-        """Lease a zeroed (a64 [8,m] i64, a32 [3,m] i32) pair.  Padding
-        rows keep ``empty_batch`` semantics: zeros everywhere, eff_ms 1
-        (the eff_ms re-fill is the caller's job — ``_fill_packed``
-        writes that row for every slot it doesn't scatter)."""
+    def lease(self, m: int, rows: int | None = None) -> WaveLease:
+        """Lease a (a64 [8,m] i64, a32 [3,m] i32) pair that reads as
+        padding — ``empty_batch`` semantics: zeros everywhere, eff_ms 1
+        — in every column the caller will not write.  ``rows``: the
+        caller overwrites every cell of columns [0, rows) and touches
+        nothing else, so only [rows, m) is made padding, and of those
+        only what the buffer's last holder wrote (in a steady stream of
+        equal waves: nothing).  Without it the caller may write
+        anywhere and gets the whole pair clean."""
         with self._mu:
             ring = self._free.get(m)
             buf = ring.pop() if ring else None
@@ -167,26 +345,30 @@ class WaveBufferPool:
             else:
                 self.misses += 1
             self.outstanding += 1
+        lo = rows or 0
         if buf is not None:
-            a64, a32 = buf
-            a64.fill(0)
-            a32.fill(0)
+            a64, a32, dirty = buf
+            if dirty > lo:
+                a64[:, lo:dirty] = 0
+                a64[_EFF, lo:dirty] = 1
+                a32[:, lo:dirty] = 0
             if self.metrics is not None:
                 self.metrics.wave_buffer_pool_hit.inc()
         else:
             a64 = np.zeros((8, m), np.int64)
+            a64[_EFF] = 1
             a32 = np.zeros((3, m), np.int32)
             if self.metrics is not None:
                 self.metrics.wave_buffer_pool_miss.inc()
-        return WaveLease(self, a64, a32)
+        return WaveLease(self, a64, a32, m if rows is None else rows)
 
-    def _return(self, a64, a32) -> None:
+    def _return(self, a64, a32, dirty: int) -> None:
         m = a64.shape[1]
         with self._mu:
             self.outstanding -= 1
             ring = self._free.setdefault(m, [])
             if len(ring) < self.max_per_width:
-                ring.append((a64, a32))
+                ring.append((a64, a32, dirty))
 
     def _record_leak(self) -> None:
         with self._mu:
@@ -207,7 +389,7 @@ class WaveBufferPool:
         with self._mu:
             pooled = nbytes = 0
             for ring in self._free.values():
-                for a64, a32 in ring:
+                for a64, a32, _dirty in ring:
                     pooled += 1
                     nbytes += int(a64.nbytes) + int(a32.nbytes)
             return {"pooled": pooled, "pooled_bytes": nbytes,
@@ -329,12 +511,20 @@ def pack_columns(
     the forward stamp).
     """
     n = len(khash)
-    behavior32 = behavior.astype(np.int32)
-    dur = np.minimum(np.asarray(duration, np.int64), DURATION_MAX)
+    # ONE pair in the upload layout, filled in place: the batch handed
+    # back is row views of it, so the dispatch worker joins this call's
+    # block into its wave without copying a column again
+    rows = Rows.empty(n)
+    b = rows.batch
+    key_col, greg_end, valid, behavior32 = (b.key, b.greg_end, b.valid,
+                                            b.behavior)
+    key_col[:] = khash
+    greg_end[:] = 0
+    rows.m32[_VALID] = 1
+    behavior32[:] = behavior
+    dur = np.minimum(np.asarray(duration, np.int64), DURATION_MAX,
+                     out=b.duration)
     eff = np.maximum(dur, 1)
-    greg_end = np.zeros(n, np.int64)
-    valid = np.ones(n, bool)
-    key_col = khash.astype(np.uint64).copy()
     errors: dict = {}
     greg = (behavior32 & int(Behavior.DURATION_IS_GREGORIAN)) != 0
     if greg.any():
@@ -354,25 +544,17 @@ def pack_columns(
     # leaky td bounds (oracle.py › _clamp_leaky): eff ≤ EFF_MAX and
     # hits/limit/burst ≤ TD_BOUND // eff; token values ≤ VALUE_MAX
     leaky = np.asarray(algorithm) == 1
-    eff = np.where(leaky, np.minimum(eff, EFF_MAX), eff)
+    b.algorithm[:] = leaky
+    b.eff_ms[:] = eff = np.where(leaky, np.minimum(eff, EFF_MAX), eff)
     cap_v = np.where(leaky, np.minimum(TD_BOUND // eff, VALUE_MAX),
                      VALUE_MAX)
-    lim = np.minimum(np.clip(np.asarray(limit, np.int64), 0, None), cap_v)
-    now_col = np.full(n, now_ms, np.int64)
+    lim = np.minimum(np.clip(np.asarray(limit, np.int64), 0, None), cap_v,
+                     out=b.limit)
+    np.minimum(np.clip(np.asarray(hits, np.int64), 0, None), cap_v,
+               out=b.hits)
+    b.burst[:] = np.where(burst > 0, np.minimum(burst, cap_v), lim)
+    b.now[:] = now_ms
     if created_at is not None:
         created = np.asarray(created_at, np.int64)
-        now_col = np.where(created > 0, created, now_col)
-    b = RequestBatch(
-        key=key_col,
-        hits=np.minimum(np.clip(np.asarray(hits, np.int64), 0, None), cap_v),
-        limit=lim,
-        duration=dur.copy(),
-        eff_ms=eff,
-        greg_end=greg_end,
-        behavior=behavior32,
-        algorithm=leaky.astype(np.int32),
-        burst=np.where(burst > 0, np.minimum(burst, cap_v), lim),
-        valid=valid,
-        now=now_col,
-    )
+        np.copyto(b.now, created, where=created > 0)
     return b, errors

@@ -64,14 +64,13 @@ def _job_len(job) -> int:
     return (len(job.reqs) if isinstance(job, _Job) else len(job.khash))
 
 
-def _concat_columns(parts):
-    """[(RequestBatch, khash), ...] → one concatenated (batch, khash)."""
-    import numpy as np
-
-    batch = type(parts[0][0])(*[
-        np.concatenate([np.asarray(b[f]) for b, _ in parts])
-        for f in range(len(parts[0][0]))])
-    return batch, np.concatenate([kh for _, kh in parts])
+def _release_wave(batch) -> None:
+    """Return the lease a joined wave's batch is a view of, if it is
+    (ShardedEngine.join_calls): the end of every path on which the
+    engine did not take it into a token.  Idempotent."""
+    lease = getattr(getattr(batch, "rows", None), "lease", None)
+    if lease is not None:
+        lease.release()
 
 
 class ResultView:
@@ -124,17 +123,19 @@ class _Job:
 
 
 class _PackedJob:
-    """Columnar job (C++ wire-ingest lane): a RequestBatch of numpy
-    columns + key hashes instead of RateLimitRequest objects.
-    ``mslot`` (ISSUE 8): optional per-request mesh-GLOBAL replica slot
-    column (-1 = sharded row) — rides the job so a fused engine can
-    serve both lanes in ONE launch."""
+    """Columnar job (the wire lanes): the call's rows as ONE block in
+    the upload layout (core/batch.py › Rows — laid out by the call's
+    own thread, with what the engine derived of them) + key hashes,
+    instead of RateLimitRequest objects.  ``mslot`` (ISSUE 8): optional
+    per-request mesh-GLOBAL replica slot column (-1 = sharded row) —
+    rides the job so a fused engine can serve both lanes in ONE
+    launch."""
 
-    __slots__ = ("batch", "khash", "now_ms", "future", "t_enq", "qwait",
+    __slots__ = ("rows", "khash", "now_ms", "future", "t_enq", "qwait",
                  "trace", "span", "mslot")
 
-    def __init__(self, batch, khash, now_ms, mslot=None):
-        self.batch = batch
+    def __init__(self, rows, khash, now_ms, mslot=None):
+        self.rows = rows
         self.khash = khash
         self.now_ms = now_ms
         self.mslot = mslot
@@ -144,17 +145,10 @@ class _PackedJob:
         self.trace: Optional[str] = None
         self.span: Optional[str] = None
 
-
-def _concat_mslot(jobs):
-    """Concat the wave's per-job mesh-slot columns (None when no job
-    carries one; jobs without a column fill -1 = sharded lane)."""
-    if all(getattr(j, "mslot", None) is None for j in jobs):
-        return None
-    import numpy as np
-
-    return np.concatenate([
-        j.mslot if getattr(j, "mslot", None) is not None
-        else np.full(_job_len(j), -1, np.int32) for j in jobs])
+    @property
+    def batch(self):
+        """The rows as the RequestBatch other readers see (views)."""
+        return self.rows.batch
 
 
 class Dispatcher:
@@ -309,6 +303,17 @@ class Dispatcher:
         #: capability; serial (check_packed / check_batch) for an engine
         #: without it (OracleEngine) and for list/merged waves.
         self._pipelined = hasattr(engine, "launch_packed")
+        #: who lays out what (ISSUE 30): a packed call's rows are
+        #: stacked and examined ONCE, in its own thread (``_lay_out``,
+        #: in check_packed_view); the worker only joins the calls'
+        #: blocks (``_join``, in _concat_jobs).  An engine with a say
+        #: in either (ShardedEngine: its domain mask; a pooled lease to
+        #: join into) brings its own.
+        from .core.batch import join_calls, stack_rows
+
+        self._lay_out = getattr(
+            engine, "lay_out", lambda batch, khash, mslot: stack_rows(batch))
+        self._join = getattr(engine, "join_calls", join_calls)
         # fused-engine capability (ISSUE 8): the engine emits the
         # heavy-hitter tap columns on device at launch, so the
         # dispatcher's host-side column copies are skipped.
@@ -393,8 +398,8 @@ class Dispatcher:
         wire lanes serialize straight from the view (ops/_native.cpp ›
         build_responses_from_columns) without materializing per-job
         column tuples."""
-        return self._submit_and_wait(
-            _PackedJob(batch, khash, now_ms, mslot=mslot))
+        return self._submit_and_wait(_PackedJob(
+            self._lay_out(batch, khash, mslot), khash, now_ms, mslot=mslot))
 
     def _fault(self, point: str) -> None:
         f = self._faults
@@ -1078,8 +1083,8 @@ class Dispatcher:
         # Overlapped wave pipeline (depth K = pipeline_depth,
         # GUBER_PIPELINE_DEPTH) for pure-packed waves: while up to K
         # launched waves are in flight on the device, the worker drains
-        # and PACKS the next wave into a pooled upload buffer
-        # (core/batch.py › WaveBufferPool via engine._fill_packed) —
+        # and JOINS the next wave into a pooled upload buffer
+        # (core/batch.py › WaveBufferPool via engine.join_calls) —
         # steady-state throughput becomes max(host, device) instead of
         # host + device.  Launches are ordered by the state threading
         # device-side, so correctness does not depend on when results
@@ -1169,6 +1174,7 @@ class Dispatcher:
         with self._new_scope() as scope:
             wid = self._wave_begin(scope, "packed_pipelined", jobs,
                                    slot=slot)
+            batch = None
             try:
                 self._fault("dispatch_launch")
                 batch, khash, mslot, now = self._concat_jobs(jobs)
@@ -1185,6 +1191,7 @@ class Dispatcher:
                 self._wave_mark(wid, phase("device", self, span=False))
                 return (jobs, token, wid, batch, khash, scope)
             except Exception as e:  # noqa: BLE001 - surfaced per-caller
+                _release_wave(batch)  # no token took the wave's lease
                 self._wave_end(wid, error=e)
                 for j in jobs:
                     if not j.future.done():
@@ -1192,16 +1199,27 @@ class Dispatcher:
                 return None
 
     def _concat_jobs(self, jobs) -> tuple:
-        """(batch, khash, mslot, now) of a pure-packed wave's jobs.
+        """(batch, khash, mslot, now) of a pure-packed wave's jobs:
+        their blocks joined into one wave — O(jobs) work, no pass per
+        column; where the engine can, straight into the upload pair
+        (the batch is then views of a lease: ``_release_wave``).
+        ``jobs`` is put IN PLACE into the order the wave holds them in.
         The scalar now only backstops sweeps/padding; requests use
         their own now column.  max() keeps sweep time monotonic."""
+        from .core.batch import clock_order
+
         with phase("wave.concat", self):
-            if len(jobs) == 1:
-                batch, khash = jobs[0].batch, jobs[0].khash
-            else:
-                batch, khash = _concat_columns(
-                    [(j.batch, j.khash) for j in jobs])
-            return (batch, khash, _concat_mslot(jobs),
+            # the jobs in the order of their clocks, where whole jobs
+            # can be: a wave's rows apply in arrival-time order whatever
+            # the queue's (callers' stamps cross on their way in), and
+            # blocks already in that order need no sort of their rows
+            order = clock_order([j.rows for j in jobs])
+            if order is not None:
+                jobs[:] = [jobs[i] for i in order]
+            wave, khash, mslot = self._join(
+                [j.rows for j in jobs], [j.khash for j in jobs],
+                [j.mslot for j in jobs])
+            return (wave.batch, khash, mslot,
                     max(j.now_ms for j in jobs))
 
     def _resolve_views(self, jobs, cols) -> None:
@@ -1234,86 +1252,79 @@ class Dispatcher:
                     self._wave_end(wid)
                     self._tap_packed(khash, batch.hits, cols[0])
             except Exception as e:  # noqa: BLE001 - surfaced per-caller
-                # a token that failed before (or inside) its sync still
-                # holds the wave's pooled upload buffers
-                self.engine.drop_packed(token)
                 self._wave_end(wid, error=e)
                 for j in jobs:
                     if not j.future.done():
                         j.future.set_exception(e)
+            finally:
+                # the token is dead: its pooled upload buffers — which
+                # ``batch`` may be views of — go back only now, after
+                # the sync's re-dispatches and the tap read them
+                self.engine.drop_packed(token)
 
     def _run_merged_wave(self, wave) -> None:
         """Cross-time merge of a mixed wave: every list job is packed at
-        its own now (Gregorian period ends are per-instant), columns
-        concatenate with the packed jobs, and ONE launch serves all —
-        the device applies each key's requests in arrival-time order."""
-        import numpy as np
-
-        from .core.batch import pack_requests
-        from .hashing import hash_request_keys
+        its own now (Gregorian period ends are per-instant), its rows
+        join the packed jobs' blocks, and ONE launch serves all — the
+        device applies each key's requests in arrival-time order."""
         from .parallel.sharded import responses_from_columns
 
         with self._new_scope() as scope:
             wid = self._wave_begin(scope, "merged", wave)
+            batch = None
             try:
-                tap = self._run_merged_wave_inner(
-                    wave, np, pack_requests, hash_request_keys,
-                    responses_from_columns, wid, scope)
+                with phase("wave.concat", self):
+                    parts, batch, khash, mslot = self._merge_parts(wave)
+                now = max(j.now_ms for j in wave)
+                with self._engine_step(wid, scope):
+                    st, lim, rem, rst, full = self._engine_check_packed(
+                        batch, khash, now, mslot)
+                self._fault("dispatch_splice")
+                cols = (st, lim, rem, rst, full)
+                with phase("wave.resolve", self):
+                    a = 0
+                    for j, kh, errs in parts:
+                        b_ = a + len(kh)
+                        if isinstance(j, _PackedJob):
+                            j.future.set_result(ResultView(cols, a, b_))
+                        else:
+                            j.future.set_result(responses_from_columns(
+                                (st[a:b_], lim[a:b_], rem[a:b_], rst[a:b_],
+                                 full[a:b_]), errs))
+                        a = b_
+                with phase("wave.end", self):
+                    self._wave_end(wid)
+                    self._tap_packed(khash, batch.hits, st)
             except Exception as e:  # noqa: BLE001 - caller fails the futures
                 self._wave_end(wid, error=e)
                 raise
-            with phase("wave.end", self):
-                self._wave_end(wid)
-                self._tap_packed(*tap)
+            finally:
+                _release_wave(batch)
 
-    def _run_merged_wave_inner(self, wave, np, pack_requests,
-                               hash_request_keys,
-                               responses_from_columns, wid,
-                               scope) -> tuple:
-        with phase("wave.concat", self):
-            parts, batch, khash, mslot = self._merge_parts(
-                wave, np, pack_requests, hash_request_keys)
-        now = max(j.now_ms for j in wave)
-        with self._engine_step(wid, scope):
-            st, lim, rem, rst, full = self._engine_check_packed(
-                batch, khash, now, mslot)
-        self._fault("dispatch_splice")
-        cols = (st, lim, rem, rst, full)
-        with phase("wave.resolve", self):
-            a = 0
-            for j, _, kh, errs in parts:
-                b_ = a + len(kh)
-                if isinstance(j, _PackedJob):
-                    j.future.set_result(ResultView(cols, a, b_))
-                else:
-                    j.future.set_result(responses_from_columns(
-                        (st[a:b_], lim[a:b_], rem[a:b_], rst[a:b_],
-                         full[a:b_]), errs))
-                a = b_
-        return (khash, batch.hits, st)
+    def _merge_parts(self, wave) -> tuple:
+        """([(job, khash, errs or None)], batch, khash, mslot) of a
+        mixed wave: object jobs are packed and laid out here, on the
+        worker (they carry no block), and joined with the packed jobs'
+        as ``_concat_jobs`` joins those."""
+        from .core.batch import pack_requests
+        from .hashing import hash_request_keys
 
-    @staticmethod
-    def _merge_parts(wave, np, pack_requests, hash_request_keys) -> tuple:
-        parts = []  # (job, batch, khash, errs or None)
-        mparts = []
+        parts, calls = [], []
         for j in wave:
             if isinstance(j, _PackedJob):
-                parts.append((j, j.batch, j.khash, None))
-                mparts.append(j.mslot if j.mslot is not None
-                              else np.full(len(j.khash), -1, np.int32))
+                parts.append((j, j.khash, None))
+                calls.append(j.rows)
             else:
                 kh = hash_request_keys([r.name for r in j.reqs],
                                        [r.unique_key for r in j.reqs])
                 b, errs = pack_requests(j.reqs, j.now_ms,
                                         size=len(j.reqs), key_hashes=kh)
-                parts.append((j, b, kh, errs))
-                mparts.append(np.full(len(kh), -1, np.int32))
-        batch, khash = _concat_columns([(p[1], p[2]) for p in parts])
-        mslot = (np.concatenate(mparts)
-                 if any(isinstance(j, _PackedJob)
-                        and j.mslot is not None for j in wave)
-                 else None)
-        return parts, batch, khash, mslot
+                parts.append((j, kh, errs))
+                calls.append(self._lay_out(b, kh, None))
+        rows, khash, mslot = self._join(
+            calls, [p[1] for p in parts],
+            [getattr(j, "mslot", None) for j in wave])
+        return parts, rows.batch, khash, mslot
 
     def _run_list_jobs(self, jobs, now) -> None:
         if not jobs:
@@ -1348,6 +1359,7 @@ class Dispatcher:
             return
         with self._new_scope() as scope:
             wid = self._wave_begin(scope, "packed", jobs)
+            batch = None
             try:
                 batch, khash, mslot, now = self._concat_jobs(jobs)
                 self._fault("dispatch_launch")
@@ -1364,6 +1376,8 @@ class Dispatcher:
                 for j in jobs:
                     if not j.future.done():
                         j.future.set_exception(e)
+            finally:
+                _release_wave(batch)
 
     def close(self) -> None:
         with self._submit_mu:

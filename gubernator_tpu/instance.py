@@ -929,7 +929,7 @@ class V1Instance:
         if _wire_native is not None and self.store is None:
             peer_list = self.peers()
             if not peer_list or all(self.is_self(p) for p in peer_list):
-                # solo fused lane: bytes → leased packed wave → device
+                # solo fused lane: bytes → the call's block → wave → device
                 # → bytes in one C++ ingest pass (no parse/pack numpy
                 # columns at all); returns None for anything it can't
                 # model (GLOBAL/MR rows, Gregorian, pb2 framing,
@@ -1067,19 +1067,13 @@ class V1Instance:
             # GLOBAL rides the hot-set flow, MULTI_REGION queues async
             # replication — both need the parsed columns; the classic
             # lanes keep those semantics in one place
-            pre.lease.release()
             return None
         if pre.n > MAX_BATCH_SIZE:
-            pre.lease.release()
             raise ValueError(
                 f"Requests.RateLimits list too large; max size is "
                 f"{MAX_BATCH_SIZE}")
-        try:
-            self.dispatcher.admit(
-                pre.n, tenant_cb=lambda: self._tenant_of_wire(data))
-        except BaseException:
-            pre.lease.release()
-            raise
+        self.dispatcher.admit(
+            pre.n, tenant_cb=lambda: self._tenant_of_wire(data))
         ana = self.dispatcher.analytics
         if ana is not None:
             ana.tap_wire_names(data, pre.khash, pre.name_hash,
@@ -1100,8 +1094,9 @@ class V1Instance:
     def _wire_peer_fused(self, data: bytes,
                          now_ms: Optional[int]) -> Optional[bytes]:
         """Fused owner side of the forward hop: received TLV bytes go
-        straight into a leased packed wave (C++ parse+clamp+hash+fill,
-        zero numpy column passes) and responses serialize from the
+        straight into the call's block in the upload layout (C++
+        parse+clamp+hash+fill, zero numpy column passes), which the
+        dispatch worker joins into its wave, and responses serialize from the
         wave's result columns — a forwarded batch costs the same as a
         local wire call.  None → classic lane (GLOBAL/MR rows whose
         async queues need parsed columns, Gregorian, pb2 framing)."""
@@ -1115,10 +1110,8 @@ class V1Instance:
         if pre is None:
             return None
         if pre.behavior_or & int(self._FUSED_EXCLUDED):
-            pre.lease.release()
             return None
         if pre.n > self.config.behaviors.batch_limit:
-            pre.lease.release()
             raise ValueError(
                 "'PeerRequest.rate_limits' list too large; max size is "
                 f"{self.config.behaviors.batch_limit}")
@@ -1133,25 +1126,14 @@ class V1Instance:
         return self._run_fused(pre, now)
 
     def _run_fused(self, pre, now: int) -> bytes:
-        """Submit a prepacked call to the dispatcher and serialize its
-        responses: the rows are copied out of the lease (the queued job
-        outlives it) and coalesce with the other callers' waves."""
-        from .core.batch import RequestBatch
-
+        """Submit a prepacked call to the dispatcher — its rows are laid
+        out already, as the block the worker joins into the wave — and
+        serialize its responses."""
         disp = self.dispatcher
         n = pre.n
         ana = disp.analytics
-        a64, a32 = pre.lease.a64, pre.lease.a32
-        batch = RequestBatch(
-            key=a64[0][:n].astype(np.int64).view(np.uint64),
-            hits=a64[1][:n].copy(), limit=a64[2][:n].copy(),
-            duration=a64[3][:n].copy(), eff_ms=a64[4][:n].copy(),
-            greg_end=a64[5][:n].copy(), behavior=a32[0][:n].copy(),
-            algorithm=a32[1][:n].copy(), burst=a64[6][:n].copy(),
-            valid=a32[2][:n] != 0, now=a64[7][:n].copy())
         kh = pre.khash
-        pre.lease.release()
-        view = disp.check_packed_view(batch, kh, now)
+        view = disp.check_packed_view(pre.rows.batch, kh, now)
         status = view.cols[0][view.lo:view.hi]
         full = view.cols[4][view.lo:view.hi]
         self.metrics.over_limit_counter.inc(int((status == 1).sum()))

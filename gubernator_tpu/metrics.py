@@ -135,6 +135,13 @@ class Metrics:
             "(valid, inside the step program's domain, not cold-tier "
             "served), counted once a wave at the engine's wave.route",
             registry=r)
+        self.wave_route = Counter(
+            "gubernator_wave_route",
+            "device waves by how their rows reached the upload buffers: "
+            "identity = the calls' blocks were joined straight into the "
+            "lease (one shard, clocks in order, no cold tier, one "
+            "bucket), sorted = routed by shard and scattered",
+            ["route"], registry=r)
         self.wave_queue_wait = Histogram(
             "gubernator_dispatcher_queue_wait",
             "job wait from submit to its wave launching (s)",

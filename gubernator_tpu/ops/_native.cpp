@@ -454,37 +454,159 @@ static inline uint64_t mix64(uint64_t x) {
   return x;
 }
 
+// What an engine's launch needs to know of a call's rows (parallel/
+// sharded.py › ShardedEngine.lay_out), accumulated a row at a time by
+// the two passes below.  A row is outside the step program's value
+// domain when hits, limit or burst is not in [0, value_bound), or —
+// LEAKY_BUCKET only — eff_ms is not in [1, eff_bound), or its
+// algorithm is neither 0 nor 1: ops/pallas_step.py ›
+// pallas_value_domain_mask, whose bounds come in as arguments.
+// value_bound 0 = the full int64 domain: no row is out.
+struct Derived {
+  std::vector<int64_t> ood;  // valid rows outside the domain
+  long long leaky = 0;       // LEAKY rows that stay valid
+  int64_t now_lo = 0, now_hi = 0, prev = 0;
+  bool monotone = true;
+  Py_ssize_t n = 0;
+
+  void row(bool valid, bool exempt, int64_t hits, int64_t limit,
+           int64_t burst, int32_t alg, int64_t eff, int64_t now,
+           uint64_t value_bound, uint64_t eff_bound) {
+    bool leaky_row = alg == 1;
+    bool ok = !value_bound || exempt ||
+              ((alg == 0 || leaky_row) && hits >= 0 &&
+               (uint64_t)hits < value_bound && limit >= 0 &&
+               (uint64_t)limit < value_bound && burst >= 0 &&
+               (uint64_t)burst < value_bound &&
+               (!leaky_row || (eff >= 1 && (uint64_t)eff < eff_bound)));
+    if (valid && !ok) ood.push_back((int64_t)n);
+    if (valid && ok && leaky_row) leaky++;
+    if (n == 0) {
+      now_lo = now_hi = now;
+    } else {
+      if (now < prev) monotone = false;
+      if (now < now_lo) now_lo = now;
+      if (now > now_hi) now_hi = now;
+    }
+    prev = now;
+    n++;
+  }
+
+  // (ood i64le bytes, leaky, now_lo, now_hi, monotone)
+  PyObject* build() const {
+    static const char kNone[1] = {0};
+    return Py_BuildValue(
+        "(y#LLLO)", ood.empty() ? kNone : (const char*)ood.data(),
+        (Py_ssize_t)(ood.size() * 8), leaky, (long long)now_lo,
+        (long long)now_hi, monotone ? Py_True : Py_False);
+  }
+};
+
+// derive_rows(m64, m32, mslot | None, value_bound, eff_bound) ->
+//   (ood i64le, leaky, now_lo, now_hi, monotone)
+// The derivation alone, over a call's rows already in the upload
+// layout (m64 [8,n] i64, m32 [3,n] i32; rows contiguous, any row
+// stride — a view of a wider pair will do): what pack_wire_wave derives
+// in its own pass, for the producers that pack in Python.  mslot
+// (i32[n], optional): rows with mslot >= 0 are mesh-GLOBAL rows, exempt
+// from the domain.  One pass, the GIL kept: ~n × 11 loads.
+static PyObject* derive_rows(PyObject*, PyObject* args) {
+  PyObject *o64, *o32, *oms;
+  unsigned long long value_bound, eff_bound;
+  if (!PyArg_ParseTuple(args, "OOOKK", &o64, &o32, &oms, &value_bound,
+                        &eff_bound))
+    return nullptr;
+  Py_buffer b64, b32, bms;
+  if (PyObject_GetBuffer(o64, &b64, PyBUF_STRIDES | PyBUF_FORMAT) < 0)
+    return nullptr;
+  if (PyObject_GetBuffer(o32, &b32, PyBUF_STRIDES | PyBUF_FORMAT) < 0) {
+    PyBuffer_Release(&b64);
+    return nullptr;
+  }
+  bool has_ms = oms != Py_None;
+  if (has_ms &&
+      PyObject_GetBuffer(oms, &bms, PyBUF_STRIDES | PyBUF_FORMAT) < 0) {
+    PyBuffer_Release(&b64);
+    PyBuffer_Release(&b32);
+    return nullptr;
+  }
+  PyObject* out = nullptr;
+  Py_ssize_t n = b64.ndim == 2 ? b64.shape[1] : -1;
+  if (b64.ndim != 2 || b32.ndim != 2 || b64.shape[0] != 8 ||
+      b32.shape[0] != 3 || b32.shape[1] != n || b64.itemsize != 8 ||
+      b32.itemsize != 4 || (n > 1 && (b64.strides[1] != 8 ||
+                                      b32.strides[1] != 4)) ||
+      (has_ms && (bms.ndim != 1 || bms.shape[0] != n ||
+                  bms.itemsize != 4 ||
+                  (n > 1 && bms.strides[0] != 4)))) {
+    PyErr_SetString(PyExc_ValueError,
+                    "derive_rows wants [8,n] i64, [3,n] i32 (rows "
+                    "contiguous) and an optional i32[n]");
+  } else {
+    const char* p64 = (const char*)b64.buf;
+    const char* p32 = (const char*)b32.buf;
+    auto r64 = [&](int r) {
+      return (const int64_t*)(p64 + r * b64.strides[0]);
+    };
+    auto r32 = [&](int r) {
+      return (const int32_t*)(p32 + r * b32.strides[0]);
+    };
+    const int64_t *hits = r64(1), *limit = r64(2), *eff = r64(4),
+                  *burst = r64(6), *now = r64(7);
+    const int32_t *alg = r32(1), *valid = r32(2);
+    const int32_t* ms = has_ms ? (const int32_t*)bms.buf : nullptr;
+    Derived d;
+    for (Py_ssize_t i = 0; i < n; i++)
+      d.row(valid[i] != 0, ms && ms[i] >= 0, hits[i], limit[i], burst[i],
+            alg[i], eff[i], now[i], value_bound, eff_bound);
+    out = d.build();
+  }
+  PyBuffer_Release(&b64);
+  PyBuffer_Release(&b32);
+  if (has_ms) PyBuffer_Release(&bms);
+  return out;
+}
+
 // pack_wire_wave(data, now_ms, a64, a32, m,
-//                duration_max, value_max, eff_max, td_bound) ->
+//                duration_max, value_max, eff_max, td_bound,
+//                value_bound, eff_bound) ->
 //   None                              (needs the classic/pb2 path)
 // | (n, khash u64le, behavior_or, tlv_off u64le, tlv_len u64le,
-//    name_hash u64le)
+//    name_hash u64le, (ood i64le, leaky, now_lo, now_hi, monotone))
 //
 // The fused wire ingest: one pass over a GetRateLimitsReq /
 // GetPeerRateLimitsReq that parses, validates, clamps (bit-identical to
 // core/batch.py › pack_columns — the clamp bounds come in as arguments
 // so types.py stays the single source of truth), key-hashes
-// (FNV-1a64 + mix64, zero-remapped) and writes the rows STRAIGHT into a
-// leased pair of packed wave-upload matrices (a64 [8,m] i64 row-major:
+// (FNV-1a64 + mix64, zero-remapped) and writes the rows STRAIGHT into
+// the call's pair in the upload layout (a64 [8,m] i64 row-major:
 // key,hits,limit,duration,eff_ms,greg_end,burst,now; a32 [3,m] i32:
-// behavior,algorithm,valid — parallel/sharded.py › PACK64/PACK32).
-// Padding rows [n, m) keep empty_batch semantics: the buffers arrive
-// zeroed from the pool and only eff_ms is re-filled to 1 here.
+// behavior,algorithm,valid — core/batch.py › PACK64/PACK32).  Every
+// cell is written: rows [n, m) read as empty_batch padding (zeros,
+// eff_ms 1), so the caller may pass uninitialised memory.
 //
-// Returns None (caller releases the lease and falls back) whenever the
-// batch needs host-side Python: pb2-fallback framing (as
-// parse_get_rate_limits), n > m, or any DURATION_IS_GREGORIAN row
-// (calendar period ends are computed in Python).  GLOBAL/MULTI_REGION
-// gating is the caller's policy — behavior_or is returned for it.
-// name_hash: as parse_get_rate_limits returns it.
+// In the same pass it derives what the engine's launch needs to know of
+// the call (struct Derived above): ood, the indices of rows outside the
+// step program's value domain (its bounds passed in as the clamp bounds
+// are); leaky, the LEAKY_BUCKET rows inside it; now_lo / now_hi, the
+// least and largest arrival time; monotone, whether arrival times never
+// decrease in row order.
+//
+// Returns None (caller falls back) whenever the batch needs host-side
+// Python: pb2-fallback framing (as parse_get_rate_limits), n > m, or any
+// DURATION_IS_GREGORIAN row (calendar period ends are computed in
+// Python).  GLOBAL/MULTI_REGION gating is the caller's policy —
+// behavior_or is returned for it.  name_hash: as parse_get_rate_limits
+// returns it.
 static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
   Py_buffer view, b64, b32;
   long long now_ms;
   Py_ssize_t m;
   unsigned long long duration_max, value_max, eff_max, td_bound;
-  if (!PyArg_ParseTuple(args, "y*Lw*w*nKKKK", &view, &now_ms, &b64, &b32,
+  unsigned long long value_bound, eff_bound;
+  if (!PyArg_ParseTuple(args, "y*Lw*w*nKKKKKK", &view, &now_ms, &b64, &b32,
                         &m, &duration_max, &value_max, &eff_max,
-                        &td_bound))
+                        &td_bound, &value_bound, &eff_bound))
     return nullptr;
   if (b64.len < m * 8 * (Py_ssize_t)sizeof(int64_t) ||
       b32.len < m * 3 * (Py_ssize_t)sizeof(int32_t)) {
@@ -502,17 +624,18 @@ static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
   int64_t* r_limit = a64 + 2 * m;
   int64_t* r_dur = a64 + 3 * m;
   int64_t* r_eff = a64 + 4 * m;
+  int64_t* r_greg = a64 + 5 * m;
   int64_t* r_burst = a64 + 6 * m;
   int64_t* r_now = a64 + 7 * m;
   int32_t* r_beh = a32;
   int32_t* r_alg = a32 + m;
   int32_t* r_valid = a32 + 2 * m;
-  for (Py_ssize_t i = 0; i < m; i++) r_eff[i] = 1;  // padding eff_ms
   const uint8_t* base = (const uint8_t*)view.buf;
   const uint8_t* p = base;
   const uint8_t* end = p + view.len;
   std::vector<uint64_t> khash, name_hash, tlv_off, tlv_len;
   khash.reserve(64);
+  Derived derived;
   uint64_t beh_or = 0;
   const uint64_t GREG = 4;  // Behavior.DURATION_IS_GREGORIAN
   bool fallback = false;
@@ -620,18 +743,29 @@ static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
     r_limit[n] = lim;
     r_dur[n] = dur;
     r_eff[n] = eff;
+    r_greg[n] = 0;
     r_burst[n] = burst;
     // the caller's accepted-at clock wins when the forward hop stamped
     // it (created_at, field 10): applying a forwarded request at OUR
     // wall clock would mix time bases in the key's bucket row and a
     // later base reads the earlier one as expired — bucket reset,
     // debits silently gone (cold-key conservation loss)
-    r_now[n] = f_created > 0 ? f_created : (int64_t)now_ms;
+    int64_t now_i = f_created > 0 ? f_created : (int64_t)now_ms;
+    r_now[n] = now_i;
     r_beh[n] = f_beh;
     r_alg[n] = leaky ? 1 : 0;
     r_valid[n] = 1;
     beh_or |= (uint64_t)(uint32_t)f_beh;
+    derived.row(true, false, hits, lim, burst, leaky ? 1 : 0, eff, now_i,
+                value_bound, eff_bound);
     n++;
+  }
+  if (!fallback) {
+    for (Py_ssize_t i = n; i < m; i++) {  // padding: empty_batch rows
+      for (int r = 0; r < 8; r++) a64[r * m + i] = 0;
+      r_eff[i] = 1;
+      for (int r = 0; r < 3; r++) a32[r * m + i] = 0;
+    }
   }
   PyBuffer_Release(&view);
   PyBuffer_Release(&b64);
@@ -642,9 +776,9 @@ static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
   const char* to_p = n ? (const char*)tlv_off.data() : kEmptyW;
   const char* tl_p = n ? (const char*)tlv_len.data() : kEmptyW;
   const char* nh_p = n ? (const char*)name_hash.data() : kEmptyW;
-  return Py_BuildValue("(ny#Ky#y#y#)", n, kh_p, n * 8,
+  return Py_BuildValue("(ny#Ky#y#y#N)", n, kh_p, n * 8,
                        (unsigned long long)beh_or, to_p, n * 8, tl_p,
-                       n * 8, nh_p, n * 8);
+                       n * 8, nh_p, n * 8, derived.build());
 }
 
 // split_resp_items(bytes) ->
@@ -1097,6 +1231,9 @@ static PyMethodDef methods[] = {
     {"pack_wire_wave", pack_wire_wave, METH_VARARGS,
      "Fused ingest: wire bytes -> clamped rows written into leased "
      "packed wave matrices (or None)"},
+    {"derive_rows", derive_rows, METH_VARARGS,
+     "What a launch needs to know of rows in the upload layout: "
+     "out-of-domain rows, leaky rows, the clocks"},
     {"stamp_req_tlvs", stamp_req_tlvs, METH_VARARGS,
      "Join request TLV slices, appending created_at (field 10) where "
      "unset — the forward hop's caller-clock stamp"},

@@ -83,36 +83,67 @@ def count_req_items(data: bytes):
 
 
 def pack_wire_wave(data: bytes, now_ms: int, a64: np.ndarray,
-                   a32: np.ndarray):
+                   a32: np.ndarray, value_domain=None):
     """Fused wire ingest: parse + validate + clamp + key-hash (FNV-1a64
     → mix64, zero-remapped) one request message and write the rows
-    straight into a leased packed wave-upload pair (``a64`` [8, m] i64,
-    ``a32`` [3, m] i32 — parallel/sharded.py › PACK64/PACK32 layout,
-    zeroed by the pool; only the eff_ms padding row is re-filled here).
+    straight into the call's pair in the upload layout (``a64`` [8, m]
+    i64, ``a32`` [3, m] i32 — core/batch.py › PACK64/PACK32; every cell
+    is written, rows past n as padding, so ``np.empty`` will do).
 
-    Returns None (caller releases the lease and falls back to the
-    classic numpy pack) for anything the lane doesn't model: pb2
-    framing, n > m, or any DURATION_IS_GREGORIAN row.  Otherwise
-    (n, khash u64[n] MIXED, behavior_or, tlv_off, tlv_len, name_hash
-    u64[n] — raw FNV-1a64 of each request's name alone).  Clamp
-    bounds are passed from types.py so the constants have one home;
-    clamp arithmetic is pinned bit-identical to core/batch.py ›
-    pack_columns by tests/test_native.py."""
+    Returns None (caller falls back to the classic numpy pack) for
+    anything the lane doesn't model: pb2 framing, n > m, or any
+    DURATION_IS_GREGORIAN row.  Otherwise (n, khash u64[n] MIXED,
+    behavior_or, tlv_off, tlv_len, name_hash u64[n] — raw FNV-1a64 of
+    each request's name alone —, derived), where derived = (ood,
+    leaky, now_lo, now_hi, monotone) is what ``ShardedEngine.lay_out``
+    derives of a call's rows, from the same pass: ``value_domain`` is
+    the engine's (VALUE_BOUND, EFF_BOUND), None for the full domain.
+    Clamp bounds are passed from types.py so the constants have one
+    home; clamp arithmetic is pinned bit-identical to core/batch.py ›
+    pack_columns by tests/test_native.py, the derived values to
+    ``lay_out`` by tests/test_wave_layout.py."""
     from ..types import DURATION_MAX, EFF_MAX, TD_BOUND, VALUE_MAX
 
     m = a64.shape[1]
+    if not (a64.flags.c_contiguous and a32.flags.c_contiguous
+            and a64.dtype == np.int64 and a32.dtype == np.int32
+            and a64.shape == (8, m) and a32.shape == (3, m)):
+        raise ValueError("pack_wire_wave wants C-contiguous [8, m] i64 "
+                         "and [3, m] i32")
+    vb, eb = value_domain if value_domain is not None else (0, 0)
     r = _native.pack_wire_wave(data, int(now_ms), a64, a32, m,
                                DURATION_MAX, VALUE_MAX, EFF_MAX,
-                               TD_BOUND)
+                               TD_BOUND, vb, eb)
     if r is None:
         return None
-    n, kh, beh_or, toff, tlen, nh = r
+    n, kh, beh_or, toff, tlen, nh, derived = r
     return (n,
             np.frombuffer(kh, "<u8", count=n),
             int(beh_or),
             np.frombuffer(toff, "<u8", count=n),
             np.frombuffer(tlen, "<u8", count=n),
-            np.frombuffer(nh, "<u8", count=n))
+            np.frombuffer(nh, "<u8", count=n),
+            _derived(derived))
+
+
+def _derived(d):
+    ood, leaky, now_lo, now_hi, monotone = d
+    return (np.frombuffer(ood, "<i8") if ood else None, leaky, now_lo,
+            now_hi, monotone)
+
+
+def derive_rows(m64: np.ndarray, m32: np.ndarray, mslot=None,
+                value_domain=None):
+    """What ``pack_wire_wave`` derives of a call's rows, for rows laid
+    out in Python (``m64`` [8, n] i64, ``m32`` [3, n] i32, rows
+    contiguous): (ood, leaky, now_lo, now_hi, monotone) in ONE pass
+    that keeps the GIL — a handler's ~25 numpy calls, each to be won
+    back from the other handlers, become one.  ``mslot``: i32[n], rows
+    >= 0 exempt from the domain; ``value_domain``: as above."""
+    vb, eb = value_domain if value_domain is not None else (0, 0)
+    if mslot is not None:
+        mslot = np.ascontiguousarray(mslot, np.int32)
+    return _derived(_native.derive_rows(m64, m32, mslot, vb, eb))
 
 
 def split_resp_items(data: bytes):

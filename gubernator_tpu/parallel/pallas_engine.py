@@ -44,7 +44,7 @@ from ..core.batch import RequestBatch
 from ..core.step import decide_batch_impl
 from ..ops import pallas_step as ps
 from .mesh import SHARD_AXIS, XLA_EXEC_MU, exec_gate
-from .sharded import PACK32, PACK64, ShardedEngine
+from .sharded import ShardedEngine
 
 log = logging.getLogger("gubernator_tpu.pallas_engine")
 
@@ -598,22 +598,24 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
 
     # ---- serving -------------------------------------------------------
 
-    def _mask_out_of_domain(self, batch, mslot=None):
-        """Invalidate rows outside the kernel's value domain; returns
-        (masked batch, ood index array or None, the mask's own
-        ``algorithm == 1`` column or None where no row is leaky).
-        Mesh-GLOBAL rows (mslot >= 0) are exempt: they decide on the
-        replica table's XLA math inside the fused program, which has
-        the full int64 domain."""
-        mask, leaky = ps.pallas_value_domain_mask(batch)
+    value_domain = (ps.VALUE_BOUND, ps.EFF_BOUND)
+
+    def _out_of_domain(self, rows, mslot=None):
+        """``lay_out`` without the C++ extension, which is given
+        ``value_domain`` and applies the same rule: (indices of the
+        valid rows outside the kernel's value domain or None, the count
+        of LEAKY rows that stay valid).  Mesh-GLOBAL
+        rows (mslot >= 0) are exempt: they decide on the replica
+        table's XLA math inside the fused program, which has the full
+        int64 domain."""
+        mask, leaky = ps.pallas_value_domain_mask(rows.batch)
         if mslot is not None:
-            mask = mask | (np.asarray(mslot) >= 0)
-        v = np.asarray(batch.valid)
+            mask |= np.asarray(mslot) >= 0
+        v = rows.valid
         ood = v & ~mask
-        if not ood.any():
-            return batch, None, leaky
-        return (batch._replace(valid=jnp.asarray(v & mask)),
-                np.nonzero(ood)[0], leaky)
+        return (np.nonzero(ood)[0] if ood.any() else None,
+                0 if leaky is None
+                else int(np.count_nonzero(leaky & v & mask)))
 
     @staticmethod
     def _merge_ood(cols, ood):

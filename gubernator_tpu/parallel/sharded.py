@@ -23,8 +23,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..hashing import shard_of
 from ..types import RateLimitRequest, RateLimitResponse, Status
-from ..core.batch import (RequestBatch, WaveBufferPool, empty_batch,
-                          pack_requests)
+from ..core.batch import (PACK32, PACK64, RequestBatch, Rows,  # noqa: F401
+                          WaveBufferPool, clock_order, empty_batch,
+                          join_calls, pack_requests, stack_rows)
 from ..core.step import decide_batch_impl, _insert, _lookup, _probe_slots
 from ..core.table import TableState, init_table
 from ..tracing import phase
@@ -43,18 +44,17 @@ VALUE_COLS = tuple(f for f in TableState._fields if f != "key")
 
 
 class PrepackedWave:
-    """One fused-ingest call: a leased packed upload pair with rows
-    [0, n) already parsed/clamped/hashed in C++ (pack_wire_wave), plus
-    the per-request metadata the serving lanes gate on.  The holder
-    owns the lease and must release it on every path (instance.py ›
-    _run_fused copies the rows out and releases it)."""
+    """One fused-ingest call: its rows parsed/clamped/hashed AND laid
+    out by C++ in one pass (pack_wire_wave) into a right-sized pair,
+    with what the engine derives of them (``Rows``), plus the
+    per-request metadata the serving lanes gate on."""
 
-    __slots__ = ("lease", "n", "khash", "behavior_or", "tlv_off",
+    __slots__ = ("rows", "n", "khash", "behavior_or", "tlv_off",
                  "tlv_len", "name_hash")
 
-    def __init__(self, lease, n, khash, behavior_or, tlv_off, tlv_len,
+    def __init__(self, rows, n, khash, behavior_or, tlv_off, tlv_len,
                  name_hash):
-        self.lease = lease
+        self.rows = rows
         self.n = n
         self.khash = khash
         self.behavior_or = behavior_or
@@ -244,30 +244,11 @@ def make_sharded_step(mesh, donate: bool = False):
     return jax.jit(sharded, donate_argnums=(0,) if donate else ())
 
 
-#: Packed-transfer wire layout for the serving step: every RequestBatch
-#: int64 column rides one [8, B] int64 upload (key bit-viewed; row 7 is
-#: the per-request arrival time), the int32/bool columns one [3, B]
-#: int32 upload, and all five outputs one [5, B] int64 download.  A
-#: device call then costs 2 uploads + 1 download instead of 10 + 5 —
-#: per-transfer latency (PCIe doorbells) dominates these tiny arrays,
-#: not bandwidth.
-PACK64 = ("key", "hits", "limit", "duration", "eff_ms", "greg_end",
-          "burst", "now")
-PACK32 = ("behavior", "algorithm", "valid")
-
-
 def pack_wave_host(b: RequestBatch) -> tuple[np.ndarray, np.ndarray]:
-    """RequestBatch of numpy columns → ([8,B] i64, [3,B] i32)."""
-    B = len(b.key)
-    a64 = np.empty((len(PACK64), B), np.int64)
-    a64[0] = np.asarray(b.key).view(np.int64)
-    for i, f in enumerate(PACK64[1:], start=1):
-        a64[i] = getattr(b, f)
-    a32 = np.empty((len(PACK32), B), np.int32)
-    a32[0] = b.behavior
-    a32[1] = b.algorithm
-    a32[2] = b.valid
-    return a64, a32
+    """RequestBatch of numpy columns → ([8,B] i64, [3,B] i32), the
+    upload layout (core/batch.py › PACK64 / PACK32)."""
+    rows = stack_rows(b)
+    return rows.m64, rows.m32
 
 
 def make_sharded_step_packed(mesh, donate: bool = False):
@@ -360,11 +341,13 @@ class ShardedEngine:
         self._grow_fns: dict = {}  # cap_new → compiled grow program
         self.dropped_rows = 0  # rows lost to grow/restore re-placement
         #: reusable packed-upload matrices, one ring per wave width
-        #: (core/batch.py): leased in _fill_packed, released right
-        #: after the launch consumes them (jax copies host operands at
-        #: dispatch).  V1Instance binds its Metrics here for the
+        #: (core/batch.py): leased in join_calls / _fill, released when
+        #: the wave's token is dropped (the token's batch may be views
+        #: of them).  V1Instance binds its Metrics here for the
         #: hit/miss/leak counters.
         self.wave_pool = WaveBufferPool()
+        self._iota = np.arange(self.n * self.wave_buckets[-1],
+                               dtype=np.int64)  # see _first
         #: bound TierController (tiering.py) when GUBER_TIER_COLD=1 —
         #: check_packed pre-masks cold-resident rows out of the device
         #: wave and serves them (plus residual table-full rows) from
@@ -451,20 +434,170 @@ class ShardedEngine:
             return self._pallas_sweep_fn(self.state,
                                          jnp.asarray(now_ms, jnp.int64))
 
-    @staticmethod
-    def _arrival_order(batch: RequestBatch) -> np.ndarray:
-        """Request indices in arrival-time order (earliest requests
-        take the earliest waves, so same-key requests split across
-        waves apply in time order).  The common serving shape — a wave
-        whose ``now`` column is already non-decreasing (one caller, or
-        dispatcher-merged jobs queued in clock order) — skips the
-        argsort: an O(n) monotonicity check replaces the O(n log n)
-        sort on the per-wave host path."""
-        now_col = np.asarray(batch.now)
-        n = len(now_col)
-        if n <= 1 or (now_col[1:] >= now_col[:-1]).all():
-            return np.arange(n, dtype=np.int64)
-        return np.argsort(now_col, kind="stable")
+    #: (VALUE_BOUND, EFF_BOUND) of the step program's value domain, for
+    #: the C++ ingest, which derives a call's out-of-domain rows in its
+    #: one pass; None = the full int64 domain (the XLA step)
+    value_domain = None
+
+    def _first(self, n: int) -> np.ndarray:
+        """arange(n) as a view of one shared array — READ-ONLY: the
+        identity route's indices and slots, and the arrival order of a
+        wave whose clock never runs backwards."""
+        if n > len(self._iota):
+            self._iota = np.arange(2 * n, dtype=np.int64)
+        return self._iota[:n]
+
+    # ---- a call's rows, laid out once (ISSUE 30) ------------------------
+    #
+    # Who lays out what: the CALL's thread stacks its rows into one
+    # pair in the upload layout (core/batch.py › Rows) and derives,
+    # once, what the launch needs to know of them (``lay_out``); the
+    # dispatch worker — the one thread the device waits for — joins
+    # the calls' blocks into the wave (``join_calls``) and launches.
+    # Its work a wave is O(calls), not a pass per column.
+
+    def lay_out(self, batch: RequestBatch, khash, mslot=None) -> Rows:
+        """A call's rows with what this engine derives of them: the
+        rows outside the step program's domain, the LEAKY rows that
+        stay valid, and the range and order of their clocks."""
+        rows = stack_rows(batch)
+        if rows.monotone is not None:
+            return rows  # derived already (the C++ ingest, an earlier call)
+        if _wire_native is not None:
+            # one C++ pass that keeps the GIL: this runs in ~30 handler
+            # threads at once, and every numpy call of the fallback
+            # below would have to win the GIL back from the others
+            (rows.ood, rows.leaky, rows.now_lo, rows.now_hi,
+             rows.monotone) = _wire_native.derive_rows(
+                 rows.m64, rows.m32, mslot, self.value_domain)
+            return rows
+        rows.ood, rows.leaky = self._out_of_domain(rows, mslot)
+        now = rows.now
+        rows.monotone = bool((now[1:] >= now[:-1]).all())
+        if len(now):
+            rows.now_lo, rows.now_hi = int(now.min()), int(now.max())
+        return rows
+
+    def _out_of_domain(self, rows: Rows, mslot=None):
+        """``lay_out`` without the C++ extension: (indices of the valid
+        rows this engine's step program cannot represent — None where
+        none is — and the count of LEAKY_BUCKET rows that stay valid).
+        The XLA step has the full int64 domain: nothing to mask."""
+        alg = rows.algorithm
+        if not alg.any():
+            return None, 0
+        return None, int(np.count_nonzero((alg == 1) & rows.valid))
+
+    def join_calls(self, calls: Sequence[Rows], khashes, mslots=None):
+        """The dispatch worker's `wave.concat`: the calls' blocks → ONE
+        wave, (rows, khash, mslot).
+
+        Where the route would be the identity — one shard, no cold
+        tier, the rows fit the largest bucket and their clocks never
+        run backwards, so ``_build_waves`` would put row i in slot i of
+        one device wave — the blocks are joined STRAIGHT into a pooled
+        upload pair: the concat is the fill, and the wave's rows are
+        views of its lease.  Otherwise they are joined into fresh
+        matrices that ``_fill`` scatters by shard.  Which of the two
+        depends only on what is observed here."""
+        total = sum(len(c) for c in calls)
+        mono = clock_order(calls) == list(range(len(calls)))
+        lease = None
+        if (self.n == 1 and self.tier is None and mono
+                and 0 < total <= self.wave_buckets[-1]):
+            lease = self.wave_pool.lease(
+                next(b for b in self.wave_buckets if total <= b),
+                rows=total)
+        try:
+            wave, khash, mslot = join_calls(calls, khashes, mslots,
+                                            into=lease)
+        except BaseException:
+            if lease is not None:
+                lease.release()
+            raise
+        # what the calls derived, offset and summed in plain Python
+        oods, off = [], 0
+        for c in calls:
+            if c.ood is not None:
+                oods.append(c.ood + off)
+            off += len(c)
+        wave.ood = (None if not oods else oods[0] if len(oods) == 1
+                    else np.concatenate(oods))
+        wave.leaky = sum(c.leaky for c in calls)
+        wave.monotone = mono
+        return wave, khash, mslot
+
+    def _wave_of(self, batch: RequestBatch, khash, mslot):
+        """The wave of a launch: the one ``join_calls`` joined into a
+        lease (the dispatcher's), else this one call's rows joined
+        now."""
+        rows = getattr(batch, "rows", None)
+        if rows is not None and rows.lease is not None:
+            return rows, khash, mslot
+        return self.join_calls([self.lay_out(batch, khash, mslot)],
+                               [khash], [mslot])
+
+    def _ride_invalid(self, wave: Rows, khash, mslot):
+        """`wave.route`, first half: (the wave's valid column with the
+        out-of-domain and the cold-tier rows cleared — None where no
+        row is —, cold mask, the valid rows before it), and the count
+        of ``gubernator_wave_leaky_rows``.
+
+        Tiered store (tiering.py): cold-resident rows must NOT hit the
+        device table (a non-full table would insert them fresh — a
+        state fork); they ride the wave invalid.  Mesh-pinned rows
+        (mslot >= 0) are never cold: the pin seed pops the cold copy."""
+        valid = None
+        if wave.ood is not None:
+            valid = wave.valid.copy()
+            valid[wave.ood] = False
+        leaky = wave.leaky
+        cold = orig_valid = None
+        tier = self.tier
+        if tier is not None:
+            kh = np.asarray(khash)
+            orig_valid = (wave.valid if valid is None else valid) & (kh != 0)
+            cold = tier.resident_mask(kh) & orig_valid
+            if mslot is not None:
+                cold &= np.asarray(mslot) < 0
+            if cold.any():
+                valid = (wave.valid if valid is None else valid) & ~cold
+                if leaky:
+                    leaky -= int(np.count_nonzero(
+                        wave.algorithm[cold] == 1))
+        self._count_leaky_rows(leaky)
+        return valid, cold, orig_valid
+
+    def _device_waves(self, wave: Rows, khash, mslot, valid, pending=None):
+        """Yield (idx, slots, lease, mblk) for each device wave of
+        ``wave``'s rows ``pending`` (None = all, in arrival order),
+        its rows in a leased upload pair.  The ONE fill: a wave joined
+        into its lease is there already (`wave.fill` marks the rows
+        that ride invalid, nothing more); any other is routed by shard
+        (`wave.route`) and scattered.  The caller releases each lease.
+
+        Earliest requests take the earliest device waves: same-key
+        requests split across waves then apply in arrival-time order
+        (within a wave the device's (row, now) sort handles it)."""
+        if pending is None and wave.lease is not None:
+            with phase("wave.fill"):
+                if valid is not None:
+                    wave.valid[:] = valid
+            self._count_route("identity")
+            idx = self._first(len(wave))
+            yield idx, idx, wave.lease, wave.mblk
+            return
+        with phase("wave.route"):
+            if pending is None:
+                pending = (self._first(len(wave)) if wave.monotone
+                           else np.argsort(wave.now, kind="stable"))
+            plan = self._build_waves(khash, pending)
+        for idx, slots, bw_w in plan:
+            with phase("wave.fill"):
+                lease, mblk = self._fill(wave, mslot, valid, idx, slots,
+                                         bw_w)
+            self._count_route("sorted")
+            yield idx, slots, lease, mblk
 
     def _build_waves(self, khash: np.ndarray, pending: np.ndarray):
         """Route ``pending`` request indices into device waves.
@@ -494,104 +627,88 @@ class ShardedEngine:
             waves.append((idx, slots, bw_w))
         return waves
 
-    def _fill_packed(self, batch: RequestBatch, idx, slots, bw_w,
-                     mslot=None):
-        """Scatter a wave's requests straight into a LEASED pair of
-        packed wire matrices (one [8, n·Bw] i64 + one [3, n·Bw] i32
-        from ``wave_pool``): fuses the old glob-fill + pack_wave_host
-        into a single set of writes, without the per-wave allocation
-        the old path paid (at a fast device step — TPU: ~0.2 ms — the
-        host-side copies and allocator churn ARE the serving ceiling).
-        Returns (a64, a32, lease, mblk); the caller must
-        ``lease.release()`` once the launch has consumed the buffers,
-        on every path.  ``mslot`` (ISSUE 8, fused engines only) is the
-        per-request mesh-GLOBAL slot column; it rides a plain -1-filled
-        block array (``mblk``), not the lease — mesh waves are the
-        GLOBAL minority, pooling them would tax every wave.
-        Padding rows keep empty_batch semantics: zeros everywhere,
-        eff_ms 1, valid false."""
+    def _fill(self, wave: Rows, mslot, valid, idx, slots, bw_w):
+        """Scatter a device wave's rows out of the joined matrices into
+        a LEASED upload pair ([8, n·Bw] i64 + [3, n·Bw] i32 from
+        ``wave_pool``), one assignment a matrix.  Returns (lease,
+        mblk); the caller releases the lease once the wave's results
+        are on the host, on every path.  ``mslot`` (ISSUE 8, fused
+        engines only) is the per-request mesh-GLOBAL slot column; it
+        rides a plain -1-filled block array (``mblk``), not the lease —
+        mesh waves are the GLOBAL minority, pooling them would tax
+        every wave.  Padding keeps empty_batch semantics (the pool's)."""
         lease = self.wave_pool.lease(self.n * bw_w)
-        a64, a32 = lease.a64, lease.a32
-        a64[PACK64.index("eff_ms")] = 1
-        a64[0][slots] = np.asarray(batch.key).view(np.int64)[idx]
-        for i, f in enumerate(PACK64[1:], start=1):
-            a64[i][slots] = np.asarray(getattr(batch, f))[idx]
-        for i, f in enumerate(PACK32):
-            a32[i][slots] = np.asarray(getattr(batch, f))[idx]
+        lease.a64[:, slots] = wave.m64[:, idx]
+        lease.a32[:, slots] = wave.m32[:, idx]
+        if valid is not None:
+            lease.a32[PACK32.index("valid"), slots] = valid[idx]
         mblk = None
         if mslot is not None:
             mblk = np.full(self.n * bw_w, -1, np.int32)
             mblk[slots] = np.asarray(mslot)[idx]
-        return a64, a32, lease, mblk
+        return lease, mblk
 
     def launch_packed(self, batch: RequestBatch, khash: np.ndarray,
                       now_ms: int, mslot=None):
         """Pipeline phase 1 of check_packed: route and LAUNCH the waves
         without blocking on device results, so the dispatcher can
         overlap the next wave's host work with this one's device time.
-        Returns an opaque token for ``sync_packed``.  State threads
-        through the launches, so later launches are ordered after these
-        device-side regardless of when anyone syncs.  ``mslot`` rides
-        the token so the sync-side retry keeps the rows' lanes.
+        Returns an opaque token for ``sync_packed``; ``drop_packed``
+        ends it.  State threads through the launches, so later launches
+        are ordered after these device-side regardless of when anyone
+        syncs.  ``mslot`` rides the token so the sync-side retry keeps
+        the rows' lanes.
 
         Cold-tier rows (tiering.py) ride the wave invalid and their
         indices ride the token: the SYNC side re-dispatches them
         through check_packed under the engine lock — serving them here
         would let a promotion that lands between launch and sync read
         a row this lane already consumed.  Rows outside the step
-        program's value domain (``_mask_out_of_domain``) ride invalid
-        too; the sync side marks them unservable."""
-        with phase("wave.route"):
-            batch, ood, leaky = self._mask_out_of_domain(batch, mslot)
-            tier = self.tier
-            cold_idx = None
-            if tier is not None:
-                kh = np.asarray(khash)
-                ov = np.asarray(batch.valid) & (kh != 0)
-                cm = tier.resident_mask(kh) & ov
-                if mslot is not None:
-                    cm &= np.asarray(mslot) < 0
-                if cm.any():
-                    cold_idx = np.nonzero(cm)[0]
-                    batch = batch._replace(
-                        valid=np.asarray(batch.valid) & ~cm)
-            if leaky is not None:
-                self._count_leaky_rows(
-                    np.count_nonzero(leaky & np.asarray(batch.valid)))
-            waves = self._build_waves(khash, self._arrival_order(batch))
-        launched, leases = [], []
+        program's value domain (``_out_of_domain``) ride invalid too;
+        the sync side marks them unservable.
+
+        The token's batch is row views of the wave — of its LEASE where
+        the blocks were joined straight into one — so every lease rides
+        the token until it is dropped."""
+        wave, khash, mslot = self._wave_of(batch, khash, mslot)
+        launched = []
+        leases = [] if wave.lease is None else [wave.lease]
         try:
-            for idx, slots, bw_w in waves:
-                with phase("wave.fill"):
-                    a64, a32, lease, mblk = self._fill_packed(
-                        batch, idx, slots, bw_w, mslot)
-                # the lease rides the token until sync_packed has the
-                # wave's results: the launch is asynchronous, and the
-                # runtime may still be reading the host operands (the
-                # CPU backend aliases them outright) — a pooled buffer
-                # handed to the next wave before that is a data race
+            with phase("wave.route"):
+                valid, cold, _ = self._ride_invalid(wave, khash, mslot)
+            for idx, slots, lease, mblk in self._device_waves(
+                    wave, khash, mslot, valid):
+                # the lease rides the token until it is dropped: the
+                # launch is asynchronous, and the runtime may still be
+                # reading the host operands (the CPU backend aliases
+                # them outright) — a pooled buffer handed to the next
+                # wave before the results are here is a data race
                 leases.append(lease)
                 # positional mblk only when a mesh lane exists: tests
                 # and profilers wrap _launch_arrays with the classic
                 # 3-arg signature
                 packed, counters = (
-                    self._launch_arrays(a64, a32, now_ms) if mblk is None
-                    else self._launch_arrays(a64, a32, now_ms, mblk))
+                    self._launch_arrays(lease.a64, lease.a32, now_ms)
+                    if mblk is None
+                    else self._launch_arrays(lease.a64, lease.a32, now_ms,
+                                             mblk))
                 launched.append((idx, slots, packed, counters, lease))
         except BaseException:
             for lease in leases:
                 lease.release()
             raise
-        return (batch, khash, now_ms, launched, mslot, cold_idx, ood)
+        cold_idx = (np.nonzero(cold)[0]
+                    if cold is not None and cold.any() else None)
+        return (wave.batch, khash, now_ms, launched, mslot, cold_idx,
+                wave.ood)
 
-    def _mask_out_of_domain(self, batch: RequestBatch, mslot=None):
-        """(batch with the rows this engine's step program cannot
-        represent made invalid, their indices or None, the wave's
-        ``algorithm == LEAKY_BUCKET`` column or None where no row is
-        leaky).  The XLA step has the full int64 domain: nothing to
-        mask."""
-        alg = np.asarray(batch.algorithm)
-        return batch, None, alg == 1 if alg.any() else None
+    def _count_route(self, route: str) -> None:
+        """``gubernator_wave_route_total{route}``: one device wave that
+        was joined straight into its lease ("identity") or routed by
+        shard and scattered ("sorted")."""
+        m = self.metrics_ref
+        if m is not None:
+            m.wave_route.labels(route=route).inc()
 
     def _serve_out_of_domain(self, cols, ood, batch, khash, now_ms,
                              mslot):
@@ -608,7 +725,8 @@ class ShardedEngine:
 
     def sync_packed(self, token, engine_lock=None) -> tuple:
         """Pipeline phase 2: block on the launched waves and assemble
-        the response columns (same contract as check_packed).  Reading
+        the response columns (same contract as check_packed).  The
+        token stays alive — ``drop_packed`` ends it.  Reading
         launched outputs needs no lock (state isn't touched); the
         table-full RETRY path re-enters check_packed, which mutates
         state, so it runs under ``engine_lock`` when one is given.  A
@@ -617,14 +735,8 @@ class ShardedEngine:
         table-full corner, and the device clamps per-key time
         monotonically."""
         batch, khash, now_ms, launched, mslot, cold_idx, ood = token
-        finished = []
-        for _idx, _slots, packed, counters, lease in launched:
-            try:
-                finished.append(self._finish_wave(packed, counters))
-            except BaseException:
-                self.drop_packed(token)
-                raise
-            lease.release()  # results are here: the operands were read
+        finished = [self._finish_wave(packed, counters)
+                    for _idx, _slots, packed, counters, _lease in launched]
         n = len(khash)
         err_idx: List[int] = []
         with phase("wave.scatter"):
@@ -651,7 +763,7 @@ class ShardedEngine:
             import contextlib
 
             ei = np.asarray(sorted(err_idx))
-            sub = type(batch)(*[np.asarray(c)[ei] for c in batch])
+            sub = batch.rows.take(ei).batch
             msub = None if mslot is None else np.asarray(mslot)[ei]
             with (engine_lock if engine_lock is not None
                   else contextlib.nullcontext()):
@@ -670,8 +782,8 @@ class ShardedEngine:
             # from whichever tier the key is in NOW — exact even when a
             # promotion landed between our launch and this sync
             ci = np.asarray(cold_idx)
-            sub = type(batch)(*[np.asarray(c)[ci] for c in batch])
-            sub = sub._replace(valid=np.ones(len(ci), bool))
+            sub = batch.rows.take(ci).batch
+            sub.valid[:] = True
             msub = None if mslot is None else np.asarray(mslot)[ci]
             with (engine_lock if engine_lock is not None
                   else contextlib.nullcontext()):
@@ -685,10 +797,11 @@ class ShardedEngine:
         return status, lim_o, rem_o, rst_o, full
 
     def drop_packed(self, token) -> None:
-        """Give up a launched token that will never be synced (the
-        dispatcher's failure paths): return its upload buffers to the
-        pool.  Idempotent; the device work itself already happened —
-        state threads through the launches."""
+        """The end of a launched token, synced or not: return its
+        upload buffers to the pool.  Whoever launched calls it once the
+        token's batch is no longer read (it may be views of a lease) —
+        on every path.  Idempotent; the device work itself already
+        happened — state threads through the launches."""
         for wave in token[3]:
             wave[-1].release()
 
@@ -748,34 +861,29 @@ class ShardedEngine:
     # ---- fused wire lane (ops/_native.cpp › pack_wire_wave) ------------
 
     def prepack_wire(self, data: bytes, now_ms: int):
-        """Fused C++ wire ingest: one pass from request wire bytes to a
-        LEASED pair of packed wave-upload matrices — parse, validate,
-        clamp (bit-identical to pack_columns), key-hash (mixed,
-        zero-remapped) and fill, with zero intermediate numpy columns.
+        """Fused C++ wire ingest: one pass from request wire bytes to
+        the call's ``Rows`` — parse, validate, clamp (bit-identical to
+        pack_columns), key-hash (mixed, zero-remapped), lay out in a
+        right-sized pair and derive what ``lay_out`` would, with zero
+        intermediate numpy columns.
 
-        Single-shard meshes only (block order == request order, so the
-        wave needs no shard routing or slot scatter); multi-shard and
-        anything the C++ lane can't model (pb2 framing, Gregorian rows,
-        n over the largest bucket) returns None and the caller takes
-        the classic parse → pack_columns path.
-
-        Returns a PrepackedWave whose lease the caller OWNS: every
-        return path must end in ``pre.lease.release()``."""
+        Single-shard meshes only; multi-shard and anything the C++ lane
+        can't model (pb2 framing, Gregorian rows, n over the largest
+        bucket) returns None and the caller takes the classic parse →
+        pack_columns path."""
         if self.n != 1 or _wire_native is None:
             return None
         cnt = _wire_native.count_req_items(data)
-        if not cnt:
-            return None
-        bw = next((b for b in self.wave_buckets if cnt <= b), None)
-        if bw is None:
+        if not cnt or cnt > self.wave_buckets[-1]:
             return None  # oversize: classic path splits into waves
-        lease = self.wave_pool.lease(bw)
-        res = _wire_native.pack_wire_wave(data, now_ms, lease.a64,
-                                          lease.a32)
+        rows = Rows.empty(cnt)
+        res = _wire_native.pack_wire_wave(data, now_ms, rows.m64, rows.m32,
+                                          self.value_domain)
         if res is None:
-            lease.release()
             return None
-        return PrepackedWave(lease, *res)
+        (rows.ood, rows.leaky, rows.now_lo, rows.now_hi,
+         rows.monotone) = res[-1]
+        return PrepackedWave(rows, *res[:-1])
 
     def check_batch(self, reqs: Sequence[RateLimitRequest], now_ms: int
                     ) -> List[RateLimitResponse]:
@@ -805,57 +913,46 @@ class ShardedEngine:
         only fused engines receive it (instance.py gates on
         ``engine.mesh_bound``).
         """
+        wave, khash, mslot = self._wave_of(batch, khash, mslot)
+        try:
+            return self._check_wave(wave, khash, now_ms, mslot)
+        finally:
+            # a lease joined HERE goes back now that nothing reads the
+            # wave's rows (views of it); one the caller joined (the
+            # dispatcher, who reads the batch afterwards) is the
+            # caller's to release
+            if (wave.lease is not None
+                    and wave is not getattr(batch, "rows", None)):
+                wave.lease.release()
+
+    def _check_wave(self, wave: Rows, khash, now_ms: int, mslot) -> tuple:
         n = len(khash)
         status = np.zeros(n, np.int32)
         rem_o = np.zeros(n, np.int64)
         rst_o = np.zeros(n, np.int64)
         lim_o = np.zeros(n, np.int64)
         full = np.zeros(n, bool)
-        # tiered store (tiering.py): cold-resident rows must NOT hit
-        # the device table (a non-full table would insert them fresh —
-        # a state fork); ride the wave invalid and serve from the cold
-        # tier in the resolve below.  Mesh-pinned rows (mslot >= 0) are
-        # never cold: the pin seed pops the cold copy.
         with phase("wave.route"):
-            batch, ood, leaky = self._mask_out_of_domain(batch, mslot)
-            tier = self.tier
-            cold_mask = None
-            orig_valid = None
-            if tier is not None:
-                kh = np.asarray(khash)
-                orig_valid = np.asarray(batch.valid) & (kh != 0)
-                cold_mask = tier.resident_mask(kh) & orig_valid
-                if mslot is not None:
-                    cold_mask &= np.asarray(mslot) < 0
-                if cold_mask.any():
-                    batch = batch._replace(
-                        valid=np.asarray(batch.valid) & ~cold_mask)
-            if leaky is not None:
-                self._count_leaky_rows(
-                    np.count_nonzero(leaky & np.asarray(batch.valid)))
-            # earliest requests take the earliest waves: same-key
-            # requests split across waves then apply in arrival-time
-            # order (within a wave the device's (row, now) sort handles
-            # it)
-            pending = self._arrival_order(batch)
-            waves = self._build_waves(khash, pending)
+            valid, cold_mask, orig_valid = self._ride_invalid(wave, khash,
+                                                              mslot)
         retried = False
-        while len(pending):
+        pending = None  # every row, in arrival order
+        while pending is None or len(pending):
             err_idx: List[int] = []
-            for idx, slots, bw_w in waves:
-                with phase("wave.fill"):
-                    a64, a32, lease, mblk = self._fill_packed(
-                        batch, idx, slots, bw_w, mslot)
+            for idx, slots, lease, mblk in self._device_waves(
+                    wave, khash, mslot, valid, pending):
                 try:
                     # see launch_packed: 3-arg call when no mesh lane
                     launched = (
-                        self._launch_arrays(a64, a32, now_ms)
+                        self._launch_arrays(lease.a64, lease.a32, now_ms)
                         if mblk is None
-                        else self._launch_arrays(a64, a32, now_ms, mblk))
+                        else self._launch_arrays(lease.a64, lease.a32,
+                                                 now_ms, mblk))
+                    o_st, o_rem, o_rst, o_lim, o_err = self._finish_wave(
+                        *launched)
                 finally:
-                    lease.release()  # launch copied the host operands
-                o_st, o_rem, o_rst, o_lim, o_err = self._finish_wave(
-                    *launched)
+                    if lease is not wave.lease:
+                        lease.release()  # results are here
                 with phase("wave.scatter"):
                     status[idx] = o_st[slots]
                     rem_o[idx] = o_rem[slots]
@@ -880,18 +977,16 @@ class ShardedEngine:
                     rst_o[i] = 0
                     lim_o[i] = 0
                 pending = np.empty(0, np.int64)
-            if len(pending):
-                with phase("wave.route"):
-                    waves = self._build_waves(khash, pending)
         cols = (status, lim_o, rem_o, rst_o, full)
-        if tier is not None:
+        batch = wave.batch
+        if self.tier is not None:
             # cold lane: pre-masked cold-resident rows plus residual
             # table-full rows (brand-new keys, device table saturated —
             # the tier turns table-full into find-or-create on host)
-            cols = tier.resolve(self, batch, khash, now_ms, cols,
-                                cold_mask, orig_valid, mslot=mslot)
-        return self._serve_out_of_domain(cols, ood, batch, khash, now_ms,
-                                         mslot)
+            cols = self.tier.resolve(self, batch, khash, now_ms, cols,
+                                     cold_mask, orig_valid, mslot=mslot)
+        return self._serve_out_of_domain(cols, wave.ood, batch, khash,
+                                         now_ms, mslot)
 
     def _try_auto_grow(self, grew: list) -> bool:
         """Grow 2× (once per wave) if under auto_grow_limit.  Returns

@@ -162,11 +162,15 @@ PHASE_CATALOG: Dict[str, str] = {
     "worker.gap": "the dispatch worker BETWEEN two of its phases: "
                   "glue, and waiting to get the GIL back",
     "wave.begin": "_wave_begin: telemetry, per-job waits, event",
-    "wave.concat": "column concat of the merged jobs (+ mslot, now)",
+    "wave.concat": "the jobs' blocks put in clock order and joined into "
+                   "the wave (into its upload lease where the route is "
+                   "the identity)",
     "lock.engine": "waiting to acquire the engine lock",
-    "wave.route": "engine: domain mask, tier mask, leaky rows counted, "
-                  "arrival order, _build_waves",
-    "wave.fill": "engine: _fill_packed into the leased upload buffers",
+    "wave.route": "engine: tier mask, leaky rows counted; where the "
+                  "wave is not in its lease yet: arrival order, "
+                  "_build_waves",
+    "wave.fill": "engine: _fill scatters the joined rows into the leased "
+                 "upload buffers (identity route: marks invalid rows)",
     "lock.xla_exec": "engine: waiting to acquire XLA_EXEC_MU",
     "lock.mesh_state": "mesh-GLOBAL tier: a fused launch waiting for "
                        "the tier's state lock (fold tick, pins)",

@@ -54,13 +54,10 @@ def _analytics(cap=None):
 
 
 def _learn_item(reqs):
-    from gubernator_tpu.core.batch import WaveBufferPool
-
     data = _message(reqs)
-    lease = WaveBufferPool().lease(1024)
-    _n, kh, _bo, toff, tlen, nh = native.pack_wire_wave(
-        data, 1, lease.a64, lease.a32)
-    lease.release()
+    _n, kh, _bo, toff, tlen, nh, _derived = native.pack_wire_wave(
+        data, 1, np.empty((8, 1024), np.int64),
+        np.empty((3, 1024), np.int32))
     return ("learn", data, kh, nh, toff, tlen, False)
 
 

@@ -89,18 +89,21 @@ class TestNativeCodec:
         assert reparsed["hits"].tolist() == parsed["hits"].tolist()
 
     def test_pack_wire_wave_now_prefers_created(self):
-        from gubernator_tpu.core.batch import WaveBufferPool
+        import numpy as np
+
         from gubernator_tpu.ops import native
 
         data = req_to_tlv(_req("a", created=T0 + 3)) + \
             req_to_tlv(_req("b", created=0))
-        lease = WaveBufferPool().lease(64)
-        res = native.pack_wire_wave(data, T0 + 99, lease.a64, lease.a32)
+        a64 = np.empty((8, 2), np.int64)
+        res = native.pack_wire_wave(data, T0 + 99, a64,
+                                    np.empty((3, 2), np.int32))
         assert res is not None
         n = res[0]
         assert n == 2
-        assert lease.a64[7][:2].tolist() == [T0 + 3, T0 + 99]
-        lease.release()
+        assert a64[7].tolist() == [T0 + 3, T0 + 99]
+        # the call's clocks, as the launch reads them: T0+3 then T0+99
+        assert res[-1][2:] == (T0 + 3, T0 + 99, True)
 
     def test_pb2_fallback_paths_still_parse_stamped_tlvs(self):
         # pb2 treats field 10 as an unknown field: parses cleanly, and

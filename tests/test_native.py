@@ -72,27 +72,26 @@ def _parsed_columns(parser):
     passes over the same message."""
     import numpy as np
 
-    from gubernator_tpu.core.batch import WaveBufferPool
     from gubernator_tpu.wire import req_to_tlv
 
     reqs = _wire_requests()
     data = b"".join(req_to_tlv(r) for r in reqs)
     if parser == "parse_get_rate_limits":
         return native.parse_get_rate_limits(data), reqs, data
-    lease = WaveBufferPool().lease(512)
-    n, kh, beh_or, toff, tlen, nh = native.pack_wire_wave(
-        data, 1_700_000_000_999, lease.a64, lease.a32)
+    # the call's own pair, uninitialised as prepack_wire hands it over
+    # (and wider than the message: rows past n must read as padding)
+    a64 = np.full((8, 512), -7, np.int64)
+    a32 = np.full((3, 512), -7, np.int32)
+    n, kh, beh_or, toff, tlen, nh, _derived = native.pack_wire_wave(
+        data, 1_700_000_000_999, a64, a32)
+    assert not a64[[0, 1, 2, 3, 5, 6, 7], n:].any() and not a32[:, n:].any()
+    assert (a64[4, n:] == 1).all() and not a64[5].any()
     cols = {"n": n, "khash": kh, "behavior_or": beh_or,
             "tlv_off": toff, "tlv_len": tlen, "name_hash": nh,
-            "hits": np.array(lease.a64[1][:n]),
-            "limit": np.array(lease.a64[2][:n]),
-            "duration": np.array(lease.a64[3][:n]),
-            "burst_filled": np.array(lease.a64[6][:n]),
-            "now": np.array(lease.a64[7][:n]),
-            "behavior": np.array(lease.a32[0][:n]),
-            "algorithm": np.array(lease.a32[1][:n]),
-            "valid": np.array(lease.a32[2][:n])}
-    lease.release()
+            "hits": a64[1][:n], "limit": a64[2][:n],
+            "duration": a64[3][:n], "burst_filled": a64[6][:n],
+            "now": a64[7][:n], "behavior": a32[0][:n],
+            "algorithm": a32[1][:n], "valid": a32[2][:n]}
     return cols, reqs, data
 
 

@@ -104,6 +104,7 @@ class TestServingParity:
         batch, _errs = pack_requests(reqs, NOW, size=3, key_hashes=kh)
         token = pe.launch_packed(batch, kh, NOW)
         st, lim, rem, rst, full = pe.sync_packed(token)
+        pe.drop_packed(token)
         assert list(full) == [False, True, False]
         assert rem[0] == 4 and rem[2] == 4
 

@@ -99,6 +99,7 @@ def _drive_parity(small, big, tc, ranks, *, steps=50, nkeys=2000,
         if pipelined:
             tok = small.launch_packed(b, kh, now)
             r1 = small.sync_packed(tok, engine_lock=lock)
+            small.drop_packed(tok)
         else:
             r1 = small.check_packed(b, kh, now)
         r2 = big.check_packed(b, kh, now)

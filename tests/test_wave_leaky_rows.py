@@ -70,7 +70,9 @@ def via_check_packed(eng, reqs):
 
 def via_launch_packed(eng, reqs):
     batch, kh = packed(reqs)
-    assert not eng.sync_packed(eng.launch_packed(batch, kh, NOW))[4].any()
+    token = eng.launch_packed(batch, kh, NOW)
+    assert not eng.sync_packed(token)[4].any()
+    eng.drop_packed(token)
 
 
 PATHS = {"check_packed": via_check_packed, "launch_packed": via_launch_packed}

@@ -413,5 +413,13 @@ def test_launched_wave_keeps_its_upload_buffers_until_synced():
             st, lim, rem, rst, full = eng.sync_packed(tok)
             assert (lim == limit).all() and (rem == limit - 1).all(), \
                 (rep, limit, np.unique(lim).tolist())
+            # synced, not dead: the token's batch is views of its lease
+            # (one shard, one clock: joined straight into it) until the
+            # launcher drops it
+            assert tok[0].rows.lease is not None
+            assert (tok[0].limit == limit).all()
+        assert eng.wave_pool.stats()["outstanding"] == 2
+        for tok in tokens:
+            eng.drop_packed(tok)
     assert eng.wave_pool.stats()["outstanding"] == 0
     assert eng.wave_pool.stats()["leaks"] == 0

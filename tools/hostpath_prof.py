@@ -67,8 +67,8 @@ def _bucket_of(key) -> str:
         return "dispatch_future"
     if f.endswith("core/batch.py") or f.endswith("hashing.py") \
             or (f.endswith("parallel/sharded.py")
-                and name in ("_fill_packed", "_build_waves",
-                             "_arrival_order", "pack_wave_host",
+                and name in ("_fill", "_build_waves", "join_calls",
+                             "lay_out", "pack_wave_host",
                              "lease", "_return")):
         return "parse_pack"
     if f.endswith("metrics.py") or "prometheus" in f:

@@ -49,9 +49,8 @@ FAST = bool(os.environ.get("GUBER_BENCH_FAST"))
 N_KEYS = int(os.environ.get("GUBER_BENCH_KEYS",
                             1_000_000 if FAST else 10_000_000))
 CAP = int(os.environ.get("GUBER_BENCH_CAP", 1 << 21 if FAST else 1 << 26))
-#: the probe window stays at the serving default (8) everywhere —
-#: bench exports no probe override; GUBER_PROBES in the environment
-#: always means an operator choice.
+#: the probe window stays at the serving default (core/step.py ›
+#: PROBES, a constant of the table) everywhere.
 #: device batch = coalesced client batches of 1024 (GUBER_BENCH_B
 #: overrides for batch-size sweeps)
 B = int(os.environ.get("GUBER_BENCH_B", 8192 if FAST else 65536))
@@ -101,7 +100,8 @@ def main() -> int:
     import jax.numpy as jnp
 
     from gubernator_tpu.core.batch import RequestBatch
-    from gubernator_tpu.core.step import decide_batch, decide_batch_donated
+    from gubernator_tpu.core.step import (PROBES, decide_batch,
+                                          decide_batch_donated)
     from gubernator_tpu.core.table import init_table
 
     backend = jax.default_backend()
@@ -292,11 +292,11 @@ def main() -> int:
             "backend": backend,
             "device": device,
             "populate_errs": dict(populate_errs),
-            "probes": int(os.environ.get("GUBER_PROBES", "8")),
+            "probes": PROBES,
             "ksplit": int(os.environ.get("GUBER_KSPLIT", "0")),
             "config": (f"TOKEN_BUCKET {N_KEYS} keys Zipf({ZIPF_A}) hits=1 "
                        f"CAP={CAP} "
-                       f"probes={os.environ.get('GUBER_PROBES', '8')}"),
+                       f"probes={PROBES}"),
             "baseline_is": ("north-star target 50M decisions/s/chip (no "
                             "published reference numbers; BASELINE.md)"),
             "baseline_configs": {},

@@ -98,7 +98,6 @@ ENV_REGISTRY: Dict[str, str] = {
     "GUBER_PEER_HEALTH_GATE": "0 disables the health-gated routing ring",
     "GUBER_PEER_READMIT_AFTER": "recovered time before an ejected peer readmits (duration)",
     "GUBER_PIPELINE_DEPTH": "in-flight launched waves in the pipeline (min 1)",
-    "GUBER_PROBES": "device step: open-addressing probe count (core/step.py)",
     "GUBER_PROFILE_DIR": "on-demand device-profiler capture directory",
     "GUBER_RESULT_TIMEOUT_S": "caller wave-result timeout seconds (finite, > 0)",
     "GUBER_SCENARIO_DIR": "scenario-lab spec library directory (default scenarios/)",
@@ -264,7 +263,11 @@ class Config:
     #: the Loader / Store protocols.
     loader: Optional[object] = None
     store: Optional[object] = None
-    #: Seconds between expired-row sweeps (0 disables).
+    #: Milliseconds between expired-row sweeps (0 disables).  Also how
+    #: long an insert into a probe window clogged by expired rows can
+    #: go on failing: no wave sweeps for itself, a table_full row asks
+    #: for one sweep ahead of the tick, once an interval
+    #: (instance._maybe_sweep).
     sweep_interval_ms: int = 30_000
     #: Decision-step implementation: "xla" (default — unbounded values,
     #: auto-grow) or "pallas" (the hand-scheduled Mosaic kernel as the
@@ -357,6 +360,8 @@ class DaemonConfig:
     advertise_address: str = ""
     cache_size: int = 1 << 16
     cache_autogrow_max: int = 0
+    #: Milliseconds between expiry sweeps (Config.sweep_interval_ms).
+    sweep_interval_ms: int = 30_000
     #: Device wave rows per shard (Config.batch_rows).
     batch_rows: int = 1024
     handover_on_reshard: bool = False
@@ -406,6 +411,7 @@ class DaemonConfig:
         return Config(
             cache_size=self.cache_size,
             cache_autogrow_max=self.cache_autogrow_max,
+            sweep_interval_ms=self.sweep_interval_ms,
             batch_rows=self.batch_rows,
             step_impl=self.step_impl,
             engine=self.engine,

@@ -41,13 +41,22 @@ from ..types import FRAC_SAFE, TD_BOUND, Algorithm, Behavior
 from .batch import RequestBatch
 from .table import TableState
 
-#: probe window per lookup (GUBER_PROBES overrides).  At the
-#: north-star load (10M keys / CAP 2^24 = 0.60) a window of 8 leaves
-#: ~4e-4 of requests unservable (their keys lost every claim round
-#: during populate — r3 artifact `win_cap24.err_fraction`); the
-#: default is sized so the flagship shape serves 100% of its working
-#: set (verified empirically on the exact populate key set).
-PROBES = int(__import__("os").environ.get("GUBER_PROBES", "8"))
+#: probe window of the column table, per lookup: a constant of the
+#: table, not an option (a window shorter than the depth a snapshot's
+#: rows were placed at would hide them).  Sized for the north-star
+#: deployment: 10M keys in CAP 2^26, load 0.149.
+#: First-free placement leaves a key without a slot in one key set in
+#: N * load^P / (P + 1): at P = 8 that is 0.27 — about one 10M key set
+#: in four loses a key, and a live key without a slot answers
+#: table_full on every request — at P = 16 it is 4e-8.  The first 8
+#: probes are the same sequence whatever P, so every row a shorter
+#: window placed is still found and snapshots stay valid.
+PROBES = 16
+#: probe window of the 4,096-slot replica maps (parallel/hotset.py,
+#: parallel/meshglobal.py and the mesh lane of the fused program):
+#: their slots are pinned from the host over this window, and it does
+#: not grow with the table's
+REPLICA_PROBES = 8
 INSERT_ROUNDS = 4  # slot-claim rounds per batch
 
 #: K-split scatter fallback (GUBER_KSPLIT=<log2 window>, default off):
@@ -214,10 +223,11 @@ class _Req(NamedTuple):
     now: jax.Array  # per-request arrival time (epoch ms)
 
 
-def _probe_slots(key: jax.Array, cap: int) -> jax.Array:
-    """[B, PROBES] int32 probe sequence (double hashing, odd stride)."""
+def _probe_slots(key: jax.Array, cap: int, probes: int = PROBES
+                 ) -> jax.Array:
+    """[B, probes] int32 probe sequence (double hashing, odd stride)."""
     stride = (key >> jnp.uint64(17)) | jnp.uint64(1)
-    p = jnp.arange(PROBES, dtype=jnp.uint64)
+    p = jnp.arange(probes, dtype=jnp.uint64)
     slots = (key[:, None] + p[None, :] * stride[:, None]) & jnp.uint64(cap - 1)
     return slots.astype(jnp.int32)
 
@@ -405,7 +415,8 @@ def _tree_where(mask, a, b):
     return jax.tree.map(lambda x, y: jnp.where(mask, x, y), a, b)
 
 
-def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array
+def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array,
+                      probes: int = PROBES
                       ) -> tuple[TableState, StepOutput]:
     """Apply one request batch to the table; returns (new state, outputs).
 
@@ -414,7 +425,8 @@ def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array
     composition including duplicate keys.
 
     Unjitted building block: compose under jit/scan/shard_map.  Use
-    ``decide_batch`` for direct host dispatch.
+    ``decide_batch`` for direct host dispatch.  ``probes`` is the probe
+    window of ``state`` (the replica maps pass ``REPLICA_PROBES``).
     """
     cap = state.key.shape[0]
     B = batch.key.shape[0]
@@ -433,7 +445,7 @@ def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array
                             jnp.asarray(batch.now, i64), now)
 
     # ---- probe / insert -------------------------------------------------
-    slots = _probe_slots(key, cap)
+    slots = _probe_slots(key, cap, probes)
     tkey = state.key
     row, _ = _lookup(tkey, slots, key)
     row = jnp.where(valid & (row >= 0), row, -1)

@@ -372,6 +372,7 @@ class V1Instance:
         self._handover_gen_mu = threading.Lock()
         self._closed = False
         self._last_sweep = clock_ms()  # clock-ok: sweep cadence bookkeeping, never a bucket stamp
+        self._last_asked_sweep = 0  # the last sweep a table_full row asked for
         self.store = config.store
         self.loader = config.loader
         if self.loader is not None:
@@ -2792,11 +2793,29 @@ class V1Instance:
                 status=int(resp.status)))
 
     def _maybe_sweep(self, now: int) -> None:
+        """The whole-table expiry sweep, between waves and under the
+        engine lock: when the interval has come round (cause "tick"),
+        or ahead of it, once an interval, when a wave answered a row
+        table_full ("table_full": a window clogged by expired rows
+        takes inserts again after it; one full of live keys does not,
+        which is why no wave sweeps for itself)."""
         iv = self.config.sweep_interval_ms
-        if iv > 0 and now - self._last_sweep >= iv:
+        if iv <= 0:
+            return
+        if now - self._last_sweep >= iv:
+            cause = "tick"
             self._last_sweep = now
-            with self._engine_mu:
-                self.engine.sweep(now)
+        elif (getattr(self.engine, "sweep_wanted", False)
+              and now - self._last_asked_sweep >= iv):
+            cause = "table_full"
+            self._last_asked_sweep = now
+        else:
+            return
+        with self._engine_mu, phase("sweep", self.dispatcher):
+            self.engine.sweep_wanted = False
+            self.engine.sweep(now)
+        self.metrics.sweeps.labels(cause=cause).inc()
+        if cause == "tick":
             self._hot_decay()
 
     # ---- peer service (owner side) -------------------------------------

@@ -142,6 +142,24 @@ class Metrics:
             "lease (one shard, clocks in order, no cold tier, one "
             "bucket), sorted = routed by shard and scattered",
             ["route"], registry=r)
+        self.sweeps = Counter(
+            "gubernator_sweep",
+            "whole-table expiry sweeps by cause (instance._maybe_sweep, "
+            "between waves): tick = the sweep interval came round, "
+            "table_full = a wave answered a row table_full and asked "
+            "for one ahead of the tick (at most one an interval)",
+            ["cause"], registry=r)
+        self.table_full_rows = Counter(
+            "gubernator_table_full_rows",
+            "rows answered table_full (unservable): probe window or "
+            "bucket full after the retry, or values outside the step "
+            "program's domain; cold-tier served rows excluded",
+            registry=r)
+        self.restore_unplaced_rows = Gauge(
+            "gubernator_restore_unplaced_rows",
+            "rows of the last engine.restore that found no slot in "
+            "their probe window and no cold tier to adopt them",
+            registry=r)
         self.wave_queue_wait = Histogram(
             "gubernator_dispatcher_queue_wait",
             "job wait from submit to its wave launching (s)",

@@ -64,7 +64,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.batch import (RequestBatch, clamp_config,
                           empty_batch, pack_requests)
-from ..core.step import decide_batch_impl, divmod_nn
+from ..core.step import REPLICA_PROBES, decide_batch_impl, divmod_nn
 from ..core.table import TableState, init_table
 from ..types import EFF_MAX, RateLimitRequest, RateLimitResponse, Status
 from .mesh import SHARD_AXIS
@@ -96,7 +96,7 @@ def make_hot_step(mesh):
             hits=a64[1], limit=a64[2], duration=a64[3], eff_ms=a64[4],
             greg_end=a64[5], burst=a64[6], now=a64[7],
             behavior=a32[0], algorithm=a32[1], valid=a32[2] != 0)
-        st, out = decide_batch_impl(st, bt, now)
+        st, out = decide_batch_impl(st, bt, now, REPLICA_PROBES)
         st = jax.tree.map(lambda x: x[None], st)
         packed = jnp.stack([
             out.status.astype(jnp.int64), out.remaining, out.reset_time,
@@ -209,12 +209,10 @@ class HotSetEngine:
         """The key's probe sequence — MUST match core/step.py ›
         _probe_slots, since the device kernel looks keys up by probing;
         a pinned key outside its probe window would be invisible."""
-        from ..core.step import PROBES
-
         k = np.uint64(key_hash)
         stride = int((k >> np.uint64(17)) | np.uint64(1))
         return [int((int(k) + p * stride) & (self.capacity - 1))
-                for p in range(PROBES)]
+                for p in range(REPLICA_PROBES)]
 
     def pin(self, req: RateLimitRequest, key_hash: int, now_ms: int,
             seed: Optional[dict] = None) -> bool:

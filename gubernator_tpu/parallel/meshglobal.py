@@ -55,7 +55,8 @@ from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.batch import RequestBatch, clamp_config, empty_batch, pack_requests
-from ..core.step import _lookup, _probe_slots, decide_batch_impl
+from ..core.step import (REPLICA_PROBES, _lookup, _probe_slots,
+                         decide_batch_impl)
 from ..core.table import TableState, init_table
 from ..hashing import shard_of
 from ..tracing import phase
@@ -94,12 +95,12 @@ def make_mesh_global_step(mesh, cap: int):
             hits=a64[1], limit=a64[2], duration=a64[3], eff_ms=a64[4],
             greg_end=a64[5], burst=a64[6], now=a64[7],
             behavior=a32[0], algorithm=a32[1], valid=a32[2] != 0)
-        st, out = decide_batch_impl(st, bt, now)
+        st, out = decide_batch_impl(st, bt, now, REPLICA_PROBES)
         # per-slot accumulation: re-probe the (post-step) key column so
         # each applied request's hits land on its row's accumulator
         # slot.  Erred rows (probe window exhausted) never mutated
         # state, so they don't accumulate either.
-        slots = _probe_slots(bt.key, cap)
+        slots = _probe_slots(bt.key, cap, REPLICA_PROBES)
         row, _ = _lookup(st.key, slots, bt.key)
         ok = bt.valid & (row >= 0) & (~out.err)
         wrow = jnp.where(ok, row, cap)
@@ -212,12 +213,10 @@ class MeshGlobalEngine:
     # ---- host slot management (hot-set discipline) ---------------------
 
     def _probe_slots_host(self, key_hash: int) -> List[int]:
-        from ..core.step import PROBES
-
         k = np.uint64(key_hash)
         stride = int((k >> np.uint64(17)) | np.uint64(1))
         return [int((int(k) + p * stride) & (self.capacity - 1))
-                for p in range(PROBES)]
+                for p in range(REPLICA_PROBES)]
 
     def is_pinned(self, key_hash: int) -> bool:
         return key_hash in self.slots

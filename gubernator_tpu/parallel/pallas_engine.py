@@ -41,7 +41,7 @@ from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.batch import RequestBatch
-from ..core.step import decide_batch_impl
+from ..core.step import REPLICA_PROBES, decide_batch_impl
 from ..ops import pallas_step as ps
 from .mesh import SHARD_AXIS, XLA_EXEC_MU, exec_gate
 from .sharded import ShardedEngine
@@ -349,7 +349,7 @@ def make_fused_mesh_step_packed(mesh, *, flavor: str, mesh_cap: int,
         mst = jax.tree.map(lambda x: x[0], mstate)
         a = acc[0]
         mb = batch._replace(valid=batch.valid & mesh_rows)
-        mst, mout = decide_batch_impl(mst, mb, now)
+        mst, mout = decide_batch_impl(mst, mb, now, REPLICA_PROBES)
         ok = mb.valid & (~mout.err)
         applied = jnp.where(ok, jnp.maximum(batch.hits, 0),
                             jnp.int64(0))

@@ -202,8 +202,8 @@ def responses_from_columns(cols, errors=None):
         if errors is not None and errors[i]:
             out.append(RateLimitResponse(error=errors[i]))
         elif full_l[i]:
-            # probe window exhausted by LIVE keys even after the sweep
-            # retry (and auto-grow, if enabled) inside check_packed
+            # probe window full even after the retry (and auto-grow,
+            # if enabled) inside check_packed
             out.append(RateLimitResponse(error="rate limit table full"))
         else:
             out.append(RateLimitResponse(
@@ -257,7 +257,9 @@ def make_sharded_step_packed(mesh, donate: bool = False):
     outputs, (over, insert) counters)."""
     S = SHARD_AXIS
 
-    def _step(state, a64, a32, now):
+    # the name is the jit's, and a profile's "XLA Modules" line tells
+    # this program from the fused engines' `_step` by it
+    def xla_step_packed(state, a64, a32, now):
         batch = RequestBatch(
             key=lax.bitcast_convert_type(a64[0], jnp.uint64),
             hits=a64[1], limit=a64[2], duration=a64[3], eff_ms=a64[4],
@@ -272,7 +274,7 @@ def make_sharded_step_packed(mesh, donate: bool = False):
         return state, packed, (over, ins)
 
     sharded = shard_map(
-        _step, mesh=mesh,
+        xla_step_packed, mesh=mesh,
         in_specs=(P(S), P(None, S), P(None, S), P()),
         out_specs=(P(S), P(None, S), P()),
     )
@@ -333,6 +335,12 @@ class ShardedEngine:
         self.over_count = 0
         self.insert_count = 0
         self.sweep_count = 0
+        #: a wave answered a row table_full since the last sweep: its
+        #: probe window (or bucket) was full.  If expired rows clog it,
+        #: a sweep frees it — the instance runs one BETWEEN waves
+        #: (``_maybe_sweep``), never a wave for itself: a window full of
+        #: live keys would buy a pass over the table on every wave
+        self.sweep_wanted = False
         self.live_rows = -1  # set by the fused Pallas sweep
         self._gather = None  # lazily-built row programs
         self._upsert = None
@@ -716,6 +724,15 @@ class ShardedEngine:
         answered: the XLA step masks none."""
         return cols
 
+    def _count_table_full(self, full: np.ndarray) -> None:
+        """``gubernator_table_full_rows_total``: the rows one call of
+        ``check_packed`` / ``sync_packed`` answers table_full
+        (unservable), whatever made them so — counted where the column
+        is final, once a row."""
+        m = self.metrics_ref
+        if m is not None and (n := int(np.count_nonzero(full))):
+            m.table_full_rows.inc(n)
+
     def _count_leaky_rows(self, n: int) -> None:
         """``gubernator_wave_leaky_rows``: the LEAKY_BUCKET rows of one
         wave that go on to the device program."""
@@ -728,7 +745,7 @@ class ShardedEngine:
         the response columns (same contract as check_packed).  The
         token stays alive — ``drop_packed`` ends it.  Reading
         launched outputs needs no lock (state isn't touched); the
-        table-full RETRY path re-enters check_packed, which mutates
+        table-full RETRY path re-enters ``_check_rows``, which mutates
         state, so it runs under ``engine_lock`` when one is given.  A
         retried row applies after any wave launched meanwhile —
         acceptable: erred rows never mutated state, retries are the
@@ -758,7 +775,7 @@ class ShardedEngine:
                 # out-of-domain rows rode invalid (never erred, never
                 # cold): unservable, the shape a full probe window has
                 full[ood] = True
-        # the re-dispatches below run check_packed, phases and all
+        # the re-dispatches below run _check_rows, phases and all
         if err_idx:
             import contextlib
 
@@ -767,8 +784,8 @@ class ShardedEngine:
             msub = None if mslot is None else np.asarray(mslot)[ei]
             with (engine_lock if engine_lock is not None
                   else contextlib.nullcontext()):
-                r_st, r_lim, r_rem, r_rst, r_full = self.check_packed(
-                    sub, khash[ei], now_ms, mslot=msub)
+                r_st, r_lim, r_rem, r_rst, r_full = self._check_rows(
+                    sub, khash[ei], now_ms, msub)
             status[ei] = r_st
             lim_o[ei] = r_lim
             rem_o[ei] = r_rem
@@ -778,7 +795,7 @@ class ShardedEngine:
             import contextlib
 
             # cold-tier rows rode the waves invalid (see launch_packed):
-            # re-dispatch just them through check_packed, which serves
+            # re-dispatch just them through _check_rows, which serves
             # from whichever tier the key is in NOW — exact even when a
             # promotion landed between our launch and this sync
             ci = np.asarray(cold_idx)
@@ -787,13 +804,14 @@ class ShardedEngine:
             msub = None if mslot is None else np.asarray(mslot)[ci]
             with (engine_lock if engine_lock is not None
                   else contextlib.nullcontext()):
-                c_st, c_lim, c_rem, c_rst, c_full = self.check_packed(
-                    sub, khash[ci], now_ms, mslot=msub)
+                c_st, c_lim, c_rem, c_rst, c_full = self._check_rows(
+                    sub, khash[ci], now_ms, msub)
             status[ci] = c_st
             lim_o[ci] = c_lim
             rem_o[ci] = c_rem
             rst_o[ci] = c_rst
             full[ci] = c_full
+        self._count_table_full(full)
         return status, lim_o, rem_o, rst_o, full
 
     def drop_packed(self, token) -> None:
@@ -908,11 +926,20 @@ class ShardedEngine:
 
         Invalid rows (batch.valid False) come back zeroed; the caller
         owns their error strings.  Same wave routing, duplicate-order,
-        and sweep-retry semantics as check_batch.  ``mslot`` (ISSUE 8):
+        and retry semantics as check_batch.  ``mslot`` (ISSUE 8):
         per-request mesh-GLOBAL replica slot, -1 for sharded rows —
         only fused engines receive it (instance.py gates on
         ``engine.mesh_bound``).
         """
+        cols = self._check_rows(batch, khash, now_ms, mslot)
+        self._count_table_full(cols[4])
+        return cols
+
+    def _check_rows(self, batch: RequestBatch, khash: np.ndarray,
+                    now_ms: int, mslot) -> tuple:
+        """``check_packed`` without the count of its table_full rows:
+        what ``sync_packed`` re-dispatches its erred and cold rows
+        through, and counts with the rest of its wave."""
         wave, khash, mslot = self._wave_of(batch, khash, mslot)
         try:
             return self._check_wave(wave, khash, now_ms, mslot)
@@ -962,14 +989,17 @@ class ShardedEngine:
                     if werr.any():
                         err_idx.extend(idx[werr].tolist())
             if err_idx and not retried:
-                # probe windows clogged with expired rows: sweep once and
-                # retry those requests (check_batch does the same)
+                # a row that lost every claim round to the wave's other
+                # inserts finds its slot alone: launch those once more.
+                # A window that IS full is not helped, and no wave
+                # sweeps the table for it (see ``sweep_wanted``)
                 retried = True
-                self.sweep(now_ms)
                 pending = np.asarray(sorted(err_idx))
             elif err_idx and self._try_auto_grow([False]):
                 pending = np.asarray(sorted(err_idx))
             else:
+                if err_idx:
+                    self.sweep_wanted = True
                 full[err_idx] = True
                 for i in err_idx:
                     status[i] = 0
@@ -1175,49 +1205,117 @@ class ShardedEngine:
         device kernel), then uploads the table once.  Returns rows
         restored; rows that don't fit (capacity shrank) are dropped with
         a count, mirroring the reference's best-effort Loader.Load.
-        """
-        from ..core.step import PROBES
 
+        The table is the one a row-at-a-time walk in row order builds
+        (``tests/test_restore_place.py`` keeps that walk): a row takes
+        the first slot of its window that is free, holds its own key,
+        or is held by a LATER row — placed in numpy rounds over the
+        rows still moving, see ``_place_rows``.  A key that comes twice
+        keeps its first row's slot and its last row's values; rows of
+        key 0 (the empty slot's mark) are no rows and are skipped.
+        """
         host = {f: np.asarray(getattr(self.state, f)).copy()
                 for f in self.state._fields}
-        cap = self.cap_local
-        keys = arrays["key"].astype(np.uint64)
-        shard = shard_of(keys, self.n)
-        stride = (keys >> np.uint64(17)) | np.uint64(1)
-        placed = 0
-        unplaced: List[int] = []
-        for i in range(len(keys)):
-            base = int(shard[i]) * cap
-            k = keys[i]
-            for p in range(PROBES):
-                slot = base + int((k + np.uint64(p) * stride[i])
-                                  & np.uint64(cap - 1))
-                if host["key"][slot] == 0 or host["key"][slot] == k:
-                    for f in host:
-                        if f != "key":
-                            host[f][slot] = arrays[f][i]
-                    host["key"][slot] = k
-                    placed += 1
-                    break
-            else:
-                unplaced.append(i)
-        if unplaced and self.tier is not None:
+        keys = np.asarray(arrays["key"]).astype(np.uint64)
+        with phase("restore.place", self.metrics_ref):
+            slot_of = self._place_rows(host["key"], keys)
+            fit = slot_of >= 0
+            # in row order, so a key's last row writes last; every row
+            # fits as a rule, and then no column is copied to be masked
+            rows = slice(None) if fit.all() else fit
+            slots = slot_of[rows]
+            for f in host:
+                host[f][slots] = (keys if f == "key"
+                                  else np.asarray(arrays[f]))[rows]
+        placed = int(np.count_nonzero(fit))
+        lost = np.flatnonzero(~fit & (keys != 0))
+        if len(lost) and self.tier is not None:
             # tiered restore: rows the device table can't hold land in
             # the cold tier instead of being dropped — the snapshot
             # round-trip keeps every row in exactly one tier
-            placed += self.tier.adopt_rows(arrays, unplaced)
+            adopted = self.tier.adopt_rows(arrays, lost.tolist())
+            placed += adopted
+            lost = lost[adopted:]
+        if self.metrics_ref is not None:
+            self.metrics_ref.restore_unplaced_rows.set(len(lost))
         sh = table_sharding(self.mesh)
-        from ..core.table import TableState, init_table
+        from ..core.table import TableState
 
         self.state = TableState(**{
             f: jax.device_put(v, sh) for f, v in host.items()})
-        # device_put of an aligned host column is zero-copy on this
-        # image's XLA:CPU without pinning the numpy owner — once `host`
-        # dies the allocator reuses the table's backing memory and live
-        # rows turn into heap garbage (state lost across restart, and
-        # worse: ~1.6k phantom rows evicting real ones).  Pin the
-        # columns for the engine's lifetime; the donated step keeps
-        # writing the state into these same buffers, so the cost is one
-        # table copy (~cap×9×8 bytes), not a leak per wave.
-        self._restore_host_pin = host
+        if jax.default_backend() == "cpu":
+            # device_put of an aligned host column is zero-copy on this
+            # image's XLA:CPU without pinning the numpy owner — once
+            # `host` dies the allocator reuses the table's backing
+            # memory and live rows turn into heap garbage (state lost
+            # across restart, and worse: ~1.6k phantom rows evicting
+            # real ones).  Pin the columns for the engine's lifetime;
+            # the donated step keeps writing the state into these same
+            # buffers, so the cost is one table copy (~cap×9×8 bytes),
+            # not a leak per wave.  Other backends copy to the device.
+            self._restore_host_pin = host
         return placed
+
+    def _place_rows(self, table_key: np.ndarray, keys: np.ndarray
+                    ) -> np.ndarray:
+        """Slot of every row (-1: its window is full, or its key is 0)
+        over the host copy of the key column — first-free placement in
+        row order, without walking the rows.
+
+        Row i's slot is the first of its PROBES slots that no EARLIER
+        row's key takes (a slot the table already holds counts as
+        taken, unless it holds row i's own key).  That recursion has
+        one fixed point, and rounds reach it: every key still moving
+        claims the slot at its own depth; the earliest row wins a slot,
+        also from a later row that sat there, and whoever loses goes
+        one slot on.  A slot's holder only ever gets earlier, so a key
+        never passes a slot it would have kept.  A round is one numpy
+        pass over the keys still moving: at load 0.15 a tenth of them
+        go on each time."""
+        from ..core.step import PROBES
+
+        n = len(keys)
+        # one contender a distinct key, in the order of its first row
+        order = np.argsort(keys, kind="stable")
+        sk = keys[order]
+        head = np.ones(n, bool)
+        head[1:] = sk[1:] != sk[:-1]
+        first = order[head]  # first row of each distinct key
+        ids = np.sort(first[sk[head] != 0])
+        k = keys[ids]
+        base = (shard_of(k, self.n).astype(np.int64)
+                * np.int64(self.cap_local))
+        stride = (k >> np.uint64(17)) | np.uint64(1)
+        depth = np.zeros(len(ids), np.uint64)
+        where = np.full(len(ids), -1, np.int64)
+        #: the contender (index into ``ids``: earlier row, lower index)
+        #: that holds each slot; the table's own rows hold theirs
+        #: before any (-1), ``free`` after all
+        free = len(ids)
+        holder = np.where(table_key != 0, np.int64(-1), np.int64(free))
+        moving = np.arange(free)
+        while len(moving):
+            slot = base[moving] + (
+                (k[moving] + depth[moving] * stride[moving])
+                & np.uint64(self.cap_local - 1)).astype(np.int64)
+            found = table_key[slot] == k[moving]  # the table holds it
+            where[moving[found]] = slot[found]
+            claims = ~found & (holder[slot] > moving)
+            c_who, c_slot = moving[claims], slot[claims]
+            ousted = holder[c_slot]
+            # ascending, written backwards: a slot's earliest stays
+            holder[c_slot[::-1]] = c_who[::-1]
+            won = holder[c_slot] == c_who
+            where[c_who[won]] = c_slot[won]
+            ousted = np.unique(ousted[ousted < free])
+            where[ousted] = -1
+            moving = np.sort(np.concatenate(
+                [moving[where[moving] < 0], ousted]))
+            depth[moving] += np.uint64(1)
+            moving = moving[depth[moving] < PROBES]
+        # a row's slot is its key's: the slot of the key's first row
+        by_first = np.full(n, -1, np.int64)
+        by_first[ids] = where
+        out = np.empty(n, np.int64)
+        out[order] = by_first[first[np.cumsum(head) - 1]]
+        return out

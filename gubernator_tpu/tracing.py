@@ -204,6 +204,13 @@ PHASE_CATALOG: Dict[str, str] = {
     "broadcast": "GLOBAL owner tick: one broadcast pass",
     "snapshot": "Loader save blackout",
     "restore": "Loader load blackout",
+    "restore.place": "ShardedEngine.restore: the snapshot's rows placed "
+                     "into the host copy of the column table (numpy "
+                     "rounds), before the upload",
+    "sweep": "engine.sweep, whole: the expiry pass over the table, "
+             "between waves under the engine lock (_maybe_sweep: the "
+             "tick, or a table_full row's request); host wall time, "
+             "queue behind the waves in flight included",
     "global_fold": "mesh-GLOBAL reconcile tick (swap + fold launch)",
 }
 

@@ -241,11 +241,12 @@ def test_section_registry_covers_baseline_rows():
 
 def test_flagship_defaults_are_the_round5_shape():
     """The driver runs `python bench.py` with NO env: the defaults ARE
-    the flagship claim.  Round 5 moved it to CAP 2^26 / 8-probe (the
-    16-probe window triggers the serialized scatter lowering at CAP >=
-    2^25 on 2026-08 backend builds, while 2^26/8-probe is zero-loss for
-    the 10M-key populate — BASELINE.md round-5 table).  Import in a
-    child: bench's module-level env defaults must not leak here."""
+    the flagship claim: CAP 2^26 for 10M keys, and the probe window the
+    serving default (core/step.py › PROBES: 16 since PR 31 — 8 loses a
+    key in one 10M key set in four; round 5's worry that a 16-probe
+    window takes the serialized scatter lowering was priced on the
+    chip, PERF.md §6 PR 31).  Import in a child: bench's module-level
+    env defaults must not leak here."""
     code = (
         "import os, json\n"
         "import bench\n"
@@ -262,7 +263,7 @@ def test_flagship_defaults_are_the_round5_shape():
     assert got["cap"] == 1 << 26, got
     assert got["n_keys"] == 10_000_000, got
     # bench must NOT export a probe override anymore: the serving
-    # default (core/step.py PROBES == 8) is the flagship window
+    # default (core/step.py PROBES) is the flagship window
     assert got["probes_env"] == "", got
 
 

@@ -92,3 +92,15 @@ def test_instance_config_normalizes():
     cfg = d.instance_config()
     assert cfg.cache_size == 1 << 16  # rounded up to power of two
     assert cfg.behaviors is d.behaviors
+
+
+@pytest.mark.parametrize("ms", [30_000, 1_000, 0])
+def test_the_sweep_interval_reaches_the_instance(ms):
+    """DaemonConfig → Config: the time between whole-table expiry
+    sweeps, and with it how long an insert into a window clogged by
+    expired rows can go on failing (no wave sweeps for itself:
+    instance._maybe_sweep).  A benchmark configuration's ``rehearsal``
+    section sets it so that its 4-s window holds a sweep."""
+    assert DaemonConfig().sweep_interval_ms == 30_000
+    d = DaemonConfig(sweep_interval_ms=ms)
+    assert d.instance_config().sweep_interval_ms == ms

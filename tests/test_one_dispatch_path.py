@@ -7,7 +7,8 @@ so what these tests run under ``JAX_PLATFORMS=cpu`` is what the chip
 runs.
 
 With one path there is ONE table-full retry and ONE cold-tier serve per
-engine (``check_packed``'s, re-entered by ``sync_packed``): a row that
+engine (``check_packed``'s body ``_check_rows``, re-entered by
+``sync_packed``): a row that
 came in through the fused wire ingest gets exactly those.
 """
 import random
@@ -69,7 +70,7 @@ def spy_threads(eng, *entries):
 def small_instance(capacity: int, **config):
     eng = ShardedEngine(make_mesh(n=1), capacity_per_shard=capacity,
                         batch_per_shard=64)
-    seen = spy_threads(eng, "launch_packed", "check_packed")
+    seen = spy_threads(eng, "launch_packed", "_check_rows")
     inst = V1Instance(Config(cache_size=capacity, sweep_interval_ms=0,
                              **config), engine=eng)
     return inst, seen
@@ -107,7 +108,7 @@ def test_the_removed_option_is_ignored(monkeypatch, value):
     the program no longer reads it, and no longer lists it."""
     monkeypatch.setenv(REMOVED_OPTION, value)
     assert REMOVED_OPTION not in ENV_REGISTRY
-    assert len(ENV_REGISTRY) == 104
+    assert len(ENV_REGISTRY) == 103  # PR 31: GUBER_PROBES went
     eng = ShardedEngine(make_mesh(n=1), capacity_per_shard=1 << 10,
                         batch_per_shard=64)
     d = Dispatcher(eng)
@@ -147,27 +148,59 @@ def test_an_engine_without_launch_packed_is_served_serially():
 def test_a_table_full_row_on_the_fused_lane_gets_the_one_retry():
     """A 64-row table clogged with EXPIRED rows: new keys arriving
     through the fused wire ingest exhaust their probe windows in the
-    launched wave and are answered by ``sync_packed``'s re-entry into
-    ``check_packed`` (sweep, then retry) — on the worker, equal to the
-    oracle."""
-    inst, seen = small_instance(64)
+    launched wave and go through ``sync_packed``'s re-dispatch (the
+    engine's one retry, on the worker).  No wave sweeps the table for
+    them (ISSUE 31): those still without a slot are answered ``table
+    full`` and counted once each, the sweep they ask for runs AFTER the
+    wave in the caller's thread, and the same keys then all land —
+    every answer that is not an error equal to the oracle's."""
+    eng = ShardedEngine(make_mesh(n=1), capacity_per_shard=64,
+                        batch_per_shard=64)
+    seen = spy_threads(eng, "launch_packed", "_check_rows")
+    swept = []
+    eng.sweep = lambda now, _f=eng.sweep: (
+        swept.append(threading.current_thread().name), _f(now))[1]
+    inst = V1Instance(Config(cache_size=64, sweep_interval_ms=5_000),
+                      engine=eng)
+    inst._last_sweep = NOW + 10_000  # no tick before NOW + 15 s
     oracle = Oracle()
     try:
+        # 64 keys into 64 rows: a few find their window full of the
+        # others, LIVE — the sweep they ask for frees nothing
         old = [req(f"old{i}", duration=1_000) for i in range(64)]
         inst.get_rate_limits_wire(wire(old), now_ms=NOW)
-        oracle.check_batch(old, NOW)
-        del seen[:]
+        assert swept == [threading.current_thread().name]
+        del seen[:], swept[:]
         later = NOW + 10_000  # every resident row has expired
         new = [req(f"new{i}", hits=i % 3) for i in range(40)]
         before = fused_rows(inst)
+        full_before = inst.metrics.table_full_rows._value.get()
         got = answers(inst.get_rate_limits_wire(wire(new), now_ms=later))
         assert fused_rows(inst) - before == 40
-        assert got == oracle_answers(oracle, new, later)
+        full = [i for i, g in enumerate(got) if g[4]]
+        assert full and {got[i][4] for i in full} == {
+            "rate limit table full"}
+        served = [q for i, q in enumerate(new) if i not in full]
+        assert [g for g in got if not g[4]] == oracle_answers(
+            oracle, served, later)
+        assert (inst.metrics.table_full_rows._value.get() - full_before
+                == len(full))
         assert seen[0] == ("launch_packed", WORKER, 40)
         retried = seen[1:]
         assert retried and all(
-            e == "check_packed" and th == WORKER and 0 < n <= 40
+            e == "_check_rows" and th == WORKER and 0 < n <= 40
             for e, th, n in retried), seen
+        # the sweep they asked for: one, after the wave, not on the worker
+        assert swept == [threading.current_thread().name]
+        assert inst.metrics.sweeps.labels(
+            cause="table_full")._value.get() == 2
+        assert inst.metrics.sweeps.labels(cause="tick")._value.get() == 0
+        again = [new[i] for i in full]
+        got = answers(inst.get_rate_limits_wire(wire(again),
+                                                now_ms=later + 1))
+        assert got == oracle_answers(oracle, again, later + 1)
+        assert (inst.metrics.table_full_rows._value.get() - full_before
+                == len(full))
     finally:
         inst.close()
 
@@ -175,7 +208,7 @@ def test_a_table_full_row_on_the_fused_lane_gets_the_one_retry():
 def test_a_cold_tier_row_on_the_fused_lane_gets_the_one_cold_serve():
     """A 64-row table under 400 keys with the cold tier on: rows whose
     key lives in the host tier ride the launched wave invalid and are
-    served by ``check_packed`` at sync time — every answer of every
+    served by ``_check_rows`` at sync time — every answer of every
     call equal to the oracle's, none "table full"."""
     inst, seen = small_instance(64, tier_cold=True,
                                 tier_promote_threshold=4)
@@ -194,6 +227,6 @@ def test_a_cold_tier_row_on_the_fused_lane_gets_the_one_cold_serve():
         assert st["cold_served"] > 0 and st["cold_keys"] > 0, st
         assert {th for _, th, _ in seen} == {WORKER}
         assert sum(e == "launch_packed" for e, _, _ in seen) == 12
-        assert any(e == "check_packed" for e, _, _ in seen)
+        assert any(e == "_check_rows" for e, _, _ in seen)
     finally:
         inst.close()

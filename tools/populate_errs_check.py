@@ -28,8 +28,9 @@ OUT = "/tmp/populate_errs.json"
 
 
 def run_one(log2cap: int, probes: int, n_keys: int, B: int) -> dict:
-    """One candidate per child process: GUBER_PROBES is read at module
-    import, so probe-window variants can't share an interpreter."""
+    """One candidate per child process, each with a step of its own
+    jitted for the candidate's probe window (the table's own PROBES is
+    a constant of core/step.py)."""
     code = f"""
 import json, time
 import numpy as np
@@ -38,10 +39,12 @@ jax.config.update("jax_platforms", "cpu")
 import sys
 sys.path.insert(0, {_REPO!r})
 from bench import _keyhash, pad_chunk, _mk_batch
-from gubernator_tpu.core.step import decide_batch_donated, PROBES
+from functools import partial
+from gubernator_tpu.core.step import decide_batch_impl
 from gubernator_tpu.core.table import init_table
 
-assert PROBES == {probes}, f"probe env plumbing failed: {{PROBES}}"
+decide_batch_donated = jax.jit(
+    partial(decide_batch_impl, probes={probes}), donate_argnums=0)
 i64 = jnp.int64
 cap, n_keys, B = 1 << {log2cap}, {n_keys}, {B}
 st = init_table(cap)
@@ -57,7 +60,7 @@ for a in range(0, n_keys, B):
 print(json.dumps({{"errs": errs, "seconds": round(time.time() - t0, 1),
                    "load": round(n_keys / cap, 3)}}))
 """
-    env = dict(os.environ, GUBER_PROBES=str(probes), JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-c", code], env=env,
                        stdout=subprocess.PIPE, timeout=7200)
     line = r.stdout.decode().strip().splitlines()[-1]

@@ -76,7 +76,7 @@ ENV_REGISTRY: Dict[str, str] = {
     "GUBER_K8S_NAMESPACE": "k8s discovery: namespace to watch",
     "GUBER_K8S_POD_SELECTOR": "k8s discovery: pod label selector",
     "GUBER_K8S_SERVICE": "k8s discovery: service name whose endpoints are peers",
-    "GUBER_KSPLIT": "device step: probe K-split override (core/step.py)",
+    "GUBER_KSPLIT": "device step: probe K-split override (core/table.py)",
     "GUBER_LOG_LEVEL": "root log level",
     "GUBER_MEMBERLIST_KNOWN_HOSTS": "memberlist discovery: seed hosts",
     "GUBER_MEM_ADVISE_FLOOR": "memory ledger: per-consumer minimum rows in the advised split (default 64)",

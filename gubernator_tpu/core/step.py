@@ -39,7 +39,8 @@ from jax import lax
 
 from ..types import FRAC_SAFE, TD_BOUND, Algorithm, Behavior
 from .batch import RequestBatch
-from .table import TableState
+from .table import (TableState, Words, match_rows, put_rows, split64,
+                    take_rows)
 
 #: probe window of the column table, per lookup: a constant of the
 #: table, not an option (a window shorter than the depth a snapshot's
@@ -58,18 +59,6 @@ PROBES = 16
 #: not grow with the table's
 REPLICA_PROBES = 8
 INSERT_ROUNDS = 4  # slot-claim rounds per batch
-
-#: K-split scatter fallback (GUBER_KSPLIT=<log2 window>, default off):
-#: a TPU compiler that serializes the donated step's table scatters at
-#: large CAP can be worked around by performing every table-row scatter
-#: as CAP/2^K slice-local scatters — subtracting each window's base
-#: preserves BOTH scatter promises (an ascending+unique index vector
-#: stays ascending+unique; rows outside the window fall out of bounds
-#: and drop), so no masking is needed.  Opt-in: on backends WITHOUT
-#: the pathology it is pure overhead (measured 2x on XLA:CPU at CAP
-#: 2^22 — the per-window concatenate streams the table).  Not measured
-#: on the current stack.
-KSPLIT_LOG2 = int(__import__("os").environ.get("GUBER_KSPLIT", "0"))
 
 
 def _long_divmod(n, d) -> tuple[jax.Array, jax.Array]:
@@ -111,35 +100,6 @@ def divmod_nn(n, d) -> tuple[jax.Array, jax.Array]:
         n, d, tpu=_long_divmod,
         default=lambda n, d: (lax.div(n, d), lax.rem(n, d)))
 
-
-def _scatter_rows(col, idx, vals, *, sorted_idx: bool):
-    """Table-row scatter with the backend promises, K-split when
-    enabled (see KSPLIT_LOG2).  ``idx`` entries out of [0, len(col))
-    are drop sentinels; ``sorted_idx`` mirrors each call site's
-    indices_are_sorted claim (the insert claim vector is unique but
-    unsorted)."""
-    cap = col.shape[0]
-    if not KSPLIT_LOG2 or cap <= (1 << KSPLIT_LOG2):
-        return col.at[idx].set(vals, mode="drop", unique_indices=True,
-                               indices_are_sorted=sorted_idx)
-    S = 1 << KSPLIT_LOG2
-    # Out-of-window rows get DISTINCT >= S sentinels (dropped): a plain
-    # idx - base would send below-window rows NEGATIVE, and negative
-    # scatter indices WRAP (numpy semantics), corrupting the window's
-    # tail.  The remap keeps uniqueness but not global order, so the
-    # per-window scatters promise unique only — uniqueness is what
-    # unlocks the parallel lowering; sortedness is a secondary hint the
-    # split trades away.
-    arange_b = jnp.arange(idx.shape[0], dtype=idx.dtype)
-    parts = []
-    for k in range(cap // S):
-        base = k * S
-        loc = jnp.where((idx >= base) & (idx < base + S),
-                        idx - base, S + arange_b)
-        sl = lax.slice_in_dim(col, base, base + S)
-        parts.append(sl.at[loc].set(vals, mode="drop",
-                                    unique_indices=True))
-    return lax.concatenate(parts, 0)
 
 _RESET = int(Behavior.RESET_REMAINING)
 _DRAIN = int(Behavior.DRAIN_OVER_LIMIT)
@@ -223,26 +183,35 @@ class _Req(NamedTuple):
     now: jax.Array  # per-request arrival time (epoch ms)
 
 
-def _probe_slots(key: jax.Array, cap: int, probes: int = PROBES
+def _probe_slots(key: Words, cap: int, probes: int = PROBES
                  ) -> jax.Array:
-    """[B, probes] int32 probe sequence (double hashing, odd stride)."""
-    stride = (key >> jnp.uint64(17)) | jnp.uint64(1)
-    p = jnp.arange(probes, dtype=jnp.uint64)
-    slots = (key[:, None] + p[None, :] * stride[:, None]) & jnp.uint64(cap - 1)
+    """[B, probes] int32 probe sequence (double hashing, odd stride):
+    ``(key + p * ((key >> 17) | 1)) & (cap - 1)``.  cap <= 2^31, so only
+    the sum's low word counts, and it is computed on the key's words in
+    32-bit arithmetic (which wraps as the 64-bit sum's low word does) —
+    the host's uint64 formula (sharded.py › _place_rows) gives the same
+    slots."""
+    u32 = jnp.uint32
+    stride = (key.lo >> u32(17)) | (key.hi << u32(15)) | u32(1)
+    p = jnp.arange(probes, dtype=u32)
+    slots = (key.lo[:, None] + p[None, :] * stride[:, None]) & u32(cap - 1)
     return slots.astype(jnp.int32)
 
 
-def _lookup(tkey: jax.Array, slots: jax.Array, key: jax.Array):
-    """(row int32[B] or -1, keys_at [B,P]) — first probe slot holding key."""
-    keys_at = tkey[slots]
-    match = keys_at == key[:, None]
-    found = match.any(axis=1)
-    fp = jnp.argmax(match, axis=1)
-    row = jnp.take_along_axis(slots, fp[:, None], axis=1)[:, 0]
-    return jnp.where(found, row, -1), keys_at
+def _first_hit(hit: jax.Array, slots: jax.Array):
+    """(any hit bool[B], the slot of the first one int32[B])."""
+    fp = jnp.argmax(hit, axis=1)
+    return hit.any(axis=1), jnp.take_along_axis(slots, fp[:, None],
+                                                axis=1)[:, 0]
 
 
-def _insert(tkey: jax.Array, slots: jax.Array, key: jax.Array,
+def _lookup(tkey: Words, slots: jax.Array, key: Words) -> jax.Array:
+    """row int32[B] or -1 — first probe slot holding key."""
+    found, row = _first_hit(match_rows(tkey, slots, key)[0], slots)
+    return jnp.where(found, row, -1)
+
+
+def _insert(tkey: Words, slots: jax.Array, key: Words,
             valid: jax.Array, row: jax.Array):
     """Claim first-empty probe slots for missing keys, deterministically.
 
@@ -252,23 +221,17 @@ def _insert(tkey: jax.Array, slots: jax.Array, key: jax.Array,
     The analog of lrucache.go › Add, without locks: one batch is one
     program, so claim conflicts are resolved by sort order, not mutexes.
     """
-    cap = tkey.shape[0]
-    B = key.shape[0]
+    cap = tkey.lo.shape[0]
+    B = key.lo.shape[0]
     n_claimed = jnp.asarray(0, jnp.int64)
 
     for _ in range(INSERT_ROUNDS):
-        keys_at = tkey[slots]
-        match = keys_at == key[:, None]
-        found = match.any(axis=1)
-        fp = jnp.argmax(match, axis=1)
-        frow = jnp.take_along_axis(slots, fp[:, None], axis=1)[:, 0]
+        match, empty = match_rows(tkey, slots, key)
+        found, frow = _first_hit(match, slots)
         row = jnp.where((row < 0) & valid & found, frow, row)
 
         active = valid & (row < 0)
-        empty = keys_at == 0
-        has_empty = empty.any(axis=1)
-        ep = jnp.argmax(empty, axis=1)
-        cand = jnp.take_along_axis(slots, ep[:, None], axis=1)[:, 0]
+        has_empty, cand = _first_hit(empty, slots)
         cand_eff = jnp.where(active & has_empty, cand, cap)
         order = jnp.argsort(cand_eff, stable=True)
         c_s = cand_eff[order]
@@ -278,24 +241,19 @@ def _insert(tkey: jax.Array, slots: jax.Array, key: jax.Array,
         # both scatters can promise uniqueness (losers get DISTINCT
         # out-of-bounds sentinels, dropped by mode="drop") — without the
         # promise the TPU backend must assume colliding writes and can
-        # emit a serialized scatter loop (observed 2026-08-01: 217 ms
-        # per step at CAP >= 2^22 vs 0.118 ms at 2^21)
+        # emit a serialized scatter loop
         winner = jnp.zeros(B, bool).at[order].set(first,
                                                   unique_indices=True)
         claim = jnp.where(winner, cand,
                           cap + jnp.arange(B, dtype=cand.dtype))
         if _CHECK_SCATTER_INVARIANTS:  # traced-ok: test-only scatter-invariant hook, off in production
             jax.debug.callback(_record_unique, "insert_tkey", claim)
-        tkey = _scatter_rows(tkey, claim, key, sorted_idx=False)
+        tkey = put_rows(tkey, claim, key)
         row = jnp.where(winner, cand, row)
         n_claimed = n_claimed + winner.sum(dtype=jnp.int64)
 
     # final resolve for same-key losers of the last round
-    keys_at = tkey[slots]
-    match = keys_at == key[:, None]
-    found = match.any(axis=1)
-    fp = jnp.argmax(match, axis=1)
-    frow = jnp.take_along_axis(slots, fp[:, None], axis=1)[:, 0]
+    found, frow = _first_hit(match_rows(tkey, slots, key)[0], slots)
     row = jnp.where((row < 0) & valid & found, frow, row)
     return tkey, row, n_claimed
 
@@ -428,14 +386,14 @@ def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array,
     ``decide_batch`` for direct host dispatch.  ``probes`` is the probe
     window of ``state`` (the replica maps pass ``REPLICA_PROBES``).
     """
-    cap = state.key.shape[0]
+    cap = state.capacity
     B = batch.key.shape[0]
     i32 = jnp.int32
     i64 = jnp.int64
     now = jnp.asarray(now_ms, i64)
 
-    key = batch.key
-    valid = batch.valid & (key != 0)
+    valid = batch.valid & (batch.key != 0)
+    key = split64(batch.key)
     # per-request arrival time; 0 entries (padding / legacy callers
     # without the column) fall back to the scalar argument
     if batch.now is None:
@@ -447,7 +405,7 @@ def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array,
     # ---- probe / insert -------------------------------------------------
     slots = _probe_slots(key, cap, probes)
     tkey = state.key
-    row, _ = _lookup(tkey, slots, key)
+    row = _lookup(tkey, slots, key)
     row = jnp.where(valid & (row >= 0), row, -1)
     miss = valid & (row < 0)
 
@@ -499,13 +457,21 @@ def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array,
         now=now_col[perm],
     )
 
-    def uni(x):
-        return seg_max(x) == seg(x)
+    # A segment is contiguous in the sorted order, so a field is
+    # uniform over it iff no position but its head differs from the one
+    # before: ONE 32-bit segment reduction for all the fields, where a
+    # segment min AND max per int64 field are scatters the chip runs
+    # serially (0.57 ms each at B = 8,192 on a v5e: PERF.md §6, PR 32).
+    def breaks(*fields):
+        differs = jnp.zeros(B - 1, bool)
+        for x in fields:
+            differs = differs | (x[1:] != x[:-1])
+        broken = (~head) & jnp.concatenate([jnp.zeros(1, bool), differs])
+        return seg_max(broken.astype(i32)) > 0
 
-    uniform_cfg = (uni(sf.hits) & uni(sf.limit) & uni(sf.duration)
-                   & uni(sf.eff) & uni(sf.behavior) & uni(sf.alg)
-                   & uni(sf.burst))
-    uni_now = uni(sf.now)
+    uniform_cfg = ~breaks(sf.hits, sf.limit, sf.duration, sf.eff,
+                          sf.behavior, sf.alg, sf.burst)
+    uni_now = ~breaks(sf.now)
     any_flag = seg_max((sf.behavior & (_RESET | _DRAIN))) > 0
     # (simple/complex masks are finalized after the head apply: token
     # segments with mixed arrival times can still take the closed form
@@ -513,7 +479,7 @@ def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array,
 
     # ---- gather item state per segment ---------------------------------
     def grow(col, fill=0):
-        return col.at[seg_row].get(mode="fill", fill_value=fill)
+        return take_rows(col, seg_row, fill)
 
     item0 = _Item(
         alg=(grow(state.meta) & 1).astype(i32),
@@ -544,7 +510,11 @@ def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array,
     # keeps dispatcher-coalesced concurrent callers — distinct clocks,
     # shared hot keys — on the vectorized path instead of a while_loop
     # as long as the longest such segment (the serving common case).
-    time_safe = uni_now | ((~is_leaky0) & (seg_max(sf.now) < item1.exp))
+    # (both sorts leave a segment in arrival order: its last position
+    # holds its latest arrival)
+    last = jnp.where(exists, seg_start + seg_len - 1, B).astype(i32)
+    latest = sf.now.at[last].get(mode="fill", fill_value=0)
+    time_safe = uni_now | ((~is_leaky0) & (latest < item1.exp))
     uniform = uniform_cfg & time_safe
     simple = exists & uniform & (~any_flag)
     complex_seg = exists & (seg_len > 1) & (~simple)
@@ -740,8 +710,7 @@ def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array,
     # segments get DISTINCT out-of-bounds sentinels (dropped by
     # mode="drop") so the unique_indices promise below is honest: it
     # lets the TPU backend vectorize the scatters instead of assuming
-    # colliding writes (the CAP>=2^22 217 ms/step serialization,
-    # 2026-08-01).  The vector is also globally ASCENDING — both sort
+    # colliding writes.  The vector is also globally ASCENDING — both sort
     # paths end with a stable argsort by row, so seg_row rises across
     # live segment ids (err/invalid rows are remapped to cap and sort
     # LAST into a non-exists segment), and the cap+i sentinels occupy
@@ -753,7 +722,7 @@ def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array,
         jax.debug.callback(_record_wrow, wrow)
     meta_new = (item_final.alg & 1) | ((item_final.status & 1) << 1)
 
-    # Hot/cold column split (PERF.md §4.1, VERDICT r1 item 2): the four
+    # Hot/cold column split: the four
     # hot columns (meta, remaining, t_ms, expire_at) change on ~every
     # step; the cold config columns (limit, duration, eff_ms, burst —
     # and key, via the insert cond above) change only on insert or
@@ -767,16 +736,15 @@ def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array,
         | (item_final.eff != item0.eff)
         | (item_final.burst != item0.burst))).any()
 
+    def put(col, vals):
+        return put_rows(col, wrow, vals, sorted_idx=True)
+
     def _cold_scatter(cols):
         limit_c, duration_c, eff_c, burst_c = cols
-        return (_scatter_rows(limit_c, wrow, item_final.limit,
-                              sorted_idx=True),
-                _scatter_rows(duration_c, wrow, item_final.duration,
-                              sorted_idx=True),
-                _scatter_rows(eff_c, wrow, item_final.eff,
-                              sorted_idx=True),
-                _scatter_rows(burst_c, wrow, item_final.burst,
-                              sorted_idx=True))
+        return (put(limit_c, item_final.limit),
+                put(duration_c, item_final.duration),
+                put(eff_c, item_final.eff),
+                put(burst_c, item_final.burst))
 
     limit_n, duration_n, eff_n, burst_n = lax.cond(
         cold_dirty, _cold_scatter, lambda cols: cols,
@@ -784,18 +752,14 @@ def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array,
 
     new_state = TableState(
         key=tkey,
-        meta=_scatter_rows(state.meta, wrow, meta_new.astype(i32),
-                           sorted_idx=True),
+        meta=put(state.meta, meta_new.astype(i32)),
         limit=limit_n,
         duration=duration_n,
         eff_ms=eff_n,
         burst=burst_n,
-        remaining=_scatter_rows(state.remaining, wrow, item_final.rem,
-                                sorted_idx=True),
-        t_ms=_scatter_rows(state.t_ms, wrow, item_final.t,
-                           sorted_idx=True),
-        expire_at=_scatter_rows(state.expire_at, wrow, item_final.exp,
-                                sorted_idx=True),
+        remaining=put(state.remaining, item_final.rem),
+        t_ms=put(state.t_ms, item_final.t),
+        expire_at=put(state.expire_at, item_final.exp),
     )
 
     # ---- back to request order -----------------------------------------
@@ -814,25 +778,18 @@ def decide_batch_impl(state: TableState, batch: RequestBatch, now_ms: jax.Array,
     )
 
 
-#: Host-dispatch entry point WITHOUT buffer donation — test/debug use.
-#:
-#: Serving uses the donated variant below (and has since the v5e
-#: measurement of 2026-07-31, PERF.md §5.1): on that lowering the
-#: NON-donated row scatters serialize at ~3 µs/row — 209 ms/batch at
-#: B=65536, 365× slower than donated — and donation also wins 6.3× on
-#: CPU.  Copy mode survives only for callers that cannot thread state
-#: linearly (tests asserting on both old and new tables, lowerings
-#: without aliasing support).
+#: Host-dispatch entry point WITHOUT buffer donation — test/debug use:
+#: callers that cannot thread state linearly (tests asserting on both
+#: old and new tables, lowerings without aliasing support).  Serving
+#: uses the donated variant below.
 decide_batch = jax.jit(decide_batch_impl)
 
 #: Donated variant: the table aliases in/out, so the cond-gated cold
 #: columns (limit/duration/eff/burst; key when no insert) pass through
-#: with ZERO copies on clean steps, and — lowering permitting — the hot
-#: scatters update in place, making per-step HBM traffic ~B-sized
-#: instead of CAP-sized (the VERDICT r1 "streaming wall" fix).  Inside
+#: with ZERO copies on clean steps and the hot scatters update in
+#: place, making per-step HBM traffic ~B-sized instead of CAP-sized
+#: (what the step costs on the chip: PERF.md §5, cell 6).  Inside
 #: lax.scan the loop-carried state gets the same in-place treatment
-#: automatically, which is how the round-0 551 M/s on-chip rate was
-#: reached.  Callers MUST thread state linearly: the old state dies at
-#: the call.  bench.py measures both entry points and records which one
-#: wins on the current backend.
+#: automatically.  Callers MUST thread state linearly: the old state
+#: dies at the call.
 decide_batch_donated = jax.jit(decide_batch_impl, donate_argnums=0)

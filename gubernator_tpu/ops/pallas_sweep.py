@@ -7,11 +7,11 @@ case — a pure dense streaming pass over the table — which is exactly
 the memory-bound shape Pallas is for, and fusing the occupancy count
 into the same pass halves its HBM traffic vs. sweep-then-count.
 
-TPU Mosaic has no 64-bit vector lanes, so the int64/uint64 columns are
-bit-split into (hi, lo) int32 pairs on the way in and recombined on the
-way out; the expiry comparison is done on the split words (signed hi,
-unsigned lo).  Set ``interpret=True`` (or run on CPU) for the
-reference-interpreter path used by tests.
+TPU Mosaic has no 64-bit vector lanes, and the table has no 64-bit
+columns (core/table.py › Words): the kernel takes the key's and
+expire_at's word columns as they are and the expiry comparison is done
+on the words (signed hi, unsigned lo).  Set ``interpret=True`` (or run
+on CPU) for the reference-interpreter path used by tests.
 
 Usage: ``sweep_expired_pallas(state, now_ms)`` — a drop-in equivalent
 of core/table.py › sweep_expired that also returns the live-row count.
@@ -22,27 +22,14 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..core.table import TableState
+from ..core.table import TableState, Words, split64
 
 LANES = 128
-BLK = 8  # sublanes per block → (8, 128) int32 tiles
-
-
-def _split64(x: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """int64/uint64 [n] → (hi int32, lo int32) bit halves."""
-    u = x.astype(jnp.uint64)
-    hi = (u >> jnp.uint64(32)).astype(jnp.uint32).astype(jnp.int32)
-    lo = u.astype(jnp.uint32).astype(jnp.int32)
-    return hi, lo
-
-
-def _join64(hi: jax.Array, lo: jax.Array, dtype) -> jax.Array:
-    u = (hi.astype(jnp.uint32).astype(jnp.uint64) << jnp.uint64(32)) | \
-        lo.astype(jnp.uint32).astype(jnp.uint64)
-    return u.astype(dtype)
+BLK = 8  # sublanes per block → (8, 128) 32-bit tiles
 
 
 def _sweep_kernel(now_ref, khi_ref, klo_ref, ehi_ref, elo_ref,
@@ -57,23 +44,27 @@ def _sweep_kernel(now_ref, khi_ref, klo_ref, ehi_ref, elo_ref,
         live_ref[0] = jnp.int32(0)
 
     now_hi, now_lo = now_ref[0], now_ref[1]
-    ehi, elo = ehi_ref[:], elo_ref[:]
-    # expire_at <= now on split words: signed hi compare, unsigned lo.
-    # (lo words are reinterpreted-int32; flipping the sign bit makes
-    # int32 compare order match the unsigned order.)
+    ehi_w, elo_w = ehi_ref[:], elo_ref[:]
+    # expire_at <= now on the words: signed hi compare, unsigned lo.
+    # The uint32 words are reinterpreted in registers (a bitcast in the
+    # wrapper would be a pass over the column of its own); flipping the
+    # low words' sign bit makes int32 compare order match the unsigned
+    # order.
+    ehi = lax.bitcast_convert_type(ehi_w, jnp.int32)
+    elo = lax.bitcast_convert_type(elo_w, jnp.int32)
     flip = jnp.int32(-2147483648)
     expired = (ehi < now_hi) | ((ehi == now_hi) &
                                 (elo ^ flip <= now_lo ^ flip))
     khi, klo = khi_ref[:], klo_ref[:]
-    empty = (khi == 0) & (klo == 0)
     zero = jnp.zeros_like(khi)
+    empty = (khi == zero) & (klo == zero)
     # zero exactly what sweep_expired zeroes (expired rows only — an
     # empty row's stale expire_at is never read, and bit-equality with
     # the XLA sweep is what the parity tests assert)
     khi_out[:] = jnp.where(expired, zero, khi)
     klo_out[:] = jnp.where(expired, zero, klo)
-    ehi_out[:] = jnp.where(expired, zero, ehi)
-    elo_out[:] = jnp.where(expired, zero, elo)
+    ehi_out[:] = jnp.where(expired, zero, ehi_w)
+    elo_out[:] = jnp.where(expired, zero, elo_w)
     # count in float32: with x64 enabled, jnp.sum on int32 routes through
     # an int64 accumulator (numpy promotion) even when dtype=int32 is
     # passed, and Mosaic cannot lower 64-bit; f32 is promotion-stable and
@@ -87,7 +78,7 @@ def _sweep_2d(khi, klo, ehi, elo, now_hi_lo, *, interpret: bool):
     grid = (rows // BLK,)
     tile = pl.BlockSpec((BLK, LANES), lambda i: (i, 0),
                         memory_space=pltpu.VMEM)
-    out_shape = jax.ShapeDtypeStruct((rows, LANES), jnp.int32)
+    out_shape = jax.ShapeDtypeStruct((rows, LANES), jnp.uint32)
     # x64 off while tracing the kernel: every operand is already int32,
     # but under x64 the BlockSpec index_map's literals trace as i64
     # scalars and Mosaic fails to legalize the index function's return
@@ -117,21 +108,20 @@ def sweep_expired_pallas(state: TableState, now_ms, *,
     get key=0 AND expire_at=0 so later occupants are unconditionally
     fresh), plus the live count from the same pass.
     """
-    cap = state.key.shape[0]
+    cap = state.capacity
     if cap % (BLK * LANES):
         raise ValueError(f"capacity {cap} not a multiple of {BLK * LANES}")
-    shape2d = (cap // LANES, LANES)
 
-    khi, klo = _split64(state.key)
-    ehi, elo = _split64(state.expire_at)
-    nhi, nlo = _split64(jnp.asarray(now_ms, jnp.int64)[None])
-    now_hi_lo = jnp.concatenate([nhi, nlo])
+    def tiles(x):  # a word column as the kernel's [rows, LANES]
+        return x.reshape(cap // LANES, LANES)
 
-    khi2, klo2, ehi2, elo2, live = _sweep_2d(
-        khi.reshape(shape2d), klo.reshape(shape2d),
-        ehi.reshape(shape2d), elo.reshape(shape2d),
+    now = split64(jnp.asarray(now_ms, jnp.int64))
+    now_hi_lo = lax.bitcast_convert_type(jnp.stack([now.hi, now.lo]),
+                                         jnp.int32)
+    key, exp = state.key, state.expire_at
+    khi, klo, ehi, elo, live = _sweep_2d(
+        tiles(key.hi), tiles(key.lo), tiles(exp.hi), tiles(exp.lo),
         now_hi_lo, interpret=interpret)
-
-    new_key = _join64(khi2.reshape(-1), klo2.reshape(-1), jnp.uint64)
-    new_exp = _join64(ehi2.reshape(-1), elo2.reshape(-1), jnp.int64)
-    return state._replace(key=new_key, expire_at=new_exp), live[0]
+    return state._replace(
+        key=Words(lo=klo.reshape(-1), hi=khi.reshape(-1)),
+        expire_at=Words(lo=elo.reshape(-1), hi=ehi.reshape(-1))), live[0]

@@ -65,7 +65,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..core.batch import (RequestBatch, clamp_config,
                           empty_batch, pack_requests)
 from ..core.step import REPLICA_PROBES, decide_batch_impl, divmod_nn
-from ..core.table import TableState, init_table
+from ..core.table import (TableState, from_host, init_table, join64,
+                          split64, to_host)
 from ..types import EFF_MAX, RateLimitRequest, RateLimitResponse, Status
 from .mesh import SHARD_AXIS
 
@@ -118,13 +119,15 @@ def make_hot_sync(mesh):
     def _sync(state, base_rem, base_t):
         st = jax.tree.map(lambda x: x[0], state)
         brem, bt = base_rem[0], base_t[0]
-        limit = st.limit
+        # a replica map is smaller than a wave: its rows as int64
+        limit, t_ms, rem = (join64(st.limit), join64(st.t_ms),
+                            join64(st.remaining))
         is_leaky = (st.meta & 1) == 1
         # --- token: refresh detection + consumption vs (refreshed) base
-        refreshed = (~is_leaky) & (st.t_ms != bt)
+        refreshed = (~is_leaky) & (t_ms != bt)
         any_refresh = lax.pmax(refreshed.astype(jnp.int32), S) > 0
         start = jnp.where(refreshed, limit, brem)
-        d_tok = jnp.maximum(start - st.remaining, 0)
+        d_tok = jnp.maximum(start - rem, 0)
         # --- leaky: consumption vs base replenished to the replica's t.
         # elapsed is clamped so elapsed × limit cannot wrap int64: leaky
         # burst ≤ TD_BOUND // eff per the packer clamps, so cap_td ≤ 2^61
@@ -132,24 +135,25 @@ def make_hot_sync(mesh):
         # to 1 on token rows (stored token eff can reach DURATION_MAX =
         # 2^53; an unmasked product would wrap even though d_leaky is
         # discarded by the is_leaky select).
-        eff = jnp.maximum(jnp.where(is_leaky, st.eff_ms, 1), 1)
-        cap_td = st.burst * eff
+        eff = jnp.maximum(jnp.where(is_leaky, join64(st.eff_ms), 1), 1)
+        cap_td = join64(st.burst) * eff
         el_max = divmod_nn(cap_td, jnp.maximum(limit, 1))[0] + 1
 
         def rep_at(t):
             el = jnp.clip(t - bt, 0, el_max)
             return jnp.minimum(brem + el * limit, cap_td)
 
-        d_leaky = jnp.maximum(rep_at(st.t_ms) - st.remaining, 0)
+        d_leaky = jnp.maximum(rep_at(t_ms) - rem, 0)
         d = jnp.where(is_leaky, d_leaky, d_tok)
         total = lax.psum(d, S)
-        new_t = lax.pmax(st.t_ms, S)
+        new_t = lax.pmax(t_ms, S)
         merged_base = jnp.where(any_refresh, limit, brem)
         new_rem_tok = jnp.clip(merged_base - total, 0, limit)
         new_rem_leaky = jnp.clip(rep_at(new_t) - total, 0, cap_td)
         new_rem = jnp.where(is_leaky, new_rem_leaky, new_rem_tok)
-        new_exp = lax.pmax(st.expire_at, S)
-        st = st._replace(remaining=new_rem, t_ms=new_t, expire_at=new_exp)
+        new_exp = lax.pmax(join64(st.expire_at), S)
+        st = st._replace(remaining=split64(new_rem), t_ms=split64(new_t),
+                         expire_at=split64(new_exp))
         out_state = jax.tree.map(lambda x: x[None], st)
         return out_state, new_rem[None], new_t[None]
 
@@ -273,12 +277,10 @@ class HotSetEngine:
                 host[f] = host[f].dtype.type(seed[f])
         # one tiny device_put per column: pin is rare (promotion only)
         with self._state_mu:
-            new_cols = {}
-            for f in TableState._fields:
-                col = np.asarray(getattr(self.state, f)).copy()
+            cols = to_host(self.state)
+            for f, col in cols.items():
                 col[:, slot] = host[f]
-                new_cols[f] = jax.device_put(col, _rep(self.mesh))
-            self.state = TableState(**new_cols)
+            self.state = jax.device_put(from_host(cols), _rep(self.mesh))
             br = np.asarray(self.base_rem).copy()
             br[:, slot] = host["remaining"]
             self.base_rem = jax.device_put(br, _rep(self.mesh))
@@ -301,8 +303,8 @@ class HotSetEngine:
         if slot is None:
             return None
         with self._state_mu:
-            return {f: np.asarray(getattr(self.state, f))[0, slot]
-                    for f in TableState._fields if f != "key"}
+            return {f: col[0, slot]
+                    for f, col in to_host(self.state).items() if f != "key"}
 
     def unpin(self, key_hash: int) -> None:
         """Stop hot-routing a key.  The slot stays reserved and the

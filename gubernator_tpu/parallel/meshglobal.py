@@ -57,7 +57,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..core.batch import RequestBatch, clamp_config, empty_batch, pack_requests
 from ..core.step import (REPLICA_PROBES, _lookup, _probe_slots,
                          decide_batch_impl)
-from ..core.table import TableState, init_table
+from ..core.table import (TableState, from_host, init_table, is_empty,
+                          split64, to_host)
 from ..hashing import shard_of
 from ..tracing import phase
 from ..types import EFF_MAX, RateLimitRequest, RateLimitResponse, Status
@@ -100,8 +101,8 @@ def make_mesh_global_step(mesh, cap: int):
         # each applied request's hits land on its row's accumulator
         # slot.  Erred rows (probe window exhausted) never mutated
         # state, so they don't accumulate either.
-        slots = _probe_slots(bt.key, cap, REPLICA_PROBES)
-        row, _ = _lookup(st.key, slots, bt.key)
+        kw = split64(bt.key)
+        row = _lookup(st.key, _probe_slots(kw, cap, REPLICA_PROBES), kw)
         ok = bt.valid & (row >= 0) & (~out.err)
         wrow = jnp.where(ok, row, cap)
         a = a.at[wrow].add(jnp.where(ok, jnp.maximum(bt.hits, 0), 0),
@@ -132,15 +133,19 @@ def make_mesh_global_fold(mesh):
         my = lax.axis_index(S)
         # home shard from the key column itself (hashing.shard_of):
         # ((h >> 32) * n) >> 32 — the exact host formula, on device
-        home = (((st.key >> jnp.uint64(32)) * jnp.uint64(n))
+        home = ((st.key.hi.astype(jnp.uint64) * jnp.uint64(n))
                 >> jnp.uint64(32)).astype(jnp.int32)
-        mine = (home == my) & (st.key != 0)
-        new = {"key": st.key}  # identical on every replica by pinning
-        for f in _VALUE_COLS:
-            col = getattr(st, f)
-            new[f] = lax.psum(jnp.where(mine, col, jnp.zeros_like(col)), S)
+        mine = (home == my) & ~is_empty(st.key)
+
+        def adopt(col):
+            # word by word: exactly one replica is a slot's home, so
+            # the sum IS the home's word
+            return lax.psum(jnp.where(mine, col, jnp.zeros_like(col)), S)
+
+        # keys never fold: identical on every replica by pinning
+        folded = st._replace(**{f: jax.tree.map(adopt, getattr(st, f))
+                                for f in _VALUE_COLS})
         slot_tot = lax.psum(a, S)
-        folded = TableState(**new)
         return (jax.tree.map(lambda x: x[None], folded),
                 jnp.zeros_like(a)[None], slot_tot)
 
@@ -269,13 +274,11 @@ class MeshGlobalEngine:
         if not placed:
             return ok
         with self._state_mu:
-            new_cols = {}
-            for f in TableState._fields:
-                col = np.asarray(getattr(self.state, f)).copy()
-                for slot, host in placed:
+            cols = to_host(self.state)
+            for slot, host in placed:
+                for f, col in cols.items():
                     col[:, slot] = host[f]
-                new_cols[f] = jax.device_put(col, _rep(self.mesh))
-            self.state = TableState(**new_cols)
+            self.state = jax.device_put(from_host(cols), _rep(self.mesh))
         return ok
 
     def pin(self, req: RateLimitRequest, key_hash: int, now_ms: int,
@@ -325,8 +328,8 @@ class MeshGlobalEngine:
             return None
         home = int(shard_of(int(key_hash), self.n))
         with self._state_mu:
-            return {f: np.asarray(getattr(self.state, f))[home, slot]
-                    for f in TableState._fields if f != "key"}
+            return {f: col[home, slot]
+                    for f, col in to_host(self.state).items() if f != "key"}
 
     # ---- request path ---------------------------------------------------
 

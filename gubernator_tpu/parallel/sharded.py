@@ -27,7 +27,9 @@ from ..core.batch import (PACK32, PACK64, RequestBatch, Rows,  # noqa: F401
                           WaveBufferPool, clock_order, empty_batch,
                           join_calls, pack_requests, stack_rows)
 from ..core.step import decide_batch_impl, _insert, _lookup, _probe_slots
-from ..core.table import TableState, init_table
+from ..core.table import (COLUMN_DTYPES, TableState, column_to_host,
+                          from_host, init_table, is_empty, put_rows,
+                          split64, take_rows)
 from ..tracing import phase
 from .mesh import (SHARD_AXIS, XLA_EXEC_MU, exec_gate, make_mesh,
                    shard_table, table_sharding)
@@ -81,13 +83,12 @@ def make_gather_rows(mesh):
     broadcasts (global.go › runBroadcasts collecting changed items)."""
 
     def _gather(state, keys):
-        slots = _probe_slots(keys, state.key.shape[0])
-        row, _ = _lookup(state.key, slots, keys)
+        kw = split64(keys)
+        row = _lookup(state.key, _probe_slots(kw, state.capacity), kw)
         found = (keys != 0) & (row >= 0)
-        cols = tuple(
-            getattr(state, f).at[jnp.where(found, row, 0)].get()
-            for f in VALUE_COLS)
-        return found, cols
+        at = jnp.where(found, row, 0)
+        return found, tuple(take_rows(getattr(state, f), at)
+                            for f in VALUE_COLS)
 
     return jax.jit(shard_map(
         _gather, mesh=mesh, in_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
@@ -100,14 +101,14 @@ def make_remove_rows(mesh):
     used by the Store-backed admin path."""
 
     def _remove(state, keys):
-        slots = _probe_slots(keys, state.key.shape[0])
-        row, _ = _lookup(state.key, slots, keys)
+        kw = split64(keys)
+        row = _lookup(state.key, _probe_slots(kw, state.capacity), kw)
         found = (keys != 0) & (row >= 0)
-        wrow = jnp.where(found, row, state.key.shape[0])
+        wrow = jnp.where(found, row, state.capacity)
+        zero = jnp.zeros(keys.shape, jnp.int64)
         return state._replace(
-            key=state.key.at[wrow].set(jnp.uint64(0), mode="drop"),
-            expire_at=state.expire_at.at[wrow].set(jnp.int64(0),
-                                                   mode="drop"),
+            key=put_rows(state.key, wrow, zero, unique=False),
+            expire_at=put_rows(state.expire_at, wrow, zero, unique=False),
         ), found
 
     return jax.jit(shard_map(
@@ -122,16 +123,16 @@ def make_upsert_rows(mesh):
     Returns (new_state, placed mask)."""
 
     def _upsert(state, keys, cols):
-        cap = state.key.shape[0]
+        cap = state.capacity
         valid = keys != 0
-        slots = _probe_slots(keys, cap)
-        tkey, row, _ = _insert(state.key, slots, keys, valid,
+        kw = split64(keys)
+        tkey, row, _ = _insert(state.key, _probe_slots(kw, cap), kw, valid,
                                jnp.full(keys.shape, -1, jnp.int32))
         placed = valid & (row >= 0)
         wrow = jnp.where(placed, row, cap)
         new = {"key": tkey}
         for f, col in zip(VALUE_COLS, cols):
-            new[f] = getattr(state, f).at[wrow].set(col, mode="drop")
+            new[f] = put_rows(getattr(state, f), wrow, col, unique=False)
         return TableState(**new), placed
 
     sharded = shard_map(
@@ -147,6 +148,8 @@ def make_grow(mesh, cap_new: int):
     for capacity changes (ROUND_NOTES gap: the host-mediated
     snapshot/restore loop is shard-count independent but streams the
     whole table through host memory; this is one device program).
+    The old table IS the batch here, and it stays words: the probe
+    sequence, the claims and the row moves all work on the halves.
 
     Key→shard ownership depends only on the mesh size (hashing.shard_of),
     so capacity changes never move rows across shards: the program is a
@@ -159,25 +162,27 @@ def make_grow(mesh, cap_new: int):
     """
 
     def _grow(state):
-        cap_old = state.key.shape[0]
+        cap_old = state.capacity
         key = state.key
-        valid = key != 0
-        slots = _probe_slots(key, cap_new)
-        tkey, row, _ = _insert(jnp.zeros(cap_new, jnp.uint64), slots, key,
-                               valid, jnp.full(cap_old, -1, jnp.int32))
-        placed = valid & (row >= 0)
-        wrow = jnp.where(placed, row, cap_new)
+        valid = ~is_empty(key)
         # init_table is shard_map-safe (no device placement; its guards
         # are host-side trace-time checks) and the single source of
         # truth for column defaults
         fresh = init_table(cap_new)
-        new = {"key": tkey}
-        for f in VALUE_COLS:
-            new[f] = getattr(fresh, f).at[wrow].set(getattr(state, f),
-                                                    mode="drop")
+        tkey, row, _ = _insert(fresh.key, _probe_slots(key, cap_new), key,
+                               valid, jnp.full(cap_old, -1, jnp.int32))
+        placed = valid & (row >= 0)
+        wrow = jnp.where(placed, row, cap_new)
+
+        def move(to, frm):
+            return to.at[wrow].set(frm, mode="drop")
+
+        new = fresh._replace(key=tkey, **{
+            f: jax.tree.map(move, getattr(fresh, f), getattr(state, f))
+            for f in VALUE_COLS})
         dropped = lax.psum((valid & (~placed)).sum(dtype=jnp.int64),
                            SHARD_AXIS)
-        return TableState(**new), dropped
+        return new, dropped
 
     return jax.jit(shard_map(
         _grow, mesh=mesh, in_specs=P(SHARD_AXIS),
@@ -281,6 +286,22 @@ def make_sharded_step_packed(mesh, donate: bool = False):
     return jax.jit(sharded, donate_argnums=(0,) if donate else ())
 
 
+def make_pallas_sweep(mesh, interpret: bool = False):
+    """jit program: per-shard fused Pallas sweep (ops/pallas_sweep.py)
+    + psum'd live count, (state, now) → (state, live)."""
+    from ..ops.pallas_sweep import sweep_expired_pallas
+
+    def _one(state, now):
+        st, live = sweep_expired_pallas(state, now, interpret=interpret)
+        return st, lax.psum(live, SHARD_AXIS)
+
+    # check_vma=False: pallas_call's out_shape carries no
+    # varying-mesh-axes annotation
+    return jax.jit(shard_map(
+        _one, mesh=mesh, in_specs=(P(SHARD_AXIS), P()),
+        out_specs=(P(SHARD_AXIS), P()), check_vma=False))
+
+
 class ShardedEngine:
     """Host dispatcher over a sharded table: the multi-chip analog of the
     reference's V1Instance request router (gubernator.go ›
@@ -369,10 +390,8 @@ class ShardedEngine:
         The serving step aliases the table in/out by default
         (GUBER_STEP_DONATE=0 opts out): clean-step cold columns pass
         through copy-free and row scatters update in place (see
-        core/step.py › decide_batch_donated).  Measured on a real v5e
-        (tools/tpu_session.py, 2026-07-31): donate 0.573 ms/step vs
-        copy 209 ms at CAP 2^21 — non-donated scatters serialize on
-        TPU — and donate also wins 6.3× on CPU (PERF.md §5)."""
+        core/step.py › decide_batch_donated; what the step costs on
+        the chip: PERF.md §5, cell 6)."""
         import os as _os
 
         self.state = shard_table(self.mesh, self.cap_local)
@@ -421,23 +440,11 @@ class ShardedEngine:
                             "live rows", self.cap_local, dropped)
 
     def _pallas_sweep(self, now_ms: int):
-        """shard_map'd fused sweep: per-shard Pallas pass + psum'd live
-        count.  Interpret mode off-TPU (Mosaic kernels are TPU-only)."""
+        """The fused sweep (``make_pallas_sweep``); interpret mode
+        off-TPU (Mosaic kernels are TPU-only)."""
         if self._pallas_sweep_fn is None:
-            from ..ops.pallas_sweep import sweep_expired_pallas
-
-            interpret = jax.default_backend() != "tpu"
-
-            def _one(state, now):
-                st, live = sweep_expired_pallas(state, now,
-                                                interpret=interpret)
-                return st, lax.psum(live, SHARD_AXIS)
-
-            # check_vma=False: pallas_call's out_shape carries no
-            # varying-mesh-axes annotation
-            self._pallas_sweep_fn = jax.jit(shard_map(
-                _one, mesh=self.mesh, in_specs=(P(SHARD_AXIS), P()),
-                out_specs=(P(SHARD_AXIS), P()), check_vma=False))
+            self._pallas_sweep_fn = make_pallas_sweep(
+                self.mesh, interpret=jax.default_backend() != "tpu")
         with XLA_EXEC_MU:
             return self._pallas_sweep_fn(self.state,
                                          jnp.asarray(now_ms, jnp.int64))
@@ -1081,8 +1088,7 @@ class ShardedEngine:
             self._gather = make_gather_rows(self.mesh)
         m = len(khash)
         found = np.zeros(m, bool)
-        out = {f: np.zeros(m, np.asarray(getattr(self.state, f)).dtype)
-               for f in VALUE_COLS}
+        out = {f: np.zeros(m, COLUMN_DTYPES[f]) for f in VALUE_COLS}
         for wave, slots in self._route_waves(khash):
             keys = np.zeros(self.n * self.B, np.uint64)
             keys[slots] = khash[wave]
@@ -1176,9 +1182,8 @@ class ShardedEngine:
         shard = int(shard_of(np.array([k], np.uint64), self.n)[0])
         slots = (shard * self.cap_local + local).astype(np.int64)
         with XLA_EXEC_MU:
-            keys = np.asarray(
-                jnp.take(self.state.key, jnp.asarray(slots), axis=0))
-        return keys.astype(np.uint64)
+            keys = np.asarray(take_rows(self.state.key, jnp.asarray(slots)))
+        return keys.view(np.uint64)
 
     def each(self):
         """Iterate live rows as store.CacheItem objects (Cache.Each
@@ -1214,19 +1219,22 @@ class ShardedEngine:
         keeps its first row's slot and its last row's values; rows of
         key 0 (the empty slot's mark) are no rows and are skipped.
         """
-        host = {f: np.asarray(getattr(self.state, f)).copy()
-                for f in self.state._fields}
-        keys = np.asarray(arrays["key"]).astype(np.uint64)
+        # the table's word columns, writable on the host; the
+        # snapshot's 64-bit columns are read as words through views
+        host = jax.tree.map(np.array, self.state)
+        keys = np.ascontiguousarray(arrays["key"], dtype=np.uint64)
+        rows_of = from_host({**arrays, "key": keys})
         with phase("restore.place", self.metrics_ref):
-            slot_of = self._place_rows(host["key"], keys)
+            slot_of = self._place_rows(
+                column_to_host(host.key, np.uint64), keys)
             fit = slot_of >= 0
             # in row order, so a key's last row writes last; every row
             # fits as a rule, and then no column is copied to be masked
             rows = slice(None) if fit.all() else fit
             slots = slot_of[rows]
-            for f in host:
-                host[f][slots] = (keys if f == "key"
-                                  else np.asarray(arrays[f]))[rows]
+            for to, frm in zip(jax.tree.leaves(host),
+                               jax.tree.leaves(rows_of)):
+                to[slots] = frm[rows]
         placed = int(np.count_nonzero(fit))
         lost = np.flatnonzero(~fit & (keys != 0))
         if len(lost) and self.tier is not None:
@@ -1238,11 +1246,7 @@ class ShardedEngine:
             lost = lost[adopted:]
         if self.metrics_ref is not None:
             self.metrics_ref.restore_unplaced_rows.set(len(lost))
-        sh = table_sharding(self.mesh)
-        from ..core.table import TableState
-
-        self.state = TableState(**{
-            f: jax.device_put(v, sh) for f, v in host.items()})
+        self.state = jax.device_put(host, table_sharding(self.mesh))
         if jax.default_backend() == "cpu":
             # device_put of an aligned host column is zero-copy on this
             # image's XLA:CPU without pinning the numpy owner — once
@@ -1251,7 +1255,7 @@ class ShardedEngine:
             # across restart, and worse: ~1.6k phantom rows evicting
             # real ones).  Pin the columns for the engine's lifetime;
             # the donated step keeps writing the state into these same
-            # buffers, so the cost is one table copy (~cap×9×8 bytes),
+            # buffers, so the cost is one table copy (~cap×68 bytes),
             # not a leak per wave.  Other backends copy to the device.
             self._restore_host_pin = host
         return placed

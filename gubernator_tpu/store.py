@@ -141,7 +141,9 @@ def save_arrays(path: str, arrays: dict) -> None:
 
 def table_to_arrays(state) -> dict:
     """Device TableState → host column dict (drops empty rows)."""
-    cols = {name: np.asarray(getattr(state, name)) for name in _COLUMNS}
+    from .core.table import to_host
+
+    cols = to_host(state)
     live = cols["key"] != 0
     return {name: col[live] for name, col in cols.items()}
 

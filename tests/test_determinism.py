@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from gubernator_tpu import Algorithm, Behavior, RateLimitRequest
+from gubernator_tpu.core.table import to_host
 from gubernator_tpu.parallel import ShardedEngine, make_mesh
 
 NOW = 1_761_000_000_000
@@ -53,9 +54,8 @@ def test_identical_streams_identical_decisions():
     r2, e2 = _run(4, s)
     assert r1 == r2
     # table state must match bit-for-bit too
-    for f in e1.state._fields:
-        assert (np.asarray(getattr(e1.state, f))
-                == np.asarray(getattr(e2.state, f))).all(), f
+    for f, col in to_host(e1.state).items():
+        assert (col == to_host(e2.state)[f]).all(), f
 
 
 def test_shard_count_does_not_change_decisions():

@@ -203,3 +203,22 @@ def test_probe_window_exhaustion():
     assert 0 < pinned <= 8
     eng.unpin_all()
     assert eng.pin(req("x0"), kh("x0"), NOW)
+
+
+@pytest.mark.parametrize("limit", [1000, (1 << 32) + 50, (1 << 45) + 7])
+def test_sync_merges_consumption_past_the_low_word(mesh4, limit):
+    """The replica map holds 64-bit columns as two 32-bit words
+    (core/table.py): the sync joins them at replica size, merges in
+    int64 and splits the merged row back."""
+    eng = HotSetEngine(mesh4, capacity=256, batch_per_chip=32)
+    assert eng.pin(req(key="w", limit=limit), kh("w"), NOW)
+    out = eng.check_batch([req(key="w", limit=limit, hits=2)] * 8,
+                          [kh("w")] * 8, NOW + 1)
+    assert all(r.error == "" for r in out)
+    eng.sync()
+    merged = eng.row_state(kh("w"))
+    assert merged["remaining"] == limit - 16
+    assert merged["limit"] == limit
+    q = eng.check_batch([req(key="w", limit=limit, hits=0)] * 4,
+                        [kh("w")] * 4, NOW + 2)
+    assert {r.remaining for r in q} == {limit - 16}

@@ -121,6 +121,42 @@ class TestRescaleGuards:
         run_parity([([r], NOW), ([r], NOW + DAY), ([r], NOW + 100 * DAY)])
 
 
+class TestWordBoundaries:
+    """The table holds every int64 column as two 32-bit words
+    (core/table.py): clocks, expiries and counters that CROSS a word
+    boundary between two batches must carry into the high word."""
+
+    @pytest.mark.parametrize("alg", [Algorithm.TOKEN_BUCKET,
+                                     Algorithm.LEAKY_BUCKET])
+    @pytest.mark.parametrize("edge", [1 << 31, 1 << 32, 1 << 33, 1 << 40])
+    def test_clock_crosses_a_word_boundary(self, edge, alg):
+        from gubernator_tpu.core.table import to_host
+
+        r = lambda h: mk(key="wb", hits=h, limit=50, duration=700,
+                         algorithm=alg)
+        times = [edge - 500, edge - 1, edge, edge + 1, edge + 150,
+                 edge + 199, edge + 200, edge + 1000]
+        state = run_parity([([r(1), r(2)], t) for t in times])
+        host = to_host(state)
+        row = host["key"] != 0
+        assert host["t_ms"][row] >= edge  # the high word carried
+        assert host["expire_at"][row] > edge
+
+    @pytest.mark.parametrize("limit", [(1 << 32) + 5, (1 << 33) - 1,
+                                       (1 << 53)])
+    def test_remaining_counts_down_through_a_word_boundary(self, limit):
+        """remaining starts above 2^32 and is spent to below it: the
+        borrow out of the high word, then the query reads it back."""
+        from gubernator_tpu.core.table import to_host
+
+        spend = limit - (1 << 32) + 3  # leaves 2^32 - 3
+        r = lambda h: mk(key="rb", hits=h, limit=limit, duration=DAY)
+        state = run_parity([([r(1)], NOW), ([r(spend - 1)], NOW + 1),
+                            ([r(0), r(5), r(0)], NOW + 2)])
+        host = to_host(state)
+        assert host["remaining"][host["key"] != 0] == (1 << 32) - 8
+
+
 class TestFuzzInt64:
     def test_random_durations_parity(self):
         rng = np.random.default_rng(20260730)

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from gubernator_tpu.config import BehaviorConfig, Config
+from gubernator_tpu.core.table import to_host
 from gubernator_tpu.hashing import hash_key
 from gubernator_tpu.instance import V1Instance
 from gubernator_tpu.parallel import make_mesh
@@ -88,12 +89,13 @@ def test_conservation_convergence_staleness(monkeypatch):
             inst.metrics.mesh_global_staleness._value.get()) * 1000 \
             <= SYNC_MS
         # every replica of every pinned key agrees post-fold
+        remaining = to_host(mge.state)["remaining"]
         for kh, slot in mge.slots.items():
-            col = np.asarray(mge.state.remaining)[:, slot]
+            col = remaining[:, slot]
             assert len(set(col.tolist())) == 1, (kh, col)
         # k0: 4 waves × 4 occurrences × 2 hits + 3 object-lane hits
         kh0 = hash_key("mg", "k0")
-        rem = np.asarray(mge.state.remaining)[0, mge.slots[kh0]]
+        rem = remaining[0, mge.slots[kh0]]
         assert int(rem) == 100_000 - (4 * 4 * 2 + 3)
         # zero gRPC peer RPCs: no peers, and no hit aggregate was ever
         # queued for the gRPC lanes
@@ -125,7 +127,7 @@ def test_fold_on_a_one_device_mesh_conserves(monkeypatch):
         assert inst.metrics.mesh_global_fold_errors._value.get() == 0
         assert not inst._mesh_degraded
         kh0 = hash_key("mg", "k0")
-        rem = np.asarray(mge.state.remaining)[0, mge.slots[kh0]]
+        rem = to_host(mge.state)["remaining"][0, mge.slots[kh0]]
         assert int(rem) == 100_000 - 4 * 4 * 2
     finally:
         inst.close()
@@ -643,3 +645,32 @@ def test_packed_columns_equal_cfg_of(cfg):
     got = tuple(int(np.asarray(c)[0]) for c in (
         batch.algorithm, batch.limit, batch.duration, batch.burst))
     assert got == meshglobal._cfg_of(req) == hotset._cfg_of(req)
+
+
+@pytest.mark.parametrize("limit", [10_000, (1 << 32) + 50, (1 << 45) + 7])
+@pytest.mark.parametrize("n", [1, 4])
+def test_fold_adopts_the_home_row_word_by_word(limit, n):
+    """The replica map holds its 64-bit columns as two 32-bit words
+    (core/table.py): the fold psums each word of the home-masked
+    column, and exactly one replica is a slot's home — so every replica
+    ends with the home's row, also where the value needs the high
+    word."""
+    from gubernator_tpu.parallel.meshglobal import MeshGlobalEngine
+
+    mge = MeshGlobalEngine(make_mesh(n=n), capacity=256, batch_per_chip=16)
+    r = RateLimitRequest(name="mgw", unique_key="wide", hits=3, limit=limit,
+                         duration=600_000, behavior=Behavior.GLOBAL)
+    kh = hash_key("mgw", "wide")
+    assert mge.pin(r, kh, NOW)
+    out = mge.check_batch([r] * 8, [kh] * 8, NOW + 1)
+    assert [x.remaining for x in out] == [limit - 3 * (i + 1)
+                                         for i in range(8)]
+    mge.fold(mge.swap_accum())
+    mge.drain()
+    assert mge.stats()["folded_hits"] == mge.stats()["injected_hits"] == 24
+    host = to_host(mge.state)
+    slot = mge.slots[kh]
+    assert (host["remaining"][:, slot] == limit - 24).all()
+    assert (host["limit"][:, slot] == limit).all()
+    assert (host["key"][:, slot] == np.uint64(kh)).all()
+    assert mge.row_state(kh)["remaining"] == limit - 24

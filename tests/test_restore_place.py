@@ -15,6 +15,7 @@ import pytest
 from gubernator_tpu import Oracle, RateLimitRequest
 from gubernator_tpu.config import Config
 from gubernator_tpu.core.step import PROBES, REPLICA_PROBES
+from gubernator_tpu.core.table import to_host
 from gubernator_tpu.hashing import hash_request_keys, shard_of
 from gubernator_tpu.instance import V1Instance
 from gubernator_tpu.metrics import Metrics
@@ -61,8 +62,7 @@ def rows_of(keys: np.ndarray, seed: int) -> dict:
 
 
 def host_table(eng) -> dict:
-    return {f: np.asarray(getattr(eng.state, f)).copy()
-            for f in eng.state._fields}
+    return to_host(eng.state)
 
 
 class Tier:
@@ -118,6 +118,41 @@ def test_restore_builds_the_table_the_row_walk_builds(n, cap, m, bits,
         in text
     if cap <= 1 << 6:
         assert left_want, "the case is there for windows that fill"
+
+
+@pytest.mark.parametrize("n_shards", [1, 4])
+def test_a_snapshot_the_parent_wrote_restores_to_the_same_answers(n_shards):
+    """The host side of the table is the parent's: a file of int64 /
+    uint64 columns written BEFORE the table was held as 32-bit words
+    (``tests/snapshot_history.py`` says by whom and how) restores, comes
+    back from ``snapshot`` as it went in, and every answer after it is
+    the one an engine that served the whole history gives."""
+    import snapshot_history as sh
+
+    snap = dict(np.load(sh.PATH, allow_pickle=False))
+    assert {f: v.dtype for f, v in snap.items()} == {
+        **{f: np.int64 for f in FIELDS}, "key": np.uint64, "meta": np.int32}
+    assert {0xDEADBEEF << 32, 0xDEADBEEF, 1} <= set(snap["key"].tolist())
+
+    def engine():
+        return ShardedEngine(make_mesh(n=n_shards), capacity_per_shard=1 << 8,
+                             batch_per_shard=64)
+
+    whole = engine()
+    sh.serve(whole, sh.BEFORE)
+    restored = engine()
+    assert restored.restore(snap) == len(snap["key"])
+    back = restored.snapshot()
+    a, b = np.argsort(snap["key"]), np.argsort(back["key"])
+    for f in snap:
+        assert back[f].dtype == snap[f].dtype
+        assert (back[f][b] == snap[f][a]).all(), f
+    for got, want in zip(sh.serve(restored, sh.AFTER),
+                         sh.serve(whole, sh.AFTER)):
+        for g, w in zip(got, want):
+            assert (g == w).all()
+        assert not got[4].any()  # no row table_full
+    assert len({tuple(c[0].tolist()) for c in sh.serve(whole, sh.AFTER)}) > 1
 
 
 def test_restore_into_a_table_that_holds_rows_and_leaves_the_rest_to_the_tier():
@@ -223,7 +258,7 @@ def test_a_key_whose_first_8_slots_are_taken_is_served_as_the_oracle_serves_it(
         == [""] * 8
     oracle.check_batch(warm, now)
     # the others sit in the key's first eight slots
-    held = np.asarray(inst.engine.state.key)[window(kh, cap, 8)]
+    held = host_table(inst.engine)["key"][window(kh, cap, 8)]
     assert (held != 0).all() and kh not in held.tolist()
     for step in range(7):  # inserted, found, over its limit
         now += 1000
@@ -232,7 +267,7 @@ def test_a_key_whose_first_8_slots_are_taken_is_served_as_the_oracle_serves_it(
         assert got.error == ""
         assert (int(got.status), got.remaining, got.reset_time) == \
             (int(want.status), want.remaining, want.reset_time), step
-    slot = np.flatnonzero(np.asarray(inst.engine.state.key) == kh)
+    slot = np.flatnonzero(host_table(inst.engine)["key"] == kh)
     assert len(slot) == 1 and slot[0] in window(kh, cap, PROBES)[8:]
     # restored beyond the eight, and found by the step there
     snap = inst.engine.snapshot()

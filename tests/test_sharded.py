@@ -192,8 +192,84 @@ class TestOnDeviceGrow:
         eng.grow(1 << 10)
         from jax.sharding import PartitionSpec as P
 
-        assert eng.state.key.sharding.spec == P("shard")
-        assert eng.state.key.shape[0] == 4 * (1 << 10)
+        import jax
+
+        for word_column in jax.tree.leaves(eng.state):
+            assert word_column.sharding.spec == P("shard")
+            assert word_column.shape == (4 * (1 << 10),)
+
+
+#: identities and values at the edges of the table's two-word columns
+#: (at most three share a probe sequence: upsert has four claim rounds)
+EDGE_KEYS = np.array(
+    [0xDEADBEEF << 32, 1 << 32,                          # low word 0
+     0xDEADBEEF, 1, 0xFFFFFFFF,                          # high word 0
+     (7 << 32) | 300, (8 << 32) | 300, 300, (7 << 32) | 301,  # one shared
+     (1 << 63) | 500, (1 << 64) - 1, 1 << 63,            # top bits
+     (1 << 63) | (1 << 31) | 40, (1 << 31) | 90], np.uint64)
+EDGE_VALUES = np.array(
+    [0, 1, -1, 1 << 32, (1 << 32) - 1, -(1 << 32), (1 << 31), -(1 << 31),
+     (1 << 63) - 1, -(1 << 63), 1 << 53, (0x7FFFFFFF << 32),
+     0xFFFFFFFF, -(1 << 40) + 3], np.int64)
+
+
+@pytest.mark.parametrize("n_shards", [1, 4])
+@pytest.mark.parametrize("program", ["gather", "snapshot", "grow",
+                                     "remove", "restore"])
+def test_row_programs_at_word_edges(n_shards, program):
+    """upsert → {gather, snapshot, grow, remove, restore}: every int64
+    a column can hold comes back as it went in, under keys of which
+    either word may be 0 (core/table.py › Words)."""
+    eng = ShardedEngine(make_mesh(n=n_shards), capacity_per_shard=1 << 8,
+                        batch_per_shard=16)
+    n = len(EDGE_KEYS)
+    cols = {f: np.roll(EDGE_VALUES, i) for i, f in enumerate(
+        ("limit", "duration", "eff_ms", "burst", "remaining", "t_ms",
+         "expire_at"))}
+    cols["meta"] = (np.arange(n) % 4).astype(np.int32)
+    assert eng.upsert_rows(EDGE_KEYS, cols) == n
+    assert eng.occupancy() == n
+
+    def same_rows(found, got, keys=EDGE_KEYS, want=cols):
+        assert found.all()
+        for f, col in want.items():
+            assert (got[f] == col).all(), f
+            assert got[f].dtype == col.dtype, f
+
+    if program == "gather":
+        same_rows(*eng.gather_rows(EDGE_KEYS))
+        absent = EDGE_KEYS ^ np.uint64(1 << 32)  # one bit of the HIGH word
+        absent = absent[~np.isin(absent, EDGE_KEYS)]
+        assert not eng.gather_rows(absent)[0].any()
+    elif program == "snapshot":
+        snap = eng.snapshot()
+        assert snap["key"].dtype == np.uint64
+        order = np.argsort(snap["key"])
+        by_key = np.argsort(EDGE_KEYS)
+        assert (snap["key"][order] == EDGE_KEYS[by_key]).all()
+        for f, col in cols.items():
+            assert snap[f].dtype == col.dtype
+            assert (snap[f][order] == col[by_key]).all(), f
+    elif program == "grow":
+        assert eng.grow(1 << 10) == 0
+        same_rows(*eng.gather_rows(EDGE_KEYS))
+        assert eng.occupancy() == n
+    elif program == "remove":
+        assert eng.remove_rows(EDGE_KEYS[::2]) == len(EDGE_KEYS[::2])
+        found, got = eng.gather_rows(EDGE_KEYS)
+        assert (found == (np.arange(n) % 2 == 1)).all()
+        assert (got["remaining"][found] == cols["remaining"][1::2]).all()
+        assert eng.occupancy() == n // 2
+    else:
+        other = ShardedEngine(make_mesh(n=n_shards),
+                              capacity_per_shard=1 << 8, batch_per_shard=16)
+        assert other.restore(eng.snapshot()) == n
+        same_rows(*other.gather_rows(EDGE_KEYS))
+        # the XLA sweep on the restored words: expire_at <= now, signed
+        now = 1 << 32
+        other.sweep(now)
+        found, _ = other.gather_rows(EDGE_KEYS)
+        assert (found == (cols["expire_at"] > now)).all()
 
 
 def test_graft_entry_single():

@@ -10,19 +10,27 @@ import pytest
 
 from gubernator_tpu import Algorithm, Behavior, GregorianDuration, Oracle, RateLimitRequest
 from gubernator_tpu.core import decide_batch, init_table, pack_requests
+from gubernator_tpu.core.batch import clamp_config
+from gubernator_tpu.core.table import to_host
+from gubernator_tpu.types import DURATION_MAX, VALUE_MAX
 
 NOW = 1_760_000_000_000
 CAP = 1 << 14
 
 
-def run_stream(batches, cap=CAP):
-    """batches: list of (reqs, now_ms). Returns list of mismatches."""
+def run_stream(batches, cap=CAP, key_hash=None, final=None):
+    """batches: list of (reqs, now_ms). Returns list of mismatches.
+    ``key_hash`` maps a request's unique_key to the 64-bit identity it
+    is filed under (default: the real hash); ``final`` receives the
+    table after the last batch."""
     oracle = Oracle()
     state = init_table(cap)
     mismatches = []
     for bi, (reqs, now) in enumerate(batches):
         want = oracle.check_batch(reqs, now)
-        packed, errs = pack_requests(reqs, now)
+        hashes = None if key_hash is None else np.array(
+            [key_hash[r.unique_key] for r in reqs], np.uint64)
+        packed, errs = pack_requests(reqs, now, key_hashes=hashes)
         state, out = decide_batch(state, packed, now)
         status = np.asarray(out.status)
         rem = np.asarray(out.remaining)
@@ -39,11 +47,13 @@ def run_stream(batches, cap=CAP):
             exp = (int(w.status), int(w.remaining), int(w.reset_time), int(w.limit))
             if got != exp:
                 mismatches.append((bi, i, reqs[i], exp, got))
+    if final is not None:
+        final.update(to_host(state))
     return mismatches
 
 
-def assert_parity(batches, cap=CAP):
-    mm = run_stream(batches, cap)
+def assert_parity(batches, cap=CAP, **kw):
+    mm = run_stream(batches, cap, **kw)
     assert not mm, f"{len(mm)} mismatches; first 5: {mm[:5]}"
 
 
@@ -230,6 +240,67 @@ class TestRandomizedParity:
         assert_parity(batches)
 
 
+#: identities whose words are the edges of the two-word key column:
+#: only BOTH words 0 is the empty mark, and two keys that share one
+#: word are two keys
+WORD_EDGE_KEYS = {
+    "low word 0": [0xDEADBEEF << 32, 1 << 32, 0xFFFFFFFF << 32],
+    "high word 0": [0xDEADBEEF, 1, 0xFFFFFFFF],
+    "same low word": [(7 << 32) | 5, (8 << 32) | 5, 5],
+    "same high word": [(9 << 32) | 1, (9 << 32) | 2, 9 << 32],
+    "top bits": [(1 << 63) | 1, (1 << 63) | (1 << 31), (1 << 64) - 1,
+                 (1 << 31), (1 << 63)],
+}
+
+
+class TestWordEdges:
+    """The table holds 64-bit columns as two 32-bit words
+    (core/table.py): parity at the edges that creates."""
+
+    @pytest.mark.parametrize("case", WORD_EDGE_KEYS)
+    @pytest.mark.parametrize("cap", [1 << 4, 1 << 10])
+    def test_keys_at_word_edges(self, case, cap):
+        # cap 16: every key shares a probe window with the others
+        hashes = WORD_EDGE_KEYS[case]
+        key_hash = {f"e{i}": h for i, h in enumerate(hashes)}
+        final = {}
+        batches = []
+        for t in range(4):
+            reqs = [mk(key=u, hits=1 + i, limit=6,
+                       algorithm=(Algorithm.LEAKY_BUCKET if (i + t) % 2
+                                  else Algorithm.TOKEN_BUCKET)
+                       if t > 1 else Algorithm.TOKEN_BUCKET)
+                    for i, u in enumerate(key_hash)]
+            batches.append((reqs + reqs[:2], NOW + t * 500))
+        assert_parity(batches, cap, key_hash=key_hash, final=final)
+        held = final["key"][final["key"] != 0]
+        assert sorted(held.tolist()) == sorted(hashes)
+
+    @pytest.mark.parametrize("limit,hits,duration", [
+        (1 << 32, 1, 60_000),                  # the high word's first bit
+        ((1 << 32) - 1, (1 << 32) - 2, 60_000),  # the low word, full
+        ((1 << 40) + 3, 1 << 33, 1 << 33),
+        (VALUE_MAX, VALUE_MAX - 1, DURATION_MAX),  # the domain's extremes
+        (VALUE_MAX, 0, 1),
+    ])
+    @pytest.mark.parametrize("alg", [Algorithm.TOKEN_BUCKET,
+                                     Algorithm.LEAKY_BUCKET])
+    def test_values_past_32_bits(self, limit, hits, duration, alg):
+        r = mk(key="big", hits=hits, limit=limit, duration=duration,
+               algorithm=alg, burst=limit)
+        q = mk(key="big", hits=0, limit=limit, duration=duration,
+               algorithm=alg, burst=limit)
+        final = {}
+        assert_parity([([r], NOW), ([r, q], NOW + 1), ([q, r], NOW + 7),
+                       ([r], NOW + (1 << 33))], final=final)
+        row = final["key"] != 0
+        assert row.sum() == 1
+        # what the words spell on the host is what the step stored
+        assert final["limit"][row] == clamp_config(
+            int(alg), limit, duration, limit, 0)[1]
+        assert final["t_ms"][row] >= NOW and final["expire_at"][row] > NOW
+
+
 def test_donated_step_matches_copy_step():
     """The SERVING default (decide_batch_donated: same impl, table
     donated in/out) must produce outputs and final state bit-identical
@@ -258,10 +329,9 @@ def test_donated_step_matches_copy_step():
             np.testing.assert_array_equal(
                 np.asarray(getattr(outc, f)), np.asarray(getattr(outd, f)),
                 err_msg=f"step {step_i}: {f} diverged")
-    for i, (c, d) in enumerate(zip(stc, std)):
+    for f, c in to_host(stc).items():
         np.testing.assert_array_equal(
-            np.asarray(c), np.asarray(d),
-            err_msg=f"final state col {i} diverged")
+            c, to_host(std)[f], err_msg=f"final state col {f} diverged")
 
 
 def test_tpu_long_division_is_exact():

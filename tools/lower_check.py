@@ -104,7 +104,7 @@ def _programs(mesh):
     ]
     if os.environ.get("GUBER_KSPLIT"):
         # the K-split rewrite only activates at CAP > 2^ksplit — a
-        # genuinely split table (read at core.step import: own process)
+        # genuinely split table (read at core.table import: own process)
         return [(f"xla_step_donated_ksplit{os.environ['GUBER_KSPLIT']}",
                  decide_batch_donated, (table(1 << 22), batch, now), False)]
     return progs + [

@@ -304,14 +304,14 @@ class Dispatcher:
         #: without it (OracleEngine) and for list/merged waves.
         self._pipelined = hasattr(engine, "launch_packed")
         #: who lays out what (ISSUE 30): a packed call's rows are
-        #: stacked and examined ONCE, in its own thread (``_lay_out``,
+        #: stacked and examined ONCE, in its own thread (``lay_out``,
         #: in check_packed_view); the worker only joins the calls'
         #: blocks (``_join``, in _concat_jobs).  An engine with a say
         #: in either (ShardedEngine: its domain mask; a pooled lease to
         #: join into) brings its own.
         from .core.batch import join_calls, stack_rows
 
-        self._lay_out = getattr(
+        self.lay_out = getattr(
             engine, "lay_out", lambda batch, khash, mslot: stack_rows(batch))
         self._join = getattr(engine, "join_calls", join_calls)
         # fused-engine capability (ISSUE 8): the engine emits the
@@ -399,7 +399,7 @@ class Dispatcher:
         build_responses_from_columns) without materializing per-job
         column tuples."""
         return self._submit_and_wait(_PackedJob(
-            self._lay_out(batch, khash, mslot), khash, now_ms, mslot=mslot))
+            self.lay_out(batch, khash, mslot), khash, now_ms, mslot=mslot))
 
     def _fault(self, point: str) -> None:
         f = self._faults
@@ -1320,7 +1320,7 @@ class Dispatcher:
                 b, errs = pack_requests(j.reqs, j.now_ms,
                                         size=len(j.reqs), key_hashes=kh)
                 parts.append((j, kh, errs))
-                calls.append(self._lay_out(b, kh, None))
+                calls.append(self.lay_out(b, kh, None))
         rows, khash, mslot = self._join(
             calls, [p[1] for p in parts],
             [getattr(j, "mslot", None) for j in wave])

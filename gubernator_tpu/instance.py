@@ -1682,18 +1682,23 @@ class V1Instance:
                                created=None) -> bytes:
         """Columns → pack → device step → response wire bytes: the
         shared fast-lane body (solo client wire, peer wire, and the
-        clustered lane's local sub-batch all end here).  Resolves from
-        the dispatcher's ResultView — row bounds into the wave's shared
-        downloaded result columns — and serializes straight from them
-        in THIS caller's thread (ops/_native.cpp ›
-        build_responses_from_columns), so response build never runs on
-        the dispatch worker and materializes no per-job column
-        tuples."""
+        clustered lane's local sub-batch all end here)."""
         from .core.batch import pack_columns
 
         batch, errs = pack_columns(kh, hits, limit, duration, algorithm,
                                    behavior, burst, now,
                                    created_at=created)
+        return self._packed_batch_to_bytes(batch, errs, kh, now)
+
+    def _packed_batch_to_bytes(self, batch, errs: dict, kh: np.ndarray,
+                               now: int) -> bytes:
+        """A packed call → device step → response wire bytes.  Resolves
+        from the dispatcher's ResultView — row bounds into the wave's
+        shared downloaded result columns — and serializes straight from
+        them in THIS caller's thread (ops/_native.cpp ›
+        build_responses_from_columns), so response build never runs on
+        the dispatch worker and materializes no per-job column
+        tuples."""
         view = self.dispatcher.check_packed_view(batch, kh, now)
         status = view.cols[0][view.lo:view.hi]
         full = view.cols[4][view.lo:view.hi]
@@ -1716,14 +1721,25 @@ class V1Instance:
     def _wire_check_columns(self, parsed: dict, now: int) -> bytes:
         """Parsed wire columns → device step → serialized responses
         (identical for the client and peer wire)."""
+        from .core.batch import pack_columns
         from .hashing import mix64_np
 
-        kh = mix64_np(parsed["khash_raw"])
-        kh = np.where(kh == 0, np.uint64(1), kh)
-        return self._packed_check_to_bytes(
-            kh, parsed["hits"], parsed["limit"], parsed["duration"],
-            parsed["algorithm"], parsed["behavior"], parsed["burst"], now,
-            created=parsed.get("created_at"))
+        # local.pack (ISSUE 33): what a call on this lane does in its
+        # own thread before it is queued — hash, pack, lay out — wall
+        # AND thread CPU, as route.* (on 32 handler threads and one GIL
+        # the difference is waiting), sampled as `handler` is.  The
+        # fused lane (one shard) does all of it in one C++ pass inside
+        # `ingest` and never comes here.
+        disp = self.dispatcher
+        with phase("local.pack", disp, cpu=True, every=disp.call_sample):
+            kh = mix64_np(parsed["khash_raw"])
+            kh = np.where(kh == 0, np.uint64(1), kh)
+            batch, errs = pack_columns(
+                kh, parsed["hits"], parsed["limit"], parsed["duration"],
+                parsed["algorithm"], parsed["behavior"], parsed["burst"],
+                now, created_at=parsed.get("created_at"))
+            disp.lay_out(batch, kh, None)  # check_packed_view finds it done
+        return self._packed_batch_to_bytes(batch, errs, kh, now)
 
     def _wire_check_clustered(self, parsed: dict, data: bytes, now: int
                               ) -> bytes:

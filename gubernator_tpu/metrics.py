@@ -142,6 +142,24 @@ class Metrics:
             "lease (one shard, clocks in order, no cold tier, one "
             "bucket), sorted = routed by shard and scattered",
             ["route"], registry=r)
+        # what the shard route costs (ISSUE 33), counted beside
+        # gubernator_wave_route at ShardedEngine._count_route, once a
+        # device wave
+        self.wave_slots = Counter(
+            "gubernator_wave_slots",
+            "slots device waves uploaded, launched over and downloaded: "
+            "the lease's width, shards x the bucket of the wave's "
+            "densest shard on the sorted route, the bucket on the "
+            "identity route; padding included", registry=r)
+        self.wave_routed_rows = Counter(
+            "gubernator_wave_routed_rows",
+            "rows device waves carried (slots less padding)", registry=r)
+        self.wave_densest_shard_rows = Counter(
+            "gubernator_wave_densest_shard_rows",
+            "rows of each device wave's densest shard, summed: what "
+            "chose the wave's bucket; x shards / routed rows is the "
+            "skew, 1.0 an even wave (one shard: the rows themselves)",
+            registry=r)
         self.sweeps = Counter(
             "gubernator_sweep",
             "whole-table expiry sweeps by cause (instance._maybe_sweep, "

@@ -598,7 +598,9 @@ class ShardedEngine:
             with phase("wave.fill"):
                 if valid is not None:
                     wave.valid[:] = valid
-            self._count_route("identity")
+            # one shard: the lease is the bucket, every row is its shard's
+            self._count_route("identity", wave.lease.a64.shape[1],
+                              len(wave), len(wave))
             idx = self._first(len(wave))
             yield idx, idx, wave.lease, wave.mblk
             return
@@ -607,18 +609,19 @@ class ShardedEngine:
                 pending = (self._first(len(wave)) if wave.monotone
                            else np.argsort(wave.now, kind="stable"))
             plan = self._build_waves(khash, pending)
-        for idx, slots, bw_w in plan:
+        for idx, slots, bw_w, wcnt in plan:
             with phase("wave.fill"):
                 lease, mblk = self._fill(wave, mslot, valid, idx, slots,
                                          bw_w)
-            self._count_route("sorted")
+            self._count_route("sorted", self.n * bw_w, len(idx), wcnt)
             yield idx, slots, lease, mblk
 
     def _build_waves(self, khash: np.ndarray, pending: np.ndarray):
         """Route ``pending`` request indices into device waves.
 
-        Returns [(idx, slots, bw_w)]: original indices, block slots, and
-        the wave's bucket size.  Stable sorts keep request order inside
+        Returns [(idx, slots, bw_w, wcnt)]: original indices, block
+        slots, the wave's bucket size and the rows of its densest shard
+        (what chose the bucket).  Stable sorts keep request order inside
         a shard (sequential parity for duplicate keys).  Waves split at
         the largest bucket per shard; each wave then rides the smallest
         bucket covering its own densest shard, so a coalesced burst
@@ -639,7 +642,7 @@ class ShardedEngine:
             bw_w = next((b for b in self.wave_buckets if wcnt <= b),
                         self.wave_buckets[-1])
             slots = s_sorted[m].astype(np.int64) * bw_w + posin[m] % Bw
-            waves.append((idx, slots, bw_w))
+            waves.append((idx, slots, bw_w, wcnt))
         return waves
 
     def _fill(self, wave: Rows, mslot, valid, idx, slots, bw_w):
@@ -717,13 +720,23 @@ class ShardedEngine:
         return (wave.batch, khash, now_ms, launched, mslot, cold_idx,
                 wave.ood)
 
-    def _count_route(self, route: str) -> None:
+    def _count_route(self, route: str, slots: int, rows: int,
+                     densest: int) -> None:
         """``gubernator_wave_route_total{route}``: one device wave that
         was joined straight into its lease ("identity") or routed by
-        shard and scattered ("sorted")."""
+        shard and scattered ("sorted") — and what it cost: the slots it
+        uploads (``gubernator_wave_slots_total``: the lease's width,
+        padding included), the rows it carries
+        (``gubernator_wave_routed_rows_total``) and the rows of its
+        densest shard (``gubernator_wave_densest_shard_rows_total``:
+        what chose its bucket).  Plain integers the route already
+        holds."""
         m = self.metrics_ref
         if m is not None:
             m.wave_route.labels(route=route).inc()
+            m.wave_slots.inc(slots)
+            m.wave_routed_rows.inc(rows)
+            m.wave_densest_shard_rows.inc(densest)
 
     def _serve_out_of_domain(self, cols, ood, batch, khash, now_ms,
                              mslot):

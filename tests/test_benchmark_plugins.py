@@ -125,3 +125,143 @@ def test_xla_cost_counts_a_hand_made_wave_and_a_hand_made_profile(tmp_path):
     least_s = per_row * 16 / 819e9
     assert read("xla_step_roofline") == pytest.approx(100 * least_s / 8e-3)
     assert read("sweep_ms") == pytest.approx(25.0)
+
+
+# ---- ISSUE 33: the four-chip LOCAL deployment and its readers -----------
+
+R4_CELL, G4_CELL = "r4-zipf-b1000-sat", "r4-global-b1000-sat"
+SHARD_METRICS = {"shard_pad_share": [R4_CELL, G4_CELL],
+                 "shard_skew": [R4_CELL, G4_CELL],
+                 "local_pack_ms": [R4_CELL],
+                 "shard_kernel_ns_per_slot": [R4_CELL],
+                 "shard_kernel_roofline": [R4_CELL]}
+
+
+def test_the_sharded_deployment_and_its_cell_are_found_by_name():
+    from benchmark import run
+
+    man = _manifest()
+    entry = next(c for c in man["configs"] if c["name"] == "region4-10m")
+    assert entry["reduced"] == [] and len(entry["source"]) <= 200
+    assert entry["file"] == "benchmark/configs/region4-10m.json"
+    cell = run.load_cell(R4_CELL, rehearsal=False)
+    cfg = cell["config"]
+    assert (cfg["name"], cfg["engine"], cfg["chips"], cell["chips"]) == (
+        "region4-10m", "pallas-fused", 4, 4)
+    assert cfg["source"] == entry["source"]
+    assert cfg["env"] == {} and cfg["reduced"] == []
+    sizes = cfg["sizes"]
+    assert sizes["table_rows"] == 1 << 26 == 4 * sizes["table_rows_per_chip"]
+    assert sizes["table_bytes_in_hbm"] == (1 << 26) * sizes["bytes_per_row"] \
+        == 4 * sizes["table_bytes_in_hbm_per_chip"] == 1 << 32
+    # cell 4's daemon, cell 1's traffic, population and guarantees
+    north = run.load_cell("r1-zipf-b1000-sat", rehearsal=False)
+    glob = run.load_cell(G4_CELL, rehearsal=False)["config"]
+    assert cell["traffic"] == north["traffic"]
+    assert cfg["daemon"] == glob["daemon"] == {
+        "cache_size": 1 << 26, "global_mode": "mesh"}
+    assert cfg["populations"] == north["config"]["populations"]
+    assert cfg["populations"]["resident"] == glob["populations"]["resident"]
+    assert cfg["guarantees"] == north["config"]["guarantees"]
+    assert not any(k.startswith("global") for k in cfg["guarantees"])
+    small = run.load_cell(R4_CELL, rehearsal=True)["config"]
+    assert small["daemon"] == {"cache_size": 16384, "global_mode": "mesh"}
+    assert small["populations"]["resident"]["keys"] == 3000
+    got = {m["name"] for m in cell["per_layer"]}
+    assert set(SHARD_METRICS) <= got
+    assert {"frontdoor_ms", "handler_ms", "queue_wait_ms", "door_inflight",
+            "wave_identity_route_share"} <= got
+    # first plane, even shards; the mesh runner's; the fold's
+    assert not {"kernel_ns_per_row", "decide_kernel_roofline", "fold_ms",
+                "route_pack_ms", "route_gil_wait_share"} & got
+    assert {m["name"] for m in cell["end_to_end"]} == {
+        "decisions_per_s", "call_p50_ms", "setup_s"}
+    assert sum(w["chips"] == 4 for w in man["workloads"]) == 2
+    assert len(man["workloads"]) == 7
+
+
+def _nothing() -> dict:
+    return {"m0": {}, "m1": {}, "tm0": {}, "tm1": {},
+            "trace": {"devices": 0}, "_shard_kernel": (0.0, 0),
+            "device_kind": "TPU v5 lite", "config": {"chips": 4},
+            "rec": {"key_index": np.zeros(0, np.int64),
+                    "n": np.zeros(0, np.int64)}}
+
+
+@pytest.mark.parametrize("name", SHARD_METRICS)
+def test_a_shard_reader_is_found_and_reads_nothing_where_nothing_is(name):
+    """A program without the counters or the phase — the parent commit —
+    gives the reader nothing to read, with a profile or without: it
+    returns None and does not raise."""
+    from benchmark.harness import plugins
+
+    entry = next(m for m in _manifest()["per_layer"] if m["name"] == name)
+    assert entry["workloads"] == SHARD_METRICS[name]
+    read = plugins.load("layer_metrics", name).read
+    assert read(_nothing()) is None
+    # the parent's scrapes and a profile that HOLDS kernel calls
+    waves = {'gubernator_wave_route_total{route="sorted"}': 50.0,
+             "gubernator_dispatcher_wave_size_sum": 4e5,
+             "gubernator_dispatcher_wave_size_count": 50.0}
+    ctx = dict(_nothing(), m1=waves, tm1=waves, _shard_kernel=(0.2, 200))
+    assert read(ctx) is None
+
+
+def test_shard_cost_and_its_readers_on_a_hand_made_wave_and_profile(
+        tmp_path):
+    from benchmark.harness import plugins, shard_cost, tracered
+
+    assert shard_cost.BUCKET_BYTES == 8192
+    # two waves of two 4-row calls: keys {1,2,3} and {7,8,9,1}
+    keys = np.array([1, 1, 2, 3, 3, 3, 2, 1,
+                     7, 8, 9, 9, 1, 1, 1, 1], np.int64)
+    n = np.array([4, 4, 4, 4], np.int64)
+    assert shard_cost.distinct_keys_per_row(keys, n, 8.0) == 7 / 16
+    assert shard_cost.wave_bytes_per_row(keys, n, 8.0) == 2 * 8192 * 7 / 16
+    # one call a wave: a key counts once a call
+    assert shard_cost.wave_bytes_per_row(keys, n, 4.0) == 2 * 8192 * 10 / 16
+    assert shard_cost.wave_bytes_per_row(keys[:3], n, 8.0) == 0.0
+    assert shard_cost.wave_bytes_per_row(keys, n, None) == 0.0
+    # the profile: two device waves on four planes with UNEVEN kernel
+    # times (the densest shard's chip works longest), another op, a
+    # module line and a host plane
+    kern = '%_step.1 = custom-call(), custom_call_target="tpu_custom_call"'
+    times = {0: (9e6, 8e6), 1: (3e6, 2e6), 2: (2e6, 3e6), 3: (1e6, 4e6)}
+    rows = [[f"/device:TPU:{d}", "XLA Ops", kern, 1e7 * i, t]
+            for d, ts in times.items() for i, t in enumerate(ts)]
+    rows += [["/device:TPU:0", "XLA Ops", "%fusion = fusion(", 5e7, 1e6],
+             ["/device:TPU:1", "XLA Modules", "jit__step(1)", 0.0, 4e6],
+             ["/host:CPU", "python3", kern, 0.0, 7e6]]
+    old, tracered.load_xplane = tracered.load_xplane, lambda d: rows
+    try:
+        ctx = {"trace_dir": str(tmp_path)}
+        assert shard_cost.kernel_planes(ctx) == (32e-3, 8)
+    finally:
+        tracered.load_xplane = old
+    # the scrapes: 10 device waves between the profile's, each 4 × 8
+    # slots for 8 rows, 5 on the densest shard; 20 over the window
+    route = 'gubernator_wave_route_total{route="sorted"}'
+    size = "gubernator_dispatcher_wave_size"
+    pack = 'gubernator_phase_duration_%s{phase="local.pack"}'
+    zero = {route: 0.0, shard_cost.SLOTS: 0.0, shard_cost.ROUTED_ROWS: 0.0,
+            shard_cost.DENSEST_ROWS: 0.0, size + "_sum": 0.0,
+            size + "_count": 0.0, pack % "sum": 0.0, pack % "count": 0.0}
+    at = lambda k: {route: k, shard_cost.SLOTS: 32.0 * k,  # noqa: E731
+                    shard_cost.ROUTED_ROWS: 8.0 * k,
+                    shard_cost.DENSEST_ROWS: 5.0 * k, size + "_sum": 8.0 * k,
+                    size + "_count": k, pack % "sum": 0.003 * k,
+                    pack % "count": 2.0 * k}
+    ctx.update(m0=zero, m1=at(20.0), tm0=at(5.0), tm1=at(15.0),
+               device_kind="TPU v5 lite", config={"chips": 4},
+               rec={"key_index": keys, "n": n})
+    read = lambda name: plugins.load("layer_metrics", name).read(ctx)  # noqa: E731
+    assert read("shard_pad_share") == pytest.approx(75.0)
+    assert read("shard_skew") == pytest.approx(5 * 4 / 8)
+    assert read("local_pack_ms") == pytest.approx(1.5)
+    # 32 ms of kernel over 8 calls of 8 slots a shard
+    assert read("shard_kernel_ns_per_slot") == pytest.approx(32e6 / (8 * 8))
+    # 8 calls ÷ 4 chips = 2 device waves of 8 rows, 7 distinct keys in 16
+    least_s = 2 * 8192 * (7 / 16) * 8 * 2 / 819e9
+    assert read("shard_kernel_roofline") == pytest.approx(
+        100 * least_s / 32e-3)
+    assert read("shard_kernel_roofline") < 100

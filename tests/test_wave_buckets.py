@@ -31,8 +31,9 @@ class TestBuildWaves:
         kh = rng.integers(1, 2**64, size=40, dtype=np.uint64)
         waves = eng._build_waves(kh, np.arange(40))
         assert len(waves) == 1
-        idx, slots, bw = waves[0]
+        idx, slots, bw, densest = waves[0]
         assert bw == eng.wave_buckets[0]
+        assert densest == np.bincount(shard_of(kh, eng.n)).max()
         assert sorted(idx.tolist()) == list(range(40))
         assert slots.max() < eng.n * bw
 
@@ -53,7 +54,8 @@ class TestBuildWaves:
         n = eng.n * eng.wave_buckets[-1] + 200
         kh = rng.integers(1, 2**64, size=n, dtype=np.uint64)
         covered = set()
-        for idx, slots, bw in eng._build_waves(kh, np.arange(n)):
+        for idx, slots, bw, densest in eng._build_waves(kh, np.arange(n)):
+            assert densest == np.bincount(slots // bw).max()
             assert len(np.unique(slots)) == len(slots)
             assert slots.min() >= 0 and slots.max() < eng.n * bw
             # slot's shard block must match the key's shard
@@ -68,7 +70,7 @@ class TestBuildWaves:
         kh0 = keys_for_shard(eng, 0, 150, rng)  # one hot shard
         waves = eng._build_waves(kh0, np.arange(150))
         seen = []
-        for idx, slots, bw in waves:
+        for idx, slots, bw, _ in waves:
             order = np.argsort(slots)
             seen.extend(idx[order].tolist())
         assert seen == list(range(150))
@@ -81,4 +83,5 @@ class TestBuildWaves:
                              keys_for_shard(eng, 1, 5, rng)])
         waves = eng._build_waves(kh, np.arange(95))
         assert len(waves) == 1
-        assert waves[0][2] == next(b for b in eng.wave_buckets if b >= 90)
+        assert waves[0][2:] == (next(b for b in eng.wave_buckets if b >= 90),
+                                90)

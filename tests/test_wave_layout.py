@@ -123,7 +123,7 @@ def parent_uploads(eng, batch, khash, mslot):
              else np.argsort(now, kind="stable"))
     cols = batch._replace(valid=valid)
     out = []
-    for idx, slots, bw in eng._build_waves(khash, order):
+    for idx, slots, bw, _ in eng._build_waves(khash, order):
         a64 = np.zeros((8, eng.n * bw), np.int64)
         a32 = np.zeros((3, eng.n * bw), np.int32)
         a64[PACK64.index("eff_ms")] = 1
@@ -386,7 +386,7 @@ def disp_job(disp, batch, khash, now, mslot=None):
     caller's thread)."""
     from gubernator_tpu.dispatcher import _PackedJob
 
-    return _PackedJob(disp._lay_out(batch, khash, mslot), khash, now,
+    return _PackedJob(disp.lay_out(batch, khash, mslot), khash, now,
                       mslot=mslot)
 
 

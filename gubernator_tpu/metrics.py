@@ -160,6 +160,13 @@ class Metrics:
             "chose the wave's bucket; x shards / routed rows is the "
             "skew, 1.0 an even wave (one shard: the rows themselves)",
             registry=r)
+        self.wave_native_route = Counter(
+            "gubernator_wave_native_route",
+            "sorted-route device waves the C++ extension planned and "
+            "filled (ops/_native.cpp: route_plan, route_fill), one pass "
+            "each that keeps the GIL; the rest of "
+            'gubernator_wave_route{route="sorted"} took the numpy route',
+            registry=r)
         self.sweeps = Counter(
             "gubernator_sweep",
             "whole-table expiry sweeps by cause (instance._maybe_sweep, "

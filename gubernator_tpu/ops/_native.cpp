@@ -13,6 +13,8 @@
 //   build_rate_limit_resps(...) -> bytes            packed columns -> wire
 //   build_responses_from_columns(...) -> bytes      shared-column rows
 //                                                   [lo, hi) -> wire
+//   route_plan(...) / route_fill(...)               a wave's rows routed
+//                                                   by shard, into its pair
 //
 // The avalanche finalizer stays in Python/numpy (hashing.mix64_np) so
 // there is exactly one source of truth for it.
@@ -565,6 +567,246 @@ static PyObject* derive_rows(PyObject*, PyObject* args) {
   PyBuffer_Release(&b32);
   if (has_ms) PyBuffer_Release(&bms);
   return out;
+}
+
+// ---- the shard route of a device wave (parallel/sharded.py) ------------
+//
+// route_plan and route_fill are ShardedEngine._build_waves and ._fill in
+// one pass each that KEEPS the GIL: the dispatch worker — the one thread
+// the devices wait for — ran ~35 numpy calls a device wave there, most
+// of which give the GIL up and have to win it back from ~30 handler
+// threads (PERF.md §6, PR 36).  The numpy pair stays in sharded.py as
+// the path of a checkout without this extension and as the reference
+// tests/test_wave_route_native.py holds these two to, byte for byte.
+
+// A buffer argument held for the length of a call.
+struct Buf {
+  Py_buffer b;
+  bool held = false;
+  bool get(PyObject* o, bool writable = false) {
+    int flags = PyBUF_STRIDES | PyBUF_FORMAT | (writable ? PyBUF_WRITABLE : 0);
+    held = PyObject_GetBuffer(o, &b, flags) == 0;
+    return held;
+  }
+  // an integer (or, one byte wide, a bool) array of this item size
+  bool ints(Py_ssize_t itemsize) const {
+    const char* f = b.format ? b.format : "B";
+    if (*f == '@' || *f == '=' || *f == '<') f++;
+    return b.itemsize == itemsize && f[0] && !f[1] &&
+           strchr(itemsize == 1 ? "?bB" : "hHiIlLqQ", f[0]);
+  }
+  // [n] of that, any stride
+  bool vec(Py_ssize_t itemsize) const { return b.ndim == 1 && ints(itemsize); }
+  // [n] of that, one after the other
+  bool packed(Py_ssize_t itemsize) const {
+    return vec(itemsize) && (b.shape[0] < 2 || b.strides[0] == itemsize);
+  }
+  // [rows, n] of that, each row contiguous (any row stride: a view of
+  // a wider pair will do)
+  bool matrix(Py_ssize_t rows, Py_ssize_t itemsize) const {
+    return b.ndim == 2 && ints(itemsize) && b.shape[0] == rows &&
+           (b.shape[1] < 2 || b.strides[1] == itemsize);
+  }
+  char* row(int r) const { return (char*)b.buf + r * b.strides[0]; }
+  ~Buf() {
+    if (held) PyBuffer_Release(&b);
+  }
+};
+
+// route_plan(khash u64[N], pending i64[k] | None, shards, buckets) ->
+//   [(idx i64le bytes, slots i64le bytes, bw_w, wcnt), ...]
+// The device waves of rows `pending` (None = every row, in row order):
+// a row's shard is hashing.shard_of (((h >> 32) * shards) >> 32), its
+// position the count of earlier `pending` rows of its shard (a counting
+// sort IS the stable sort), its device wave position / the largest
+// bucket; a wave rides bw_w, the smallest bucket covering wcnt, the rows
+// of its densest shard, and lists its rows by shard, then position —
+// the order a stable argsort by shard lists them — each with its slot,
+// shard * bw_w + position % the largest bucket.  buckets: ascending.
+static PyObject* route_plan(PyObject*, PyObject* args) {
+  // the tables below hold a few words a shard: no mesh comes near this
+  const Py_ssize_t MAX_SHARDS = 1 << 16;
+  PyObject *okh, *opend, *obuckets;
+  Py_ssize_t shards;
+  if (!PyArg_ParseTuple(args, "OOnO", &okh, &opend, &shards, &obuckets))
+    return nullptr;
+  Buf kh, pend;
+  bool all = opend == Py_None;
+  if (!kh.get(okh) || (!all && !pend.get(opend))) return nullptr;
+  std::vector<int64_t> buckets;
+  PyObject* seq = PySequence_Fast(obuckets, "route_plan wants buckets");
+  if (!seq) return nullptr;
+  for (Py_ssize_t i = 0; i < PySequence_Fast_GET_SIZE(seq); i++)
+    buckets.push_back(PyLong_AsLongLong(PySequence_Fast_GET_ITEM(seq, i)));
+  Py_DECREF(seq);
+  if (PyErr_Occurred()) return nullptr;
+  bool ascending = !buckets.empty() && buckets[0] > 0;
+  for (size_t i = 1; i < buckets.size(); i++)
+    ascending = ascending && buckets[i] > buckets[i - 1];
+  if (!kh.vec(8) || (!all && !pend.packed(8)) || shards < 1 ||
+      shards > MAX_SHARDS || !ascending) {
+    PyErr_SetString(PyExc_ValueError,
+                    "route_plan wants a 64-bit khash[N], a contiguous "
+                    "i64 pending[k] or None, 1..65536 shards and "
+                    "ascending positive buckets");
+    return nullptr;
+  }
+  const Py_ssize_t N = kh.b.shape[0], k = all ? N : pend.b.shape[0];
+  const int64_t* pending = all ? nullptr : (const int64_t*)pend.b.buf;
+  const int64_t Bw = buckets.back();
+  // pass 1: every row's shard, and the rows of each shard
+  std::vector<int32_t> shard(k);
+  std::vector<int64_t> cnt(shards, 0);
+  int64_t densest = 0;
+  for (Py_ssize_t j = 0; j < k; j++) {
+    int64_t i = pending ? pending[j] : j;
+    if (i < 0 || i >= N) {
+      PyErr_SetString(PyExc_IndexError, "route_plan: pending row not in khash");
+      return nullptr;
+    }
+    uint64_t h =
+        *(const uint64_t*)((const char*)kh.b.buf + i * kh.b.strides[0]);
+    int32_t s = (int32_t)(((h >> 32) * (uint64_t)shards) >> 32);
+    shard[j] = s;
+    if (++cnt[s] > densest) densest = cnt[s];
+  }
+  // each wave's bucket, densest shard and where its shards' runs start
+  const Py_ssize_t W = (Py_ssize_t)((densest + Bw - 1) / Bw);
+  std::vector<int64_t> start(W * shards), bw(W), wcnt(W, 0);
+  std::vector<int64_t*> idx(W), slots(W);
+  PyObject* plan = PyList_New(W);
+  if (!plan) return nullptr;
+  for (Py_ssize_t w = 0; w < W; w++) {
+    int64_t rows = 0;
+    for (Py_ssize_t s = 0; s < shards; s++) {
+      int64_t c = cnt[s] - w * Bw;
+      c = c < 0 ? 0 : c > Bw ? Bw : c;
+      start[w * shards + s] = rows;
+      rows += c;
+      if (c > wcnt[w]) wcnt[w] = c;
+    }
+    bw[w] = Bw;
+    for (size_t b = buckets.size(); b-- > 0 && wcnt[w] <= buckets[b];)
+      bw[w] = buckets[b];
+    PyObject* bi = PyBytes_FromStringAndSize(nullptr, rows * 8);
+    PyObject* bs = PyBytes_FromStringAndSize(nullptr, rows * 8);
+    PyObject* t = bi && bs ? Py_BuildValue("(OOLL)", bi, bs,
+                                           (long long)bw[w],
+                                           (long long)wcnt[w])
+                           : nullptr;
+    Py_XDECREF(bi);
+    Py_XDECREF(bs);
+    if (!t) {
+      Py_DECREF(plan);
+      return nullptr;
+    }
+    PyList_SET_ITEM(plan, w, t);
+    idx[w] = (int64_t*)PyBytes_AS_STRING(bi);
+    slots[w] = (int64_t*)PyBytes_AS_STRING(bs);
+  }
+  // pass 2: every row to its place in its wave's lists
+  std::vector<int64_t> seen(shards, 0);
+  for (Py_ssize_t j = 0; j < k; j++) {
+    int32_t s = shard[j];
+    int64_t p = seen[s]++, w = p / Bw, r = p - w * Bw;
+    int64_t at = start[w * shards + s] + r;
+    idx[w][at] = pending ? pending[j] : j;
+    slots[w][at] = s * bw[w] + r;
+  }
+  return plan;
+}
+
+// One row of an upload matrix: `get(i)` of row idx[j] at slots[j], `pad`
+// in every other slot — each of the m cells written once.  slots:
+// strictly ascending, below m.
+template <class T, class Get>
+static inline void lay_row(T* dst, Py_ssize_t m, const int64_t* idx,
+                           const int64_t* slots, Py_ssize_t k, T pad,
+                           Get get) {
+  Py_ssize_t at = 0;
+  for (Py_ssize_t j = 0; j < k; j++) {
+    for (Py_ssize_t s = slots[j]; at < s;) dst[at++] = pad;
+    dst[at++] = get(idx[j]);
+  }
+  while (at < m) dst[at++] = pad;
+}
+
+// route_fill(m64 [8,N] i64, m32 [3,N] i32, valid bool[N] | None,
+//            mslot i32[N] | None, idx i64[k], slots i64[k],
+//            a64 [8,m] i64, a32 [3,m] i32, mblk i32[m] | None) -> None
+// One device wave of route_plan into its upload pair: rows idx of the
+// joined matrices at slots, eleven words each — `valid` in place of the
+// rows' own where given —, every other slot empty_batch padding (zeros,
+// eff_ms 1), and mslot's lane into mblk (-1 outside the rows; given
+// exactly when mslot is).  EVERY cell of a64, a32 and mblk is written,
+// as pack_wire_wave writes its pair's: uninitialised memory will do.
+// slots: strictly ascending, as route_plan lists them.  A wrong argument
+// raises before anything is written.
+static PyObject* route_fill(PyObject*, PyObject* args) {
+  PyObject *o64, *o32, *ovalid, *omslot, *oidx, *oslots, *oa64, *oa32, *omblk;
+  if (!PyArg_ParseTuple(args, "OOOOOOOOO", &o64, &o32, &ovalid, &omslot,
+                        &oidx, &oslots, &oa64, &oa32, &omblk))
+    return nullptr;
+  Buf m64, m32, valid, mslot, idx, slots, a64, a32, mblk;
+  bool has_valid = ovalid != Py_None, has_ms = omslot != Py_None;
+  if (!m64.get(o64) || !m32.get(o32) || (has_valid && !valid.get(ovalid)) ||
+      (has_ms && !mslot.get(omslot)) || !idx.get(oidx) ||
+      !slots.get(oslots) || !a64.get(oa64, true) || !a32.get(oa32, true) ||
+      (omblk != Py_None && !mblk.get(omblk, true)))
+    return nullptr;
+  if (!m64.matrix(8, 8) || !m32.matrix(3, 4) || !a64.matrix(8, 8) ||
+      !a32.matrix(3, 4) || !idx.packed(8) || !slots.packed(8) ||
+      m32.b.shape[1] != m64.b.shape[1] || a32.b.shape[1] != a64.b.shape[1] ||
+      slots.b.shape[0] != idx.b.shape[0] ||
+      (has_valid && !(valid.vec(1) && valid.b.shape[0] == m64.b.shape[1])) ||
+      (has_ms && !(mslot.packed(4) && mslot.b.shape[0] == m64.b.shape[1])) ||
+      has_ms != mblk.held ||
+      (has_ms && !(mblk.packed(4) && mblk.b.shape[0] == a64.b.shape[1]))) {
+    PyErr_SetString(PyExc_ValueError,
+                    "route_fill wants [8,N] i64 + [3,N] i32 rows, "
+                    "bool[N] | None, i32[N] | None, i64[k] idx and slots, "
+                    "a writable [8,m] i64 + [3,m] i32 pair (rows "
+                    "contiguous) and an i32[m] mblk with mslot alone");
+    return nullptr;
+  }
+  const Py_ssize_t N = m64.b.shape[1], m = a64.b.shape[1],
+                   k = idx.b.shape[0];
+  const int64_t* ix = (const int64_t*)idx.b.buf;
+  const int64_t* sl = (const int64_t*)slots.b.buf;
+  int64_t below = -1;
+  for (Py_ssize_t j = 0; j < k; j++) {
+    if (ix[j] < 0 || ix[j] >= N || sl[j] <= below || sl[j] >= m) {
+      PyErr_SetString(PyExc_IndexError,
+                      "route_fill: a row outside the wave, or slots not "
+                      "strictly ascending inside the pair");
+      return nullptr;
+    }
+    below = sl[j];
+  }
+  const int EFF = 4, VALID = 2;  // core/batch.py › PACK64, PACK32
+  for (int r = 0; r < 8; r++) {
+    const int64_t* src = (const int64_t*)m64.row(r);
+    lay_row((int64_t*)a64.row(r), m, ix, sl, k, (int64_t)(r == EFF),
+            [src](int64_t i) { return src[i]; });
+  }
+  for (int r = 0; r < 3; r++) {
+    const int32_t* src = (const int32_t*)m32.row(r);
+    if (r == VALID && has_valid) {
+      const char* v = (const char*)valid.b.buf;
+      Py_ssize_t step = valid.b.strides[0];
+      lay_row((int32_t*)a32.row(r), m, ix, sl, k, (int32_t)0,
+              [v, step](int64_t i) { return (int32_t)(v[i * step] != 0); });
+    } else {
+      lay_row((int32_t*)a32.row(r), m, ix, sl, k, (int32_t)0,
+              [src](int64_t i) { return src[i]; });
+    }
+  }
+  if (has_ms) {
+    const int32_t* src = (const int32_t*)mslot.b.buf;
+    lay_row((int32_t*)mblk.b.buf, m, ix, sl, k, (int32_t)-1,
+            [src](int64_t i) { return src[i]; });
+  }
+  Py_RETURN_NONE;
 }
 
 // pack_wire_wave(data, now_ms, a64, a32, m,
@@ -1234,6 +1476,11 @@ static PyMethodDef methods[] = {
     {"derive_rows", derive_rows, METH_VARARGS,
      "What a launch needs to know of rows in the upload layout: "
      "out-of-domain rows, leaky rows, the clocks"},
+    {"route_plan", route_plan, METH_VARARGS,
+     "The device waves of a wave's rows, routed by shard: each wave's "
+     "row indices, slots, bucket and densest shard"},
+    {"route_fill", route_fill, METH_VARARGS,
+     "One routed device wave into its upload pair, every cell written"},
     {"stamp_req_tlvs", stamp_req_tlvs, METH_VARARGS,
      "Join request TLV slices, appending created_at (field 10) where "
      "unset — the forward hop's caller-clock stamp"},

@@ -12,6 +12,14 @@ import numpy as np
 
 from . import _native  # ImportError here means: run `make native`
 
+if not hasattr(_native, "route_fill"):
+    # NOT an ImportError: every importer reads that as "no extension"
+    # and falls back to numpy — a build older than _native.cpp is a
+    # broken checkout, not an optional feature switched off
+    raise RuntimeError(
+        f"{_native.__file__} is older than _native.cpp (no route_plan / "
+        "route_fill): rebuild it with `make native`")
+
 
 def hash_keys(keys: Sequence[str]) -> np.ndarray:
     """Raw FNV-1a64 of each key string → uint64[n]."""
@@ -144,6 +152,35 @@ def derive_rows(m64: np.ndarray, m32: np.ndarray, mslot=None,
     if mslot is not None:
         mslot = np.ascontiguousarray(mslot, np.int32)
     return _derived(_native.derive_rows(m64, m32, mslot, vb, eb))
+
+
+def route_plan(khash: np.ndarray, pending, shards: int, buckets):
+    """``ShardedEngine._build_waves`` in one pass that keeps the GIL:
+    the device waves of rows ``pending`` (i64[k]; None = every row of
+    ``khash`` in row order) as [(idx, slots, bw_w, wcnt)] — row indices
+    and block slots (read-only i64), the wave's bucket and the rows of
+    its densest shard.  ``buckets``: ascending."""
+    return [(np.frombuffer(idx, "<i8"), np.frombuffer(slots, "<i8"), bw_w,
+             wcnt)
+            for idx, slots, bw_w, wcnt in _native.route_plan(
+                khash, pending, shards, buckets)]
+
+
+def route_fill(m64: np.ndarray, m32: np.ndarray, valid, mslot,
+               idx: np.ndarray, slots: np.ndarray, a64: np.ndarray,
+               a32: np.ndarray, mblk=None) -> None:
+    """``ShardedEngine._fill`` in one pass that keeps the GIL: rows
+    ``idx`` of the joined matrices (``m64`` [8, N] i64, ``m32`` [3, N]
+    i32) at ``slots`` (strictly ascending, as ``route_plan`` lists
+    them) of the upload pair (``a64`` [8, m], ``a32`` [3, m]), ``valid``
+    (bool[N]) in place of the rows' own where given, padding in every
+    other slot, and ``mslot``'s lane (i32[N]) into ``mblk`` (i32[m], -1
+    outside the rows; given exactly when ``mslot`` is).  EVERY cell of
+    the pair and of ``mblk`` is written — lease the pair with
+    ``rows=m``; a wrong argument raises before any is."""
+    if mslot is not None:
+        mslot = np.ascontiguousarray(mslot, np.int32)
+    _native.route_fill(m64, m32, valid, mslot, idx, slots, a64, a32, mblk)
 
 
 def split_resp_items(data: bytes):

@@ -604,16 +604,27 @@ class ShardedEngine:
             idx = self._first(len(wave))
             yield idx, idx, wave.lease, wave.mblk
             return
+        # with the C++ extension the plan and the fill are ONE pass
+        # each that keeps the GIL (ops/_native.cpp › route_plan,
+        # route_fill); _build_waves and _fill are the same route in
+        # numpy, for a checkout without it
+        native = _wire_native is not None
         with phase("wave.route"):
-            if pending is None:
-                pending = (self._first(len(wave)) if wave.monotone
-                           else np.argsort(wave.now, kind="stable"))
-            plan = self._build_waves(khash, pending)
+            if pending is None and not wave.monotone:
+                pending = np.argsort(wave.now, kind="stable")
+            if native:
+                plan = _wire_native.route_plan(khash, pending, self.n,
+                                               self.wave_buckets)
+            else:
+                plan = self._build_waves(
+                    khash, self._first(len(wave)) if pending is None
+                    else pending)
+        fill = self._fill_native if native else self._fill
         for idx, slots, bw_w, wcnt in plan:
             with phase("wave.fill"):
-                lease, mblk = self._fill(wave, mslot, valid, idx, slots,
-                                         bw_w)
-            self._count_route("sorted", self.n * bw_w, len(idx), wcnt)
+                lease, mblk = fill(wave, mslot, valid, idx, slots, bw_w)
+            self._count_route("sorted", self.n * bw_w, len(idx), wcnt,
+                              native)
             yield idx, slots, lease, mblk
 
     def _build_waves(self, khash: np.ndarray, pending: np.ndarray):
@@ -664,6 +675,17 @@ class ShardedEngine:
         if mslot is not None:
             mblk = np.full(self.n * bw_w, -1, np.int32)
             mblk[slots] = np.asarray(mslot)[idx]
+        return lease, mblk
+
+    def _fill_native(self, wave: Rows, mslot, valid, idx, slots, bw_w):
+        """``_fill`` as one C++ pass that writes EVERY cell of the pair
+        — the rows, the padding between them, ``mblk`` — so the pool
+        clears nothing (``rows=m``: the caller overwrites it all)."""
+        m = self.n * bw_w
+        lease = self.wave_pool.lease(m, rows=m)
+        mblk = None if mslot is None else np.empty(m, np.int32)
+        _wire_native.route_fill(wave.m64, wave.m32, valid, mslot, idx,
+                                slots, lease.a64, lease.a32, mblk)
         return lease, mblk
 
     def launch_packed(self, batch: RequestBatch, khash: np.ndarray,
@@ -721,7 +743,7 @@ class ShardedEngine:
                 wave.ood)
 
     def _count_route(self, route: str, slots: int, rows: int,
-                     densest: int) -> None:
+                     densest: int, native: bool = False) -> None:
         """``gubernator_wave_route_total{route}``: one device wave that
         was joined straight into its lease ("identity") or routed by
         shard and scattered ("sorted") — and what it cost: the slots it
@@ -730,10 +752,13 @@ class ShardedEngine:
         (``gubernator_wave_routed_rows_total``) and the rows of its
         densest shard (``gubernator_wave_densest_shard_rows_total``:
         what chose its bucket).  Plain integers the route already
-        holds."""
+        holds.  ``native``: the C++ pass planned and filled it
+        (``gubernator_wave_native_route_total``)."""
         m = self.metrics_ref
         if m is not None:
             m.wave_route.labels(route=route).inc()
+            if native:
+                m.wave_native_route.inc()
             m.wave_slots.inc(slots)
             m.wave_routed_rows.inc(rows)
             m.wave_densest_shard_rows.inc(densest)

@@ -167,10 +167,13 @@ PHASE_CATALOG: Dict[str, str] = {
                    "the identity)",
     "lock.engine": "waiting to acquire the engine lock",
     "wave.route": "engine: tier mask, leaky rows counted; where the "
-                  "wave is not in its lease yet: arrival order, "
-                  "_build_waves",
-    "wave.fill": "engine: _fill scatters the joined rows into the leased "
-                 "upload buffers (identity route: marks invalid rows)",
+                  "wave is not in its lease yet: arrival order and the "
+                  "plan of its device waves by shard (one C++ pass, "
+                  "route_plan; without the extension _build_waves)",
+    "wave.fill": "engine: a device wave's rows into the leased upload "
+                 "buffers (one C++ pass that writes every cell, "
+                 "route_fill; without the extension _fill scatters "
+                 "them; identity route: marks invalid rows)",
     "lock.xla_exec": "engine: waiting to acquire XLA_EXEC_MU",
     "lock.mesh_state": "mesh-GLOBAL tier: a fused launch waiting for "
                        "the tier's state lock (fold tick, pins)",

@@ -265,3 +265,51 @@ def test_shard_cost_and_its_readers_on_a_hand_made_wave_and_profile(
     assert read("shard_kernel_roofline") == pytest.approx(
         100 * least_s / 32e-3)
     assert read("shard_kernel_roofline") < 100
+
+
+# ---- ISSUE 36: the share of sorted waves the C++ extension routed -------
+
+_SORTED = 'gubernator_wave_route_total{route="sorted"}'
+_IDENT = 'gubernator_wave_route_total{route="identity"}'
+_NATIVE = "gubernator_wave_native_route_total"
+
+#: case → (first scrape, second scrape, what the reader gives)
+NATIVE_SHARE = {
+    "no_scrape_at_all": ({}, {}, None),
+    # the parent commit: sorted waves, no such counter
+    "sorted_waves_without_the_counter": (
+        {_SORTED: 10.0}, {_SORTED: 60.0}, None),
+    # a one-shard cell: the counter is there, no wave was sorted
+    "the_counter_without_a_sorted_wave": (
+        {_NATIVE: 0.0, _SORTED: 0.0, _IDENT: 5.0},
+        {_NATIVE: 0.0, _SORTED: 0.0, _IDENT: 55.0}, None),
+    "no_sorted_wave_inside_the_window": (
+        {_NATIVE: 7.0, _SORTED: 7.0}, {_NATIVE: 7.0, _SORTED: 7.0}, None),
+    "every_sorted_wave": (
+        {_NATIVE: 3.0, _SORTED: 3.0, _IDENT: 9.0},
+        {_NATIVE: 53.0, _SORTED: 53.0, _IDENT: 9.0}, 100.0),
+    # 50 sorted waves in the window, 40 of them the extension's; the
+    # identity waves beside them count for nothing
+    "four_sorted_waves_in_five": (
+        {_NATIVE: 10.0, _SORTED: 10.0, _IDENT: 0.0},
+        {_NATIVE: 50.0, _SORTED: 60.0, _IDENT: 50.0}, 80.0),
+    # a checkout without the extension serves the numpy route
+    "the_numpy_route": (
+        {_NATIVE: 0.0, _SORTED: 0.0}, {_NATIVE: 0.0, _SORTED: 25.0}, 0.0),
+}
+
+
+@pytest.mark.parametrize("case", NATIVE_SHARE)
+def test_wave_native_route_share_on_a_hand_made_pair_of_scrapes(case):
+    from benchmark.harness import plugins
+
+    entry = next(m for m in _manifest()["per_layer"]
+                 if m["name"] == "wave_native_route_share")
+    assert entry == {
+        "name": "wave_native_route_share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "engine",
+        "moves": "decisions_per_s", "workloads": [R4_CELL, G4_CELL]}
+    m0, m1, want = NATIVE_SHARE[case]
+    got = plugins.load("layer_metrics", "wave_native_route_share").read(
+        dict(_nothing(), m0=m0, m1=m1))
+    assert got is None if want is None else got == pytest.approx(want)

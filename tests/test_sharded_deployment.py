@@ -24,7 +24,7 @@ from gubernator_tpu.daemon import spawn_daemon
 from gubernator_tpu.hashing import shard_of
 from gubernator_tpu.metrics import Metrics
 from gubernator_tpu.netutil import free_port
-from gubernator_tpu.parallel import ShardedEngine, make_mesh
+from gubernator_tpu.parallel import ShardedEngine, make_mesh, sharded
 from gubernator_tpu.parallel.pallas_engine import PallasServingEngine
 
 CELL = "r4-zipf-b1000-sat"
@@ -207,7 +207,7 @@ def counted(eng) -> tuple:
     return tuple(int(c._value.get()) for c in (
         m.wave_slots, m.wave_routed_rows, m.wave_densest_shard_rows,
         m.wave_route.labels(route="sorted"),
-        m.wave_route.labels(route="identity")))
+        m.wave_route.labels(route="identity"), m.wave_native_route))
 
 
 def check(eng, kh):
@@ -221,7 +221,7 @@ def check(eng, kh):
     return tuple(a - b for a, b in zip(counted(eng), before))
 
 
-def test_the_route_counters_hold_to_a_hand_made_wave(cpu_mesh):
+def test_the_route_counters_hold_to_a_hand_made_wave(cpu_mesh, monkeypatch):
     four = ShardedEngine(cpu_mesh, capacity_per_shard=1 << 10,
                          batch_per_shard=16, wave_buckets=(16, 64))
     four.metrics_ref = Metrics()
@@ -229,20 +229,25 @@ def test_the_route_counters_hold_to_a_hand_made_wave(cpu_mesh):
     # bucket — 4 × 16 slots for 16 rows
     kh = np.concatenate([keys_on(s, c, 4, 7) for s, c in
                          enumerate((10, 3, 2, 1))])
-    assert check(four, kh) == (4 * 16, 16, 10, 1, 0)
+    # — and the C++ extension planned and filled it (ISSUE 36)
+    assert check(four, kh) == (4 * 16, 16, 10, 1, 0, 1)
     # 40 on shard 2: the large bucket — 4 × 64 slots for 46 rows
     kh = np.concatenate([keys_on(s, c, 4, 11) for s, c in
                          enumerate((3, 2, 40, 1))])
-    assert check(four, kh) == (4 * 64, 46, 40, 1, 0)
+    assert check(four, kh) == (4 * 64, 46, 40, 1, 0, 1)
     # 70 on shard 1 overflow the largest bucket: a full large wave, then
     # the 6 rows left in the small one
     kh = np.concatenate([keys_on(s, c, 4, 13) for s, c in
                          enumerate((2, 70, 1, 1))])
-    assert check(four, kh) == (4 * 64 + 4 * 16, 74, 64 + 6, 2, 0)
+    assert check(four, kh) == (4 * 64 + 4 * 16, 74, 64 + 6, 2, 0, 2)
     # one shard, clocks in order: the identity route — the lease is the
     # bucket, the rows are their shard's
     one = ShardedEngine(make_mesh(n=1), capacity_per_shard=1 << 10,
                         batch_per_shard=16, wave_buckets=(16, 64))
     one.metrics_ref = Metrics()
-    assert check(one, keys_on(0, 10, 1, 17)) == (16, 10, 10, 0, 1)
-    assert check(one, keys_on(0, 40, 1, 19)) == (64, 40, 40, 0, 1)
+    assert check(one, keys_on(0, 10, 1, 17)) == (16, 10, 10, 0, 1, 0)
+    assert check(one, keys_on(0, 40, 1, 19)) == (64, 40, 40, 0, 1, 0)
+    # a checkout without the extension routes the same waves in numpy:
+    # sorted, not native
+    monkeypatch.setattr(sharded, "_wire_native", None)
+    assert check(four, kh) == (4 * 64 + 4 * 16, 74, 64 + 6, 2, 0, 0)

@@ -355,11 +355,20 @@ def test_the_ingest_derives_what_lay_out_derives(kind, domain, monkeypatch):
 #: ``launch_packed`` for one identity-route wave: three joins (a64, a32,
 #: khash) and the launch's scalar clock make 4.  The parent made ~70
 #: (12 concatenates, ~17 for the mask, ~15 to route, 22 scatters).
-CEILING = 6
+#: On four shards the wave takes the sorted route, planned and filled by
+#: the C++ extension (ISSUE 36): the joins, the two views of the plan's
+#: lists, the sharded put of the pair and the token's batch views make
+#: 9 call events.  The numpy route made 16 — and ~25 array operators
+#: (shifts, gathers, ``==``, ``%``, scatters) this profiler cannot see,
+#: each of which the C++ pass took with them.
+CEILING = {1: 6, 4: 9}
 
 
-def test_worker_numpy_calls_do_not_grow_with_jobs(numpy_calls):
-    eng = ShardedEngine(make_mesh(n=1), capacity_per_shard=1 << 10,
+@pytest.mark.parametrize("shards", CEILING)
+def test_worker_numpy_calls_do_not_grow_with_jobs(numpy_calls, shards,
+                                                  cpu_mesh):
+    eng = ShardedEngine(cpu_mesh if shards == 4 else make_mesh(n=shards),
+                        capacity_per_shard=1 << 10,
                         batch_per_shard=64, wave_buckets=(64, 512))
     eng.warmup()  # a first launch traces and compiles: not the worker's
     disp = Dispatcher(eng)
@@ -373,10 +382,11 @@ def test_worker_numpy_calls_do_not_grow_with_jobs(numpy_calls):
                 batch, kh, ms, now = disp._concat_jobs(packed)
                 token = eng.launch_packed(batch, kh, now)
             counts[n_jobs] = calls.n
-            assert batch.rows.lease is not None  # the identity arm
+            # one shard: the identity arm; four: routed by shard
+            assert (batch.rows.lease is not None) == (shards == 1)
             eng.sync_packed(token)
             eng.drop_packed(token)
-        assert counts[2] == counts[8] <= CEILING, counts
+        assert counts[2] == counts[8] <= CEILING[shards], counts
     finally:
         disp.close()
 

@@ -1,0 +1,10 @@
+"""Share of the CPU the daemon's Python threads used over the window
+that role `grpc-serve` used — grpcio's `_serve` loop, the ONE Python thread a server through which every call's arrival, request message and response pass: its
+Δ`gubernator_thread_cpu_seconds_total` ÷ Σ the Python roles', in %
+(`gil_demand_cores` is that sum in cores).  A program without the
+thread ledger reads nothing."""
+from benchmark.harness import threadcost
+
+
+def read(ctx):
+    return threadcost.python_cpu_share(ctx, "grpc-serve")

@@ -17,6 +17,7 @@ from __future__ import annotations
 import json
 import logging
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import List, Optional
@@ -35,11 +36,35 @@ from .proto import peers_pb2 as peers_pb
 from .store import FileLoader
 from .telemetry import exc_text
 from .tlsutil import setup_tls
-from .tracing import grpc_request_context, request_context, span
+from .tracing import (_tls, grpc_request_context, phase, request_context,
+                      span)
 from .types import Behavior, PeerInfo, RateLimitRequest
 from .wire import health_to_pb, req_from_pb, resp_to_pb
 
 log = logging.getLogger("gubernator_tpu.daemon")
+
+
+class DoorPool(ThreadPoolExecutor):
+    """A gRPC server's handler pool, stamping when each task is handed
+    over and when it starts.  grpcio submits a unary call when the CALL
+    is announced, before its request message has arrived
+    (``grpc/_server.py › _handle_unary_unary``); the pool thread then
+    blocks in ``unary_request()`` until the ``_serve`` loop has handed
+    the message over, and only then calls the servicer.  ``submit``
+    runs on the ``_serve`` thread, the task on a pool thread: the two
+    readings, left in the pool thread's ``tracing._tls`` (``door_at``,
+    ``door_run_at``), are what the servicer makes the phases
+    `door.wait` and `door.recv` of."""
+
+    def submit(self, fn, /, *args, **kwargs):
+        return super().submit(_door_task, time.perf_counter(), fn,
+                              *args, **kwargs)
+
+
+def _door_task(at, fn, *args, **kwargs):
+    _tls.door_at = at
+    _tls.door_run_at = time.perf_counter()
+    return fn(*args, **kwargs)
 
 
 class _V1Servicer:
@@ -74,14 +99,28 @@ class _V1Servicer:
         deadline scopes deadline-aware admission shedding (ISSUE 5).
 
         gubernator_door_inflight: handlers in flight at this one's
-        entry, itself included.  A mean at the pool's 32 says callers
-        queue for a worker thread; a mean well under it says their
-        time passes before Python code runs (gRPC core, GIL hand-off)."""
+        entry, itself included (1 call in 8).  What a call waited for
+        between gRPC and this line is measured beside it, from the
+        DoorPool's two readings: `door.wait` (handed to the pool →
+        started on a pool thread: the pool's queue, the wake-up, the
+        GIL) and `door.recv` (started → here: grpcio waiting for the
+        request message, which the ONE `_serve` loop hands over under
+        the GIL).  1 call in 8; every call where the dispatcher times
+        every call (``call_sample`` 1)."""
         door = self._door
         door.append(None)
         n = self._door_calls = self._door_calls + 1
-        if not n & 7:  # the mean needs no more than 1 call in 8
-            self.instance.metrics.door_inflight.observe(len(door))
+        disp = self.instance.dispatcher
+        if not n & 7 or disp.call_sample == 1:
+            here = time.perf_counter()
+            if not n & 7:  # the mean needs no more than 1 call in 8
+                self.instance.metrics.door_inflight.observe(len(door))
+            at, run_at = _tls.door_at, _tls.door_run_at
+            if at is not None:  # served through a DoorPool
+                phase("door.wait", disp, span=False).begin(at=at).end(
+                    at=run_at)
+                phase("door.recv", disp, span=False).begin(
+                    at=run_at).end(at=here)
         try:
             with grpc_request_context(
                     context, recorder=self.instance.span_recorder), \
@@ -208,7 +247,7 @@ class Daemon:
         # resolved to the real bound port before the advertise address
         # (and thus peer identity / discovery) is derived from it.
         self.grpc_server = grpc.server(
-            ThreadPoolExecutor(max_workers=32),
+            DoorPool(max_workers=32, thread_name_prefix="grpc-handler"),
             options=[("grpc.so_reuseport", 0)])
         if self.tls is not None:
             bound = self.grpc_server.add_secure_port(
@@ -278,7 +317,8 @@ class Daemon:
                 # peer port's health service, and SERVING there must
                 # imply the front door is already accepting.
                 self.client_server = grpc.server(
-                    ThreadPoolExecutor(max_workers=32),
+                    DoorPool(max_workers=32,
+                             thread_name_prefix="grpc-client-handler"),
                     options=[("grpc.so_reuseport", 1)])
                 add_v1_servicer_raw(self.client_server,
                                     _V1Servicer(self.instance))

@@ -1004,8 +1004,9 @@ class Dispatcher:
         they partition the dispatch worker's wall time."""
         gap = take_gap()
         if gap:
-            phase("worker.gap", self, span=False).begin(at=0.0).end(
-                at=gap)
+            # a sum handed over, not a section timed here: its
+            # intervals are annotated where they happen (phase.end)
+            self.observe_phase("worker.gap", gap)
         if self._carry is not None:
             first, self._carry = self._carry, None
             co = phase("worker.coalesce", self, span=False).begin()

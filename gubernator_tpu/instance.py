@@ -910,15 +910,14 @@ class V1Instance:
         pb2 object path with identical semantics.  Raises ValueError
         on oversize batches (mirroring ``get_rate_limits``).
         """
-        # the `handler` phase: the whole call.  Where GLOBAL rows route
-        # on the handler threads (mesh mode: call_sample is 1) every
-        # call, wall and thread CPU — on 32 threads and one GIL the
-        # difference is waiting; elsewhere 1 call in 8, wall only: a
+        # the `handler` phase: the whole call, wall and thread CPU — on
+        # 32 threads and one GIL the difference is waiting.  Where
+        # GLOBAL rows route on the handler threads (mesh mode:
+        # call_sample is 1) every call; elsewhere 1 call in 8: a
         # per-call phase costs single-request traffic its share of the
         # rate
-        every = self.dispatcher.call_sample
-        with phase("handler", self.dispatcher, cpu=every == 1,
-                   every=every):
+        with phase("handler", self.dispatcher, cpu=True,
+                   every=self.dispatcher.call_sample):
             return self._get_rate_limits_wire(data, now_ms)
 
     def _get_rate_limits_wire(self, data: bytes,

@@ -56,7 +56,10 @@ class IntervalLoop:
     def __init__(self, period_ms: int, fn: Callable[[], None], name: str):
         self.interval = Interval(period_ms)
         self._fn = fn
-        self._thread = threading.Thread(target=self._run, name=name, daemon=True)
+        # "tick:": the thread ledger's role of every IntervalLoop
+        # thread (tracing.THREAD_ROLES)
+        self._thread = threading.Thread(target=self._run,
+                                        name=f"tick:{name}", daemon=True)
         self._thread.start()
 
     def _run(self) -> None:

@@ -23,6 +23,8 @@ from prometheus_client import (
     generate_latest,
 )
 
+from .tracing import ThreadLedger
+
 _BUCKETS = (.0005, .001, .0025, .005, .01, .025, .05, .1, .25, .5, 1.0, 2.5)
 
 #: wave durations reach minutes on a cold TPU compile — the histogram
@@ -286,7 +288,7 @@ class Metrics:
         self.phase_cpu = Counter(
             "gubernator_phase_cpu_seconds",
             "thread CPU seconds inside the phases that record them "
-            "(route.*, handler in mesh-GLOBAL mode, a wave's pack and "
+            "(route.*, handler, local.pack, a wave's pack and "
             "resolve), read at the same boundaries as phase_duration: "
             "wall - cpu is time the thread waited for the GIL or a lock",
             ["phase"], registry=r)
@@ -476,6 +478,12 @@ class Metrics:
             "memory-ledger rows per consumer: state=capacity is the "
             "allocated row budget, state=occupied the live occupancy",
             ["consumer", "state"], registry=r)
+        # The thread ledger (ISSUE 37): gubernator_thread_* by role,
+        # read from /proc/self/task when this registry is rendered
+        # (tracing.ThreadLedger) — who uses the daemon's cores, and how
+        # much of one GIL the Python threads ask for.
+        self.thread_ledger = ThreadLedger()
+        r.register(self.thread_ledger)
 
     @contextmanager
     def time_func(self, name: str):

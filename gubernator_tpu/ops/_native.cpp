@@ -15,6 +15,8 @@
 //                                                   [lo, hi) -> wire
 //   route_plan(...) / route_fill(...)               a wave's rows routed
 //                                                   by shard, into its pair
+//   thread_files(dir, name) -> [(tid, bytes)]       /proc/self/task/*/name,
+//                                                   the GIL released once
 //
 // The avalanche finalizer stays in Python/numpy (hashing.mix64_np) so
 // there is exactly one source of truth for it.
@@ -31,8 +33,16 @@
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
 
+#include <dirent.h>
+#include <fcntl.h>
+#include <unistd.h>
+
 #include <cstdint>
+#include <cstdio>
+#include <cstdlib>
 #include <cstring>
+#include <string>
+#include <utility>
 #include <vector>
 
 static const uint64_t FNV_OFFSET = 0xCBF29CE484222325ULL;
@@ -1461,6 +1471,56 @@ static PyObject* cold_clear(PyObject*, PyObject* args) {
   Py_RETURN_NONE;
 }
 
+// thread_files(dir, name) -> [(tid, bytes), ...] | None
+//
+// <dir>/<tid>/<name> of every thread under <dir> (/proc/self/task) that
+// has the file, for the thread ledger (tracing.py › ThreadLedger), with
+// the GIL released ONCE for the whole walk.  A Python loop gives the GIL
+// up at every open, read and close, and on a daemon whose 32 handler
+// threads want it waits a switch interval to get it back each time:
+// 3–5 ms a THREAD on the chip's hosts, 0.7–2.5 s a scrape of 230–470
+// threads, every one a forced hand-off for whoever held the GIL (PERF.md
+// §6, PR 37).  None where <dir> cannot be listed.
+static PyObject* thread_files(PyObject*, PyObject* args) {
+  const char *dir, *name;
+  if (!PyArg_ParseTuple(args, "ss", &dir, &name)) return nullptr;
+  std::vector<std::pair<long, std::string>> got;
+  bool listed = false;
+  Py_BEGIN_ALLOW_THREADS
+  DIR* d = opendir(dir);
+  if (d != nullptr) {
+    listed = true;
+    char path[512], buf[1024];
+    while (struct dirent* e = readdir(d)) {
+      char* end;
+      long tid = strtol(e->d_name, &end, 10);
+      if (end == e->d_name || *end != '\0') continue;
+      int len = snprintf(path, sizeof path, "%s/%s/%s", dir, e->d_name, name);
+      if (len < 0 || (size_t)len >= sizeof path) continue;
+      int fd = open(path, O_RDONLY | O_CLOEXEC);
+      if (fd < 0) continue;  // exited under the walk, or no such file
+      ssize_t n = read(fd, buf, sizeof buf);
+      close(fd);
+      if (n > 0) got.emplace_back(tid, std::string(buf, (size_t)n));
+    }
+    closedir(d);
+  }
+  Py_END_ALLOW_THREADS
+  if (!listed) Py_RETURN_NONE;
+  PyObject* out = PyList_New((Py_ssize_t)got.size());
+  if (out == nullptr) return nullptr;
+  for (size_t i = 0; i < got.size(); i++) {
+    PyObject* item = Py_BuildValue("(ly#)", got[i].first, got[i].second.data(),
+                                   (Py_ssize_t)got[i].second.size());
+    if (item == nullptr) {
+      Py_DECREF(out);
+      return nullptr;
+    }
+    PyList_SET_ITEM(out, (Py_ssize_t)i, item);
+  }
+  return out;
+}
+
 static PyMethodDef methods[] = {
     {"fnv1a64_batch", fnv1a64_batch, METH_O,
      "Batch raw FNV-1a64 of str/bytes -> (le64 bytes, n)"},
@@ -1481,6 +1541,9 @@ static PyMethodDef methods[] = {
      "row indices, slots, bucket and densest shard"},
     {"route_fill", route_fill, METH_VARARGS,
      "One routed device wave into its upload pair, every cell written"},
+    {"thread_files", thread_files, METH_VARARGS,
+     "One file of every thread under /proc/self/task, the GIL released "
+     "once for the walk -> [(tid, bytes)] (or None)"},
     {"stamp_req_tlvs", stamp_req_tlvs, METH_VARARGS,
      "Join request TLV slices, appending created_at (field 10) where "
      "unset — the forward hop's caller-clock stamp"},

@@ -12,13 +12,18 @@ import numpy as np
 
 from . import _native  # ImportError here means: run `make native`
 
-if not hasattr(_native, "route_fill"):
+#: the entry point the newest ``_native.cpp`` added (PR 37; PR 36's
+#: were ``route_plan`` / ``route_fill``): a build without it is older
+#: than the source
+NEWEST = "thread_files"
+
+if not hasattr(_native, NEWEST):
     # NOT an ImportError: every importer reads that as "no extension"
     # and falls back to numpy — a build older than _native.cpp is a
     # broken checkout, not an optional feature switched off
     raise RuntimeError(
-        f"{_native.__file__} is older than _native.cpp (no route_plan / "
-        "route_fill): rebuild it with `make native`")
+        f"{_native.__file__} is older than _native.cpp (no {NEWEST}): "
+        "rebuild it with `make native`")
 
 
 def hash_keys(keys: Sequence[str]) -> np.ndarray:
@@ -181,6 +186,14 @@ def route_fill(m64: np.ndarray, m32: np.ndarray, valid, mslot,
     if mslot is not None:
         mslot = np.ascontiguousarray(mslot, np.int32)
     _native.route_fill(m64, m32, valid, mslot, idx, slots, a64, a32, mblk)
+
+
+def thread_files(task_dir: str, name: str):
+    """``[(tid, bytes of <task_dir>/<tid>/<name>)]`` for every thread
+    that has the file, ``None`` where the directory cannot be listed —
+    the whole walk with the GIL released once (the thread ledger,
+    ``tracing.py › ThreadLedger``)."""
+    return _native.thread_files(task_dir, name)
 
 
 def split_resp_items(data: bytes):

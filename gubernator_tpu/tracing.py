@@ -30,6 +30,7 @@ import logging
 import os
 import time
 from collections import OrderedDict, deque
+from fnmatch import fnmatchcase
 from typing import Dict, Iterator, List, Optional
 
 log = logging.getLogger("gubernator_tpu.tracing")
@@ -60,6 +61,9 @@ class _ThreadState(threading.local):
     wave = None     # enclosing WaveScope
     cursor = None   # partition_thread(): end of the last phase
     gap = 0.0       # partition_thread(): seconds between phases
+    gap_ann = None  # partition_thread(): open "worker.gap" annotation
+    door_at = None      # DoorPool: submit() of the task this thread runs
+    door_run_at = None  # DoorPool: when that task started on this thread
 
 
 _tls = _ThreadState()
@@ -186,8 +190,17 @@ PHASE_CATALOG: Dict[str, str] = {
     "wave.resolve": "dispatcher: the future.set_result loop",
     "wave.end": "_wave_end + the analytics tap",
     # handler threads, per call
+    "door.wait": "front door: grpcio's submit of a call to the handler "
+                 "pool (on the _serve thread) → its task starts on a "
+                 "pool thread: the pool's queue, the thread's wake-up, "
+                 "winning the GIL (DoorPool; 1 call in 8, every call in "
+                 "mesh-GLOBAL mode)",
+    "door.recv": "front door: the task's start → the servicer's first "
+                 "line: grpcio waiting for the request message (the "
+                 "_serve loop reaching that event under the GIL) and "
+                 "its prelude; shares door.wait's end reading",
     "handler": "instance.get_rate_limits_wire, whole, wall and CPU "
-               "(mesh-GLOBAL mode only)",
+               "(1 call in 8; every call in mesh-GLOBAL mode)",
     "ingest": "wire parse / fused prepack (bytes → columns)",
     "build": "response wire-byte serialization",
     "call.wait": "handler blocked on its wave's future (queue wait + "
@@ -479,7 +492,12 @@ def partition_thread() -> None:
     the GIL back from 32 handler threads — is then summed up as the
     thread's gap (``take_gap``), so that phases + gap partition the
     thread's wall time.  Phases given an explicit ``at=`` neither read
-    nor move the cursor."""
+    nor move the cursor.  While a profile records, each gap is also a
+    ``TraceAnnotation("worker.gap")`` — opened by ``phase.end``, closed
+    by the thread's next ``phase.begin`` (one given ``at=`` closes it
+    only where that IS the cursor: the boundary it shares with the
+    phase before) — so the profile shows on its own clock what
+    ``take_gap`` sums after the fact."""
     _tls.cursor = time.perf_counter()
     _tls.gap = 0.0
 
@@ -571,7 +589,9 @@ class phase:
     the chip's host and dearer under load — so it is for per-call
     phases whose wall − CPU split decides something, and for the
     phases of the waves the dispatcher samples (``WaveScope.cpu``).
-    The annotation is made only while a profile records.
+    The annotation is made only while a profile records; on a
+    partition thread (``partition_thread``) the time to the thread's
+    next phase is then annotated ``worker.gap``.
 
     ``every=n`` times 1 use in n of this name and lets the others
     through untouched (a per-call phase on a path that serves hundreds
@@ -605,6 +625,13 @@ class phase:
         if self._skip:
             return self
         cls = _annotation or _trace_annotation()
+        gap_ann = _tls.gap_ann
+        if gap_ann is not None and (at is None or at == _tls.cursor):
+            # partition thread, profile recording: the gap ends where
+            # its next phase begins (a begin AT the cursor shares the
+            # boundary with the phase before: no gap at all)
+            _tls.gap_ann = None
+            gap_ann.__exit__(None, None, None)
         if cls.is_enabled():  # a profile is recording
             ann = self._ann = cls(self.name)
             ann.__enter__()
@@ -646,16 +673,24 @@ class phase:
         that ``every=`` let through untimed)."""
         if self._skip:
             return 0.0
+        gap_from_here = False
         if at is None:
             at = time.perf_counter()
             if _tls.cursor is not None:
                 _tls.cursor = at
+                gap_from_here = True
         self.t1 = at
         cpu = (time.thread_time() - self._c0) if self._cpu else None
         ns0 = self._ns0
         ns1 = time.time_ns() if ns0 else 0  # clock-ok: telemetry wall clock (span end)
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
+        if (gap_from_here and _tls.gap_ann is None
+                and _annotation.is_enabled()):
+            # a partition thread while a profile records: its gap on
+            # the profile's clock, from here to its next begin()
+            gap_ann = _tls.gap_ann = _annotation("worker.gap")
+            gap_ann.__enter__()
         dt = at - self._t0
         if dt < 0.0:
             dt = 0.0
@@ -722,6 +757,317 @@ def span(name: str, metrics=None, attrs: Optional[dict] = None) -> phase:
     an exception in the body force-samples the whole trace."""
     return phase(name, _FuncDuration(metrics) if metrics is not None
                  else None, always=True, attrs=attrs)
+
+
+# --- the thread ledger (ISSUE 37) ----------------------------------------
+#
+# Every phase above times a SECTION; none counts a THREAD.  The kernel
+# does: /proc/self/task/<tid>/schedstat holds, in nanoseconds, what each
+# thread of the process has run on a CPU and what it has waited on a
+# run queue for one.  The ledger adds those up by ROLE when /metrics is
+# rendered, so "is this daemon GIL-bound, and by whom" is a sum over the
+# Python roles' CPU between two scrapes (at most one core's worth can
+# hold the GIL) beside what the native roles use of the same cores.
+
+#: role → (kind, patterns, which threads).  kind "py": a thread Python
+#: started (``threading.enumerate()``), matched on ``Thread.name``; kind
+#: "comm": every other thread of the process — gRPC core's, the XLA /
+#: PJRT / TPU runtime's pools — matched on /proc/self/task/<tid>/comm
+#: (the kernel keeps 15 characters).  ``fnmatch`` patterns; within a
+#: kind the first role that matches wins, so each kind ends in its
+#: ``*-other``: an unknown thread is counted there, never dropped.
+#: Linted both ways against OBSERVABILITY.md's "Thread roles" table
+#: (tools/guberlint/docs.py › thread_roles_doc_problems).
+THREAD_ROLES: Dict[str, tuple] = {
+    "worker": ("py", ("device-dispatcher",),
+               "the dispatch worker: every wave's launch and sync"),
+    "handler": ("py", ("grpc-handler_*", "grpc-client-handler_*"),
+                "the gRPC pools' threads (daemon.py › DoorPool): "
+                "grpcio's per-call Python, the servicer, the call's "
+                "ingest / pack / build, its wait for the wave"),
+    "grpc-serve": ("py", ("*(_serve)",),
+                   "grpcio's _serve loop, ONE Python thread a server: "
+                   "every call's arrival, request message and response "
+                   "pass through it"),
+    "analytics": ("py", ("key-analytics",),
+                  "the analytics worker: tenant learn, sketch fold, "
+                  "publish"),
+    "tick": ("py", ("tick:*",),
+             "every IntervalLoop thread: the mesh-GLOBAL fold and the "
+             "GLOBAL manager's ticks, SLO engine, discovery polls"),
+    "py-other": ("py", ("*",),
+                 "any other Python thread: main, the HTTP listener and "
+                 "its request threads, the watchdog, peer lanes, an "
+                 "embedding program's own (the benchmark's)"),
+    "native-grpc": ("comm", ("grpc*", "default-executo*", "resolver-exe*",
+                             "event_engine*", "timer_manager*",
+                             "lifeguard"),
+                    "gRPC core: pollers, the event engine, timers"),
+    "native-xla": ("comm", ("tf_*", "tpu*", "TPU*", "xla*", "XLA*",
+                            "pjrt*", "PjRt*", "pjit*", "py_xla_*",
+                            "tfrt-*", "StreamExec*", "tsl*", "profiler*",
+                            "llvm-worker*",
+                            # libtpu's own pools, as a chip run showed
+                            # them (41 + 38/4 a chip; PERF.md §6, PR 37)
+                            "futex-default-*", "EventFDAsyncWor*",
+                            "DefaultEventMan*", "PendingEventLog*",
+                            "SlowOperationAl*", "BreakpointDebug*",
+                            "thread_manager_*", "thread_threadpo*",
+                            "learning_*", "timedcall"),
+                   "the XLA / PJRT / TSL / TPU runtime's thread pools "
+                   "(launches, transfers, host callbacks, the profiler)"),
+    "native-other": ("comm", ("*",),
+                     "any other native thread (BLAS pools, threads a "
+                     "library never named: comm is the interpreter's)"),
+}
+
+
+#: nanoseconds a clock tick of /proc/<pid>/task/<tid>/stat (off POSIX:
+#: unused, the ledger finds no /proc)
+_NS_PER_TICK = 1_000_000_000 // (
+    os.sysconf("SC_CLK_TCK") if hasattr(os, "sysconf") else 100)
+
+try:  # the walk of /proc/self/task: one call, the GIL given up ONCE
+    from .ops.native import thread_files as _thread_files
+except ImportError:  # pragma: no cover - unbuilt extension: no ledger
+    _thread_files = None
+
+
+def thread_role(kind: str, name: str) -> str:
+    """The role of a thread of ``kind`` ("py" / "comm") named ``name``."""
+    for role, (k, patterns, _doc) in THREAD_ROLES.items():
+        if k == kind and any(fnmatchcase(name, p) for p in patterns):
+            return role
+    raise AssertionError(f"THREAD_ROLES has no catch-all of kind {kind}")
+
+
+class ThreadLedger:
+    """CPU, run-queue wait and wake-ups of the process's threads by
+    role (``THREAD_ROLES``), read from /proc/self/task WHEN /metrics IS
+    RENDERED and at no other time: a collector on ``Metrics.registry``,
+    nothing on the serving path, no option.
+
+    Per thread one file.  ``schedstat`` where the kernel has it: ns on
+    a CPU, ns runnable but waiting for a core, times scheduled in —
+    nanosecond counters, where ``time.thread_time()`` ticks in 10-ms
+    steps on some hosts.  Where it has not (a sandboxed kernel: the
+    chip tool's machines), ``stat``: utime + stime in clock ticks — CPU
+    only, sound as a sum over seconds, and the run-queue and wake-up
+    series are then NOT exported (``source`` says which file it is).
+    For the role ``worker`` also ``status``: voluntary / involuntary
+    context switches (a voluntary one is the thread blocking: on the
+    GIL, a lock, the device), exported where the kernel counts them.
+    ``comm`` is read once a thread id, by a second walk in the reads
+    that meet a native thread they do not know.
+
+    Every walk is ONE call into the C++ extension (``ops/_native.cpp ›
+    thread_files``) that gives the GIL up once.  A Python loop over the
+    files gives it up at every open, read and close, and on a loaded
+    daemon waits a switch interval to get it back each time: 3–5 ms a
+    thread, seconds a scrape, one more contender for what the ledger
+    measures (PERF.md §6, PR 37).  So there is none: a daemon without
+    the extension has no ledger, as one off Linux has none.
+
+    Role totals only grow: the ledger keeps each thread id's last
+    reading and adds DELTAS to the thread's role, so a thread that
+    exits takes nothing away.  A thread that lives and dies between
+    two reads is missed, and so is what a thread ran after its last
+    read.  A re-read inside ``MIN_INTERVAL_S`` returns the totals of
+    the read before.  The totals are the PROCESS's: two daemons in one
+    process (the test cluster) each report all of its threads.
+
+    Off Linux (no ``/proc/self/task``) and without the extension the
+    collector yields nothing."""
+
+    MIN_INTERVAL_S = 0.5
+
+    def __init__(self, task_dir: str = "/proc/self/task"):
+        self._task_dir = task_dir
+        self._mu = threading.Lock()
+        #: the per-thread file the counters come from: "schedstat",
+        #: "stat", or None until a read has found one
+        self.source: Optional[str] = None
+        self._last: Dict[int, tuple] = {}  # guarded-by: self._mu
+        self._roles: Dict[int, tuple] = {}  # guarded-by: self._mu
+        self._snap: Optional[dict] = None  # guarded-by: self._mu
+        #: role → [cpu ns, run-queue wait ns, times scheduled in]
+        self._totals = {r: [0, 0, 0] for r in THREAD_ROLES}  # guarded-by: self._mu
+        #: the worker threads' [voluntary, involuntary] switches; None
+        #: where the kernel's status files do not count them (asked of
+        #: the first thread of the first walk)
+        self._switches: Optional[list] = None  # guarded-by: self._mu
+
+    def _walk(self, name: str) -> Optional[list]:
+        """``[(tid, text of <task_dir>/<tid>/<name>)]`` for every thread
+        that has the file; ``None`` where the directory cannot be
+        listed, or the extension is not built."""
+        if _thread_files is None:
+            return None
+        got = _thread_files(self._task_dir, name)
+        return got and [(tid, raw.decode("ascii", "replace"))
+                        for tid, raw in got]
+
+    def _files(self) -> Optional[list]:
+        """One walk of the file this kernel has — ``schedstat``, else
+        ``stat``, settled by the first walk that finds either — or
+        ``None`` where it has neither."""
+        for source in (self.source,) if self.source else ("schedstat",
+                                                          "stat"):
+            files = self._walk(source)
+            if files:
+                self.source = source
+                return files
+        return None
+
+    def _counters(self, text: str) -> tuple:
+        """(cpu ns, run-queue wait ns, times scheduled in) from one
+        thread's ``schedstat`` or ``stat``."""
+        if self.source == "schedstat":
+            cpu, wait, slices = text.split()[:3]
+            return int(cpu), int(wait), int(slices)
+        # the fields after "(comm)": utime and stime are the 12th and
+        # 13th of them (the 14th and 15th of the line)
+        fields = text.rsplit(")", 1)[1].split()
+        return (int(fields[11]) + int(fields[12])) * _NS_PER_TICK, 0, 0
+
+    def _worker_switches(self, tid: int) -> Optional[tuple]:
+        """(voluntary, involuntary) context switches of one thread, or
+        None where its status does not hold them."""
+        try:
+            with open(f"{self._task_dir}/{tid}/status") as f:
+                st = f.read()
+            return tuple(int(st.split(key, 1)[1].split(None, 1)[0])
+                         for key in ("\nvoluntary_ctxt_switches:",
+                                     "\nnonvoluntary_ctxt_switches:"))
+        except (OSError, ValueError, IndexError):
+            return None
+
+    def read(self) -> Optional[dict]:
+        """Walk the threads (at most once in ``MIN_INTERVAL_S``) and
+        return ``{"clock", "source", "roles": {role: (cpu s, run-queue
+        wait s, wake-ups, threads now)}, "switches": (voluntary,
+        involuntary) or None}`` — ``None`` where the kernel offers no
+        per-thread counters."""
+        with self._mu:
+            now = time.perf_counter()
+            snap = self._snap
+            if snap is not None and now - snap["clock"] < self.MIN_INTERVAL_S:
+                return snap
+            files = self._files()
+            if files is None:
+                return None  # no /proc, or no per-thread counters in it
+            # a foreign thread that once ran Python shows as a
+            # _DummyThread: it is what its comm says, not "py-other"
+            py_names = {t.native_id: t.name for t in threading.enumerate()
+                        if t.native_id is not None
+                        and not isinstance(t, threading._DummyThread)}
+            last, totals = self._last, self._totals
+            if snap is None and self._worker_switches(files[0][0]):
+                self._switches = [0, 0]
+            seen = {}
+            counts = dict.fromkeys(totals, 0)
+            roles, comms = self._roles, None
+            for tid, text in files:
+                try:
+                    cur = self._counters(text)
+                except (ValueError, IndexError):
+                    continue  # exited under the walk
+                # the role, cached under what it was matched on: the
+                # Python name (which a thread may change), else comm
+                name = py_names.get(tid)
+                got = roles.get(tid)
+                if got is None or got[0] != name:
+                    if name is not None:
+                        role = thread_role("py", name)
+                    else:
+                        if comms is None:  # a native thread not met yet
+                            comms = dict(self._walk("comm") or ())
+                        if tid not in comms:
+                            continue  # exited between the two walks
+                        role = thread_role("comm", comms[tid].strip())
+                    roles[tid] = got = (name, role)
+                role = got[1]
+                old = last.get(tid)
+                if old is None or cur[0] < old[0]:
+                    old = (0,) * 5  # new thread (or a reused id)
+                tot = totals[role]
+                tot[0] += cur[0] - old[0]
+                tot[1] += cur[1] - old[1]
+                tot[2] += cur[2] - old[2]
+                if role == "worker" and self._switches is not None:
+                    sw = self._worker_switches(tid)
+                    if sw is None:
+                        # its status raced its exit: the CPU above
+                        # counts, the switches keep their last reading
+                        cur += old[3:]
+                    else:
+                        if len(old) == 5:
+                            self._switches[0] += sw[0] - old[3]
+                            self._switches[1] += sw[1] - old[4]
+                        cur += sw
+                seen[tid] = cur
+                counts[role] += 1
+            self._last = seen
+            for tid in [t for t in self._roles if t not in seen]:
+                del self._roles[tid]
+            self._snap = snap = {
+                "clock": now, "source": self.source,
+                "roles": {r: (t[0] / 1e9, t[1] / 1e9, t[2], counts[r])
+                          for r, t in totals.items()},
+                "switches": self._switches and tuple(self._switches)}
+            return snap
+
+    def collect(self):
+        """prometheus_client's custom-collector hook: the families of
+        one ``read()``."""
+        snap = self.read()
+        if snap is None:
+            return
+        from prometheus_client.core import (CounterMetricFamily,
+                                            GaugeMetricFamily)
+
+        cpu = CounterMetricFamily(
+            "gubernator_thread_cpu_seconds",
+            "seconds the process's threads have run on a CPU, by thread "
+            "role (tracing.THREAD_ROLES; /proc/self/task/*/schedstat, "
+            "read when /metrics is rendered): the Python roles' sum "
+            "over an interval is an upper bound of the GIL's load",
+            labels=["role"])
+        runq = CounterMetricFamily(
+            "gubernator_thread_runq_wait_seconds",
+            "seconds the threads were runnable but waited for a core, "
+            "by role: starved of CORES, not of the GIL", labels=["role"])
+        wake = CounterMetricFamily(
+            "gubernator_thread_wakeups",
+            "times the threads were scheduled onto a CPU, by role",
+            labels=["role"])
+        alive = GaugeMetricFamily(
+            "gubernator_threads", "threads alive at this read, by role",
+            labels=["role"])
+        for role, (c, w, n, k) in snap["roles"].items():
+            cpu.add_metric([role], c)
+            runq.add_metric([role], w)
+            wake.add_metric([role], n)
+            alive.add_metric([role], k)
+        families = [cpu, alive]
+        if snap["source"] == "schedstat":  # `stat` has neither
+            families += [runq, wake]
+        if snap["switches"] is not None:
+            sw = CounterMetricFamily(
+                "gubernator_thread_switches",
+                "context switches of the dispatch worker: voluntary = "
+                "it blocked (the GIL, a lock, the device), involuntary "
+                "= it was preempted", labels=["role", "kind"])
+            sw.add_metric(["worker", "voluntary"], snap["switches"][0])
+            sw.add_metric(["worker", "involuntary"], snap["switches"][1])
+            families.append(sw)
+        clock = GaugeMetricFamily(
+            "gubernator_thread_ledger_clock_seconds",
+            "time.perf_counter() of the read the gubernator_thread_* "
+            "totals come from: divide their deltas by THIS one's delta "
+            "(a scrape inside 0.5 s of the last repeats its totals)")
+        clock.add_metric([], snap["clock"])
+        yield from (*families, clock)
 
 
 # --- cross-daemon assembly (ISSUE 12) ---------------------------------

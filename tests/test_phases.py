@@ -426,9 +426,11 @@ def test_profile_capture_holds_phase_annotations_by_name(pipelined,
     assert {"ingest", "handler", "call.wait", "build", "queue_wait",
             "worker.wait", "worker.coalesce", "wave.begin", "wave.route",
             "wave.fill", "lock.xla_exec", "wave.dispatch", "wave.sync",
-            "wave.scatter", "wave.resolve", "wave.end",
+            "wave.scatter", "wave.resolve", "wave.end", "worker.gap",
             *COARSE} <= seen, sorted(seen)
-    # the worker's line: its phases, no two open at once
+    # the worker's line: its phases and the gaps between them (each an
+    # annotation of its own while a profile records), no two open at
+    # once
     worker = [evs for evs in by_line.values()
               if any(n == "worker.wait" for _, _, n in evs)]
     assert len(worker) == 1
@@ -436,6 +438,14 @@ def test_profile_capture_holds_phase_annotations_by_name(pipelined,
     assert len(evs) > 30
     for a, b in zip(evs, evs[1:]):
         assert a[1] <= b[0], (a, b)
+    # and gaps lie between phases, never side by side
+    names = [e[2] for e in evs]
+    assert names.count("worker.gap") > 10
+    assert all(not (a == b == "worker.gap")
+               for a, b in zip(names, names[1:]))
+    # no other thread has one
+    assert sum(any(n == "worker.gap" for _, _, n in evs)
+               for evs in by_line.values()) == 1
 
 
 # ---- the front door counts who is inside -------------------------------
@@ -467,3 +477,29 @@ def test_door_inflight_counts_handlers_in_flight():
         assert n <= total <= 4 * n  # itself included; 4 callers at most
     finally:
         d.close()
+
+
+# ---- the handler's CPU, wherever it is timed (ISSUE 37) ----------------
+
+
+def test_handler_records_cpu_on_the_calls_it_samples(pipelined):
+    """Off mesh-GLOBAL mode `handler` times 1 call in 8 — wall AND
+    thread CPU (two ``thread_time()`` a sampled call), so that the
+    program's share of a handler thread's CPU can be read."""
+    inst = V1Instance(Config(cache_size=1 << 12, sweep_interval_ms=0),
+                      mesh=make_mesh(n=1))
+    try:
+        assert inst.dispatcher.call_sample == 8
+        tracing.phase._uses.pop("handler", None)
+        for i in range(16):
+            inst.get_rate_limits_wire(ser(20, key=f"h{i}_"), now_ms=NOW + i)
+        text = inst.metrics.render().decode()
+    finally:
+        inst.close()
+    assert hist(text, "gubernator_phase_duration", "handler", "count") == 2
+    wall = hist(text, "gubernator_phase_duration", "handler", "sum")
+    cpu = hist(text, "gubernator_phase_cpu_seconds", "handler", "total")
+    cpu_wall = hist(text, "gubernator_phase_cpu_wall_seconds", "handler",
+                    "total")
+    assert cpu is not None and 0.0 <= cpu <= wall + 0.02
+    assert cpu_wall == pytest.approx(wall)

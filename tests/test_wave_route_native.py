@@ -288,8 +288,10 @@ def test_a_build_older_than_the_source_is_refused_not_taken_for_none(
         monkeypatch):
     """``sharded.py``, ``instance.py`` and ``hashing.py`` read an
     ImportError of ``ops/native.py`` as "no extension" and fall back to
-    numpy.  A ``_native*.so`` from before ISSUE 36 must not pass for
-    that: the import fails with another error, naming the cure."""
+    numpy.  A ``_native*.so`` from before the newest entry point
+    (``native.NEWEST``; ISSUE 36 brought the check with ``route_*``)
+    must not pass for that: the import fails with another error, naming
+    the cure."""
     import importlib.util
     import sys
     import types
@@ -300,7 +302,7 @@ def test_a_build_older_than_the_source_is_refused_not_taken_for_none(
     stale = types.ModuleType(_native.__name__)
     stale.__file__ = "_native.stale.so"
     for name in dir(_native):
-        if not name.startswith("__") and not name.startswith("route_"):
+        if not name.startswith("__") and name != native.NEWEST:
             setattr(stale, name, getattr(_native, name))
     monkeypatch.setitem(sys.modules, _native.__name__, stale)
     monkeypatch.setattr(ops, "_native", stale)
@@ -312,7 +314,7 @@ def test_a_build_older_than_the_source_is_refused_not_taken_for_none(
         spec.loader.exec_module(probe)
     assert not isinstance(err.value, ImportError)
     assert "_native.stale.so" in str(err.value)
-    # with the entry points the same copy imports
-    stale.route_plan, stale.route_fill = _native.route_plan, _native.route_fill
+    # with the entry point the same copy imports
+    setattr(stale, native.NEWEST, getattr(_native, native.NEWEST))
     spec.loader.exec_module(probe)
     assert len(probe.route_plan(KH, None, 4, SMALL)) == 1

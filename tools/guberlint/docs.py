@@ -183,8 +183,9 @@ def span_catalog_doc_problems() -> list:
     return problems
 
 
-#: literal phase names at ``tracing.phase`` call sites
-_PHASE_RX = re.compile(r"\bphase\(\s*[\"']([a-z_.]+)[\"']")
+#: literal phase names at ``tracing.phase`` call sites, and where a sum
+#: is handed straight to a sink's ``observe_phase`` (`worker.gap`)
+_PHASE_RX = re.compile(r"\b(?:observe_)?phase\(\s*[\"']([a-z_.]+)[\"']")
 
 
 def emitted_phase_names(pkg_dir: str) -> set:
@@ -225,6 +226,35 @@ def phase_catalog_doc_problems() -> list:
         problems.append(
             f"OBSERVABILITY.md's phase catalog table documents phase "
             f"{name!r} but tracing.PHASE_CATALOG has no such phase")
+    return problems
+
+
+def thread_roles_doc_problems() -> list:
+    """OBSERVABILITY.md's thread-roles table ↔ tracing.THREAD_ROLES,
+    both ways; and each kind of the table ends in a catch-all, so that
+    no thread is dropped."""
+    from gubernator_tpu.tracing import THREAD_ROLES
+
+    with open(DOC, encoding="utf-8") as f:
+        doc = f.read()
+    documented = _table_cell_names(doc, "#### Thread roles",
+                                   r"`([a-z][a-z-]*)`")
+    problems = []
+    for role in sorted(set(THREAD_ROLES) - documented):
+        problems.append(
+            f"thread role {role!r} is in tracing.THREAD_ROLES but "
+            f"missing from OBSERVABILITY.md's thread-roles table")
+    for role in sorted(documented - set(THREAD_ROLES)):
+        problems.append(
+            f"OBSERVABILITY.md's thread-roles table documents role "
+            f"{role!r} but tracing.THREAD_ROLES has no such role")
+    for kind in ("py", "comm"):
+        last = [pats for k, pats, _doc in THREAD_ROLES.values()
+                if k == kind][-1:]
+        if last != [("*",)]:
+            problems.append(
+                f"tracing.THREAD_ROLES: the last role of kind {kind!r} "
+                f"must match every name ('*'), or a thread is dropped")
     return problems
 
 
@@ -319,6 +349,7 @@ def run(ctx) -> List[Violation]:
         ("OBSERVABILITY.md", slo_catalog_doc_problems),
         ("OBSERVABILITY.md", span_catalog_doc_problems),
         ("OBSERVABILITY.md", phase_catalog_doc_problems),
+        ("OBSERVABILITY.md", thread_roles_doc_problems),
     )
     out: List[Violation] = []
     for doc_rel, fn in groups:
@@ -336,7 +367,8 @@ def main() -> int:
                 + env_registry_doc_problems()
                 + slo_catalog_doc_problems()
                 + span_catalog_doc_problems()
-                + phase_catalog_doc_problems())
+                + phase_catalog_doc_problems()
+                + thread_roles_doc_problems())
     if problems:
         for p in problems:
             print(f"check_metrics: {p}", file=sys.stderr)

@@ -1046,7 +1046,12 @@ class V1Instance:
 
     #: behaviors whose async side effects (hot-set routing, GLOBAL
     #: reconcile queues, cross-region replication) need the parsed
-    #: columns — the fused lane hands them to the classic lanes
+    #: columns — the fused lane hands them to the classic lanes, which
+    #: keep those semantics in one place.  The policy lives HERE; the
+    #: ingest's pre-pass is handed it (``prepack_wire``'s ``excluded``)
+    #: and declines at the first row that carries one of these bits,
+    #: before it packs anything: an all-GLOBAL call costs this lane one
+    #: request's header.
     _FUSED_EXCLUDED = Behavior.GLOBAL | Behavior.MULTI_REGION
 
     def _wire_client_fused(self, data: bytes,
@@ -1059,14 +1064,9 @@ class V1Instance:
             return None
         now = clock_ms() if now_ms is None else now_ms  # clock-domain: caller
         ing = phase("ingest", self.dispatcher).begin()
-        pre = prepack(data, now)
+        pre = prepack(data, now, int(self._FUSED_EXCLUDED))
         ing.end(keep=pre is not None)
         if pre is None:
-            return None
-        if pre.behavior_or & int(self._FUSED_EXCLUDED):
-            # GLOBAL rides the hot-set flow, MULTI_REGION queues async
-            # replication — both need the parsed columns; the classic
-            # lanes keep those semantics in one place
             return None
         if pre.n > MAX_BATCH_SIZE:
             raise ValueError(
@@ -1082,6 +1082,7 @@ class V1Instance:
             pre.n)
         self.metrics.wire_lane_counter.labels(lane="wire_local").inc(
             pre.n)
+        self.metrics.wire_fused_counter.inc(pre.n)
         self.metrics.concurrent_checks.inc()
         try:
             with self.metrics.time_func("GetRateLimits"):
@@ -1105,11 +1106,9 @@ class V1Instance:
             return None
         now = clock_ms() if now_ms is None else now_ms  # clock-domain: caller
         ing = phase("ingest", self.dispatcher).begin()
-        pre = prepack(data, now)
+        pre = prepack(data, now, int(self._FUSED_EXCLUDED))
         ing.end(keep=pre is not None)
         if pre is None:
-            return None
-        if pre.behavior_or & int(self._FUSED_EXCLUDED):
             return None
         if pre.n > self.config.behaviors.batch_limit:
             raise ValueError(
@@ -1123,6 +1122,7 @@ class V1Instance:
             pre.n)
         self.metrics.wire_lane_counter.labels(lane="peer_wire").inc(
             pre.n)
+        self.metrics.wire_fused_counter.inc(pre.n)
         return self._run_fused(pre, now)
 
     def _run_fused(self, pre, now: int) -> bytes:
@@ -1727,8 +1727,11 @@ class V1Instance:
         # own thread before it is queued — hash, pack, lay out — wall
         # AND thread CPU, as route.* (on 32 handler threads and one GIL
         # the difference is waiting), sampled as `handler` is.  The
-        # fused lane (one shard) does all of it in one C++ pass inside
-        # `ingest` and never comes here.
+        # fused lane (any shard count) does all of it in one C++ pass
+        # inside `ingest` and never comes here: this is the lane of
+        # what that pass declines — Gregorian rows, a MULTI_REGION row,
+        # a call over the largest bucket, the gated peer wire, a
+        # checkout without the extension's ingest.
         disp = self.dispatcher
         with phase("local.pack", disp, cpu=True, every=disp.call_sample):
             kh = mix64_np(parsed["khash_raw"])

@@ -87,6 +87,15 @@ class Metrics:
             "gubernator_wire_lane_requests",
             "requests by serving lane (wire-columnar vs pb2 fallback)",
             ["lane"], registry=r)
+        # both lanes below wear lane="wire_local" / "peer_wire": this
+        # says how many of those rows the ONE C++ pass packed
+        # (instance.py › _wire_client_fused / _wire_peer_fused); the
+        # rest were parsed and packed in numpy by their handler
+        self.wire_fused_counter = Counter(
+            "gubernator_wire_fused_requests",
+            "requests the fused C++ wire ingest (prepack_wire) parsed "
+            "and laid out in one pass; a subset of "
+            "gubernator_wire_lane_requests", registry=r)
         self.hot_demotion_counter = Counter(
             "gubernator_hotset_demotions",
             "hot-set pinned keys demoted back to the sharded path",

@@ -425,15 +425,24 @@ static PyObject* stamp_req_tlvs(PyObject*, PyObject* args) {
       (Py_ssize_t)out.size());
 }
 
-// count_req_items(bytes) -> n | None
-// Top-level-only scan of a GetRateLimitsReq / GetPeerRateLimitsReq:
-// counts the repeated field-1 TLVs without touching their payloads, so
-// the fused ingest below can size its wave bucket (and lease the packed
-// upload buffers) before the single full parse.  None on any framing
-// the fast lane doesn't model (caller falls back to pb2).
-static PyObject* count_req_items(PyObject*, PyObject* arg) {
+// count_req_items(bytes, excluded=0) -> n | None
+// The fused ingest's pre-pass over a GetRateLimitsReq /
+// GetPeerRateLimitsReq: counts the repeated field-1 TLVs, so the ingest
+// below can size the call's pair before the single full parse.  None on
+// any framing the fast lane doesn't model (caller falls back to pb2).
+//
+// `excluded` is a mask of Behavior bits the caller's lane does not
+// serve (instance.py › _FUSED_EXCLUDED, and DURATION_IS_GREGORIAN, which
+// pack_wire_wave cannot model): None at the FIRST request that carries
+// one, before a pair exists — an all-GLOBAL call costs the fused lane
+// one request's header, not a pass.  With a mask the walk reads each
+// request's field tags (LEN payloads skipped by their length, last
+// behavior wins, as the full parse has it); with 0 it touches no
+// payload at all.
+static PyObject* count_req_items(PyObject*, PyObject* args) {
   Py_buffer view;
-  if (PyObject_GetBuffer(arg, &view, PyBUF_SIMPLE) < 0) return nullptr;
+  unsigned long long excluded = 0;
+  if (!PyArg_ParseTuple(args, "y*|K", &view, &excluded)) return nullptr;
   const uint8_t* p = (const uint8_t*)view.buf;
   const uint8_t* end = p + view.len;
   Py_ssize_t n = 0;
@@ -445,8 +454,28 @@ static PyObject* count_req_items(PyObject*, PyObject* arg) {
       fallback = true;
       break;
     }
+    const uint8_t* q = p;
     p += len;
     n++;
+    if (!excluded) continue;
+    uint64_t beh = 0;
+    while (q < p && !fallback) {
+      uint64_t t, v;
+      if (!read_varint(&q, p, &t) || !read_varint(&q, p, &v)) {
+        fallback = true;
+      } else if ((t & 7) == 2) {  // v is the payload's length
+        if ((uint64_t)(p - q) < v) fallback = true;
+        q += v;
+      } else if ((t & 7) != 0) {  // the full parse models varint, LEN
+        fallback = true;
+      } else if ((t >> 3) == 7) {
+        beh = (uint64_t)(uint32_t)v;
+      }
+    }
+    if (fallback || (beh & excluded)) {
+      fallback = true;
+      break;
+    }
   }
   PyBuffer_Release(&view);
   if (fallback) Py_RETURN_NONE;
@@ -847,9 +876,10 @@ static PyObject* route_fill(PyObject*, PyObject* args) {
 // Returns None (caller falls back) whenever the batch needs host-side
 // Python: pb2-fallback framing (as parse_get_rate_limits), n > m, or any
 // DURATION_IS_GREGORIAN row (calendar period ends are computed in
-// Python).  GLOBAL/MULTI_REGION gating is the caller's policy —
-// behavior_or is returned for it.  name_hash: as parse_get_rate_limits
-// returns it.
+// Python).  GLOBAL/MULTI_REGION gating is the caller's policy, applied
+// BEFORE this pass by the pre-pass (count_req_items' mask): a call that
+// reaches here is one the lane serves.  behavior_or, name_hash: as
+// parse_get_rate_limits returns them.
 static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
   Py_buffer view, b64, b32;
   long long now_ms;
@@ -1528,8 +1558,9 @@ static PyMethodDef methods[] = {
      "Batch FNV-1a64 of name+'_'+key pairs -> (le64 bytes, n)"},
     {"parse_get_rate_limits", parse_get_rate_limits, METH_O,
      "GetRateLimitsReq wire bytes -> packed column buffers (or None)"},
-    {"count_req_items", count_req_items, METH_O,
-     "Top-level scan: count repeated field-1 request TLVs (or None)"},
+    {"count_req_items", count_req_items, METH_VARARGS,
+     "Pre-pass: count repeated field-1 request TLVs; None on foreign "
+     "framing or at the first request with an excluded behavior bit"},
     {"pack_wire_wave", pack_wire_wave, METH_VARARGS,
      "Fused ingest: wire bytes -> clamped rows written into leased "
      "packed wave matrices (or None)"},

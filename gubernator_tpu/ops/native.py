@@ -87,12 +87,14 @@ def stamp_req_tlvs(data: bytes, tlv_off: np.ndarray, tlv_len: np.ndarray,
         int(stamp_ms))
 
 
-def count_req_items(data: bytes):
-    """Top-level-only TLV count of a GetRateLimitsReq /
-    GetPeerRateLimitsReq, or None on framing the fast lane doesn't
-    model.  Lets the fused ingest size its wave bucket (and lease the
-    packed upload buffers) before the single full parse."""
-    return _native.count_req_items(data)
+def count_req_items(data: bytes, excluded: int = 0):
+    """TLV count of a GetRateLimitsReq / GetPeerRateLimitsReq — the
+    fused ingest's pre-pass, which sizes the call's pair before the
+    single full parse — or None on framing the fast lane doesn't model.
+    ``excluded``: Behavior bits the caller's lane does not serve; None
+    at the FIRST request that carries one, before anything is
+    allocated.  0 (the default) reads no request's payload."""
+    return _native.count_req_items(data, int(excluded))
 
 
 def pack_wire_wave(data: bytes, now_ms: int, a64: np.ndarray,

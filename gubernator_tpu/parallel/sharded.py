@@ -22,7 +22,8 @@ from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..hashing import shard_of
-from ..types import RateLimitRequest, RateLimitResponse, Status
+from ..types import (Behavior, RateLimitRequest, RateLimitResponse,
+                     Status)
 from ..core.batch import (PACK32, PACK64, RequestBatch, Rows,  # noqa: F401
                           WaveBufferPool, clock_order, empty_batch,
                           join_calls, pack_requests, stack_rows)
@@ -41,6 +42,10 @@ try:  # fused C++ wire ingest (ops/_native.cpp); optional
 except ImportError:  # pragma: no cover - unbuilt extension
     _wire_native = None
 
+#: the Behavior bit the C++ ingest cannot model (calendar period ends
+#: are computed in Python): its pre-pass declines such a call
+_GREGORIAN = int(Behavior.DURATION_IS_GREGORIAN)
+
 #: TableState value columns addressable by row programs (all but `key`).
 VALUE_COLS = tuple(f for f in TableState._fields if f != "key")
 
@@ -48,18 +53,16 @@ VALUE_COLS = tuple(f for f in TableState._fields if f != "key")
 class PrepackedWave:
     """One fused-ingest call: its rows parsed/clamped/hashed AND laid
     out by C++ in one pass (pack_wire_wave) into a right-sized pair,
-    with what the engine derives of them (``Rows``), plus the
-    per-request metadata the serving lanes gate on."""
+    with what the engine derives of them (``Rows``), plus its size and
+    the per-request views the analytics tap reads.  Which calls the
+    lane serves is settled before one exists (``prepack_wire``)."""
 
-    __slots__ = ("rows", "n", "khash", "behavior_or", "tlv_off",
-                 "tlv_len", "name_hash")
+    __slots__ = ("rows", "n", "khash", "tlv_off", "tlv_len", "name_hash")
 
-    def __init__(self, rows, n, khash, behavior_or, tlv_off, tlv_len,
-                 name_hash):
+    def __init__(self, rows, n, khash, tlv_off, tlv_len, name_hash):
         self.rows = rows
         self.n = n
         self.khash = khash
-        self.behavior_or = behavior_or
         self.tlv_off = tlv_off
         self.tlv_len = tlv_len
         self.name_hash = name_hash
@@ -923,20 +926,24 @@ class ShardedEngine:
 
     # ---- fused wire lane (ops/_native.cpp › pack_wire_wave) ------------
 
-    def prepack_wire(self, data: bytes, now_ms: int):
+    def prepack_wire(self, data: bytes, now_ms: int, excluded: int = 0):
         """Fused C++ wire ingest: one pass from request wire bytes to
         the call's ``Rows`` — parse, validate, clamp (bit-identical to
         pack_columns), key-hash (mixed, zero-remapped), lay out in a
         right-sized pair and derive what ``lay_out`` would, with zero
-        intermediate numpy columns.
+        intermediate numpy columns and the GIL kept throughout.
 
-        Single-shard meshes only; multi-shard and anything the C++ lane
-        can't model (pb2 framing, Gregorian rows, n over the largest
-        bucket) returns None and the caller takes the classic parse →
-        pack_columns path."""
-        if self.n != 1 or _wire_native is None:
+        Any shard count: the block is rows in the call's own order, and
+        the dispatch worker's route (``_device_waves``) is the one place
+        that knows about shards.  None — the caller takes the classic
+        parse → pack_columns path — for what the C++ lane can't model
+        (pb2 framing, Gregorian rows, n over the largest bucket: the
+        classic path splits) and for a call with a row that carries one
+        of the caller's ``excluded`` Behavior bits: the pre-pass stops
+        at the first such row, before the pair exists."""
+        if _wire_native is None:
             return None
-        cnt = _wire_native.count_req_items(data)
+        cnt = _wire_native.count_req_items(data, excluded | _GREGORIAN)
         if not cnt or cnt > self.wave_buckets[-1]:
             return None  # oversize: classic path splits into waves
         rows = Rows.empty(cnt)
@@ -944,9 +951,10 @@ class ShardedEngine:
                                           self.value_domain)
         if res is None:
             return None
+        n, khash, _behavior_or, tlv_off, tlv_len, name_hash, derived = res
         (rows.ood, rows.leaky, rows.now_lo, rows.now_hi,
-         rows.monotone) = res[-1]
-        return PrepackedWave(rows, *res[:-1])
+         rows.monotone) = derived
+        return PrepackedWave(rows, n, khash, tlv_off, tlv_len, name_hash)
 
     def check_batch(self, reqs: Sequence[RateLimitRequest], now_ms: int
                     ) -> List[RateLimitResponse]:

@@ -205,10 +205,13 @@ PHASE_CATALOG: Dict[str, str] = {
     "build": "response wire-byte serialization",
     "call.wait": "handler blocked on its wave's future (queue wait + "
                  "wave, from the caller's side)",
-    "local.pack": "_wire_check_columns: a call of the numpy wire lane "
-                  "hashed, packed and laid out in its own thread "
-                  "(mix64 + pack_columns + lay_out), before it is "
-                  "queued; wall and CPU",
+    "local.pack": "_wire_check_columns: a call the fused C++ ingest "
+                  "declined (Gregorian or MULTI_REGION rows, more rows "
+                  "than the largest bucket, GLOBAL rows on the peer "
+                  "wire, no extension) hashed, packed and laid out in "
+                  "numpy in its own thread (mix64 + pack_columns + "
+                  "lay_out), before it is queued; wall and CPU.  A "
+                  "plain LOCAL call never enters it, on any mesh",
     "route.pack": "_wire_mesh_runner: mix64 + pack_columns + masks",
     "route.keys": "_wire_mesh_runner: the call's mesh rows grouped by "
                   "key in one dict pass: one config per key, pinned "

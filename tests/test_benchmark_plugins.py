@@ -8,9 +8,12 @@ the existing cells' request bytes.  The cell ``r1-leaky-b1000-sat`` is
 judged by these rules; nothing else guards them in CI."""
 import pytest
 
-pytest.register_assert_rewrite("benchmark.tests.test_plugins")
+pytest.register_assert_rewrite("benchmark.tests.test_plugins",
+                              "benchmark.tests.test_fused_ingest_share")
 
 from benchmark.tests.test_plugins import *  # noqa: E402,F401,F403
+# ISSUE 38: the reader of the fused ingest's share, on canned scrapes
+from benchmark.tests.test_fused_ingest_share import *  # noqa: E402,F401,F403
 
 
 # ---- ISSUE 31: the XLA-engine deployment and its readers ----------------

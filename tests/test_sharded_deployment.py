@@ -5,7 +5,9 @@ sharded over the mesh, the population's rows restored onto the four
 shards, 1000-request LOCAL Zipf(1.1) calls over the raw-bytes gRPC front
 door, EVERY answer against the benchmark's own plain token-bucket
 reference (which imports nothing of the program), the first of them from
-restored state, all by the numpy lane ``wire_local`` and the sorted
+restored state, all by the numpy lane ``wire_local`` (1000 rows are
+more than the rehearsal's bucket: the fused ingest's size gate; one
+100-row call then rides the fused lane, ISSUE 38) and the sorted
 route.  Beside it: a one-shard and a four-shard engine answer one seeded
 Zipf stream alike whichever bucket its waves ride, and the shard route's
 counters hold to a hand-made wave."""
@@ -116,11 +118,28 @@ def test_the_sharded_deployment_answers_as_the_plain_reference(
         assert routed == CALLS * PER_CALL
         assert slots % (4 * eng.wave_buckets[0]) == 0 and slots >= routed
         assert routed / 4 < densest <= routed  # skew in (1, 4]
+        # 1000 rows a call are more than the rehearsal's largest bucket
+        # (128 rows a shard), so the fused C++ ingest declined every one
+        # of them — its size gate is per call, on any shard count — and
+        # each was packed in its handler (`local.pack`) and split
         pack = scrape(inst, 'gubernator_phase_duration_count{phase="local.pack"')
         assert sum(pack.values()) == CALLS
         cpu = scrape(inst, "gubernator_phase_cpu_wall_seconds_total"
                            '{phase="local.pack"')
         assert sum(cpu.values()) > 0
+        assert count("gubernator_wire_fused_requests") == 0
+        # a call that fits the bucket rides the fused lane on the mesh
+        # (ISSUE 38) and is answered from the same rows
+        stamp = v0 + CALLS * 2_600
+        idx = draw(rng, mix["keys"], 100, pop["keys"])
+        got = wire.decode_responses(
+            call(tpl.call(tr.key_id(idx, SEED), stamp), timeout=300))
+        want = ref.call(idx, stamp)
+        for f in ("status", "limit", "remaining", "reset_time"):
+            assert (got[f] == want[f]).all(), f
+        assert count("gubernator_wire_fused_requests") == 100
+        assert sum(scrape(inst, 'gubernator_phase_duration_count'
+                                '{phase="local.pack"').values()) == CALLS
     finally:
         chan.close()
         daemon.close()

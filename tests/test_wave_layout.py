@@ -364,9 +364,27 @@ def test_the_ingest_derives_what_lay_out_derives(kind, domain, monkeypatch):
 CEILING = {1: 6, 4: 9}
 
 
+def fused_calls(n_jobs: int):
+    """``n_jobs`` wire calls of 3–7 plain rows, some keys shared."""
+    rng = np.random.default_rng(n_jobs)
+    return [b"".join(
+        req_to_tlv(RateLimitRequest(
+            name="wl", unique_key=(f"k{rng.integers(0, 6)}"
+                                   if rng.random() < 0.4 else f"j{j}:{i}"),
+            hits=1, limit=50, duration=10_000))
+        for i in range(int(rng.integers(3, 8)))) for j in range(n_jobs)]
+
+
+# ``producer``: who laid the jobs' blocks out — ``lay_out`` over loose
+# columns (the numpy lane) or the C++ ingest (``prepack_wire``: the
+# call's own right-sized pair, on any shard count since ISSUE 38).  The
+# worker's ceiling is the same: it joins blocks, whoever made them.
+@pytest.mark.parametrize("producer", ["lay_out", "fused"])
 @pytest.mark.parametrize("shards", CEILING)
 def test_worker_numpy_calls_do_not_grow_with_jobs(numpy_calls, shards,
-                                                  cpu_mesh):
+                                                  producer, cpu_mesh):
+    from gubernator_tpu.dispatcher import _PackedJob
+
     eng = ShardedEngine(cpu_mesh if shards == 4 else make_mesh(n=shards),
                         capacity_per_shard=1 << 10,
                         batch_per_shard=64, wave_buckets=(64, 512))
@@ -375,16 +393,22 @@ def test_worker_numpy_calls_do_not_grow_with_jobs(numpy_calls, shards,
     try:
         counts = {}
         for n_jobs in (2, 2, 8):  # the first wave allocates its lease
-            jobs = jobs_of(n_jobs, n_jobs, "mixed", "plain")
-            packed = [disp_job(disp, b, k, NOW + 10 * i)
-                      for i, (b, k, _) in enumerate(jobs)]
+            if producer == "fused":
+                pres = [eng.prepack_wire(data, NOW + 10 * i)
+                        for i, data in enumerate(fused_calls(n_jobs))]
+                packed = [_PackedJob(pre.rows, pre.khash, NOW + 10 * i)
+                          for i, pre in enumerate(pres)]
+            else:
+                jobs = jobs_of(n_jobs, n_jobs, "mixed", "plain")
+                packed = [disp_job(disp, b, k, NOW + 10 * i)
+                          for i, (b, k, _) in enumerate(jobs)]
             with numpy_calls() as calls:
                 batch, kh, ms, now = disp._concat_jobs(packed)
                 token = eng.launch_packed(batch, kh, now)
             counts[n_jobs] = calls.n
             # one shard: the identity arm; four: routed by shard
             assert (batch.rows.lease is not None) == (shards == 1)
-            eng.sync_packed(token)
+            assert not eng.sync_packed(token)[4].any()
             eng.drop_packed(token)
         assert counts[2] == counts[8] <= CEILING[shards], counts
     finally:

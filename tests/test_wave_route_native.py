@@ -318,3 +318,92 @@ def test_a_build_older_than_the_source_is_refused_not_taken_for_none(
     setattr(stale, native.NEWEST, getattr(_native, native.NEWEST))
     spec.loader.exec_module(probe)
     assert len(probe.route_plan(KH, None, 4, SMALL)) == 1
+
+
+# ---- ISSUE 38: fused blocks on the sorted route --------------------------
+
+def test_fused_and_columns_calls_join_into_one_sorted_wave(cpu_mesh):
+    """Two calls the fused C++ ingest packed (``prepack_wire``, which
+    serves any shard count since ISSUE 38) and one the numpy lane packed
+    (``pack_columns`` + ``lay_out``) are joined by the dispatch worker
+    into ONE wave that takes the sorted route on four shards as ONE
+    device wave — and every row is answered as three calls run one by
+    one answer it: a key that all three calls hit is debited in their
+    order."""
+    from gubernator_tpu.core.batch import pack_columns
+    from gubernator_tpu.dispatcher import Dispatcher, _PackedJob
+    from gubernator_tpu.metrics import Metrics
+    from gubernator_tpu.types import RateLimitRequest
+    from gubernator_tpu.wire import req_to_tlv
+
+    now = 1_792_000_000_000
+
+    def call(tag: str, n: int) -> bytes:
+        reqs = [RateLimitRequest(name="fj", unique_key=f"{tag}{i}", hits=1,
+                                 limit=7, duration=60_000) for i in range(n)]
+        # the key every call shares: limit 4, two hits a call
+        reqs[n // 2:n // 2] = [RateLimitRequest(
+            name="fj", unique_key="shared", hits=1, limit=4,
+            duration=60_000)] * 2
+        return b"".join(req_to_tlv(r) for r in reqs)
+
+    calls = [call("a", 30), call("b", 41), call("c", 23)]
+
+    def engine():
+        eng = ShardedEngine(cpu_mesh, capacity_per_shard=1 << 10,
+                            batch_per_shard=64, wave_buckets=(64, 512))
+        eng.metrics_ref = Metrics()
+        return eng
+
+    def columns_job(eng, data, at):
+        p = native.parse_get_rate_limits(data)
+        kh = mix64_np(p["khash_raw"])
+        b, errs = pack_columns(kh, p["hits"], p["limit"], p["duration"],
+                               p["algorithm"], p["behavior"], p["burst"],
+                               at, created_at=p["created_at"])
+        assert not errs
+        return eng.lay_out(b, kh), kh
+
+    def fused_job(eng, data, at):
+        pre = eng.prepack_wire(data, at)
+        return pre.rows, pre.khash
+
+    # one by one, each call a wave of its own, all on the numpy lane
+    ref = engine()
+    want = []
+    for i, data in enumerate(calls):
+        rows, kh = columns_job(ref, data, now + i)
+        want.append(ref.check_packed(rows.batch, kh, now + i))
+    # one wave: fused, columns, fused
+    eng = engine()
+    disp = Dispatcher(eng)
+    try:
+        jobs = [_PackedJob(*make(eng, data, now + i), now + i)
+                for i, (make, data) in enumerate(zip(
+                    (fused_job, columns_job, fused_job), calls))]
+        # the fused blocks are the call's own right-sized pair
+        assert [len(j.rows) for j in jobs] == [32, 43, 25]
+        batch, kh, ms, at = disp._concat_jobs(jobs)
+        assert batch.rows.lease is None and ms is None  # four shards
+        token = eng.launch_packed(batch, kh, at)
+        assert len(token[3]) == 1  # ONE device wave
+        got = eng.sync_packed(token)
+        eng.drop_packed(token)
+    finally:
+        disp.close()
+    route = eng.metrics_ref.wave_route
+    assert (route.labels(route="sorted")._value.get(),
+            route.labels(route="identity")._value.get()) == (1, 0)
+    assert eng.metrics_ref.wave_native_route._value.get() == 1
+    a = 0
+    for w in want:
+        b = a + len(w[0])
+        for col_got, col_want in zip(got, w):
+            assert col_got[a:b].tolist() == col_want.tolist()
+        a = b
+    assert a == len(got[0]) == 100
+    # the shared key: six hits on a limit of 4, in the calls' order
+    shared = [i for i, k in enumerate(kh.tolist())
+              if kh.tolist().count(k) == 6]
+    assert len(shared) == 6
+    assert [int(got[0][i]) for i in shared] == [0, 0, 0, 0, 1, 1]

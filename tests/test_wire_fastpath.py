@@ -3,6 +3,14 @@ instance.get_rate_limits_wire vs the pb2 object path).
 
 The fast lane must be byte-behavior identical to the slow path for every
 batch it accepts, and must fall back (not misbehave) for everything else.
+
+Every test runs on BOTH columnar lanes and on 1, 2 and 4 shards (ISSUE
+38): ``fused`` — the one C++ pass, ``ShardedEngine.prepack_wire``, which
+serves a table of any shard count — and ``columns`` — the numpy lane
+``_wire_check_columns``, by an engine whose ``prepack_wire`` declines
+every call.  Which lane served a case is ASSERTED, by
+``gubernator_wire_fused_requests_total``: until ISSUE 38 the two-shard
+mesh these tests build silently tested the numpy lane alone.
 """
 import numpy as np
 import pytest
@@ -23,10 +31,46 @@ if _wire_native is None:  # pragma: no cover
 
 NOW = 1_766_000_000_000
 
+#: the lane and the shard count of the running case (``lane`` below)
+LANE, SHARDS = "fused", 2
 
-def mk_instance():
-    return V1Instance(Config(cache_size=1 << 12, sweep_interval_ms=0),
-                      mesh=make_mesh(n=2))
+
+@pytest.fixture(autouse=True,
+                params=[(lane, n) for lane in ("fused", "columns")
+                        for n in (1, 2, 4)],
+                ids=lambda p: f"{p[0]}-{p[1]}shards")
+def lane(request):
+    global LANE, SHARDS
+    LANE, SHARDS = request.param
+
+
+def decline_prepack(inst) -> None:
+    """The engine of the ``columns`` lane: one whose ``prepack_wire``
+    declines every call, as an engine without the C++ ingest would."""
+    inst.engine.prepack_wire = lambda *a, **kw: None
+
+
+def fused_rows(inst) -> int:
+    """``gubernator_wire_fused_requests_total``: rows that came in by
+    the one C++ pass."""
+    return int(inst.metrics.wire_fused_counter._value.get())
+
+
+def served_by_lane(inst, rows: int) -> None:
+    """``rows`` rows of what ``inst`` served were eligible for the fused
+    lane: on the fused lane it took exactly those, on the columns lane
+    none."""
+    assert fused_rows(inst) == (rows if LANE == "fused" else 0)
+
+
+def mk_instance(**cfg):
+    inst = V1Instance(
+        Config(**{"cache_size": 1 << 12, "sweep_interval_ms": 0, **cfg}),
+        mesh=make_mesh(n=SHARDS))
+    assert inst.engine.n == SHARDS
+    if LANE == "columns":
+        decline_prepack(inst)
+    return inst
 
 
 def to_wire(reqs):
@@ -35,13 +79,15 @@ def to_wire(reqs):
     return m.SerializeToString()
 
 
-def run_both(reqs, now=NOW):
+def run_both(reqs, now=NOW, fused=None):
     """Same request stream through a fast-lane instance and a slow-path
-    instance; returns (fast pb2 responses, slow responses)."""
+    instance; returns (fast pb2 responses, slow responses).  ``fused``:
+    the rows of it the fused lane serves (default: all)."""
     fast, slow = mk_instance(), mk_instance()
     try:
         out = pb.GetRateLimitsResp.FromString(
             fast.get_rate_limits_wire(to_wire(reqs), now_ms=now))
+        served_by_lane(fast, len(reqs) if fused is None else fused)
         slow_rs = slow.get_rate_limits(reqs, now_ms=now)
         return list(out.responses), slow_rs
     finally:
@@ -89,7 +135,7 @@ def test_parity_gregorian_and_invalid_ordinal():
                          duration=int(GregorianDuration.HOURS),
                          behavior=Behavior.DURATION_IS_GREGORIAN),
     ]
-    fast, slow = run_both(reqs)
+    fast, slow = run_both(reqs, fused=0)  # the C++ pass has no calendar
     assert fast[1].error and "gregorian" in fast[1].error
     assert_match(fast, slow)
 
@@ -113,7 +159,7 @@ def test_fallback_paths_still_correct():
         RateLimitRequest(name="gl", unique_key="k", hits=1, limit=5,
                          duration=10_000, behavior=Behavior.GLOBAL),
     ]
-    fast, slow = run_both(reqs)
+    fast, slow = run_both(reqs, fused=0)
     assert fast[1].error  # empty unique_key surfaces as error response
     assert_match(fast, slow)
 
@@ -133,6 +179,7 @@ def test_empty_batch_returns_empty_response():
             inst.get_rate_limits_wire(
                 pb.GetRateLimitsReq().SerializeToString(), now_ms=NOW))
         assert len(out.responses) == 0
+        served_by_lane(inst, 0)
     finally:
         inst.close()
 
@@ -171,6 +218,7 @@ def test_oversize_batch_raises():
                 for i in range(1001)]
         with pytest.raises(ValueError, match="too large"):
             inst.get_rate_limits_wire(to_wire(reqs), now_ms=NOW)
+        served_by_lane(inst, 0)
     finally:
         inst.close()
 
@@ -186,6 +234,7 @@ def test_sequential_state_carries_across_wire_calls():
                 inst.get_rate_limits_wire(data, now_ms=NOW + i))
             statuses.append(int(out.responses[0].status))
         assert statuses == [0, 0, 0, 1, 1]
+        served_by_lane(inst, 5)
     finally:
         inst.close()
 
@@ -193,10 +242,7 @@ def test_sequential_state_carries_across_wire_calls():
 def test_wire_lane_auto_grows_under_live_pressure():
     """The wire lane inherits auto-grow: a tiny table fills with live
     keys and capacity doubles instead of surfacing 'table full'."""
-    inst = V1Instance(
-        Config(cache_size=1 << 8, cache_autogrow_max=1 << 14,
-               sweep_interval_ms=0),
-        mesh=make_mesh(n=2))
+    inst = mk_instance(cache_size=1 << 8, cache_autogrow_max=1 << 14)
     try:
         reqs = [RateLimitRequest(name="wag", unique_key=f"k{i}", hits=1,
                                  limit=9, duration=10**7)
@@ -209,5 +255,6 @@ def test_wire_lane_auto_grows_under_live_pressure():
         out = pb.GetRateLimitsResp.FromString(
             inst.get_rate_limits_wire(to_wire(reqs), now_ms=NOW + 1))
         assert {r.remaining for r in out.responses} == {7}
+        served_by_lane(inst, 1800)
     finally:
         inst.close()

@@ -55,17 +55,30 @@ def _wire(reqs):
     return m.SerializeToString()
 
 
-@settings(max_examples=_FX * 20, deadline=None,
+#: lane × shards (ISSUE 38): the fused C++ ingest serves any shard
+#: count; ``columns`` is the numpy lane, by an engine whose
+#: ``prepack_wire`` declines.  The budget of examples the one two-shard
+#: case had (20, on the numpy lane alone) is spread over the six.
+LANES = [(lane, n) for lane in ("fused", "columns") for n in (1, 2, 4)]
+
+
+@pytest.mark.parametrize("lane,shards", LANES,
+                         ids=[f"{la}-{n}shards" for la, n in LANES])
+@settings(max_examples=_FX * 6, deadline=None,
           suppress_health_check=[HealthCheck.too_slow])
 @given(_stream)
-def test_wire_lane_matches_oracle_on_any_stream(stream):
+def test_wire_lane_matches_oracle_on_any_stream(lane, shards, stream):
     inst = V1Instance(Config(cache_size=1 << 11, sweep_interval_ms=0),
-                      mesh=make_mesh(n=2))
+                      mesh=make_mesh(n=shards))
+    if lane == "columns":
+        inst.engine.prepack_wire = lambda *a, **kw: None
     try:
         oracle = Oracle()
         now = NOW
+        rows = 0
         for reqs, dt in stream:
             now += dt
+            rows += len(reqs)
             want = oracle.check_batch(reqs, now)
             out = pb.GetRateLimitsResp.FromString(
                 inst.get_rate_limits_wire(_wire(reqs), now_ms=now))
@@ -75,5 +88,8 @@ def test_wire_lane_matches_oracle_on_any_stream(stream):
                 assert (int(g.status), g.remaining, g.reset_time,
                         g.limit) == (int(w.status), w.remaining,
                                      w.reset_time, w.limit), (i, reqs[i])
+        # WHICH lane answered: every call of the strategy is eligible
+        assert inst.metrics.wire_fused_counter._value.get() == (
+            rows if lane == "fused" else 0)
     finally:
         inst.close()

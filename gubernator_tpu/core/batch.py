@@ -20,6 +20,7 @@ import numpy as np
 
 from ..gregorian import gregorian_expiration, gregorian_rate_duration_ms
 from ..hashing import hash_keys
+from ..tracing import phase
 from ..types import (DURATION_MAX, EFF_MAX, TD_BOUND, VALUE_MAX, Behavior,
                      RateLimitRequest)
 
@@ -113,13 +114,15 @@ class Rows:
     (``ShardedEngine.lay_out``; ``monotone is None`` = not derived):
     ``ood`` the indices of valid rows outside its step program's value
     domain (None = none), ``leaky`` the count of LEAKY_BUCKET rows that
-    stay valid, ``now_lo`` / ``now_hi`` / ``monotone`` the range of the
+    stay valid, ``greg`` the count of valid DURATION_IS_GREGORIAN rows
+    (None = not counted yet; ``pack_columns`` knows it for free),
+    ``now_lo`` / ``now_hi`` / ``monotone`` the range of the
     arrival-time row and whether it never decreases.  A wave joined
     straight into a pooled upload pair carries its ``lease`` (and the
     mesh-slot block ``mblk``); ``m64`` / ``m32`` are then views of it
     and die with it."""
 
-    __slots__ = ("m64", "m32", "ood", "leaky", "now_lo", "now_hi",
+    __slots__ = ("m64", "m32", "ood", "leaky", "greg", "now_lo", "now_hi",
                  "monotone", "lease", "mblk")
 
     def __init__(self, m64, m32):
@@ -127,6 +130,7 @@ class Rows:
         self.m32 = m32
         self.ood = None
         self.leaky = 0
+        self.greg = None
         self.now_lo = self.now_hi = 0
         self.monotone = None
         self.lease = None
@@ -444,17 +448,20 @@ def pack_requests(
     GREG = int(Behavior.DURATION_IS_GREGORIAN)  # hot loop: plain-int flags
     b.now[:n] = now_ms
     for i, r in enumerate(reqs):
+        at = now_ms
         if r.created_at:
             # caller's accepted-at clock (forward hop, types.py): the
             # request applies at ITS time base, so a key served through
-            # two daemons never mixes bases in one bucket row
-            b.now[i] = r.created_at
+            # two daemons never mixes bases in one bucket row — and its
+            # calendar period is the one that holds that clock
+            # (gregorian.py, the rule)
+            b.now[i] = at = r.created_at
         behavior = int(r.behavior)
         leaky = int(r.algorithm) == 1
         duration = min(int(r.duration), DURATION_MAX)
         if behavior & GREG:
             try:
-                b.greg_end[i] = gregorian_expiration(now_ms, duration)
+                b.greg_end[i] = gregorian_expiration(at, duration)
                 eff = gregorian_rate_duration_ms(duration)
             except (ValueError, KeyError):
                 errors[i] = f"invalid gregorian duration ordinal: {duration}"
@@ -495,6 +502,7 @@ def pack_columns(
     burst: np.ndarray,
     now_ms: int,
     created_at: np.ndarray | None = None,
+    sink=None,
 ) -> tuple[RequestBatch, dict]:
     """Vectorized pack of already-columnar requests (the C++ wire-ingest
     lane, ops/_native.cpp › parse_get_rate_limits) → RequestBatch.
@@ -506,9 +514,13 @@ def pack_columns(
 
     ``created_at`` (optional i64[n], 0 = unset) is the caller's
     accepted-at clock from the forward hop: rows carrying it take it as
-    their ``now`` so they apply at the CALLER's time base (Gregorian
-    period ends still derive from ``now_ms`` — calendar rows never ride
-    the forward stamp).
+    their ``now`` so they apply at the CALLER's time base, and a
+    Gregorian row's period is the one that holds that clock
+    (gregorian.py, the rule) — one period end per distinct (ordinal,
+    period) of the call, never a loop over rows.  ``sink`` (the
+    dispatcher) takes the ``pack.calendar`` phase round that
+    arithmetic; the batch's ``rows.greg`` is the count of its valid
+    Gregorian rows.
     """
     n = len(khash)
     # ONE pair in the upload layout, filled in place: the batch handed
@@ -516,31 +528,24 @@ def pack_columns(
     # block into its wave without copying a column again
     rows = Rows.empty(n)
     b = rows.batch
-    key_col, greg_end, valid, behavior32 = (b.key, b.greg_end, b.valid,
-                                            b.behavior)
-    key_col[:] = khash
-    greg_end[:] = 0
+    b.key[:] = khash
+    b.greg_end[:] = 0
     rows.m32[_VALID] = 1
-    behavior32[:] = behavior
+    b.behavior[:] = behavior
     dur = np.minimum(np.asarray(duration, np.int64), DURATION_MAX,
                      out=b.duration)
     eff = np.maximum(dur, 1)
     errors: dict = {}
-    greg = (behavior32 & int(Behavior.DURATION_IS_GREGORIAN)) != 0
-    if greg.any():
-        # ≤ a handful of distinct calendar ordinals per batch: compute
-        # each period end once on the host, broadcast to its requests
-        for d in np.unique(dur[greg]):
-            m = greg & (dur == d)
-            try:
-                greg_end[m] = gregorian_expiration(now_ms, int(d))
-                eff[m] = gregorian_rate_duration_ms(int(d))
-            except (ValueError, KeyError):
-                valid[m] = False
-                key_col[m] = 0
-                msg = f"invalid gregorian duration ordinal: {int(d)}"
-                for i in np.nonzero(m)[0]:
-                    errors[int(i)] = msg
+    b.now[:] = now_ms
+    if created_at is not None:
+        created = np.asarray(created_at, np.int64)
+        np.copyto(b.now, created, where=created > 0)
+    rows.greg = 0
+    greg = np.flatnonzero(b.behavior & int(Behavior.DURATION_IS_GREGORIAN))
+    if len(greg):
+        with phase("pack.calendar", sink, cpu=True,
+                   every=getattr(sink, "call_sample", 1)):
+            rows.greg = _calendar_ends(b, greg, eff, errors)
     # leaky td bounds (oracle.py › _clamp_leaky): eff ≤ EFF_MAX and
     # hits/limit/burst ≤ TD_BOUND // eff; token values ≤ VALUE_MAX
     leaky = np.asarray(algorithm) == 1
@@ -553,8 +558,50 @@ def pack_columns(
     np.minimum(np.clip(np.asarray(hits, np.int64), 0, None), cap_v,
                out=b.hits)
     b.burst[:] = np.where(burst > 0, np.minimum(burst, cap_v), lim)
-    b.now[:] = now_ms
-    if created_at is not None:
-        created = np.asarray(created_at, np.int64)
-        np.copyto(b.now, created, where=created > 0)
     return b, errors
+
+
+def _one_value(col: np.ndarray) -> bool:
+    """Whether a non-empty column holds one value throughout — as bytes
+    (a copy and a compare that keep the GIL), not as a reduction."""
+    raw = col.tobytes()
+    return raw == raw[:col.itemsize] * len(col)
+
+
+def _calendar_ends(b: "PackedBatch", at: np.ndarray, eff: np.ndarray,
+                   errors: dict) -> int:
+    """``greg_end`` and ``eff`` of a call's Gregorian rows (indices
+    ``at``), each row's period the one that holds ITS clock (``b.now``),
+    and the count of them that stay valid.  One period end a distinct
+    (ordinal, clock) pair — a client's call carries one stamp, so one
+    pair — broadcast to the pair's rows; rows of an invalid ordinal are
+    made invalid and reported.
+
+    The pairs are found by comparing bytes, else in a ``set`` over
+    ``tolist()``, not by ``np.unique``: this runs in ~30 handler threads
+    under one GIL, and every numpy call that gives the GIL up (sorts,
+    reductions, ufuncs over a call's 1,000 rows) has to win it back
+    from the others (PERF.md §6, PR 25 and PR 39: 12.7 ms a call with
+    ``np.unique``, 0.8 without)."""
+    whole = len(at) == len(b.key)
+    sel = slice(None) if whole else at
+    dur, now = b.duration[sel], b.now[sel]
+    if _one_value(dur) and _one_value(now):
+        pairs = {(int(dur[0]), int(now[0]))}
+    else:
+        pairs = set(zip(dur.tolist(), now.tolist()))
+    valid = len(at)
+    for d, t in pairs:
+        rows = sel if len(pairs) == 1 else at[(dur == d) & (now == t)]
+        try:
+            end = gregorian_expiration(t, d)
+            eff[rows] = gregorian_rate_duration_ms(d)
+            b.greg_end[rows] = end
+        except (ValueError, KeyError):
+            bad = np.arange(len(b.key))[rows]
+            valid -= len(bad)
+            b.valid[bad] = False
+            b.key[bad] = 0
+            errors.update(dict.fromkeys(
+                bad.tolist(), f"invalid gregorian duration ordinal: {d}"))
+    return valid

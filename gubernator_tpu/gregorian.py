@@ -6,6 +6,23 @@ reference's holster gregorian helpers (algorithms.go › tokenBucket's
 GregorianExpiration call — reconstructed): the bucket expires at the END
 of the current calendar period in UTC, so every key resets at the period
 boundary.
+
+THE RULE, stated here once (``types.py`` beside ``created_at`` points
+here; ``core/batch.py › pack_requests`` / ``› pack_columns`` and
+``oracle.py › Oracle.check`` apply it): **a request's calendar period
+is the one that holds the clock the request is APPLIED at** — its
+``created_at`` stamp where it carries one, the serving daemon's clock
+where it carries none.  ``now`` and ``greg_end`` of one row are never
+taken from two clocks.  Upstream reads ``clock.Now()`` for both, so the
+two agree there by construction; here a forwarded request applies at its
+caller's stamp (``types.RateLimitRequest.created_at``), and a period end
+taken from the wall clock would open a bucket at 23:59:59.9 on the stamp
+that expires with TOMORROW's period on the wall clock — or, for a caller
+whose clock runs ahead, one that is born expired and never denies.
+
+MINUTES, HOURS, DAYS and WEEKS are integer divisions of epoch-ms (the
+epoch began on a Thursday at 00:00 UTC, weeks start on Monday); MONTHS
+and YEARS keep the calendar.
 """
 from __future__ import annotations
 
@@ -15,44 +32,38 @@ import datetime as _dt
 from .types import GREGORIAN_APPROX_MS, GregorianDuration
 
 _UTC = _dt.timezone.utc
-
-
-def _from_ms(ms: int) -> _dt.datetime:
-    return _dt.datetime.fromtimestamp(ms / 1000.0, tz=_UTC)
-
-
-def _to_ms(dt: _dt.datetime) -> int:
-    return int(dt.timestamp() * 1000)
+_DAY_MS = 86_400_000
+#: period length in ms of the ordinals whose periods all have one length
+_FIXED_MS = {
+    int(GregorianDuration.MINUTES): 60_000,
+    int(GregorianDuration.HOURS): 3_600_000,
+    int(GregorianDuration.DAYS): _DAY_MS,
+    int(GregorianDuration.WEEKS): 7 * _DAY_MS,
+}
+#: 1970-01-01 was a Thursday: the Monday before it lies 3 days back
+_WEEK_SHIFT_MS = 3 * _DAY_MS
 
 
 def gregorian_expiration(now_ms: int, ordinal: int) -> int:
-    """Epoch-ms of the end of the calendar period containing ``now_ms``.
+    """Epoch-ms of the end of the calendar period containing ``now_ms``
+    — the clock the request is applied at (the module's rule).
 
     ``ordinal`` is a GregorianDuration value.  Raises ValueError on an
     unknown ordinal (the reference surfaces this as a per-request error).
     """
-    d = GregorianDuration(ordinal)  # raises ValueError if out of range
-    now = _from_ms(now_ms)
-    if d == GregorianDuration.MINUTES:
-        start = now.replace(second=0, microsecond=0)
-        end = start + _dt.timedelta(minutes=1)
-    elif d == GregorianDuration.HOURS:
-        start = now.replace(minute=0, second=0, microsecond=0)
-        end = start + _dt.timedelta(hours=1)
-    elif d == GregorianDuration.DAYS:
-        start = now.replace(hour=0, minute=0, second=0, microsecond=0)
-        end = start + _dt.timedelta(days=1)
-    elif d == GregorianDuration.WEEKS:
-        day0 = now.replace(hour=0, minute=0, second=0, microsecond=0)
-        start = day0 - _dt.timedelta(days=now.weekday())  # Monday start
-        end = start + _dt.timedelta(weeks=1)
-    elif d == GregorianDuration.MONTHS:
-        ndays = calendar.monthrange(now.year, now.month)[1]
-        start = now.replace(day=1, hour=0, minute=0, second=0, microsecond=0)
-        end = start + _dt.timedelta(days=ndays)
+    d = int(GregorianDuration(ordinal))  # raises ValueError if out of range
+    now_ms = int(now_ms)
+    width = _FIXED_MS.get(d)
+    if width is not None:
+        shift = _WEEK_SHIFT_MS if d == GregorianDuration.WEEKS else 0
+        return (now_ms + shift) // width * width + width - shift
+    now = _dt.datetime.fromtimestamp(now_ms // 1000, tz=_UTC)
+    if d == GregorianDuration.MONTHS:
+        year, month = ((now.year, now.month + 1) if now.month < 12
+                       else (now.year + 1, 1))
     else:  # YEARS
-        end = _dt.datetime(now.year + 1, 1, 1, tzinfo=_UTC)
-    return _to_ms(end)
+        year, month = now.year + 1, 1
+    return calendar.timegm((year, month, 1, 0, 0, 0)) * 1000
 
 
 def gregorian_rate_duration_ms(ordinal: int) -> int:

@@ -926,6 +926,7 @@ class V1Instance:
         parsed = None
         is_global = False
         clustered = False
+        declined = False
         if _wire_native is not None and self.store is None:
             peer_list = self.peers()
             if not peer_list or all(self.is_self(p) for p in peer_list):
@@ -938,9 +939,12 @@ class V1Instance:
                 out = self._wire_client_fused(data, now_ms)
                 if out is not None:
                     return out
+                declined = True
             ing = phase("ingest", self.dispatcher).begin()
             parsed = _wire_native.parse_get_rate_limits(data)
             ing.end(keep=parsed is not None)
+            if declined:
+                self._count_fused_declined(parsed)
             if parsed is not None:
                 is_global = bool(parsed["behavior_or"]
                                  & int(Behavior.GLOBAL))
@@ -1053,6 +1057,28 @@ class V1Instance:
     #: before it packs anything: an all-GLOBAL call costs this lane one
     #: request's header.
     _FUSED_EXCLUDED = Behavior.GLOBAL | Behavior.MULTI_REGION
+
+    def _count_fused_declined(self, parsed: Optional[dict]) -> None:
+        """``gubernator_wire_fused_declined{reason}``: one call the
+        fused ingest refused, by why — read off what the classic parse
+        that follows a refusal has in hand anyway (``behavior_or``,
+        ``n``), so the refusal itself stays one request's header.  A
+        call with rows of several kinds counts under the first of
+        global, multi_region, gregorian that any row carries."""
+        if not hasattr(self.engine, "prepack_wire"):
+            return  # no fused lane: nothing was refused
+        reason = "other"  # framing the C++ lanes do not model, 0 rows
+        if parsed is not None:
+            b = parsed["behavior_or"]
+            if b & int(Behavior.GLOBAL):
+                reason = "global"
+            elif b & int(Behavior.MULTI_REGION):
+                reason = "multi_region"
+            elif b & int(Behavior.DURATION_IS_GREGORIAN):
+                reason = "gregorian"
+            elif parsed["n"] > self.engine.wave_buckets[-1]:
+                reason = "too_large"
+        self.metrics.wire_fused_declined.labels(reason=reason).inc()
 
     def _wire_client_fused(self, data: bytes,
                            now_ms: Optional[int]) -> Optional[bytes]:
@@ -1211,6 +1237,8 @@ class V1Instance:
             ing = phase("ingest", self.dispatcher).begin()
             parsed = _wire_native.parse_get_rate_limits(data)
             ing.end(keep=parsed is not None)
+            if not gate_rehome:
+                self._count_fused_declined(parsed)
         if parsed is None:
             from google.protobuf.message import DecodeError
 
@@ -1433,7 +1461,7 @@ class V1Instance:
         batch, errs = pack_columns(
             kh, parsed["hits"], parsed["limit"], parsed["duration"],
             parsed["algorithm"], parsed["behavior"], parsed["burst"], now,
-            created_at=parsed.get("created_at"))
+            created_at=parsed.get("created_at"), sink=self.dispatcher)
         beh = np.asarray(batch.behavior)
         glob_mask = (beh & int(Behavior.GLOBAL)) != 0
         excluded = (beh & int(self._HOT_EXCLUDED)) != 0
@@ -1545,7 +1573,8 @@ class V1Instance:
             batch, errs = pack_columns(
                 kh, parsed["hits"], parsed["limit"], parsed["duration"],
                 parsed["algorithm"], parsed["behavior"], parsed["burst"],
-                now, created_at=parsed.get("created_at"))
+                now, created_at=parsed.get("created_at"),
+                sink=self.dispatcher)
             beh = np.asarray(batch.behavior)
             glob_mask = (beh & int(Behavior.GLOBAL)) != 0
             excluded = (beh & int(self._HOT_EXCLUDED)) != 0
@@ -1739,7 +1768,7 @@ class V1Instance:
             batch, errs = pack_columns(
                 kh, parsed["hits"], parsed["limit"], parsed["duration"],
                 parsed["algorithm"], parsed["behavior"], parsed["burst"],
-                now, created_at=parsed.get("created_at"))
+                now, created_at=parsed.get("created_at"), sink=disp)
             disp.lay_out(batch, kh, None)  # check_packed_view finds it done
         return self._packed_batch_to_bytes(batch, errs, kh, now)
 

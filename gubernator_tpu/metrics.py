@@ -96,6 +96,17 @@ class Metrics:
             "requests the fused C++ wire ingest (prepack_wire) parsed "
             "and laid out in one pass; a subset of "
             "gubernator_wire_lane_requests", registry=r)
+        self.wire_fused_declined = Counter(
+            "gubernator_wire_fused_declined",
+            "calls the fused C++ wire ingest refused, by why: global / "
+            "multi_region (the lane's policy, _FUSED_EXCLUDED) / "
+            "gregorian (pack_wire_wave models no calendar) — the first "
+            "of the three that any row of the call carries —, too_large "
+            "(more rows than the largest wave bucket), other (framing "
+            "the C++ lanes do not model, an empty call); counted where "
+            "the classic parse that follows a refusal has behavior_or "
+            "and n in hand (instance.py › _count_fused_declined)",
+            ["reason"], registry=r)
         self.hot_demotion_counter = Counter(
             "gubernator_hotset_demotions",
             "hot-set pinned keys demoted back to the sharded path",
@@ -146,6 +157,19 @@ class Metrics:
             "(valid, inside the step program's domain, not cold-tier "
             "served), counted once a wave at the engine's wave.route",
             registry=r)
+        self.wave_gregorian_rows = Counter(
+            "gubernator_wave_gregorian_rows",
+            "DURATION_IS_GREGORIAN rows that entered a wave's device "
+            "program (valid, not cold-tier served), counted beside "
+            "gubernator_wave_leaky_rows from the counts each call's "
+            "handler took while it packed (pack_columns; lay_out for "
+            "loose columns)", registry=r)
+        self.wave_created_rows = Counter(
+            "gubernator_wave_created_rows",
+            "rows a wave opened in the table (keys it found no row "
+            "for): the insert_count every step program already returns, "
+            "added where a wave's counters reach the host "
+            "(ShardedEngine._download_wave)", registry=r)
         self.wave_route = Counter(
             "gubernator_wave_route",
             "device waves by how their rows reached the upload buffers: "

@@ -52,7 +52,9 @@ workloads is exact.  Domain: every td product is kept ≤ TD_BOUND (2^61)
 by the input clamps below plus two in-kernel guards (rescale/replenish —
 see the comment block above ``_clamp_token``).
 
-- Gregorian ordinals use the calendar for token expiry; the leak rate for
+- Gregorian ordinals use the calendar for token expiry — the period that
+  holds the clock the request is applied at, its ``created_at`` stamp
+  where it carries one (gregorian.py states the rule); the leak rate for
   leaky uses the fixed-width approximation (GREGORIAN_APPROX_MS).
 - duration change rescales td to the new denominator (whole tokens exact,
   fractional part floor-rounded).
@@ -133,6 +135,9 @@ def _eff_duration_ms(duration: int, behavior: int) -> int:
 
 
 def _token_expire(now_ms: int, created_ms: int, duration: int, behavior: int) -> int:
+    """``now_ms`` is the clock the request is applied at (``Oracle.check``
+    hands a stamped request's stamp): a calendar period is the one that
+    holds it (gregorian.py, the rule)."""
     if behavior & Behavior.DURATION_IS_GREGORIAN:
         return gregorian_expiration(now_ms, duration)
     return created_ms + max(int(duration), 1)
@@ -325,6 +330,12 @@ class Oracle:
         self.items: Dict[str, Item] = {}
 
     def check(self, req: RateLimitRequest, now_ms: int) -> RateLimitResponse:
+        # the clock a request is APPLIED at: its created_at stamp where
+        # it carries one, as the packers take it (core/batch.py) — and so
+        # the clock its calendar period is read from (gregorian.py, the
+        # rule): now and period end never come from two clocks
+        if req.created_at:
+            now_ms = int(req.created_at)
         key = req.key
         item = self.items.get(key)
         if int(req.algorithm) == Algorithm.LEAKY_BUCKET:

@@ -481,6 +481,9 @@ class ShardedEngine:
         rows = stack_rows(batch)
         if rows.monotone is not None:
             return rows  # derived already (the C++ ingest, an earlier call)
+        if rows.greg is None:  # loose columns: pack_columns counts its own
+            rows.greg = int(np.count_nonzero(
+                rows.valid & ((rows.m32[0] & _GREGORIAN) != 0)))
         if _wire_native is not None:
             # one C++ pass that keeps the GIL: this runs in ~30 handler
             # threads at once, and every numpy call of the fallback
@@ -542,6 +545,7 @@ class ShardedEngine:
         wave.ood = (None if not oods else oods[0] if len(oods) == 1
                     else np.concatenate(oods))
         wave.leaky = sum(c.leaky for c in calls)
+        wave.greg = sum(c.greg or 0 for c in calls)
         wave.monotone = mono
         return wave, khash, mslot
 
@@ -558,8 +562,9 @@ class ShardedEngine:
     def _ride_invalid(self, wave: Rows, khash, mslot):
         """`wave.route`, first half: (the wave's valid column with the
         out-of-domain and the cold-tier rows cleared — None where no
-        row is —, cold mask, the valid rows before it), and the count
-        of ``gubernator_wave_leaky_rows``.
+        row is —, cold mask, the valid rows before it), and the counts
+        of ``gubernator_wave_leaky_rows`` and
+        ``gubernator_wave_gregorian_rows``.
 
         Tiered store (tiering.py): cold-resident rows must NOT hit the
         device table (a non-full table would insert them fresh — a
@@ -569,7 +574,7 @@ class ShardedEngine:
         if wave.ood is not None:
             valid = wave.valid.copy()
             valid[wave.ood] = False
-        leaky = wave.leaky
+        leaky, greg = wave.leaky, wave.greg or 0
         cold = orig_valid = None
         tier = self.tier
         if tier is not None:
@@ -583,7 +588,10 @@ class ShardedEngine:
                 if leaky:
                     leaky -= int(np.count_nonzero(
                         wave.algorithm[cold] == 1))
-        self._count_leaky_rows(leaky)
+                if greg:
+                    greg -= int(np.count_nonzero(
+                        wave.m32[0][cold] & _GREGORIAN))
+        self._count_wave_rows(leaky, greg)
         return valid, cold, orig_valid
 
     def _device_waves(self, wave: Rows, khash, mslot, valid, pending=None):
@@ -781,12 +789,17 @@ class ShardedEngine:
         if m is not None and (n := int(np.count_nonzero(full))):
             m.table_full_rows.inc(n)
 
-    def _count_leaky_rows(self, n: int) -> None:
-        """``gubernator_wave_leaky_rows``: the LEAKY_BUCKET rows of one
-        wave that go on to the device program."""
+    def _count_wave_rows(self, leaky: int, greg: int) -> None:
+        """``gubernator_wave_leaky_rows`` and
+        ``gubernator_wave_gregorian_rows``: the LEAKY_BUCKET and the
+        DURATION_IS_GREGORIAN rows of one wave that go on to the device
+        program."""
         m = self.metrics_ref
-        if n and m is not None:
-            m.wave_leaky_rows.inc(n)
+        if m is not None:
+            if leaky:
+                m.wave_leaky_rows.inc(leaky)
+            if greg:
+                m.wave_gregorian_rows.inc(greg)
 
     def sync_packed(self, token, engine_lock=None) -> tuple:
         """Pipeline phase 2: block on the launched waves and assemble
@@ -915,7 +928,11 @@ class ShardedEngine:
     def _download_wave(self, packed, counters):
         out = np.asarray(packed)
         self.over_count += int(counters[0])
-        self.insert_count += int(counters[1])
+        created = int(counters[1])
+        self.insert_count += created
+        m = self.metrics_ref
+        if created and m is not None:
+            m.wave_created_rows.inc(created)
         return out[0], out[1], out[2], out[3], out[4] != 0
 
     def _run_wave(self, glob: RequestBatch, now_ms: int):
@@ -954,6 +971,7 @@ class ShardedEngine:
         n, khash, _behavior_or, tlv_off, tlv_len, name_hash, derived = res
         (rows.ood, rows.leaky, rows.now_lo, rows.now_hi,
          rows.monotone) = derived
+        rows.greg = 0  # the pre-pass declined every Gregorian row
         return PrepackedWave(rows, n, khash, tlv_off, tlv_len, name_hash)
 
     def check_batch(self, reqs: Sequence[RateLimitRequest], now_ms: int

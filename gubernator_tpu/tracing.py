@@ -208,10 +208,19 @@ PHASE_CATALOG: Dict[str, str] = {
     "local.pack": "_wire_check_columns: a call the fused C++ ingest "
                   "declined (Gregorian or MULTI_REGION rows, more rows "
                   "than the largest bucket, GLOBAL rows on the peer "
-                  "wire, no extension) hashed, packed and laid out in "
+                  "wire, no extension; which, and how often: "
+                  "gubernator_wire_fused_declined{reason}, counted at "
+                  "instance.py › _count_fused_declined) hashed, packed "
+                  "and laid out in "
                   "numpy in its own thread (mix64 + pack_columns + "
                   "lay_out), before it is queued; wall and CPU.  A "
                   "plain LOCAL call never enters it, on any mesh",
+    "pack.calendar": "pack_columns: the period ends of a call's "
+                     "DURATION_IS_GREGORIAN rows, one a distinct "
+                     "(ordinal, clock) pair, each on the clock its row "
+                     "is applied at (gregorian.py); inside local.pack "
+                     "or route.pack, wall and CPU, sampled as they are; "
+                     "a call without such rows never enters it",
     "route.pack": "_wire_mesh_runner: mix64 + pack_columns + masks",
     "route.keys": "_wire_mesh_runner: the call's mesh rows grouped by "
                   "key in one dict pass: one config per key, pinned "

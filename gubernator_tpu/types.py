@@ -131,7 +131,10 @@ class RateLimitRequest:
     #: the earlier-base row as expired — the bucket resets and every
     #: prior debit is silently discarded (the concurrent cold-key
     #: conservation loss; cross-daemon clock skew does the same to
-    #: short-duration limits in production).
+    #: short-duration limits in production).  A DURATION_IS_GREGORIAN
+    #: request's calendar period is the one that holds THIS clock too
+    #: (gregorian.py states the rule): ``now`` and the period end of one
+    #: row never come from two clocks.
     created_at: int = 0
     metadata: Dict[str, str] = field(default_factory=dict)
 

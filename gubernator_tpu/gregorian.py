@@ -1,7 +1,12 @@
 """Calendar-period expiry for DURATION_IS_GREGORIAN.
 
 Host-side only: the device compares integer millisecond timestamps, the
-host does calendars (SURVEY.md §7.3).  Mirrors the behavior of the
+host does calendars (SURVEY.md §7.3) — here, and in the fused wire
+ingest's C++ twin of ``gregorian_expiration`` (``ops/_native.cpp ›
+Period``, which ``pack_wire_wave`` asks a calendar row's period end of;
+``tests/test_native_calendar.py`` holds it to this module ms for ms,
+and this module stays the rule and the classic lane's calendar).
+Mirrors the behavior of the
 reference's holster gregorian helpers (algorithms.go › tokenBucket's
 GregorianExpiration call — reconstructed): the bucket expires at the END
 of the current calendar period in UTC, so every key resets at the period

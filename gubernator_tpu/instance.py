@@ -933,8 +933,9 @@ class V1Instance:
                 # solo fused lane: bytes → the call's block → wave → device
                 # → bytes in one C++ ingest pass (no parse/pack numpy
                 # columns at all); returns None for anything it can't
-                # model (GLOBAL/MR rows, Gregorian, pb2 framing,
-                # busy-path gates) and the classic lanes below take
+                # model (GLOBAL/MR rows, a calendar row of an invalid
+                # ordinal, pb2 framing, busy-path gates) and the
+                # classic lanes below take
                 # over with identical semantics
                 out = self._wire_client_fused(data, now_ms)
                 if out is not None:
@@ -1064,7 +1065,10 @@ class V1Instance:
         that follows a refusal has in hand anyway (``behavior_or``,
         ``n``), so the refusal itself stays one request's header.  A
         call with rows of several kinds counts under the first of
-        global, multi_region, gregorian that any row carries."""
+        global, multi_region that any row carries, else too_large;
+        ``gregorian`` is what is left of a calendar call: the pass
+        serves those, all but a row of an invalid ordinal (or a clock
+        outside the calendar), whose error the classic lane builds."""
         if not hasattr(self.engine, "prepack_wire"):
             return  # no fused lane: nothing was refused
         reason = "other"  # framing the C++ lanes do not model, 0 rows
@@ -1074,10 +1078,10 @@ class V1Instance:
                 reason = "global"
             elif b & int(Behavior.MULTI_REGION):
                 reason = "multi_region"
-            elif b & int(Behavior.DURATION_IS_GREGORIAN):
-                reason = "gregorian"
             elif parsed["n"] > self.engine.wave_buckets[-1]:
                 reason = "too_large"
+            elif b & int(Behavior.DURATION_IS_GREGORIAN):
+                reason = "gregorian"
         self.metrics.wire_fused_declined.labels(reason=reason).inc()
 
     def _wire_client_fused(self, data: bytes,
@@ -1125,8 +1129,10 @@ class V1Instance:
         parse+clamp+hash+fill, zero numpy column passes), which the
         dispatch worker joins into its wave, and responses serialize from the
         wave's result columns — a forwarded batch costs the same as a
-        local wire call.  None → classic lane (GLOBAL/MR rows whose
-        async queues need parsed columns, Gregorian, pb2 framing)."""
+        local wire call, a forwarded calendar row (its period the one
+        that holds its ``created_at`` stamp) included.  None → classic
+        lane (GLOBAL/MR rows whose async queues need parsed columns, a
+        calendar row of an invalid ordinal, pb2 framing)."""
         prepack = getattr(self.engine, "prepack_wire", None)
         if prepack is None:
             return None

@@ -100,9 +100,11 @@ class Metrics:
             "gubernator_wire_fused_declined",
             "calls the fused C++ wire ingest refused, by why: global / "
             "multi_region (the lane's policy, _FUSED_EXCLUDED) / "
-            "gregorian (pack_wire_wave models no calendar) — the first "
-            "of the three that any row of the call carries —, too_large "
-            "(more rows than the largest wave bucket), other (framing "
+            "— the first of the two that any row of the call carries —, "
+            "too_large (more rows than the largest wave bucket), "
+            "gregorian (a calendar row pack_wire_wave cannot model: an "
+            "ordinal outside 0..5, a clock outside the calendar; every "
+            "other calendar call is served), other (framing "
             "the C++ lanes do not model, an empty call); counted where "
             "the classic parse that follows a refusal has behavior_or "
             "and n in hand (instance.py › _count_fused_declined)",

@@ -432,13 +432,13 @@ static PyObject* stamp_req_tlvs(PyObject*, PyObject* args) {
 // any framing the fast lane doesn't model (caller falls back to pb2).
 //
 // `excluded` is a mask of Behavior bits the caller's lane does not
-// serve (instance.py › _FUSED_EXCLUDED, and DURATION_IS_GREGORIAN, which
-// pack_wire_wave cannot model): None at the FIRST request that carries
-// one, before a pair exists — an all-GLOBAL call costs the fused lane
-// one request's header, not a pass.  With a mask the walk reads each
-// request's field tags (LEN payloads skipped by their length, last
-// behavior wins, as the full parse has it); with 0 it touches no
-// payload at all.
+// serve (instance.py › _FUSED_EXCLUDED; DURATION_IS_GREGORIAN is not
+// among them: pack_wire_wave does the calendar itself): None at the
+// FIRST request that carries one, before a pair exists — an all-GLOBAL
+// call costs the fused lane one request's header, not a pass.  With a
+// mask the walk reads each request's field tags (LEN payloads skipped
+// by their length, last behavior wins, as the full parse has it); with
+// 0 it touches no payload at all.
 static PyObject* count_req_items(PyObject*, PyObject* args) {
   Py_buffer view;
   unsigned long long excluded = 0;
@@ -495,6 +495,102 @@ static inline uint64_t mix64(uint64_t x) {
   return x;
 }
 
+// ---- the calendar (gregorian.py › gregorian_expiration's twin) --------
+//
+// The end of the calendar period (UTC) that holds a clock, in epoch-ms:
+// what a DURATION_IS_GREGORIAN row expires at.  MINUTES, HOURS, DAYS and
+// WEEKS are integer divisions of epoch-ms (1970-01-01 was a Thursday:
+// the Monday before it lies 3 days back); MONTHS and YEARS keep the
+// proleptic Gregorian calendar by the days-from-civil arithmetic of
+// 400-year eras.  tests/test_native_calendar.py holds this to
+// gregorian.py ms for ms, all six ordinals.
+
+static const uint32_t GREGORIAN = 4;  // Behavior.DURATION_IS_GREGORIAN
+static const int64_t DAY_MS = 86400000;
+static const int GREG_ORDINALS = 6;  // types.GregorianDuration: 0..5
+// the clocks gregorian_expiration takes for EVERY ordinal (datetime's
+// years 1..9999, the next period's first day among them):
+// [0001-01-01, 9999-01-01)
+static const int64_t CLOCK_MIN = -62135596800000LL;
+static const int64_t CLOCK_MAX = 253370764800000LL;
+
+static inline int64_t floor_div(int64_t a, int64_t b) {  // b > 0
+  return a / b - (a % b < 0);
+}
+
+// days since 1970-01-01 of the first of month m (1..12) of year y
+static inline int64_t days_from_civil(int64_t y, int m) {
+  y -= m <= 2;
+  int64_t era = floor_div(y, 400);
+  int64_t yoe = y - era * 400;                               // [0, 399]
+  int64_t doy = (153 * (m > 2 ? m - 3 : m + 9) + 2) / 5;     // day 1
+  int64_t doe = yoe * 365 + yoe / 4 - yoe / 100 + doy;
+  return era * 146097 + doe - 719468;
+}
+
+// the year and month (1..12) of a day counted from 1970-01-01
+static inline void civil_from_days(int64_t z, int64_t* y, int* m) {
+  z += 719468;
+  int64_t era = floor_div(z, 146097);
+  int64_t doe = z - era * 146097;                            // [0, 146096]
+  int64_t yoe = (doe - doe / 1460 + doe / 36524 - doe / 146096) / 365;
+  int64_t doy = doe - (365 * yoe + yoe / 4 - yoe / 100);
+  int64_t mp = (5 * doy + 2) / 153;                          // [0, 11]
+  *m = (int)(mp < 10 ? mp + 3 : mp - 9);
+  *y = yoe + era * 400 + (*m <= 2);
+}
+
+// One calendar period [start, end) of an ordinal.  A call's rows share
+// a stamp and, in practice, an ordinal: the pass keeps the last period
+// and asks the calendar again only for a clock outside it, so MONTHS /
+// YEARS cost one days-from-civil a call, not a row.
+struct Period {
+  int64_t ordinal = -1, start = 0, end = 0;
+
+  bool holds(int64_t ord, int64_t now) const {
+    return ord == ordinal && start <= now && now < end;
+  }
+
+  // false: an ordinal or a clock the calendar does not take
+  bool find(int64_t ord, int64_t now) {
+    if (ord < 0 || ord >= GREG_ORDINALS || now < CLOCK_MIN ||
+        now >= CLOCK_MAX)
+      return false;
+    ordinal = ord;
+    if (ord <= 3) {
+      static const int64_t width[4] = {60000, 3600000, DAY_MS, 7 * DAY_MS};
+      int64_t w = width[ord], shift = ord == 3 ? 3 * DAY_MS : 0;
+      start = floor_div(now + shift, w) * w - shift;
+      end = start + w;
+      return true;
+    }
+    int64_t y;
+    int m;
+    civil_from_days(floor_div(now, DAY_MS), &y, &m);
+    if (ord == 4) {  // MONTHS: the first of the next month
+      start = days_from_civil(y, m) * DAY_MS;
+      end = (m < 12 ? days_from_civil(y, m + 1)
+                    : days_from_civil(y + 1, 1)) * DAY_MS;
+    } else {  // YEARS: the first of the next January
+      start = days_from_civil(y, 1) * DAY_MS;
+      end = days_from_civil(y + 1, 1) * DAY_MS;
+    }
+    return true;
+  }
+};
+
+// gregorian_end(now_ms, ordinal) -> epoch-ms | None
+// gregorian.py › gregorian_expiration in C++, as pack_wire_wave applies
+// it a row; None for an ordinal outside 0..5 or a clock outside
+// [0001-01-01, 9999-01-01) — what the fused ingest declines a call for.
+static PyObject* gregorian_end(PyObject*, PyObject* args) {
+  long long now_ms, ordinal;
+  if (!PyArg_ParseTuple(args, "LL", &now_ms, &ordinal)) return nullptr;
+  Period p;
+  if (!p.find(ordinal, now_ms)) Py_RETURN_NONE;
+  return PyLong_FromLongLong(p.end);
+}
+
 // What an engine's launch needs to know of a call's rows (parallel/
 // sharded.py › ShardedEngine.lay_out), accumulated a row at a time by
 // the two passes below.  A row is outside the step program's value
@@ -506,13 +602,15 @@ static inline uint64_t mix64(uint64_t x) {
 struct Derived {
   std::vector<int64_t> ood;  // valid rows outside the domain
   long long leaky = 0;       // LEAKY rows that stay valid
+  long long greg = 0;        // valid DURATION_IS_GREGORIAN rows
   int64_t now_lo = 0, now_hi = 0, prev = 0;
   bool monotone = true;
   Py_ssize_t n = 0;
 
   void row(bool valid, bool exempt, int64_t hits, int64_t limit,
-           int64_t burst, int32_t alg, int64_t eff, int64_t now,
-           uint64_t value_bound, uint64_t eff_bound) {
+           int64_t burst, int32_t alg, int32_t behavior, int64_t eff,
+           int64_t now, uint64_t value_bound, uint64_t eff_bound) {
+    if (valid && ((uint32_t)behavior & GREGORIAN)) greg++;
     bool leaky_row = alg == 1;
     bool ok = !value_bound || exempt ||
               ((alg == 0 || leaky_row) && hits >= 0 &&
@@ -533,24 +631,24 @@ struct Derived {
     n++;
   }
 
-  // (ood i64le bytes, leaky, now_lo, now_hi, monotone)
+  // (ood i64le bytes, leaky, greg, now_lo, now_hi, monotone)
   PyObject* build() const {
     static const char kNone[1] = {0};
     return Py_BuildValue(
-        "(y#LLLO)", ood.empty() ? kNone : (const char*)ood.data(),
-        (Py_ssize_t)(ood.size() * 8), leaky, (long long)now_lo,
+        "(y#LLLLO)", ood.empty() ? kNone : (const char*)ood.data(),
+        (Py_ssize_t)(ood.size() * 8), leaky, greg, (long long)now_lo,
         (long long)now_hi, monotone ? Py_True : Py_False);
   }
 };
 
 // derive_rows(m64, m32, mslot | None, value_bound, eff_bound) ->
-//   (ood i64le, leaky, now_lo, now_hi, monotone)
+//   (ood i64le, leaky, greg, now_lo, now_hi, monotone)
 // The derivation alone, over a call's rows already in the upload
 // layout (m64 [8,n] i64, m32 [3,n] i32; rows contiguous, any row
 // stride — a view of a wider pair will do): what pack_wire_wave derives
 // in its own pass, for the producers that pack in Python.  mslot
 // (i32[n], optional): rows with mslot >= 0 are mesh-GLOBAL rows, exempt
-// from the domain.  One pass, the GIL kept: ~n × 11 loads.
+// from the domain.  One pass, the GIL kept: ~n × 12 loads.
 static PyObject* derive_rows(PyObject*, PyObject* args) {
   PyObject *o64, *o32, *oms;
   unsigned long long value_bound, eff_bound;
@@ -594,12 +692,12 @@ static PyObject* derive_rows(PyObject*, PyObject* args) {
     };
     const int64_t *hits = r64(1), *limit = r64(2), *eff = r64(4),
                   *burst = r64(6), *now = r64(7);
-    const int32_t *alg = r32(1), *valid = r32(2);
+    const int32_t *beh = r32(0), *alg = r32(1), *valid = r32(2);
     const int32_t* ms = has_ms ? (const int32_t*)bms.buf : nullptr;
     Derived d;
     for (Py_ssize_t i = 0; i < n; i++)
       d.row(valid[i] != 0, ms && ms[i] >= 0, hits[i], limit[i], burst[i],
-            alg[i], eff[i], now[i], value_bound, eff_bound);
+            alg[i], beh[i], eff[i], now[i], value_bound, eff_bound);
     out = d.build();
   }
   PyBuffer_Release(&b64);
@@ -850,10 +948,11 @@ static PyObject* route_fill(PyObject*, PyObject* args) {
 
 // pack_wire_wave(data, now_ms, a64, a32, m,
 //                duration_max, value_max, eff_max, td_bound,
-//                value_bound, eff_bound) ->
+//                value_bound, eff_bound, greg_eff i64le[6]) ->
 //   None                              (needs the classic/pb2 path)
 // | (n, khash u64le, behavior_or, tlv_off u64le, tlv_len u64le,
-//    name_hash u64le, (ood i64le, leaky, now_lo, now_hi, monotone))
+//    name_hash u64le,
+//    (ood i64le, leaky, greg, now_lo, now_hi, monotone))
 //
 // The fused wire ingest: one pass over a GetRateLimitsReq /
 // GetPeerRateLimitsReq that parses, validates, clamps (bit-identical to
@@ -866,36 +965,54 @@ static PyObject* route_fill(PyObject*, PyObject* args) {
 // cell is written: rows [n, m) read as empty_batch padding (zeros,
 // eff_ms 1), so the caller may pass uninitialised memory.
 //
+// A DURATION_IS_GREGORIAN row's `duration` is its ordinal: greg_end is
+// the end of the calendar period that holds the clock the row is
+// APPLIED at (its created_at stamp, else now_ms — gregorian.py, the
+// rule: `now` and `greg_end` of one row never come from two clocks;
+// struct Period above), and eff_ms the ordinal's approximate width,
+// greg_eff[ordinal] (types.GREGORIAN_APPROX_MS, passed in as the clamp
+// bounds are), before the LEAKY clamp — core/batch.py › pack_columns
+// and › _calendar_ends, bit for bit.
+//
 // In the same pass it derives what the engine's launch needs to know of
 // the call (struct Derived above): ood, the indices of rows outside the
 // step program's value domain (its bounds passed in as the clamp bounds
-// are); leaky, the LEAKY_BUCKET rows inside it; now_lo / now_hi, the
-// least and largest arrival time; monotone, whether arrival times never
-// decrease in row order.
+// are); leaky, the LEAKY_BUCKET rows inside it; greg, the calendar
+// rows; now_lo / now_hi, the least and largest arrival time; monotone,
+// whether arrival times never decrease in row order.
 //
 // Returns None (caller falls back) whenever the batch needs host-side
-// Python: pb2-fallback framing (as parse_get_rate_limits), n > m, or any
-// DURATION_IS_GREGORIAN row (calendar period ends are computed in
-// Python).  GLOBAL/MULTI_REGION gating is the caller's policy, applied
-// BEFORE this pass by the pre-pass (count_req_items' mask): a call that
-// reaches here is one the lane serves.  behavior_or, name_hash: as
-// parse_get_rate_limits returns them.
+// Python: pb2-fallback framing (as parse_get_rate_limits), n > m, or a
+// calendar row whose answer only the classic lane builds — an ordinal
+// outside 0..5 (an error on that ROW there, the others served) or a
+// clock outside what the calendar takes.  The call is refused WHOLE:
+// no row of it is served from here.  GLOBAL/MULTI_REGION gating is the
+// caller's policy, applied BEFORE this pass by the pre-pass
+// (count_req_items' mask): a call that reaches here is one the lane
+// serves.  behavior_or, name_hash: as parse_get_rate_limits returns
+// them.
 static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
-  Py_buffer view, b64, b32;
+  Py_buffer view, b64, b32, bge;
   long long now_ms;
   Py_ssize_t m;
   unsigned long long duration_max, value_max, eff_max, td_bound;
   unsigned long long value_bound, eff_bound;
-  if (!PyArg_ParseTuple(args, "y*Lw*w*nKKKKKK", &view, &now_ms, &b64, &b32,
-                        &m, &duration_max, &value_max, &eff_max,
-                        &td_bound, &value_bound, &eff_bound))
+  if (!PyArg_ParseTuple(args, "y*Lw*w*nKKKKKKy*", &view, &now_ms, &b64,
+                        &b32, &m, &duration_max, &value_max, &eff_max,
+                        &td_bound, &value_bound, &eff_bound, &bge))
     return nullptr;
-  if (b64.len < m * 8 * (Py_ssize_t)sizeof(int64_t) ||
+  int64_t greg_eff[GREG_ORDINALS];
+  bool sized = bge.len == (Py_ssize_t)sizeof(greg_eff);
+  if (sized) memcpy(greg_eff, bge.buf, sizeof(greg_eff));
+  PyBuffer_Release(&bge);
+  if (!sized || b64.len < m * 8 * (Py_ssize_t)sizeof(int64_t) ||
       b32.len < m * 3 * (Py_ssize_t)sizeof(int32_t)) {
     PyBuffer_Release(&view);
     PyBuffer_Release(&b64);
     PyBuffer_Release(&b32);
-    PyErr_SetString(PyExc_ValueError, "packed buffers too small");
+    PyErr_SetString(PyExc_ValueError,
+                    sized ? "packed buffers too small"
+                          : "greg_eff: one i64 width an ordinal");
     return nullptr;
   }
   int64_t* a64 = (int64_t*)b64.buf;  // rows: key hits limit duration
@@ -918,8 +1035,8 @@ static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
   std::vector<uint64_t> khash, name_hash, tlv_off, tlv_len;
   khash.reserve(64);
   Derived derived;
+  Period period;  // the last calendar period a row asked for
   uint64_t beh_or = 0;
-  const uint64_t GREG = 4;  // Behavior.DURATION_IS_GREGORIAN
   bool fallback = false;
   Py_ssize_t n = 0;
   while (p < end) {
@@ -986,10 +1103,29 @@ static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
     if (fallback) break;
     if (name_p == nullptr || name_len == 0 || key_p == nullptr ||
         key_len == 0 || !valid_utf8(name_p, name_len) ||
-        !valid_utf8(key_p, key_len) ||
-        ((uint64_t)(uint32_t)f_beh & GREG) || n >= m) {
+        !valid_utf8(key_p, key_len) || n >= m) {
       fallback = true;
       break;
+    }
+    // the caller's accepted-at clock wins when the forward hop stamped
+    // it (created_at, field 10): applying a forwarded request at OUR
+    // wall clock would mix time bases in the key's bucket row and a
+    // later base reads the earlier one as expired — bucket reset,
+    // debits silently gone (cold-key conservation loss)
+    int64_t now_i = f_created > 0 ? f_created : (int64_t)now_ms;
+    // clamps: the exact pack_columns arithmetic (core/batch.py)
+    int64_t dur = f_dur < (int64_t)duration_max ? f_dur
+                                                : (int64_t)duration_max;
+    int64_t eff = dur > 1 ? dur : 1;
+    int64_t greg_end = 0;
+    if ((uint32_t)f_beh & GREGORIAN) {
+      // the period that holds the clock the row is applied at
+      if (!period.holds(dur, now_i) && !period.find(dur, now_i)) {
+        fallback = true;  // the classic lane builds that row's error
+        break;
+      }
+      greg_end = period.end;
+      eff = greg_eff[dur];
     }
     uint64_t h = fnv1a64(name_p, (Py_ssize_t)name_len);
     name_hash.push_back(h);
@@ -1001,10 +1137,6 @@ static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
     khash.push_back(hm);
     tlv_off.push_back((uint64_t)(tlv_start - base));
     tlv_len.push_back((uint64_t)(qend - tlv_start));
-    // clamps: the exact pack_columns arithmetic (core/batch.py)
-    int64_t dur = f_dur < (int64_t)duration_max ? f_dur
-                                                : (int64_t)duration_max;
-    int64_t eff = dur > 1 ? dur : 1;
     int leaky = f_alg == 1;
     uint64_t cap_v = value_max;
     if (leaky) {
@@ -1025,21 +1157,15 @@ static PyObject* pack_wire_wave(PyObject*, PyObject* args) {
     r_limit[n] = lim;
     r_dur[n] = dur;
     r_eff[n] = eff;
-    r_greg[n] = 0;
+    r_greg[n] = greg_end;
     r_burst[n] = burst;
-    // the caller's accepted-at clock wins when the forward hop stamped
-    // it (created_at, field 10): applying a forwarded request at OUR
-    // wall clock would mix time bases in the key's bucket row and a
-    // later base reads the earlier one as expired — bucket reset,
-    // debits silently gone (cold-key conservation loss)
-    int64_t now_i = f_created > 0 ? f_created : (int64_t)now_ms;
     r_now[n] = now_i;
     r_beh[n] = f_beh;
     r_alg[n] = leaky ? 1 : 0;
     r_valid[n] = 1;
     beh_or |= (uint64_t)(uint32_t)f_beh;
-    derived.row(true, false, hits, lim, burst, leaky ? 1 : 0, eff, now_i,
-                value_bound, eff_bound);
+    derived.row(true, false, hits, lim, burst, leaky ? 1 : 0, f_beh, eff,
+                now_i, value_bound, eff_bound);
     n++;
   }
   if (!fallback) {
@@ -1564,6 +1690,9 @@ static PyMethodDef methods[] = {
     {"pack_wire_wave", pack_wire_wave, METH_VARARGS,
      "Fused ingest: wire bytes -> clamped rows written into leased "
      "packed wave matrices (or None)"},
+    {"gregorian_end", gregorian_end, METH_VARARGS,
+     "End of the calendar period (UTC) that holds an epoch-ms clock, by "
+     "GregorianDuration ordinal (or None): the fused ingest's calendar"},
     {"derive_rows", derive_rows, METH_VARARGS,
      "What a launch needs to know of rows in the upload layout: "
      "out-of-domain rows, leaky rows, the clocks"},

@@ -10,12 +10,14 @@ from typing import Sequence
 
 import numpy as np
 
+from ..types import (DURATION_MAX, EFF_MAX, GREGORIAN_APPROX_MS, TD_BOUND,
+                     VALUE_MAX, GregorianDuration)
 from . import _native  # ImportError here means: run `make native`
 
-#: the entry point the newest ``_native.cpp`` added (PR 37; PR 36's
-#: were ``route_plan`` / ``route_fill``): a build without it is older
-#: than the source
-NEWEST = "thread_files"
+#: the entry point the newest ``_native.cpp`` added (PR 40; PR 37's
+#: was ``thread_files``, PR 36's ``route_plan`` / ``route_fill``): a
+#: build without it is older than the source
+NEWEST = "gregorian_end"
 
 if not hasattr(_native, NEWEST):
     # NOT an ImportError: every importer reads that as "no extension"
@@ -25,6 +27,10 @@ if not hasattr(_native, NEWEST):
         f"{_native.__file__} is older than _native.cpp (no {NEWEST}): "
         "rebuild it with `make native`")
 
+#: types.GREGORIAN_APPROX_MS by ordinal, as the i64le[6] the C++ pass
+#: reads a calendar row's ``eff_ms`` from
+_GREG_WIDTHS = np.array([GREGORIAN_APPROX_MS[d] for d in GregorianDuration],
+                        "<i8").tobytes()
 
 def hash_keys(keys: Sequence[str]) -> np.ndarray:
     """Raw FNV-1a64 of each key string → uint64[n]."""
@@ -105,20 +111,24 @@ def pack_wire_wave(data: bytes, now_ms: int, a64: np.ndarray,
     i64, ``a32`` [3, m] i32 — core/batch.py › PACK64/PACK32; every cell
     is written, rows past n as padding, so ``np.empty`` will do).
 
-    Returns None (caller falls back to the classic numpy pack) for
-    anything the lane doesn't model: pb2 framing, n > m, or any
-    DURATION_IS_GREGORIAN row.  Otherwise (n, khash u64[n] MIXED,
-    behavior_or, tlv_off, tlv_len, name_hash u64[n] — raw FNV-1a64 of
-    each request's name alone —, derived), where derived = (ood,
-    leaky, now_lo, now_hi, monotone) is what ``ShardedEngine.lay_out``
-    derives of a call's rows, from the same pass: ``value_domain`` is
-    the engine's (VALUE_BOUND, EFF_BOUND), None for the full domain.
-    Clamp bounds are passed from types.py so the constants have one
-    home; clamp arithmetic is pinned bit-identical to core/batch.py ›
-    pack_columns by tests/test_native.py, the derived values to
-    ``lay_out`` by tests/test_wave_layout.py."""
-    from ..types import DURATION_MAX, EFF_MAX, TD_BOUND, VALUE_MAX
-
+    A DURATION_IS_GREGORIAN row takes its ``greg_end`` — the end of the
+    calendar period that holds the clock the row is applied at
+    (gregorian.py, the rule) — and its approximate width from the same
+    pass.  Returns None (caller falls back to the classic numpy pack)
+    for anything the lane doesn't model: pb2 framing, n > m, or a
+    calendar row only the classic lane answers (an ordinal outside
+    0..5, a clock ``gregorian_end`` does not take).  Otherwise (n,
+    khash u64[n] MIXED, behavior_or, tlv_off, tlv_len, name_hash u64[n]
+    — raw FNV-1a64 of each request's name alone —, derived), where
+    derived = (ood, leaky, greg, now_lo, now_hi, monotone) is what
+    ``ShardedEngine.lay_out`` derives of a call's rows, from the same
+    pass: ``value_domain`` is the engine's (VALUE_BOUND, EFF_BOUND),
+    None for the full domain.  Clamp bounds and the six approximate
+    widths are passed from types.py so the constants have one home;
+    the arithmetic is pinned bit-identical to core/batch.py ›
+    pack_columns by tests/test_native.py and
+    tests/test_native_calendar.py, the derived values to ``lay_out`` by
+    tests/test_wave_layout.py."""
     m = a64.shape[1]
     if not (a64.flags.c_contiguous and a32.flags.c_contiguous
             and a64.dtype == np.int64 and a32.dtype == np.int32
@@ -128,7 +138,7 @@ def pack_wire_wave(data: bytes, now_ms: int, a64: np.ndarray,
     vb, eb = value_domain if value_domain is not None else (0, 0)
     r = _native.pack_wire_wave(data, int(now_ms), a64, a32, m,
                                DURATION_MAX, VALUE_MAX, EFF_MAX,
-                               TD_BOUND, vb, eb)
+                               TD_BOUND, vb, eb, _GREG_WIDTHS)
     if r is None:
         return None
     n, kh, beh_or, toff, tlen, nh, derived = r
@@ -141,17 +151,26 @@ def pack_wire_wave(data: bytes, now_ms: int, a64: np.ndarray,
             _derived(derived))
 
 
+def gregorian_end(now_ms: int, ordinal: int):
+    """``gregorian.gregorian_expiration`` as the fused ingest computes
+    it: the end (epoch-ms) of the calendar period that holds
+    ``now_ms``, or None for an ordinal outside 0..5 or a clock outside
+    [0001-01-01, 9999-01-01) — what ``pack_wire_wave`` declines a call
+    for.  tests/test_native_calendar.py holds the two to each other."""
+    return _native.gregorian_end(int(now_ms), int(ordinal))
+
+
 def _derived(d):
-    ood, leaky, now_lo, now_hi, monotone = d
-    return (np.frombuffer(ood, "<i8") if ood else None, leaky, now_lo,
-            now_hi, monotone)
+    ood, leaky, greg, now_lo, now_hi, monotone = d
+    return (np.frombuffer(ood, "<i8") if ood else None, leaky, greg,
+            now_lo, now_hi, monotone)
 
 
 def derive_rows(m64: np.ndarray, m32: np.ndarray, mslot=None,
                 value_domain=None):
     """What ``pack_wire_wave`` derives of a call's rows, for rows laid
     out in Python (``m64`` [8, n] i64, ``m32`` [3, n] i32, rows
-    contiguous): (ood, leaky, now_lo, now_hi, monotone) in ONE pass
+    contiguous): (ood, leaky, greg, now_lo, now_hi, monotone) in ONE pass
     that keeps the GIL — a handler's ~25 numpy calls, each to be won
     back from the other handlers, become one.  ``mslot``: i32[n], rows
     >= 0 exempt from the domain; ``value_domain``: as above."""

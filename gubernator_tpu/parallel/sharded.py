@@ -481,17 +481,17 @@ class ShardedEngine:
         rows = stack_rows(batch)
         if rows.monotone is not None:
             return rows  # derived already (the C++ ingest, an earlier call)
-        if rows.greg is None:  # loose columns: pack_columns counts its own
-            rows.greg = int(np.count_nonzero(
-                rows.valid & ((rows.m32[0] & _GREGORIAN) != 0)))
         if _wire_native is not None:
             # one C++ pass that keeps the GIL: this runs in ~30 handler
             # threads at once, and every numpy call of the fallback
             # below would have to win the GIL back from the others
-            (rows.ood, rows.leaky, rows.now_lo, rows.now_hi,
+            (rows.ood, rows.leaky, rows.greg, rows.now_lo, rows.now_hi,
              rows.monotone) = _wire_native.derive_rows(
                  rows.m64, rows.m32, mslot, self.value_domain)
             return rows
+        if rows.greg is None:  # loose columns: pack_columns counts its own
+            rows.greg = int(np.count_nonzero(
+                rows.valid & ((rows.m32[0] & _GREGORIAN) != 0)))
         rows.ood, rows.leaky = self._out_of_domain(rows, mslot)
         now = rows.now
         rows.monotone = bool((now[1:] >= now[:-1]).all())
@@ -950,17 +950,22 @@ class ShardedEngine:
         right-sized pair and derive what ``lay_out`` would, with zero
         intermediate numpy columns and the GIL kept throughout.
 
+        A DURATION_IS_GREGORIAN row's period end is the pass's own
+        arithmetic too (``_native.cpp › Period``, gregorian.py's twin).
+
         Any shard count: the block is rows in the call's own order, and
         the dispatch worker's route (``_device_waves``) is the one place
         that knows about shards.  None — the caller takes the classic
         parse → pack_columns path — for what the C++ lane can't model
-        (pb2 framing, Gregorian rows, n over the largest bucket: the
-        classic path splits) and for a call with a row that carries one
-        of the caller's ``excluded`` Behavior bits: the pre-pass stops
-        at the first such row, before the pair exists."""
+        (pb2 framing, a calendar row of an invalid ordinal or a clock
+        outside the calendar — the classic lane builds that row's
+        error —, n over the largest bucket: the classic path splits)
+        and for a call with a row that carries one of the caller's
+        ``excluded`` Behavior bits: the pre-pass stops at the first
+        such row, before the pair exists."""
         if _wire_native is None:
             return None
-        cnt = _wire_native.count_req_items(data, excluded | _GREGORIAN)
+        cnt = _wire_native.count_req_items(data, excluded)
         if not cnt or cnt > self.wave_buckets[-1]:
             return None  # oversize: classic path splits into waves
         rows = Rows.empty(cnt)
@@ -969,9 +974,8 @@ class ShardedEngine:
         if res is None:
             return None
         n, khash, _behavior_or, tlv_off, tlv_len, name_hash, derived = res
-        (rows.ood, rows.leaky, rows.now_lo, rows.now_hi,
+        (rows.ood, rows.leaky, rows.greg, rows.now_lo, rows.now_hi,
          rows.monotone) = derived
-        rows.greg = 0  # the pre-pass declined every Gregorian row
         return PrepackedWave(rows, n, khash, tlv_off, tlv_len, name_hash)
 
     def check_batch(self, reqs: Sequence[RateLimitRequest], now_ms: int

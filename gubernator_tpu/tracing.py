@@ -206,7 +206,8 @@ PHASE_CATALOG: Dict[str, str] = {
     "call.wait": "handler blocked on its wave's future (queue wait + "
                  "wave, from the caller's side)",
     "local.pack": "_wire_check_columns: a call the fused C++ ingest "
-                  "declined (Gregorian or MULTI_REGION rows, more rows "
+                  "declined (MULTI_REGION rows, a calendar row of an "
+                  "invalid ordinal, more rows "
                   "than the largest bucket, GLOBAL rows on the peer "
                   "wire, no extension; which, and how often: "
                   "gubernator_wire_fused_declined{reason}, counted at "
@@ -214,13 +215,16 @@ PHASE_CATALOG: Dict[str, str] = {
                   "and laid out in "
                   "numpy in its own thread (mix64 + pack_columns + "
                   "lay_out), before it is queued; wall and CPU.  A "
-                  "plain LOCAL call never enters it, on any mesh",
+                  "LOCAL call, plain or calendar, never enters it, on "
+                  "any mesh",
     "pack.calendar": "pack_columns: the period ends of a call's "
                      "DURATION_IS_GREGORIAN rows, one a distinct "
                      "(ordinal, clock) pair, each on the clock its row "
                      "is applied at (gregorian.py); inside local.pack "
                      "or route.pack, wall and CPU, sampled as they are; "
-                     "a call without such rows never enters it",
+                     "a call without such rows never enters it, nor one "
+                     "the fused C++ ingest serves (its pass does the "
+                     "calendar itself: _native.cpp › Period)",
     "route.pack": "_wire_mesh_runner: mix64 + pack_columns + masks",
     "route.keys": "_wire_mesh_runner: the call's mesh rows grouped by "
                   "key in one dict pass: one config per key, pinned "

@@ -103,7 +103,7 @@ class TestNativeCodec:
         assert n == 2
         assert a64[7].tolist() == [T0 + 3, T0 + 99]
         # the call's clocks, as the launch reads them: T0+3 then T0+99
-        assert res[-1][2:] == (T0 + 3, T0 + 99, True)
+        assert res[-1][3:] == (T0 + 3, T0 + 99, True)
 
     def test_pb2_fallback_paths_still_parse_stamped_tlvs(self):
         # pb2 treats field 10 as an unknown field: parses cleanly, and

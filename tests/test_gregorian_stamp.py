@@ -13,9 +13,13 @@
   it — against the plug-in's plain reference, answer for answer, across
   two minute boundaries with calls 1 ms either side of one, on restored
   rows and on rows the calls create, on both engines;
+  and by both lanes: the fused C++ ingest, which does the calendar
+  itself since ISSUE 40, and the classic numpy lane (``local.pack`` →
+  ``pack_columns`` → ``_calendar_ends``) of an engine whose ingest
+  declines every call, as a checkout without the extension serves;
 * what ISSUE 39 counts: ``gubernator_wire_fused_declined{reason}``,
   ``gubernator_wave_gregorian_rows``, ``gubernator_wave_created_rows``
-  and the phase ``pack.calendar`` on known calls.
+  and the phase ``pack.calendar`` on known calls, lane by lane.
 """
 import numpy as np
 import pytest
@@ -186,12 +190,21 @@ def calls_plan() -> list:
             for t in stamps]
 
 
-@pytest.fixture(scope="module", params=list(ENGINES))
+LANES = [(e, lane) for e in ENGINES for lane in ("fused", "classic")]
+
+
+@pytest.fixture(scope="module", params=LANES,
+                ids=[f"{e}-{lane}" for e, lane in LANES])
 def served(request):
-    """(instance, [(got, want)] a call) after the plan ran: restored
-    rows, stamped calls on a daemon whose clock is a day behind."""
-    eng = ENGINES[request.param](make_mesh(n=1), capacity_per_shard=1 << 12,
-                                 batch_per_shard=64)
+    """(instance, [(got, want)] a call, reference, lane) after the plan
+    ran: restored rows, stamped calls on a daemon whose clock is a day
+    behind.  ``classic``: the engine's fused ingest declines every call,
+    so each is parsed and packed in numpy (``_calendar_ends``)."""
+    kind, lane = request.param
+    eng = ENGINES[kind](make_mesh(n=1), capacity_per_shard=1 << 12,
+                        batch_per_shard=64)
+    if lane == "classic":
+        eng.prepack_wire = lambda *a, **kw: None
     inst = V1Instance(Config(cache_size=1 << 12, sweep_interval_ms=0),
                       engine=eng)
     try:
@@ -209,13 +222,13 @@ def served(request):
             got = wire.decode_responses(
                 inst.get_rate_limits_wire(data, now_ms=V0 - DAY + 7 * k))
             out.append((got, ref.call(idx, stamp), stamp, idx))
-        yield inst, out, ref
+        yield inst, out, ref, lane
     finally:
         inst.close()
 
 
 def test_stamped_minutes_calls_equal_the_reference_answer_for_answer(served):
-    _, out, ref = served
+    _, out, ref, _ = served
     for got, want, stamp, idx in out:
         assert got["errors"] == 0 and len(got["status"]) == len(idx)
         for f in ("status", "limit", "remaining", "reset_time"):
@@ -237,7 +250,7 @@ def test_the_window_rules_pass_the_program_and_fail_the_old_rule(served):
     """The same answers through ``window_violations`` (one caller's
     order is one of the serial orders): 0 — and the wall-clock rule in
     the program's place, as the parent commit had it, breaks them."""
-    _, out, _ = served
+    _, out, _, _ = served
     cat = lambda f: np.concatenate([g[f] for g, *_ in out])  # noqa: E731
     n = [len(idx) for *_, idx in out]
     ans = {f: cat(f) for f in ("status", "limit", "remaining", "reset_time")}
@@ -258,19 +271,24 @@ def test_the_window_rules_pass_the_program_and_fail_the_old_rule(served):
 
 
 def test_what_the_calls_raised_the_counters_by(served):
-    inst, out, _ = served
+    """Either lane carries the same calendar rows into the same waves;
+    a call the fused ingest SERVES is not declined and passes neither
+    ``local.pack`` nor ``pack.calendar``."""
+    inst, out, _, lane = served
     m = inst.metrics
     rows_sent = sum(len(idx) for *_, idx in out)
     assert m.wave_gregorian_rows._value.get() == rows_sent
+    fused = lane == "fused"
     assert m.wire_fused_declined.labels(
-        reason="gregorian")._value.get() == len(out)
-    assert m.wire_fused_counter._value.get() == 0
+        reason="gregorian")._value.get() == (0 if fused else len(out))
+    assert m.wire_fused_counter._value.get() == (rows_sent if fused else 0)
     created = len({int(i) for *_, idx in out for i in idx
                    if i >= POP["keys"]})
     assert m.wave_created_rows._value.get() == created
     text = inst.metrics.render().decode()
-    assert 'gubernator_phase_duration_count{phase="pack.calendar"}' in text
-    assert 'gubernator_phase_duration_count{phase="local.pack"}' in text
+    for name in ("pack.calendar", "local.pack"):
+        line = f'gubernator_phase_duration_count{{phase="{name}"}}'
+        assert (line in text) == (not fused), name
 
 
 def test_an_unstamped_calendar_row_follows_the_daemons_clock_and_a_plain_call_is_not_declined():
@@ -285,19 +303,30 @@ def test_an_unstamped_calendar_row_follows_the_daemons_clock_and_a_plain_call_is
         assert got["reset_time"].tolist() == [greg.period_end(wall, 0)]
         declined = lambda why: m.wire_fused_declined.labels(  # noqa: E731
             reason=why)._value.get()
-        assert declined("gregorian") == 1
+        # served by the fused ingest, its calendar the daemon's clock's
+        assert declined("gregorian") == 0
+        assert m.wire_fused_counter._value.get() == 1
         plain = RateLimitRequest(name="g", unique_key="p", hits=1, limit=5,
                                  duration=10_000)
         inst.get_rate_limits_wire(req_to_tlv(plain) * 3, now_ms=wall)
-        assert m.wire_fused_counter._value.get() == 3
+        assert m.wire_fused_counter._value.get() == 4
+        # a MULTI_REGION row declines its call by policy, calendar row
+        # or none; an invalid ordinal is what "gregorian" counts now
         mr = RateLimitRequest(name="g", unique_key="m", hits=1, limit=5,
                               duration=10_000,
                               behavior=Behavior.MULTI_REGION)
         inst.get_rate_limits_wire(req_to_tlv(mr) + req_to_tlv(r),
                                   now_ms=wall)
         assert (declined("gregorian"), declined("multi_region"),
+                declined("other")) == (0, 1, 0)
+        bad = request("b", 9)
+        got = wire.decode_responses(inst.get_rate_limits_wire(
+            req_to_tlv(r) + req_to_tlv(bad), now_ms=wall))
+        assert got["errors"] == 1
+        assert (declined("gregorian"), declined("multi_region"),
                 declined("other")) == (1, 1, 0)
-        assert m.wave_gregorian_rows._value.get() == 2
+        assert m.wire_fused_counter._value.get() == 4
+        assert m.wave_gregorian_rows._value.get() == 3
         assert m.wave_created_rows._value.get() == 3  # u, p, m
     finally:
         inst.close()

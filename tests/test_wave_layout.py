@@ -319,7 +319,7 @@ def test_the_ingest_derives_what_lay_out_derives(kind, domain, monkeypatch):
     data = b"".join(req_to_tlv(r) for r in reqs)
     a64, a32 = np.empty((8, n), np.int64), np.empty((3, n), np.int32)
     res = native.pack_wire_wave(data, NOW, a64, a32, domain)
-    ood, leaky, now_lo, now_hi, monotone = res[-1]
+    ood, leaky, _greg, now_lo, now_hi, monotone = res[-1]
 
     eng = ShardedEngine.__new__(
         ShardedEngine if domain is None else PallasServingEngine)

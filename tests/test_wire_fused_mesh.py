@@ -12,14 +12,21 @@ Held here, on 1, 2 and 4 shards of the CPU mesh and on both engines:
   (``_wire_check_columns``, through an engine whose ``prepack_wire``
   declines), call after call, and ``gubernator_wire_fused_requests_total``
   says which lane answered;
-* a call with a GLOBAL, a MULTI_REGION or a Gregorian row — first,
-  middle or last — declines in the pre-pass: no pair is allocated, the
-  full pass never runs, and the classic lane answers as before."""
+* a call with a GLOBAL or a MULTI_REGION row — first, middle or last —
+  declines in the pre-pass: no pair is allocated, the full pass never
+  runs, and the classic lane answers as before;
+* a call with a calendar row (``DURATION_IS_GREGORIAN``; ISSUE 40) in
+  any of the three positions IS packed, in one pass, and answered byte
+  for byte as the twin without a fused lane answers it, across its
+  period's end; one whose calendar row has an invalid ordinal is refused
+  whole, inside the pass, counted ``reason="gregorian"``, and its
+  per-row error is the classic lane's."""
 import pytest
 
 from gubernator_tpu import Algorithm
 from gubernator_tpu.config import Config
 from gubernator_tpu.core.batch import Rows
+from gubernator_tpu.gregorian import gregorian_expiration
 from gubernator_tpu.hashing import shard_of
 from gubernator_tpu.instance import V1Instance, _wire_native
 from gubernator_tpu.ops import pallas_step as ps
@@ -127,29 +134,29 @@ def test_both_lanes_answer_one_mixed_call_byte_for_byte(lanes):
         assert lane.encode() in inst.metrics.render()
 
 
-#: the behaviours a call declines for: the two the instance's policy
-#: hands the pre-pass (_FUSED_EXCLUDED), and the one the pass cannot
-#: model
+#: the behaviours a call declines for in the pre-pass: the two the
+#: instance's policy hands it (_FUSED_EXCLUDED)
 DECLINED = {
     "global": dict(behavior=Behavior.GLOBAL, duration=60_000),
     "multi_region": dict(behavior=Behavior.MULTI_REGION, duration=60_000),
-    "gregorian": dict(behavior=Behavior.DURATION_IS_GREGORIAN,
-                      duration=int(GregorianDuration.HOURS)),
 }
+AT = {"first": 0, "middle": 4, "last": 9}
 
 
-@pytest.mark.parametrize("where", ["first", "middle", "last"])
-@pytest.mark.parametrize("what", DECLINED)
-def test_a_declined_call_is_declined_before_it_is_packed(lanes, what, where,
-                                                         monkeypatch):
-    kind, n, fused, columns = lanes
+def call_with(what: str, where: str, **odd):
+    """Nine plain rows and the odd one at ``where``, as wire bytes."""
     plain = [RateLimitRequest(name="fd", unique_key=f"{what}{where}{i}",
                               hits=1, limit=5, duration=60_000)
              for i in range(9)]
     odd = RateLimitRequest(name="fd", unique_key=f"{what}{where}odd",
-                           hits=1, limit=5, **DECLINED[what])
-    at = {"first": 0, "middle": 4, "last": 9}[where]
-    data = wire(plain[:at] + [odd] + plain[at:])
+                           hits=1, limit=5, **odd)
+    at = AT[where]
+    return wire(plain[:at] + [odd] + plain[at:]), wire(plain)
+
+
+@pytest.fixture
+def spied(monkeypatch):
+    """Counts of the pairs allocated and of the full passes run."""
     calls = {"empty": 0, "pass": 0}
 
     def count(name, fn):
@@ -161,13 +168,27 @@ def test_a_declined_call_is_declined_before_it_is_packed(lanes, what, where,
     monkeypatch.setattr(Rows, "empty", count("empty", Rows.empty))
     monkeypatch.setattr(sharded._wire_native, "pack_wire_wave",
                         count("pass", _wire_native.pack_wire_wave))
+    return calls
+
+
+def declined(inst, why: str) -> int:
+    return int(inst.metrics.wire_fused_declined.labels(
+        reason=why)._value.get())
+
+
+@pytest.mark.parametrize("where", list(AT))
+@pytest.mark.parametrize("what", DECLINED)
+def test_a_declined_call_is_declined_before_it_is_packed(lanes, what, where,
+                                                         spied):
+    kind, n, fused, columns = lanes
+    data, plain = call_with(what, where, **DECLINED[what])
+    calls = spied
     excluded = int(V1Instance._FUSED_EXCLUDED)
     assert fused.engine.prepack_wire(data, NOW, excluded) is None
     assert calls == {"empty": 0, "pass": 0}
     # the engine alone (no policy handed in) declines only what its
     # pass cannot model
-    pre = fused.engine.prepack_wire(data, NOW)
-    assert (pre is None) == (what == "gregorian")
+    assert fused.engine.prepack_wire(data, NOW) is not None
     calls.update(empty=0)
     calls["pass"] = 0
     before = fused_rows(fused)
@@ -181,8 +202,114 @@ def test_a_declined_call_is_declined_before_it_is_packed(lanes, what, where,
     assert [(int(r.status), r.remaining, r.error) for r in out] == \
         [(0, 4, "")] * 10
     # and the same rows without the odd one ride the fused lane
-    fused.get_rate_limits_wire(wire(plain), now_ms=NOW + 1)
+    fused.get_rate_limits_wire(plain, now_ms=NOW + 1)
     assert fused_rows(fused) - before == 9 and calls["pass"] == 1
+
+
+HOUR = 3_600_000
+
+
+@pytest.mark.parametrize("where", list(AT))
+def test_a_calendar_call_is_packed_in_one_pass_and_answered_as_the_classic_lane_does(
+        lanes, where, spied):
+    """A ``DURATION_IS_GREGORIAN`` row first, in the middle or last: the
+    pre-pass lets it by, the ONE pass does its calendar, and call after
+    call — six in its hour (limit 5: the sixth is OVER_LIMIT), one past
+    the hour's end (a new bucket) — the answer is the classic lane's."""
+    kind, n, fused, columns = lanes
+    data, _ = call_with("gregorian", where,
+                        behavior=Behavior.DURATION_IS_GREGORIAN,
+                        duration=int(GregorianDuration.HOURS))
+    at = AT[where]
+    end = gregorian_expiration(NOW, GregorianDuration.HOURS)
+    pre = fused.engine.prepack_wire(data, NOW,
+                                    int(V1Instance._FUSED_EXCLUDED))
+    assert pre is not None and pre.n == 10 and pre.rows.greg == 1
+    assert pre.rows.batch.greg_end.tolist() == [
+        end * (i == at) for i in range(10)]
+    spied.update({"empty": 0, "pass": 0})
+    before = fused_rows(fused), declined(fused, "gregorian")
+    greg0 = [int(i.metrics.wave_gregorian_rows._value.get())
+             for i in (fused, columns)]
+    clocks = [NOW + 7 * k for k in range(6)] + [end + 5]
+    for k, now in enumerate(clocks):
+        got = fused.get_rate_limits_wire(data, now_ms=now)
+        assert got == columns.get_rate_limits_wire(data, now_ms=now), k
+        odd = pb.GetRateLimitsResp.FromString(got).responses[at]
+        assert not odd.error
+        assert (int(odd.status), odd.remaining, odd.reset_time) == (
+            (0, 4, end), (0, 3, end), (0, 2, end), (0, 1, end),
+            (0, 0, end), (1, 0, end), (0, 4, end + HOUR))[k]
+    assert spied["pass"] == len(clocks)  # one pass a call, no more
+    assert fused_rows(fused) - before[0] == 10 * len(clocks)
+    assert declined(fused, "gregorian") == before[1]
+    assert fused_rows(columns) == 0
+    assert [int(i.metrics.wave_gregorian_rows._value.get()) - g
+            for i, g in zip((fused, columns), greg0)] == [len(clocks)] * 2
+
+
+@pytest.mark.parametrize("ordinal", [GregorianDuration.MINUTES,
+                                     GregorianDuration.MONTHS])
+def test_forwarded_calendar_rows_take_the_period_of_their_stamp(lanes,
+                                                                ordinal):
+    """The peer wire (``_wire_peer_fused``): forwarded rows carry
+    ``created_at``, a month ahead of the owner's clock here, and their
+    period is the stamp's by the same line of the pass — answered as
+    the classic lane answers, a row past the period's end included."""
+    kind, n, fused, columns = lanes
+    stamp = NOW + 31 * 86_400_000
+    end = gregorian_expiration(stamp, ordinal)
+    assert end != gregorian_expiration(NOW, ordinal)
+    reqs = [RateLimitRequest(
+        name="fp", unique_key=f"{int(ordinal)}k{i % 4}", hits=1, limit=5,
+        duration=int(ordinal), behavior=Behavior.DURATION_IS_GREGORIAN,
+        algorithm=Algorithm.LEAKY_BUCKET if i % 2 else Algorithm.TOKEN_BUCKET,
+        created_at=end + 3 if i == 11 else stamp + i) for i in range(12)]
+    data = wire(reqs)
+    before = fused_rows(fused), declined(fused, "gregorian")
+    for call in range(2):
+        got = fused.get_peer_rate_limits_wire(data, now_ms=NOW + call)
+        assert got == columns.get_peer_rate_limits_wire(data,
+                                                        now_ms=NOW + call)
+        out = pb.GetRateLimitsResp.FromString(got).responses
+        # a LEAKY row a month wide is outside the Mosaic kernel's
+        # domain (eff ≥ 2^31 ms) and unservable there, in either lane
+        wide = kind == "pallas_fused" and ordinal == GregorianDuration.MONTHS
+        assert [bool(r.error) for r in out] == [
+            wide and i % 2 == 1 for i in range(12)]
+        if call == 0:  # TOKEN rows: key 0 three times, then a new period
+            assert [out[i].reset_time for i in (0, 4, 8)] == [end] * 3
+            assert [out[i].remaining for i in (0, 4, 8)] == [4, 3, 2]
+    assert fused_rows(fused) - before[0] == 24
+    assert declined(fused, "gregorian") == before[1]
+
+
+@pytest.mark.parametrize("where", list(AT))
+def test_an_invalid_ordinal_declines_its_call_whole(lanes, where, spied):
+    """The pre-pass cannot know (it reads behaviour bits, not
+    durations): the pass refuses at the row, nothing of the call is
+    served from it, and the classic lane answers — that ROW with its
+    error, the others as ever."""
+    kind, n, fused, columns = lanes
+    data, plain = call_with("badordinal", where,
+                            behavior=Behavior.DURATION_IS_GREGORIAN,
+                            duration=9)
+    at = AT[where]
+    assert fused.engine.prepack_wire(
+        data, NOW, int(V1Instance._FUSED_EXCLUDED)) is None
+    assert spied["pass"] == 1
+    before = fused_rows(fused), declined(fused, "gregorian")
+    got = fused.get_rate_limits_wire(data, now_ms=NOW)
+    assert fused_rows(fused) == before[0]
+    assert declined(fused, "gregorian") == before[1] + 1
+    assert got == columns.get_rate_limits_wire(data, now_ms=NOW)
+    out = pb.GetRateLimitsResp.FromString(got).responses
+    assert [(int(r.status), r.remaining, r.error) for r in out] == [
+        (0, 0, "invalid gregorian duration ordinal: 9") if i == at
+        else (0, 4, "") for i in range(10)]
+    # the same rows without the odd one ride the fused lane
+    fused.get_rate_limits_wire(plain, now_ms=NOW + 1)
+    assert fused_rows(fused) - before[0] == 9
 
 
 def test_the_pre_pass_reads_what_the_full_parse_reads():

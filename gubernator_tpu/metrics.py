@@ -219,8 +219,10 @@ class Metrics:
             registry=r)
         self.restore_unplaced_rows = Gauge(
             "gubernator_restore_unplaced_rows",
-            "rows of the last engine.restore that found no slot in "
-            "their probe window and no cold tier to adopt them",
+            "rows of the last engine.restore that no tier took: no "
+            "slot in their probe window (or, on the bucket table, "
+            "values outside the kernel's domain) and no cold tier to "
+            "adopt them",
             registry=r)
         self.wave_queue_wait = Histogram(
             "gubernator_dispatcher_queue_wait",
@@ -446,6 +448,12 @@ class Metrics:
             "requests served from the host cold tier (device miss or "
             "table overflow; byte-exact with the device step)",
             registry=r)
+        self.tier_cold_creates = Counter(
+            "gubernator_tier_cold_creates",
+            "keys CREATED in the host cold tier: a served request whose "
+            "key neither tier held (a first-seen key whose device "
+            "bucket or probe window is full); counted beside "
+            "gubernator_tier_cold_serves, one inc a wave", registry=r)
         self.tier_promotions = Counter(
             "gubernator_tier_promotions",
             "cold rows migrated into the device table after their "

@@ -41,6 +41,7 @@
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>
+#include <new>
 #include <string>
 #include <utility>
 #include <vector>
@@ -1520,6 +1521,64 @@ static PyObject* cold_put(PyObject*, PyObject* args) {
   return PyLong_FromLong(present ? 0 : 1);
 }
 
+// cold_put_batch(capsule, keys u64le[n], rows i64le[n * 8]) -> inserted
+//
+// n puts in one pass with the GIL released (the caller holds
+// TierController._mu, as for every mutation): a restore's overflow is
+// millions of rows, and one cold_put each is a Python call, a tuple and
+// a 64-byte bytes object a row.  The table grows ONCE, to what holds
+// them all under the load bound; a key that comes twice keeps its last
+// row, as n single puts would.  A table that cannot be allocated is a
+// MemoryError with the store untouched, not an exception through C.
+static PyObject* cold_put_batch(PyObject*, PyObject* args) {
+  PyObject* obj;
+  Py_buffer keys, rows;
+  if (!PyArg_ParseTuple(args, "Oy*y*", &obj, &keys, &rows)) return nullptr;
+  ColdStore* cs = cold_from(obj);
+  Py_ssize_t n = keys.len / 8;
+  if (cs == nullptr || keys.len % 8 ||
+      rows.len != n * COLD_ROW * (Py_ssize_t)sizeof(int64_t)) {
+    if (cs != nullptr)
+      PyErr_SetString(PyExc_ValueError,
+                      "want n u64 keys and n rows of 64 bytes");
+    PyBuffer_Release(&keys);
+    PyBuffer_Release(&rows);
+    return nullptr;
+  }
+  const uint64_t* kp = (const uint64_t*)keys.buf;
+  const int64_t* rp = (const int64_t*)rows.buf;
+  Py_ssize_t inserted = 0;
+  bool no_memory = false;
+  Py_BEGIN_ALLOW_THREADS
+  size_t cap = cs->cap;
+  while ((cs->used + (size_t)n + 1) * 10 >= cap * 7) cap <<= 1;
+  if (cap != cs->cap || (cs->filled + (size_t)n + 1) * 10 >= cs->cap * 7) {
+    try {
+      cold_grow(cs, cap);  // same size: sheds the tombstones
+    } catch (const std::bad_alloc&) {
+      no_memory = true;  // the store is as it was: nothing was put
+    }
+  }
+  for (Py_ssize_t k = 0; k < n && !no_memory; k++) {
+    bool present;
+    size_t i = cold_find(cs, kp[k], &present);
+    if (!present) {
+      if (cs->state[i] == 0) cs->filled++;
+      cs->keys[i] = kp[k];
+      cs->state[i] = 1;
+      cs->used++;
+      inserted++;
+    }
+    std::memcpy(&cs->rows[i * COLD_ROW], &rp[k * COLD_ROW],
+                COLD_ROW * sizeof(int64_t));
+  }
+  Py_END_ALLOW_THREADS
+  PyBuffer_Release(&keys);
+  PyBuffer_Release(&rows);
+  if (no_memory) return PyErr_NoMemory();
+  return PyLong_FromSsize_t(inserted);
+}
+
 // cold_get(capsule, key u64) -> bytes(64) | None
 static PyObject* cold_get(PyObject*, PyObject* args) {
   PyObject* obj;
@@ -1720,6 +1779,8 @@ static PyMethodDef methods[] = {
      "table -> capsule"},
     {"cold_put", cold_put, METH_VARARGS,
      "cold_put(capsule, key, row64B) -> 1 inserted / 0 overwrote"},
+    {"cold_put_batch", cold_put_batch, METH_VARARGS,
+     "cold_put_batch(capsule, keys u64le, rows i64le) -> keys inserted"},
     {"cold_get", cold_get, METH_VARARGS,
      "cold_get(capsule, key) -> 64-byte row | None"},
     {"cold_pop", cold_pop, METH_VARARGS,

@@ -43,6 +43,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..core.batch import RequestBatch
 from ..core.step import REPLICA_PROBES, decide_batch_impl
 from ..ops import pallas_step as ps
+from ..tracing import phase
 from .mesh import SHARD_AXIS, XLA_EXEC_MU, exec_gate
 from .sharded import ShardedEngine
 
@@ -659,6 +660,16 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
 
     # ---- tiered store hooks (tiering.py) -------------------------------
 
+    def warmup(self, now_ms: int = 1) -> None:
+        """Every wave bucket's step program, and with a cold tier bound
+        the row programs a migration runs (one bucket fetched, one
+        written back as it was): an admission's first use must not
+        compile inside a served wave."""
+        super().warmup(now_ms)
+        if self.tier is not None:
+            bid = np.zeros(1, np.int64)
+            self._write_buckets(bid, self._fetch_buckets(bid))
+
     def tier_row_admissible(self, row) -> bool:
         """Admission domain gate: a cold row whose values exceed the
         kernel's packed-word domain must STAY cold — upsert_rows would
@@ -763,24 +774,39 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
                 cols[f][i] = cvt[f][0]
         return found, cols
 
-    def _prepared_rows(self, khash: np.ndarray, cols: dict
-                       ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    def _prepared_rows(self, khash: np.ndarray, cols: dict,
+                       ood_too: bool = False) -> tuple:
         """Shared upsert/restore front half: convert all rows, drop
         (and count) out-of-domain ones, then dedupe the survivors
         (keeping per-key occurrence counts for sequential-equivalent
         accounting).  Validate-before-dedupe order matters: a
         sequential walk checks each occurrence, so an invalid late
-        duplicate never shadows an earlier valid write."""
+        duplicate never shadows an earlier valid write.  Returns (keys,
+        words, counts, src, ood): ``src[i]`` is the caller's row that
+        ``words[i]`` was made from (a key's LAST valid occurrence).
+
+        ``ood_too`` (a restore into a bound cold tier, which holds what
+        the kernel's domain does not): no row is dropped; the dedupe
+        runs over ALL rows, so that a key's last row decides its tier,
+        and ``ood`` is (src, counts) of the keys whose last row is out
+        of domain — empty otherwise."""
         keys = np.asarray(khash).astype(np.uint64)
         words, valid = _columns_to_words_batch(cols, keys)
-        self.dropped_rows += int((~valid).sum())
-        keys, words = keys[valid], words[valid]
-        counts = np.ones(len(keys), np.int64)
-        if keys.size:
-            keep, counts = _dedupe_last(keys)
-            if len(keep) != len(keys):
-                keys, words = keys[keep], words[keep]
-        return keys, words, counts
+        if ood_too:
+            src = np.arange(len(keys))
+        else:
+            self.dropped_rows += int((~valid).sum())
+            src = np.flatnonzero(valid)
+        counts = np.ones(len(src), np.int64)
+        if src.size:
+            keep, counts = _dedupe_last(keys[src])
+            if len(keep) != len(src):
+                src = src[keep]
+        ok = valid[src]
+        ood = src[~ok], counts[~ok]
+        if len(ood[0]):
+            src, counts = src[ok], counts[ok]
+        return keys[src], words[src], counts, src, ood
 
     def _grouped_bucket_view(self, keys: np.ndarray
                              ) -> tuple[np.ndarray, np.ndarray]:
@@ -791,7 +817,7 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
     def upsert_rows(self, khash: np.ndarray, cols: dict) -> int:
         if len(khash) == 0:
             return 0
-        keys, words, counts = self._prepared_rows(khash, cols)
+        keys, words, counts, _, _ = self._prepared_rows(khash, cols)
         if keys.size == 0:
             return 0
         # ONE batched device fetch of the distinct buckets (a per-key
@@ -870,26 +896,47 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
     def restore(self, arrays: dict) -> int:
         """Vectorized (no per-row Python): a 1M-row snapshot restores
         in seconds, not minutes — bounded by tests/test_pallas_engine
-        TestSnapshotRestore.test_restore_1m_rows_is_fast."""
+        TestSnapshotRestore.test_restore_1m_rows_is_fast.
+
+        A bucket's free slots go to its rows in snapshot order.  With a
+        cold tier bound, rows that find their bucket full and rows
+        outside the kernel's value domain (which must STAY cold:
+        ``tier_row_admissible``) go to it in ONE batch (tiering.py ›
+        adopt_rows), so that every snapshot row lands in exactly one
+        tier, as on the XLA engine.  Without one both are dropped.
+        Returns rows placed + adopted; ``dropped_rows`` and the gauge
+        ``restore_unplaced_rows`` count what no tier took."""
         if len(arrays["key"]) == 0:
             return 0
-        keys, words, counts = self._prepared_rows(arrays["key"], arrays)
-        if keys.size == 0:
-            return 0  # all dropped: no host copy / re-upload for a no-op
-        host = np.ascontiguousarray(
-            ps.buckets_to_rows(np.asarray(self.state)))
-        ubids, group_id = self._grouped_bucket_view(keys)
-        buckets = host[ubids]
-        khi, klo = _split_np(keys)
-        placed = _place_into_buckets(buckets, group_id, klo, khi, words)
-        self.dropped_rows += int(counts[~placed].sum())  # bucket full
-        if not placed.any():
-            return 0  # saturated buckets: skip the no-op re-upload
-        host[ubids] = buckets
-        self.state = jax.device_put(
-            np.ascontiguousarray(ps.buckets_to_rows(host)),
-            self._rows_sharding)
-        return int(counts[placed].sum())
+        tier, dropped0 = self.tier, self.dropped_rows
+        keys, words, counts, src, (ood, ood_counts) = self._prepared_rows(
+            arrays["key"], arrays, ood_too=tier is not None)
+        placed = np.zeros(0, bool)
+        if keys.size:  # else no host copy / re-upload for a no-op
+            with phase("restore.place", self.metrics_ref):
+                host = np.ascontiguousarray(
+                    ps.buckets_to_rows(np.asarray(self.state)))
+                ubids, group_id = self._grouped_bucket_view(keys)
+                buckets = host[ubids]
+                khi, klo = _split_np(keys)
+                placed = _place_into_buckets(buckets, group_id, klo, khi,
+                                             words)
+        restored = int(counts[placed].sum())
+        lost = int(counts[~placed].sum())  # bucket full
+        if tier is not None and (lost or len(ood)):
+            # a key's last row, as on the device; every row of it counts
+            tier.adopt_rows(arrays, np.concatenate([src[~placed], ood]))
+            restored, lost = restored + lost + int(ood_counts.sum()), 0
+        self.dropped_rows += lost
+        if self.metrics_ref is not None:
+            self.metrics_ref.restore_unplaced_rows.set(
+                self.dropped_rows - dropped0)
+        if placed.any():  # else saturated buckets: no no-op re-upload
+            host[ubids] = buckets
+            self.state = jax.device_put(
+                np.ascontiguousarray(ps.buckets_to_rows(host)),
+                self._rows_sharding)
+        return restored
 
 
 class XlaFusedEngine(FusedServingMixin, ShardedEngine):

@@ -12,7 +12,9 @@ on exactly one shard, so owner-applies-hits parity is exact.
 """
 from __future__ import annotations
 
+import contextlib
 import logging
+import time
 from typing import List, Sequence
 
 import jax
@@ -580,7 +582,11 @@ class ShardedEngine:
         if tier is not None:
             kh = np.asarray(khash)
             orig_valid = (wave.valid if valid is None else valid) & (kh != 0)
+            # inside wave.route: its own ticks at both ends
+            timed = phase("tier.premask", self.metrics_ref).begin(
+                at=time.perf_counter())
             cold = tier.resident_mask(kh) & orig_valid
+            timed.end(at=time.perf_counter())
             if mslot is not None:
                 cold &= np.asarray(mslot) < 0
             if cold.any():
@@ -838,8 +844,6 @@ class ShardedEngine:
                 full[ood] = True
         # the re-dispatches below run _check_rows, phases and all
         if err_idx:
-            import contextlib
-
             ei = np.asarray(sorted(err_idx))
             sub = batch.rows.take(ei).batch
             msub = None if mslot is None else np.asarray(mslot)[ei]
@@ -853,8 +857,6 @@ class ShardedEngine:
             rst_o[ei] = r_rst
             full[ei] = r_full
         if cold_idx is not None and len(cold_idx):
-            import contextlib
-
             # cold-tier rows rode the waves invalid (see launch_packed):
             # re-dispatch just them through _check_rows, which serves
             # from whichever tier the key is in NOW — exact even when a
@@ -1084,14 +1086,19 @@ class ShardedEngine:
                 pending = np.empty(0, np.int64)
         cols = (status, lim_o, rem_o, rst_o, full)
         batch = wave.batch
-        if self.tier is not None:
-            # cold lane: pre-masked cold-resident rows plus residual
-            # table-full rows (brand-new keys, device table saturated —
-            # the tier turns table-full into find-or-create on host)
-            cols = self.tier.resolve(self, batch, khash, now_ms, cols,
-                                     cold_mask, orig_valid, mslot=mslot)
-        return self._serve_out_of_domain(cols, wave.ood, batch, khash,
-                                         now_ms, mslot)
+        tier = self.tier
+        # the cold lane's host work is scatter time, not worker.gap
+        with (phase("wave.scatter") if tier is not None
+              else contextlib.nullcontext()):
+            if tier is not None:
+                # cold lane: pre-masked cold-resident rows plus residual
+                # table-full rows (brand-new keys, device table
+                # saturated — the tier turns table-full into
+                # find-or-create on host)
+                cols = tier.resolve(self, batch, khash, now_ms, cols,
+                                    cold_mask, orig_valid, mslot=mslot)
+            return self._serve_out_of_domain(cols, wave.ood, batch, khash,
+                                             now_ms, mslot)
 
     def _try_auto_grow(self, grew: list) -> bool:
         """Grow 2× (once per wave) if under auto_grow_limit.  Returns
@@ -1309,7 +1316,7 @@ class ShardedEngine:
             # tiered restore: rows the device table can't hold land in
             # the cold tier instead of being dropped — the snapshot
             # round-trip keeps every row in exactly one tier
-            adopted = self.tier.adopt_rows(arrays, lost.tolist())
+            adopted = self.tier.adopt_rows(arrays, lost)
             placed += adopted
             lost = lost[adopted:]
         if self.metrics_ref is not None:

@@ -40,10 +40,12 @@ from __future__ import annotations
 import logging
 import os
 import threading
+import time
 from typing import Callable, Dict, Optional
 
 import numpy as np
 
+from .tracing import phase
 from .types import FRAC_SAFE, TD_BOUND, Algorithm, Behavior
 
 log = logging.getLogger("gubernator_tpu.tiering")
@@ -199,6 +201,10 @@ class _DictColdStore:
     def put(self, kh: int, row) -> None:
         self._d[kh] = tuple(row)
 
+    def put_batch(self, keys: np.ndarray, rows: np.ndarray) -> None:
+        """``put`` for every (keys[i], rows[i]) — u64[n], i64[n, 8]."""
+        self._d.update(zip(keys.tolist(), map(tuple, rows.tolist())))
+
     def pop(self, kh: int):
         return self._d.pop(kh, None)
 
@@ -242,6 +248,13 @@ class _NativeColdStore:
         self._m.cold_put(self._h,
                          int(kh),
                          np.asarray(row, "<i8").tobytes())
+
+    def put_batch(self, keys: np.ndarray, rows: np.ndarray) -> None:
+        """``put`` for every (keys[i], rows[i]) — u64[n], i64[n, 8] —
+        as one C++ pass that grows the table once."""
+        self._m.cold_put_batch(self._h,
+                               np.ascontiguousarray(keys, "<u8"),
+                               np.ascontiguousarray(rows, "<i8"))
 
     def pop(self, kh: int):
         b = self._m.cold_pop(self._h, kh)
@@ -311,6 +324,9 @@ class TierController:
         #: this feed a cold key could never accrue admission rank.
         self._tap = tap
         self.cold_served = 0  # guarded-by: self._mu
+        #: keys CREATED cold: a served row whose key no tier held (a
+        #: first-seen key whose device bucket is full)
+        self.cold_created = 0  # guarded-by: self._mu
         self.promotions = 0  # lock-free: resolve-path only (engine-lock serialized)
         self.demotions = 0  # lock-free: resolve-path only (engine-lock serialized)
         self.migrations_aborted = 0  # lock-free: resolve-path only (engine-lock serialized)
@@ -341,6 +357,7 @@ class TierController:
         with self._mu:
             return {"cold_keys": len(self._store),
                     "cold_served": self.cold_served,
+                    "cold_created": self.cold_created,
                     "native": self._store.native,
                     "promotions": self.promotions,
                     "demotions": self.demotions,
@@ -379,17 +396,19 @@ class TierController:
     def adopt_rows(self, arrays: dict, idx) -> int:
         """Adopt restore-overflow rows (store.py column arrays, row
         indices ``idx`` did not place on device) — restore's no-phantom
-        contract: every snapshot row lands in exactly one tier."""
-        keys = np.asarray(arrays["key"], np.uint64)
-        cols = [np.asarray(arrays[f], np.int64) for f in ROW_COLS]
-        n = 0
-        with self._mu:
-            for i in idx:
-                self._store.put(int(keys[i]),
-                                tuple(int(c[i]) for c in cols))
-                n += 1
+        contract: every snapshot row lands in exactly one tier.  ONE
+        batch put (a key that comes twice keeps its last row); phase
+        `restore.adopt`."""
+        idx = np.asarray(idx, np.int64)
+        with phase("restore.adopt", self.metrics):
+            keys = np.asarray(arrays["key"], np.uint64)[idx]
+            rows = np.empty((len(idx), len(ROW_COLS)), np.int64)
+            for j, f in enumerate(ROW_COLS):
+                rows[:, j] = np.asarray(arrays[f])[idx]
+            with self._mu:
+                self._store.put_batch(keys, rows)
         self._gauge()
-        return n
+        return len(idx)
 
     def snapshot_arrays(self) -> Optional[dict]:
         """Cold rows as store.py column arrays (key included), or None
@@ -427,6 +446,9 @@ class TierController:
             need = need & (np.asarray(mslot) < 0)
         if not need.any():
             return cols
+        # inside wave.scatter: its own ticks at both ends (phase.begin)
+        timed = phase("tier.resolve", self.metrics).begin(
+            at=time.perf_counter())
         idxs = np.nonzero(need)[0]
 
         h_hits = np.asarray(batch.hits)
@@ -445,12 +467,16 @@ class TierController:
 
         order = sorted(idxs.tolist(), key=lambda i: (_eff_now(i), i))
         served_khs = []
+        created = 0
         with self._mu:
             store = self._store
             for i in order:
                 kh = int(khash[i])
+                row = store.get(kh)
+                if row is None:
+                    created += 1
                 st, orem, rst, olim, new_row = _host_apply(
-                    store.get(kh), int(h_hits[i]), int(h_lim[i]),
+                    row, int(h_hits[i]), int(h_lim[i]),
                     int(h_dur[i]), int(h_eff[i]), int(h_greg[i]),
                     int(h_beh[i]), int(h_alg[i]), int(h_bur[i]),
                     _eff_now(i))
@@ -462,9 +488,12 @@ class TierController:
                 full[i] = False
                 served_khs.append(kh)
             self.cold_served += len(order)
+            self.cold_created += created
         m = self.metrics
         if m is not None:
             m.tier_cold_serves.inc(len(order))
+            if created:
+                m.tier_cold_creates.inc(created)
         self._gauge()
         if self._tap is not None:
             try:
@@ -472,6 +501,7 @@ class TierController:
             except Exception:  # pragma: no cover - analytics only
                 log.exception("tier rank-feed tap")
         self._admit(engine, served_khs)
+        timed.end(at=time.perf_counter())
         return status, lim_o, rem_o, rst_o, full
 
     # ---- admission / migration -----------------------------------------
@@ -479,7 +509,9 @@ class TierController:
     def _admit(self, engine, khs) -> None:
         """Promote every just-served cold key whose sketch rank clears
         the admission threshold.  No rank feed (analytics off) → no
-        admission: serving stays exact, just host-paced."""
+        admission: serving stays exact, just host-paced.  Phase
+        `tier.migrate`: one sample an admission tried, victim pick and
+        demotion inside."""
         rank = self.rank_fn
         if rank is None or not khs:
             return
@@ -494,7 +526,10 @@ class TierController:
             except Exception:  # pragma: no cover - analytics only
                 return
             if r >= thr:
+                timed = phase("tier.migrate", self.metrics).begin(
+                    at=time.perf_counter())
                 self.promote(engine, kh, r)
+                timed.end(at=time.perf_counter())
 
     def promote(self, engine, kh: int, rank: int) -> bool:
         """Migrate one cold row to the device tier, evicting the

@@ -187,6 +187,19 @@ PHASE_CATALOG: Dict[str, str] = {
                  "download",
     "wave.scatter": "engine: result scatter into request order "
                     "(+ retry / cold rows / out-of-domain merge)",
+    "tier.premask": "engine, inside wave.route: the cold tier's "
+                    "membership read of the wave's keys (resident_mask, "
+                    "one C++ pass under the tier's lock), so that "
+                    "cold-resident rows ride the wave invalid; only "
+                    "with GUBER_TIER_COLD=1",
+    "tier.resolve": "tiering.resolve, inside wave.scatter: the cold "
+                    "lane of a wave that has cold rows — each applied "
+                    "to the host store in Python, then the admission of "
+                    "the keys served (tier.migrate inside)",
+    "tier.migrate": "tiering._admit, inside tier.resolve: one "
+                    "admission tried (promote) — the row's upsert into "
+                    "its device bucket, the victim's pick and demotion "
+                    "where the bucket is full",
     "wave.resolve": "dispatcher: the future.set_result loop",
     "wave.end": "_wave_end + the analytics tap",
     # handler threads, per call
@@ -240,9 +253,14 @@ PHASE_CATALOG: Dict[str, str] = {
     "broadcast": "GLOBAL owner tick: one broadcast pass",
     "snapshot": "Loader save blackout",
     "restore": "Loader load blackout",
-    "restore.place": "ShardedEngine.restore: the snapshot's rows placed "
-                     "into the host copy of the column table (numpy "
-                     "rounds), before the upload",
+    "restore.place": "engine.restore: the snapshot's rows placed into "
+                     "the host copy of the table (ShardedEngine: numpy "
+                     "rounds over the column table; PallasServingEngine: "
+                     "the table's download and a bucket's free slots to "
+                     "its rows in snapshot order), before the upload",
+    "restore.adopt": "tiering.adopt_rows: the rows a restore found no "
+                     "device slot for, into the host cold tier in one "
+                     "batch put",
     "sweep": "engine.sweep, whole: the expiry pass over the table, "
              "between waves under the engine lock (_maybe_sweep: the "
              "tick, or a table_full row's request); host wall time, "
@@ -596,11 +614,14 @@ class phase:
 
     ``begin(at=)`` / ``end(at=)`` take a tick the caller already read
     (two phases that share a boundary share the reading, so they
-    partition exactly); ``end(keep=False)`` closes the section without
-    a sample (nothing was done in it).  ``span=False`` keeps a phase
-    that overlaps its siblings out of the span tree; ``always=True``
-    records the span whatever the sampling decision, for the commit to
-    decide (``span()``: the handler entries).  ``cpu=True`` costs two
+    partition exactly; a phase INSIDE another phase of its thread,
+    `tier.premask` in `wave.route`, reads its own tick at both ends, so
+    on a partition thread it neither moves the cursor nor counts the
+    enclosing phase's time as gap); ``end(keep=False)`` closes the
+    section without a sample (nothing was done in it).  ``span=False``
+    keeps a phase that overlaps its siblings out of the span tree;
+    ``always=True`` records the span whatever the sampling decision,
+    for the commit to decide (``span()``: the handler entries).  ``cpu=True`` costs two
     ``time.thread_time()`` calls — a real system call, ~6 µs each on
     the chip's host and dearer under load — so it is for per-call
     phases whose wall − CPU split decides something, and for the

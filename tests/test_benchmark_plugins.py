@@ -155,7 +155,10 @@ def test_an_xla_reader_is_found_and_reads_nothing_where_nothing_is(name):
     from benchmark.harness import plugins
 
     entry = next(m for m in _manifest()["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == [XLA_CELL]
+    # (the sweep's phase is the Pallas engine's too: cell 10, whose
+    # tiered waves ask for a sweep of their own, reads it since PR 41)
+    assert entry["workloads"] == [XLA_CELL] + (
+        ["r1-churn-100m"] if name == "sweep_ms" else [])
     read = plugins.load("layer_metrics", name).read
     ctx = {"m0": {}, "m1": {}, "tm0": {}, "tm1": {}, "trace": {"devices": 0},
            "_xla_step_modules": (0.0, 0), "device_kind": "TPU v5 lite",
@@ -395,7 +398,9 @@ def test_wave_native_route_share_on_a_hand_made_pair_of_scrapes(case):
     assert entry == {
         "name": "wave_native_route_share", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "engine",
-        "moves": "decisions_per_s", "workloads": [R4_CELL, G4_CELL]}
+        "moves": "decisions_per_s",
+        # cell 10: a tier bound, so every one-shard wave is a sorted wave
+        "workloads": [R4_CELL, G4_CELL, "r1-churn-100m"]}
     m0, m1, want = NATIVE_SHARE[case]
     got = plugins.load("layer_metrics", "wave_native_route_share").read(
         dict(_nothing(), m0=m0, m1=m1))

@@ -339,6 +339,22 @@ class HeavyHitterSketch:
             return 0
         return int(self._cnt[self._sorted_slot[pos]])
 
+    def counts_of(self, khashes) -> np.ndarray:
+        """``count_of`` for every key hash of an array — i64[n], 0
+        where untracked — in ONE vectorised probe of the sorted index:
+        the tiered store's admission reads a wave's ~1,100 served keys
+        at once (tiering.py › _admit)."""
+        kh = np.asarray(khashes, np.uint64)
+        out = np.zeros(kh.size, np.int64)
+        self._reindex()
+        if not self._sorted_kh.size or not kh.size:
+            return out
+        pos = np.minimum(np.searchsorted(self._sorted_kh, kh),
+                         self._sorted_kh.size - 1)
+        hit = self._sorted_kh[pos] == kh
+        out[hit] = self._cnt[self._sorted_slot[pos[hit]]]
+        return out
+
     def topk(self, k: Optional[int] = None) -> List[dict]:
         k = self.k if k is None else max(int(k), 1)
         k = min(k, self._used)
@@ -1409,12 +1425,14 @@ class KeyAnalytics:
         with self._mu:
             return self.sketch.count_of(khash)
 
-    def sketch_counts(self, khashes) -> List[int]:
-        """Batched :meth:`sketch_count` — ONE lock acquisition for a
-        probe window's worth of victim-candidate ranks (tiering.py ›
-        _pick_victim picks the coldest device row to evict)."""
+    def sketch_counts(self, khashes) -> np.ndarray:
+        """Batched :meth:`sketch_count`, i64[n] — ONE lock acquisition
+        and one vectorised probe for a wave's served cold keys
+        (tiering.py › _admit) or a probe window's worth of
+        victim-candidate ranks (› _pick_victim picks the coldest device
+        row to evict)."""
         with self._mu:
-            return [self.sketch.count_of(int(k)) for k in khashes]
+            return self.sketch.counts_of(khashes)
 
     def stats(self) -> dict:
         with self._mu:

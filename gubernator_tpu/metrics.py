@@ -448,6 +448,13 @@ class Metrics:
             "requests served from the host cold tier (device miss or "
             "table overflow; byte-exact with the device step)",
             registry=r)
+        self.tier_cold_native_serves = Counter(
+            "gubernator_tier_cold_native_serves",
+            "of gubernator_tier_cold_serves, the requests that ONE C++ "
+            "pass applied (ops/_native.cpp cold_apply_batch: the native "
+            "store of a build that has it); the rest went through the "
+            "Python loop of _host_apply calls; one inc a wave's cold "
+            "lane", registry=r)
         self.tier_cold_creates = Counter(
             "gubernator_tier_cold_creates",
             "keys CREATED in the host cold tier: a served request whose "

@@ -37,6 +37,7 @@
 #include <fcntl.h>
 #include <unistd.h>
 
+#include <algorithm>
 #include <cstdint>
 #include <cstdio>
 #include <cstdlib>
@@ -1579,6 +1580,340 @@ static PyObject* cold_put_batch(PyObject*, PyObject* args) {
   return PyLong_FromSsize_t(inserted);
 }
 
+// ---- cold_apply_batch: a wave's cold lane in one pass --------------------
+//
+// tiering.py › _host_apply, statement for statement, over a 128-bit
+// intermediate.  Python's integers do not overflow and its // and %
+// floor; every product below is of two values that fit 64 bits (so it
+// fits 128), every sum goes through the checked builtins, every
+// division floors, and a result that does not fit its 64-bit column is
+// OverflowError — as `np.asarray(row, "<i8")` and `column[i] = value`
+// are in the Python lane.  A wrapped value is never stored or answered.
+typedef __int128 i128;
+
+static inline i128 mul64(int64_t a, int64_t b) { return (i128)a * b; }
+
+static inline i128 wide_div(i128 a, i128 b) {
+  i128 q = a / b, r = a % b;
+  return (r != 0 && ((r < 0) != (b < 0))) ? q - 1 : q;
+}
+
+static inline i128 wide_mod(i128 a, i128 b) {
+  i128 r = a % b;
+  return (r != 0 && ((r < 0) != (b < 0))) ? r + b : r;
+}
+
+static inline bool fits64(i128 v) {
+  return v >= (i128)INT64_MIN && v <= (i128)INT64_MAX;
+}
+
+static const int64_t COLD_LEAKY = 1;         // Algorithm.LEAKY_BUCKET
+static const int64_t COLD_RESET = 8;         // Behavior.RESET_REMAINING
+static const int64_t COLD_DRAIN = 32;        // Behavior.DRAIN_OVER_LIMIT
+
+struct ColdReq {
+  int64_t hits, limit, duration, eff, greg_end, behavior, alg, burst,
+      req_now;
+};
+
+struct ColdAns {
+  int64_t row[COLD_ROW];  // the key's new row, ROW_COLS order
+  int64_t status, out_rem;
+  i128 reset_time;  // checked by the caller: it is answered, not stored
+};
+
+// One request applied to one cold row (`row`: the stored one, or
+// _ZERO_ROW for a missing key).  False where a value of the new row does
+// not fit 64 bits or a 128-bit sum overflowed: nothing of `ans` is good.
+static bool cold_apply_row(const int64_t* row, const ColdReq& q,
+                           int64_t td_bound, int64_t frac_safe,
+                           ColdAns* ans) {
+  bool wide = false;  // a 128-bit sum overflowed (unreachable: see above)
+  auto add = [&wide](i128 a, i128 b) {
+    i128 r;
+    if (__builtin_add_overflow(a, b, &r)) wide = true;
+    return r;
+  };
+  auto sub = [&wide](i128 a, i128 b) {
+    i128 r;
+    if (__builtin_sub_overflow(a, b, &r)) wide = true;
+    return r;
+  };
+  const int64_t meta = row[0], i_limit = row[1], i_duration = row[2],
+                i_eff = row[3], i_rem = row[5], i_t = row[6],
+                i_exp = row[7];
+  const int64_t i_alg = meta & 1, i_status = (meta >> 1) & 1;
+  const int64_t hits = q.hits, limit = q.limit, eff = q.eff,
+                alg = q.alg, burst = q.burst;
+
+  const int64_t now = q.req_now > i_t ? q.req_now : i_t;
+  const bool is_leaky = alg == COLD_LEAKY;
+  const bool is_greg = (q.behavior & (int64_t)GREGORIAN) != 0;
+
+  // --- fresh determination (missing/expired/algorithm switch)
+  bool fresh = now >= i_exp || i_alg != alg;
+  const bool tok_dur_change = !is_leaky && !fresh && q.duration != i_duration;
+  i128 exp1 = i_exp;
+  if (tok_dur_change) {
+    exp1 = is_greg ? (i128)q.greg_end : add(i_t, eff);
+    if (exp1 <= now) fresh = true;
+  }
+
+  // --- adopt fresh or existing state
+  const int64_t eff_l = is_leaky ? eff : 1;
+  int64_t limit0, eff0, t0, status0;
+  i128 rem0, exp0;
+  if (fresh) {
+    limit0 = limit;
+    eff0 = eff;
+    rem0 = mul64(is_leaky ? burst : limit, eff_l);
+    t0 = now;
+    exp0 = (!is_leaky && is_greg) ? (i128)q.greg_end : add(now, eff);
+    status0 = 0;
+  } else {
+    limit0 = i_limit;
+    eff0 = i_eff;
+    rem0 = i_rem;
+    t0 = i_t;
+    exp0 = exp1;
+    status0 = i_status;
+  }
+
+  // --- leaky denominator change -> rescale td fixed point
+  if (is_leaky && !fresh && eff != eff0) {
+    const int64_t d = eff0 > 1 ? eff0 : 1;
+    // rem0 is the stored column here, so whole and frac fit 64 bits
+    i128 whole = wide_div(rem0, d);
+    const i128 frac = wide_mod(rem0, d);
+    const int64_t cap_whole = td_bound / (eff > 1 ? eff : 1);
+    if (whole > cap_whole) whole = cap_whole;
+    const bool frac_ok = eff0 <= frac_safe && eff <= frac_safe;
+    rem0 = add(mul64((int64_t)whole, eff),
+               wide_div(mul64(frac_ok ? (int64_t)frac : 0, eff), d));
+  }
+  if (is_leaky || tok_dur_change) eff0 = eff;
+
+  // --- RESET_REMAINING (existing items only)
+  const bool reset_live = (q.behavior & COLD_RESET) != 0 && !fresh;
+  if (reset_live) {
+    rem0 = mul64(limit, eff_l);
+    status0 = 0;
+  }
+  const int64_t limit_after_reset =
+      (reset_live && !is_leaky) ? limit : limit0;
+
+  // --- token limit change in place
+  if (!is_leaky && limit != limit_after_reset) {
+    rem0 = sub(add(rem0, limit), limit_after_reset);
+    if (rem0 < 0)
+      rem0 = 0;
+    else if (rem0 > limit)
+      rem0 = limit;
+  }
+  const int64_t limit1 = limit;
+
+  // --- leaky replenish (exact: elapsed x limit td, clamped to burst)
+  const int64_t burst1 = is_leaky ? burst : limit1;
+  int64_t t1;
+  if (is_leaky) {
+    const i128 elapsed = sub(now, t0);  // >= 0: now is max(req_now, i_t)
+    const i128 cap_td = mul64(burst1, eff0);
+    const int64_t safe_el = td_bound / (limit1 > 1 ? limit1 : 1);
+    if (elapsed > safe_el) {
+      rem0 = cap_td;
+    } else {  // 0 <= elapsed <= td_bound: fits 64 bits
+      rem0 = add(rem0, mul64((int64_t)elapsed, limit1));
+      if (rem0 > cap_td) rem0 = cap_td;
+    }
+    t1 = now;
+  } else {
+    t1 = t0;
+  }
+
+  const int64_t d0 = eff0 > 1 ? eff0 : 1;
+  const i128 rate =
+      limit1 > 0 ? wide_div(eff0, limit1 > 1 ? limit1 : 1) : (i128)eff0;
+  const i128 exp_out = is_leaky ? add(now, eff0) : exp0;
+  // leaky: from the request's OWN stamp, not the clamped clock
+  const i128 reset_time = is_leaky ? add(q.req_now, rate) : exp_out;
+
+  // --- hits
+  const i128 cost = mul64(hits, is_leaky ? eff0 : 1);
+  i128 rem2;
+  int64_t status1;
+  if (hits == 0) {  // query
+    rem2 = rem0;
+    status1 = status0;
+  } else if (cost <= rem0) {
+    rem2 = sub(rem0, cost);
+    status1 = 0;
+  } else {
+    rem2 = (q.behavior & COLD_DRAIN) != 0 ? (i128)0 : rem0;
+    status1 = 1;
+  }
+
+  if (wide || !fits64(rem2) || !fits64(exp_out)) return false;
+  ans->row[0] = alg | (status1 << 1);
+  ans->row[1] = limit1;
+  ans->row[2] = q.duration;
+  ans->row[3] = eff0;
+  ans->row[4] = burst1;
+  ans->row[5] = (int64_t)rem2;
+  ans->row[6] = t1;
+  ans->row[7] = (int64_t)exp_out;
+  ans->status = status1;
+  ans->out_rem = (int64_t)(is_leaky ? wide_div(rem2, d0) : rem2);
+  ans->reset_time = reset_time;
+  return true;
+}
+
+// cold_apply_batch(capsule, khash u64le[n], idx i64le[m],
+//                  hits, limit, duration, eff_ms, greg_end, behavior,
+//                  algorithm, burst, now  (i64le[n] each),
+//                  now_ms, td_bound, frac_safe,
+//                  status i32[n], limit i64[n], remaining i64[n],
+//                  reset i64[n], full bool[n]  (writable))
+//   -> (served, created, distinct served keys u64le bytes)
+//
+// TierController.resolve's loop as one pass: rows `idx` of the wave in
+// (effective stamp, index) order — `now[i]` if > 0 else `now_ms` — each
+// key's slot found ONCE (find-or-insert; a missing key starts from
+// _ZERO_ROW = zeros with eff_ms 1 and counts as created), the transition
+// applied, the new row written into the slot and the four answers into
+// the response columns, `full[i]` cleared.  The distinct keys come back
+// in order of first service (what admission walks).  The table grows at
+// most once, up front, for as many inserts as the call may make.  Keeps
+// the GIL (a ~1-ms pass); the caller holds TierController._mu.
+//
+// OverflowError where the Python lane raises it, with the same state
+// behind it: the rows before the one at fault are stored and answered,
+// that one is not (a `reset` that does not fit is raised AFTER the row,
+// `status` and `remaining` were written — `rst_o[i] = rst` is the third
+// assignment there).
+static PyObject* cold_apply_batch(PyObject*, PyObject* args) {
+  PyObject* obj;
+  Py_buffer b[16] = {};
+  long long now_ms, td_bound, frac_safe;
+  if (!PyArg_ParseTuple(
+          args, "Oy*y*y*y*y*y*y*y*y*y*y*LLLw*w*w*w*w*", &obj, &b[0], &b[1],
+          &b[2], &b[3], &b[4], &b[5], &b[6], &b[7], &b[8], &b[9], &b[10],
+          &now_ms, &td_bound, &frac_safe, &b[11], &b[12], &b[13], &b[14],
+          &b[15]))
+    return nullptr;
+  PyObject* out = nullptr;
+  ColdStore* cs = cold_from(obj);
+  const Py_ssize_t n = b[0].len / 8, m = b[1].len / 8;
+  const uint64_t* kh = (const uint64_t*)b[0].buf;
+  const int64_t* idx = (const int64_t*)b[1].buf;
+  const int64_t* col[9];
+  bool shaped = b[0].len % 8 == 0 && b[1].len % 8 == 0 &&
+                b[11].len == n * 4 && b[12].len == n * 8 &&
+                b[13].len == n * 8 && b[14].len == n * 8 && b[15].len == n &&
+                td_bound > 0;
+  for (int c = 0; c < 9; c++) {
+    col[c] = (const int64_t*)b[2 + c].buf;
+    shaped = shaped && b[2 + c].len == n * 8;
+  }
+  for (Py_ssize_t k = 0; shaped && k < m; k++)
+    shaped = idx[k] >= 0 && idx[k] < n;
+  if (cs == nullptr) {
+    // PyCapsule_GetPointer set the error
+  } else if (!shaped) {
+    PyErr_SetString(PyExc_ValueError,
+                    "want nine i64 columns and five response columns of "
+                    "the khash's length, and row indices inside it");
+  } else {
+    int32_t* o_st = (int32_t*)b[11].buf;
+    int64_t* o_lim = (int64_t*)b[12].buf;
+    int64_t* o_rem = (int64_t*)b[13].buf;
+    int64_t* o_rst = (int64_t*)b[14].buf;
+    uint8_t* o_full = (uint8_t*)b[15].buf;
+    const int64_t* h_now = col[8];
+    try {
+      // (effective stamp, index): the device's segment order
+      std::vector<std::pair<int64_t, int64_t>> order((size_t)m);
+      for (Py_ssize_t k = 0; k < m; k++) {
+        int64_t t = h_now[idx[k]];
+        order[(size_t)k] = {t > 0 ? t : (int64_t)now_ms, idx[k]};
+      }
+      if (!std::is_sorted(order.begin(), order.end()))
+        std::sort(order.begin(), order.end());
+      // the distinct served keys, in order of first service: `seen` is
+      // an open-addressed set of row indices, sized so the pass never
+      // allocates once the store is touched
+      std::vector<uint64_t> distinct;
+      distinct.reserve((size_t)m);
+      size_t smask = 1;
+      while (smask < (size_t)m * 2 + 1) smask <<= 1;
+      std::vector<int64_t> seen(smask--, -1);
+      auto first_service = [&](uint64_t key, int64_t i) {
+        for (size_t j = (size_t)key & smask;; j = (j + 1) & smask) {
+          if (seen[j] < 0) {
+            seen[j] = i;
+            return true;
+          }
+          if (kh[seen[j]] == key) return false;
+        }
+      };
+      // cold_put's growth rule, for all m possible inserts at once: a
+      // mostly-live table doubles, a mostly-tombstone one rehashes in
+      // place; either way it ends at most half full
+      if ((cs->filled + (size_t)m + 1) * 10 >= cs->cap * 7) {
+        size_t cap = cs->cap;
+        while ((cs->used + (size_t)m + 1) * 10 >= cap * 5) cap <<= 1;
+        cold_grow(cs, cap);
+      }
+      static const int64_t zero_row[COLD_ROW] = {0, 0, 0, 1, 0, 0, 0, 0};
+      Py_ssize_t created = 0;
+      bool overflow = false;
+      ColdAns ans;
+      for (size_t k = 0; k < (size_t)m; k++) {
+        const int64_t i = order[k].second;
+        const uint64_t key = kh[i];
+        bool present;
+        const size_t slot = cold_find(cs, key, &present);
+        const ColdReq q = {col[0][i], col[1][i], col[2][i], col[3][i],
+                           col[4][i], col[5][i], col[6][i], col[7][i],
+                           order[k].first};
+        if (!cold_apply_row(present ? &cs->rows[slot * COLD_ROW] : zero_row,
+                            q, td_bound, frac_safe, &ans)) {
+          overflow = true;
+          break;
+        }
+        if (!present) {
+          if (cs->state[slot] == 0) cs->filled++;
+          cs->keys[slot] = key;
+          cs->state[slot] = 1;
+          cs->used++;
+          created++;
+        }
+        std::memcpy(&cs->rows[slot * COLD_ROW], ans.row, sizeof ans.row);
+        o_st[i] = (int32_t)ans.status;
+        o_rem[i] = ans.out_rem;
+        if (!fits64(ans.reset_time)) {
+          overflow = true;
+          break;
+        }
+        o_rst[i] = (int64_t)ans.reset_time;
+        o_lim[i] = ans.row[1];  // the request's limit
+        o_full[i] = 0;
+        if (first_service(key, i)) distinct.push_back(key);
+      }
+      if (overflow)
+        PyErr_SetString(PyExc_OverflowError,
+                        "Python int too large to convert to C long");
+      else
+        out = Py_BuildValue("(nny#)", m, created,
+                            (const char*)distinct.data(),
+                            (Py_ssize_t)(distinct.size() * 8));
+    } catch (const std::bad_alloc&) {
+      PyErr_NoMemory();  // before the first row: the store is as it was
+    }
+  }
+  for (Py_buffer& v : b) PyBuffer_Release(&v);
+  return out;
+}
+
 // cold_get(capsule, key u64) -> bytes(64) | None
 static PyObject* cold_get(PyObject*, PyObject* args) {
   PyObject* obj;
@@ -1781,6 +2116,10 @@ static PyMethodDef methods[] = {
      "cold_put(capsule, key, row64B) -> 1 inserted / 0 overwrote"},
     {"cold_put_batch", cold_put_batch, METH_VARARGS,
      "cold_put_batch(capsule, keys u64le, rows i64le) -> keys inserted"},
+    {"cold_apply_batch", cold_apply_batch, METH_VARARGS,
+     "cold_apply_batch(capsule, khash, idx, 9 request columns, now_ms, "
+     "td_bound, frac_safe, 5 response columns) -> (served, created, "
+     "distinct keys): a wave's cold rows applied in one pass"},
     {"cold_get", cold_get, METH_VARARGS,
      "cold_get(capsule, key) -> 64-byte row | None"},
     {"cold_pop", cold_pop, METH_VARARGS,

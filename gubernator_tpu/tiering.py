@@ -13,7 +13,14 @@ migrates rows between them —
   on the resolve path: ``_host_apply`` mirrors the device transition
   (core/step.py › _apply_position) in plain integer arithmetic, bit
   for bit over the packed input domain, so decisions are byte-identical
-  to an uncapped single-tier run;
+  to an uncapped single-tier run.  Which lane applies it is what the
+  store IS, not an option: over the native store a wave's cold rows are
+  ONE C++ pass (ops/_native.cpp › ``cold_apply_batch``: the order, the
+  find-or-insert, ``_host_apply`` statement for statement in 128-bit
+  intermediates, the answers patched in place); over the dict store —
+  or a built extension without that entry point — the Python loop of
+  ``_host_apply`` calls, which is also the reference the pass is held
+  to (tests/test_cold_apply_batch.py);
 - when a cold key's heavy-hitter rank (analytics.py sketch) clears the
   admission threshold its row migrates to HBM, evicting the coldest
   resident row of its probe window back to host under a
@@ -64,6 +71,11 @@ _DRAIN = int(Behavior.DRAIN_OVER_LIMIT)
 #: the all-zero item a missing key adopts — identical to the device's
 #: out-of-range gather fill (core/step.py › grow: zeros, eff_ms 1)
 _ZERO_ROW = (0, 0, 0, 1, 0, 0, 0, 0)
+
+#: the nine request columns of a wave that a cold row is applied from,
+#: in ``_host_apply``'s argument order
+_REQ_COLS = ("hits", "limit", "duration", "eff_ms", "greg_end", "behavior",
+             "algorithm", "burst", "now")
 
 
 def _host_apply(row, hits, limit, duration, eff, greg_end, behavior,
@@ -188,6 +200,8 @@ class _DictColdStore:
     forces it).  NOT thread-safe — TierController._mu serializes."""
 
     native = False
+    #: no batch pass: ``TierController.resolve`` walks the rows itself
+    apply_batch = None
 
     def __init__(self):
         self._d: Dict[int, tuple] = {}
@@ -234,6 +248,10 @@ class _NativeColdStore:
     def __init__(self, native_mod):
         self._m = native_mod
         self._h = native_mod.cold_new(1024)
+        #: a wave's cold lane as ONE C++ pass, where the build has it
+        self.apply_batch = (self._apply_batch
+                            if hasattr(native_mod, "cold_apply_batch")
+                            else None)
 
     def __len__(self) -> int:
         return self._m.cold_len(self._h)
@@ -255,6 +273,21 @@ class _NativeColdStore:
         self._m.cold_put_batch(self._h,
                                np.ascontiguousarray(keys, "<u8"),
                                np.ascontiguousarray(rows, "<i8"))
+
+    def _apply_batch(self, khash, idxs, req_cols, now_ms: int, cols):
+        """Rows ``idxs`` of a wave applied to their keys' rows in
+        (effective stamp, index) order and answered into ``cols`` — the
+        engine's five response columns, patched in place: status i32,
+        limit / remaining / reset i64, full bool, contiguous — as
+        ``resolve``'s loop does.  Returns (served, keys created, the
+        distinct served keys u64[k] in order of first service);
+        OverflowError where the loop raises it."""
+        served, created, keys = self._m.cold_apply_batch(
+            self._h, np.ascontiguousarray(khash, "<u8"),
+            np.ascontiguousarray(idxs, "<i8"),
+            *(np.ascontiguousarray(c, "<i8") for c in req_cols),
+            int(now_ms), TD_BOUND, FRAC_SAFE, *cols)
+        return served, created, np.frombuffer(keys, "<u8")
 
     def pop(self, kh: int):
         b = self._m.cold_pop(self._h, kh)
@@ -437,8 +470,11 @@ class TierController:
         Per-key requests apply in (arrival time, original index) order
         — the same lexicographic order the device's segment sort gives
         the hot tier, so duplicate-key batches keep sequential parity.
+        Over the native store that is ONE C++ pass
+        (``_NativeColdStore.apply_batch``), over the dict store the
+        loop below; admission then reads the served keys' ranks once.
         """
-        status, lim_o, rem_o, rst_o, full = cols
+        status, full = cols[0], cols[4]
         need = full & orig_valid if orig_valid is not None else full.copy()
         if cold_mask is not None:
             need = need | cold_mask
@@ -450,86 +486,104 @@ class TierController:
         timed = phase("tier.resolve", self.metrics).begin(
             at=time.perf_counter())
         idxs = np.nonzero(need)[0]
+        req = [np.asarray(getattr(batch, f)) for f in _REQ_COLS]
+        with self._mu:
+            store = self._store
+            native = store.apply_batch is not None
+            if native:
+                served, created, served_khs = store.apply_batch(
+                    khash, idxs, req, now_ms, cols)
+            else:
+                served, created, served_khs = self._apply_rows(
+                    store, khash, idxs, req, now_ms, cols)
+            self.cold_served += served
+            self.cold_created += created
+        m = self.metrics
+        if m is not None:
+            m.tier_cold_serves.inc(served)
+            if native:
+                m.tier_cold_native_serves.inc(served)
+            if created:
+                m.tier_cold_creates.inc(created)
+        self._gauge()
+        if self._tap is not None:
+            try:
+                self._tap(khash[idxs], req[0][idxs], status[idxs])
+            except Exception:  # pragma: no cover - analytics only
+                log.exception("tier rank-feed tap")
+        self._admit(engine, served_khs)
+        timed.end(at=time.perf_counter())
+        return cols
 
-        h_hits = np.asarray(batch.hits)
-        h_lim = np.asarray(batch.limit)
-        h_dur = np.asarray(batch.duration)
-        h_eff = np.asarray(batch.eff_ms)
-        h_greg = np.asarray(batch.greg_end)
-        h_beh = np.asarray(batch.behavior)
-        h_alg = np.asarray(batch.algorithm)
-        h_bur = np.asarray(batch.burst)
-        h_now = np.asarray(batch.now)
+    @staticmethod
+    def _apply_rows(store, khash, idxs, req, now_ms: int, cols) -> tuple:
+        """The Python lane of ``resolve`` (any store): ``store.get`` →
+        ``_host_apply`` → ``store.put`` a row, in (effective stamp,
+        index) order.  What ``apply_batch`` returns."""
+        status, lim_o, rem_o, rst_o, full = cols
+        h_now = req[-1]
 
         def _eff_now(i: int) -> int:
             t = int(h_now[i])
             return t if t > 0 else int(now_ms)
 
         order = sorted(idxs.tolist(), key=lambda i: (_eff_now(i), i))
-        served_khs = []
+        served_khs = {}  # distinct, in order of first service
         created = 0
-        with self._mu:
-            store = self._store
-            for i in order:
-                kh = int(khash[i])
-                row = store.get(kh)
-                if row is None:
-                    created += 1
-                st, orem, rst, olim, new_row = _host_apply(
-                    row, int(h_hits[i]), int(h_lim[i]),
-                    int(h_dur[i]), int(h_eff[i]), int(h_greg[i]),
-                    int(h_beh[i]), int(h_alg[i]), int(h_bur[i]),
-                    _eff_now(i))
-                store.put(kh, new_row)
-                status[i] = st
-                rem_o[i] = orem
-                rst_o[i] = rst
-                lim_o[i] = olim
-                full[i] = False
-                served_khs.append(kh)
-            self.cold_served += len(order)
-            self.cold_created += created
-        m = self.metrics
-        if m is not None:
-            m.tier_cold_serves.inc(len(order))
-            if created:
-                m.tier_cold_creates.inc(created)
-        self._gauge()
-        if self._tap is not None:
-            try:
-                self._tap(khash[idxs], h_hits[idxs], status[idxs])
-            except Exception:  # pragma: no cover - analytics only
-                log.exception("tier rank-feed tap")
-        self._admit(engine, served_khs)
-        timed.end(at=time.perf_counter())
-        return status, lim_o, rem_o, rst_o, full
+        for i in order:
+            kh = int(khash[i])
+            row = store.get(kh)
+            if row is None:
+                created += 1
+            st, orem, rst, olim, new_row = _host_apply(
+                row, *(int(c[i]) for c in req[:-1]), _eff_now(i))
+            store.put(kh, new_row)
+            status[i] = st
+            rem_o[i] = orem
+            rst_o[i] = rst
+            lim_o[i] = olim
+            full[i] = False
+            served_khs[kh] = None
+        return len(order), created, list(served_khs)
 
     # ---- admission / migration -----------------------------------------
 
     def _admit(self, engine, khs) -> None:
-        """Promote every just-served cold key whose sketch rank clears
-        the admission threshold.  No rank feed (analytics off) → no
-        admission: serving stays exact, just host-paced.  Phase
-        `tier.migrate`: one sample an admission tried, victim pick and
-        demotion inside."""
-        rank = self.rank_fn
-        if rank is None or not khs:
+        """Promote every just-served cold key (``khs``: distinct, in
+        order of first service) whose sketch rank clears the admission
+        threshold.  No rank feed (analytics off) → no admission: serving
+        stays exact, just host-paced.  The ranks are read ONCE a wave
+        where the feed has a batched read (``rank_batch``), a key at a
+        time otherwise.  Phase `tier.migrate`: one sample an admission
+        tried, victim pick and demotion inside."""
+        if self.rank_fn is None or not len(khs):
             return
         thr = self.promote_threshold
-        seen = set()
-        for kh in khs:
-            if kh in seen:
-                continue
-            seen.add(kh)
+        if self.rank_batch is not None:
             try:
-                r = rank(kh)
+                ranks = np.asarray(self.rank_batch(khs))
+            except Exception:  # pragma: no cover - analytics only
+                return
+            hot = ((int(khs[j]), int(ranks[j]))
+                   for j in np.nonzero(ranks >= thr)[0])
+        else:
+            hot = self._ranked_over(khs, thr)
+        for kh, r in hot:
+            timed = phase("tier.migrate", self.metrics).begin(
+                at=time.perf_counter())
+            self.promote(engine, kh, r)
+            timed.end(at=time.perf_counter())
+
+    def _ranked_over(self, khs, thr: int):
+        """(key, rank) of ``khs`` at or over ``thr``, read a key at a
+        time as each is reached (``rank_fn`` alone: the tests' feeds)."""
+        for kh in khs:
+            try:
+                r = self.rank_fn(int(kh))
             except Exception:  # pragma: no cover - analytics only
                 return
             if r >= thr:
-                timed = phase("tier.migrate", self.metrics).begin(
-                    at=time.perf_counter())
-                self.promote(engine, kh, r)
-                timed.end(at=time.perf_counter())
+                yield int(kh), r
 
     def promote(self, engine, kh: int, rank: int) -> bool:
         """Migrate one cold row to the device tier, evicting the

@@ -37,7 +37,8 @@ SEED = 4100000021
 ROWS = 2048  # 16 buckets of 128 slots
 NEW_READERS = ("tier_cold_rows_per_wave", "tier_created_keys_per_wave",
                "tier_resolve_ms", "tier_premask_ms",
-               "tier_migrations_per_s", "tier_cold_keys_m")
+               "tier_migrations_per_s", "tier_cold_keys_m",
+               "tier_native_apply_share")
 
 
 def _cell():
@@ -209,12 +210,18 @@ def test_a_keys_last_row_decides_its_tier(last):
 
 # ---- (b) the deployment answers as the plain reference ------------------
 
-def test_the_tiered_deployment_answers_as_the_plain_reference(monkeypatch):
+@pytest.mark.parametrize("native", ["1", "0"])
+def test_the_tiered_deployment_answers_as_the_plain_reference(monkeypatch,
+                                                              native):
+    """Both lanes of the cold tier: the native store, whose rows ONE C++
+    pass applies (the deployment's own), and the dict store with the
+    Python loop (``GUBER_TIER_NATIVE=0``)."""
     _, cfg, mix, pop = _cell()
     for name in [k for k in os.environ if k.startswith("GUBER_")]:
         monkeypatch.delenv(name)
     for name, value in cfg["env"].items():
         monkeypatch.setenv(name, value)
+    monkeypatch.setenv("GUBER_TIER_NATIVE", native)
     addr = f"127.0.0.1:{free_port()}"
     daemon = spawn_daemon(DaemonConfig(
         grpc_listen_address=addr,
@@ -277,6 +284,9 @@ def test_the_tiered_deployment_answers_as_the_plain_reference(monkeypatch):
             if line and not line.startswith("#"))}
         assert m["gubernator_tier_cold_creates_total"] == st["cold_created"]
         assert m["gubernator_tier_cold_serves_total"] == st["cold_served"]
+        assert st["native"] == (native == "1")
+        assert m["gubernator_tier_cold_native_serves_total"] == (
+            st["cold_served"] if st["native"] else 0.0)
         assert m.get("gubernator_table_full_rows_total", 0.0) == 0.0
         for p in ("tier.premask", "tier.resolve", "restore.adopt"):
             assert m[f'gubernator_phase_duration_count{{phase="{p}"}}'] > 0
@@ -412,6 +422,7 @@ def test_the_new_readers_on_canned_scrapes():
     m0 = {"gubernator_dispatcher_wave_size_count": 10.0,
           "gubernator_dispatcher_wave_duration_count": 10.0,
           "gubernator_tier_cold_serves_total": 100.0,
+          "gubernator_tier_cold_native_serves_total": 100.0,
           "gubernator_tier_cold_creates_total": 50.0,
           "gubernator_tier_promotions_total": 1.0,
           "gubernator_tier_demotions_total": 1.0,
@@ -424,6 +435,7 @@ def test_the_new_readers_on_canned_scrapes():
           "gubernator_dispatcher_wave_size_count": 20.0,
           "gubernator_dispatcher_wave_duration_count": 20.0,
           "gubernator_tier_cold_serves_total": 12_100.0,
+          "gubernator_tier_cold_native_serves_total": 9_100.0,
           "gubernator_tier_cold_creates_total": 10_550.0,
           "gubernator_tier_promotions_total": 4.0,
           "gubernator_tier_demotions_total": 2.0,
@@ -436,7 +448,8 @@ def test_the_new_readers_on_canned_scrapes():
                    "tier_resolve_ms": pytest.approx(100.0),
                    "tier_premask_ms": pytest.approx(10.0),
                    "tier_migrations_per_s": 1.0,
-                   "tier_cold_keys_m": 9.5}
+                   "tier_cold_keys_m": 9.5,
+                   "tier_native_apply_share": 75.0}
 
 
 def test_the_cpu_rehearsal_of_the_cell_is_correct_and_reads_the_tier():
@@ -458,6 +471,9 @@ def test_the_cpu_rehearsal_of_the_cell_is_correct_and_reads_the_tier():
     assert 0 < metrics["tier_cold_rows_per_wave"]["value"] \
         < metrics["rows_per_wave"]["value"]
     assert metrics["tier_created_keys_per_wave"]["value"] > 0
+    # the rehearsal's daemon runs the build it was started from: every
+    # cold row went through the C++ pass
+    assert metrics["tier_native_apply_share"]["value"] == 100.0
     assert metrics["tier_cold_keys_m"]["value"] * 1e6 > 3000 - 2048
     assert metrics["fused_ingest_share"]["value"] == 100.0
     assert metrics["wave_identity_route_share"]["value"] == 0.0

@@ -112,20 +112,38 @@ def _drive_parity(small, big, tc, ranks, *, steps=50, nkeys=2000,
     return st
 
 
-def test_engine_capped_parity_and_migration():
+def _lane(monkeypatch, native: str) -> None:
+    """``GUBER_TIER_NATIVE``: "1" = the native store, whose cold lane is
+    the C++ pass (ISSUE 42); "0" = the dict store and the Python loop."""
+    monkeypatch.setenv("GUBER_TIER_NATIVE", native)
+
+
+def _assert_lane(tc, native: str) -> None:
+    assert tc.stats()["native"] == (native == "1")
+    assert (tc._store.apply_batch is not None) == (native == "1")
+
+
+@pytest.mark.parametrize("native", ["1", "0"])
+def test_engine_capped_parity_and_migration(monkeypatch, native):
     """Tentpole acceptance at engine level: 2000 keys through a 64-row
     table + cold tier are byte-identical to a 16K-row table, zero
     table-full rows, with real promote/demote traffic, and every row
-    lives in exactly one tier afterwards."""
+    lives in exactly one tier afterwards — on both lanes of the cold
+    tier."""
+    _lane(monkeypatch, native)
     small, big, tc, ranks = _engine_pair()
+    _assert_lane(tc, native)
     _drive_parity(small, big, tc, ranks)
 
 
-def test_pipelined_lane_cold_serve_parity():
+@pytest.mark.parametrize("native", ["1", "0"])
+def test_pipelined_lane_cold_serve_parity(monkeypatch, native):
     """The launch/sync split lane: cold rows ride the wave invalid and
     re-dispatch exactly at sync time (under the engine lock), so the
     pipelined dispatcher path keeps the same byte-identical contract."""
+    _lane(monkeypatch, native)
     small, big, tc, ranks = _engine_pair()
+    _assert_lane(tc, native)
     _drive_parity(small, big, tc, ranks, pipelined=True, seed=6)
 
 
@@ -380,3 +398,58 @@ def test_cold_store_native_dict_parity(monkeypatch):
     s1 = {int(k): tuple(map(int, r)) for k, r in zip(k1, r1)}
     s2 = {int(k): tuple(map(int, r)) for k, r in zip(k2, r2)}
     assert s1 == s2
+
+
+@pytest.mark.parametrize("state", ["empty", "partly_full", "evicting"])
+def test_sketch_counts_of_an_array_is_count_of_key_by_key(state):
+    """The admission's batched rank read (ISSUE 42): one lock, one
+    reindex, one vectorised probe — the answers of ``count_of`` a key at
+    a time, for tracked and untracked keys, in the caller's order, with
+    repeats."""
+    from gubernator_tpu.analytics import KeyAnalytics
+
+    a = KeyAnalytics(k=16, width=64)
+    try:
+        rng = np.random.default_rng(9)
+        pool = rng.integers(1, 1 << 63, 400).astype(np.uint64) * np.uint64(2)
+        fed = {"empty": 0, "partly_full": 40, "evicting": 400}[state]
+        with a._mu:
+            for lo in range(0, fed, 50):
+                kh = pool[rng.integers(0, fed, 200)]
+                a.sketch.update(kh, rng.integers(1, 9, 200),
+                                np.zeros(200, bool), NOW + lo)
+        ask = np.concatenate([pool[:120], pool[:5], pool[300:],
+                              np.array([0, 1, (1 << 64) - 1], np.uint64)])
+        rng.shuffle(ask)
+        got = a.sketch_counts(ask)
+        assert got.dtype == np.int64 and got.shape == ask.shape
+        want = [a.sketch_count(int(k)) for k in ask]
+        assert got.tolist() == want
+        assert a.sketch_counts(ask.tolist()).tolist() == want  # a list too
+        assert (got > 0).any() == (fed > 0)
+        assert (got == 0).any()
+        assert a.sketch_counts([]).shape == (0,)
+    finally:
+        a.close()
+
+
+def test_admission_reads_its_ranks_once_a_wave_in_order_of_service():
+    """``_admit`` with a batched feed: ONE call for all the served keys,
+    then ``promote`` for those at or over the threshold, in the order
+    they were served."""
+    class _E:
+        tier = None
+
+    asked, promoted = [], []
+    ranks = {11: 9, 12: 3, 13: 8, 14: 100}
+    tc = TierController(
+        _E(), rank_fn=lambda kh: 1 / 0, promote_threshold=8,
+        rank_batch=lambda khs: (asked.append(list(map(int, khs)))
+                                or np.array([ranks[int(k)] for k in khs])))
+    tc.promote = lambda engine, kh, rank: promoted.append((kh, rank))
+    tc._admit(_E(), np.array([14, 12, 11, 13], np.uint64))
+    assert asked == [[14, 12, 11, 13]]
+    assert promoted == [(14, 100), (11, 9), (13, 8)]
+    assert all(type(v) is int for pair in promoted for v in pair)
+    tc._admit(_E(), [])
+    assert len(asked) == 1

@@ -124,6 +124,9 @@ def main() -> int:
     VALUE_MAX = (1 << 62) - 1
     EFF_MAX = 1 << 31
     TD_BOUND = (1 << 62) - 1
+    # the six approximate calendar widths (types.GREGORIAN_APPROX_MS)
+    GREG_WIDTHS = np.array([60_000, 3_600_000, DAY, 7 * DAY, 30 * DAY,
+                            365 * DAY], "<i8").tobytes()
 
     errs: list = []
     barrier = threading.Barrier(args.threads)
@@ -148,6 +151,27 @@ def main() -> int:
         keys = np.arange(base + 1, base + 33, dtype="<u8")
         out = np.zeros(32, np.uint8)
         native.cold_contains(store, keys.tobytes(), out)
+        # a wave's cold lane in one pass (ISSUE 42): find-or-insert,
+        # the transition, the answers patched in place — 48 rows over 24
+        # keys (each twice, stamps out of index order, some unset), token
+        # and leaky, on a table that has to grow for them now and then
+        m = 48
+        kh = ((np.arange(m, dtype="<u8") % 24) + base + 200 + (i % 5) * 24)
+        alg = (np.arange(m) % 3 == 0).astype("<i8")
+        lim = np.full(m, 100, "<i8")
+        dur = np.full(m, 60_000, "<i8")
+        stamp = NOW + i * 1000 - np.arange(m, dtype="<i8") * (i % 2)
+        stamp[::7] = 0
+        st = np.full(m, -1, np.int32)
+        o_lim, o_rem, o_rst = (np.zeros(m, "<i8") for _ in range(3))
+        full = np.ones(m, bool)
+        served, created, keys = native.cold_apply_batch(
+            store, kh, np.arange(m, dtype="<i8"),
+            np.ones(m, "<i8"), lim, dur, dur, np.zeros(m, "<i8"),
+            np.zeros(m, "<i8"), alg, lim * 2, stamp, NOW + i * 1000,
+            TD_BOUND, 1 << 31, st, o_lim, o_rem, o_rst, full)
+        assert served == m and created <= 24 and len(keys) == 8 * 24
+        assert not full.any() and (st >= 0).all() and (o_lim == 100).all()
         n, kb, rb = native.cold_snapshot(store)
         assert len(kb) == 8 * n and len(rb) == 64 * n
         assert native.cold_len(store) == n
@@ -174,7 +198,7 @@ def main() -> int:
                 # fused pack into THIS thread's leased matrices
                 res = native.pack_wire_wave(
                     fwd, NOW + i, a64, a32, m, DURATION_MAX, VALUE_MAX,
-                    EFF_MAX, TD_BOUND)
+                    EFF_MAX, TD_BOUND, 0, 0, GREG_WIDTHS)
                 assert res is not None and res[0] == n_req
                 # response build out of shared-shape columns
                 st = np.zeros(n_req, np.int32)

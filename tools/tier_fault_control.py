@@ -15,6 +15,8 @@ store is applied as if the store held none, so it opens a fresh bucket
 in the row's place (what upstream's LRU does to an evicted key).
 ``fork``: every ``every``-th such request is answered but its write is
 lost, so the key's next request is answered from the state before it.
+(On the native store's lane, where ONE C++ pass applies a wave, "request"
+reads "key of a wave that the store holds": ``inject``.)
 The last line on standard error says how many rows were faulted and how
 many of them were LIVE at the request's clock: forgetting a bucket that
 has run out changes no answer (an expired row IS a missing one), so
@@ -30,8 +32,13 @@ sys.path.insert(0, REPO)
 
 
 def inject(fault: str, every: int) -> dict:
-    """Patch the cold lane's transition (``tiering._host_apply``, which
-    ``resolve`` calls once a cold row); returns the live counts."""
+    """Patch the cold lane's transition on BOTH its lanes; returns the
+    live counts.  The Python loop calls ``tiering._host_apply`` once a
+    cold row: the fault is put there, a request at a time.  The C++ pass
+    (``_NativeColdStore._apply_batch``: the native store's lane, the
+    cell's since ISSUE 42) applies a whole wave: the fault is put at the
+    store round it, a held KEY of a wave at a time — forgotten before
+    the pass, or given its old row back after it."""
     from gubernator_tpu import tiering
 
     if fault not in ("forget", "fork"):
@@ -40,19 +47,45 @@ def inject(fault: str, every: int) -> dict:
     expire_at = tiering.ROW_COLS.index("expire_at")
     apply = tiering._host_apply
 
-    def faulty(row, *request):
-        if row is None:
-            return apply(row, *request)
+    def pick(row, clock) -> bool:
+        """Whether this held row is the ``every``-th: counted if so."""
         done["held"] += 1
         if done["held"] % every:
-            return apply(row, *request)
+            return False
         done["faults"] += 1
-        done["live"] += row[expire_at] > request[-1]  # at its clock
+        done["live"] += row[expire_at] > clock  # at its clock
+        return True
+
+    def faulty(row, *request):
+        if row is None or not pick(row, request[-1]):
+            return apply(row, *request)
         if fault == "forget":
             return apply(None, *request)
         return (*apply(row, *request)[:4], row)  # the write is lost
 
+    apply_batch = tiering._NativeColdStore._apply_batch
+
+    def faulty_batch(store, khash, idxs, req_cols, now_ms, cols):
+        # the wave's held keys, each at the row that comes first
+        rows = idxs[store.contains_batch(khash[idxs])]
+        first = {}
+        for i in rows.tolist():
+            first.setdefault(int(khash[i]), i)
+        picked = []
+        for kh, i in first.items():
+            row = store.get(kh)
+            if pick(row, int(req_cols[-1][i]) or now_ms):
+                picked.append((kh, row))
+                if fault == "forget":
+                    store.pop(kh)
+        out = apply_batch(store, khash, idxs, req_cols, now_ms, cols)
+        if fault == "fork":
+            for kh, row in picked:
+                store.put(kh, row)  # the wave's writes are lost
+        return out
+
     tiering._host_apply = faulty
+    tiering._NativeColdStore._apply_batch = faulty_batch
     return done
 
 

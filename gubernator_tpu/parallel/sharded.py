@@ -716,13 +716,17 @@ class ShardedEngine:
         syncs.  ``mslot`` rides the token so the sync-side retry keeps
         the rows' lanes.
 
-        Cold-tier rows (tiering.py) ride the wave invalid and their
-        indices ride the token: the SYNC side re-dispatches them
-        through check_packed under the engine lock — serving them here
-        would let a promotion that lands between launch and sync read
-        a row this lane already consumed.  Rows outside the step
-        program's value domain (``_out_of_domain``) ride invalid too;
-        the sync side marks them unservable.
+        A token's UNANSWERED rows are the ones these launches leave
+        without an answer: the rows that err (no slot in their probe
+        window: found at sync) and, with a tier bound (tiering.py), the
+        rows that are cold-resident NOW — they ride the wave invalid
+        and their indices ride the token.  The SYNC side re-dispatches
+        both together, once, under the engine lock (``sync_packed``) —
+        serving a cold row here would let a promotion that lands
+        between launch and sync read a row this lane already consumed.
+        Rows outside the step program's value domain
+        (``_out_of_domain``) ride invalid too; the sync side marks them
+        unservable.
 
         The token's batch is row views of the wave — of its LEASE where
         the blocks were joined straight into one — so every lease rides
@@ -811,13 +815,25 @@ class ShardedEngine:
         """Pipeline phase 2: block on the launched waves and assemble
         the response columns (same contract as check_packed).  The
         token stays alive — ``drop_packed`` ends it.  Reading
-        launched outputs needs no lock (state isn't touched); the
-        table-full RETRY path re-enters ``_check_rows``, which mutates
-        state, so it runs under ``engine_lock`` when one is given.  A
-        retried row applies after any wave launched meanwhile —
-        acceptable: erred rows never mutated state, retries are the
-        table-full corner, and the device clamps per-key time
-        monotonically."""
+        launched outputs needs no lock (state isn't touched).
+
+        The token's unanswered rows — the rows that erred and the rows
+        that rode invalid because they were cold-resident at launch
+        (``launch_packed``) — are re-dispatched TOGETHER, in arrival
+        order, through ONE ``_check_rows``, which mutates state, so it
+        runs under ``engine_lock`` when one is given: one premask read
+        NOW (a key promoted, or created on the host, since the launch
+        goes where it lives now), one launch of the rows the device may
+        hold, one ``tier.resolve`` of the rest.  That launch stays even
+        with a tier to answer what errs: the next wave was launched
+        before this sync and may have INSERTED an erred key into a slot
+        freed meanwhile — the re-dispatch lands after it and reads the
+        device's truth; creating the key on the host unasked would fork
+        its bucket.  How many launches an erred row costs after it
+        depends on whether something answers the residue
+        (``_check_wave``).  A re-dispatched row applies after any wave
+        launched meanwhile — acceptable: unanswered rows never mutated
+        state, and the device clamps per-key time monotonically."""
         batch, khash, now_ms, launched, mslot, cold_idx, ood = token
         finished = [self._finish_wave(packed, counters)
                     for _idx, _slots, packed, counters, _lease in launched]
@@ -842,38 +858,25 @@ class ShardedEngine:
                 # out-of-domain rows rode invalid (never erred, never
                 # cold): unservable, the shape a full probe window has
                 full[ood] = True
-        # the re-dispatches below run _check_rows, phases and all
+        if cold_idx is not None:
+            # disjoint: a cold row rode invalid, so it cannot have erred
+            err_idx.extend(cold_idx.tolist())
         if err_idx:
-            ei = np.asarray(sorted(err_idx))
-            sub = batch.rows.take(ei).batch
-            msub = None if mslot is None else np.asarray(mslot)[ei]
+            # the re-dispatch runs _check_rows, phases and all
+            ui = np.asarray(sorted(err_idx))
+            sub = batch.rows.take(ui).batch
+            if cold_idx is not None:
+                sub.valid[:] = True  # erred or cold: each was valid
+            msub = None if mslot is None else np.asarray(mslot)[ui]
             with (engine_lock if engine_lock is not None
                   else contextlib.nullcontext()):
                 r_st, r_lim, r_rem, r_rst, r_full = self._check_rows(
-                    sub, khash[ei], now_ms, msub)
-            status[ei] = r_st
-            lim_o[ei] = r_lim
-            rem_o[ei] = r_rem
-            rst_o[ei] = r_rst
-            full[ei] = r_full
-        if cold_idx is not None and len(cold_idx):
-            # cold-tier rows rode the waves invalid (see launch_packed):
-            # re-dispatch just them through _check_rows, which serves
-            # from whichever tier the key is in NOW — exact even when a
-            # promotion landed between our launch and this sync
-            ci = np.asarray(cold_idx)
-            sub = batch.rows.take(ci).batch
-            sub.valid[:] = True
-            msub = None if mslot is None else np.asarray(mslot)[ci]
-            with (engine_lock if engine_lock is not None
-                  else contextlib.nullcontext()):
-                c_st, c_lim, c_rem, c_rst, c_full = self._check_rows(
-                    sub, khash[ci], now_ms, msub)
-            status[ci] = c_st
-            lim_o[ci] = c_lim
-            rem_o[ci] = c_rem
-            rst_o[ci] = c_rst
-            full[ci] = c_full
+                    sub, khash[ui], now_ms, msub, redispatch=True)
+            status[ui] = r_st
+            lim_o[ui] = r_lim
+            rem_o[ui] = r_rem
+            rst_o[ui] = r_rst
+            full[ui] = r_full
         self._count_table_full(full)
         return status, lim_o, rem_o, rst_o, full
 
@@ -1013,13 +1016,14 @@ class ShardedEngine:
         return cols
 
     def _check_rows(self, batch: RequestBatch, khash: np.ndarray,
-                    now_ms: int, mslot) -> tuple:
+                    now_ms: int, mslot, redispatch: bool = False) -> tuple:
         """``check_packed`` without the count of its table_full rows:
-        what ``sync_packed`` re-dispatches its erred and cold rows
-        through, and counts with the rest of its wave."""
+        what ``sync_packed`` re-dispatches a token's unanswered rows
+        through (``redispatch``), and counts with the rest of its
+        wave."""
         wave, khash, mslot = self._wave_of(batch, khash, mslot)
         try:
-            return self._check_wave(wave, khash, now_ms, mslot)
+            return self._check_wave(wave, khash, now_ms, mslot, redispatch)
         finally:
             # a lease joined HERE goes back now that nothing reads the
             # wave's rows (views of it); one the caller joined (the
@@ -1029,7 +1033,26 @@ class ShardedEngine:
                     and wave is not getattr(batch, "rows", None)):
                 wave.lease.release()
 
-    def _check_wave(self, wave: Rows, khash, now_ms: int, mslot) -> tuple:
+    def _check_wave(self, wave: Rows, khash, now_ms: int, mslot,
+                    redispatch: bool = False) -> tuple:
+        """Answer every row of ``wave``, blocking: launch the rows the
+        device may hold, launch the rows that err once more — a row
+        that lost every claim round finds its slot alone —, then grow
+        the table if that is allowed, and give what still errs to the
+        tier (find-or-create on the host) or, with none bound, answer
+        it table_full.
+
+        ``redispatch``: the rows are a synced token's unanswered rows
+        (``sync_packed``), launched once already.  With a tier bound
+        that launch was the first try and this wave's one launch IS the
+        retry: nothing can launch between it and ``tier.resolve`` under
+        the engine lock, so a second would re-read a device that cannot
+        have changed; and a re-dispatch none of whose rows would ride
+        valid (its rows are all cold NOW) launches nothing — the
+        columns stay the zeros they start as and the tier serves them.
+        With no tier a re-dispatch retries as any wave does: there the
+        second launch is the difference between an answer and a
+        table_full error."""
         n = len(khash)
         status = np.zeros(n, np.int32)
         rem_o = np.zeros(n, np.int64)
@@ -1039,8 +1062,10 @@ class ShardedEngine:
         with phase("wave.route"):
             valid, cold_mask, orig_valid = self._ride_invalid(wave, khash,
                                                               mslot)
-        retried = False
+        retried = redispatch and self.tier is not None
         pending = None  # every row, in arrival order
+        if retried and not (wave.valid if valid is None else valid).any():
+            pending = ()  # every row is cold NOW: nothing to launch
         while pending is None or len(pending):
             err_idx: List[int] = []
             for idx, slots, lease, mblk in self._device_waves(

@@ -33,9 +33,10 @@ instance engine lock, so a key is resident in exactly ONE tier at any
 decision point.  ``ShardedEngine.check_packed`` pre-masks cold-resident
 rows out of the device wave (a cold key hitting a non-full device table
 would otherwise insert fresh — a state fork) and serves them here on
-the way out.  The pipelined launch/sync lane and the fused C++ ingest
-lane re-enter ``check_packed`` for their cold rows, the same way their
-table-full retry already does.
+the way out.  The pipelined launch/sync lane re-dispatches a wave's
+cold rows at sync time together with its erred rows, ONCE
+(``ShardedEngine.sync_packed``): one premask, one launch — the erred
+rows' retry —, one ``resolve``.
 
 The cold store itself is the native open-addressed table in
 ops/_native.cpp (``cold_*`` primitives, khash u64 → 8×i64 row) when the

@@ -397,12 +397,16 @@ def test_the_deployment_and_its_cell_are_found_by_name():
             "wave_native_route_share", "sweep_ms",
             "device_idle_share", "hbm_peak_gb"} <= mine
     # the kernel's two readers divide by the rows of ONE launch a
-    # dispatcher wave; a tiered wave is four (PERF.md 7): not here
+    # dispatcher wave; a tiered wave is two (PERF.md 7): not here
     assert not {"kernel_ns_per_row", "decide_kernel_roofline"} & mine
     manifest = run.load_json(REPO, "BENCHMARK.json")
     for m in manifest["per_layer"]:
         if m["name"] in NEW_READERS:
             assert m["workloads"] == [CELL] and m["layer"] == "cold tier"
+    assert manifest["per_layer"][-1] == {
+        "name": "tier_launches_per_wave", "unit": "launches",
+        "better": "lower", "source": "program_counter", "layer": "engine",
+        "moves": "decisions_per_s", "workloads": [CELL]}
 
 
 @pytest.mark.parametrize("name", NEW_READERS)
@@ -452,6 +456,24 @@ def test_the_new_readers_on_canned_scrapes():
                    "tier_native_apply_share": 75.0}
 
 
+@pytest.mark.parametrize("routes, want", [
+    ({}, None),  # a program without the counter
+    ({"sorted": (30.0, 70.0)}, 2.0),  # ISSUE 43: launch + ONE re-dispatch
+    ({"sorted": (30.0, 110.0)}, 4.0),  # the parent: three re-dispatches
+    ({"identity": (5.0, 20.0), "sorted": (0.0, 5.0)}, 1.0),
+])
+def test_launches_per_wave_sums_the_routes(routes, want):
+    """``tier_launches_per_wave``: the device waves of every route ÷ the
+    dispatcher's waves, between the window's scrapes."""
+    m0 = {"gubernator_dispatcher_wave_size_count": 10.0}
+    m1 = {"gubernator_dispatcher_wave_size_count": 30.0}
+    for route, (a, b) in routes.items():
+        m0[f'gubernator_wave_route_total{{route="{route}"}}'] = a
+        m1[f'gubernator_wave_route_total{{route="{route}"}}'] = b
+    read = plugins.load("layer_metrics", "tier_launches_per_wave").read
+    assert read({"m0": m0, "m1": m1, "seconds": 4.0}) == want
+
+
 def test_the_cpu_rehearsal_of_the_cell_is_correct_and_reads_the_tier():
     p = subprocess.run(
         [sys.executable, os.path.join(REPO, "benchmark", "run.py"),
@@ -477,6 +499,10 @@ def test_the_cpu_rehearsal_of_the_cell_is_correct_and_reads_the_tier():
     assert metrics["tier_cold_keys_m"]["value"] * 1e6 > 3000 - 2048
     assert metrics["fused_ingest_share"]["value"] == 100.0
     assert metrics["wave_identity_route_share"]["value"] == 0.0
+    # the wave and at most ONE re-dispatch of its unanswered rows (a
+    # wave in flight at a scrape is split between the counters: ~40
+    # waves a window here; the parent reads 3.65)
+    assert 1.0 < metrics["tier_launches_per_wave"]["value"] < 2.5
 
 
 @pytest.mark.parametrize("fault", ["forget", "fork"])

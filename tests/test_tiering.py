@@ -85,6 +85,14 @@ def _audit_all_rows(small, big, tc, nkeys):
         assert got == want, (int(allk[i]), got, want)
 
 
+def _pipelined(eng, b, kh, now, lock):
+    tok = eng.launch_packed(b, kh, now)
+    try:
+        return eng.sync_packed(tok, engine_lock=lock)
+    finally:
+        eng.drop_packed(tok)
+
+
 def _drive_parity(small, big, tc, ranks, *, steps=50, nkeys=2000,
                   pipelined=False, seed=5):
     rng = random.Random(seed)
@@ -97,9 +105,7 @@ def _drive_parity(small, big, tc, ranks, *, steps=50, nkeys=2000,
         for k in kh:
             ranks[int(k)] = ranks.get(int(k), 0) + 1
         if pipelined:
-            tok = small.launch_packed(b, kh, now)
-            r1 = small.sync_packed(tok, engine_lock=lock)
-            small.drop_packed(tok)
+            r1 = _pipelined(small, b, kh, now, lock)
         else:
             r1 = small.check_packed(b, kh, now)
         r2 = big.check_packed(b, kh, now)
@@ -145,6 +151,160 @@ def test_pipelined_lane_cold_serve_parity(monkeypatch, native):
     small, big, tc, ranks = _engine_pair()
     _assert_lane(tc, native)
     _drive_parity(small, big, tc, ranks, pipelined=True, seed=6)
+
+
+def _count_launches(eng) -> list:
+    """Every later ``_launch_arrays`` of ``eng`` appends to the list
+    returned."""
+    real = type(eng)._launch_arrays.__get__(eng)
+    calls = []
+
+    def spy(*a):
+        calls.append(1)
+        return real(*a)
+
+    eng._launch_arrays = spy
+    return calls
+
+
+def _fill_table(engines, nkeys=400):
+    """Keys 1..nkeys through every engine, 50 a wave: a 64-row table is
+    full and its tier holds the rest."""
+    for a in range(1, nkeys, 50):
+        keys = list(range(a, min(a + 50, nkeys)))
+        b, kh = _packed(keys, [1] * len(keys), NOW)
+        cols = [e.check_packed(b, kh, NOW) for e in engines]
+        for c in cols[1:]:
+            _assert_wave_parity(c, cols[0], a)
+
+
+def _keys_by_tier(small, tc, keys):
+    """(device-resident, cold-resident) of ``keys``, which are all
+    held."""
+    kh = np.array([hash_key("tier", f"k{k}") for k in keys], np.uint64)
+    cold = tc.resident_mask(kh)
+    found, _ = small.gather_rows(kh)
+    assert (cold ^ found).all()
+    keys = np.asarray(keys)
+    return keys[found].tolist(), keys[cold].tolist()
+
+
+def _redispatch_engines(monkeypatch, native):
+    """A full 64-row tiered engine for the pipelined lane and the
+    uncapped control — the same 399 keys through both — with the
+    tier's controller and the tiered engine's launch count."""
+    _lane(monkeypatch, native)
+    small, big, tc, _ = _engine_pair()
+    _assert_lane(tc, native)
+    _fill_table([big, small])
+    assert small.occupancy() == 64 and tc.cold_keys() == 399 - 64
+    return small, big, tc, _count_launches(small)
+
+
+@pytest.mark.parametrize("native", ["1", "0"])
+def test_sync_redispatches_erred_and_cold_rows_once(monkeypatch, native):
+    """ISSUE 43 (a): a pipelined wave with erred AND cold-resident rows
+    is TWO launches — the wave and ONE re-dispatch of both kinds of
+    unanswered row together — and answers row for row what
+    ``check_packed`` and the uncapped control answer, duplicate keys
+    within the wave included."""
+    small, big, tc, launches = _redispatch_engines(monkeypatch, native)
+    twin = ShardedEngine(make_mesh(n=1), capacity_per_shard=64,
+                         batch_per_shard=64)  # the blocking lane's
+    TierController(twin, rank_fn=lambda kh: 0)
+    _fill_table([twin])
+    hot, cold = _keys_by_tier(small, tc, range(1, 400))
+    first_seen = list(range(1000, 1012))  # every window is full: they err
+    keys = (hot[:6] + cold[:6] + first_seen + cold[:3] + first_seen[:4]
+            + hot[:2] + cold[4:9] + first_seen[2:5])
+    random.Random(43).shuffle(keys)
+    lock = threading.Lock()
+    for step in range(3):  # step 2, 3: the first-seen keys are cold now
+        now = NOW + 1000 * (step + 1)
+        b, kh = _packed(keys, [(i + step) % 3 for i in range(len(keys))],
+                        now)
+        served = tc.stats()["cold_served"]
+        del launches[:]
+        r1 = _pipelined(small, b, kh, now, lock)
+        assert len(launches) == (2 if step == 0 else 1), (step, launches)
+        _assert_wave_parity(r1, big.check_packed(b, kh, now), step)
+        _assert_wave_parity(r1, twin.check_packed(b, kh, now), step)
+        # hot[:6] are 8 of the rows; the host answered all the others
+        assert tc.stats()["cold_served"] - served == len(keys) - 8
+    _audit_all_rows(small, big, tc, 1012)
+
+
+@pytest.mark.parametrize("native", ["1", "0"])
+def test_sync_launches_nothing_for_cold_rows_alone(monkeypatch, native):
+    """ISSUE 43 (b): a wave whose only unanswered rows are
+    cold-resident launches nothing at sync — no row of its re-dispatch
+    would ride valid — and the tier answers them exactly."""
+    small, big, tc, launches = _redispatch_engines(monkeypatch, native)
+    hot, cold = _keys_by_tier(small, tc, range(1, 400))
+    keys = hot[:10] + cold[:10] + cold[:5] + hot[3:6]
+    now = NOW + 1000
+    b, kh = _packed(keys, [1] * len(keys), now)
+    r1 = _pipelined(small, b, kh, now, threading.Lock())
+    assert len(launches) == 1
+    _assert_wave_parity(r1, big.check_packed(b, kh, now), 0)
+    _audit_all_rows(small, big, tc, 400)
+
+
+@pytest.mark.parametrize("native", ["1", "0"])
+def test_sync_retry_reads_a_key_the_next_wave_inserted(monkeypatch, native):
+    """ISSUE 43 (c), the race the one retry launch exists for: a key
+    errs in wave N, a slot of its window is freed and wave N+1 —
+    launched before N's sync — INSERTS it.  N's sync must serve the row
+    from the device, where its re-dispatch lands after N+1 and finds
+    the key, and leave no host copy: the key stays in one tier."""
+    small, _, tc, launches = _redispatch_engines(monkeypatch, native)
+    b, kh = _packed([5000], [1], NOW + 1000)
+    k = int(kh[0])
+    lock = threading.Lock()
+    tok_n = small.launch_packed(b, kh, NOW + 1000)  # errs: window full
+    victim = next(int(v) for v in small.probe_occupant_keys(k) if v)
+    assert tc.demote(small, victim)
+    tok_n1 = small.launch_packed(b, kh, NOW + 1000)  # inserts the key
+    assert len(launches) == 2
+    r_n = small.sync_packed(tok_n, engine_lock=lock)
+    assert len(launches) == 3  # N's row did err, and was launched ONCE more
+    r_n1 = small.sync_packed(tok_n1, engine_lock=lock)
+    assert len(launches) == 3
+    small.drop_packed(tok_n)
+    small.drop_packed(tok_n1)
+    assert not r_n[4].any() and not r_n1[4].any()
+    # N+1 reached the device first: the two hits, each applied once
+    assert (int(r_n1[2][0]), int(r_n[2][0])) == (999, 998)
+    assert not tc.resident_mask(kh).any() and tc.peek_row(k) is None
+    found, cols = small.gather_rows(kh)
+    assert found.all() and int(cols["remaining"][0]) == 998
+    assert tc.stats()["cold_created"] == 399 - 64  # the fill's, no more
+
+
+@pytest.mark.parametrize("pipelined", [True, False])
+def test_no_tier_full_table_keeps_its_retries(pipelined):
+    """ISSUE 43 (d): with NO tier bound a wave's erred rows keep every
+    retry they had — a pipelined wave is its launch, the re-dispatch
+    and the re-dispatch's own retry, a blocking one its launch and the
+    retry — and what still errs is answered table_full."""
+    eng = ShardedEngine(make_mesh(n=1), capacity_per_shard=64,
+                        batch_per_shard=64)
+    _fill_table([eng])
+    assert eng.tier is None and eng.occupancy() == 64
+    found, _ = eng.gather_rows(np.array(
+        [hash_key("tier", f"k{k}") for k in range(1, 400)], np.uint64))
+    keys = (np.nonzero(found)[0][:5] + 1).tolist() + [2000, 2001, 2000]
+    now = NOW + 1000
+    b, kh = _packed(keys, [1] * len(keys), now)
+    launches = _count_launches(eng)
+    cols = (_pipelined(eng, b, kh, now, threading.Lock()) if pipelined
+            else eng.check_packed(b, kh, now))
+    assert len(launches) == (3 if pipelined else 2)
+    assert cols[4].tolist() == [False] * 5 + [True] * 3
+    assert [int(v) for v in cols[2][:5]] == [998] * 5
+    for c in cols[:4]:
+        assert not np.asarray(c)[5:].any()
+    assert eng.sweep_wanted
 
 
 def test_fused_engine_overflow_parity():

@@ -1696,42 +1696,6 @@ def _sec_group():
         grp.stop()
 
 
-def _sec_hot():
-    """Hot-set psum tier: replica-local GLOBAL decisions + one psum
-    fold per sync (the north-star replacement for global.go)."""
-    import jax
-
-    from gubernator_tpu.hashing import hash_key
-    from gubernator_tpu.parallel import HotSetEngine, make_mesh
-    from gubernator_tpu.types import RateLimitRequest
-
-    mesh = make_mesh()
-    hot = HotSetEngine(mesh, capacity=1024, batch_per_chip=2048)
-    n = hot.n
-    hreq = RateLimitRequest(name="hot", unique_key="k", hits=1,
-                            limit=10**9, duration=600_000)
-    hkh = hash_key("hot", "k")
-    hot.pin(hreq, hkh, NOW0)
-    wave = [hreq] * (n * 2048)
-    khs = [hkh] * len(wave)
-    hot.check_batch(wave, khs, NOW0)  # compile
-    t0 = time.perf_counter()
-    reps = 10
-    for r in range(reps):
-        hot.check_batch(wave, khs, NOW0 + 1 + r)
-    dps_hot = reps * len(wave) / (time.perf_counter() - t0)
-    hot.sync()
-    jax.block_until_ready(hot.state)
-    t0 = time.perf_counter()
-    for _ in range(20):
-        hot.sync()
-    jax.block_until_ready(hot.state)  # async dispatch: wait for the fold
-    sync_ms = (time.perf_counter() - t0) / 20 * 1e3
-    return {"7_hot_psum": {"decisions_per_s": round(dps_hot),
-                           "sync_ms": round(sync_ms, 3),
-                           "n_replicas": int(n)}}
-
-
 def _sec_cfg5():
     """Config 5: huge multi-tenant table (100M keys → CAP 2^27),
     Gregorian resets + RESET_REMAINING churn.  The TRUE BASELINE.json
@@ -1912,7 +1876,7 @@ def _sec_mesh():
     """Pod-coherent GLOBAL over the mesh (ISSUE 7): the same seeded
     GLOBAL wire traffic served twice — GUBER_GLOBAL_MODE=mesh (the
     collective-reconcile tier, zero gRPC peer RPCs) vs grpc (the
-    reference hit-queue path, hot set off so the sharded table serves)
+    reference hit-queue path: the owner rows of the sharded table serve)
     — with the A/B bit-identity, exact-conservation verdict, reconcile
     generations, and measured coherence staleness recorded in the row."""
     import jax
@@ -1981,8 +1945,7 @@ def _sec_mesh():
         row["hbm"] = _hbm_block(mi)
     finally:
         mi.close()
-    gi = V1Instance(Config(cache_size=1 << 14, sweep_interval_ms=0,
-                           hot_set_capacity=0),
+    gi = V1Instance(Config(cache_size=1 << 14, sweep_interval_ms=0),
                     mesh=make_mesh())
     try:
         dps_grpc, grpc_outs = _drive(gi)
@@ -2056,7 +2019,7 @@ def _sec_tiered():
            "requests": sent + B, "device_cap_rows": 4096}
     ti = V1Instance(Config(cache_size=4096, cache_autogrow_max=4096,
                            tier_cold=True, tier_promote_threshold=4,
-                           hot_set_capacity=0, sweep_interval_ms=0),
+                           sweep_interval_ms=0),
                     mesh=make_mesh())
     try:
         dps_tier, tier_outs = _drive(ti)
@@ -2085,7 +2048,7 @@ def _sec_tiered():
     # read as a tier A/B failure — autogrow keeps the oracle exact
     ocap = 1 << (2 * nkeys - 1).bit_length()
     oi = V1Instance(Config(cache_size=ocap, cache_autogrow_max=ocap * 8,
-                           hot_set_capacity=0, sweep_interval_ms=0),
+                           sweep_interval_ms=0),
                     mesh=make_mesh())
     try:
         dps_oracle, oracle_outs = _drive(oi)
@@ -2349,7 +2312,6 @@ _SECTIONS = {
     "svc": (_sec_svc, ["6_service_path", "8_peer_path"]),
     "cluster": (_sec_cluster, ["9_clustered_service"]),
     "group": (_sec_group, ["10_reuseport_group"]),
-    "hot": (_sec_hot, ["7_hot_psum"]),
     "cfg5": (_sec_cfg5, ["5_gregorian_churn"]),
     "pallas": (_sec_pallas, ["11_pallas_serving"]),
     "mesh": (_sec_mesh, ["12_mesh_global"]),
@@ -2359,9 +2321,8 @@ _SECTIONS = {
 }
 
 #: sections in reporting order (main runs `group` first: see there)
-_SECTION_ORDER = ["cfg12", "cfg4", "svc", "cluster", "group", "hot",
-                  "cfg5", "pallas", "mesh", "tiered", "scenarios",
-                  "fleet"]
+_SECTION_ORDER = ["cfg12", "cfg4", "svc", "cluster", "group", "cfg5",
+                  "pallas", "mesh", "tiered", "scenarios", "fleet"]
 
 #: sections (and inline stages of main) that raised — a non-empty list
 #: makes the process exit nonzero after the rows are printed

@@ -4,8 +4,9 @@ ISSUE 4: after the wave telemetry of ISSUE 1 the serving loop's
 *aggregate* health is visible, but not WHICH keys are hot, which drive
 OVER_LIMIT, or where a request's milliseconds go between ingest, queue,
 device and peer forward.  Hot-key skew is the dominant failure mode of
-distributed limiters (PAPERS.md), and the hot-set promoter
-(parallel/hotset.py) needs exactly this hotness signal.
+distributed limiters (PAPERS.md), and the tiered store's admission
+and the mesh tier's overflow policy (tiering.py, instance.py ›
+_mesh_overflow_victim) rank keys by exactly this hotness signal.
 
 Two pieces, both bounded-memory and OFF the caller's critical path:
 
@@ -326,10 +327,9 @@ class HeavyHitterSketch:
 
     def count_of(self, khash: int) -> int:
         """Tracked count for one key hash (0 when untracked) — the
-        hot-set promoter's feed (ROADMAP: promotion driven by the
-        sketch's signal instead of ad-hoc counting).  An overestimate
-        by at most the key's ``err``, which only makes promotion
-        eager, never starved."""
+        rank the tiered store admits by and the mesh tier picks its
+        overflow victim by.  An overestimate by at most the key's
+        ``err``, which only makes promotion eager, never starved."""
         self._reindex()
         if not self._sorted_kh.size:
             return 0
@@ -1419,8 +1419,8 @@ class KeyAnalytics:
 
     def sketch_count(self, khash: int) -> int:
         """Thread-safe tracked-count read for one key hash (0 when
-        untracked) — the hot-set promotion feed (instance.py ›
-        _count_toward_promotion) and the tiered store's admission rank
+        untracked) — the mesh tier's overflow rank (instance.py ›
+        _mesh_overflow_victim) and the tiered store's admission rank
         (tiering.py)."""
         with self._mu:
             return self.sketch.count_of(khash)

@@ -293,12 +293,6 @@ class Config:
     #: degraded fallback keep the gRPC lanes either way.
     #: GUBER_GLOBAL_MODE overrides.
     global_mode: str = ""
-    #: Replicated hot-set capacity for GLOBAL keys (0 disables the psum
-    #: tier; see parallel/hotset.py).  Active only for pod-local
-    #: deployments (no cross-host peers).
-    hot_set_capacity: int = 1024
-    #: GLOBAL hits on one key before it is promoted to the hot set.
-    hot_promote_threshold: int = 64
     #: Host cold tier behind the device table (ISSUE 10): a key that
     #: misses (or overflows) the HBM-resident table is served EXACTLY
     #: from host memory instead of erroring table_full, and migrates to

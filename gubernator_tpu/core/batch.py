@@ -36,7 +36,7 @@ MAX_INPUT = VALUE_MAX
 def clamp_config(algorithm, limit, duration, burst, behavior=0):
     """Scalar mirror of the packer clamps for (alg, limit, duration, burst).
 
-    Used by the hot-set pin path (parallel/hotset.py) so pinned rows agree
+    Used by the mesh tier's pin path (parallel/meshglobal.py) so pinned rows agree
     bit-for-bit with every packed request carrying the same config — a
     disagreement reads as a config change on the device and resets the
     row.  Must stay in lockstep with pack_requests/pack_columns and the

@@ -53,9 +53,9 @@ from .table import (TableState, Words, match_rows, put_rows, split64,
 #: probes are the same sequence whatever P, so every row a shorter
 #: window placed is still found and snapshots stay valid.
 PROBES = 16
-#: probe window of the 4,096-slot replica maps (parallel/hotset.py,
-#: parallel/meshglobal.py and the mesh lane of the fused program):
-#: their slots are pinned from the host over this window, and it does
+#: probe window of the 4,096-slot replica map (parallel/meshglobal.py
+#: and the mesh lane of the fused program):
+#: its slots are pinned from the host over this window, and it does
 #: not grow with the table's
 REPLICA_PROBES = 8
 INSERT_ROUNDS = 4  # slot-claim rounds per batch

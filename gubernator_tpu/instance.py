@@ -75,8 +75,8 @@ def clock_ms() -> int:
 def _group_key_configs(kh: np.ndarray, idx: np.ndarray, batch,
                        pinned_cfg: dict):
     """Rows ``idx`` of a packed batch grouped by key in ONE pass over
-    the call's rows, whatever the number of distinct keys (the routing
-    pass ``_wire_mesh_runner`` and ``_wire_global_runner`` share).
+    the call's rows, whatever the number of distinct keys
+    (``_wire_mesh_runner``'s routing pass).
 
     Returns ``(keys, unpinned)`` — the key of each row as a Python int,
     in row order, and the distinct keys ``pinned_cfg`` does not hold,
@@ -272,9 +272,9 @@ class V1Instance:
         # Tiered key store (ISSUE 10, tiering.py): host cold tier
         # behind the device table with sketch-rank admission.  The
         # controller binds as engine.tier; check_packed pre-masks and
-        # cold-serves through it.  Victim picks skip mesh-/hot-set-
-        # pinned keys: their device row is a replica-coherence home
-        # copy, and demoting it would fork state.
+        # cold-serves through it.  Victim picks skip mesh-pinned
+        # keys: their device row is a replica-coherence home copy,
+        # and demoting it would fork state.
         self._tier = None
         tier_cold = os.environ.get("GUBER_TIER_COLD")
         if (tier_cold == "1" if tier_cold is not None
@@ -299,8 +299,8 @@ class V1Instance:
                 skip_victim=self._tier_victim_pinned, tap=tap,
                 rank_batch=(analytics.sketch_counts
                             if analytics is not None else None))
-        # every eagerly-built consumer enrolls now; the lazy tiers
-        # (hot set, mesh-GLOBAL) enroll inside their _ensure_* builders
+        # every eagerly-built consumer enrolls now; the lazy mesh-GLOBAL
+        # tier enrolls inside its _ensure_meshglobal builder
         self._enroll_memledger()
         self._peer_tls = peer_tls_creds
         # Datacenter-aware deployments route through a region picker
@@ -354,16 +354,6 @@ class V1Instance:
         self._mesh_fail_streak = 0  # lock-free: tick-thread only
         self._mesh_degraded = False  # lock-free: single racy bool
         self._mesh_down_until = 0.0  # lock-free: single racy float
-        # Replicated hot-set (psum GLOBAL tier, parallel/hotset.py):
-        # lazily built on first promotion; pod-local only.  Unused in
-        # mesh mode (the mesh tier serves ALL qualifying GLOBAL keys —
-        # two replica tiers for one key would double-count).
-        self._hotset = None
-        self._hot_mu = threading.Lock()
-        #: key_hash → weight
-        self._hot_counts: Dict[int, int] = {}  # guarded-by: self._hot_mu
-        self._hot_sync_loop = None
-        self._promote_pending: List[tuple] = []
         # stateful-handover serialization: one pass at a time, and a
         # generation counter so a newer membership change supersedes an
         # in-flight pass (it re-snapshots whatever is left)
@@ -474,9 +464,8 @@ class V1Instance:
             return
         self._fault_point("snapshot")
         with phase("snapshot", self.dispatcher):
-            # hot-set / mesh-tier rows live outside the sharded table;
-            # fold them back in so the snapshot is complete
-            self._demote_all()
+            # mesh-tier rows live outside the sharded table; fold
+            # them back in so the snapshot is complete
             self._mesh_demote_all()
             arrays = self.engine.snapshot()
             if self._tier is not None:
@@ -528,14 +517,12 @@ class V1Instance:
         for departed in old.values():
             threading.Thread(target=departed.shutdown, daemon=True,
                              name="peer-shutdown").start()
-        # The hot-set psum tier is pod-local: once any non-self peer
-        # exists (hot routing turns off), hot keys must go back to
+        # The mesh-GLOBAL tier is pod-local: once any non-self peer
+        # exists (mesh routing turns off), its keys must go back to
         # daemon-level ownership with their consumption intact.
         have_others = any(info.grpc_address != self._self_addr
                           for info in infos)
         if have_others:
-            self._demote_all()
-            # the mesh-GLOBAL tier is pod-local by the same rule
             self._mesh_demote_all()
         # Stateful re-sharding (beyond-reference, opt-in): the
         # reference resets re-homed keys (SURVEY.md §5.3); with the
@@ -896,9 +883,10 @@ class V1Instance:
         packed arrays → one device step → wire bytes, zero per-request
         Python objects) when the batch qualifies: extension built, no
         Store hooks, no metadata, non-empty names/keys.  Solo (no peers
-        beyond self): GLOBAL batches ride a columnar hot-set flow
-        (pinned keys → replica step, the rest → sharded step +
-        vectorized promotion counting).  Clustered: ALL batches ride
+        beyond self): GLOBAL batches ride the mesh tier's columnar flow
+        under ``global_mode=mesh`` (``_wire_mesh_runner``), and are
+        plain owner rows of the sharded step otherwise (the broadcast
+        has no one to go to).  Clustered: ALL batches ride
         the clustered columnar lane — non-GLOBAL rows are ring-split by
         owner (owned keys stepped locally, the rest forwarded as raw
         TLV slices over the peer wire and spliced back in order);
@@ -959,7 +947,8 @@ class V1Instance:
                     # (global_manager.queue_*_raw), so no per-request
                     # objects are needed
                     clustered = True
-                # solo GLOBAL rides the columnar hot-set flow; the
+                # solo GLOBAL rows are served where they live (the
+                # mesh tier or the owner row, _wire_global_runner); the
                 # object path's queue_update is a no-op with no peers
                 # (nothing to broadcast to)
         if parsed is not None:
@@ -990,7 +979,7 @@ class V1Instance:
                 else:
                     mr_mask = _NO_ROWS
                 if is_global:
-                    lane = "wire_hotset"
+                    lane = "wire_global"
                     inner = self._wire_global_runner(parsed, now)
                 else:
                     lane = "wire_local"
@@ -1049,7 +1038,7 @@ class V1Instance:
 
     # ---- fused wire lane (ops/_native.cpp › pack_wire_wave) ------------
 
-    #: behaviors whose async side effects (hot-set routing, GLOBAL
+    #: behaviors whose async side effects (mesh-tier routing, GLOBAL
     #: reconcile queues, cross-region replication) need the parsed
     #: columns — the fused lane hands them to the classic lanes, which
     #: keep those semantics in one place.  The policy lives HERE; the
@@ -1433,130 +1422,22 @@ class V1Instance:
             gm.queue_update_raw(k, tlv)
 
     def _wire_global_runner(self, parsed: dict, now: int):
-        """Columnar solo-GLOBAL flow (the wire-lane twin of
-        ``_hot_route``): pinned keys take the replicated hot-set step,
-        everything else the sharded step, with vectorized promotion
-        counting.  Returns a zero-argument executor, or None when a
-        per-request case needs the object path (a pinned key whose
-        config changed or that received excluded flags — those demote).
+        """Where a solo daemon's GLOBAL call is served: the mesh tier
+        under ``global_mode=mesh`` while it is routable, the owner rows
+        of the sharded step otherwise (the object path's queue_update
+        broadcasts to no one).  Returns a zero-argument executor, or
+        None when the mesh runner hands a per-request case to the
+        object path (a pinned key whose config changed — it demotes).
 
         All gating runs here, before any state mutation, so a None
         return leaves the instance untouched for the fallback.
         """
-        if self._global_mode == "mesh":
-            # mesh backend (ISSUE 7): qualifying rows ride the mesh
-            # tier; degraded/stood-down (or anything the columnar
-            # mesh runner can't model) serves owner-sharded — always
-            # correct, reconciled by the gRPC queues
-            if self._mesh_routable():
-                runner = self._wire_mesh_runner(parsed, now)
-                if runner is not None:
-                    return runner
-                return None  # pinned-key demote case: object path
-            return lambda: self._wire_check_columns(parsed, now)
-        if self.config.hot_set_capacity <= 0:
-            # tier disabled: solo GLOBAL is just the local path (the
-            # object path's queue_update broadcasts to no one)
-            return lambda: self._wire_check_columns(parsed, now)
-        from .core.batch import pack_columns
-        from .hashing import mix64_np
-
-        n = parsed["n"]
-        kh = mix64_np(parsed["khash_raw"])
-        kh = np.where(kh == 0, np.uint64(1), kh)
-        batch, errs = pack_columns(
-            kh, parsed["hits"], parsed["limit"], parsed["duration"],
-            parsed["algorithm"], parsed["behavior"], parsed["burst"], now,
-            created_at=parsed.get("created_at"), sink=self.dispatcher)
-        beh = np.asarray(batch.behavior)
-        glob_mask = (beh & int(Behavior.GLOBAL)) != 0
-        excluded = (beh & int(self._HOT_EXCLUDED)) != 0
-        hs = self._hotset
-        hot_mask = np.zeros(n, bool)
-        if hs is not None and hs.slots:
-            with hs._mu:
-                pinned_keys = np.fromiter(hs.slots.keys(), np.uint64,
-                                          len(hs.slots))
-            pinned_mask = glob_mask & np.isin(kh, pinned_keys)
-            if pinned_mask.any():
-                if (pinned_mask & excluded).any():
-                    return None  # flagged request on a pinned key
-                # config match (duration compares unfloored, exactly
-                # as clamp_config and pack_columns store it); a key
-                # unpinned since the snapshot above takes the object
-                # path too
-                groups = _group_key_configs(
-                    kh, np.nonzero(pinned_mask)[0], batch, hs.pinned_cfg)
-                if groups is None or groups[1]:
-                    return None  # config changed → demote path
-                hot_mask = pinned_mask
-        # promotion counting for unpinned qualifying GLOBAL keys
-        promo_mask = glob_mask & ~hot_mask & ~excluded & \
-            np.asarray(batch.valid)
-
-        def run() -> bytes:
-            status = np.zeros(n, np.int64)
-            rem = np.zeros(n, np.int64)
-            rst = np.zeros(n, np.int64)
-            lim_o = np.zeros(n, np.int64)
-            errors: Optional[list] = None
-            if promo_mask.any():
-                pidx = np.nonzero(promo_mask)[0]
-                w = np.maximum(np.asarray(batch.hits)[pidx], 1)
-                uniq, first, inv = np.unique(
-                    kh[pidx], return_index=True, return_inverse=True)
-                weights = np.bincount(inv, weights=w).astype(np.int64)
-                hits_col = np.asarray(batch.hits)
-                for k, f, wt in zip(uniq, first, weights):
-                    i = int(pidx[f])  # first occurrence in the batch
-                    self._count_toward_promotion(
-                        int(k), int(wt), RateLimitRequest(
-                            name="", unique_key="",
-                            hits=int(hits_col[i]),
-                            limit=int(np.asarray(batch.limit)[i]),
-                            duration=int(np.asarray(batch.duration)[i]),
-                            algorithm=int(np.asarray(
-                                batch.algorithm)[i]),
-                            behavior=int(beh[i]),
-                            burst=int(np.asarray(batch.burst)[i])))
-            shard_mask = ~hot_mask
-            if shard_mask.any():
-                idx = np.nonzero(shard_mask)[0]
-                sub = type(batch)(*[np.asarray(c)[idx] for c in batch])
-                s_st, s_lim, s_rem, s_rst, s_full = \
-                    self.dispatcher.check_packed(sub, kh[idx], now)
-                status[idx] = s_st
-                lim_o[idx] = s_lim
-                rem[idx] = s_rem
-                rst[idx] = s_rst
-                if s_full.any():
-                    errors = [None] * n
-                    for j in np.nonzero(s_full)[0]:
-                        errors[int(idx[j])] = "rate limit table full"
-            if hot_mask.any():
-                idx = np.nonzero(hot_mask)[0]
-                sub = type(batch)(*[np.asarray(c)[idx] for c in batch])
-                h_st, h_rem, h_rst, h_lim, h_lost = hs.check_columns(
-                    sub, kh[idx], now)
-                status[idx] = h_st
-                rem[idx] = h_rem
-                rst[idx] = h_rst
-                lim_o[idx] = h_lim
-                if h_lost.any():
-                    errors = errors or [None] * n
-                    for j in np.nonzero(h_lost)[0]:
-                        errors[int(idx[j])] = "hot-set row lost"
-            if errs:
-                errors = errors or [None] * n
-                for i, emsg in errs.items():
-                    errors[i] = emsg
-            self.metrics.over_limit_counter.inc(int((status == 1).sum()))
-            if self._promote_pending:
-                self._drain_promotions(now)
-            return _wire_native.build_rate_limit_resps(
-                status, lim_o, rem, rst, errors)
-
-        return run
+        if self._mesh_routable():
+            # qualifying rows ride the mesh tier (ISSUE 7); degraded or
+            # stood down, the owner rows below serve — always correct,
+            # reconciled by the gRPC queues
+            return self._wire_mesh_runner(parsed, now)
+        return lambda: self._wire_check_columns(parsed, now)
 
     def _wire_mesh_runner(self, parsed: dict, now: int):
         """Columnar mesh-GLOBAL flow (ISSUE 7; the wire-lane twin of
@@ -1564,8 +1445,8 @@ class V1Instance:
         mesh-resident replica tier — pinned on first touch, in ONE
         batched upload — everything else rides the sharded step.
         Returns a zero-argument executor, or None when a pinned key's
-        config changed (the object path demotes it with state intact,
-        exactly the hot set's fallback contract)."""
+        config changed (the object path demotes it with state
+        intact)."""
         from .core.batch import pack_columns
         from .hashing import mix64_np
 
@@ -1583,7 +1464,7 @@ class V1Instance:
                 sink=self.dispatcher)
             beh = np.asarray(batch.behavior)
             glob_mask = (beh & int(Behavior.GLOBAL)) != 0
-            excluded = (beh & int(self._HOT_EXCLUDED)) != 0
+            excluded = (beh & int(self._REPLICA_EXCLUDED)) != 0
             mesh_mask = glob_mask & ~excluded & np.asarray(batch.valid)
             mge = self._ensure_meshglobal()
         pins: List[tuple] = []
@@ -2136,9 +2017,7 @@ class V1Instance:
         n = len(reqs)
         responses: List[Optional[RateLimitResponse]] = [None] * n
         local_idx: List[int] = []
-        hot: List[tuple[int, int]] = []  # (request idx, key hash)
         meshl: List[tuple[int, int]] = []  # mesh-GLOBAL (idx, key hash)
-        solo = None  # lazily: are we the only daemon (hot tier eligible)?
         fwd: List[tuple[int, PeerClient, RateLimitRequest]] = []
 
         have_peers = bool(self.peers())
@@ -2168,24 +2047,14 @@ class V1Instance:
                 continue
             behavior = int(req.behavior)
             if behavior & GLOBAL:
-                # Pod-local hot keys take the psum tier: replica-local
-                # decision, consumption folded by one collective per
-                # sync tick (parallel/hotset.py) — no queues at all.
-                # "Pod-local" = no peers other than ourselves.
-                if solo is None:
-                    solo = not have_peers or all(
-                        self.is_self(p) for p in self.peers())
-                if solo and self._global_mode == "mesh":
-                    # mesh backend (ISSUE 7): ALL qualifying GLOBAL
-                    # keys ride the mesh-resident replica tier; the
-                    # hot set stays out of the picture (two replica
-                    # tiers for one key would double-count).  A False
-                    # return (excluded flags, window full, degraded
-                    # stand-down) takes the owner-sharded path below.
-                    if self._mesh_routable() and \
-                            self._mesh_route(req, meshl, i, now):
-                        continue
-                elif solo and self._hot_route(req, hot, i):
+                # Pod-local (no peers other than ourselves) under
+                # global_mode=mesh: ALL qualifying GLOBAL keys ride
+                # the mesh-resident replica tier (ISSUE 7,
+                # parallel/meshglobal.py) — no queues at all.  A False
+                # return (excluded flags, window full, degraded
+                # stand-down) takes the owner-sharded path below.
+                if self._mesh_routable() and \
+                        self._mesh_route(req, meshl, i, now):
                     continue
                 # Otherwise: answer from the local replica now, reconcile
                 # hits to the owner asynchronously (global.go semantics).
@@ -2266,18 +2135,6 @@ class V1Instance:
             # values are exact; the fold converges the other replicas)
             self._after_local(m_reqs, m_resps)
 
-        if hot:
-            hot_reqs = [reqs[i] for i, _ in hot]
-            hot_resps = self._hotset.check_batch(
-                hot_reqs, [h for _, h in hot], now)
-            for (i, _), resp in zip(hot, hot_resps):
-                responses[i] = resp
-                if resp.status == Status.OVER_LIMIT:
-                    self.metrics.over_limit_counter.inc()
-            # Store write-through covers hot keys too (replica-local
-            # values; the post-sync merge supersedes them next tick)
-            self._after_local(hot_reqs, hot_resps)
-
         if local_idx:
             local_reqs = [reqs[i] for i in local_idx]
             self._read_through(local_reqs)
@@ -2308,8 +2165,6 @@ class V1Instance:
                     gm.queue_update(req)  # row written by the step above
                 else:
                     gm.queue_hits(self._req_stamped(req, now))
-        if self._promote_pending:
-            self._drain_promotions(now)
 
         timeout = (self.config.behaviors.batch_timeout_ms
                    + self.config.behaviors.batch_wait_ms) / 1000.0 + 30.0
@@ -2380,164 +2235,13 @@ class V1Instance:
         self._maybe_sweep(now)
         return responses  # type: ignore[return-value]
 
-    # ---- hot-set (psum GLOBAL tier) ------------------------------------
-
-    _HOT_EXCLUDED = (Behavior.RESET_REMAINING | Behavior.DRAIN_OVER_LIMIT
-                     | Behavior.DURATION_IS_GREGORIAN | Behavior.MULTI_REGION)
-
-    def _hot_route(self, req: RateLimitRequest, hot, i) -> bool:
-        """Route a GLOBAL request to the replicated hot set if pinned;
-        count toward promotion otherwise.  Returns True when routed."""
-        if self.config.hot_set_capacity <= 0:
-            return False
-        # both algorithms qualify (hotset.py merges each natively); only
-        # per-request flags that mutate config/state stay excluded
-        qualifies = not int(req.behavior) & int(self._HOT_EXCLUDED)
-        kh = hash_key(req.name, req.unique_key)
-        hs = self._hotset
-        if hs is not None and hs.is_pinned(kh):
-            if not qualifies or not hs.matches_pinned(kh, req):
-                # config changed or a flagged request (RESET/DRAIN/…)
-                # arrived: migrate hot state back so the standard path
-                # operates on the live values, not the promotion-time row.
-                # Counted: one flagged request on a hot key silently
-                # forfeits the psum tier for it — operators should see it
-                self.metrics.hot_demotion_counter.labels(
-                    reason="flagged" if not qualifies
-                    else "config_change").inc()
-                self._demote(kh)
-                return False
-            hot.append((i, kh))
-            return True
-        if not qualifies:
-            return False
-        self._count_toward_promotion(kh, max(int(req.hits), 1), req)
-        return False
-
-    def _count_toward_promotion(self, kh: int, weight: int,
-                                req: RateLimitRequest) -> None:
-        """Promotion bookkeeping, keyed by key hash (guarded: concurrent
-        handlers must not double-promote or KeyError on the shared
-        counter dict).  ``req`` carries the (limit, duration, algorithm,
-        burst) the pin will adopt.
-
-        The promotion SIGNAL is the Space-Saving heavy-hitter ledger
-        (``/debug/topkeys``, analytics.py) when analytics is on — the
-        PR-4 ROADMAP hook: the sketch sees every lane's resolved waves
-        (including columnar wire traffic this counter never did), so a
-        key hot through any path promotes.  The decayed ad-hoc counter
-        stays as the floor: the sketch's paced async folds must never
-        STARVE promotion (tap shedding under overload), only feed it."""
-        ana = self.analytics
-        with self._hot_mu:
-            c = self._hot_counts.get(kh, 0) + weight
-            self._hot_counts[kh] = c
-            if ana is not None:
-                # sketch count is an overestimate by ≤ its err bound —
-                # promotion can only get more eager, never starved
-                c = max(c, ana.sketch_count(kh))
-            if c >= self.config.hot_promote_threshold:
-                # promote AFTER this batch's device step so the seed
-                # row includes this request's own hits
-                self._promote_pending.append((req, kh))
-                self._hot_counts.pop(kh, None)
-            elif len(self._hot_counts) > 100_000:
-                # decay inline too: _maybe_sweep may be disabled, and
-                # the counter dict must stay bounded regardless
-                self._decay_counts_locked()
-
-    def _drain_promotions(self, now: int) -> None:
-        """Pin newly-hot keys, seeding from their sharded-table rows so
-        pre-promotion consumption carries over.  ``now`` is the batch's
-        logical time — wall clock would break caller-driven time."""
-        with self._hot_mu:
-            pending, self._promote_pending = self._promote_pending, []
-        for req, kh in pending:
-            hs = self._ensure_hotset()
-            # _seed_row also consults the cold tier: a key can be hot
-            # by sketch rank while its row is still cold-resident
-            if hs.pin(req, kh, now, seed=self._seed_row(kh)):
-                self._seed_commit(kh)
-
-    def _demote(self, key_hash: int) -> None:
-        """Migrate one hot key's merged state back into the sharded
-        table, then release its slot — consumption must survive the
-        transition in both directions."""
-        hs = self._hotset
-        if hs is None:
-            return
-        hs.sync()  # fold all replicas so the row read is authoritative
-        row = hs.row_state(key_hash)
-        if row is not None:
-            cols = {f: np.array([row[f]]) for f in row}
-            with self._engine_mu:
-                placed = self.engine.upsert_rows(
-                    np.array([key_hash], np.uint64), cols)
-                if not placed and self._tier is not None:
-                    self._tier.put_row(key_hash,
-                                       {f: int(row[f]) for f in row})
-        hs.unpin(key_hash)
-
-    def _demote_all(self) -> None:
-        """Demote every hot key: ONE sync collective, one batched
-        writeback (peer-join/shutdown latency must not scale with K
-        collectives)."""
-        hs = self._hotset
-        if hs is None:
-            return
-        khs = list(hs.slots.keys())
-        if not khs:
-            return
-        self.metrics.hot_demotion_counter.labels(
-            reason="membership_change").inc(len(khs))
-        hs.sync()
-        rows = [(kh, hs.row_state(kh)) for kh in khs]
-        rows = [(kh, r) for kh, r in rows if r is not None]
-        if rows:
-            karr = np.array([kh for kh, _ in rows], np.uint64)
-            cols = {f: np.array([r[f] for _, r in rows])
-                    for f in rows[0][1]}
-            with self._engine_mu:
-                placed = self.engine.upsert_rows(karr, cols)
-                if placed < len(rows) and self._tier is not None:
-                    found, _ = self.engine.gather_rows(karr)
-                    for j, (kh, r) in enumerate(rows):
-                        if not found[j]:
-                            self._tier.put_row(
-                                kh, {f: int(r[f]) for f in r})
-        for kh in khs:
-            hs.unpin(kh)
-
-    # lock-free: caller holds self._hot_mu (the *_locked suffix contract)
-    def _decay_counts_locked(self) -> None:
-        """Halve promotion counters, drop zeros.  Caller holds _hot_mu."""
-        self._hot_counts = {k: v // 2
-                            for k, v in self._hot_counts.items()
-                            if v // 2 > 0}
-
-    def _hot_decay(self) -> None:
-        """Counter decay on the sweep tick: bounds _hot_counts memory
-        and ages out cold keys."""
-        with self._hot_mu:
-            self._decay_counts_locked()
-
-    def _ensure_hotset(self):
-        with self._gm_mu:
-            if self._hotset is None:
-                from .interval import IntervalLoop
-                from .parallel.hotset import HotSetEngine
-
-                cap = 1 << (self.config.hot_set_capacity - 1).bit_length()
-                self._hotset = HotSetEngine(self.engine.mesh, capacity=cap)
-                self._hot_sync_loop = IntervalLoop(
-                    self.config.behaviors.global_sync_wait_ms,
-                    self._hotset.sync, name="hotset-psum-sync")
-                if self.memledger is not None:
-                    self.memledger.enroll("hotset", self._probe_hotset,
-                                          advisable=True)
-            return self._hotset
-
     # ---- mesh-resident GLOBAL (ISSUE 7, parallel/meshglobal.py) --------
+
+    #: per-request flags that mutate config/state: such a row is never
+    #: served from a replica tier, and demotes a pinned key
+    _REPLICA_EXCLUDED = (Behavior.RESET_REMAINING | Behavior.DRAIN_OVER_LIMIT
+                         | Behavior.DURATION_IS_GREGORIAN
+                         | Behavior.MULTI_REGION)
 
     def _mesh_mode(self) -> bool:
         """True when the mesh reconcile backend is selected AND the
@@ -2546,8 +2250,8 @@ class V1Instance:
                 and getattr(self.engine, "mesh", None) is not None)
 
     def _mesh_routable(self) -> bool:
-        """Mesh routing is pod-local (the hot set's rule: no non-self
-        peers) and stands down while the fold is degraded — then the
+        """Mesh routing is pod-local (no non-self peers) and stands
+        down while the fold is degraded — then the
         owner-sharded path + gRPC queues serve, which is always
         correct, just slower to cohere."""
         if not self._mesh_mode() or self._mesh_degraded:
@@ -2624,7 +2328,7 @@ class V1Instance:
         first touch (seeded from the sharded row), demote on config
         change or excluded flags.  Returns True when routed; False
         sends the request down the standard (owner-sharded) path."""
-        qualifies = not int(req.behavior) & int(self._HOT_EXCLUDED)
+        qualifies = not int(req.behavior) & int(self._REPLICA_EXCLUDED)
         kh = hash_key(req.name, req.unique_key)
         mge = self._ensure_meshglobal()
         if mge.is_pinned(kh):
@@ -2868,8 +2572,6 @@ class V1Instance:
             self.engine.sweep_wanted = False
             self.engine.sweep(now)
         self.metrics.sweeps.labels(cause=cause).inc()
-        if cause == "tick":
-            self._hot_decay()
 
     # ---- peer service (owner side) -------------------------------------
 
@@ -3199,8 +2901,6 @@ class V1Instance:
         reference exposes the same through its Cache.Remove + Store).
         Returns True when a row existed."""
         kh = hash_key(name, unique_key)
-        if self._hotset is not None and self._hotset.is_pinned(kh):
-            self._demote(kh)
         if self._meshglobal is not None and self._meshglobal.is_pinned(kh):
             self._mesh_demote(kh)
         with self._engine_mu:
@@ -3213,12 +2913,9 @@ class V1Instance:
         return n > 0
 
     def _tier_victim_pinned(self, kh: int) -> bool:
-        """Tier-eviction victim filter: a replica-pinned key's device
-        row is the HOME copy of hot-set/mesh coherence — demoting it
+        """Tier-eviction victim filter: a mesh-pinned key's device
+        row is the HOME copy of the tier's coherence — demoting it
         to the cold tier while the pin serves would fork its state."""
-        hs = self._hotset
-        if hs is not None and hs.is_pinned(kh):
-            return True
         mge = self._meshglobal
         return mge is not None and mge.is_pinned(kh)
 
@@ -3315,22 +3012,6 @@ class V1Instance:
                            "demote_rate": st["demotions"],
                            "rate": st["cold_served"]}}
 
-    def _probe_hotset(self) -> dict:
-        import jax
-
-        hs = self._hotset
-        if hs is None:
-            return {"bytes": 0}
-        with hs._state_mu:
-            nbytes = self._leaves_nbytes(
-                jax.tree.leaves(hs.state) + [hs.base_rem, hs.base_t])
-        with hs._mu:
-            occ = len(hs.slots)
-        with self._hot_mu:
-            rate = float(sum(self._hot_counts.values()))
-        return {"bytes": nbytes, "capacity_rows": int(hs.capacity),
-                "occupied_rows": occ, "demand": {"hit_rate": rate}}
-
     def _probe_meshglobal(self) -> dict:
         import jax
 
@@ -3364,8 +3045,6 @@ class V1Instance:
             self.global_manager.close()
         if self.mr_manager is not None:
             self.mr_manager.close()
-        if self._hot_sync_loop is not None:
-            self._hot_sync_loop.close()
         if self._probe_loop is not None:
             self._probe_loop.close()
         self.dispatcher.close()

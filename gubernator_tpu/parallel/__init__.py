@@ -8,4 +8,3 @@ pod there are no "peers", just mesh axes.
 """
 from .mesh import make_mesh, shard_table, table_sharding  # noqa: F401
 from .sharded import ShardedEngine, make_sharded_step  # noqa: F401
-from .hotset import HotSetEngine  # noqa: F401

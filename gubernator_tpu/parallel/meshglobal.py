@@ -6,8 +6,7 @@ over the counter tensor so a TPU pod acts as a single coherent
 rate-limit region without gRPC peer fan-out".
 
 Layout: every shard holds a full replica of a bounded GLOBAL counter
-table ([n, C] with leading device axis, like the hot set), and — new
-here — a pair of per-shard **hit accumulators** living on device right
+table ([n, C] with leading device axis), and a pair of per-shard **hit accumulators** living on device right
 next to it.  Requests route to their key's HOME shard (the same
 hash-range ownership `hashing.shard_of` gives the sharded table), so
 the home replica sees every hit and its row is always EXACT — decisions
@@ -36,8 +35,8 @@ next tick (serving waves order after it device-side through the state
 threading).  Staleness is therefore bounded by the reconcile interval
 and measured per fold (`gubernator_mesh_global_staleness_seconds`).
 
-Scope mirrors the hot set's: TOKEN/LEAKY keys without
-RESET/DRAIN/Gregorian flags; everything else (and every key once the
+Scope: TOKEN/LEAKY keys without RESET/DRAIN/Gregorian flags
+(instance.py › _REPLICA_EXCLUDED); everything else (and every key once the
 tier stands down — see V1Instance's degraded fallback) takes the
 owner-sharded path, which is coherent by construction.  Cross-pod /
 multi-region traffic keeps the gRPC lanes (`global_manager.py`).
@@ -77,7 +76,9 @@ def _rep(mesh):
 
 def _cfg_of(req: RateLimitRequest) -> tuple:
     """(alg, limit, duration, burst) exactly as pack_requests clamps
-    them (the hot set's pinned-config contract, same reason)."""
+    them: a pinned row must agree bit-for-bit with every packed
+    request of the same config, or the device reads a config change
+    and resets the row."""
     return clamp_config(req.algorithm, req.limit, req.duration,
                         req.burst, req.behavior)
 
@@ -158,8 +159,8 @@ def make_mesh_global_fold(mesh):
 class MeshGlobalEngine:
     """Host manager of the mesh-resident GLOBAL tier.
 
-    Pins keys to fixed probe-path slots (deterministic across replicas,
-    exactly the hot set's discipline), routes each request to its HOME
+    Pins keys to fixed probe-path slots (deterministic across
+    replicas), routes each request to its HOME
     shard's sub-batch, and runs the reconcile collective on the
     GlobalSyncWait tick (driven by GlobalManager's mesh backend).
     """
@@ -171,14 +172,14 @@ class MeshGlobalEngine:
         self.capacity = capacity
         self.B = batch_per_chip
         #: serializes pin/unpin mutations of the slot maps (reads of
-        #: the dicts are GIL-atomic snapshots, the hot set's contract)
+        #: the dicts are GIL-atomic snapshots)
         self._mu = threading.Lock()
         self.slots: Dict[int, int] = {}
         #: key_hash → (alg, limit, duration, burst)
         self.pinned_cfg: Dict[int, tuple] = {}
-        #: demoted keys keep their slot + device row (the hot set's
-        #: retire rule: clearing the key would let an in-flight request
-        #: insert a phantom fresh bucket)
+        #: demoted keys keep their slot + device row (clearing the key
+        #: would let an in-flight request insert a phantom fresh
+        #: bucket)
         self._retired: Dict[int, int] = {}
         self._occupied: set = set()
         #: serializes every state/accumulator read-modify-write
@@ -215,7 +216,7 @@ class MeshGlobalEngine:
         — the cost model's (bytes, ndev) feature for global_fold."""
         return (len(_VALUE_COLS) + 1) * self.capacity * 8
 
-    # ---- host slot management (hot-set discipline) ---------------------
+    # ---- host slot management ------------------------------------------
 
     def _probe_slots_host(self, key_hash: int) -> List[int]:
         k = np.uint64(key_hash)
@@ -288,8 +289,8 @@ class MeshGlobalEngine:
     @staticmethod
     def _fresh_row(req: RateLimitRequest, key_hash: int, now_ms: int,
                    seed: Optional[dict]) -> dict:
-        """Initial replica row — the packer-exact eff/burst math the
-        hot set's pin uses (core/batch.py clamps)."""
+        """Initial replica row — the packer-exact eff/burst math
+        (core/batch.py clamps)."""
         alg, limit, dur, burst = _cfg_of(req)
         eff = max(int(dur), 1)
         if alg:

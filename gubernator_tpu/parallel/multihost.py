@@ -3,7 +3,7 @@
 SURVEY.md §5.8 — the reference's distributed comm backend is gRPC
 between daemons.  Here the traffic classes map to:
 
-- intra-pod: ICI collectives under shard_map (sharded.py / hotset.py),
+- intra-pod: ICI collectives under shard_map (sharded.py / meshglobal.py),
 - multi-pod / multi-region: daemon-level peering over the reference
   wire protocol (peer_client.py, global_manager.py, multiregion.py),
 - multi-HOST pods (one logical engine spanning hosts, e.g. a v5e-256):

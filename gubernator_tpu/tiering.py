@@ -409,7 +409,7 @@ class TierController:
 
     def pop_row(self, kh: int):
         """Remove + return the key's cold row ({col: int} or None) —
-        the mesh/hot-set pin seed path: the replica tier takes
+        the mesh tier's pin seed path: the replica tier takes
         ownership, so the cold copy must not linger (a stale shadow
         would resurface after the pin retires)."""
         with self._mu:
@@ -419,7 +419,7 @@ class TierController:
         return dict(zip(ROW_COLS, row))
 
     def put_row(self, kh: int, cols: dict) -> None:
-        """Adopt one row (mesh demote / hot-set demote overflow: the
+        """Adopt one row (mesh demote overflow: the
         device table had no slot — before the tier this row was silently
         dropped)."""
         with self._mu:

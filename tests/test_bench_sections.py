@@ -230,7 +230,7 @@ def test_section_registry_covers_baseline_rows():
     assert len(declared) == len(set(declared)), "duplicate row keys"
     for row in ["1_single_key_smoke", "2_leaky_1k_keys",
                 "4_global_sharded", "5_gregorian_churn",
-                "6_service_path", "7_hot_psum", "8_peer_path",
+                "6_service_path", "8_peer_path",
                 "9_clustered_service", "10_reuseport_group",
                 "11_pallas_serving", "12_mesh_global",
                 "13_tiered_store", "15_scenarios", "16_fleet"]:

@@ -6,7 +6,7 @@ from gubernator_tpu.hashing import hash_keys
 from gubernator_tpu.instance import V1Instance
 from gubernator_tpu.parallel import ShardedEngine, make_mesh
 from gubernator_tpu.store import MockStore
-from gubernator_tpu.types import RateLimitRequest
+from gubernator_tpu.types import Behavior, RateLimitRequest
 
 NOW = 1_769_500_000_000
 
@@ -51,5 +51,25 @@ def test_instance_remove_including_hot_and_store():
         assert inst.remove("cache", "gone") is False
         r = inst.get_rate_limits([req("gone", hits=0)], now_ms=NOW + 1)[0]
         assert r.remaining == 9  # fresh after removal
+    finally:
+        inst.close()
+
+
+def test_instance_remove_of_a_much_hit_global_key():
+    """A GLOBAL key hit 120 times on a solo daemon is one row of the
+    sharded table: ``remove`` deletes it there, and the next request
+    opens a fresh bucket."""
+    inst = V1Instance(Config(cache_size=1 << 10, sweep_interval_ms=0),
+                      mesh=make_mesh(n=4))
+    try:
+        g = dict(limit=500, behavior=Behavior.GLOBAL)
+        for t in range(3):
+            out = inst.get_rate_limits([req("much", **g)] * 40,
+                                       now_ms=NOW + t)
+        assert out[-1].remaining == 500 - 120
+        assert inst.remove("cache", "much") is True
+        assert inst.remove("cache", "much") is False
+        r = inst.get_rate_limits([req("much", **g)], now_ms=NOW + 3)[0]
+        assert r.remaining == 499  # fresh after removal
     finally:
         inst.close()

@@ -17,10 +17,11 @@ def test_embedded_engine_example(capsys):
     assert "decisions in" in out
 
 
-def test_global_hotset_example():
-    import runpy
-
-    runpy.run_path("examples/global_hotset.py", run_name="__main__")
+def test_global_mesh_example(capsys):
+    runpy.run_path("examples/global_mesh.py", run_name="__main__")
+    out = capsys.readouterr().out
+    assert "mesh keys pinned: 1" in out
+    assert "4032 of 4032 hits folded" in out
 
 
 def test_pallas_serving_example(capsys, monkeypatch):

@@ -3,8 +3,8 @@
 The ledger's claim is strong — accounted bytes equal the live jax-array
 ``nbytes`` at any instant, on every engine configuration — so the audit
 independently walks the instance's device-resident state (engine table
-leaves, mesh-GLOBAL replica + both hit accumulators, hot-set replica +
-base buffers) and compares against ``memledger.snapshot()`` totals.
+leaves, mesh-GLOBAL replica + both hit accumulators) and compares
+against ``memledger.snapshot()`` totals.
 Covered configs: classic sharded, fused XLA serving, mesh-GLOBAL bound,
 and the tiered store (whose cold tier must land on the HOST ledger, not
 the device one).  Enrollment is leak-free across engine stand-down, and
@@ -44,12 +44,6 @@ def _expected_device_bytes(inst) -> int:
             total += sum(int(a.nbytes)
                          for a in jax.tree.leaves(mge.state))
             total += sum(int(a.nbytes) for a in mge._acc)
-    hs = inst._hotset
-    if hs is not None:
-        with hs._state_mu:
-            total += sum(int(a.nbytes)
-                         for a in jax.tree.leaves(hs.state))
-            total += int(hs.base_rem.nbytes) + int(hs.base_t.nbytes)
     return total
 
 
@@ -116,7 +110,7 @@ def test_exact_tiered_and_snapshot_restore_roundtrip():
     def _cfg():
         return Config(cache_size=1024, cache_autogrow_max=1024,
                       tier_cold=True, tier_promote_threshold=2,
-                      hot_set_capacity=0, sweep_interval_ms=0,
+                      sweep_interval_ms=0,
                       loader=loader)
 
     inst = V1Instance(_cfg(), mesh=make_mesh(n=1))

@@ -109,6 +109,45 @@ def test_conservation_convergence_staleness(monkeypatch):
         inst.close()
 
 
+def test_leaky_conservation_and_convergence(monkeypatch):
+    """The same through an instance for LEAKY_BUCKET keys, on both
+    lanes: every hit is folded, every replica of every key agrees after
+    the fold, and the home row is the oracle's."""
+    from gubernator_tpu.oracle import Oracle
+    from gubernator_tpu.types import Algorithm
+
+    inst = mesh_instance(monkeypatch)
+    try:
+        leaky = dict(limit=1000, burst=1500,
+                     algorithm=Algorithm.LEAKY_BUCKET)
+        oracle = Oracle()
+        for w in range(4):
+            reqs = [greq(f"lk{i % 5}", hits=2, **leaky) for i in range(20)]
+            now = NOW + 1 + 7000 * w  # 7 s apart: the bucket leaks
+            got = inst.get_rate_limits(reqs, now_ms=now) if w % 2 \
+                else list(pb.GetRateLimitsResp.FromString(
+                    inst.get_rate_limits_wire(ser(reqs),
+                                              now_ms=now)).responses)
+            for r, g, o in zip(reqs, got, oracle.check_batch(reqs, now)):
+                assert (g.error, int(g.status), g.remaining,
+                        g.reset_time) == ("", int(o.status), o.remaining,
+                                          o.reset_time), (w, r.unique_key)
+        inst._mesh_reconcile_tick()
+        mge = inst._meshglobal
+        s = mge.stats()
+        assert s["pinned_keys"] == 5
+        assert s["folded_hits"] == s["injected_hits"] == 4 * 20 * 2, s
+        remaining = to_host(mge.state)["remaining"]
+        for kh, slot in mge.slots.items():
+            col = remaining[:, slot]
+            assert len(set(col.tolist())) == 1, (kh, col)
+            assert mge.row_state(kh)["meta"] & 1  # a leaky row
+        gm = inst.global_manager
+        assert gm is not None and not gm._hits and not gm._hits_raw
+    finally:
+        inst.close()
+
+
 def test_fold_on_a_one_device_mesh_conserves(monkeypatch):
     """One chip is a 1-device mesh — the shape no CI mesh ever had.
     The fold's collectives must stay real there (an elided psum fails
@@ -135,7 +174,7 @@ def test_fold_on_a_one_device_mesh_conserves(monkeypatch):
 
 def test_bit_identical_vs_grpc_path(monkeypatch):
     """Same seeded traffic through mesh mode and through the gRPC-mode
-    solo path (hot set off → owner-sharded GLOBAL): response bytes
+    solo path (owner-sharded GLOBAL): response bytes
     must match bit for bit — home-shard routing makes the mesh
     replica's decisions exactly the owner-sharded decisions."""
     mi = mesh_instance(monkeypatch)
@@ -145,7 +184,7 @@ def test_bit_identical_vs_grpc_path(monkeypatch):
     finally:
         mi.close()
     gi = V1Instance(Config(cache_size=1 << 12, sweep_interval_ms=0,
-                           hot_set_capacity=0, batch_rows=64),
+                           batch_rows=64),
                     mesh=make_mesh(n=8))
     try:
         grpc_outs = seeded_traffic(gi)
@@ -231,7 +270,7 @@ def test_degraded_fallback_and_recovery(monkeypatch):
 
 def test_config_change_demotes_with_state(monkeypatch):
     """A limit change on a mesh-pinned key demotes it (state intact)
-    and the new config applies — the hot set's contract, kept."""
+    and the new config applies."""
     inst = mesh_instance(monkeypatch)
     try:
         inst.get_rate_limits([greq("cfg", hits=11, limit=100)],
@@ -251,7 +290,7 @@ def test_config_change_demotes_with_state(monkeypatch):
 
 def test_flagged_requests_bypass_mesh(monkeypatch):
     """RESET/DRAIN/Gregorian/MULTI_REGION-flagged GLOBAL rows never
-    enter the mesh tier (the hot set's exclusion rule)."""
+    enter the mesh tier (instance.py › _REPLICA_EXCLUDED)."""
     inst = mesh_instance(monkeypatch)
     try:
         r = inst.get_rate_limits(
@@ -265,14 +304,19 @@ def test_flagged_requests_bypass_mesh(monkeypatch):
 
 
 def test_grpc_mode_untouched_by_default(monkeypatch):
-    """The default mode stays grpc: no mesh tier is ever built, and
-    the hot set keeps its job."""
+    """The default mode stays grpc: no replica tier of any kind is ever
+    built — a much-hit GLOBAL key is a row of the sharded table."""
     inst = V1Instance(Config(cache_size=1 << 10, sweep_interval_ms=0),
                       mesh=make_mesh(n=4))
     try:
         assert inst._global_mode == "grpc"
-        inst.get_rate_limits([greq("g0")], now_ms=NOW)
-        assert inst._meshglobal is None
+        for t in range(3):
+            inst.get_rate_limits([greq("g0")] * 40, now_ms=NOW + t)
+        assert inst._meshglobal is None and not inst._mesh_mode()
+        assert "mesh_global" not in inst.memledger.consumers()
+        found, cols = inst.engine.gather_rows(
+            np.array([hash_key("mg", "g0")], np.uint64))
+        assert found[0] and int(cols["remaining"][0]) == 100_000 - 120
     finally:
         inst.close()
 
@@ -281,35 +325,6 @@ def test_unknown_global_mode_is_loud():
     with pytest.raises(ValueError, match="global_mode"):
         V1Instance(Config(cache_size=1 << 10, global_mode="typo"),
                    mesh=make_mesh(n=1))
-
-
-def test_sketch_feeds_hotset_promotion(monkeypatch):
-    """ISSUE 7 satellite (the PR-4 ROADMAP hook): the Space-Saving
-    heavy-hitter ledger drives hot-set promotion.  A key made hot by
-    NON-GLOBAL traffic (which never touched the ad-hoc promotion
-    counter) promotes on its FIRST GLOBAL request, because the sketch
-    already counts it past the threshold."""
-    inst = V1Instance(
-        Config(cache_size=1 << 10, sweep_interval_ms=0,
-               hot_set_capacity=64, hot_promote_threshold=8,
-               behaviors=BehaviorConfig(global_sync_wait_ms=25)),
-        mesh=make_mesh(n=4))
-    try:
-        ana = inst.analytics
-        if ana is None:
-            pytest.skip("analytics disabled")
-        plain = RateLimitRequest(name="mg", unique_key="skp", hits=1,
-                                 limit=100_000, duration=600_000)
-        for i in range(10):
-            inst.get_rate_limits([plain], now_ms=NOW + i)
-        assert ana.flush(), "analytics flush timed out"
-        kh = hash_key("mg", "skp")
-        assert ana.sketch_count(kh) >= 10
-        assert inst._hot_counts.get(kh, 0) == 0  # ad-hoc never saw it
-        inst.get_rate_limits([greq("skp")], now_ms=NOW + 20)
-        assert inst._hotset is not None and inst._hotset.is_pinned(kh)
-    finally:
-        inst.close()
 
 
 # ---- ISSUE 25: GLOBAL routing by the call, not by the distinct key -----
@@ -430,10 +445,10 @@ def test_wire_lane_byte_equal_to_object_path(big_pair, shape, state):
         assert wi.get_rate_limits_wire(data, now_ms=now) == \
             oi.get_rate_limits_wire(data, now_ms=now)
         now += 1
-    n_wire, n_pb2 = lane(wi, "wire_hotset"), lane(wi, "pb2_fallback")
+    n_wire, n_pb2 = lane(wi, "wire_global"), lane(wi, "pb2_fallback")
     got = wi.get_rate_limits_wire(data, now_ms=now)
     assert got == obj_bytes(oi, reqs, now)
-    assert lane(wi, "wire_hotset") - n_wire == ROWS
+    assert lane(wi, "wire_global") - n_wire == ROWS
     assert lane(wi, "pb2_fallback") == n_pb2
     rs = pb.GetRateLimitsResp.FromString(got).responses
     assert len(rs) == ROWS and all(r.error == "" for r in rs)
@@ -628,12 +643,12 @@ def test_routing_work_does_not_grow_with_distinct_keys(big_pair,
          behavior=Behavior.GLOBAL | Behavior.DURATION_IS_GREGORIAN),
 ], ids=lambda c: "-".join(f"{k[:3]}{int(v)}" for k, v in c.items()))
 def test_packed_columns_equal_cfg_of(cfg):
-    """What lets the runners compare a batch's columns with the tiers'
+    """What lets the runner compare a batch's columns with the tier's
     pinned_cfg tuples as they are: pack_columns clamps (alg, limit,
     duration, burst) exactly as clamp_config does — token and leaky,
     at the bounds, and under DURATION_IS_GREGORIAN (whose rows never
-    reach the tiers; the clamps agree there all the same)."""
-    from gubernator_tpu.parallel import hotset, meshglobal
+    reach the tier; the clamps agree there all the same)."""
+    from gubernator_tpu.parallel import meshglobal
 
     req = greq("x", **cfg)
     col = lambda v, t=np.int64: np.array([v], t)  # noqa: E731
@@ -644,7 +659,7 @@ def test_packed_columns_equal_cfg_of(cfg):
     assert not errs
     got = tuple(int(np.asarray(c)[0]) for c in (
         batch.algorithm, batch.limit, batch.duration, batch.burst))
-    assert got == meshglobal._cfg_of(req) == hotset._cfg_of(req)
+    assert got == meshglobal._cfg_of(req)
 
 
 @pytest.mark.parametrize("limit", [10_000, (1 << 32) + 50, (1 << 45) + 7])

@@ -30,7 +30,7 @@ from jax.sharding import PartitionSpec as P
 
 mesh = multihost.global_mesh()
 
-# per-"chip" hot-set style consumption fold across the process boundary
+# per-"chip" consumption fold across the process boundary
 def fold(d):
     return lax.psum(d, "shard")
 

@@ -277,7 +277,7 @@ class TestMeshFusedScatter:
         identical seeded GLOBAL traffic."""
         mi = self.mesh_inst(monkeypatch)
         gi = V1Instance(Config(cache_size=1 << 12, sweep_interval_ms=0,
-                               hot_set_capacity=0, batch_rows=64),
+                               batch_rows=64),
                         mesh=make_mesh(n=8))
         try:
             datas = [ser([self.g(f"k{i % 5}") for i in range(20)])

@@ -233,13 +233,12 @@ def xla_instance(monkeypatch):
 
 def test_the_table_window_is_longer_than_the_replica_maps(xla_instance):
     """8 probes lose a key in one 10M key set in four (core/step.py);
-    the 4,096-slot replica maps keep their 8 (their slots are pinned
+    the 4,096-slot replica map keeps its 8 (its slots are pinned
     from the host, and cell 4's routing walks them)."""
-    from gubernator_tpu.parallel import hotset, meshglobal
+    from gubernator_tpu.parallel import meshglobal
 
     assert PROBES >= 16 and REPLICA_PROBES == 8
-    for mod in (hotset, meshglobal):
-        assert mod.REPLICA_PROBES == 8
+    assert meshglobal.REPLICA_PROBES == 8
 
 
 def test_a_key_whose_first_8_slots_are_taken_is_served_as_the_oracle_serves_it(

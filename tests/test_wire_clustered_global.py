@@ -63,8 +63,6 @@ class TestClusteredGlobalWireLane:
         c = cluster_mod.start(3, behaviors=BehaviorConfig(
             global_sync_wait_ms=40, global_broadcast_interval_ms=40,
             global_timeout_ms=5000),
-            # promotion thresholds irrelevant here: clustered daemons
-            # never use the solo hot tier
             cache_size=1 << 12)
         yield c
         c.stop()

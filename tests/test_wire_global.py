@@ -1,14 +1,20 @@
-"""Columnar solo-GLOBAL wire lane: the hot-set psum tier driven from
-wire bytes (instance._wire_global_runner), vs the object path."""
+"""Solo GLOBAL in the default ``grpc`` mode: a daemon without peers
+serves a GLOBAL row from its OWNER row in the sharded table — no replica
+tier, nothing to reconcile — on the wire lane
+(instance._wire_global_runner → _wire_check_columns) and on the object
+path alike, and every answer is ``oracle.py``'s."""
+import numpy as np
 import pytest
 
 from gubernator_tpu.config import BehaviorConfig, Config
 from gubernator_tpu.hashing import hash_key
 from gubernator_tpu.instance import V1Instance, _wire_native
+from gubernator_tpu.oracle import Oracle
 from gubernator_tpu.parallel import make_mesh
 from gubernator_tpu.proto import gubernator_pb2 as pb
-from gubernator_tpu.types import Behavior, RateLimitRequest
-from gubernator_tpu.wire import req_to_pb
+from gubernator_tpu.types import (Algorithm, Behavior, PeerInfo,
+                                  RateLimitRequest)
+from gubernator_tpu.wire import req_to_pb, resp_to_pb
 
 if _wire_native is None:  # pragma: no cover
     pytest.skip("native extension not built", allow_module_level=True)
@@ -16,22 +22,23 @@ if _wire_native is None:  # pragma: no cover
 NOW = 1_773_000_000_000
 
 
-def mk_instance(threshold=4):
-    # sync_wait effectively infinite: these tests assert exact
-    # replica-local values, so the periodic psum fold must only run
-    # when called explicitly (a tick mid-test legally changes
-    # remaining — GLOBAL is eventually consistent)
+def mk_instance(n=4, cache_size=1 << 10, **kw):
+    # the sync tick held off: whatever a window could over-admit, it
+    # would over-admit here
     return V1Instance(
-        Config(cache_size=1 << 10, sweep_interval_ms=0,
-               hot_set_capacity=64, hot_promote_threshold=threshold,
-               behaviors=BehaviorConfig(global_sync_wait_ms=10**9)),
-        mesh=make_mesh(n=4))
+        Config(cache_size=cache_size, sweep_interval_ms=0,
+               behaviors=BehaviorConfig(global_sync_wait_ms=10**9), **kw),
+        mesh=make_mesh(n=n))
 
 
 def greq(key="wg", hits=1, limit=1000, duration=600_000, **kw):
     kw.setdefault("behavior", Behavior.GLOBAL)
     return RateLimitRequest(name="wgl", unique_key=key, hits=hits,
                             limit=limit, duration=duration, **kw)
+
+
+def no_replica_tier(inst):
+    return inst._meshglobal is None and not inst._mesh_mode()
 
 
 def wire(reqs):
@@ -43,26 +50,6 @@ def wire(reqs):
 def send(inst, reqs, now):
     return list(pb.GetRateLimitsResp.FromString(
         inst.get_rate_limits_wire(wire(reqs), now_ms=now)).responses)
-
-
-def test_wire_global_promotes_then_serves_hot():
-    inst = mk_instance(threshold=4)
-    try:
-        kh = hash_key("wgl", "wg")
-        rs = send(inst, [greq() for _ in range(6)], NOW)
-        assert all(r.error == "" and int(r.status) == 0 for r in rs)
-        # threshold crossed inside the batch → pinned after the drain
-        assert inst._hotset is not None and inst._hotset.is_pinned(kh)
-        # hot serving: replicas answer; one sync folds consumption
-        rs = send(inst, [greq() for _ in range(40)], NOW + 1)
-        assert all(r.error == "" and int(r.status) == 0 for r in rs)
-        inst._hotset.sync()
-        rs = send(inst, [greq(hits=0)] * 4, NOW + 2)
-        assert len({r.remaining for r in rs}) == 1
-        # 6 pre-promotion hits survive in the seed + 40 hot hits
-        assert rs[0].remaining == 1000 - 46
-    finally:
-        inst.close()
 
 
 def test_wire_vs_object_path_parity():
@@ -80,50 +67,14 @@ def test_wire_vs_object_path_parity():
                         w.limit, w.error) == \
                     (int(o.status), o.remaining, o.reset_time, o.limit,
                      o.error), (t, i)
-        assert wi._hotset is not None and len(wi._hotset.slots) == 3
-        assert len(oi._hotset.slots) == 3
+        assert no_replica_tier(wi) and no_replica_tier(oi)
     finally:
         wi.close()
         oi.close()
 
 
-def test_wire_global_config_change_demotes():
-    inst = mk_instance(threshold=1)
-    try:
-        kh = hash_key("wgl", "cfg")
-        send(inst, [greq(key="cfg", limit=100)], NOW)
-        send(inst, [greq(key="cfg", limit=100) for _ in range(10)],
-             NOW + 1)
-        assert inst._hotset.is_pinned(kh)
-        # changed limit → object-path fallback demotes and re-limits
-        r = send(inst, [greq(key="cfg", limit=50)], NOW + 2)[0]
-        assert not inst._hotset.is_pinned(kh)
-        assert r.limit == 50
-        # 11 consumed at limit 100 → 89; 100→50 adjust → 39; −1 → 38
-        assert r.remaining == 38
-    finally:
-        inst.close()
-
-
-def test_wire_global_flagged_pinned_key_falls_back():
-    inst = mk_instance(threshold=1)
-    try:
-        kh = hash_key("wgl", "flg")
-        send(inst, [greq(key="flg")], NOW)
-        send(inst, [greq(key="flg")], NOW + 1)
-        assert inst._hotset.is_pinned(kh)
-        r = send(inst, [greq(
-            key="flg",
-            behavior=Behavior.GLOBAL | Behavior.RESET_REMAINING)],
-            NOW + 2)[0]
-        assert not inst._hotset.is_pinned(kh)  # demoted by object path
-        assert r.remaining == 999  # RESET_REMAINING → full minus 1
-    finally:
-        inst.close()
-
-
 def test_wire_mixed_global_and_local_batch():
-    inst = mk_instance(threshold=2)
+    inst = mk_instance()
     try:
         reqs = [greq(key="mix") if i % 2 == 0 else
                 RateLimitRequest(name="wgl", unique_key="loc", hits=1,
@@ -137,65 +88,18 @@ def test_wire_mixed_global_and_local_batch():
         inst.close()
 
 
-def test_wire_global_leaky_rides_hot_tier():
-    from gubernator_tpu.types import Algorithm
-
-    inst = mk_instance(threshold=2)
-    try:
-        kh = hash_key("wgl", "lk")
-        lr = [greq(key="lk", algorithm=Algorithm.LEAKY_BUCKET)
-              for _ in range(10)]
-        rs = send(inst, lr, NOW)
-        assert all(int(r.status) == 0 for r in rs)
-        assert inst._hotset.is_pinned(kh)
-        rs = send(inst, lr, NOW + 1)
-        assert all(int(r.status) == 0 for r in rs)
-        inst._hotset.sync()
-        rs = send(inst, [greq(key="lk", hits=0,
-                              algorithm=Algorithm.LEAKY_BUCKET)],
-                  NOW + 2)
-        assert rs[0].remaining == 1000 - 20
-    finally:
-        inst.close()
-
-
-# ---- ISSUE 25: the pinned-key pass shared with _wire_mesh_runner -------
-#
-# _wire_global_runner's config match over pinned keys is the mesh
-# runner's (instance.py › _group_key_configs): the parameters of
-# tests/test_mesh_global.py, over the hot set.
-
-import numpy as np  # noqa: E402
-
-from gubernator_tpu import instance as instance_mod  # noqa: E402
-from gubernator_tpu.types import Algorithm  # noqa: E402
-from gubernator_tpu.wire import resp_to_pb  # noqa: E402
+# ---- 1,000-row calls: the wire lane against the object path -----------
 
 ROWS = 1000
 
 
 @pytest.fixture(scope="module")
 def big_pair():
-    """(wire, object) instances whose hot set holds a 1,000-key call;
-    every case empties it first (HotSetEngine.unpin_all)."""
-    mk = lambda: V1Instance(  # noqa: E731
-        Config(cache_size=1 << 14, sweep_interval_ms=0,
-               hot_set_capacity=4096, hot_promote_threshold=1,
-               behaviors=BehaviorConfig(global_sync_wait_ms=10**9)),
-        mesh=make_mesh(n=4))
-    wi, oi = mk(), mk()
+    """(wire, object) instances that take 1,000-key calls."""
+    wi, oi = (mk_instance(cache_size=1 << 14) for _ in range(2))
     yield wi, oi
     wi.close()
     oi.close()
-
-
-def empty_hot_set(*insts):
-    for inst in insts:
-        if inst._hotset is not None:
-            inst._hotset.unpin_all()
-        with inst._hot_mu:
-            inst._hot_counts.clear()
-            inst._promote_pending.clear()
 
 
 def lane(inst, name):
@@ -236,11 +140,10 @@ def obj_bytes(inst, reqs, now):
 @pytest.mark.parametrize("shape", ["g1", "g12", "g490", "g1000", "mixed",
                                    "leaky"])
 def test_wire_lane_byte_equal_to_object_path(big_pair, shape, state):
-    """1,000-row solo-GLOBAL calls: first touch (the call promotes its
-    keys) and warm (all pinned) give the object path's bytes, on the
-    wire lane."""
+    """1,000-row solo-GLOBAL calls, first touch and warm, give the
+    object path's bytes on the ``wire_global`` lane: no pb2 fallback,
+    no pin — the rows are the sharded table's."""
     wi, oi = big_pair
-    empty_hot_set(wi, oi)
     reqs = call_of(shape, f"eq-{shape}-{state}-")
     data = wire(reqs)
     now = NOW
@@ -248,85 +151,153 @@ def test_wire_lane_byte_equal_to_object_path(big_pair, shape, state):
         assert wi.get_rate_limits_wire(data, now_ms=now) == \
             oi.get_rate_limits_wire(data, now_ms=now)
         now += 1
-    n_wire, n_pb2 = lane(wi, "wire_hotset"), lane(wi, "pb2_fallback")
+    n_wire, n_pb2 = lane(wi, "wire_global"), lane(wi, "pb2_fallback")
     got = wi.get_rate_limits_wire(data, now_ms=now)
     assert got == obj_bytes(oi, reqs, now)
-    assert lane(wi, "wire_hotset") - n_wire == ROWS
+    assert lane(wi, "wire_global") - n_wire == ROWS
     assert lane(wi, "pb2_fallback") == n_pb2
     rs = pb.GetRateLimitsResp.FromString(got).responses
     assert len(rs) == ROWS and all(r.error == "" for r in rs)
-    for inst in (wi, oi):  # threshold 1: every GLOBAL key is pinned now
-        assert all(inst._hotset.is_pinned(hash_key(r.name, r.unique_key))
-                   for r in reqs if r.behavior & Behavior.GLOBAL)
+    khs = np.array([hash_key(r.name, r.unique_key) for r in reqs
+                    if r.behavior & Behavior.GLOBAL], np.uint64)
+    for inst in (wi, oi):
+        assert no_replica_tier(inst)
+        assert inst.engine.gather_rows(khs)[0].all()
 
 
-@pytest.mark.parametrize("case", ["mid-batch", "pinned-changed"])
-def test_config_change_on_one_of_490_pinned_keys_returns_none(big_pair,
-                                                              case):
-    """One pinned key of ~490 changes its limit — on its last row, or
-    on all of them: None, before anything moved; the object path
-    demotes the key and serves the call."""
-    wi, oi = big_pair
-    empty_hot_set(wi, oi)
-    ns = f"cc-{case}-"
-    reqs = call_of("g490", ns)
-    data = wire(reqs)
-    assert wi.get_rate_limits_wire(data, now_ms=NOW) == \
-        oi.get_rate_limits_wire(data, now_ms=NOW)
-    rows_of = {}
-    for i, r in enumerate(reqs):
-        rows_of.setdefault(r.unique_key, []).append(i)
-    victim = next(k for k, rows in sorted(rows_of.items())
-                  if 2 <= len(rows) <= 6)
-    hit = rows_of[victim] if case == "pinned-changed" \
-        else rows_of[victim][-1:]
-    for i in hit:
-        reqs[i] = greq(victim, hits=reqs[i].hits, limit=999)
-    data = wire(reqs)
-    hs = wi._hotset
-
-    def state():
-        with hs._mu, wi._hot_mu:
-            return (dict(hs.slots), dict(hs.pinned_cfg),
-                    dict(wi._hot_counts), list(wi._promote_pending))
-
-    before = state()
-    assert wi._wire_global_runner(
-        _wire_native.parse_get_rate_limits(data), NOW + 1) is None
-    assert state() == before
-    n_pb2 = lane(wi, "pb2_fallback")
-    assert wi.get_rate_limits_wire(data, now_ms=NOW + 1) == \
-        obj_bytes(oi, reqs, NOW + 1)
-    assert lane(wi, "pb2_fallback") - n_pb2 == ROWS
-    kh = hash_key("wgl", victim)  # demoted, then promoted anew
-    assert hs.pinned_cfg.get(kh) == oi._hotset.pinned_cfg.get(kh) != \
-        before[1][kh]
+# ---- the owner row is exact: oracle.py, hit for hit ---------------------
+#
+# What the parent gave up at a key's 64th hit (it pinned the key into a
+# per-chip replica that diverged until the next sync tick) and the owner
+# row never does.
 
 
-def test_pinned_key_pass_does_not_grow_with_distinct_keys(big_pair,
-                                                          monkeypatch,
-                                                          numpy_calls):
-    """Warm 1,000-row calls with 12 and ~490 distinct pinned keys make
-    the same numpy calls in the runner (profiler's count of numpy
-    functions and ndarray methods), and build no RateLimitRequest."""
-    wi, _ = big_pair
-    empty_hot_set(wi)
-    built = []
-    real_req = instance_mod.RateLimitRequest
-    counts = {}
-    datas = {shape: wire(call_of(shape, "cx-")) for shape in ("g12", "g490")}
-    for data in datas.values():  # promotes: both calls are warm below,
-        wi.get_rate_limits_wire(data, now_ms=NOW)  # over ONE pinned set
-    for shape, data in datas.items():
-        parsed = _wire_native.parse_get_rate_limits(data)
-        monkeypatch.setattr(
-            instance_mod, "RateLimitRequest",
-            lambda *a, **kw: (built.append(1), real_req(*a, **kw))[1])
-        with numpy_calls() as calls:
-            runner = wi._wire_global_runner(parsed, NOW + 1)
-        monkeypatch.setattr(instance_mod, "RateLimitRequest", real_req)
-        assert runner is not None
-        counts[shape] = calls.n
-        assert runner()
-    assert built == []
-    assert counts["g12"] == counts["g490"] > 0, counts
+def same_as(want, got):
+    return (int(got.status), got.remaining, got.reset_time, got.limit,
+            got.error) == (int(want.status), want.remaining,
+                           want.reset_time, want.limit, want.error)
+
+
+def drive(inst, oracle, calls, t0=NOW):
+    """``calls`` against the instance — even calls on the wire lane, odd
+    ones on the object path — and against the oracle: every answer the
+    same.  Returns the number of rows admitted."""
+    admitted = 0
+    for t, reqs in enumerate(calls):
+        now = t0 + t
+        got = send(inst, reqs, now) if t % 2 == 0 \
+            else inst.get_rate_limits(reqs, now_ms=now)
+        want = oracle.check_batch(reqs, now)
+        for i, (w, g) in enumerate(zip(want, got)):
+            assert same_as(w, g), (t, i, w, g)
+            admitted += int(g.status) == 0 and reqs[i].hits > 0
+    return admitted
+
+
+@pytest.mark.parametrize("limit", [1000, (1 << 32) + 50, (1 << 45) + 7])
+@pytest.mark.parametrize("algorithm", [Algorithm.TOKEN_BUCKET,
+                                       Algorithm.LEAKY_BUCKET],
+                         ids=["token", "leaky"])
+@pytest.mark.parametrize("n_devices", [4, 1])
+def test_solo_global_equals_the_oracle_past_64_hits(n_devices, algorithm,
+                                                    limit):
+    """70 single hits (the parent promoted the key at its 64th), then
+    ten 40-row calls that together ask for more than the limit: every
+    answer is the oracle's, so no window over-admits, whatever the
+    limit's width (the table holds it as two 32-bit words)."""
+    inst = mk_instance(n=n_devices)
+    try:
+        step = max(1, limit // 300)
+        calls = [[greq("ex", limit=limit, algorithm=algorithm)]
+                 for _ in range(70)]
+        calls += [[greq("ex", hits=step, limit=limit, algorithm=algorithm)
+                   for _ in range(40)] for _ in range(10)]
+        admitted = drive(inst, Oracle(), calls)
+        assert 70 < admitted < 470  # the limit was reached, and held
+        assert no_replica_tier(inst)
+    finally:
+        inst.close()
+
+
+@pytest.mark.parametrize("lane_of", ["wire", "object"])
+def test_470_hits_on_a_limit_of_200_admit_exactly_200(lane_of):
+    """One GLOBAL key of limit 200 on a 4-device mesh, the sync tick held
+    off: 70 single hits, then ten 40-row calls.  The parent admitted 470
+    (each chip's replica started at the 130 that were left)."""
+    inst = mk_instance(n=4)
+    try:
+        calls = [[greq("x200", limit=200)] for _ in range(70)]
+        calls += [[greq("x200", limit=200)] * 40 for _ in range(10)]
+        admitted = 0
+        for t, reqs in enumerate(calls):
+            got = send(inst, reqs, NOW + t) if lane_of == "wire" \
+                else inst.get_rate_limits(reqs, now_ms=NOW + t)
+            admitted += sum(int(r.status) == 0 for r in got)
+        assert admitted == 200
+    finally:
+        inst.close()
+
+
+@pytest.mark.parametrize("flag", [Behavior.RESET_REMAINING,
+                                  Behavior.DRAIN_OVER_LIMIT],
+                         ids=["reset_remaining", "drain_over_limit"])
+def test_flagged_request_on_a_much_hit_global_key(flag):
+    """100 hits, then a flagged request (what demoted a pinned key), then
+    more hits: the oracle's answers throughout."""
+    inst = mk_instance(n=4)
+    try:
+        calls = [[greq("fl", limit=150)] * 25 for _ in range(4)]
+        calls += [[greq("fl", hits=70, limit=150,
+                        behavior=Behavior.GLOBAL | flag)]]
+        calls += [[greq("fl", limit=150)] * 10 for _ in range(2)]
+        oracle = Oracle()
+        drive(inst, oracle, calls)
+        r = send(inst, [greq("fl", hits=0, limit=150)], NOW + 99)[0]
+        # RESET: full again, less its own 70 and the 20 after it;
+        # DRAIN: 70 > the 50 left drains the row, and it stays drained
+        assert r.remaining == (60 if flag == Behavior.RESET_REMAINING
+                               else 0)
+    finally:
+        inst.close()
+
+
+def test_limit_and_duration_change_mid_stream_keep_the_consumption():
+    inst = mk_instance(n=4)
+    try:
+        calls = [[greq("cfg", limit=1000)] * 50 for _ in range(2)]
+        calls += [[greq("cfg", limit=500)]]  # 900 → 400, −1
+        calls += [[greq("cfg", limit=500, duration=300_000)] * 9]
+        drive(inst, Oracle(), calls)
+        r = send(inst, [greq("cfg", hits=0, limit=500,
+                             duration=300_000)], NOW + 50)[0]
+        assert r.limit == 500 and r.remaining == 390
+    finally:
+        inst.close()
+
+
+def test_peers_joining_after_100_solo_hits_keep_the_consumed_row():
+    """A peer joins after 100 solo hits: there is no tier to demote, the
+    consumed row is where it always was, and the daemon — still the
+    key's owner — answers the next hit from it."""
+    me = "127.0.0.1:1"
+    inst = mk_instance(n=4, advertise_address=me)
+    try:
+        peers = [PeerInfo(grpc_address=me),
+                 PeerInfo(grpc_address="127.0.0.1:2")]
+        inst.set_peers(peers)
+        key = next(k for k in (f"join{i}" for i in range(64))
+                   if inst.is_self(inst.owner_of(greq(k).key)))
+        inst.set_peers([peers[0]])
+        for t in range(4):
+            send(inst, [greq(key, limit=100_000)] * 25, NOW + t)
+        inst.set_peers(peers)
+        found, cols = inst.engine.gather_rows(
+            np.array([hash_key("wgl", key)], np.uint64))
+        assert found[0] and int(cols["remaining"][0]) == 100_000 - 100
+        n_clustered = lane(inst, "wire_clustered")
+        r = send(inst, [greq(key, limit=100_000)], NOW + 5)[0]
+        assert r.error == "" and r.remaining == 100_000 - 101
+        assert lane(inst, "wire_clustered") - n_clustered == 1
+        assert no_replica_tier(inst)
+    finally:
+        inst.close()

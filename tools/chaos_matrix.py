@@ -572,7 +572,7 @@ def _memory_pressure_cell() -> dict:
         inst = V1Instance(Config(
             cache_size=4096, cache_autogrow_max=4096,
             tier_cold=True, tier_promote_threshold=2,
-            hot_set_capacity=0, sweep_interval_ms=0))
+            sweep_interval_ms=0))
     finally:
         if prev is None:
             os.environ.pop("GUBER_MEM_PRESSURE", None)

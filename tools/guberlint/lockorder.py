@@ -37,7 +37,7 @@ LOCK_ORDER: Tuple[Tuple[str, str], ...] = (
     ("engine_lock", r"^self\._engine_lock$"),
     ("xla_exec_mu", r"^XLA_EXEC_MU$"),
     ("tel_mu", r"^self\._tel_mu$"),
-    ("leaf_mu", r"^(self|hs|fs|gm)\._mu$"),
+    ("leaf_mu", r"^(self|mge|fs|gm)\._mu$"),
 )
 
 _COMPILED = [(name, re.compile(pat)) for name, pat in LOCK_ORDER]

@@ -97,7 +97,6 @@ def _run_split(split: dict, batches, collect_advice: bool):
                                  cache_autogrow_max=hot_rows,
                                  tier_cold=True,
                                  tier_promote_threshold=2,
-                                 hot_set_capacity=0,
                                  sweep_interval_ms=0,
                                  global_mode="mesh"),
                           mesh=make_mesh(n=1))

@@ -327,9 +327,9 @@ class HeavyHitterSketch:
 
     def count_of(self, khash: int) -> int:
         """Tracked count for one key hash (0 when untracked) — the
-        rank the tiered store admits by and the mesh tier picks its
-        overflow victim by.  An overestimate by at most the key's
-        ``err``, which only makes promotion eager, never starved."""
+        rank the mesh tier picks its overflow victim by.  An
+        overestimate by at most the key's ``err`` (the tiered store
+        admits by ``known_of``, which takes it off)."""
         self._reindex()
         if not self._sorted_kh.size:
             return 0
@@ -339,11 +339,9 @@ class HeavyHitterSketch:
             return 0
         return int(self._cnt[self._sorted_slot[pos]])
 
-    def counts_of(self, khashes) -> np.ndarray:
-        """``count_of`` for every key hash of an array — i64[n], 0
-        where untracked — in ONE vectorised probe of the sorted index:
-        the tiered store's admission reads a wave's ~1,100 served keys
-        at once (tiering.py › _admit)."""
+    def _read(self, khashes, col) -> np.ndarray:
+        """i64[n]: ``col(slots)`` for the tracked keys of an array, 0
+        where untracked — ONE vectorised probe of the sorted index."""
         kh = np.asarray(khashes, np.uint64)
         out = np.zeros(kh.size, np.int64)
         self._reindex()
@@ -352,8 +350,29 @@ class HeavyHitterSketch:
         pos = np.minimum(np.searchsorted(self._sorted_kh, kh),
                          self._sorted_kh.size - 1)
         hit = self._sorted_kh[pos] == kh
-        out[hit] = self._cnt[self._sorted_slot[pos[hit]]]
+        out[hit] = col(self._sorted_slot[pos[hit]])
         return out
+
+    def counts_of(self, khashes) -> np.ndarray:
+        """``count_of`` for every key hash of an array — i64[n], 0
+        where untracked."""
+        return self._read(khashes, lambda slot: self._cnt[slot])
+
+    def known_of(self, khashes) -> np.ndarray:
+        """``count - err`` for every key hash of an array — i64[n], 0
+        where untracked: the hits each key is KNOWN to have drawn since
+        the sketch began to track it (a tracked key's true count lies
+        in ``[count - err, count]``).  Where the key domain is larger
+        than ``width`` a newcomer inherits the evicted minimum as both
+        its count and its ``err``, so under load EVERY tracked key's
+        ``count`` reads in the thousands and says "tracked right now";
+        ``count - err`` says "drew this many hits while tracked", which
+        is what the tiered store admits and picks victims by
+        (tiering.py › _admit reads a wave's ~1,100 served keys at
+        once).  Equal to ``counts_of`` while the domain fits in
+        ``width`` (every ``err`` is 0)."""
+        return self._read(khashes,
+                          lambda slot: self._cnt[slot] - self._err[slot])
 
     def topk(self, k: Optional[int] = None) -> List[dict]:
         k = self.k if k is None else max(int(k), 1)
@@ -1420,19 +1439,24 @@ class KeyAnalytics:
     def sketch_count(self, khash: int) -> int:
         """Thread-safe tracked-count read for one key hash (0 when
         untracked) — the mesh tier's overflow rank (instance.py ›
-        _mesh_overflow_victim) and the tiered store's admission rank
-        (tiering.py)."""
+        _mesh_overflow_victim)."""
         with self._mu:
             return self.sketch.count_of(khash)
 
     def sketch_counts(self, khashes) -> np.ndarray:
         """Batched :meth:`sketch_count`, i64[n] — ONE lock acquisition
-        and one vectorised probe for a wave's served cold keys
-        (tiering.py › _admit) or a probe window's worth of
-        victim-candidate ranks (› _pick_victim picks the coldest device
-        row to evict)."""
+        and one vectorised probe."""
         with self._mu:
             return self.sketch.counts_of(khashes)
+
+    def sketch_known(self, khashes) -> np.ndarray:
+        """Thread-safe :meth:`HeavyHitterSketch.known_of`, i64[n]: the
+        tiered store's admission and victim rank — ONE lock acquisition
+        and one vectorised probe for a wave's served cold keys or a
+        migration pass's victim candidates (tiering.py › _admit,
+        › _pick_victims)."""
+        with self._mu:
+            return self.sketch.known_of(khashes)
 
     def stats(self) -> dict:
         with self._mu:

@@ -298,6 +298,14 @@ class Daemon:
                 # use, not worth taxing every (test) daemon startup.
                 with self.instance._engine_mu:
                     self.instance.engine.warmup()
+            elif getattr(self.instance.engine, "tier", None) is not None:
+                # off TPU too, with a cold tier bound: the row programs
+                # of a migration pass (a dozen tiny compiles) — a pass
+                # runs inside a served wave, and a deployment that
+                # guarantees no compile there is held to it at its
+                # rehearsal size as well
+                with self.instance._engine_mu:
+                    self.instance.engine.warmup_tier()
             self.instance.get_rate_limits(
                 [RateLimitRequest(name="_warmup", unique_key="w", hits=0,
                                   limit=1, duration=1000)])

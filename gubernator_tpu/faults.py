@@ -98,14 +98,17 @@ FAULT_POINTS = {
                "aborted tick loses nothing)",
     "snapshot": "instance._save_to_loader — before the Loader snapshot",
     "restore": "instance._load_from_loader — before the Loader restore",
-    "tier_promote": "TierController.promote — after the admissibility "
-                    "gate, before the cold row is written to the "
-                    "device table (error aborts the migration: the row "
-                    "stays cold, tier_migrations_aborted increments)",
-    "tier_demote": "TierController.demote — before the victim row is "
-                   "gathered off the device (error aborts the "
-                   "eviction: the row stays hot and the triggering "
-                   "promotion is abandoned)",
+    "tier_promote": "TierController.migrate — once a promotee of a "
+                    "migration pass, after the admissibility gate, "
+                    "before the pass touches the device (error drops "
+                    "that ONE row from the pass: it stays cold, "
+                    "tier_migrations_aborted increments; the rest of "
+                    "the pass goes on)",
+    "tier_demote": "TierController.migrate › _pick_victims (and demote) "
+                   "— once a victim picked, before its row is taken "
+                   "off the device image (error aborts that ONE "
+                   "eviction: the row stays hot and the promotion that "
+                   "asked for it is abandoned)",
 }
 
 

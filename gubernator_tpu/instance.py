@@ -283,7 +283,11 @@ class V1Instance:
 
             thr = int(os.environ.get("GUBER_TIER_PROMOTE")
                       or config.tier_promote_threshold)
-            rank_fn = (analytics.sketch_count
+            # the admission and victim rank: the hits a key is KNOWN
+            # to have drawn (count - err), not its raw sketch count —
+            # under load every tracked key's count reads in the
+            # thousands (ARCHITECTURE.md §2.2)
+            rank_fn = ((lambda kh: int(analytics.sketch_known([kh])[0]))
                        if analytics is not None else None)
             tap = None
             if getattr(engine, "fused_tap", False) \
@@ -297,7 +301,7 @@ class V1Instance:
                 metrics=self.metrics, recorder=self.recorder,
                 fault=self._fault_point,
                 skip_victim=self._tier_victim_pinned, tap=tap,
-                rank_batch=(analytics.sketch_counts
+                rank_batch=(analytics.sketch_known
                             if analytics is not None else None))
         # every eagerly-built consumer enrolls now; the lazy mesh-GLOBAL
         # tier enrolls inside its _ensure_meshglobal builder

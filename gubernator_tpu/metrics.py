@@ -471,6 +471,13 @@ class Metrics:
             "tier migrations abandoned at the tier_promote/tier_demote "
             "faultpoints (the row stays in its source tier — no state "
             "is lost)", registry=r)
+        self.tier_admissions_deferred = Counter(
+            "gubernator_tier_admissions_deferred",
+            "cold keys whose rank cleared the admission threshold in a "
+            "wave whose migration pass was already full "
+            "(tiering.MIGRATE_MAX keys a pass): they stay cold, are "
+            "answered exactly there, and are admitted when next "
+            "served; one inc(n) a pass", registry=r)
         # Tenant-aware SLO plane (ISSUE 11): per-tenant RED ledger
         # gauges (bounded cardinality — GUBER_TENANT_MAX buckets plus
         # __other__; the analytics worker republishes on its paced

@@ -1947,6 +1947,54 @@ static PyObject* cold_pop(PyObject*, PyObject* args) {
   return out;
 }
 
+// cold_take_batch(capsule, keys u64le[n], rows i64le[n * 8] writable,
+//                 found u8[n] writable, remove) -> keys found
+// The cold side of a migration pass (tiering.py › TierController.migrate)
+// in one call each way: the rows of a wave's admitted keys read together
+// (remove = 0: found[i] says whether keys[i] is held, rows[i] is its row,
+// left as it was where it is not), and the keys the pass placed on the
+// device taken out together (remove = 1: cold_pop's tombstone for each
+// key found; a key that comes twice is found once).
+static PyObject* cold_take_batch(PyObject*, PyObject* args) {
+  PyObject* obj;
+  Py_buffer keys, rows, found;
+  int remove;
+  if (!PyArg_ParseTuple(args, "Oy*w*w*p", &obj, &keys, &rows, &found,
+                        &remove))
+    return nullptr;
+  ColdStore* cs = cold_from(obj);
+  const Py_ssize_t n = keys.len / 8;
+  const Py_ssize_t row_bytes = COLD_ROW * (Py_ssize_t)sizeof(int64_t);
+  if (cs == nullptr || rows.len < n * row_bytes || found.len < n) {
+    if (cs != nullptr)
+      PyErr_SetString(PyExc_ValueError, "rows or found too short");
+    PyBuffer_Release(&keys);
+    PyBuffer_Release(&rows);
+    PyBuffer_Release(&found);
+    return nullptr;
+  }
+  const uint64_t* kp = (const uint64_t*)keys.buf;
+  int64_t* rp = (int64_t*)rows.buf;
+  uint8_t* fp = (uint8_t*)found.buf;
+  Py_ssize_t got = 0;
+  for (Py_ssize_t k = 0; k < n; k++) {
+    bool present;
+    size_t i = cold_find(cs, kp[k], &present);
+    fp[k] = present ? 1 : 0;
+    if (!present) continue;
+    got++;
+    std::memcpy(&rp[k * COLD_ROW], &cs->rows[i * COLD_ROW], row_bytes);
+    if (remove) {
+      cs->state[i] = 2;  // tombstone keeps later probe chains intact
+      cs->used--;
+    }
+  }
+  PyBuffer_Release(&keys);
+  PyBuffer_Release(&rows);
+  PyBuffer_Release(&found);
+  return PyLong_FromSsize_t(got);
+}
+
 // cold_len(capsule) -> resident key count
 static PyObject* cold_len(PyObject*, PyObject* args) {
   PyObject* obj;
@@ -2116,6 +2164,9 @@ static PyMethodDef methods[] = {
      "cold_put(capsule, key, row64B) -> 1 inserted / 0 overwrote"},
     {"cold_put_batch", cold_put_batch, METH_VARARGS,
      "cold_put_batch(capsule, keys u64le, rows i64le) -> keys inserted"},
+    {"cold_take_batch", cold_take_batch, METH_VARARGS,
+     "cold_take_batch(capsule, keys u64le, rows i64le out, found u8 out, "
+     "remove) -> keys found: a batch of rows read, or read and removed"},
     {"cold_apply_batch", cold_apply_batch, METH_VARARGS,
      "cold_apply_batch(capsule, khash, idx, 9 request columns, now_ms, "
      "td_bound, frac_safe, 5 response columns) -> (served, created, "

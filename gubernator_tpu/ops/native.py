@@ -14,11 +14,11 @@ from ..types import (DURATION_MAX, EFF_MAX, GREGORIAN_APPROX_MS, TD_BOUND,
                      VALUE_MAX, GregorianDuration)
 from . import _native  # ImportError here means: run `make native`
 
-#: the entry point the newest ``_native.cpp`` added (PR 42; PR 41's
-#: was ``cold_put_batch``, PR 40's ``gregorian_end``, PR 37's
+#: the entry point the newest ``_native.cpp`` added (PR 46; PR 42's
+#: was ``cold_apply_batch``, PR 41's ``cold_put_batch``, PR 40's ``gregorian_end``, PR 37's
 #: ``thread_files``, PR 36's ``route_plan`` / ``route_fill``): a build
 #: without it is older than the source
-NEWEST = "cold_apply_batch"
+NEWEST = "cold_take_batch"
 
 if not hasattr(_native, NEWEST):
     # NOT an ImportError: every importer reads that as "no extension"

@@ -45,7 +45,7 @@ from ..core.step import REPLICA_PROBES, decide_batch_impl
 from ..ops import pallas_step as ps
 from ..tracing import phase
 from .mesh import SHARD_AXIS, XLA_EXEC_MU, exec_gate
-from .sharded import ShardedEngine
+from .sharded import ROW_OP_SIZES, VALUE_COLS, ShardedEngine, padded
 
 log = logging.getLogger("gubernator_tpu.pallas_engine")
 
@@ -212,6 +212,81 @@ def _place_into_buckets(buckets: np.ndarray, group_id: np.ndarray,
     # this fancy assignment has no write collisions
     buckets[group_id[placed], slot[placed]] = words[placed]
     return placed
+
+
+def _row_columns(rows: np.ndarray) -> dict:
+    """i64[n, 8] rows in ``VALUE_COLS`` order (tiering.ROW_COLS: a
+    cold row's value columns) → the SoA column dict
+    ``_columns_to_words_batch`` takes."""
+    cols = {f: rows[:, j] for j, f in enumerate(VALUE_COLS)}
+    cols["meta"] = cols["meta"].astype(np.int32)
+    return cols
+
+
+class _BucketImage:
+    """The host image ONE migration pass of the tier works on
+    (tiering.py › TierController.migrate): the distinct buckets of the
+    pass's keys, fetched from the device ONCE (phase `tier.fetch`; the
+    fetch queues behind whatever wave is already launched, so the image
+    holds what that wave did to every row in it), mutated here on the
+    host — promotees placed, victims taken out, two keys that share a
+    bucket resolved on the one copy — and written back ONCE by
+    ``commit`` (phase `tier.write`), if anything changed.  Nothing else
+    may touch the table between the two: the pass runs under the engine
+    lock on the thread that launches."""
+
+    def __init__(self, eng, keys: np.ndarray):
+        self.eng, self.keys = eng, keys
+        self.ubids, self.gid = eng._grouped_bucket_view(keys)
+        with phase("tier.fetch", eng.metrics_ref):
+            self.buckets = eng._fetch_buckets(self.ubids)
+        self.dirty = False
+
+    def place(self, sel: np.ndarray, rows: np.ndarray) -> np.ndarray:
+        """Keys ``sel`` (indices into the image's keys) placed with
+        their i64[k, 8] ``rows``: bool[k], False where the key's bucket
+        has no free slot.  Rows are inside the kernel's domain
+        (``tier_rows_admissible``: the caller's gate)."""
+        keys = self.keys[sel]
+        words, _ = _columns_to_words_batch(_row_columns(rows), keys)
+        khi, klo = _split_np(keys)
+        placed = _place_into_buckets(self.buckets, self.gid[sel], klo, khi,
+                                     words)
+        self.dirty |= bool(placed.any())
+        return placed
+
+    def occupants(self, sel: np.ndarray) -> np.ndarray:
+        """u64[k, SLOTS]: the resident keys of the bucket of each key
+        ``sel`` — its probe window, so its eviction candidates — as the
+        image holds them NOW (0 = free slot)."""
+        res = _join_u64(self.buckets[:, :, ps.W_KHI],
+                        self.buckets[:, :, ps.W_KLO])
+        return res[self.gid[sel]]
+
+    def take(self, sel: np.ndarray, vkeys: np.ndarray) -> tuple:
+        """Resident ``vkeys[j]`` of the bucket of key ``sel[j]`` taken
+        out of the image: (found bool[k], rows i64[k, 8], zeros where
+        not found) — all eight value columns as the kernel left them."""
+        g = self.gid[sel]
+        hit = self.occupants(sel) == vkeys[:, None]
+        hit &= (vkeys != 0)[:, None]
+        found = hit.any(axis=1)
+        slot = hit.argmax(axis=1)
+        rows = np.zeros((len(sel), len(VALUE_COLS)), np.int64)
+        if found.any():
+            at = (g[found], slot[found])
+            cols = _rows_to_columns(self.buckets[at])
+            rows[found] = np.stack(
+                [np.asarray(cols[f], np.int64) for f in VALUE_COLS], axis=1)
+            self.buckets[at] = 0
+            self.dirty = True
+        return found, rows
+
+    def commit(self) -> None:
+        if self.dirty:
+            with phase("tier.write", self.eng.metrics_ref):
+                self.eng._write_buckets(self.ubids, self.buckets)
+            self.dirty = False
 
 
 #: the bucket table's partition: bucket axis over the mesh
@@ -660,36 +735,37 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
 
     # ---- tiered store hooks (tiering.py) -------------------------------
 
-    def warmup(self, now_ms: int = 1) -> None:
-        """Every wave bucket's step program, and with a cold tier bound
-        the row programs a migration runs (one bucket fetched, one
-        written back as it was): an admission's first use must not
-        compile inside a served wave."""
-        super().warmup(now_ms)
-        if self.tier is not None:
-            bid = np.zeros(1, np.int64)
+    def warmup_tier(self) -> None:
+        """The row programs a tier migration pass runs (``_BucketImage``)
+        at every padded length (``ROW_OP_SIZES``): the buckets fetched,
+        and written back as they were."""
+        for m in ROW_OP_SIZES:
+            bid = np.zeros(m, np.int64)
             self._write_buckets(bid, self._fetch_buckets(bid))
 
-    def tier_row_admissible(self, row) -> bool:
-        """Admission domain gate: a cold row whose values exceed the
-        kernel's packed-word domain must STAY cold — upsert_rows would
-        silently drop it, and the migration would lose the row."""
-        cols = {f: np.array([v], np.int64) for f, v in zip(
-            ("meta", "limit", "duration", "eff_ms", "burst",
-             "remaining", "t_ms", "expire_at"), row)}
-        cols["meta"] = cols["meta"].astype(np.int32)
-        _, valid = _columns_to_words_batch(cols, np.array([1], np.uint64))
-        return bool(valid[0])
+    def tier_rows_admissible(self, rows: np.ndarray) -> np.ndarray:
+        """Admission domain gate, bool[n] for i64[n, 8] cold rows
+        (tiering.ROW_COLS order): a cold row whose values exceed the
+        kernel's packed-word domain must STAY cold — placing it would
+        truncate it, and the migration would lose the row."""
+        rows = np.asarray(rows, np.int64).reshape(-1, len(VALUE_COLS))
+        _, valid = _columns_to_words_batch(
+            _row_columns(rows), np.ones(len(rows), np.uint64))
+        return valid
 
-    def probe_occupant_keys(self, kh: int) -> np.ndarray:
-        """Eviction-candidate read for the tier controller: the
-        bucketized layout's probe window IS the key's bucket, so the
-        occupants are the bucket's resident keys (0 = free slot)."""
+    def tier_image(self, khash: np.ndarray) -> "_BucketImage":
+        """The host image a migration pass works on (tiering.py ›
+        TierController.migrate): the distinct buckets of ``khash``,
+        fetched ONCE here and written back ONCE by its ``commit``."""
+        return _BucketImage(self, np.asarray(khash, np.uint64))
+
+    def probe_occupants(self, khash: np.ndarray) -> np.ndarray:
+        """u64[k, SLOTS] eviction-candidate read: the bucketized
+        layout's probe window IS the key's bucket, so a key's occupants
+        are its bucket's resident keys (0 = free slot)."""
         b = self._fetch_buckets(
-            self._bucket_ids(np.array([kh], np.uint64)))[0]
-        lo = b[:, ps.W_KLO].astype(np.uint64) & np.uint64(0xFFFFFFFF)
-        hi = b[:, ps.W_KHI].astype(np.uint64) & np.uint64(0xFFFFFFFF)
-        return (hi << np.uint64(32)) | lo
+            self._bucket_ids(np.asarray(khash, np.uint64)))
+        return _join_u64(b[:, :, ps.W_KHI], b[:, :, ps.W_KLO])
 
     # ---- sweep ---------------------------------------------------------
 
@@ -734,22 +810,32 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
 
     def _fetch_buckets(self, bids: np.ndarray) -> np.ndarray:
         """Gather [m, SLOTS, WORDS] slot-row copies of buckets ``bids``
-        to host (writable: callers mutate them in place)."""
-        got = np.asarray(jnp.take(self.state, jnp.asarray(bids), axis=0))
-        return np.ascontiguousarray(ps.buckets_to_rows(got))
+        to host (writable: callers mutate them in place).  The device
+        gather runs at a padded length (``sharded.padded``), so that a
+        batch of any size up to the largest runs a program
+        ``warmup_tier`` compiled."""
+        got = np.asarray(jnp.take(self.state,
+                                  jnp.asarray(padded(bids)), axis=0))
+        return np.ascontiguousarray(ps.buckets_to_rows(got[:len(bids)]))
 
     def _write_buckets(self, bids: np.ndarray, rows: np.ndarray) -> None:
         # duplicate buckets in one call carry identical content (the
-        # caller mutates a shared host copy per bucket), so last-write
-        # equivalence holds even without a uniqueness promise
+        # caller mutates a shared host copy per bucket; the padding
+        # repeats the last bucket), so last-write equivalence holds
+        # even without a uniqueness promise
         if not hasattr(self, "_write_fn"):
             # cached: a fresh lambda per call would retrace+recompile
             # the scatter on every store write-through
             self._write_fn = jax.jit(lambda s, i, r: s.at[i].set(r),
                                      donate_argnums=(0,))
-        self.state = self._write_fn(
-            self.state, jnp.asarray(bids),
-            jnp.asarray(np.ascontiguousarray(ps.buckets_to_rows(rows))))
+        pad = padded(bids)
+        got = np.ascontiguousarray(ps.buckets_to_rows(rows))
+        if len(pad) != len(bids):
+            got = np.concatenate(
+                [got, np.broadcast_to(got[-1], (len(pad) - len(bids),)
+                                      + got.shape[1:])])
+        self.state = self._write_fn(self.state, jnp.asarray(pad),
+                                    jnp.asarray(got))
 
     def gather_rows(self, khash: np.ndarray) -> tuple[np.ndarray, dict]:
         m = len(khash)
@@ -901,7 +987,7 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
         A bucket's free slots go to its rows in snapshot order.  With a
         cold tier bound, rows that find their bucket full and rows
         outside the kernel's value domain (which must STAY cold:
-        ``tier_row_admissible``) go to it in ONE batch (tiering.py ›
+        ``tier_rows_admissible``) go to it in ONE batch (tiering.py ›
         adopt_rows), so that every snapshot row lands in exactly one
         tier, as on the XLA engine.  Without one both are dropped.
         Returns rows placed + adopted; ``dropped_rows`` and the gauge

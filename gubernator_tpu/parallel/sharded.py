@@ -307,6 +307,65 @@ def make_pallas_sweep(mesh, interpret: bool = False):
         out_specs=(P(SHARD_AXIS), P()), check_vma=False))
 
 
+#: the lengths the key (or bucket) list of a tier migration pass's row
+#: programs is padded to, its last entry repeated: a pass moves at most
+#: ``tiering.MIGRATE_MAX`` keys (the largest here), and every length is
+#: compiled by ``warmup_tier`` when a tier is bound — a gather of a new
+#: length would compile inside a served wave.  A longer list (a GLOBAL
+#: broadcast's, a demote-all's) runs at its own length, as it always did.
+ROW_OP_SIZES = (1, 16, 64, 256)
+
+
+def padded(ids: np.ndarray) -> np.ndarray:
+    """``ids`` at the smallest length of ``ROW_OP_SIZES`` that holds it,
+    its last entry repeated; as it is where none does (or it is
+    empty)."""
+    m = len(ids)
+    size = next((z for z in ROW_OP_SIZES if z >= m), m)
+    if size == m or not m:
+        return ids
+    return np.concatenate([ids, np.full(size - m, ids[-1], ids.dtype)])
+
+
+class _TableImage:
+    """A tier migration pass's view of the open-addressing table
+    (tiering.py › TierController.migrate; the bucket engine's is a host
+    image fetched once, pallas_engine.py › _BucketImage).  Here every
+    step IS a batched device program over the pass's keys — at most
+    five launches a pass whatever its size, where a key at a time cost
+    five a key — and ``commit`` has nothing left to write."""
+
+    def __init__(self, eng, keys: np.ndarray):
+        self.eng, self.keys = eng, keys
+
+    def place(self, sel: np.ndarray, rows: np.ndarray) -> np.ndarray:
+        """Keys ``sel`` upserted with their i64[k, 8] rows (VALUE_COLS
+        order): bool[k], False where the probe window is full."""
+        keys = self.keys[sel]
+        cols = {f: rows[:, j].astype(COLUMN_DTYPES[f])
+                for j, f in enumerate(VALUE_COLS)}
+        if self.eng.upsert_rows(keys, cols) == len(keys):
+            return np.ones(len(keys), bool)
+        return self.eng.gather_rows(keys)[0]
+
+    def occupants(self, sel: np.ndarray) -> np.ndarray:
+        return self.eng.probe_occupants(self.keys[sel])
+
+    def take(self, sel: np.ndarray, vkeys: np.ndarray) -> tuple:
+        """Rows ``vkeys`` gathered and removed: (found bool[k], rows
+        i64[k, 8])."""
+        found, cols = self.eng.gather_rows(vkeys)
+        found &= vkeys != 0
+        rows = np.stack([np.asarray(cols[f], np.int64)
+                         for f in VALUE_COLS], axis=1)
+        if found.any():
+            self.eng.remove_rows(vkeys[found])
+        return found, rows
+
+    def commit(self) -> None:
+        pass
+
+
 class ShardedEngine:
     """Host dispatcher over a sharded table: the multi-chip analog of the
     reference's V1Instance request router (gubernator.go ›
@@ -895,6 +954,22 @@ class ShardedEngine:
         coalesced burst never eats a cold compile inside an RPC."""
         for bw in self.wave_buckets:
             self._run_wave(empty_batch(self.n * bw), now_ms)
+        if self.tier is not None:
+            self.warmup_tier()
+
+    def warmup_tier(self) -> None:
+        """The row programs a tier migration pass runs (``_TableImage``),
+        at every padded length, on keys no row holds (no state change):
+        a pass's first use must not compile inside a served wave.
+        Part of ``warmup`` when a tier is bound; a daemon off a TPU,
+        which skips ``warmup``, calls it alone."""
+        zero = np.zeros(1, np.uint64)
+        self.gather_rows(zero)
+        self.upsert_rows(zero, {f: np.zeros(1, COLUMN_DTYPES[f])
+                                for f in VALUE_COLS})
+        self.remove_rows(zero)
+        for m in ROW_OP_SIZES:
+            self.probe_occupants(np.zeros(m, np.uint64))
 
     def _launch_arrays(self, a64: np.ndarray, a32: np.ndarray,
                        now_ms: int, mblk=None):
@@ -1267,23 +1342,32 @@ class ShardedEngine:
         finally:
             XLA_EXEC_MU.release()
 
-    def probe_occupant_keys(self, kh: int) -> np.ndarray:
-        """The resident key hashes in ``kh``'s probe window (up to
-        PROBES entries, 0 = free slot) — the tier controller's eviction
-        candidate read: any of these keys, once demoted, frees a slot
-        ``kh`` itself can take (same probe formula as the device kernel,
-        core/step.py › _probe_slots)."""
+    def probe_occupants(self, khash: np.ndarray) -> np.ndarray:
+        """u64[k, PROBES]: the resident key hashes in the probe window
+        of each key of ``khash`` (0 = free slot), in ONE device gather
+        at a padded length (``padded``) —
+        the tier controller's eviction candidate read: any of a key's
+        occupants, once demoted, frees a slot that key itself can take
+        (same probe formula as the device kernel, core/step.py ›
+        _probe_slots)."""
         from ..core.step import PROBES
 
-        k = np.uint64(kh)
+        k = padded(np.asarray(khash, np.uint64))
         stride = (k >> np.uint64(17)) | np.uint64(1)
-        local = ((k + np.arange(PROBES, dtype=np.uint64) * stride)
-                 & np.uint64(self.cap_local - 1))
-        shard = int(shard_of(np.array([k], np.uint64), self.n)[0])
-        slots = (shard * self.cap_local + local).astype(np.int64)
+        local = ((k[:, None] + np.arange(PROBES, dtype=np.uint64)
+                  * stride[:, None]) & np.uint64(self.cap_local - 1))
+        shard = shard_of(k, self.n).astype(np.int64)
+        slots = (shard[:, None] * self.cap_local
+                 + local.astype(np.int64)).reshape(-1)
         with XLA_EXEC_MU:
             keys = np.asarray(take_rows(self.state.key, jnp.asarray(slots)))
-        return keys.view(np.uint64)
+        return keys.view(np.uint64).reshape(len(k), PROBES)[:len(khash)]
+
+    def tier_image(self, khash: np.ndarray) -> "_TableImage":
+        """What a migration pass of the tier works on (tiering.py ›
+        TierController.migrate) — on this engine the table itself,
+        through its batched row programs."""
+        return _TableImage(self, np.asarray(khash, np.uint64))
 
     def each(self):
         """Iterate live rows as store.CacheItem objects (Cache.Each

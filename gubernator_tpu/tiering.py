@@ -21,11 +21,17 @@ migrates rows between them —
   or a built extension without that entry point — the Python loop of
   ``_host_apply`` calls, which is also the reference the pass is held
   to (tests/test_cold_apply_batch.py);
-- when a cold key's heavy-hitter rank (analytics.py sketch) clears the
-  admission threshold its row migrates to HBM, evicting the coldest
-  resident row of its probe window back to host under a
-  conservation-exact, created_at-preserving handoff (all eight value
-  columns move verbatim, both directions).
+- when a cold key's heavy-hitter rank (analytics.py sketch: the hits
+  it is KNOWN to have drawn, ``count - err``) clears the admission
+  threshold its row migrates to HBM, evicting the coldest resident row
+  of its probe window back to host under a conservation-exact,
+  created_at-preserving handoff (all eight value columns move verbatim,
+  both directions).  A wave's admitted keys move in ONE migration pass
+  (``TierController.migrate``): their device buckets fetched once,
+  promotees placed and victims taken out on that image, the image
+  written back once, the cold store's side of it one batch call each
+  way; a pass moves at most ``MIGRATE_MAX`` keys, and what is over
+  stays cold — still answered exactly there — until it is next served.
 
 Coherence: every membership change (serve, create, promote, demote)
 happens inside the engine's ``check_packed`` resolve or under the
@@ -72,6 +78,21 @@ _DRAIN = int(Behavior.DRAIN_OVER_LIMIT)
 #: the all-zero item a missing key adopts — identical to the device's
 #: out-of-range gather fill (core/step.py › grow: zeros, eff_ms 1)
 _ZERO_ROW = (0, 0, 0, 1, 0, 0, 0, 0)
+
+#: the most keys ONE migration pass promotes (and so the most it
+#: demotes): the bound on what a waking tenant costs the wave that
+#: admits it.  The pass's device side is one fetch and one write of its
+#: keys' distinct buckets (8 KiB each on the bucket engine, padded to
+#: pallas_engine.ROW_OP_SIZES, whose largest this is), its host side
+#: numpy over [keys, probe window]; what is over the bound stays cold,
+#: is answered exactly there, and is admitted when next served
+#: (``gubernator_tier_admissions_deferred``).  Chosen on the chip:
+#: PERF.md §6 (PR 46).
+MIGRATE_MAX = 256
+
+#: flight-recorder events a pass records of each kind (promote, demote):
+#: a waking tenant must not flush the ring of everything else
+_EVENTS_A_PASS = 4
 
 #: the nine request columns of a wave that a cold row is applied from,
 #: in ``_host_apply``'s argument order
@@ -223,6 +244,20 @@ class _DictColdStore:
     def pop(self, kh: int):
         return self._d.pop(kh, None)
 
+    def take_batch(self, keys: np.ndarray, remove: bool = False) -> tuple:
+        """(found bool[n], rows i64[n, 8], zeros where not found) of
+        ``keys``; ``remove``: each key found is taken out (a key that
+        comes twice is found once)."""
+        take = self._d.pop if remove else self._d.get
+        found = np.zeros(len(keys), bool)
+        rows = np.zeros((len(keys), len(ROW_COLS)), np.int64)
+        for i, kh in enumerate(keys.tolist()):
+            row = take(kh, None)
+            if row is not None:
+                found[i] = True
+                rows[i] = row
+        return found, rows
+
     def contains_batch(self, khash: np.ndarray) -> np.ndarray:
         d = self._d
         return np.fromiter((int(k) in d for k in khash), bool,
@@ -290,6 +325,26 @@ class _NativeColdStore:
             int(now_ms), TD_BOUND, FRAC_SAFE, *cols)
         return served, created, np.frombuffer(keys, "<u8")
 
+    def take_batch(self, keys: np.ndarray, remove: bool = False) -> tuple:
+        """``_DictColdStore.take_batch`` as ONE C++ pass
+        (``cold_take_batch``; a built extension without it: a key at a
+        time)."""
+        n = len(keys)
+        found = np.zeros(n, np.uint8)
+        rows = np.zeros((n, len(ROW_COLS)), "<i8")
+        if hasattr(self._m, "cold_take_batch"):
+            self._m.cold_take_batch(self._h,
+                                    np.ascontiguousarray(keys, "<u8"),
+                                    rows, found, bool(remove))
+        else:
+            take = self.pop if remove else self.get
+            for i, kh in enumerate(keys.tolist()):
+                row = take(kh)
+                if row is not None:
+                    found[i] = 1
+                    rows[i] = row
+        return found != 0, rows
+
     def pop(self, kh: int):
         b = self._m.cold_pop(self._h, kh)
         if b is None:
@@ -345,8 +400,8 @@ class TierController:
         self._mu = threading.Lock()
         self._store = _make_store()  # guarded-by: self._mu
         self.rank_fn = rank_fn
-        #: batched rank read (analytics.sketch_counts) — victim
-        #: selection scans a whole probe window per promotion
+        #: batched rank read (analytics.sketch_known) — a wave's served
+        #: keys at once, and a pass's victim candidates at once
         self.rank_batch = rank_batch
         self.promote_threshold = max(int(promote_threshold), 1)
         self.metrics = metrics
@@ -364,6 +419,8 @@ class TierController:
         self.promotions = 0  # lock-free: resolve-path only (engine-lock serialized)
         self.demotions = 0  # lock-free: resolve-path only (engine-lock serialized)
         self.migrations_aborted = 0  # lock-free: resolve-path only (engine-lock serialized)
+        #: admissions a pass's bound (MIGRATE_MAX) put off
+        self.admissions_deferred = 0  # lock-free: resolve-path only (engine-lock serialized)
         engine.tier = self
 
     # ---- membership reads ----------------------------------------------
@@ -395,7 +452,8 @@ class TierController:
                     "native": self._store.native,
                     "promotions": self.promotions,
                     "demotions": self.demotions,
-                    "migrations_aborted": self.migrations_aborted}
+                    "migrations_aborted": self.migrations_aborted,
+                    "admissions_deferred": self.admissions_deferred}
 
     # ---- row handoff (seeding / snapshot / overflow) -------------------
 
@@ -550,149 +608,217 @@ class TierController:
     # ---- admission / migration -----------------------------------------
 
     def _admit(self, engine, khs) -> None:
-        """Promote every just-served cold key (``khs``: distinct, in
-        order of first service) whose sketch rank clears the admission
-        threshold.  No rank feed (analytics off) → no admission: serving
-        stays exact, just host-paced.  The ranks are read ONCE a wave
-        where the feed has a batched read (``rank_batch``), a key at a
-        time otherwise.  Phase `tier.migrate`: one sample an admission
-        tried, victim pick and demotion inside."""
+        """Hand the just-served cold keys (``khs``: distinct, in order
+        of first service) whose rank clears the admission threshold to
+        ONE migration pass.  No rank feed (analytics off) → no
+        admission: serving stays exact, just host-paced.  The ranks are
+        read ONCE a wave (``rank_batch``; a feed without it a key at a
+        time).  A pass moves at most ``MIGRATE_MAX`` keys, the hottest:
+        what is over stays cold and is admitted when next served."""
         if self.rank_fn is None or not len(khs):
             return
-        thr = self.promote_threshold
-        if self.rank_batch is not None:
-            try:
-                ranks = np.asarray(self.rank_batch(khs))
-            except Exception:  # pragma: no cover - analytics only
-                return
-            hot = ((int(khs[j]), int(ranks[j]))
-                   for j in np.nonzero(ranks >= thr)[0])
-        else:
-            hot = self._ranked_over(khs, thr)
-        for kh, r in hot:
-            timed = phase("tier.migrate", self.metrics).begin(
-                at=time.perf_counter())
-            self.promote(engine, kh, r)
+        khs = np.asarray(khs, np.uint64)
+        ranks = self._ranks(khs)
+        if ranks is None:
+            return
+        hot = np.nonzero(ranks >= self.promote_threshold)[0]
+        if not hot.size:
+            return
+        if hot.size > MIGRATE_MAX:
+            over = hot.size - MIGRATE_MAX
+            hot = np.sort(hot[np.argsort(-ranks[hot],
+                                         kind="stable")[:MIGRATE_MAX]])
+            self.admissions_deferred += over
+            if self.metrics is not None:
+                self.metrics.tier_admissions_deferred.inc(over)
+        self.migrate(engine, khs[hot], ranks[hot])
+
+    def _ranks(self, khs: np.ndarray):
+        """i64[n] rank of each key by the feed, or None where the feed
+        fails (analytics only: nothing is admitted)."""
+        try:
+            if self.rank_batch is not None:
+                return np.asarray(self.rank_batch(khs), np.int64)
+            return np.fromiter((self.rank_fn(int(k)) for k in khs),
+                               np.int64, count=len(khs))
+        except Exception:  # pragma: no cover - analytics only
+            return None
+
+    def _faulted(self, point: str) -> bool:
+        """The fault point ``point`` fired (chaos runs): this one row's
+        migration is abandoned, the row stays in its source tier."""
+        if self._fault is None:
+            return False
+        try:
+            self._fault(point)
+            return False
+        except Exception:  # FaultInjected
+            self.migrations_aborted += 1
+            if self.metrics is not None:
+                self.metrics.tier_migrations_aborted.inc()
+            return True
+
+    def migrate(self, engine, khs, ranks) -> int:
+        """ONE migration pass: the cold rows of ``khs`` (ranks
+        ``ranks``, both in the caller's order) moved to the device
+        tier, each evicting the coldest resident row of its probe
+        window back to host where no slot is free.  Returns the keys
+        promoted.  Phase `tier.migrate`, one sample a pass.
+
+        The device is read once and written once (``engine.tier_image``:
+        the keys' distinct buckets, fetched behind whatever wave is
+        already launched — so a victim's row holds what that wave did
+        to it — and resolved on ONE host image, promotees that share a
+        bucket too); the cold store gives the promotees' rows in one
+        call, takes the victims' in one and drops the promotees in one.
+        Conservation-exact: all eight value columns (including
+        t_ms/created_at lineage and expire_at) move verbatim in both
+        directions; a victim is adopted cold BEFORE the device write
+        and a promotee dropped from the cold store AFTER it, so no
+        failure between leaves a row in neither tier; runs under the
+        engine lock, so no request can observe a key mid-flight.  A row
+        outside the engine's step domain stays cold; a fault at
+        `tier_promote` / `tier_demote` leaves that one row where it
+        was."""
+        timed = phase("tier.migrate", self.metrics).begin(
+            at=time.perf_counter())
+        try:
+            return self._migrate(engine, np.asarray(khs, np.uint64),
+                                 np.asarray(ranks, np.int64))
+        finally:
             timed.end(at=time.perf_counter())
 
-    def _ranked_over(self, khs, thr: int):
-        """(key, rank) of ``khs`` at or over ``thr``, read a key at a
-        time as each is reached (``rank_fn`` alone: the tests' feeds)."""
-        for kh in khs:
-            try:
-                r = self.rank_fn(int(kh))
-            except Exception:  # pragma: no cover - analytics only
-                return
-            if r >= thr:
-                yield int(kh), r
+    def _migrate(self, engine, khs: np.ndarray, ranks: np.ndarray) -> int:
+        _, first = np.unique(khs, return_index=True)
+        if first.size != khs.size:  # a key twice: once, where it was first
+            first.sort()
+            khs, ranks = khs[first], ranks[first]
+        with self._mu:
+            keep, rows = self._store.take_batch(khs)
+        admissible = getattr(engine, "tier_rows_admissible", None)
+        if admissible is not None and keep.any():
+            keep &= admissible(rows)  # outside the step domain (Pallas)
+        if self._fault is not None:
+            for i in np.nonzero(keep)[0]:
+                if self._faulted("tier_promote"):
+                    keep[i] = False
+        if not keep.any():
+            return 0
+        khs, ranks, rows = khs[keep], ranks[keep], rows[keep]
+        img = engine.tier_image(khs)
+        every = np.arange(len(khs))
+        placed = img.place(every, rows)
+        demoted = np.empty(0, np.uint64)
+        need = every[~placed]
+        if need.size:
+            victims = self._pick_victims(img, need, khs, ranks)
+            need, victims = need[victims != 0], victims[victims != 0]
+        if need.size:
+            got, vrows = img.take(need, victims)
+            need, demoted = need[got], victims[got]
+            with self._mu:
+                self._store.put_batch(demoted, vrows[got])
+            placed[need] = img.place(need, rows[need])
+        img.commit()
+        promoted = khs[placed]
+        with self._mu:
+            self._store.take_batch(promoted, remove=True)
+        self._count(promoted, ranks[placed], demoted)
+        return len(promoted)
+
+    def _count(self, promoted, ranks, demoted) -> None:
+        """One pass's counters, once (``inc(n)``), and its
+        flight-recorder events, capped."""
+        self.promotions += len(promoted)
+        self.demotions += len(demoted)
+        m, rec = self.metrics, self.recorder
+        if m is not None:
+            if len(promoted):
+                m.tier_promotions.inc(len(promoted))
+            if len(demoted):
+                m.tier_demotions.inc(len(demoted))
+        if rec is not None:
+            for kh, r in zip(promoted[:_EVENTS_A_PASS].tolist(),
+                             ranks[:_EVENTS_A_PASS].tolist()):
+                rec.record("tier_promote", khash=f"0x{kh:016x}", rank=r,
+                           of_pass=len(promoted))
+            for kh in demoted[:_EVENTS_A_PASS].tolist():
+                rec.record("tier_demote", khash=f"0x{kh:016x}",
+                           of_pass=len(demoted))
+        self._gauge()
+
+    def _pick_victims(self, img, need: np.ndarray, khs: np.ndarray,
+                      ranks: np.ndarray) -> np.ndarray:
+        """u64[k]: for each promotee ``need`` (indices into ``khs``)
+        that found no free slot, the resident key of its probe window
+        to evict, 0 where there is none.  The coldest by the rank feed,
+        and STRICTLY colder than its promotee by that same measure;
+        the LAST of its window among equals (a restore gives a bucket's
+        slots to its rows in snapshot order, hottest first: among keys
+        the feed cannot tell apart the last slot holds the coldest);
+        never a key of this pass (its row was just placed), never one
+        another promotee of the pass already took, never a
+        replica-pinned key (its device row is the home copy of
+        coherence machinery above us), and none at all where a fault
+        fires at `tier_demote`.  Promotees choose in the caller's
+        order, so two that share a window take its two coldest.  The
+        candidates' ranks are ONE read of the feed."""
+        occ = img.occupants(need)  # [k, window]
+        out = np.zeros(len(need), np.uint64)
+        cand = self._ranks(occ.reshape(-1))
+        if cand is None:
+            return out
+        never = np.iinfo(np.int64).max
+        cand = np.where((occ == 0) | np.isin(occ, khs), never,
+                        cand.reshape(occ.shape))
+        cand[cand >= ranks[need][:, None]] = never  # not strictly colder
+        skip = self._skip_victim
+        open_ = np.ones(len(need), bool)
+        while open_.any():
+            at = np.nonzero(open_)[0]
+            # (the LAST of the minimum: argmin over the window reversed)
+            best = occ.shape[1] - 1 - cand[at, ::-1].argmin(axis=1)
+            some = cand[at, best] != never
+            open_[at[~some]] = False  # everything left is at least as hot
+            at, best = at[some], best[some]
+            pick = occ[at, best]
+            # the first promotee to ask for a key has it; the others
+            # look again without it
+            gone = []
+            for j in np.sort(np.unique(pick, return_index=True)[1]):
+                r, v = at[j], pick[j]
+                if skip is not None and skip(int(v)):
+                    gone.append(v)  # pinned: nobody's, r looks again
+                    continue
+                open_[r] = False
+                if not self._faulted("tier_demote"):
+                    out[r] = v
+                    gone.append(v)
+            if gone:
+                cand[np.isin(occ, gone)] = never
+        return out
 
     def promote(self, engine, kh: int, rank: int) -> bool:
-        """Migrate one cold row to the device tier, evicting the
-        coldest resident row of its probe window back to host when no
-        slot is free.  Conservation-exact: all eight value columns
-        (including t_ms/created_at lineage and expire_at) move verbatim
-        in both directions; runs under the engine lock, so no request
-        can observe the key mid-flight."""
-        with self._mu:
-            row = self._store.get(int(kh))
-        if row is None:
-            return False
-        if not getattr(engine, "tier_row_admissible", _always)(row):
-            return False  # outside the engine's step domain (Pallas)
-        try:
-            if self._fault is not None:
-                self._fault("tier_promote")
-        except Exception:  # FaultInjected: admission aborts, row stays cold
-            self.migrations_aborted += 1
-            if self.metrics is not None:
-                self.metrics.tier_migrations_aborted.inc()
-            return False
-        karr = np.array([kh], np.uint64)
-        if not self._upsert(engine, karr, row):
-            victim = self._pick_victim(engine, kh, rank)
-            if victim is None:
-                return False
-            if not self.demote(engine, victim):
-                return False
-            if not self._upsert(engine, karr, row):
-                # the freed slot is in kh's own probe window, so this
-                # is unreachable; tolerate it without losing the row
-                return False
-        with self._mu:
-            self._store.pop(int(kh))
-        self.promotions += 1
-        if self.metrics is not None:
-            self.metrics.tier_promotions.inc()
-        if self.recorder is not None:
-            self.recorder.record("tier_promote", khash=f"0x{kh:016x}",
-                                 rank=int(rank))
-        self._gauge()
-        return True
+        """``migrate`` for one key (the tests' and the chaos tools'
+        door): True where the key's row moved to the device tier."""
+        return self.migrate(engine, [kh], [rank]) > 0
 
     def demote(self, engine, kh: int) -> bool:
-        """Migrate one device row back to the cold tier (eviction half
-        of an admission, or a cap-overflow demotion): gather the row,
-        adopt it cold, then clear the device slot.  Byte-exact handoff;
-        under the engine lock."""
-        try:
-            if self._fault is not None:
-                self._fault("tier_demote")
-        except Exception:  # FaultInjected: eviction aborts
-            self.migrations_aborted += 1
-            if self.metrics is not None:
-                self.metrics.tier_migrations_aborted.inc()
+        """One device row moved back to the cold tier, a cap-overflow
+        demotion: the eviction half of a pass, on its own image.
+        Byte-exact handoff; under the engine lock."""
+        if self._faulted("tier_demote"):
             return False
         karr = np.array([kh], np.uint64)
-        found, vcols = engine.gather_rows(karr)
-        if not found[0]:
+        img = engine.tier_image(karr)
+        got, vrows = img.take(np.zeros(1, np.int64), karr)
+        if not got[0]:
             return False
-        row = tuple(int(vcols[f][0]) for f in ROW_COLS)
         with self._mu:
-            self._store.put(int(kh), row)
-        engine.remove_rows(karr)
-        self.demotions += 1
-        if self.metrics is not None:
-            self.metrics.tier_demotions.inc()
-        if self.recorder is not None:
-            self.recorder.record("tier_demote", khash=f"0x{kh:016x}")
-        self._gauge()
+            self._store.put_batch(karr, vrows)
+        img.commit()
+        self._count(karr[:0], karr[:0], karr)
         return True
-
-    def _pick_victim(self, engine, kh: int, rank: int):
-        """The coldest (minimum sketch rank) resident key in ``kh``'s
-        probe window — strictly colder than the promotee, never a
-        replica-pinned key (its device row is the home copy of tiered
-        coherence machinery above us)."""
-        probe = getattr(engine, "probe_occupant_keys", None)
-        if probe is None or self.rank_fn is None:
-            return None
-        occ = probe(int(kh))
-        skip = self._skip_victim
-        cands = []
-        for k in occ:
-            ik = int(k)
-            if ik == 0 or ik == int(kh):
-                continue
-            if skip is not None and skip(ik):
-                continue
-            cands.append(ik)
-        if not cands:
-            return None
-        if self.rank_batch is not None:  # one sketch-lock acquisition
-            ranks = self.rank_batch(cands)
-        else:
-            ranks = [self.rank_fn(k) for k in cands]
-        best = min(range(len(cands)), key=ranks.__getitem__)
-        if ranks[best] >= rank:
-            return None  # everything resident is at least as hot
-        return cands[best]
-
-    @staticmethod
-    def _upsert(engine, karr: np.ndarray, row) -> bool:
-        cols = {}
-        for f, v in zip(ROW_COLS, row):
-            cols[f] = np.array([v], np.int32 if f == "meta" else np.int64)
-        return int(engine.upsert_rows(karr, cols)) > 0
 
     def _gauge(self) -> None:
         m = self.metrics
@@ -700,7 +826,3 @@ class TierController:
             with self._mu:
                 n = len(self._store)
             m.tier_cold_keys.set(n)
-
-
-def _always(_row) -> bool:
-    return True

@@ -194,12 +194,25 @@ PHASE_CATALOG: Dict[str, str] = {
                     "with GUBER_TIER_COLD=1",
     "tier.resolve": "tiering.resolve, inside wave.scatter: the cold "
                     "lane of a wave that has cold rows — each applied "
-                    "to the host store in Python, then the admission of "
-                    "the keys served (tier.migrate inside)",
-    "tier.migrate": "tiering._admit, inside tier.resolve: one "
-                    "admission tried (promote) — the row's upsert into "
-                    "its device bucket, the victim's pick and demotion "
-                    "where the bucket is full",
+                    "to the host store (one C++ pass over the native "
+                    "store), then the admission of the keys served "
+                    "(tier.migrate inside)",
+    "tier.migrate": "tiering.migrate, inside tier.resolve: ONE "
+                    "migration pass — all the keys a wave's admission "
+                    "handed over (at most MIGRATE_MAX): their cold rows "
+                    "read, their device buckets fetched (tier.fetch), "
+                    "promotees placed and victims picked and taken out "
+                    "on that host image, the victims put cold, the "
+                    "image written back (tier.write), the promotees "
+                    "dropped from the cold store; one sample a pass",
+    "tier.fetch": "pallas_engine._BucketImage, inside tier.migrate: "
+                  "the pass's distinct buckets gathered device → host "
+                  "at a padded length, ONE blocking round trip that "
+                  "queues behind whatever wave is already launched",
+    "tier.write": "pallas_engine._BucketImage.commit, inside "
+                  "tier.migrate: the changed image scattered host → "
+                  "device, once (the dispatch returns; the next launch "
+                  "orders after it)",
     "wave.resolve": "dispatcher: the future.set_result loop",
     "wave.end": "_wave_end + the analytics tap",
     # handler threads, per call

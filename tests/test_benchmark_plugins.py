@@ -77,8 +77,8 @@ def test_nothing_in_run_or_harness_names_a_plugin():  # noqa: F811
     names = {n for kind in ("algorithms", "keys", "arrivals")
              for n in plugins.names(kind)}
     assert {"leaky_bucket", "token_bucket", "token_bucket_gregorian",
-            "uniform", "zipf", "zipf_space", "poisson", "grid",
-            "burst"} <= names
+            "uniform", "zipf", "zipf_space", "zipf_drift", "poisson",
+            "grid", "burst"} <= names
     words = "|".join(sorted(
         {re.escape(w) for n in names for w in (n, n.replace("_", " "),
                                                n.split("_")[0])}
@@ -158,7 +158,7 @@ def test_an_xla_reader_is_found_and_reads_nothing_where_nothing_is(name):
     # (the sweep's phase is the Pallas engine's too: cell 10, whose
     # tiered waves ask for a sweep of their own, reads it since PR 41)
     assert entry["workloads"] == [XLA_CELL] + (
-        ["r1-churn-100m"] if name == "sweep_ms" else [])
+        ["r1-churn-100m", "r1-drift-100m"] if name == "sweep_ms" else [])
     read = plugins.load("layer_metrics", name).read
     ctx = {"m0": {}, "m1": {}, "tm0": {}, "tm1": {}, "trace": {"devices": 0},
            "_xla_step_modules": (0.0, 0), "device_kind": "TPU v5 lite",
@@ -400,7 +400,7 @@ def test_wave_native_route_share_on_a_hand_made_pair_of_scrapes(case):
         "source": "program_counter", "layer": "engine",
         "moves": "decisions_per_s",
         # cell 10: a tier bound, so every one-shard wave is a sorted wave
-        "workloads": [R4_CELL, G4_CELL, "r1-churn-100m"]}
+        "workloads": [R4_CELL, G4_CELL, "r1-churn-100m", "r1-drift-100m"]}
     m0, m1, want = NATIVE_SHARE[case]
     got = plugins.load("layer_metrics", "wave_native_route_share").read(
         dict(_nothing(), m0=m0, m1=m1))

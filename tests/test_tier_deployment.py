@@ -154,7 +154,7 @@ def _with_out_of_domain_rows(snap: dict, at) -> dict:
 
 def test_rows_outside_the_kernels_domain_are_adopted_not_dropped():
     """A tiered daemon's snapshot holds rows the kernel cannot serve
-    (the tier answers them, ``tier_row_admissible`` keeps them cold): a
+    (the tier answers them, ``tier_rows_admissible`` keeps them cold): a
     restore hands them to the tier, and without a tier counts them in
     ``dropped_rows`` AND the gauge."""
     _, _, _, pop = _cell()
@@ -402,11 +402,15 @@ def test_the_deployment_and_its_cell_are_found_by_name():
     manifest = run.load_json(REPO, "BENCHMARK.json")
     for m in manifest["per_layer"]:
         if m["name"] in NEW_READERS:
-            assert m["workloads"] == [CELL] and m["layer"] == "cold tier"
-    assert manifest["per_layer"][-1] == {
+            # the tier's cells, in the order they were added (ISSUE 46
+            # appended the waking-tenant cell to every list this one is on)
+            assert m["workloads"] == [CELL, "r1-drift-100m"]
+            assert m["layer"] == "cold tier"
+    assert next(m for m in manifest["per_layer"]
+                if m["name"] == "tier_launches_per_wave") == {
         "name": "tier_launches_per_wave", "unit": "launches",
         "better": "lower", "source": "program_counter", "layer": "engine",
-        "moves": "decisions_per_s", "workloads": [CELL]}
+        "moves": "decisions_per_s", "workloads": [CELL, "r1-drift-100m"]}
 
 
 @pytest.mark.parametrize("name", NEW_READERS)
@@ -474,7 +478,10 @@ def test_launches_per_wave_sums_the_routes(routes, want):
     assert read({"m0": m0, "m1": m1, "seconds": 4.0}) == want
 
 
-def test_the_cpu_rehearsal_of_the_cell_is_correct_and_reads_the_tier():
+@pytest.fixture(scope="module")
+def traced_rehearsal():
+    """The cell's CPU rehearsal under ``--trace 1``, run once: (the
+    process, its result line)."""
     p = subprocess.run(
         [sys.executable, os.path.join(REPO, "benchmark", "run.py"),
          "--workload", CELL, "--seed", str(SEED), "--seconds", "3",
@@ -483,7 +490,31 @@ def test_the_cpu_rehearsal_of_the_cell_is_correct_and_reads_the_tier():
     assert p.returncode == 0, p.stderr[-3000:]
     lines = p.stdout.strip().splitlines()
     assert len(lines) == 1
-    line = json.loads(lines[0])
+    return p, json.loads(lines[0])
+
+
+def test_the_traced_rehearsal_prints_every_metric_listed_for_the_cell(
+        traced_rehearsal):
+    """The check PR 45 failed in this cell (ISSUE 46): ``benchmark/run.py``
+    LEAVES OUT a per-layer metric whose reader returns ``None``, and the
+    driver then finds a listed metric missing — so every metric
+    ``BENCHMARK.json`` lists for this cell, or lists for none, has to
+    read something here, where a window may hold no migration at all.
+    (A ``device_trace`` metric needs a device plane, which the CPU
+    rehearsal's profile has none of.)  The waking-tenant cell's twin:
+    ``tests/test_tier_migration.py``."""
+    _, line = traced_rehearsal
+    listed = {m["name"] for m in run.load_cell(CELL, True)["per_layer"]
+              if m["source"] != "device_trace"}
+    assert listed - set(line["metrics"]) == set()
+    # the two readers of the migration pass are the other tier cell's
+    assert not {"tier_migrate_ms", "tier_rows_per_migration"} & (
+        listed | set(line["metrics"]))
+
+
+def test_the_cpu_rehearsal_of_the_cell_is_correct_and_reads_the_tier(
+        traced_rehearsal):
+    p, line = traced_rehearsal
     assert line["correct"] is True, p.stderr[-3000:]
     assert all(got <= limit for got, which, limit
                in line["checks"].values() if which == "at most")

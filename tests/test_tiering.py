@@ -262,7 +262,8 @@ def test_sync_retry_reads_a_key_the_next_wave_inserted(monkeypatch, native):
     k = int(kh[0])
     lock = threading.Lock()
     tok_n = small.launch_packed(b, kh, NOW + 1000)  # errs: window full
-    victim = next(int(v) for v in small.probe_occupant_keys(k) if v)
+    victim = next(int(v) for v in small.probe_occupants(
+        np.array([k], np.uint64))[0] if v)
     assert tc.demote(small, victim)
     tok_n1 = small.launch_packed(b, kh, NOW + 1000)  # inserts the key
     assert len(launches) == 2
@@ -595,8 +596,8 @@ def test_sketch_counts_of_an_array_is_count_of_key_by_key(state):
 
 def test_admission_reads_its_ranks_once_a_wave_in_order_of_service():
     """``_admit`` with a batched feed: ONE call for all the served keys,
-    then ``promote`` for those at or over the threshold, in the order
-    they were served."""
+    then ONE ``migrate`` pass (ISSUE 46) for those at or over the
+    threshold, in the order they were served."""
     class _E:
         tier = None
 
@@ -606,10 +607,11 @@ def test_admission_reads_its_ranks_once_a_wave_in_order_of_service():
         _E(), rank_fn=lambda kh: 1 / 0, promote_threshold=8,
         rank_batch=lambda khs: (asked.append(list(map(int, khs)))
                                 or np.array([ranks[int(k)] for k in khs])))
-    tc.promote = lambda engine, kh, rank: promoted.append((kh, rank))
+    tc.migrate = lambda engine, khs, ranks: promoted.append(
+        list(zip(khs.tolist(), ranks.tolist())))
     tc._admit(_E(), np.array([14, 12, 11, 13], np.uint64))
     assert asked == [[14, 12, 11, 13]]
-    assert promoted == [(14, 100), (11, 9), (13, 8)]
-    assert all(type(v) is int for pair in promoted for v in pair)
+    assert promoted == [[(14, 100), (11, 9), (13, 8)]]
+    assert all(type(v) is int for pair in promoted[0] for v in pair)
     tc._admit(_E(), [])
     assert len(asked) == 1

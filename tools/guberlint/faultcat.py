@@ -1,7 +1,8 @@
 """faultcat — faultpoint catalog consistency.
 
 Every instrumented faultpoint site (``self._fault("x")``,
-``fs.fire("x")``, ``fs.should("x")``, ``self._fault_point("x")``) must
+``fs.fire("x")``, ``fs.should("x")``, ``self._fault_point("x")``,
+``self._faulted("x")`` — tiering.py's "did it fire" wrapper) must
 name a point in ``faults.FAULT_POINTS``, and every cataloged point
 must still have at least one site — so the chaos matrix can never arm
 a point that silently tests nothing, and a removed call site can't
@@ -19,7 +20,7 @@ from .engine import LintContext
 PASS_ID = "faultcat"
 
 _SITE_FUNCS = {"fire", "should", "_fault", "_fault_point",
-               "_fault_tick"}
+               "_fault_tick", "_faulted"}
 
 
 def _catalog(ctx: LintContext):

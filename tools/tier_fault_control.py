@@ -1,4 +1,5 @@
-"""A control for the tiered deployment (cell ``r1-churn-100m``): ONE fault
+"""A control for the tiered deployments (cells ``r1-churn-100m`` and
+``r1-drift-100m``): ONE fault
 of the cold tier's own guarantee — "a key's bucket is never forgotten or
 forked, whichever tier holds the row" — put into the program, and the
 benchmark's cell run on it.  The run must end NOT correct: that shows the
@@ -17,6 +18,13 @@ in the row's place (what upstream's LRU does to an evicted key).
 lost, so the key's next request is answered from the state before it.
 (On the native store's lane, where ONE C++ pass applies a wave, "request"
 reads "key of a wave that the store holds": ``inject``.)
+``pass-forget`` / ``pass-fork`` put the same two faults INSIDE a migration
+pass (``tiering.py › TierController.migrate``; ISSUE 46, cell
+``r1-drift-100m``, whose work is migration) and leave the cold lane
+alone: every ``every``-th row a pass promotes is dropped from the host
+store WITHOUT being placed on the device (forgotten: in neither tier),
+or is placed as it was before its last hit (forked: the hit that the
+cold lane had just applied to it, in the same wave, is lost).
 The last line on standard error says how many rows were faulted and how
 many of them were LIVE at the request's clock: forgetting a bucket that
 has run out changes no answer (an expired row IS a missing one), so
@@ -41,9 +49,12 @@ def inject(fault: str, every: int) -> dict:
     the pass, or given its old row back after it."""
     from gubernator_tpu import tiering
 
-    if fault not in ("forget", "fork"):
-        raise SystemExit(f"no fault {fault!r}: forget or fork")
+    if fault not in ("forget", "fork", "pass-forget", "pass-fork"):
+        raise SystemExit(f"no fault {fault!r}: forget, fork, pass-forget "
+                         "or pass-fork")
     done = {"held": 0, "faults": 0, "live": 0}
+    if fault.startswith("pass-"):
+        return inject_in_pass(fault[5:], every, done)
     expire_at = tiering.ROW_COLS.index("expire_at")
     apply = tiering._host_apply
 
@@ -89,6 +100,58 @@ def inject(fault: str, every: int) -> dict:
     return done
 
 
+def inject_in_pass(fault: str, every: int, done: dict) -> dict:
+    """Patch the migration pass: the image a pass works on
+    (``engine.tier_image``) is handed over with a ``place`` that faults
+    every ``every``-th row it is given.  A promotee was served cold in
+    this very wave, so its row is live at the pass: every fault can
+    show."""
+    import numpy as np
+
+    from gubernator_tpu import tiering
+
+    remaining = tiering.ROW_COLS.index("remaining")
+    limit = tiering.ROW_COLS.index("limit")
+    migrate = tiering.TierController._migrate
+
+    def faulty_migrate(tc, engine, khs, ranks):
+        real = engine.tier_image
+
+        def image(keys):
+            img = real(keys)
+            place = img.place
+
+            def faulty_place(sel, rows):
+                sel = np.asarray(sel)
+                rows = np.array(rows, copy=True)
+                picked = np.zeros(len(sel), bool)
+                for i in range(len(sel)):
+                    done["held"] += 1
+                    if done["held"] % every == 0:
+                        picked[i] = True
+                done["faults"] += int(picked.sum())
+                done["live"] += int(picked.sum())
+                if fault == "fork":  # its last hit is lost
+                    lost = picked & (rows[:, remaining] < rows[:, limit])
+                    rows[lost, remaining] += 1
+                    return place(sel, rows)
+                out = np.ones(len(sel), bool)  # "placed": dropped cold
+                out[~picked] = place(sel[~picked], rows[~picked])
+                return out
+
+            img.place = faulty_place
+            return img
+
+        engine.tier_image = image
+        try:
+            return migrate(tc, engine, khs, ranks)
+        finally:
+            del engine.tier_image  # the class's own again
+
+    tiering.TierController._migrate = faulty_migrate
+    return done
+
+
 def main() -> int:
     fault, every = sys.argv[1], int(sys.argv[2])
     if sys.argv[3] != "--":
@@ -98,9 +161,11 @@ def main() -> int:
     from benchmark import run
 
     rc = run.main()
+    what = ("rows a migration pass placed" if fault.startswith("pass-")
+            else "requests that found their row cold")
     print(f"tier fault {fault!r}: {done['faults']} of {done['held']} "
-          f"requests that found their row cold, {done['live']} of them "
-          "a row still live", file=sys.stderr, flush=True)
+          f"{what}, {done['live']} of them a row still live",
+          file=sys.stderr, flush=True)
     return rc
 
 

@@ -27,7 +27,7 @@ log = logging.getLogger("gubernator_tpu")
 #: lints the prose docs against it — so the operator surface can never
 #: drift from the code.  Keep entries alphabetized.
 ENV_REGISTRY: Dict[str, str] = {
-    "GUBER_ADMISSION_LIMIT": "dispatcher ingress bound in rows; 0 disables (default 8×max_wave)",
+    "GUBER_ADMISSION_LIMIT": "dispatcher ingress bound in rows; 0 disables (default 65536)",
     "GUBER_ADVERTISE_ADDRESS": "address peers should dial for this daemon",
     "GUBER_ANALYTICS": "0 disables the key-analytics subsystem (sketch + phase ledger)",
     "GUBER_BATCH_LIMIT": "max requests per peer-forward batch",
@@ -129,7 +129,7 @@ ENV_REGISTRY: Dict[str, str] = {
     "GUBER_TOPK": "heavy-hitter sketch tracked-key count K",
     "GUBER_TRACE_SAMPLE": "head-sampling rate for the trace plane (0 disables)",
     "GUBER_TRACE_SPANS": "span-recorder ring capacity (completed spans kept)",
-    "GUBER_WAVE_BUCKETS": "comma-separated wave-size buckets for check_packed",
+    "GUBER_WAVE_BUCKETS": "comma-separated wave-size buckets for check_packed (default B,8B; B,8B,16B on the one-chip Mosaic engine; B = batch_rows)",
 }
 
 _DUR_RE = re.compile(r"(\d+(?:\.\d+)?)(ns|us|µs|ms|s|m|h)")

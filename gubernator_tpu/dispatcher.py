@@ -176,10 +176,17 @@ class Dispatcher:
     #: depth 1 degenerates to launch-then-sync, i.e. no overlap).
     PIPELINE_DEPTH = 2
 
+    #: default wave cap in rows, and the least an instance sets: it
+    #: raises the cap to what ONE launch of its engine holds
+    #: (``engine.wave_capacity``), never lowers it
+    MAX_WAVE = 8192
+
     #: default admission bound: rows queued (not yet launched) before
     #: ingress sheds with RESOURCE_EXHAUSTED.  GUBER_ADMISSION_LIMIT
     #: overrides; 0 disables the bound (deadline/drain shed remain).
-    ADMISSION_LIMIT_WAVES = 8
+    #: In ROWS, whatever ``max_wave`` is: what an overloaded daemon
+    #: sheds does not move with the size of its engine's largest launch
+    ADMISSION_LIMIT_ROWS = 65536
 
     #: fan-in link bound (ISSUE 12): a wave span records at most this
     #: many OTHER batched requests' (trace, span) pairs as attributes
@@ -196,7 +203,7 @@ class Dispatcher:
     #: whose GLOBAL rows route on the handler threads)
     CALL_SAMPLE = 8
 
-    def __init__(self, engine, max_wave: int = 8192,
+    def __init__(self, engine, max_wave: int = MAX_WAVE,
                  max_delay_ms: float = 0.2,
                  lock: Optional[threading.Lock] = None,
                  metrics=None, recorder=None, clock=time.monotonic,
@@ -218,7 +225,7 @@ class Dispatcher:
         # after the first before launching the wave.  GUBER_COALESCE_US
         # (microseconds) overrides the constructor default; malformed
         # or negative values keep it.  _drain_wave skips the wait
-        # entirely when the queue already holds >= max_wave rows.
+        # entirely when the queue already held >= MAX_WAVE rows.
         coalesce_env = os.environ.get("GUBER_COALESCE_US", "")
         if coalesce_env:
             try:
@@ -288,10 +295,9 @@ class Dispatcher:
         adm_env = os.environ.get("GUBER_ADMISSION_LIMIT", "")
         try:
             self.admission_limit = (int(adm_env) if adm_env
-                                    else self.ADMISSION_LIMIT_WAVES
-                                    * self.max_wave)
+                                    else self.ADMISSION_LIMIT_ROWS)
         except ValueError:
-            self.admission_limit = self.ADMISSION_LIMIT_WAVES * self.max_wave
+            self.admission_limit = self.ADMISSION_LIMIT_ROWS
         self._queued_rows = 0  # guarded-by: self._submit_mu
         #: drain flag: single racy bool write in drain(), lock-free reads
         self._draining = False
@@ -993,9 +999,10 @@ class Dispatcher:
         up to the coalescing window (GUBER_COALESCE_US, bounded by
         max_wave total requests) so bursty concurrent callers share the
         next device launch.  Jobs already queued are taken greedily
-        FIRST: when the backlog alone fills max_wave rows, the wave
-        launches with NO coalescing wait at all — the window exists to
-        catch stragglers, not to tax a saturated queue.
+        FIRST: when the backlog alone fills the default cap (MAX_WAVE
+        rows; max_wave itself where that is smaller), the wave launches
+        with NO coalescing wait at all — the window exists to catch
+        stragglers, not to tax a saturated queue.
 
         `worker.wait` is the block for the first job, `worker.coalesce`
         everything from there to the return: with the wave.* phases
@@ -1036,7 +1043,13 @@ class Dispatcher:
                 job = self._queue.get_nowait()
                 self._dequeued(job)
             except queue.Empty:
-                if self.max_delay_s <= 0:
+                if self.max_delay_s <= 0 or total >= self.MAX_WAVE:
+                    # the window catches a SMALL wave's stragglers; a
+                    # wave that fills the default cap had a backlog and
+                    # launches at once, as it did when that cap cut it:
+                    # the timed get gives the GIL up, and winning it
+                    # back from ~30 handlers cost a saturated worker
+                    # ~1 ms a wave (PERF.md §6, PR 49)
                     break
                 if deadline is None:
                     deadline = time.monotonic() + self.max_delay_s

@@ -248,7 +248,16 @@ class V1Instance:
         # device launches instead of serializing on the engine lock
         # (the worker-pool analog, see dispatcher.py).  Wave telemetry
         # lands on this instance's registry + recorder.
-        self.dispatcher = Dispatcher(engine, lock=self._engine_mu,
+        # A wave takes what ONE launch of the engine holds
+        # (``wave_capacity``: its ladder's top rung) and never less
+        # than the default cap: a daemon whose ladder is set small
+        # coalesces as it always did, its waves splitting into several
+        # launches.
+        self.dispatcher = Dispatcher(engine,
+                                     max_wave=max(
+                                         Dispatcher.MAX_WAVE, getattr(
+                                             engine, "wave_capacity", 0)),
+                                     lock=self._engine_mu,
                                      metrics=self.metrics,
                                      recorder=self.recorder,
                                      analytics=analytics,
@@ -1071,7 +1080,7 @@ class V1Instance:
                 reason = "global"
             elif b & int(Behavior.MULTI_REGION):
                 reason = "multi_region"
-            elif parsed["n"] > self.engine.wave_buckets[-1]:
+            elif parsed["n"] > self.engine.wave_capacity:
                 reason = "too_large"
             elif b & int(Behavior.DURATION_IS_GREGORIAN):
                 reason = "gregorian"

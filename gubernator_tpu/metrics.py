@@ -32,10 +32,13 @@ _BUCKETS = (.0005, .001, .0025, .005, .01, .025, .05, .1, .25, .5, 1.0, 2.5)
 #: watchdog exists for is invisible.
 _WAVE_DURATION_BUCKETS = _BUCKETS + (10.0, 30.0, 60.0, 120.0, 300.0, 600.0)
 
-#: requests per coalesced wave: 1 (a lone call) up to max_wave (8192
-#: default) and beyond for merged packed columns
+#: requests per coalesced wave: 1 (a lone call) up to max_wave — what
+#: one launch of the engine holds, 8,192 rows at the least — and beyond
+#: for merged packed columns; the default ladders' rungs (1,024 / 8,192
+#: / 16,384) and the next doubling are bounds, so the share of waves on
+#: each can be read (tools/wave_rungs.py)
 _WAVE_SIZE_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 1024, 4096,
-                      16384, 65536)
+                      8192, 16384, 32768, 65536)
 
 
 class Metrics:

@@ -635,6 +635,21 @@ class PallasServingEngine(FusedServingMixin, ShardedEngine):
 
     _flavor = "pallas"
 
+    def _default_wave_buckets(self) -> tuple:
+        """On ONE chip the ladder goes on to 16·B rows: a saturated
+        daemon's wave takes the calls queued when the worker drains
+        (10–11 of 32 callers' 1,000-row calls), so what a wave costs
+        before its first row — a launch's round trip, the worker's
+        fixed work — is paid once for them, on a device that idles four
+        fifths of the time.  A 32·B rung was measured too and served no
+        better: a third of a tiered daemon's waves rode it half empty
+        (PERF.md §6, PR 49).  On a mesh the 8·B program already holds
+        n·8·B slots and the GLOBAL fold shares the worker's loop: it
+        keeps the base ladder."""
+        if self.n == 1:
+            return (self.B, self.B * 8, self.B * 16)
+        return super()._default_wave_buckets()
+
     def _init_table_and_step(self) -> None:
         if self.cap_local < ps.SLOTS or (self.cap_local
                                          & (self.cap_local - 1)):

@@ -394,7 +394,9 @@ class ShardedEngine:
         #: bursts amortize launch cost in one big wave instead of
         #: ceil(n/B) small ones (the front-door throughput lever —
         #: VERDICT r1 item 5).  Each bucket is one compiled program;
-        #: warmup() pre-compiles them all.
+        #: warmup() pre-compiles them all.  The top rung is what ONE
+        #: launch can carry (``wave_capacity``): the instance caps its
+        #: dispatcher's waves by it.
         import os as _os
         env_buckets = _os.environ.get("GUBER_WAVE_BUCKETS", "")
         if wave_buckets:
@@ -403,7 +405,7 @@ class ShardedEngine:
             self.wave_buckets = tuple(sorted(
                 {int(x) for x in env_buckets.split(",") if x.strip()}))
         else:
-            self.wave_buckets = (batch_per_shard, batch_per_shard * 8)
+            self.wave_buckets = self._default_wave_buckets()
         #: per-shard capacity ceiling for on-device auto-grow when probe
         #: windows stay exhausted after a sweep (0 = disabled).  The
         #: reference's LRU never fails an insert; with auto-grow on,
@@ -446,6 +448,22 @@ class ShardedEngine:
         #: wave and serves them (plus residual table-full rows) from
         #: the host cold tier on the way out
         self.tier = None  # lock-free: set once at instance wiring, read-only after
+
+    def _default_wave_buckets(self) -> tuple:
+        """The ladder where the operator set none (neither the
+        constructor's ``wave_buckets`` nor GUBER_WAVE_BUCKETS): a lone
+        call's rung and the coalesced wave's.  The XLA step keeps the
+        wave at 8·B: it is bound by the device, costs per index, and
+        its ``while`` walks the longest duplicate segment of a wave —
+        a longer wave can only lengthen it (PERF.md §5, cell 6)."""
+        return (self.B, self.B * 8)
+
+    @property
+    def wave_capacity(self) -> int:
+        """Rows ONE launch can carry: the ladder's top rung.  A wave
+        over it splits into several launches (``_build_waves``), so it
+        is what the instance caps its dispatcher's waves at."""
+        return self.wave_buckets[-1]
 
     def _init_table_and_step(self) -> None:
         """Build self.state + self._step (subclass hook: the Pallas
@@ -586,7 +604,7 @@ class ShardedEngine:
         mono = clock_order(calls) == list(range(len(calls)))
         lease = None
         if (self.n == 1 and self.tier is None and mono
-                and 0 < total <= self.wave_buckets[-1]):
+                and 0 < total <= self.wave_capacity):
             lease = self.wave_pool.lease(
                 next(b for b in self.wave_buckets if total <= b),
                 rows=total)
@@ -1046,7 +1064,7 @@ class ShardedEngine:
         if _wire_native is None:
             return None
         cnt = _wire_native.count_req_items(data, excluded)
-        if not cnt or cnt > self.wave_buckets[-1]:
+        if not cnt or cnt > self.wave_capacity:
             return None  # oversize: classic path splits into waves
         rows = Rows.empty(cnt)
         res = _wire_native.pack_wire_wave(data, now_ms, rows.m64, rows.m32,

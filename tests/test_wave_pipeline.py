@@ -423,3 +423,275 @@ def test_launched_wave_keeps_its_upload_buffers_until_synced():
             eng.drop_packed(tok)
     assert eng.wave_pool.stats()["outstanding"] == 0
     assert eng.wave_pool.stats()["leaks"] == 0
+
+
+# ---- the wave cap follows the engine's largest launch (ISSUE 49) --------
+
+def _cap_instance(monkeypatch, case):
+    from gubernator_tpu.config import Config
+    from gubernator_tpu.instance import V1Instance
+    from gubernator_tpu.oracle import OracleEngine
+
+    for name in ("GUBER_ENGINE", "GUBER_STEP_IMPL", "GUBER_WAVE_BUCKETS",
+                 "GUBER_ADMISSION_LIMIT"):
+        monkeypatch.delenv(name, raising=False)
+    cfg = Config(cache_size=1 << 12, sweep_interval_ms=0)
+    mesh, engine = make_mesh(n=1), None
+    if case.startswith("pallas"):
+        monkeypatch.setenv("GUBER_STEP_IMPL", "pallas")
+    if case == "pallas_small_ladder":
+        monkeypatch.setenv("GUBER_WAVE_BUCKETS", "128")
+    elif case == "pallas_small_batch_rows":
+        cfg = Config(cache_size=1 << 12, sweep_interval_ms=0, batch_rows=64)
+    elif case == "pallas_mesh":
+        mesh = make_mesh(n=2)
+    elif case == "oracle":
+        mesh, engine = None, OracleEngine()
+    return V1Instance(cfg, mesh=mesh, engine=engine)
+
+
+@pytest.mark.parametrize("case,capacity,max_wave", [
+    ("pallas_one_chip", 16384, 16384),   # 1,024 / 8,192 / 16,384
+    ("pallas_small_ladder", 128, 8192),  # every rehearsal block's ladder
+    ("pallas_small_batch_rows", 1024, 8192),
+    ("pallas_mesh", 8192, 8192),
+    ("xla_one_chip", 8192, 8192),
+    ("oracle", None, 8192),              # an engine without a ladder
+])
+def test_instance_caps_its_waves_at_what_one_launch_holds(
+        monkeypatch, case, capacity, max_wave):
+    """(c) the instance's dispatcher has ``max_wave == max(8192,
+    engine.wave_capacity)`` and an admission default of 65,536 rows
+    whatever that is."""
+    inst = _cap_instance(monkeypatch, case)
+    try:
+        assert getattr(inst.engine, "wave_capacity", None) == capacity
+        assert inst.dispatcher.max_wave == max_wave
+        assert inst.dispatcher.admission_limit == 65536
+        assert inst.dispatcher.debug_stats()["admission"][
+            "limit_rows"] == 65536
+    finally:
+        inst.close()
+
+
+@pytest.mark.parametrize("max_wave", [8192, 32768, 4])
+def test_admission_default_is_rows_not_waves(monkeypatch, max_wave):
+    """A bare dispatcher's ingress bound is 65,536 rows at any cap, and
+    GUBER_ADMISSION_LIMIT is still the one override."""
+    from gubernator_tpu.oracle import OracleEngine
+
+    monkeypatch.delenv("GUBER_ADMISSION_LIMIT", raising=False)
+    d = Dispatcher(OracleEngine(), max_wave=max_wave)
+    try:
+        assert d.admission_limit == Dispatcher.ADMISSION_LIMIT_ROWS == 65536
+    finally:
+        d.close()
+    monkeypatch.setenv("GUBER_ADMISSION_LIMIT", "777")
+    d = Dispatcher(OracleEngine(), max_wave=max_wave)
+    try:
+        assert d.admission_limit == 777
+    finally:
+        d.close()
+
+
+def _token_cols(tag, n, now, limit=50):
+    kh = hash_request_keys(["cap"] * n, [f"{tag}{i}" for i in range(n)])
+    b, _ = pack_columns(kh, np.ones(n, np.int64),
+                        np.full(n, limit, np.int64),
+                        np.full(n, 60_000, np.int64),
+                        np.zeros(n, np.int32), np.zeros(n, np.int32),
+                        np.zeros(n, np.int64), now)
+    return b, kh
+
+
+def _spy_widths(eng):
+    widths = []
+    real = type(eng)._launch_arrays.__get__(eng)
+
+    def spy(a64, a32, *rest):
+        widths.append(a64.shape[1])
+        return real(a64, a32, *rest)
+
+    eng._launch_arrays = spy
+    return widths
+
+
+@pytest.mark.parametrize("ladder,want", [
+    (None, [64, 128, 64]),            # 8 / 64 / 128: two calls a wave
+    ((8, 64, 128, 256), [64, 256]),   # an operator's: the door in ONE wave
+    ((8, 64), [64, 64, 64, 64]),      # the old ladder under the old cap
+])
+def test_saturated_wave_takes_what_the_door_holds(ladder, want):
+    """A dispatcher capped at its engine's ``wave_capacity`` drains the
+    calls that queued behind a wave up to what ONE launch holds, and
+    that wave is ONE launch of the rung that covers it (the call that
+    would pass the cap leads the next wave); under the old ladder the
+    same calls are a wave each."""
+    from gubernator_tpu.parallel.pallas_engine import PallasServingEngine
+
+    eng = PallasServingEngine(make_mesh(n=1), capacity_per_shard=1 << 10,
+                              batch_per_shard=8, wave_buckets=ladder)
+    widths = _spy_widths(eng)
+    disp = Dispatcher(eng, max_wave=eng.wave_capacity, max_delay_ms=0.2)
+    release, entered = threading.Event(), threading.Event()
+    real_launch = eng.launch_packed
+
+    def gated(*a, **kw):
+        if not entered.is_set():
+            entered.set()
+            release.wait(timeout=60)
+        return real_launch(*a, **kw)
+
+    eng.launch_packed = gated
+    got, threads = {}, []
+    try:
+        for t in range(4):
+            b, kh = _token_cols(f"t{t}", 64, NOW + t)
+            th = threading.Thread(target=lambda t=t, b=b, kh=kh: got.update(
+                {t: disp.check_packed(b, kh, NOW + t)}))
+            th.start()
+            threads.append(th)
+            if t == 0:
+                assert entered.wait(timeout=60)
+        deadline = time.monotonic() + 30
+        while disp._queue.qsize() < 3 and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert disp._queue.qsize() >= 3
+    finally:
+        release.set()
+    for th in threads:
+        th.join(timeout=120)
+    disp.close()
+    assert widths == want
+    for t in range(4):
+        st, lim, rem, rst, full = got[t]
+        assert (rem == 49).all() and (lim == 50).all() and not full.any()
+    s = eng.wave_pool.stats()
+    assert s["outstanding"] == 0 and s["leaks"] == 0, s
+
+
+@pytest.mark.parametrize("native", ["1", "0"])
+def test_tiered_wave_past_the_old_top_rung_is_two_launches(monkeypatch,
+                                                           native):
+    """(d) with a tier bound, a wave of 2× the old top rung is still
+    exactly TWO launches — the wave on its 16·B rung and ONE
+    re-dispatch of its erred and cold-resident rows together, which
+    rides the ladder like any wave — and answers what an uncapped
+    table answers (``tier_launches_per_wave`` reads the same counter:
+    Δ``gubernator_wave_route_total`` a dispatcher wave)."""
+    from gubernator_tpu.metrics import Metrics
+    from gubernator_tpu.parallel.pallas_engine import PallasServingEngine
+    from gubernator_tpu.tiering import TierController
+
+    monkeypatch.setenv("GUBER_TIER_NATIVE", native)
+    mesh = make_mesh(n=1)
+    # one 128-slot bucket: full after 128 keys, the tier holds the rest
+    small = PallasServingEngine(mesh, capacity_per_shard=128,
+                                batch_per_shard=8)
+    big = ShardedEngine(mesh, capacity_per_shard=1 << 14,
+                        batch_per_shard=64)
+    small.metrics_ref = Metrics()
+    tc = TierController(small, rank_fn=lambda kh: 0)
+    assert tc.stats()["native"] == (native == "1")
+    assert small.wave_buckets == (8, 64, 128)
+    b_fill, kh_all = _token_cols("fill", 300, NOW)
+    for a in range(0, 300, 50):
+        b = type(b_fill)(*[np.asarray(c)[a:a + 50] for c in b_fill])
+        for e in (small, big):
+            assert not e.check_packed(b, kh_all[a:a + 50], NOW)[4].any()
+    assert small.occupancy() == 128 and tc.cold_keys() == 300 - 128
+    cold = tc.resident_mask(kh_all)
+    hot_i, cold_i = np.nonzero(~cold)[0], np.nonzero(cold)[0]
+    # 128 rows = 2 × the old top rung: device-resident keys, cold keys,
+    # keys nobody has seen (they err: the bucket is full), duplicates
+    pick = np.concatenate([hot_i[:60], cold_i[:30], hot_i[:10],
+                           cold_i[:8]])
+    rng = np.random.default_rng(49)
+    b_all, _ = _token_cols("fill", 300, NOW + 1000)
+    b_new, kh_new = _token_cols("new", 20, NOW + 1000)
+    rows = [np.concatenate([np.asarray(c)[pick], np.asarray(n)])
+            for c, n in zip(b_all, b_new)]
+    kh = np.concatenate([kh_all[pick], kh_new])
+    order = rng.permutation(len(kh))
+    batch = type(b_all)(*[r[order] for r in rows])
+    kh = kh[order]
+    assert len(kh) == 128
+    widths = _spy_widths(small)
+    route = small.metrics_ref.wave_route
+    routed0 = sum(route.labels(route=r)._value.get()
+                  for r in ("identity", "sorted"))
+    tok = small.launch_packed(batch, kh, NOW + 1000)
+    try:
+        cols = small.sync_packed(tok, engine_lock=threading.Lock())
+    finally:
+        small.drop_packed(tok)
+    assert widths == [128, 64]  # the wave; its 58 unanswered rows, once
+    assert sum(route.labels(route=r)._value.get()
+               for r in ("identity", "sorted")) - routed0 == 2
+    want = big.check_packed(batch, kh, NOW + 1000)
+    assert not cols[4].any()
+    for got, ref in zip(cols[:4], want[:4]):
+        assert np.asarray(got).tolist() == np.asarray(ref).tolist()
+
+
+@pytest.mark.parametrize("queued,windows", [
+    (9, 0),  # 9,000 rows behind the blocker: past the default cap
+    (3, 1),  # 3,000 rows: a small wave still waits for stragglers
+])
+def test_wave_past_the_default_cap_skips_the_straggler_window(queued,
+                                                              windows):
+    """A wave whose backlog alone fills the default cap (8,192 rows)
+    launches when the queue runs empty, as it did when that cap cut it;
+    the coalescing window is armed for smaller waves only."""
+
+    class NopEngine:
+        def check_packed(self, batch, khash, now):
+            m = len(khash)
+            return (np.zeros(m, np.int32), np.zeros(m, np.int64),
+                    np.zeros(m, np.int64), np.zeros(m, np.int64),
+                    np.zeros(m, bool))
+
+    eng = NopEngine()
+    disp = Dispatcher(eng, max_wave=32768, max_delay_ms=0.2)
+    b, kh = _token_cols("sw", 1000, NOW)
+    sizes, timed = [], []
+    release, entered = threading.Event(), threading.Event()
+    plain = eng.check_packed
+
+    def gated(batch, khash, now):
+        if not entered.is_set():
+            entered.set()
+            release.wait(timeout=30)
+        sizes.append(len(khash))
+        return plain(batch, khash, now)
+
+    eng.check_packed = gated
+    real_get = disp._queue.get
+
+    def get(block=True, timeout=None):
+        if block and timeout is not None and timeout <= disp.max_delay_s:
+            timed.append(timeout)
+        return real_get(block, timeout)
+
+    disp._queue.get = get
+    threads = []
+    try:
+        for t in range(queued + 1):
+            th = threading.Thread(
+                target=lambda t=t: disp.check_packed(b, kh, NOW + t))
+            th.start()
+            threads.append(th)
+            if t == 0:
+                assert entered.wait(timeout=30)
+                del timed[:]  # the blocker's own window
+        deadline = time.monotonic() + 30
+        while disp._queue.qsize() < queued and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert disp._queue.qsize() >= queued
+    finally:
+        release.set()
+    for th in threads:
+        th.join(timeout=60)
+    disp.close()
+    assert sizes == [1000, queued * 1000], sizes
+    assert len(timed) == windows, timed
